@@ -19,10 +19,6 @@ _here = (os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.abspath(os.path.join(_here, "..", "..")))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env
-
-apply_platform_env()
-
 # %% [markdown]
 # ## 1. Triple extraction
 # An LLM turns prose into typed triples. The extractor asks for a JSON

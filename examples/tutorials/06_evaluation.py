@@ -11,7 +11,7 @@
 #     python -m generativeaiexamples_tpu.eval --docs README.md --offline
 #
 # and `scripts/run_eval_e2e.py` runs it against a REAL chain server +
-# engine, committing `eval_results/eval_report.json`.
+# engine, writing `eval_results/eval_report.json` (not committed).
 
 # %%
 import json
@@ -23,10 +23,6 @@ _here = (os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.abspath(os.path.join(_here, "..", ".."))
 sys.path.insert(0, ROOT)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-from generativeaiexamples_tpu.utils.platform import apply_platform_env
-
-apply_platform_env()
 
 # %% [markdown]
 # ## Stage 1 — synthetic QA generation

@@ -64,10 +64,13 @@ class ChainServer:
     def __init__(self, config: AppConfig, example=None,
                  example_name: Optional[str] = None,
                  upload_dir: str = "/tmp/gaie_tpu/uploaded_files"):
+        from generativeaiexamples_tpu.connectors.factory import (
+            uses_local_device)
         from generativeaiexamples_tpu.pipelines.base import get_example_class
         from generativeaiexamples_tpu.pipelines.resources import Resources
 
         self.config = config
+        self._uses_device = uses_local_device(config)
         tracing.setup(config)  # no-op unless tracing.enabled/ENABLE_TRACING
         if example is not None:
             self.example = example
@@ -101,12 +104,17 @@ class ChainServer:
     # -- /health -----------------------------------------------------------
 
     async def handle_health(self, request: web.Request) -> web.Response:
-        import jax
+        # Device liveness only where this process owns a device: with
+        # remote connectors and a host-side store it must not initialise
+        # a JAX backend (the engine server's process holds the chip).
+        if self._uses_device:
+            import jax
 
-        try:
-            jax.devices()
-        except Exception as e:
-            return web.json_response({"message": f"unhealthy: {e}"}, status=503)
+            try:
+                jax.devices()
+            except Exception as e:
+                return web.json_response({"message": f"unhealthy: {e}"},
+                                         status=503)
         return web.json_response({"message": "Service is up."})
 
     # -- /metrics ----------------------------------------------------------
@@ -307,10 +315,6 @@ class ChainServer:
 def main() -> None:
     import argparse
 
-    from generativeaiexamples_tpu.utils.platform import apply_platform_env
-
-    apply_platform_env()
-
     ap = argparse.ArgumentParser(description="TPU RAG chain server")
     ap.add_argument("--host", default="0.0.0.0")
     ap.add_argument("--port", type=int, default=8081)
@@ -322,8 +326,15 @@ def main() -> None:
     logging.basicConfig(level=logging.DEBUG if args.verbose else logging.INFO)
 
     from generativeaiexamples_tpu.config.wizard import load_config
+    from generativeaiexamples_tpu.connectors.factory import uses_local_device
 
-    server = ChainServer(load_config(args.config), example_name=args.example)
+    config = load_config(args.config)
+    if uses_local_device(config):
+        from generativeaiexamples_tpu.utils.platform import (
+            setup_compile_cache)
+
+        setup_compile_cache()
+    server = ChainServer(config, example_name=args.example)
     _LOG.info("chain server: example=%s on %s:%d",
               server.example.example_name, args.host, args.port)
     web.run_app(server.app, host=args.host, port=args.port, print=None)

@@ -64,6 +64,25 @@ class EngineHub:
         return self._rerank
 
 
+_FAKE_OR_REMOTE = ("echo", "hash", "overlap", "test", "lexical", "tfidf",
+                   "bm25", "openai", "nim", "remote")
+
+
+def uses_local_device(config: AppConfig) -> bool:
+    """True when THIS process will run JAX itself: an in-process engine
+    behind a connector below, or the device-resident vector store. With
+    every connector remote and a host-side store, a chain server never
+    initialises a JAX backend — a chip belongs to one process, and that
+    process is the engine server."""
+    def in_process(section) -> bool:
+        return (not section.server_url
+                and section.model_engine not in _FAKE_OR_REMOTE)
+
+    return (in_process(config.llm) or in_process(config.embeddings)
+            or (config.reranker.enabled and in_process(config.reranker))
+            or config.vector_store.name in ("tpu", "native"))
+
+
 def get_llm(config: AppConfig, hub: Optional[EngineHub] = None):
     eng = config.llm.model_engine
     if eng in ("echo", "test"):

@@ -22,6 +22,7 @@ GQA, SwiGLU MLP, optional tied embeddings.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -31,7 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from generativeaiexamples_tpu.ops import attention as attn_ops
-from generativeaiexamples_tpu.ops.quant import mm
+from generativeaiexamples_tpu.ops.quant import QuantizedTensor, mm
 from generativeaiexamples_tpu.parallel.mesh import LLM_RULES, logical_to_spec
 
 Params = Dict[str, Any]
@@ -129,6 +130,79 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = norm(k[0], D, cfg.vocab_size, scale=D ** -0.5)
+    return params
+
+
+def init_params_on_device(cfg: LlamaConfig, seed: int = 0, *,
+                          quantize: bool = False, shardings=None) -> Params:
+    """Seeded random params drawn leaf by leaf ON DEVICE, each leaf
+    directly in its final dtype — the full-width path when no checkpoint
+    exists (hermetic serving, bench, chip_smoke). Same tree as
+    `init_params` (or, with `quantize`, as
+    `quantize_llama_params(init_params(...))`), but no f32 or bf16 copy
+    of a quantized leaf ever exists: llama3-8b int8 peaks at one leaf
+    above its ~9 GB, where init-then-quantize needs 7.5 GB of f32 for
+    `w_gate` alone. int8 codes are uniform with the per-column scale
+    that gives the leaf `init_params`' standard deviation.
+
+    `shardings`: a tree of NamedSharding aligned with the result
+    (serving.sharding.param_shardings over `jax.eval_shape` of this
+    function); each leaf is then created already sharded — never whole
+    on one chip and moved."""
+    D, H, KH, Hd, M, L, V = (cfg.dim, cfg.n_heads, cfg.n_kv_heads,
+                             cfg.head_dim, cfg.mlp_dim, cfg.n_layers,
+                             cfg.vocab_size)
+    root = jax.random.key(seed)
+    leaf_ids = itertools.count(1)
+
+    def draw(fn, sharding):
+        key = jax.random.fold_in(root, next(leaf_ids))
+        return jax.jit(fn, out_shardings=sharding)(key)
+
+    def sharding_at(*path):
+        sh = shardings
+        for name in path:
+            sh = None if sh is None else sh[name]
+        return sh
+
+    def ones(path, *shape):
+        return draw(lambda k: jnp.ones(shape, cfg.dtype), sharding_at(*path))
+
+    def normal(path, *shape, scale):
+        return draw(lambda k: jax.random.normal(k, shape, cfg.dtype)
+                    * jnp.asarray(scale, cfg.dtype), sharding_at(*path))
+
+    def weight(path, *shape, scale=None):
+        scale = scale if scale is not None else shape[-2] ** -0.5
+        if not quantize:
+            return normal(path, *shape, scale=scale)
+        sh = sharding_at(*path)
+        # uniform codes in [-127, 127] have std 127/sqrt(3)
+        q = draw(lambda k: jnp.maximum(jax.lax.bitcast_convert_type(
+            jax.random.bits(k, shape, jnp.uint8), jnp.int8), -127),
+            None if sh is None else sh.q)
+        s = draw(lambda k: jnp.full(shape[:-2] + shape[-1:],
+                                    scale * 3 ** 0.5 / 127.0, jnp.float32),
+                 None if sh is None else sh.s)
+        return QuantizedTensor(q, s)
+
+    params: Params = {
+        "tok_emb": normal(("tok_emb",), V, D, scale=0.02),
+        "ln_f": ones(("ln_f",), D),
+        "layers": {
+            "ln1": ones(("layers", "ln1"), L, D),
+            "ln2": ones(("layers", "ln2"), L, D),
+            "wq": weight(("layers", "wq"), L, D, H * Hd),
+            "wk": weight(("layers", "wk"), L, D, KH * Hd),
+            "wv": weight(("layers", "wv"), L, D, KH * Hd),
+            "wo": weight(("layers", "wo"), L, H * Hd, D),
+            "w_gate": weight(("layers", "w_gate"), L, D, M),
+            "w_up": weight(("layers", "w_up"), L, D, M),
+            "w_down": weight(("layers", "w_down"), L, M, D),
+        },
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = weight(("lm_head",), D, V, scale=D ** -0.5)
     return params
 
 

@@ -25,11 +25,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu only resolves on TPU-capable installs; tests interpret on CPU
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from generativeaiexamples_tpu.utils.platform import log_kernel_declined
 
 NEG_INF = -1e30
 
@@ -178,11 +176,6 @@ def flash_attention(
     after clamping (callers pad to bucket sizes; serving always runs
     bucketed shapes so XLA never re-tiles — SURVEY.md §7.4 item 2).
     """
-    if pltpu is None:
-        raise RuntimeError(
-            "Pallas TPU support unavailable in this jax install; "
-            "use mha_reference / attention() instead"
-        )
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     KH = k.shape[1]
@@ -281,23 +274,27 @@ def attention(
     # The kernel handles cached-continuation prefill (q_offset) and any
     # 8-multiple shape (blocks shrink to divide) — the r1 dispatcher
     # silently took the O(S^2) reference path for both (VERDICT weak #7).
-    if use_pallas and pltpu is not None and Sq % 8 == 0 and Sk % 8 == 0:
+    if use_pallas and (Sq % 8 or Sk % 8):
+        log_kernel_declined(
+            "flash_attention", "the O(S^2) XLA reference",
+            f"Sq {Sq} and Sk {Sk} must both be multiples of 8")
+        use_pallas = False
+    if use_pallas:
         ln = lengths if lengths is not None \
             else jnp.full((B,), Sk, jnp.int32)
         off = q_offset if q_offset is not None \
             else jnp.zeros((B,), jnp.int32)
         if mesh is not None and mesh.shape.get("tensor", 1) > 1:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             hs = P(None, "tensor", None, None)
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda q_, k_, v_, ln_, off_: flash_attention(
                     q_, k_, v_, causal=causal, lengths=ln_, q_offset=off_,
                     scale=scale, interpret=interpret,
                     block_q=block_q, block_k=block_k),
                 mesh=mesh, in_specs=(hs, hs, hs, P(), P()), out_specs=hs,
-                check_rep=False)
+                check_vma=False)
             return fn(q, k, v, ln, off)
         return flash_attention(q, k, v, causal=causal, lengths=ln,
                                q_offset=off, scale=scale,

@@ -29,12 +29,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:  # pragma: no cover - exercised on TPU installs
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _encoder_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, *,
@@ -72,8 +68,6 @@ def encoder_attention(
     g_heads: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    if pl is None:  # pragma: no cover
-        raise RuntimeError("Pallas unavailable; use mha_reference")
     B, H, S, D = q.shape
     scale = scale if scale is not None else D ** -0.5
     if lengths is None:

@@ -26,11 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _pick_block(dim: int, candidates=(1024, 512, 256, 128)) -> Optional[int]:
@@ -74,8 +70,6 @@ def int8_matmul(x: jax.Array, q: jax.Array, scale: jax.Array, *,
     """x [B, K] @ q [K, M] int8, scaled per output column. Returns
     [B, M] in out_dtype (default x.dtype). Raises ValueError when the
     shape doesn't tile (callers fall back to the XLA path)."""
-    if pltpu is None:
-        raise RuntimeError("Pallas TPU unavailable")
     B, K = x.shape
     K2, M = q.shape
     assert K == K2, (x.shape, q.shape)

@@ -471,13 +471,11 @@ class ShardedIVFIndex:
             top, pos = jax.lax.top_k(best, min(k, n_shards * kk))
             return top, jnp.take_along_axis(gidx, pos, axis=1), scanned
 
-        from generativeaiexamples_tpu.ops.topk import shard_map_compat
-
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(axis), P(axis),
                       P(axis) if quant else P()),
-            out_specs=(P(), P(), P()))
+            out_specs=(P(), P(), P()), check_vma=False)
         return jax.jit(fn)
 
     def search(self, queries: jax.Array, k: int,

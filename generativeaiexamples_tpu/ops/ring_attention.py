@@ -83,13 +83,7 @@ def ring_attention(
     local index i on ring rank r is r * S_local + i."""
     B, H, S_local, D = q.shape
     scale = scale if scale is not None else D ** -0.5
-    # jax.lax.axis_size is the new spelling; older jax exposes the ring
-    # size through the trace-time axis environment.
-    if hasattr(jax.lax, "axis_size"):
-        ring = jax.lax.axis_size(axis_name)
-    else:
-        frame = jax.core.axis_frame(axis_name)
-        ring = frame if isinstance(frame, int) else frame.size
+    ring = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     q_pos = rank * S_local + jnp.arange(S_local)
 
@@ -99,13 +93,8 @@ def ring_attention(
     # Mark the accumulators as varying over the ring axis: they are
     # per-shard state from step 0's output onward, and shard_map's
     # varying-axis tracking requires the loop carry type to say so up
-    # front. (pcast in jax>=0.8; pvary before.)
-    if hasattr(jax.lax, "pcast"):
-        vary = lambda x: jax.lax.pcast(x, axis_name, to="varying")  # noqa: E731
-    elif hasattr(jax.lax, "pvary"):
-        vary = lambda x: jax.lax.pvary(x, (axis_name,))  # noqa: E731
-    else:  # pre-varying-axis-tracking jax: plain values are fine
-        vary = lambda x: x  # noqa: E731
+    # front.
+    vary = lambda x: jax.lax.pcast(x, axis_name, to="varying")  # noqa: E731
     o = vary(jnp.zeros((B, H, S_local, D), jnp.float32))
     m = vary(jnp.full((B, H, S_local, 1), NEG_INF / 2, jnp.float32))
     l = vary(jnp.zeros((B, H, S_local, 1), jnp.float32))
@@ -148,15 +137,14 @@ def ring_attention_sharded(
     """shard_map wrapper: S splits over the mesh sequence axis, heads/
     batch follow their usual axes (replicated here; compose with the
     tensor axis by extending the specs)."""
-    from generativeaiexamples_tpu.ops.topk import shard_map_compat
-
     if q.shape[2] % mesh.shape[axis_name]:
         raise ValueError(
             f"sequence length {q.shape[2]} must be divisible by the "
             f"{mesh.shape[axis_name]}-way {axis_name} axis")
     spec = P(None, None, axis_name, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name,
                           causal=causal, scale=scale),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return fn(q, k, v)

@@ -24,28 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the top-level API when present
-    (`jax.shard_map`), else the pre-0.5 experimental one — and the
-    replication-check kwarg under whichever of its two spellings the
-    resolved function accepts (check_vma in newer jax, check_rep
-    before). Checking is off either way: the reductions here produce
-    replicated outputs the checker cannot prove. Kwarg probing matters
-    because the jax versions that moved the function and the ones that
-    renamed the kwarg are not the same set."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    for check_kwarg in ("check_vma", "check_rep"):
-        try:
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **{check_kwarg: False})
-        except TypeError:
-            continue
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 @functools.partial(jax.jit, static_argnames=("k",))
 def mips_topk(queries: jax.Array, database: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     """Exact top-k inner products. queries [Q,D], database [N,D] ->
@@ -100,11 +78,14 @@ class ShardedMIPSIndex:
             best, pos = jax.lax.top_k(s, min(k, n_rows))
             return best, jnp.take_along_axis(idx, pos, axis=1)
 
-        fn = shard_map_compat(
+        # check_vma off: the all-gather + re-top-k yields replicated
+        # outputs the checker cannot prove.
+        fn = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(P(), P(axis)),
             out_specs=(P(), P()),
+            check_vma=False,
         )
         return jax.jit(fn)
 

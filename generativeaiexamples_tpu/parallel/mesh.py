@@ -24,6 +24,7 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from generativeaiexamples_tpu.config.schema import MeshConfig
@@ -119,12 +120,9 @@ def build_mesh(cfg: Optional[MeshConfig] = None, devices: Optional[Sequence] = N
     devices = list(devices if devices is not None else jax.devices())
     sizes = _resolve_axis_sizes(cfg, len(devices))
     shape = tuple(sizes[a] for a in MESH_AXIS_NAMES)
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        dev_array = np.asarray(devices).reshape(shape)
+    # Topology-aware order (ICI neighbours adjacent on the fastest axes);
+    # a failure here is a wrong mesh for the attached slice and raises.
+    dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(dev_array, MESH_AXIS_NAMES)
 
 

@@ -15,19 +15,17 @@ Serves /v1/chat/completions, /v1/completions, /v1/embeddings,
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import logging
 import os
+import time
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env
-
-apply_platform_env()
-
-import jax  # noqa: E402
+import jax
+import jax.numpy as jnp
 
 
-def build_engines(cfg, model_size: str = "tiny"):
+def build_engines(cfg, model_size: str = "tiny", seed: int = 0):
     from generativeaiexamples_tpu.models import bert, llama
-    from generativeaiexamples_tpu.ops.quant import quantize_llama_params
     from generativeaiexamples_tpu.parallel.mesh import (
         build_mesh, maybe_initialize_distributed)
     from generativeaiexamples_tpu.serving import sharding as shd
@@ -91,17 +89,21 @@ def build_engines(cfg, model_size: str = "tiny"):
             "70b": llama.LlamaConfig.llama3_70b,
         }[model_size]
         lcfg = geometry()
-        logging.warning("engine.weights_path empty: random-init %s model "
-                        "(dev/bench mode)", model_size)
-        params = llama.init_params(lcfg, jax.random.PRNGKey(0))
+        logging.warning("engine.weights_path empty: seeded random %s model "
+                        "(dev/bench mode, seed %d)", model_size, seed)
+        # Drawn on device leaf by leaf in the final dtype and layout:
+        # llama3-8b int8 never exists as f32/bf16, and under a mesh no
+        # leaf is ever whole on one chip.
+        quantize = cfg.engine.quantize_weights == "int8"
+        if mesh is not None:
+            mesh = shd.compatible_mesh(lcfg, mesh)
+            params = shd.init_sharded_params(lcfg, mesh, seed,
+                                             quantize=quantize)
+        else:
+            params = llama.init_params_on_device(lcfg, seed,
+                                                 quantize=quantize)
         tokenizer = load_tokenizer("byte")
-
-    if cfg.engine.quantize_weights == "int8" and not cfg.engine.weights_path:
-        params = quantize_llama_params(params)  # loader handles the rest
     if mesh is not None:
-        if not cfg.engine.weights_path:  # real weights: loader already
-            mesh = shd.compatible_mesh(lcfg, mesh)  # clamped + placed above
-            params = shd.shard_llama_params(params, lcfg, mesh)
         logging.info("llama params sharded over mesh %s", dict(mesh.shape))
 
     n_replicas = max(1, cfg.fleet.replicas)
@@ -125,7 +127,8 @@ def build_engines(cfg, model_size: str = "tiny"):
                                              cfg.engine, mesh=mesh))
     else:
         llm = LLMEngine(params, lcfg, tokenizer, cfg.engine, mesh=mesh)
-    if os.environ.get("ENGINE_WARMUP", "1") != "0":
+    warm = os.environ.get("ENGINE_WARMUP", "1") != "0"
+    if warm:
         # Precompile prefill/decode variants so the first multi-request
         # burst never stalls live streams behind a compile; the
         # persistent compile cache makes later boots cheap. Sampled
@@ -133,9 +136,12 @@ def build_engines(cfg, model_size: str = "tiny"):
         # first real request must not eat the compile. (Fleet: the
         # jitted steps are module-level, so replica 2..N reuse replica
         # 1's compilations.)
+        t0 = time.perf_counter()
         llm.warmup(sampled=True,
                    long_prompts=os.environ.get("ENGINE_WARMUP_LONG",
                                                "0") == "1")
+        logging.info("engine warm-up done in %.1fs",
+                     time.perf_counter() - t0)
     if cfg.engine.multihost and jax.process_index() != 0:
         # Follower ranks replay rank 0's dispatch records (the
         # multihost.run_follower loop, driven from main()) — their
@@ -150,8 +156,11 @@ def build_engines(cfg, model_size: str = "tiny"):
     hermetic = not cfg.engine.weights_path
     # Encoders: real weights come from their OWN snapshots + tokenizers
     # (a llama tokenizer against a BERT vocab would silently index out of
-    # range). Without weights: hermetic tiny random models in dev mode,
-    # disabled (None -> 503) when the LLM is real.
+    # range). Without weights: seeded random models — 32-wide toys beside
+    # the tiny LLM, the published geometries (arctic-embed-l's 1024
+    # dimensions are what the chain server's default config expects)
+    # beside a full-size one — and disabled (None -> 503) when the LLM
+    # is real.
     emb = rr = None
     if cfg.embeddings.weights_path:
         from generativeaiexamples_tpu.models.hf_loader import load_bert
@@ -160,9 +169,12 @@ def build_engines(cfg, model_size: str = "tiny"):
         emb = EmbeddingEngine(bparams, bcfg,
                               load_tokenizer(cfg.embeddings.weights_path))
     elif hermetic:
-        bcfg = bert.BertConfig.tiny(vocab_size=512)
-        emb = EmbeddingEngine(bert.init_params(bcfg, jax.random.PRNGKey(1)),
-                              bcfg, tokenizer)
+        bcfg = (bert.BertConfig.tiny(vocab_size=512) if model_size == "tiny"
+                else dataclasses.replace(bert.BertConfig.arctic_embed_l(),
+                                         dtype=jnp.bfloat16))
+        emb = EmbeddingEngine(
+            bert.init_params(bcfg, jax.random.PRNGKey(seed + 1)),
+            bcfg, tokenizer)
     if cfg.reranker.weights_path:
         from generativeaiexamples_tpu.models.hf_loader import load_bert
 
@@ -170,11 +182,21 @@ def build_engines(cfg, model_size: str = "tiny"):
         rr = RerankEngine(rparams, rcfg,
                           load_tokenizer(cfg.reranker.weights_path))
     elif hermetic:
-        rcfg = bert.BertConfig(vocab_size=512, dim=32, n_layers=2,
-                               n_heads=2, mlp_dim=64, max_position=64,
-                               n_labels=1)
-        rr = RerankEngine(bert.init_params(rcfg, jax.random.PRNGKey(2)),
-                          rcfg, tokenizer)
+        rcfg = (bert.BertConfig(vocab_size=512, dim=32, n_layers=2,
+                                n_heads=2, mlp_dim=64, max_position=64,
+                                n_labels=1) if model_size == "tiny"
+                else dataclasses.replace(bert.BertConfig.reranker_base(),
+                                         dtype=jnp.bfloat16))
+        rr = RerankEngine(
+            bert.init_params(rcfg, jax.random.PRNGKey(seed + 2)),
+            rcfg, tokenizer)
+    if warm:
+        t0 = time.perf_counter()
+        for enc in (emb, rr):
+            if enc is not None:
+                enc.warmup()
+        logging.info("encoder warm-up done in %.1fs",
+                     time.perf_counter() - t0)
     return llm, emb, rr
 
 
@@ -186,6 +208,9 @@ def main() -> None:
     ap.add_argument("--model-size", default="tiny",
                     choices=("tiny", "1b", "8b", "70b"),
                     help="geometry when engine.weights_path is empty")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights when "
+                         "engine.weights_path is empty")
     ap.add_argument("--coordinator", default="",
                     help="rank-0 address host:port for jax.distributed "
                          "(multi-host serving; overrides "
@@ -203,11 +228,11 @@ def main() -> None:
     from generativeaiexamples_tpu.config.wizard import load_config
     from generativeaiexamples_tpu.serving.openai_server import (
         OpenAIServer, run_server)
+    from generativeaiexamples_tpu.utils.platform import setup_compile_cache
 
+    logging.info("persistent compile cache: %s", setup_compile_cache())
     cfg = load_config(args.config)
     if args.coordinator or args.num_processes or args.process_id is not None:
-        import dataclasses
-
         cfg = dataclasses.replace(cfg, mesh=dataclasses.replace(
             cfg.mesh,
             coordinator_address=(args.coordinator
@@ -215,7 +240,7 @@ def main() -> None:
             num_processes=args.num_processes or cfg.mesh.num_processes,
             process_id=(args.process_id if args.process_id is not None
                         else cfg.mesh.process_id)))
-    llm, emb, rr = build_engines(cfg, args.model_size)
+    llm, emb, rr = build_engines(cfg, args.model_size, args.seed)
     if cfg.engine.multihost and jax.process_index() != 0:
         from generativeaiexamples_tpu.serving.multihost import run_follower
 

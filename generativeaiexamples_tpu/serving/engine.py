@@ -583,11 +583,6 @@ class LLMEngine:
             self._mh_log = mh.DispatchLog()
             self._mh_leader = jax.process_index() == 0
         self._mh_stop_sent = False
-        if self.ecfg.compile_cache_dir:
-            from generativeaiexamples_tpu.utils.platform import (
-                setup_compile_cache)
-
-            setup_compile_cache(self.ecfg.compile_cache_dir)
         # Experimental opt-in: int8 weights through the Pallas
         # dequant-matmul kernel. Measured on v5e (llama3-8b int8, B=64):
         # XLA path 1811 tok/s vs kernel 1424-1458 — XLA's convert+dot

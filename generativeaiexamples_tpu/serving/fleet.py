@@ -635,7 +635,13 @@ def spawn_process_replica(rid: str, *, host: str = "127.0.0.1",
     call. On timeout or early exit the process is killed and
     RuntimeError raised (the autoscaler logs and retries on a later
     tick). The child inherits this process's environment (JAX_*,
-    APP_* overrides) plus `env`."""
+    APP_* overrides) plus `env`.
+
+    A process replica needs a chip of its own: a TPU belongs to one
+    process at a time, so a child started beside a parent (or sibling)
+    that holds the only chip fails or hangs at its first JAX call. Pin
+    it to a free chip or to the CPU through `env`. Its output goes to a
+    log file named in the RuntimeError, so a failed boot is readable."""
     import os
     import subprocess
     import sys
@@ -651,16 +657,21 @@ def spawn_process_replica(rid: str, *, host: str = "127.0.0.1",
     penv.update(env or {})
     if not warm:
         penv["ENGINE_WARMUP"] = "0"
-    proc = subprocess.Popen(cmd, env=penv,
-                            stdout=subprocess.DEVNULL,
-                            stderr=subprocess.DEVNULL)
+    import tempfile
+
+    log = tempfile.NamedTemporaryFile(
+        prefix=f"gaie_replica_{rid}_", suffix=".log", delete=False)
+    with log:
+        proc = subprocess.Popen(cmd, env=penv, stdout=log,
+                                stderr=subprocess.STDOUT)
     base_url = f"http://{host}:{port}"
     deadline = time.monotonic() + ready_timeout_s
     while time.monotonic() < deadline:
         if proc.poll() is not None:
             raise RuntimeError(
                 f"process replica {rid} exited with code "
-                f"{proc.returncode} before becoming ready")
+                f"{proc.returncode} before becoming ready "
+                f"(log: {log.name})")
         try:
             with urllib.request.urlopen(base_url + "/health",
                                         timeout=probe_timeout_s) as resp:
@@ -673,7 +684,7 @@ def spawn_process_replica(rid: str, *, host: str = "127.0.0.1",
         time.sleep(0.25)
     proc.kill()
     raise RuntimeError(f"process replica {rid} not ready within "
-                       f"{ready_timeout_s}s")
+                       f"{ready_timeout_s}s (log: {log.name})")
 
 
 class _ReqRecord:

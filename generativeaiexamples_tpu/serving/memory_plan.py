@@ -255,18 +255,20 @@ def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
 
 
 def device_hbm_bytes(ecfg: EngineConfig) -> int:
-    """Per-device HBM budget: config override, else backend probe
-    (TPU memory_stats), else the CPU-backend default."""
+    """Per-device HBM budget: config override, else what the TPU reports
+    (memory_stats()["bytes_limit"]; a TPU that reports none is an error,
+    never a guess), else the CPU-backend default."""
     if ecfg.hbm_gb_per_device > 0:
         return int(ecfg.hbm_gb_per_device * GiB)
-    try:
-        dev = jax.local_devices()[0]
-        stats = dev.memory_stats() if hasattr(dev, "memory_stats") else None
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return _CPU_DEFAULT_HBM
+    dev = jax.local_devices()[0]
+    if dev.platform != "tpu":
+        return _CPU_DEFAULT_HBM
+    stats = dev.memory_stats() or {}
+    if not stats.get("bytes_limit"):
+        raise MemoryPlanError(
+            f"{dev.device_kind} reports no memory_stats()['bytes_limit'] "
+            f"(got {sorted(stats)}); set engine.hbm_gb_per_device")
+    return int(stats["bytes_limit"])
 
 
 def mesh_axis_sizes(mesh) -> Dict[str, int]:

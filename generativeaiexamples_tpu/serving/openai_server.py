@@ -196,12 +196,19 @@ class OpenAIServer:
         import jax
 
         try:
-            n = len(jax.devices())
+            devices = jax.devices()
         except Exception as e:  # device lost (e.g. TPU preemption)
             return web.json_response({"status": "unhealthy", "error": str(e)},
                                      status=503)
         payload = {
-            "status": "healthy", "devices": n,
+            "status": "healthy", "devices": len(devices),
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            # bytes_in_use / peak_bytes_in_use / bytes_limit of device 0
+            # where the backend reports them (the TPU does; CPU: {}).
+            "device_memory": {
+                k: v for k, v in (devices[0].memory_stats() or {}).items()
+                if k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")},
             "engines": {"llm": self.llm is not None,
                         "embedding": self.embed is not None,
                         "reranking": self.rerank is not None},

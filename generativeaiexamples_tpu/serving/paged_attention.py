@@ -32,17 +32,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu.paged_attention import (
+    paged_attention as _stdlib_paged_attention)
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
-try:
-    from jax.experimental.pallas.ops.tpu.paged_attention import (
-        paged_attention as _stdlib_paged_attention)
-except Exception:  # pragma: no cover
-    _stdlib_paged_attention = None
+from generativeaiexamples_tpu.utils.platform import log_kernel_declined
 
 NEG_INF = -1e30
 
@@ -234,8 +228,6 @@ def paged_attention(
     scale: Optional[float] = None, interpret: bool = False,
 ) -> jax.Array:
     """In-repo Pallas paged decode attention (see module docstring)."""
-    if pltpu is None:
-        raise RuntimeError("Pallas TPU unavailable; use paged_attention_reference")
     B, H, Hd = q.shape
     KH, P, ps, _ = k_pages.shape
     maxp = page_table.shape[1]
@@ -294,8 +286,7 @@ def _paged_tpu(q, k_pages, v_pages, page_table, lengths, *, scale,
     # blocks and requires head_dim % 128 == 0 — llama3.2-1b (Hd=64)
     # lowers to a BlockSpec error. Our single-page kernel handles any
     # (8-aligned) head_dim, so geometry gates the choice.
-    use_stdlib = (_stdlib_paged_attention is not None and not interpret
-                  and Hd % 128 == 0
+    use_stdlib = (not interpret and Hd % 128 == 0
                   and _KERNEL_CHOICE in ("auto", "stdlib"))
     if use_stdlib:
         ppcb = _pages_per_block(maxp, pages_per_compute_block)
@@ -323,6 +314,9 @@ def _paged_tpu_int8(q, kv_pages, kv_scales, page_table, lengths, layer, *,
         return paged_attention_int8(
             q, kv_pages, kv_scales, page_table, lengths, layer,
             scale=scale, pages_per_compute_block=pages_per_compute_block)
+    log_kernel_declined(
+        "paged_attention_int8", "the XLA gather reference",
+        f"page_size {ps} and head_dim {Hd} must both be multiples of 128")
     return paged_attention_int8_reference_fused(
         q, kv_pages[:, layer], kv_scales[:, layer], page_table, lengths,
         scale=scale)
@@ -347,7 +341,7 @@ def paged_attention_dispatch(
     quantized = k_scales is not None
     use_pallas = (jax.default_backend() == "tpu") if use_pallas is None \
         else use_pallas
-    if not use_pallas or pltpu is None:
+    if not use_pallas:
         if quantized:
             from generativeaiexamples_tpu.serving.paged_attention_int8 import (
                 paged_attention_int8_reference_fused)
@@ -358,7 +352,6 @@ def paged_attention_dispatch(
         return paged_attention_reference(q, k_pages, v_pages, page_table,
                                          lengths, scale=scale)
     if mesh is not None and mesh.shape.get("tensor", 1) > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         hs = P(None, "tensor", None)
@@ -366,22 +359,22 @@ def paged_attention_dispatch(
             # Full fused pool [2, L, KH, P, ...]: kv-heads (the TP
             # axis) at axis 2.
             fused_s = P(None, None, "tensor")
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda q_, kvp_, s_, t_, ln_, ly_: _paged_tpu_int8(
                     q_, kvp_, s_, t_, ln_, ly_, scale=scale,
                     pages_per_compute_block=pages_per_compute_block),
                 mesh=mesh,
                 in_specs=(hs, fused_s, fused_s, P(), P(), P()),
-                out_specs=hs, check_rep=False)
+                out_specs=hs, check_vma=False)
             return fn(q, k_pages, k_scales, page_table, lengths,
                       jnp.asarray(layer, jnp.int32))
         pool_s = P("tensor", None, None, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q_, kp_, vp_, t_, ln_: _paged_tpu(
                 q_, kp_, vp_, t_, ln_, scale=scale, interpret=interpret,
                 pages_per_compute_block=pages_per_compute_block),
             mesh=mesh, in_specs=(hs, pool_s, pool_s, P(), P()),
-            out_specs=hs, check_rep=False)
+            out_specs=hs, check_vma=False)
         return fn(q, k_pages, v_pages, page_table, lengths)
     if quantized:
         return _paged_tpu_int8(q, k_pages, k_scales, page_table, lengths,

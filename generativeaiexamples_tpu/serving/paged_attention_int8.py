@@ -45,22 +45,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-
-def compiler_params(**kw):
-    """pltpu compiler-params across JAX versions: the class was named
-    TPUCompilerParams through 0.4.x and CompilerParams after the
-    rename — resolve whichever this install ships."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    return cls(**kw)
 
 
 def quantize_kv(x: jax.Array, scale_dtype=jnp.float32):
@@ -329,8 +316,6 @@ def paged_attention_int8(
     plus its ancestor-or-self chain (_tree_keep) instead of the linear
     pos < length+j span. KV traffic is unchanged: the tree only edits
     the in-kernel mask."""
-    if pltpu is None:
-        raise RuntimeError("Pallas TPU unavailable; use the reference path")
     if tree is not None:
         assert q_rep == 1 + tree[0] * tree[1], (q_rep, tree)
     if q_rep > 1:
@@ -367,8 +352,8 @@ def paged_attention_int8(
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, KH, G, Hd), qmap),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, KH, G, Hd), qmap),
         scratch_shapes=[
@@ -389,7 +374,7 @@ def paged_attention_int8(
         out_shape=jax.ShapeDtypeStruct((B, KH, G, Hd), jnp.float32),
         # Sequential grid: the prefetch buffer index threads through SMEM
         # from one grid step to the next.
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lengths, page_table.reshape(-1).astype(jnp.int32),

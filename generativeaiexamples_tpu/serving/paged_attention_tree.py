@@ -57,14 +57,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-    _pages_per_block, _tree_keep, compiler_params, paged_attention_int8)
+    _pages_per_block, _tree_keep, paged_attention_int8)
+from generativeaiexamples_tpu.utils.platform import log_kernel_declined
 
 NEG_INF = -1e30
 
@@ -243,9 +240,6 @@ def paged_tree_attention(
     in-kernel-mask replacement for paged_tree_attention_reference
     (which stays the numerics oracle; see module docstring for the
     dispatch rule). Returns [B, H, r, Hd] in q's dtype."""
-    if pltpu is None:
-        raise RuntimeError(
-            "Pallas TPU unavailable; use paged_tree_attention_reference")
     B, H, r, Hd = q.shape
     assert r == 1 + tree[0] * tree[1], (r, tree)
     KH, P, ps, _ = k_pages.shape
@@ -267,8 +261,8 @@ def paged_tree_attention(
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, KH, G, Hd), qmap),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, KH, G, Hd), qmap),
         scratch_shapes=[
@@ -286,7 +280,7 @@ def paged_tree_attention(
         out_shape=jax.ShapeDtypeStruct((B, KH, G, Hd), jnp.float32),
         # Sequential grid: the prefetch buffer index threads through
         # SMEM from one grid step to the next.
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lengths, page_table.reshape(-1).astype(jnp.int32),
@@ -301,19 +295,31 @@ def paged_tree_attention(
 # ---------------------------------------------------------------------------
 
 
-def _kernel_ok(ps: int, Hd: int, use_pallas, mesh) -> bool:
+def _kernel_ok(tree, ps: int, Hd: int, use_pallas, mesh) -> bool:
     """Geometry + backend gate shared by both twins (see module
-    docstring): single-device TPU with Mosaic's 128-lane DMA
-    alignment, unless interpret mode is forced for the parity suite."""
-    if pltpu is None or mesh is not None:
-        return False
-    if os.environ.get("ENGINE_TREE_KERNEL", "1") == "0":
-        return False
-    if _interpret_forced():
+    docstring): the canonical lattice on a single-device TPU with
+    Mosaic's 128-lane DMA alignment, unless interpret mode is forced
+    for the parity suite. A gate that declines on the TPU says so."""
+    on_tpu = bool((jax.default_backend() == "tpu") if use_pallas is None
+                  else use_pallas)
+    why = None
+    if tree is None:
+        why = "anc_mask is not the canonical (k, n_branches) lattice"
+    elif mesh is not None:
+        why = "tensor-parallel mesh; the tree kernels are single-device"
+    elif os.environ.get("ENGINE_TREE_KERNEL", "1") == "0":
+        why = "ENGINE_TREE_KERNEL=0"
+    elif _interpret_forced():
         return True
-    on_tpu = (jax.default_backend() == "tpu") if use_pallas is None \
-        else use_pallas
-    return bool(on_tpu) and ps % 128 == 0 and Hd % 128 == 0
+    elif ps % 128 or Hd % 128:
+        why = (f"page_size {ps} and head_dim {Hd} must both be multiples "
+               f"of 128")
+    if why is None:
+        return on_tpu
+    if on_tpu:
+        log_kernel_declined("paged_tree_attention",
+                            "the XLA gather reference", why)
+    return False
 
 
 # graftlint: hot-path
@@ -327,8 +333,8 @@ def paged_tree_attention_dispatch(
     with tensor parallelism keep the reference route — the linear
     verify kernel has the same single-device scope."""
     tree = tree_shape_of(anc_mask, k, n_branches)
-    if tree is not None and _kernel_ok(
-            k_pages.shape[-2], k_pages.shape[-1], use_pallas, mesh):
+    if _kernel_ok(tree, k_pages.shape[-2], k_pages.shape[-1], use_pallas,
+                  mesh):
         return paged_tree_attention(
             q, k_pages, v_pages, page_table, lengths, tree,
             scale=scale, interpret=_interpret_forced())
@@ -350,8 +356,7 @@ def paged_tree_attention_int8_dispatch(
     gather-then-dequantize reference on the layer slice."""
     B, H, r, Hd = q.shape
     tree = tree_shape_of(anc_mask, k, n_branches)
-    if tree is not None and _kernel_ok(
-            kv_pages.shape[-2], Hd, use_pallas, mesh):
+    if _kernel_ok(tree, kv_pages.shape[-2], Hd, use_pallas, mesh):
         qm = q.transpose(0, 2, 1, 3)  # [B, r, H, Hd]
         out = paged_attention_int8(
             qm, kv_pages, kv_scales, page_table, lengths, layer,

@@ -16,12 +16,14 @@ chips deployment) needs no special casing anywhere else.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from generativeaiexamples_tpu.models.llama import LlamaConfig, param_specs
+from generativeaiexamples_tpu.models.llama import (
+    LlamaConfig, init_params_on_device, param_specs)
 from generativeaiexamples_tpu.ops.quant import QuantizedTensor
 
 # PagePool k/v layout is [L, KH, P, page_size, Hd]; kv-heads live on the
@@ -118,6 +120,16 @@ def shard_llama_params(params, cfg: LlamaConfig, mesh: Mesh, rules=None):
     validate_tp(cfg, mesh)
     shardings = param_shardings(params, cfg, mesh, rules)
     return jax.tree.map(jax.device_put, params, shardings)
+
+
+def init_sharded_params(cfg: LlamaConfig, mesh: Mesh, seed: int = 0, *,
+                        quantize: bool = False, rules=None):
+    """llama.init_params_on_device with every leaf created directly in
+    its TP shards (never whole on the first chip and then moved)."""
+    validate_tp(cfg, mesh)
+    init = functools.partial(init_params_on_device, cfg, quantize=quantize)
+    shardings = param_shardings(jax.eval_shape(init), cfg, mesh, rules)
+    return init(seed, shardings=shardings)
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

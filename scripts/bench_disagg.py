@@ -102,7 +102,7 @@ def main() -> int:
     ecfg = EngineConfig(max_batch_size=4, max_seq_len=512, page_size=PS,
                         prefill_buckets=(64, 128),
                         decode_steps_per_dispatch=4, prefix_cache=True,
-                        pace_emission_max_streams=0, compile_cache_dir="")
+                        pace_emission_max_streams=0)
     tk = ByteTokenizer()
 
     def engine():

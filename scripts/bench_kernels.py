@@ -30,9 +30,8 @@ Usage:
     python scripts/bench_kernels.py --verify [B] [maxp]
     BENCH_KERNELS_ITERS=50 python scripts/bench_kernels.py
 
-Peaks come from a device-kind table (v5e/v4/v5p/v6e) overridable with
-BENCH_PEAK_GBPS / BENCH_PEAK_TFLOPS_BF16 / BENCH_PEAK_TOPS_INT8;
-unknown backends (CPU) report achieved numbers with null utilization.
+Peaks come from a device-kind table (v5e/v4/v5p/v6e); a device that is
+not in it (the CPU included) is an error for the roofline bench.
 """
 
 from __future__ import annotations
@@ -43,10 +42,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from generativeaiexamples_tpu.utils.platform import apply_platform_env  # noqa: E402
-
-apply_platform_env()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -66,20 +61,15 @@ _PEAKS = {
 
 
 def _peaks():
+    """Published peaks of the attached device; a device that is not in
+    the table is an error — a peak is looked up, never assumed."""
     kind = jax.devices()[0].device_kind.lower()
-    gbps = tflops = tops = None
-    for key, (g, t, i8) in _PEAKS.items():
+    for key, (gbps, tflops, tops) in _PEAKS.items():
         if key in kind:
-            gbps, tflops, tops = g, t, i8
-            break
-    env = os.environ
-    if env.get("BENCH_PEAK_GBPS"):
-        gbps = float(env["BENCH_PEAK_GBPS"])
-    if env.get("BENCH_PEAK_TFLOPS_BF16"):
-        tflops = float(env["BENCH_PEAK_TFLOPS_BF16"])
-    if env.get("BENCH_PEAK_TOPS_INT8"):
-        tops = float(env["BENCH_PEAK_TOPS_INT8"])
-    return kind, gbps, tflops, tops
+            return kind, gbps, tflops, tops
+    raise SystemExit(
+        f"bench_kernels: no published peaks for device_kind {kind!r} "
+        f"(known: {sorted(_PEAKS)}); add its spec-sheet row to _PEAKS")
 
 
 def _timeit(fn, iters: int) -> float:
@@ -102,10 +92,8 @@ def _entry(name, secs, bytes_moved, flops, peak_gbps, peak_flops):
         f"kern_{name}_ms": round(secs * 1e3, 4),
         f"kern_{name}_gb_s": round(gb_s, 2),
         f"kern_{name}_gflop_s": round(gf_s, 1),
-        f"kern_{name}_hbm_util": (round(gb_s / peak_gbps, 4)
-                                  if peak_gbps else None),
-        f"kern_{name}_mxu_util": (round(gf_s / 1e3 / peak_flops, 4)
-                                  if peak_flops else None),
+        f"kern_{name}_hbm_util": round(gb_s / peak_gbps, 4),
+        f"kern_{name}_mxu_util": round(gf_s / 1e3 / peak_flops, 4),
     }
 
 

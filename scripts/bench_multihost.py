@@ -51,10 +51,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env  # noqa: E402
-
-apply_platform_env()
-
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
@@ -77,11 +73,10 @@ def _engine_cfg(size: str, features: bool = False):
     if size == "tiny":
         return EngineConfig(max_batch_size=4, max_seq_len=128, page_size=8,
                             prefill_buckets=(16, 32),
-                            pace_emission_max_streams=0,
-                            compile_cache_dir="", auto_pool_pages=auto,
+                            pace_emission_max_streams=0, auto_pool_pages=auto,
                             **extra)
     return EngineConfig(auto_pool_pages=auto, pace_emission_max_streams=0,
-                        compile_cache_dir="", **extra)
+                        **extra)
 
 
 def _measured_hbm() -> int | None:

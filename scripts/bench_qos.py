@@ -56,7 +56,7 @@ def _engine(qos: bool):
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     ecfg = EngineConfig(max_batch_size=4, max_seq_len=512, page_size=8,
                         prefill_buckets=(16,), decode_steps_per_dispatch=4,
-                        pace_emission_max_streams=0, compile_cache_dir="",
+                        pace_emission_max_streams=0,
                         qos=qos)
     return LLMEngine(params, cfg, ByteTokenizer(), ecfg,
                      use_pallas=False).start()

@@ -18,10 +18,6 @@ import dataclasses
 import sys
 import tempfile
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env
-
-apply_platform_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,8 +48,7 @@ def main() -> None:
 
         ecfg = EngineConfig(max_batch_size=2, max_seq_len=128, page_size=128,
                             prefill_buckets=(32,), kv_dtype="bfloat16",
-                            decode_steps_per_dispatch=4,
-                            compile_cache_dir="")
+                            decode_steps_per_dispatch=4)
         eng = LLMEngine(params, cfg, ByteTokenizer(), ecfg).start()
         try:
             got = [ev["token_id"]

@@ -13,10 +13,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env
-
-apply_platform_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

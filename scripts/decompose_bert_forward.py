@@ -25,10 +25,6 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env  # noqa: E402
-
-apply_platform_env()
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402

@@ -19,10 +19,6 @@ import sys
 import time
 import random as pyrandom
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env
-
-apply_platform_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

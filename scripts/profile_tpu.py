@@ -16,10 +16,6 @@ from __future__ import annotations
 import sys
 import time
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env
-
-apply_platform_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -114,11 +110,9 @@ def main():
         from generativeaiexamples_tpu.serving import engine_model
         from generativeaiexamples_tpu.serving.kv_cache import (
             PageAllocator, PagePool, SequencePages)
-        from scripts.bench_params import build_params_on_device
-
         cfg = llama.LlamaConfig.llama3_8b()
         t0 = time.perf_counter()
-        params = build_params_on_device(cfg, quantize=True)
+        params = llama.init_params_on_device(cfg, quantize=True)
         jax.block_until_ready(params["layers"]["wq"].q)
         print(f"params on device in {time.perf_counter()-t0:.1f}s", flush=True)
 

@@ -65,7 +65,7 @@ def build_engine():
             cfg, jax.random.PRNGKey(0))
     ecfg = EngineConfig(max_batch_size=2, max_seq_len=256, page_size=PS,
                         prefill_buckets=(16, 32), prefix_cache=True,
-                        pace_emission_max_streams=0, compile_cache_dir="")
+                        pace_emission_max_streams=0)
     return LLMEngine(params, cfg, ByteTokenizer(), ecfg, use_pallas=False)
 
 

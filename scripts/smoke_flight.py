@@ -44,7 +44,7 @@ def _engine(params, cfg, recorder: bool, batch: int = 2):
 
     ecfg_kw = dict(max_batch_size=batch, max_seq_len=128, page_size=8,
                    prefill_buckets=(16,), decode_steps_per_dispatch=1,
-                   pace_emission_max_streams=0, compile_cache_dir="")
+                   pace_emission_max_streams=0)
     if not recorder:
         ecfg_kw["flight_recorder"] = False
     return LLMEngine(params, cfg, ByteTokenizer(), EngineConfig(**ecfg_kw),

@@ -34,8 +34,7 @@ def run(params, cfg, fused: bool):
 
     ecfg = EngineConfig(max_batch_size=2, max_seq_len=256, page_size=8,
                         prefill_buckets=(16,), decode_steps_per_dispatch=2,
-                        fused_prefill=fused, pace_emission_max_streams=0,
-                        compile_cache_dir="")
+                        fused_prefill=fused, pace_emission_max_streams=0)
     eng = LLMEngine(params, cfg, ByteTokenizer(), ecfg, use_pallas=False)
 
     def step():

@@ -40,7 +40,6 @@ ecfg = EngineConfig(max_batch_size=2, max_seq_len=256, page_size=8,
                     prefill_buckets=(16,), decode_steps_per_dispatch=2,
                     speculative_k=2, speculative_tree_branches=3,
                     step_plans=True, pace_emission_max_streams=0,
-                    compile_cache_dir="",
                     kv_dtype=os.environ.get("SMOKE_KV_DTYPE", "bfloat16"))
 eng = LLMEngine(params, cfg, ByteTokenizer(), ecfg, use_pallas=False)
 eng.start()

@@ -41,8 +41,7 @@ def main() -> int:
                         prefill_buckets=(16,), kv_dtype="float32",
                         decode_steps_per_dispatch=2,
                         prefix_cache=True, prefix_cache_capacity=1.0,
-                        kv_pager=True, kv_host_budget_mb=4,
-                        compile_cache_dir="")
+                        kv_pager=True, kv_host_budget_mb=4)
     # 5 usable pages; every request needs 3 (16-token prompt + 4
     # generated) and caches 2, so the pool ALONE holds 2 sessions'
     # prefixes — the pager must park the rest.

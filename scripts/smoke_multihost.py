@@ -82,7 +82,7 @@ def engine_config(multihost: bool, features: bool = False):
                  prefix_cache=True, kv_pager=True) if features else {}
     return EngineConfig(max_batch_size=2, max_seq_len=128, page_size=PS,
                         prefill_buckets=(16, 32),
-                        pace_emission_max_streams=0, compile_cache_dir="",
+                        pace_emission_max_streams=0,
                         multihost=multihost, auto_pool_pages=True, **extra)
 
 

@@ -41,7 +41,7 @@ def run(params, cfg):
                         prefill_buckets=(16,), decode_steps_per_dispatch=2,
                         speculative_k=2, speculative_tree_branches=3,
                         fused_prefill=True, step_plans=True,
-                        pace_emission_max_streams=0, compile_cache_dir="")
+                        pace_emission_max_streams=0)
     eng = LLMEngine(params, cfg, ByteTokenizer(), ecfg, use_pallas=False)
 
     def step():

@@ -31,8 +31,7 @@ def main() -> int:
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     ecfg = EngineConfig(max_batch_size=4, max_seq_len=64, page_size=8,
                         prefill_buckets=(16, 32), kv_dtype="float32",
-                        decode_steps_per_dispatch=2, prefix_cache=True,
-                        compile_cache_dir="")
+                        decode_steps_per_dispatch=2, prefix_cache=True)
     eng = LLMEngine(params, cfg, ByteTokenizer(), ecfg,
                     use_pallas=False).start()
     try:

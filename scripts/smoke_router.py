@@ -49,7 +49,7 @@ def main() -> int:
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     ecfg = EngineConfig(max_batch_size=2, max_seq_len=256, page_size=8,
                         prefill_buckets=(16, 32), prefix_cache=True,
-                        pace_emission_max_streams=0, compile_cache_dir="")
+                        pace_emission_max_streams=0)
 
     def engine():
         return LLMEngine(params, cfg, ByteTokenizer(), ecfg,

@@ -39,13 +39,7 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env  # noqa: E402
-
-apply_platform_env()
-
 import jax  # noqa: E402
-
-from scripts.bench_params import build_params_on_device  # noqa: E402
 
 
 def measured_overlap(prompt, out):
@@ -129,7 +123,7 @@ def main() -> int:
 
     cfg = llama.LlamaConfig.llama3_8b()
     t0 = time.perf_counter()
-    params = build_params_on_device(cfg, quantize=True)
+    params = llama.init_params_on_device(cfg, quantize=True)
     leaf = params["layers"]["wq"]
     jax.block_until_ready(leaf.q if hasattr(leaf, "q") else leaf)
     print(f"[study] params ready in {time.perf_counter()-t0:.0f}s",

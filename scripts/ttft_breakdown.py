@@ -22,10 +22,6 @@ import statistics
 import sys
 import time
 
-from generativeaiexamples_tpu.utils.platform import apply_platform_env
-
-apply_platform_env()
-
 import jax  # noqa: E402
 
 from generativeaiexamples_tpu.config.schema import EngineConfig  # noqa: E402
@@ -78,11 +74,9 @@ def stage_rows(recorder, rids):
 def main() -> None:
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    from scripts.bench_params import build_params_on_device
-
     n_req = int(sys.argv[1]) if len(sys.argv) > 1 else 9
     cfg = llama.LlamaConfig.llama3_8b()
-    params = build_params_on_device(cfg, quantize=True)
+    params = llama.init_params_on_device(cfg, quantize=True)
     jax.block_until_ready(params["layers"]["wq"].q)
     ecfg = EngineConfig(max_batch_size=128, max_seq_len=384, page_size=128,
                         prefill_buckets=(128,), kv_dtype="int8",

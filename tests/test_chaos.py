@@ -32,7 +32,7 @@ def params():
 def make_engine(params, **over):
     cfg = dict(max_batch_size=2, max_seq_len=256, page_size=PS,
                prefill_buckets=(16, 32), prefix_cache=True,
-               pace_emission_max_streams=0, compile_cache_dir="")
+               pace_emission_max_streams=0)
     cfg.update(over)
     return LLMEngine(params, TINY, ByteTokenizer(), EngineConfig(**cfg),
                      use_pallas=False)

@@ -77,7 +77,7 @@ PROMPT = list(range(5, 25))
 def _engine_tokens(params, cfg, kv_dtype="float32", n=12):
     ecfg = EngineConfig(max_batch_size=2, max_seq_len=64, page_size=8,
                         prefill_buckets=(32,), kv_dtype=kv_dtype,
-                        decode_steps_per_dispatch=4, compile_cache_dir="")
+                        decode_steps_per_dispatch=4)
     eng = LLMEngine(params, cfg, ByteTokenizer(), ecfg).start()
     try:
         return [ev["token_id"]

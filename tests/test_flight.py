@@ -42,7 +42,7 @@ def params():
 def make_engine(params, **over):
     cfg = dict(max_batch_size=2, max_seq_len=128, page_size=8,
                prefill_buckets=(16,), decode_steps_per_dispatch=2,
-               pace_emission_max_streams=0, compile_cache_dir="")
+               pace_emission_max_streams=0)
     cfg.update(over)
     return LLMEngine(params, TINY, ByteTokenizer(), EngineConfig(**cfg),
                      use_pallas=False)

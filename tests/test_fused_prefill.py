@@ -34,7 +34,7 @@ PARAMS = llama.init_params(TINY, jax.random.PRNGKey(3))
 def _engine(**kw):
     base = dict(max_batch_size=2, max_seq_len=256, page_size=8,
                 prefill_buckets=(16,), decode_steps_per_dispatch=8,
-                pace_emission_max_streams=0, compile_cache_dir="")
+                pace_emission_max_streams=0)
     base.update(kw)
     return LLMEngine(PARAMS, TINY, ByteTokenizer(), EngineConfig(**base),
                      use_pallas=False)
@@ -177,8 +177,7 @@ class TestFusedDispatch:
                                      page_size=8, prefill_buckets=(16,),
                                      decode_steps_per_dispatch=4,
                                      speculative_k=2, fused_prefill=True,
-                                     pace_emission_max_streams=0,
-                                     compile_cache_dir=""),
+                                     pace_emission_max_streams=0),
                         use_pallas=False)
         assert eng._fused_width == 0
 

@@ -139,8 +139,7 @@ class TestQuantizedPoolForward:
         params = llama.init_params(TINY, jax.random.PRNGKey(0))
         ecfg = EngineConfig(max_batch_size=4, max_seq_len=64, page_size=8,
                             prefill_buckets=(16,), kv_dtype="int8",
-                            decode_steps_per_dispatch=4,
-                            compile_cache_dir="")
+                            decode_steps_per_dispatch=4)
         eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg).start()
         try:
             outs = []

@@ -277,8 +277,7 @@ def _engine(**kw):
     base = dict(max_batch_size=1, max_seq_len=32, page_size=8,
                 prefill_buckets=(16,), kv_dtype="float32",
                 decode_steps_per_dispatch=2,
-                prefix_cache=True, prefix_cache_capacity=1.0,
-                compile_cache_dir="")
+                prefix_cache=True, prefix_cache_capacity=1.0)
     base.update(kw)
     ecfg = EngineConfig(**base)
     eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg, n_pages=6,
@@ -371,8 +370,7 @@ class TestEngineTiering:
                             prefill_buckets=(16,), kv_dtype="int8",
                             decode_steps_per_dispatch=2,
                             prefix_cache=True, prefix_cache_capacity=1.0,
-                            kv_pager=True, kv_host_budget_mb=4,
-                            compile_cache_dir="")
+                            kv_pager=True, kv_host_budget_mb=4)
         eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg, n_pages=6,
                         use_pallas=False).start()
         try:

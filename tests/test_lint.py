@@ -21,6 +21,10 @@ from generativeaiexamples_tpu.lint.cli import UsageError, resolve_checks
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.join(REPO, "generativeaiexamples_tpu")
 CLI = [sys.executable, "-m", "generativeaiexamples_tpu.lint"]
+# The package is not pip-installed: a CLI run from another directory
+# needs the repository on its path.
+CLI_ENV = dict(os.environ, PYTHONPATH=os.pathsep.join(
+    p for p in (REPO, os.environ.get("PYTHONPATH", "")) if p))
 
 
 def write_tree(root, files):
@@ -1447,7 +1451,8 @@ class TestChangedScope:
         proc = subprocess.run(
             CLI + [os.path.join(root, "pkg"), "--no-baseline",
                    "--changed"],
-            cwd=root, text=True, capture_output=True, timeout=120)
+            cwd=root, env=CLI_ENV, text=True, capture_output=True,
+            timeout=120)
         assert proc.returncode == 1, proc.stdout + proc.stderr
         assert "GL202" in proc.stdout          # changed file reported
         assert "loner.py" not in proc.stdout   # untouched: filtered
@@ -1473,7 +1478,8 @@ class TestChangedScope:
         proc = subprocess.run(
             CLI + [os.path.join(root, "pkg"), "--no-baseline",
                    "--changed"],
-            cwd=root, text=True, capture_output=True, timeout=120)
+            cwd=root, env=CLI_ENV, text=True, capture_output=True,
+            timeout=120)
         # caller.py imported the deleted helper: its GL202 finding is
         # in scope even though caller.py itself is untouched.
         assert proc.returncode == 1, proc.stdout + proc.stderr
@@ -1516,7 +1522,8 @@ class TestChangedScope:
         proc = subprocess.run(
             CLI + [os.path.join(root, "pkg"), "--no-baseline",
                    "--changed"],
-            cwd=root, text=True, capture_output=True, timeout=120)
+            cwd=root, env=CLI_ENV, text=True, capture_output=True,
+            timeout=120)
         assert proc.returncode == 1, proc.stdout + proc.stderr
         assert "GL701" in proc.stdout
         assert "engine.py" in proc.stdout
@@ -1531,7 +1538,8 @@ class TestChangedScope:
         proc = subprocess.run(
             CLI + [os.path.join(root, "pkg"), "--no-baseline",
                    "--changed"],
-            cwd=root, text=True, capture_output=True, timeout=120)
+            cwd=root, env=CLI_ENV, text=True, capture_output=True,
+            timeout=120)
         # loner.py's finding exists but is out of scope: exit 0.
         assert proc.returncode == 0, proc.stdout + proc.stderr
 

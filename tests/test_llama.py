@@ -153,3 +153,64 @@ def test_hf_loader_parses_rope_scaling(tmp_path):
     (tmp_path / "config.json").write_text(_json.dumps(cfg_json))
     with pytest.raises(ValueError, match="rope_scaling"):
         llama_config_from_hf(str(tmp_path))
+
+
+def _same_tree(a, b):
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        assert (x.shape, x.dtype) == (y.shape, y.dtype)
+
+
+@pytest.mark.parametrize("quantize", [False, True])
+def test_init_params_on_device_tree_equals_init_params(quantize):
+    """The seeded on-device generator builds the tree init_params (then
+    quantize_llama_params) builds, at full llama3-8b width — abstractly:
+    nothing 8b-sized is materialised."""
+    import functools
+
+    from generativeaiexamples_tpu.ops.quant import quantize_llama_params
+
+    cfg = llama.LlamaConfig.llama3_8b()
+
+    def reference():
+        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+        return quantize_llama_params(params) if quantize else params
+
+    _same_tree(jax.eval_shape(functools.partial(
+        llama.init_params_on_device, cfg, quantize=quantize)),
+        jax.eval_shape(reference))
+
+
+@pytest.mark.parametrize("quantize", [False, True])
+def test_init_params_on_device_is_keyed_by_its_seed(quantize):
+    a = llama.init_params_on_device(TINY, 3, quantize=quantize)
+    b = llama.init_params_on_device(TINY, 3, quantize=quantize)
+    c = llama.init_params_on_device(TINY, 4, quantize=quantize)
+    same = [bool((x == y).all())
+            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))]
+    assert all(same)
+    assert any(bool((x != y).any())
+               for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(c)))
+    # distinct leaves of one seed are distinct draws
+    wk, wv = a["layers"]["wk"], a["layers"]["wv"]
+    assert bool((getattr(wk, "q", wk) != getattr(wv, "q", wv)).any())
+    logits, _ = llama.forward(a, TINY, jnp.zeros((1, 8), jnp.int32))
+    assert bool(jnp.isfinite(logits).all())
+
+
+def test_init_sharded_params_born_sharded_same_values(eight_devices):
+    """Under a mesh every leaf is created in its TP shards (never whole
+    on one device and moved) and holds the unsharded call's values."""
+    from generativeaiexamples_tpu.config.schema import MeshConfig
+    from generativeaiexamples_tpu.parallel.mesh import build_mesh
+    from generativeaiexamples_tpu.serving import sharding as shd
+
+    mesh = build_mesh(MeshConfig(ici_tensor=2, ici_data=-1))
+    got = shd.init_sharded_params(TINY, mesh, 5, quantize=True)
+    want = llama.init_params_on_device(TINY, 5, quantize=True)
+    _same_tree(got, want)
+    for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool((x == y).all())
+    w_gate = got["layers"]["w_gate"].q
+    assert w_gate.sharding.spec[-1] == "tensor"
+    assert w_gate.addressable_shards[0].data.shape[-1] == TINY.mlp_dim // 2

@@ -90,7 +90,7 @@ def test_engine_pool_sized_from_plan(eight_devices):
     params = _sharded_params(mesh, quantize=False)
     ecfg = EngineConfig(max_batch_size=2, max_seq_len=64, page_size=8,
                         prefill_buckets=(16, 32),
-                        pace_emission_max_streams=0, compile_cache_dir="",
+                        pace_emission_max_streams=0,
                         auto_pool_pages=True)
     eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg, mesh=mesh,
                     use_pallas=False)
@@ -124,7 +124,7 @@ def test_default_sizing_unchanged_without_knob(eight_devices):
     params = _sharded_params(mesh, quantize=False)
     ecfg = EngineConfig(max_batch_size=2, max_seq_len=64, page_size=8,
                         prefill_buckets=(16, 32),
-                        pace_emission_max_streams=0, compile_cache_dir="")
+                        pace_emission_max_streams=0)
     eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg, mesh=mesh,
                     use_pallas=False)
     assert eng.memory_plan is None

@@ -106,8 +106,6 @@ def test_shard_pytree_places_on_mesh(eight_devices):
 
 def test_matmul_with_psum_over_tensor(eight_devices):
     """A hand-rolled TP matmul: contract over the sharded dim with psum."""
-    from generativeaiexamples_tpu.ops.topk import shard_map_compat
-
     m = mesh_lib.build_mesh(MeshConfig())
     x = np.random.default_rng(0).normal(size=(4, 16)).astype(np.float32)
     w = np.random.default_rng(1).normal(size=(16, 8)).astype(np.float32)
@@ -115,7 +113,7 @@ def test_matmul_with_psum_over_tensor(eight_devices):
     def local(x, w):
         return jax.lax.psum(x @ w, "tensor")
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local, mesh=m, in_specs=(P(None, "tensor"), P("tensor", None)),
         out_specs=P(),
     )

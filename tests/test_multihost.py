@@ -343,7 +343,7 @@ def _tiny_engine(params, cfg, **overrides):
 
     ecfg = EngineConfig(max_batch_size=2, max_seq_len=64, page_size=8,
                         prefill_buckets=(16,),
-                        pace_emission_max_streams=0, compile_cache_dir="",
+                        pace_emission_max_streams=0,
                         **overrides)
     return LLMEngine(params, cfg, ByteTokenizer(), ecfg,
                      use_pallas=False)

@@ -17,17 +17,6 @@ from generativeaiexamples_tpu.training import trainer
 
 TINY = llama.LlamaConfig.tiny()
 
-# pipeline_loss partitions stages with the new-API
-# `jax.shard_map(axis_names=...)`; the pre-0.5 experimental shard_map
-# has no spelling that actually partitions over only the pipeline axis
-# (CHANGES PR 2 rider), so on old jax these two tests cannot run — gate
-# them explicitly instead of letting them fail red.
-requires_new_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="needs new-API jax.shard_map(axis_names=...); the old "
-           "experimental shard_map cannot express the GPipe stage "
-           "partitioning on this jax version")
-
 
 @pytest.fixture(scope="module")
 def pp_mesh(eight_devices):
@@ -38,7 +27,6 @@ def pp_mesh(eight_devices):
 
 
 class TestPipelineLoss:
-    @requires_new_shard_map
     def test_matches_unpipelined_loss_and_grads(self, pp_mesh):
         params = llama.init_params(TINY, jax.random.PRNGKey(0))
         batch = trainer.synthetic_batch(TINY, batch=8, seq=16)
@@ -99,7 +87,6 @@ class TestPipelineLoss:
 
 
 class TestPipelineTrainStep:
-    @requires_new_shard_map
     def test_full_step_updates_params(self, pp_mesh):
         params = llama.init_params(TINY, jax.random.PRNGKey(0))
         tcfg = trainer.TrainConfig(learning_rate=1e-3, warmup_steps=1,
@@ -134,5 +121,5 @@ class TestServingRejectsPipeline:
         with pytest.raises(ValueError, match="pipeline"):
             LLMEngine(params, cfg, ByteTokenizer(),
                       EngineConfig(max_batch_size=2, max_seq_len=64,
-                                   page_size=32, compile_cache_dir=""),
+                                   page_size=32),
                       mesh=pp_mesh)

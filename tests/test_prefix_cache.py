@@ -313,8 +313,7 @@ def _engine(**kw):
     # comparisons cannot flake on cast tie-breaks.
     ecfg = EngineConfig(max_batch_size=4, max_seq_len=64, page_size=8,
                         prefill_buckets=(16, 32), kv_dtype="float32",
-                        decode_steps_per_dispatch=2,
-                        compile_cache_dir="", **kw)
+                        decode_steps_per_dispatch=2, **kw)
     eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg, use_pallas=False)
     return params, eng
 
@@ -415,8 +414,7 @@ class TestEnginePrefixReuse:
         ecfg = EngineConfig(max_batch_size=1, max_seq_len=32, page_size=8,
                             prefill_buckets=(16,), kv_dtype="float32",
                             decode_steps_per_dispatch=2,
-                            prefix_cache=True, prefix_cache_capacity=1.0,
-                            compile_cache_dir="")
+                            prefix_cache=True, prefix_cache_capacity=1.0)
         # 5 usable pages; every request needs 3 (16-token prompt + 4
         # generated), so serving a second distinct prompt forces
         # eviction of the first one's cached pages.
@@ -486,8 +484,6 @@ class TestEnginePrefixReuse:
             from generativeaiexamples_tpu.serving.engine import LLMEngine
             from generativeaiexamples_tpu.config.schema import EngineConfig
             from generativeaiexamples_tpu.utils.tokenizer import ByteTokenizer
-            from generativeaiexamples_tpu.utils import platform as plat
-            plat._COMPILE_CACHE_SET = True  # no persistent-cache hits
 
             TINY = llama.LlamaConfig.tiny()
             params = llama.init_params(TINY, jax.random.PRNGKey(0))
@@ -495,7 +491,7 @@ class TestEnginePrefixReuse:
                                 page_size=8, prefill_buckets=(16, 32),
                                 kv_dtype="float32",
                                 decode_steps_per_dispatch=2,
-                                prefix_cache=True, compile_cache_dir="")
+                                prefix_cache=True)
             eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                             use_pallas=False)
             eng.warmup()

@@ -615,8 +615,7 @@ class TestEmissionPacing:
         params = llama.init_params(TINY, jax.random.PRNGKey(0))
         ecfg = EngineConfig(max_batch_size=4, max_seq_len=64, page_size=8,
                             prefill_buckets=(16,),
-                            decode_steps_per_dispatch=8,
-                            compile_cache_dir="", **kw)
+                            decode_steps_per_dispatch=8, **kw)
         return LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                          use_pallas=False)
 
@@ -740,8 +739,7 @@ class TestPrefillPriorityLane:
         params = llama.init_params(TINY, jax.random.PRNGKey(3))
         ecfg = EngineConfig(max_batch_size=2, max_seq_len=256, page_size=8,
                             prefill_buckets=(16,),
-                            decode_steps_per_dispatch=8,
-                            compile_cache_dir="")
+                            decode_steps_per_dispatch=8)
         eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                         use_pallas=False).start()
         try:
@@ -823,8 +821,7 @@ class TestChunkedPrefill:
         params = llama.init_params(TINY, jax.random.PRNGKey(3))
         ecfg = EngineConfig(max_batch_size=2, max_seq_len=96, page_size=8,
                             prefill_buckets=(16,),
-                            decode_steps_per_dispatch=2,
-                            compile_cache_dir="")
+                            decode_steps_per_dispatch=2)
         eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                         use_pallas=False).start()
         try:
@@ -883,8 +880,7 @@ class TestChunkedPrefill:
         params = llama.init_params(TINY, jax.random.PRNGKey(3))
         ecfg = EngineConfig(max_batch_size=2, max_seq_len=256, page_size=8,
                             prefill_buckets=(16,),
-                            decode_steps_per_dispatch=2,
-                            compile_cache_dir="")
+                            decode_steps_per_dispatch=2)
         eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                         use_pallas=False).start()
         try:
@@ -947,8 +943,7 @@ class TestChunkedPrefill:
         params = llama.init_params(TINY, jax.random.PRNGKey(3))
         ecfg = EngineConfig(max_batch_size=2, max_seq_len=96, page_size=8,
                             prefill_buckets=(16,),
-                            decode_steps_per_dispatch=2,
-                            compile_cache_dir="")
+                            decode_steps_per_dispatch=2)
         eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                         use_pallas=False).start()
         try:
@@ -1004,15 +999,12 @@ class TestChunkedPrefill:
             from generativeaiexamples_tpu.serving.engine import LLMEngine
             from generativeaiexamples_tpu.config.schema import EngineConfig
             from generativeaiexamples_tpu.utils.tokenizer import ByteTokenizer
-            from generativeaiexamples_tpu.utils import platform as plat
-            plat._COMPILE_CACHE_SET = True  # no persistent-cache hits
 
             TINY = llama.LlamaConfig.tiny()
             params = llama.init_params(TINY, jax.random.PRNGKey(3))
             ecfg = EngineConfig(max_batch_size=2, max_seq_len=96,
                                 page_size=8, prefill_buckets=(16,),
-                                decode_steps_per_dispatch=2,
-                                compile_cache_dir="")
+                                decode_steps_per_dispatch=2)
             eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                             use_pallas=False)
             eng.warmup(long_prompts=True)
@@ -1051,7 +1043,7 @@ class TestChunkedPrefill:
     def test_overlong_prompt_rejected_at_page_capacity(self):
         params = llama.init_params(TINY, jax.random.PRNGKey(0))
         ecfg = EngineConfig(max_batch_size=2, max_seq_len=32, page_size=8,
-                            prefill_buckets=(16,), compile_cache_dir="")
+                            prefill_buckets=(16,))
         eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                         use_pallas=False)
         import pytest
@@ -1080,8 +1072,7 @@ class TestPrefillGroupCap:
         params = llama.init_params(TINY, jax.random.PRNGKey(0))
         ecfg = EngineConfig(max_batch_size=8, max_seq_len=64, page_size=8,
                             prefill_buckets=(16,), max_prefill_group=2,
-                            decode_steps_per_dispatch=2,
-                            compile_cache_dir="")
+                            decode_steps_per_dispatch=2)
         eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                         use_pallas=False).start()
         try:

@@ -43,7 +43,7 @@ def tp_cfg(n_kv_heads=8):
 # (tests/test_serving.py::TestEmissionPacing).
 ECFG = EngineConfig(max_batch_size=4, max_seq_len=128, page_size=32,
                     prefill_buckets=(32, 64), decode_steps_per_dispatch=4,
-                    pipeline_depth=2, compile_cache_dir="",
+                    pipeline_depth=2,
                     pace_emission_max_streams=0)
 
 

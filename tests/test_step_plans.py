@@ -30,7 +30,7 @@ PARAMS = llama.init_params(TINY, jax.random.PRNGKey(3))
 def _engine(**kw):
     base = dict(max_batch_size=2, max_seq_len=256, page_size=8,
                 prefill_buckets=(16,), decode_steps_per_dispatch=2,
-                pace_emission_max_streams=0, compile_cache_dir="")
+                pace_emission_max_streams=0)
     base.update(kw)
     return LLMEngine(PARAMS, TINY, ByteTokenizer(), EngineConfig(**base),
                      use_pallas=False)

@@ -249,8 +249,7 @@ class TestFusedSampling:
                                 page_size=8, prefill_buckets=(16,),
                                 decode_steps_per_dispatch=2,
                                 pace_emission_max_streams=0,
-                                fused_sampling=fused,
-                                compile_cache_dir="")
+                                fused_sampling=fused)
             eng = LLMEngine(params, TINY, ByteTokenizer(), ecfg,
                             use_pallas=False).start()
             try:

@@ -6,6 +6,7 @@ hybrid retrieval, and the compile cache existed but had no call sites).
 import asyncio
 import io
 import json
+import os
 import time
 
 import pytest
@@ -17,6 +18,8 @@ from generativeaiexamples_tpu.connectors.fakes import (
     EchoLLM, HashEmbedder, OverlapReranker)
 from generativeaiexamples_tpu.pipelines.base import get_example_class
 from generativeaiexamples_tpu.pipelines.resources import Resources
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _server(cfg, reranker=None, tmp_path=None):
@@ -176,7 +179,7 @@ def test_engine_emits_generation_spans():
         tiny = llama.LlamaConfig.tiny()
         params = llama.init_params(tiny, jax.random.PRNGKey(0))
         ecfg = EngineConfig(max_batch_size=2, max_seq_len=64, page_size=8,
-                            prefill_buckets=(16,), compile_cache_dir="")
+                            prefill_buckets=(16,))
         eng = LLMEngine(params, tiny, ByteTokenizer(), ecfg,
                         use_pallas=False).start()
         try:
@@ -207,16 +210,135 @@ def test_span_system_metrics_snapshot():
     assert any(k.startswith("system.cpu") for k in m)
 
 
-def test_compile_cache_configured(tmp_path):
+@pytest.fixture()
+def cache_config():
+    """Hand jax.config's cache settings back as they were (conftest
+    keeps the persistent cache off for the suite)."""
+    import jax
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    was = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in was.items():
+        jax.config.update(n, v)
+
+
+def test_compile_cache_env_variable_wins(tmp_path, monkeypatch, cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: the directory is the operator's —
+    JAX reads the variable itself and NO directory is set in code."""
     import jax
 
     from generativeaiexamples_tpu.utils import platform as plat
 
-    # module-global latch: reset for a hermetic check
-    plat._COMPILE_CACHE_SET = False
-    assert plat.setup_compile_cache(str(tmp_path / "cc"))
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "cc")
-    assert not plat.setup_compile_cache("")  # empty dir -> disabled
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    before = jax.config.jax_compilation_cache_dir
+    assert plat.setup_compile_cache() == str(tmp_path / "cc")
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "cc").exists()  # nor created here
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.5
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+
+
+def test_compile_cache_default_is_fixed_inside_checkout(monkeypatch,
+                                                        cache_config):
+    """Unset: one fixed path inside the checkout — never /tmp, a
+    mkdtemp, a pid or a time (the path is part of the cache key)."""
+    import jax
+
+    from generativeaiexamples_tpu.utils import platform as plat
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert plat.DEFAULT_COMPILE_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+    assert plat.setup_compile_cache() == plat.DEFAULT_COMPILE_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == \
+        plat.DEFAULT_COMPILE_CACHE_DIR
+    assert plat.setup_compile_cache() == plat.DEFAULT_COMPILE_CACHE_DIR
+    assert os.path.isdir(plat.DEFAULT_COMPILE_CACHE_DIR)
+
+
+def test_compile_cache_setup_failure_raises(tmp_path, monkeypatch,
+                                            cache_config):
+    """A cache that cannot be set up is an error, not a silent `False`."""
+    from generativeaiexamples_tpu.utils import platform as plat
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    blocker = tmp_path / "file"
+    blocker.write_text("not a directory")
+    monkeypatch.setattr(plat, "DEFAULT_COMPILE_CACHE_DIR",
+                        str(blocker / "cache"))
+    with pytest.raises(OSError):
+        plat.setup_compile_cache()
+
+
+def test_chain_server_health_initialises_no_backend():
+    """Remote connectors + a host-side store: the chain server answers
+    /health (and boots) without initialising a JAX backend — the chip
+    belongs to the engine server's process. JAX_PLATFORMS names a
+    platform that does not exist, so any backend initialisation raises
+    (the positive control shows it would)."""
+    import subprocess
+    import sys
+    import textwrap
+
+    script = textwrap.dedent("""
+        import asyncio
+        from aiohttp.test_utils import TestClient, TestServer
+        from generativeaiexamples_tpu.api.server import ChainServer
+        from generativeaiexamples_tpu.config.wizard import load_config
+
+        async def main():
+            server = ChainServer(load_config(None))
+            async with TestClient(TestServer(server.app)) as client:
+                resp = await client.get("/health")
+                assert resp.status == 200, await resp.text()
+                print(await resp.text())
+
+        asyncio.run(main())
+        import jax
+        try:
+            jax.devices()
+        except RuntimeError as e:
+            print("CONTROL", type(e).__name__)
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="no_such_platform", PYTHONPATH=REPO,
+               APP_LLM_MODELENGINE="openai",
+               APP_LLM_SERVERURL="http://127.0.0.1:9/v1",
+               APP_EMBEDDINGS_MODELENGINE="openai",
+               APP_EMBEDDINGS_SERVERURL="http://127.0.0.1:9/v1",
+               APP_VECTORSTORE_NAME="memory")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "Service is up." in proc.stdout
+    assert "CONTROL RuntimeError" in proc.stdout, proc.stdout
+
+
+def test_uses_local_device_follows_the_connectors():
+    import dataclasses
+
+    from generativeaiexamples_tpu.config.schema import AppConfig
+    from generativeaiexamples_tpu.connectors.factory import uses_local_device
+
+    cfg = AppConfig()  # defaults: in-process tpu engines
+    assert uses_local_device(cfg)
+    remote = dataclasses.replace(
+        cfg,
+        llm=dataclasses.replace(cfg.llm, model_engine="openai",
+                                server_url="http://e:8000/v1"),
+        embeddings=dataclasses.replace(cfg.embeddings, model_engine="tpu",
+                                       server_url="http://e:8000/v1"))
+    assert not uses_local_device(remote)
+    assert uses_local_device(dataclasses.replace(
+        remote, vector_store=dataclasses.replace(remote.vector_store,
+                                                 name="tpu")))
+    assert uses_local_device(dataclasses.replace(
+        remote, reranker=dataclasses.replace(remote.reranker, enabled=True)))
+    fakes = dataclasses.replace(
+        cfg, llm=dataclasses.replace(cfg.llm, model_engine="echo"),
+        embeddings=dataclasses.replace(cfg.embeddings, model_engine="hash"))
+    assert not uses_local_device(fakes)
 
 
 def test_tokens_per_sec_is_sliding_window():
