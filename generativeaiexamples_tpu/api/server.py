@@ -29,6 +29,7 @@ import json
 import logging
 import os
 import re
+import time
 import uuid
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
@@ -37,6 +38,7 @@ from aiohttp import web
 
 from generativeaiexamples_tpu.config.schema import AppConfig
 from generativeaiexamples_tpu.obs import tracing
+from generativeaiexamples_tpu.serving.flight import ExpHistogram
 
 _LOG = logging.getLogger(__name__)
 
@@ -90,6 +92,10 @@ class ChainServer:
             workers = max(workers, 2 * config.serving.microbatch_max_batch)
         self._executor = ThreadPoolExecutor(max_workers=workers,
                                             thread_name_prefix="chain-srv")
+        # One histogram per stage of a /generate request's timeline
+        # (obs/tracing.py::STAGES), observed by the loop thread only,
+        # after a request's last frame.
+        self._stage_hists = {st: ExpHistogram() for st in tracing.STAGES}
         self.app = web.Application(client_max_size=100 * 1024 * 1024)
         self.app.add_routes([
             web.get("/health", self.handle_health),
@@ -125,9 +131,13 @@ class ChainServer:
         index_rebuilds when the IVF index is live) plus the
         cross-request micro-batcher counters per stage (embed / rerank /
         search: mean coalesced batch size, queue-wait p50/p99,
-        dispatches saved — serving/batcher.py). The serving engine's
-        token metrics live on ITS /metrics (serving/openai_server.py)."""
-        payload: Dict[str, Any] = {}
+        dispatches saved — serving/batcher.py), and one histogram per
+        stage of /generate up to its first frame (`hist_chain_<stage>_ms`,
+        obs/tracing.py::STAGES). The serving engine's token metrics live
+        on ITS /metrics (serving/openai_server.py)."""
+        payload: Dict[str, Any] = {
+            f"hist_chain_{st}_ms": h.snapshot()
+            for st, h in self._stage_hists.items()}
         res = getattr(self.example, "res", None)
         for key in ("store", "conv_store"):
             store = getattr(res, key, None)
@@ -142,6 +152,7 @@ class ChainServer:
     # -- /generate ---------------------------------------------------------
 
     async def handle_generate(self, request: web.Request) -> web.StreamResponse:
+        received = time.monotonic()  # the request's timeline starts here
         try:
             body = await request.json()
         except json.JSONDecodeError:
@@ -172,6 +183,7 @@ class ChainServer:
             "stop": [sanitize(s) for s in (body.get("stop") or [])],
         }
         rid = str(uuid.uuid4())
+        timeline = tracing.Timeline(rid, received)
         # W3C traceparent from the caller (reference common/tracing.py:62-73)
         trace_ctx = tracing.extract_context(dict(request.headers))
 
@@ -189,9 +201,12 @@ class ChainServer:
         gspan.sp.set_attribute("request_id", rid)
 
         def run_chain():
-            # The chain runs in an executor thread: re-attach the caller's
-            # trace context so retriever/engine spans parent correctly.
-            tok = tracing.attach_context(trace_ctx)
+            # The chain runs in an executor thread: make `generate` the
+            # parent of the stages' spans there, and the timeline the
+            # one they stamp.
+            tok = tracing.attach_context(gspan.sp.context())
+            tracing.attach_timeline(timeline)
+            timeline.mark("dispatch")
             try:
                 gen = (self.example.rag_chain(query, chat_history, **llm_settings)
                        if use_kb else
@@ -200,11 +215,13 @@ class ChainServer:
                     loop.call_soon_threadsafe(q.put_nowait, piece)
             except Exception as e:  # error SSE parity (server.py:314-342)
                 _LOG.exception("chain failed")
+                timeline.ok = False
                 loop.call_soon_threadsafe(
                     q.put_nowait,
                     "Error from chain server. Please check chain-server logs "
                     f"for more details. ({type(e).__name__})")
             finally:
+                tracing.attach_timeline(None)
                 tracing.detach_context(tok)
                 loop.call_soon_threadsafe(q.put_nowait, DONE)
 
@@ -217,16 +234,27 @@ class ChainServer:
                 gspan.on_token()
                 frame = json.dumps(_chain_response(rid, piece))
                 await resp.write(f"data: {frame}\n\n".encode())
+                if gspan.tokens == 1:
+                    timeline.mark("emit")  # the first frame is written
             # sentinel frame (reference server.py:302-307)
             final = json.dumps(_chain_response(rid, "", "[DONE]"))
             await resp.write(f"data: {final}\n\n".encode())
             await resp.write_eof()
         except (ConnectionResetError, asyncio.CancelledError):
             _LOG.info("client disconnected from /generate")
+            timeline.ok = False
             raise
         finally:
-            await asyncio.shield(fut)
-            gspan.__exit__(None, None, None)
+            try:
+                await asyncio.shield(fut)
+            finally:
+                gspan.__exit__(None, None, None)
+                # After the last frame, never before: the stage
+                # histograms and the request's one `gaie.timeline` line.
+                for st, ms in timeline.durations_ms().items():
+                    if st in self._stage_hists:
+                        self._stage_hists[st].observe(ms)
+                timeline.close()
         return resp
 
     # -- /documents --------------------------------------------------------

@@ -17,6 +17,7 @@ import numpy as np
 import requests
 
 from generativeaiexamples_tpu.connectors.base import ChatBase, Message
+from generativeaiexamples_tpu.obs import tracing
 
 _LOG = logging.getLogger(__name__)
 
@@ -33,9 +34,7 @@ class OpenAIChatLLM(ChatBase):
 
     def stream_chat(self, messages: Sequence[Message], *, temperature=0.2,
                     top_p=0.7, max_tokens=1024, stop=()) -> Iterator[str]:
-        from generativeaiexamples_tpu.obs.tracing import traced_llm_stream
-
-        yield from traced_llm_stream(
+        yield from tracing.traced_llm_stream(
             "llm.openai", self._stream(messages, temperature, top_p,
                                        max_tokens, stop),
             {"model": self.model, "max_tokens": max_tokens})
@@ -49,10 +48,25 @@ class OpenAIChatLLM(ChatBase):
         }
         if stop:
             body["stop"] = list(stop)
-        r = self.session.post(f"{self.base_url}/chat/completions", json=body,
-                              stream=True, timeout=self.timeout)
-        r.raise_for_status()
-        for line in r.iter_lines():
+        # The caller's request id and trace context ride the hop (taken
+        # before the stage's span opens: the engine's span is a child of
+        # `generate`, beside this stage, not under it).
+        headers = tracing.outgoing_headers()
+        with tracing.span("llm_first_piece"):
+            r = self.session.post(f"{self.base_url}/chat/completions",
+                                  json=body, headers=headers, stream=True,
+                                  timeout=self.timeout)
+            r.raise_for_status()
+            pieces = self._pieces(r.iter_lines())
+            first = next(pieces, None)
+        if first is not None:
+            yield first
+            yield from pieces
+
+    @staticmethod
+    def _pieces(lines) -> Iterator[str]:
+        """The non-empty content pieces of an SSE body, to `[DONE]`."""
+        for line in lines:
             if not line:
                 continue
             line = line.decode() if isinstance(line, bytes) else line
@@ -89,8 +103,12 @@ class OpenAIEmbedder:
             body = {"model": self.model, "input": list(texts[i:i + self.batch]),
                     "input_type": input_type}
             r = self.session.post(f"{self.base_url}/embeddings", json=body,
+                                  headers=tracing.outgoing_headers(),
                                   timeout=self.timeout)
             r.raise_for_status()
+            # The encoder's own times (tokenize, queue, ready, total)
+            # onto the stage that made this call.
+            tracing.note_server_timing(r.headers.get("Server-Timing"))
             data = sorted(r.json()["data"], key=lambda d: d["index"])
             out.extend(d["embedding"] for d in data)
         return np.asarray(out, np.float32)
@@ -121,8 +139,10 @@ class OpenAIReranker:
         body = {"model": self.model, "query": {"text": query},
                 "passages": [{"text": p} for p in passages]}
         r = self.session.post(f"{self.base_url}/ranking", json=body,
+                              headers=tracing.outgoing_headers(),
                               timeout=self.timeout)
         r.raise_for_status()
+        tracing.note_server_timing(r.headers.get("Server-Timing"))
         out = np.zeros((len(passages),), np.float32)
         for rk in r.json()["rankings"]:
             out[rk["index"]] = rk["logit"]

@@ -188,9 +188,12 @@ def forward(
 
     resolved_pallas = attn_ops.on_tpu() if use_pallas is None else use_pallas
 
+    # The named scopes below are metadata only: they name the weight
+    # matmuls in a profile's op metadata and change no compiled program.
     def body(x, w):
         h = attn_in = x
-        qkv = (h @ w["wqkv"] + w["bqkv"]).reshape(B, S, 3, H, Hd)
+        with jax.named_scope("attn.qkv"):
+            qkv = (h @ w["wqkv"] + w["bqkv"]).reshape(B, S, 3, H, Hd)
         q, k, v = (qkv[:, :, i].transpose(0, 2, 1, 3) for i in range(3))
         if resolved_pallas and S <= 512:
             # Dedicated encoder kernel: ONE grid step per batch row
@@ -208,11 +211,14 @@ def forward(
                                      block_q=min(S, 512),
                                      block_k=min(S, 512))
         out = out.transpose(0, 2, 1, 3).reshape(B, S, H * Hd)
-        x = layer_norm(attn_in + out @ w["wo"] + w["bo"],
-                       w["ln1_w"], w["ln1_b"], cfg.ln_eps)
-        h = jax.nn.gelu(x @ w["w_in"] + w["b_in"], approximate=False)
-        x = layer_norm(x + h @ w["w_out"] + w["b_out"],
-                       w["ln2_w"], w["ln2_b"], cfg.ln_eps)
+        with jax.named_scope("attn.out"):
+            attn = attn_in + out @ w["wo"] + w["bo"]
+        x = layer_norm(attn, w["ln1_w"], w["ln1_b"], cfg.ln_eps)
+        with jax.named_scope("mlp.in"):
+            h = jax.nn.gelu(x @ w["w_in"] + w["b_in"], approximate=False)
+        with jax.named_scope("mlp.out"):
+            mlp = x + h @ w["w_out"] + w["b_out"]
+        x = layer_norm(mlp, w["ln2_w"], w["ln2_b"], cfg.ln_eps)
         return x, None
 
     xs = {"wqkv": wqkv, "bqkv": bqkv,

@@ -305,10 +305,15 @@ def _layer(cfg: LlamaConfig, x, ln1, ln2, wq, wk, wv, wo, w_gate, w_up, w_down,
     B, S, D = x.shape
     H, KH, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
+    # The named scopes around the weight matmuls (here, in forward() and
+    # in serving/engine_model.py's copies of this block) are metadata
+    # only: they name the matmuls in a profile's op metadata and change
+    # no compiled program and no compile-cache key.
     h = rms_norm(x, ln1, cfg.rms_eps)
-    q = mm(h, wq).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
-    k = mm(h, wk).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
-    v = mm(h, wv).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+    with jax.named_scope("attn.qkv"):
+        q = mm(h, wq).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
+        k = mm(h, wk).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+        v = mm(h, wv).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
     q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
     k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
@@ -329,9 +334,13 @@ def _layer(cfg: LlamaConfig, x, ln1, ln2, wq, wk, wv, wo, w_gate, w_up, w_down,
         new_kv = (kc, vc)
 
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * Hd)
-    x = x + mm(out, wo)
+    with jax.named_scope("attn.out"):
+        x = x + mm(out, wo)
     h = rms_norm(x, ln2, cfg.rms_eps)
-    x = x + mm(jax.nn.silu(mm(h, w_gate)) * mm(h, w_up), w_down)
+    with jax.named_scope("mlp.gate_up"):
+        h = jax.nn.silu(mm(h, w_gate)) * mm(h, w_up)
+    with jax.named_scope("mlp.down"):
+        x = x + mm(h, w_down)
     return x, new_kv
 
 
@@ -388,10 +397,12 @@ def forward(
         x, kv_out = jax.lax.scan(body, x, (weights, None))
 
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)
-    if cfg.tie_embeddings:
-        logits = (x @ params["tok_emb"].T.astype(x.dtype)).astype(jnp.float32)
-    else:
-        logits = mm(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = (x @ params["tok_emb"].T.astype(x.dtype)
+                      ).astype(jnp.float32)
+        else:
+            logits = mm(x, params["lm_head"]).astype(jnp.float32)
 
     new_cache = None
     if kv_cache is not None:

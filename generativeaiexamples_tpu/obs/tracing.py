@@ -12,11 +12,20 @@ minimal tracer with the same span/propagation semantics (spans with
 attributes + events, parent/child via W3C traceparent, pluggable
 exporter with `.export([spans])`). The built-in path keeps tracing real
 in environments that ship only the otel namespace shim (this image).
+
+Beside the spans, and ALWAYS on: the request `Timeline`. The chain
+server creates one per /generate request and attaches it to the thread
+that runs the chain; every outermost `span(name)` on that thread then
+stamps (name, start, end) on it, on `time.monotonic()`, whether or not
+tracing is enabled. A stage starts where the previous one ended, so the
+stages tile the request with no holes. One JSON line per request goes to
+the logger `gaie.timeline` after the last frame (docs/observability.md).
 """
 
 from __future__ import annotations
 
 import contextlib
+import json
 import logging
 import os
 import random
@@ -30,7 +39,8 @@ _TRACER = None
 _ENABLED = False
 _PROVIDER = None
 _BACKEND = None  # "otel" | "mini"
-_TLS = threading.local()  # mini-backend attached context
+_TLS = threading.local()  # mini-backend attached context; the Timeline
+_TIMELINE_LOG = logging.getLogger("gaie.timeline")
 
 # Export/attribute failure accounting (the trainer idiom: logged once,
 # counted always — a sick exporter must show up in /metrics, not
@@ -103,7 +113,7 @@ class _MiniEvent:
     def __init__(self, name: str, attributes: Dict):
         self.name = name
         self.attributes = dict(attributes)
-        self.timestamp = time.time()
+        self.timestamp = time.monotonic()
 
 
 class _MiniSpan:
@@ -114,7 +124,11 @@ class _MiniSpan:
         self.parent = parent
         self.attributes: Dict = {}
         self.events: List[_MiniEvent] = []
-        self.start_time = time.time()
+        # Start, end and event stamps are time.monotonic() (a wall clock
+        # can step under a span); the ONE wall-clock stamp places the
+        # span in calendar time for an exporter.
+        self.start_time = time.monotonic()
+        self.start_wall = time.time()
         self.end_time: Optional[float] = None
         self._exporters = exporters
 
@@ -127,7 +141,7 @@ class _MiniSpan:
     def end(self) -> None:
         if self.end_time is not None:
             return
-        self.end_time = time.time()
+        self.end_time = time.monotonic()
         for ex in self._exporters:
             try:
                 ex.export([self])
@@ -201,7 +215,7 @@ class LogExporter:
             _LOG.info(
                 "span name=%s trace=%032x dur_ms=%.1f attrs=%s events=%s",
                 s.name, s.context.trace_id,
-                ((s.end_time or time.time()) - s.start_time) * 1e3,
+                ((s.end_time or time.monotonic()) - s.start_time) * 1e3,
                 s.attributes, [e.name for e in s.events])
 
 
@@ -367,14 +381,26 @@ def detach_context(token) -> None:
 @contextlib.contextmanager
 def span(name: str, attributes: Optional[Dict] = None,
          context=None) -> Iterator:
-    """Span context manager that degrades to a timing log span."""
-    if _ENABLED and _TRACER is not None:
-        with _TRACER.start_as_current_span(name, context=context) as sp:
-            for k, v in (attributes or {}).items():
-                sp.set_attribute(k, v)
-            yield sp
-    else:
-        yield _NullSpan()
+    """The one call a stage makes. Always: the outermost span on a thread
+    that has a request Timeline attached stamps (name, start, end) on it
+    when it ends (an exception ends it too). When tracing is enabled it
+    is also an OTel/mini span, current for the block."""
+    tl = getattr(_TLS, "timeline", None)
+    if tl is not None:
+        tl.depth += 1
+    try:
+        if _ENABLED and _TRACER is not None:
+            with _TRACER.start_as_current_span(name, context=context) as sp:
+                for k, v in (attributes or {}).items():
+                    sp.set_attribute(k, v)
+                yield sp
+        else:
+            yield _NULL_SPAN
+    finally:
+        if tl is not None:
+            tl.depth -= 1
+            if tl.depth == 0:
+                tl.mark(name)
 
 
 class _NullSpan:
@@ -385,37 +411,125 @@ class _NullSpan:
         pass
 
 
-def get_system_metrics() -> Dict[str, float]:
-    """Host CPU/memory snapshot attached to every span at end — parity
-    with the reference's psutil block
-    (tools/observability/langchain/opentelemetry_callback.py:65-102).
-    psutil when available; a resource-module fallback keeps a stable
-    subset of the attribute set otherwise."""
-    try:
-        import psutil
+_NULL_SPAN = _NullSpan()
 
-        proc = psutil.Process()
-        with proc.oneshot():
-            mem = proc.memory_info()
-            return {
-                "system.cpu_percent": psutil.cpu_percent(interval=None),
-                "system.process_cpu_percent": proc.cpu_percent(interval=None),
-                "system.memory_rss_mb": round(mem.rss / 1e6, 1),
-                "system.memory_vms_mb": round(mem.vms / 1e6, 1),
-                "system.memory_percent": psutil.virtual_memory().percent,
-            }
-    except Exception:
-        try:
-            import resource
 
-            ru = resource.getrusage(resource.RUSAGE_SELF)
-            return {  # ru_maxrss is KiB on Linux
-                "system.memory_rss_mb": round(ru.ru_maxrss / 1e3, 1),
-                "system.cpu_user_s": round(ru.ru_utime, 3),
-                "system.cpu_sys_s": round(ru.ru_stime, 3),
-            }
-        except Exception:
-            return {}
+# ---------------------------------------------------------------------------
+# The request timeline (always on)
+# ---------------------------------------------------------------------------
+
+# The stages of a /generate request up to its first frame, in order
+# (api/server.py opens `dispatch` and closes `emit`; the others are
+# spans of the retriever, the pipeline and the LLM connector). The
+# chain server keeps one histogram for each.
+STAGES = ("dispatch", "embed", "search", "assemble", "llm_first_piece",
+          "emit")
+
+
+class Timeline:
+    """One request's stages on `time.monotonic()` (CLOCK_MONOTONIC, which
+    Linux shares between processes: the engine server's flight recorder
+    stamps the same clock). `mark(name)` ends a stage NOW and starts the
+    next where it ended, so consecutive stages have no gap between them
+    and their durations sum to (last end - received).
+
+    Writers: the thread the timeline is attached to (through `span`) and
+    the chain server's loop thread (`mark("emit")`, `close`); the lock
+    makes the cursor hand-over between the two safe."""
+
+    __slots__ = ("rid", "received", "stages", "ok", "depth", "_cursor",
+                 "_fields", "_lock")
+
+    def __init__(self, rid: str, received: Optional[float] = None):
+        self.rid = rid
+        self.received = time.monotonic() if received is None else received
+        # (name, start, end, fields): fields are what a callee reported
+        # about its own inside (note_server_timing), ms by name.
+        self.stages: List[tuple] = []
+        self.ok = True
+        self.depth = 0  # open spans on the attached thread
+        self._cursor = self.received
+        self._fields: Dict[str, float] = {}
+        self._lock = threading.Lock()
+
+    def mark(self, name: str) -> None:
+        with self._lock:
+            now = time.monotonic()
+            self.stages.append((name, self._cursor, now, self._fields))
+            self._cursor = now
+            self._fields = {}
+
+    def add_fields(self, fields: Dict[str, float]) -> None:
+        """Attach a callee's own times to the stage that is open now
+        (summed when the stage makes several calls)."""
+        with self._lock:
+            for k, v in fields.items():
+                self._fields[k] = self._fields.get(k, 0.0) + v
+
+    def durations_ms(self) -> Dict[str, float]:
+        """Stage name -> ms (a stage that ran twice is summed)."""
+        out: Dict[str, float] = {}
+        for name, start, end, _ in self.stages:
+            out[name] = out.get(name, 0.0) + (end - start) * 1e3
+        return out
+
+    def close(self) -> None:
+        """The request's access-log line: ONE JSON object on the logger
+        `gaie.timeline` at INFO. Called after the last frame."""
+        _TIMELINE_LOG.info("%s", json.dumps({
+            "rid": self.rid, "received": self.received, "ok": self.ok,
+            "stages": [dict({"name": n, "start": s, "end": e},
+                            **({"server": f} if f else {}))
+                       for n, s, e, f in self.stages]}))
+
+
+def attach_timeline(timeline: Optional[Timeline]) -> None:
+    """Make `timeline` the one `span` stamps on this thread (None
+    detaches: executor threads are reused)."""
+    _TLS.timeline = timeline
+
+
+def outgoing_headers() -> Dict[str, str]:
+    """Headers for a call made on behalf of the request this thread
+    serves: `x-request-id` (the timeline's rid, which the engine server
+    writes into its flight recorder's submit event) and, when tracing is
+    enabled, the W3C `traceparent` of the current span."""
+    headers: Dict[str, str] = {}
+    tl = getattr(_TLS, "timeline", None)
+    if tl is not None:
+        headers["x-request-id"] = tl.rid
+    return inject_context(headers)
+
+
+def format_server_timing(fields: Dict[str, float]) -> str:
+    """{"total": 12.3} -> "total;dur=12.300" (W3C Server-Timing, ms)."""
+    return ", ".join(f"{k};dur={v:.3f}" for k, v in fields.items())
+
+
+def parse_server_timing(value: str) -> Dict[str, float]:
+    out: Dict[str, float] = {}
+    for part in (value or "").split(","):
+        name, _, params = part.strip().partition(";")
+        for p in params.split(";"):
+            k, _, v = p.strip().partition("=")
+            if k == "dur":
+                try:
+                    out[name.strip()] = float(v)
+                except ValueError:
+                    pass
+    return out
+
+
+def note_server_timing(value: Optional[str]) -> None:
+    """A callee's `Server-Timing` response header onto the stage open on
+    this thread's timeline (no timeline, no header: nothing)."""
+    tl = getattr(_TLS, "timeline", None)
+    if tl is not None and value:
+        tl.add_fields(parse_server_timing(value))
+
+
+def enabled() -> bool:
+    return _ENABLED
 
 
 class ManualSpan:
@@ -442,18 +556,22 @@ class ManualSpan:
         if self._span is not None:
             self._span.add_event(name, attributes or {})
 
+    def context(self):
+        """This span as a parent: for `attach_context`, or a child's
+        `context=` (None when tracing is off or the span has ended)."""
+        if self._span is None:
+            return None
+        if _BACKEND == "mini":
+            return self._span.context
+        try:
+            from opentelemetry import trace
+
+            return trace.set_span_in_context(self._span)
+        except Exception:
+            return None
+
     def end(self) -> None:
         if self._span is not None:
-            for k, v in get_system_metrics().items():
-                try:
-                    self._span.set_attribute(k, v)
-                except Exception as e:
-                    # One bad attribute must not drop the REST of the
-                    # system-metric set (the old `break` silently lost
-                    # every attribute after the first failure): count
-                    # it, log once, keep going.
-                    note_trace_error(f"set_attribute({k})", e)
-                    continue
             try:
                 self._span.end()
             except Exception as e:

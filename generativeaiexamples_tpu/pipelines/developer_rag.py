@@ -11,6 +11,7 @@ from __future__ import annotations
 import logging
 from typing import Dict, Generator, List
 
+from generativeaiexamples_tpu.obs import tracing
 from generativeaiexamples_tpu.pipelines.base import BaseExample, register_example
 
 _LOG = logging.getLogger(__name__)
@@ -38,9 +39,11 @@ class QAChatbot(BaseExample):
 
     def llm_chain(self, query: str, chat_history, **llm_settings
                   ) -> Generator[str, None, None]:
-        system = self.res.config.prompts.chat_template
-        messages = ([{"role": "system", "content": system}]
-                    + list(chat_history) + [{"role": "user", "content": query}])
+        with tracing.span("assemble"):
+            system = self.res.config.prompts.chat_template
+            messages = ([{"role": "system", "content": system}]
+                        + list(chat_history)
+                        + [{"role": "user", "content": query}])
         yield from self.res.llm.stream_chat(messages, **llm_settings)
 
     def rag_chain(self, query: str, chat_history, **llm_settings
@@ -52,11 +55,13 @@ class QAChatbot(BaseExample):
             yield ("No response generated from LLM, make sure your query is "
                    "relevant to the ingested document.")
             return
-        results = self.res.retriever.limit_tokens(results)
-        context = "\n\n".join(r.text for r in results)
-        system = self.res.config.prompts.rag_template.format(context=context)
-        messages = [{"role": "system", "content": system},
-                    {"role": "user", "content": query}]
+        with tracing.span("assemble", {"n_chunks": len(results)}):
+            results = self.res.retriever.limit_tokens(results)
+            context = "\n\n".join(r.text for r in results)
+            system = self.res.config.prompts.rag_template.format(
+                context=context)
+            messages = [{"role": "system", "content": system},
+                        {"role": "user", "content": query}]
         yield from self.answer_with_fact_check(
             query, context, self.res.llm.stream_chat(messages, **llm_settings))
 

@@ -25,6 +25,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from generativeaiexamples_tpu.obs import tracing
 from generativeaiexamples_tpu.rag.splitter import ApproxTokenizer
 from generativeaiexamples_tpu.rag.vectorstore import SearchResult
 
@@ -99,11 +100,13 @@ class Retriever:
 
     def retrieve(self, query: str, top_k: Optional[int] = None,
                  with_threshold: bool = True) -> List[SearchResult]:
-        from generativeaiexamples_tpu.obs import tracing
-
         k = top_k or self.top_k
-        with tracing.span("retriever.retrieve", {"top_k": k}) as sp:
+        # Two sibling stages of the request's timeline, not one span
+        # around both: which of them a slow retrieval spent its time in
+        # is the question (PERF.md section 5).
+        with tracing.span("embed"):
             qv = self.embedder.embed_query(query)
+        with tracing.span("search", {"top_k": k}) as sp:
             results = self.store.search(
                 qv, top_k=k,
                 score_threshold=self.score_threshold if with_threshold
@@ -126,12 +129,9 @@ class Retriever:
         DBs). Result lists align with the query order; per-query
         empty-result fallback retries without the threshold, matching
         retrieve()."""
-        from generativeaiexamples_tpu.obs import tracing
-
         k = top_k or self.top_k
         thr = self.score_threshold if with_threshold else None
-        with tracing.span("retriever.retrieve_batch",
-                          {"top_k": k, "n_queries": len(queries)}) as sp:
+        with tracing.span("embed", {"n_queries": len(queries)}):
             # Batch the encoder stage too — it dominates end-to-end
             # latency, so batching only the search matmul would leave
             # most of the multi-query win on the table.
@@ -140,6 +140,8 @@ class Retriever:
             else:
                 qvs = np.stack([self.embedder.embed_query(q)
                                 for q in queries])
+        with tracing.span("search",
+                          {"top_k": k, "n_queries": len(queries)}) as sp:
             if hasattr(self.store, "search_batch"):
                 batches = self.store.search_batch(qvs, top_k=k,
                                                   score_threshold=thr)
@@ -210,7 +212,9 @@ class Retriever:
                     SearchResult(d["text"], float(s[i]), dict(d["metadata"])))
         cands = list(merged.values())
         if self.reranker is not None and cands:
-            scores = self.reranker.score(query, [c.text for c in cands])
+            with tracing.span("rerank", {"n_candidates": len(cands)}):
+                scores = self.reranker.score(query,
+                                             [c.text for c in cands])
             for c, s in zip(cands, scores):
                 c.score = float(s)
         cands.sort(key=lambda c: -c.score)

@@ -240,6 +240,12 @@ def main() -> None:
             num_processes=args.num_processes or cfg.mesh.num_processes,
             process_id=(args.process_id if args.process_id is not None
                         else cfg.mesh.process_id)))
+    # Same switch as the chain server's (tracing.enabled /
+    # ENABLE_TRACING): without it the surface extracts no traceparent
+    # and `engine.generate` never joins its caller's trace.
+    from generativeaiexamples_tpu.obs import tracing
+
+    tracing.setup(cfg)
     llm, emb, rr = build_engines(cfg, args.model_size, args.seed)
     if cfg.engine.multihost and jax.process_index() != 0:
         from generativeaiexamples_tpu.serving.multihost import run_follower

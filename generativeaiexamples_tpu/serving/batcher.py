@@ -39,7 +39,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -106,7 +106,7 @@ class MicroBatcherClosed(RuntimeError):
 
 
 class _Pending:
-    __slots__ = ("item", "key", "event", "result", "error", "t")
+    __slots__ = ("item", "key", "event", "result", "error", "t", "wait_ms")
 
     def __init__(self, item, key):
         self.item = item
@@ -115,6 +115,7 @@ class _Pending:
         self.result = None
         self.error: Optional[BaseException] = None
         self.t = time.perf_counter()
+        self.wait_ms = 0.0  # queued -> its dispatch started (set by _run)
 
 
 class MicroBatcher:
@@ -150,12 +151,21 @@ class MicroBatcher:
     # -- submission --------------------------------------------------------
 
     def submit(self, item: Any) -> Any:
-        return self.submit_many([item])[0]
+        return self._submit([item])[0].result
+
+    def submit_timed(self, item: Any) -> Tuple[Any, float]:
+        """submit(), and the ms the item waited in the queue before its
+        dispatch started (the `queue` of a Server-Timing header)."""
+        r = self._submit([item])[0]
+        return r.result, r.wait_ms
 
     def submit_many(self, items: Sequence[Any]) -> List[Any]:
         """Queue every item and block until all results land. Items from
         one call may ride different dispatches (different buckets) —
         results always come back in item order."""
+        return [r.result for r in self._submit(items)]
+
+    def _submit(self, items: Sequence[Any]) -> List[_Pending]:
         if not len(items):
             return []
         reqs = [_Pending(it, self._bucket_fn(it) if self._bucket_fn else None)
@@ -177,7 +187,7 @@ class MicroBatcher:
         for r in reqs:
             if r.error is not None:
                 raise r.error
-        return [r.result for r in reqs]
+        return reqs
 
     def close(self) -> None:
         """Stop accepting work; queued requests still complete."""
@@ -240,6 +250,7 @@ class MicroBatcher:
         self.stats.note_dispatch(len(group), waits_ms,
                                  error=error is not None)
         for i, r in enumerate(group):
+            r.wait_ms = waits_ms[i]
             if error is not None:
                 r.error = error
             else:

@@ -16,7 +16,8 @@ off is byte-identical to the pre-batcher engines.
 from __future__ import annotations
 
 import threading
-from typing import List, Optional, Sequence, Tuple
+import time
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,43 @@ import numpy as np
 from generativeaiexamples_tpu.models import bert
 from generativeaiexamples_tpu.serving.batcher import (
     MicroBatcher, MicroBatcherClosed, MicroBatchHost)
+
+
+# One request's own times, ms by name, for the surface's Server-Timing
+# header (serving/openai_server.py): `tokenize`, `queue` (the wait in
+# the micro-batcher where one is on, else for the engine's lock: another
+# caller's forward) and `ready` (dispatch -> result on the host: device
+# time PLUS the wait behind what the device was already running).
+Timing = Optional[Dict[str, float]]
+
+
+def _forward_timed(engine, rows, forward: Callable[[Any, Timing], Any],
+                   timing: Timing):
+    """The whole call rides the shared cross-request queue as ONE item
+    when a micro-batcher is on (`rows` of concurrent calls that share a
+    bucket merge into one pass, split back per caller); else the direct
+    forward."""
+    b = engine._batcher  # read once: racing disable() must not crash
+    if b is not None:
+        t0 = time.perf_counter()
+        try:
+            out, wait_ms = b.submit_timed(rows)
+        except MicroBatcherClosed:
+            pass  # raced a disable/re-enable: serve direct
+        else:
+            if timing is not None:
+                timing["queue"] = wait_ms
+                timing["ready"] = (time.perf_counter() - t0) * 1e3 - wait_ms
+            return out
+    return forward(rows, timing)
+
+
+def _note_forward(timing: Timing, t_wait: float, t_lock: float) -> None:
+    """A direct forward's `queue` (asked for the lock -> held it) and
+    `ready` (held it -> results on the host)."""
+    if timing is not None:
+        timing["queue"] = (t_lock - t_wait) * 1e3
+        timing["ready"] = (time.perf_counter() - t_lock) * 1e3
 
 
 def _bucket(n: int, buckets: Sequence[int]) -> int:
@@ -118,35 +156,36 @@ class EmbeddingEngine(MicroBatchHost):
         return [_wrap(self.tokenizer.encode(t), cls_id, sep_id, limit)
                 for t in texts]
 
-    def embed(self, texts: Sequence[str], is_query: bool = False) -> np.ndarray:
-        """[n] texts -> [n, D] float32 normalized embeddings."""
+    def embed(self, texts: Sequence[str], is_query: bool = False,
+              timing: Timing = None) -> np.ndarray:
+        """[n] texts -> [n, D] float32 normalized embeddings. A `timing`
+        dict is filled with this call's own times (see `Timing`)."""
         if not len(texts):
             return np.zeros((0, self.cfg.dim), np.float32)
         if is_query:
             texts = [self.QUERY_PREFIX + t for t in texts]
+        t0 = time.perf_counter()
         ids = self._encode_ids(texts)
-        b = self._batcher  # read once: racing disable() must not crash
-        if b is not None:
-            # The whole call rides the shared cross-request queue as ONE
-            # item; calls whose longest rows share a bucket merge into a
-            # length-sorted pass in the dispatcher. Rows are
-            # batch-independent in the forward, so same-bucket
-            # single-row calls (the coalescing case) match the direct
-            # path bitwise; merging can re-chunk a mixed-length
-            # multi-row call, which is the same masked computation at a
-            # different padding width (float rounding may differ).
-            try:
-                return b.submit(ids)
-            except MicroBatcherClosed:
-                pass  # raced a disable/re-enable: serve direct
-        return self._forward_ids(ids)
+        if timing is not None:
+            timing["tokenize"] = (time.perf_counter() - t0) * 1e3
+        # Under the micro-batcher, calls whose longest rows share a
+        # bucket merge into a length-sorted pass in the dispatcher. Rows
+        # are batch-independent in the forward, so same-bucket
+        # single-row calls (the coalescing case) match the direct path
+        # bitwise; merging can re-chunk a mixed-length multi-row call,
+        # which is the same masked computation at a different padding
+        # width (float rounding may differ).
+        return _forward_timed(self, ids, self._forward_ids, timing)
 
-    def _forward_ids(self, ids: Sequence[List[int]]) -> np.ndarray:
+    def _forward_ids(self, ids: Sequence[List[int]],
+                     timing: Timing = None) -> np.ndarray:
         """Token-id rows -> [n, D] embeddings: sort by length, pack into
         bucketed fixed-shape batches, one forward per chunk."""
         out = np.zeros((len(ids), self.cfg.dim), np.float32)
         order = sorted(range(len(ids)), key=lambda i: len(ids[i]))
-        with self._lock:
+        t_wait = time.perf_counter()
+        with self._lock, jax.profiler.TraceAnnotation("encoder.embed"):
+            t_lock = time.perf_counter()
             # Dispatch every batch asynchronously FIRST, then drain:
             # fetching inside the dispatch loop would serialize each
             # readback with the next batch's compute.
@@ -171,6 +210,7 @@ class EmbeddingEngine(MicroBatchHost):
                 vecs = np.asarray(vecs_dev)
                 for row, i in enumerate(chunk):
                     out[i] = vecs[row]
+            _note_forward(timing, t_wait, t_lock)
         return out
 
     def embed_query(self, text: str) -> np.ndarray:
@@ -231,10 +271,13 @@ class RerankEngine(MicroBatchHost):
             pos += len(g)
         return out
 
-    def score(self, query: str, passages: Sequence[str]) -> np.ndarray:
-        """[n] passages -> [n] float32 relevance scores (higher=better)."""
+    def score(self, query: str, passages: Sequence[str],
+              timing: Timing = None) -> np.ndarray:
+        """[n] passages -> [n] float32 relevance scores (higher=better).
+        A `timing` dict is filled with this call's own times."""
         if not len(passages):
             return np.zeros((0,), np.float32)
+        t0 = time.perf_counter()
         limit = self.buckets[-1]
         cls_id, sep_id = _specials(self.tokenizer)
         q_ids = self.tokenizer.encode(query)
@@ -247,23 +290,20 @@ class RerankEngine(MicroBatchHost):
             if sep_id is not None and tail:
                 tail = tail + [sep_id]
             pairs.append((head + tail, len(head)))
-        b = self._batcher  # read once: racing disable() must not crash
-        if b is not None:
-            # The whole (query, passages) set is ONE queue item;
-            # concurrent sets merge into one cross-encoder pass and
-            # split back per caller — see EmbeddingEngine.embed.
-            try:
-                return b.submit(pairs)
-            except MicroBatcherClosed:
-                pass  # raced a disable/re-enable: serve direct
-        return self._forward_pairs(pairs)
+        if timing is not None:
+            timing["tokenize"] = (time.perf_counter() - t0) * 1e3
+        # Under the micro-batcher concurrent (query, passages) sets merge
+        # into one cross-encoder pass — see EmbeddingEngine.embed.
+        return _forward_timed(self, pairs, self._forward_pairs, timing)
 
-    def _forward_pairs(self, pairs: Sequence[Tuple[List[int], int]]
-                       ) -> np.ndarray:
+    def _forward_pairs(self, pairs: Sequence[Tuple[List[int], int]],
+                       timing: Timing = None) -> np.ndarray:
         """(ids, segment-B start) rows -> [n] scores, one forward per
         bucketed chunk."""
         out = np.zeros((len(pairs),), np.float32)
-        with self._lock:
+        t_wait = time.perf_counter()
+        with self._lock, jax.profiler.TraceAnnotation("encoder.rerank"):
+            t_lock = time.perf_counter()
             # Same dispatch-all-then-drain overlap as EmbeddingEngine.
             pending = []
             for start in range(0, len(pairs), self.max_batch):
@@ -286,4 +326,5 @@ class RerankEngine(MicroBatchHost):
             for scores_dev, start, n in pending:
                 scores = np.asarray(scores_dev)
                 out[start: start + n] = scores[:n, 0]
+            _note_forward(timing, t_wait, t_lock)
         return out

@@ -63,6 +63,7 @@ import numpy as np
 
 from generativeaiexamples_tpu.config.schema import EngineConfig
 from generativeaiexamples_tpu.models.llama import LlamaConfig
+from generativeaiexamples_tpu.obs import tracing
 from generativeaiexamples_tpu.serving import engine_model
 from generativeaiexamples_tpu.serving.kv_cache import (
     PageAllocator, PagePool, SequencePages)
@@ -80,13 +81,12 @@ from generativeaiexamples_tpu.utils.tokenizer import StreamDetokenizer
 
 _LOG = logging.getLogger(__name__)
 
-# Device memory_stats() is refreshed every Nth slot retirement (and on
-# the first): on a remote/tunneled device runtime the call is a
-# blocking RPC, and _mark_done runs on the scheduler thread — a
-# per-retirement query would tax the hot path by the tunnel RTT.
-# Retired slots in between decorate their spans with the cached
-# reading.
-MEMSTATS_SAMPLE_EVERY = 32
+# The scheduler's phases on the host plane of a device trace: a
+# jax.profiler.TraceAnnotation is a flag test while no profile runs, and
+# while one runs it lands on the profiler's own clock, so an idle gap of
+# the device reads as the phase the scheduler was in (PERF.md section 3
+# lists the names). Never inside a per-token or per-slot loop.
+_phase = jax.profiler.TraceAnnotation
 
 # Failed admissions (page exhaustion) a single request may retry
 # before it is failed with an `error` stream event. The cap is a
@@ -131,6 +131,13 @@ class GenRequest:
         default_factory=queue.Queue)
     submit_time: float = dataclasses.field(default_factory=time.perf_counter)
     request_id: str = ""
+    # What the serving surface knows and the flight recorder's submit
+    # event carries: perf_counter() at its handler's entry (0.0 from an
+    # engine-direct caller; submit_time less this is the surface's work
+    # before the engine saw the request: JSON, chat template,
+    # tokenising) and the id the CALLER gave (`x-request-id`).
+    received_time: float = 0.0
+    caller_id: str = ""
     # Session identity for fleet routing (OpenAI `user` field /
     # x-session-id header): the router pins a session to the replica
     # holding its conversation KV. Unused by a single engine.
@@ -159,7 +166,7 @@ class _Slot:
         self.req = req
         self.seq = seq
         self.detok = detok
-        self.span = span  # obs.tracing.ManualSpan or None
+        self.span = span  # obs.tracing.ManualSpan; None with tracing off
         self.last_token: int = 0
         self.generated = 0
         # Tokens DISPATCHED for this slot (prefill token + K per decode
@@ -771,10 +778,6 @@ class LLMEngine:
         # (GIL-atomic float store, the `_running`/`req.cancelled`
         # cross-thread-flag idiom), read at the loop top.
         self.chaos_beat_delay_s = 0.0
-        # Sampled device memory_stats for span enrichment (see
-        # MEMSTATS_SAMPLE_EVERY). Scheduler-thread-only state.
-        self._memstats_cache: Optional[dict] = None
-        self._memstats_tick = 0
         self._rng = jax.random.PRNGKey(0)
         # Device-resident current token per slot (decode blocks chain
         # through it; the host only reads tokens one block behind).
@@ -919,11 +922,6 @@ class LLMEngine:
         # True only while _process_block_host/_process_spec_block run
         # with pacing engaged (scheduler thread; _stream_put reads it).
         self._pace_engaged = False
-        # Scheduler timing log (one line per dispatch/fetch) for perf
-        # decomposition runs; off in production.
-        self._debug_timing = os.environ.get("ENGINE_DEBUG_TIMING", "0") == "1"
-        if self._debug_timing and not logging.getLogger().handlers:
-            logging.basicConfig(level=logging.INFO)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -1859,17 +1857,21 @@ class LLMEngine:
                 # 0.0 in production, one compare per iteration).
                 time.sleep(self.chaos_beat_delay_s)
             self._drain_control_ops()
-            did_work = self._admit_waiting()
+            with _phase("sched.admit"):
+                did_work = self._admit_waiting()
             # Chunk forwards interleave with decode dispatches (paced
             # by the landed-block beat) instead of monopolizing the
             # device queue.
-            did_work = self._advance_long_prefills() or did_work
+            with _phase("sched.prefill_dispatch"):
+                did_work = self._advance_long_prefills() or did_work
             self._emit_ready_first_tokens()
             # Keep the dispatch pipeline full.
             while (len(self._inflight) < self.pipeline_depth
                    and any(s is not None for s in self.slots)):
                 try:
-                    if not self._dispatch_decode():
+                    with _phase("sched.plan"):
+                        dispatched = self._dispatch_decode()
+                    if not dispatched:
                         break
                     did_work = True
                 except Exception:
@@ -1886,7 +1888,8 @@ class LLMEngine:
                 # No blocks in flight but first tokens still en route
                 # (e.g. every active request finished at its first
                 # token): poll rather than sleep the full timeout.
-                self._wake.wait(timeout=0.002)
+                with _phase("sched.idle"):
+                    self._wake.wait(timeout=0.002)
                 self._wake.clear()
                 continue
             if not did_work:
@@ -1896,7 +1899,8 @@ class LLMEngine:
                 # sample that drowns the stall signal the histogram
                 # exists to expose.
                 self._last_beat_ready = 0.0
-                self._wake.wait(timeout=0.02)
+                with _phase("sched.idle"):
+                    self._wake.wait(timeout=0.02)
                 self._wake.clear()
 
     # graftlint: hot-path
@@ -1910,9 +1914,11 @@ class LLMEngine:
         tokens_before = self.metrics.tokens_out
         t_ready = 0.0
         try:
-            host = self._fetch_block_host(fl)
+            with _phase("sched.fetch"):
+                host = self._fetch_block_host(fl)
             t_ready = time.perf_counter()
-            self._process_block_host(fl, host)
+            with _phase("sched.emit"):
+                self._process_block_host(fl, host)
         except Exception:
             _LOG.exception("decode block failed; failing batch")
             self._fail_active()
@@ -1923,11 +1929,12 @@ class LLMEngine:
             for seq in fl.releases:
                 seq.release()
             fl.releases = []
-        self._reap_starved()
-        self._beat += 1
-        self._note_prefill_stalls()
-        self._record_beat(fl, t_ready,
-                          self.metrics.tokens_out - tokens_before)
+        with _phase("sched.retire"):
+            self._reap_starved()
+            self._beat += 1
+            self._note_prefill_stalls()
+            self._record_beat(fl, t_ready,
+                              self.metrics.tokens_out - tokens_before)
 
     # graftlint: hot-path
     def _record_beat(self, fl: _InFlight, t_ready: float,
@@ -2023,7 +2030,6 @@ class LLMEngine:
         the two latency paths that used to wait out the fetch."""
         if self._reader is None or not self._reader.is_alive():
             return _to_host(fl.block)  # tests may drive _loop inline
-        t0 = time.perf_counter() if self._debug_timing else 0.0
         self._fetch_done.clear()
         self._fetch_req.put(fl.block)
         while not self._fetch_done.wait(timeout=0.005):
@@ -2048,12 +2054,9 @@ class LLMEngine:
                           else None)
             if oldest is not None and \
                     time.perf_counter() - oldest >= self._admit_debounce_s:
-                self._admit_waiting()
+                with _phase("sched.admit"):
+                    self._admit_waiting()
         box, self._fetch_box = self._fetch_box, {}
-        if self._debug_timing:
-            _LOG.info("[timing] fetch K=%d %.1fms inflight=%d",
-                      fl.K, (time.perf_counter() - t0) * 1e3,
-                      len(self._inflight))
         if "err" in box:
             raise box["err"]
         return box["host"]
@@ -2076,10 +2079,11 @@ class LLMEngine:
             except AttributeError:
                 pass  # non-jax array (tests): treat as ready
             self._pending_first.remove(item)
-            self._emit_first_values(
-                mh_fetch_replicated(
-                    toks, "prefill first-token readback").reshape(-1),
-                metas)
+            with _phase("sched.emit"):
+                self._emit_first_values(
+                    mh_fetch_replicated(
+                        toks, "prefill first-token readback").reshape(-1),
+                    metas)
 
     @property
     def _prefill_cap(self) -> int:
@@ -2111,9 +2115,12 @@ class LLMEngine:
                 # no server-issued id; synthesize one so their
                 # lifecycle events still correlate into timeline spans.
                 req.request_id = f"req-{self.flight.stats()['flight_events']}"
+            pre_ms = (max(0.0, (req.submit_time - req.received_time) * 1e3)
+                      if req.received_time else 0.0)
             self.flight.record_event(EV_SUBMIT, req.submit_time,
                                      rid=req.request_id, tier=tier,
-                                     a=float(len(req.prompt_ids)))
+                                     a=float(len(req.prompt_ids)),
+                                     b=pre_ms, aux=req.caller_id)
         if self.qos is not None:
             self.flight.record_event(EV_QOS_PICK, time.perf_counter(),
                                      rid=req.request_id, tier=tier)
@@ -2148,13 +2155,11 @@ class LLMEngine:
         self.metrics.hists["e2e_ms"].observe(e2e_ms)
         if not self.flight.enabled:
             return
-        from generativeaiexamples_tpu.obs.tracing import span_trace_id
-
         self.flight.record_event(
             EV_RETIRE, now, rid=slot.req.request_id,
             tier=tier_id(slot.req),
             code=RETIRE_CODES.get(reason, -1), a=float(slot.generated),
-            b=e2e_ms, aux=span_trace_id(slot.span))
+            b=e2e_ms, aux=tracing.span_trace_id(slot.span))
 
     # graftlint: hot-path
     def _qos_pop_waiting(self) -> GenRequest:
@@ -2359,7 +2364,8 @@ class LLMEngine:
             for start in range(0, len(entries), cap):
                 part = entries[start:start + cap]
                 try:
-                    self._prefill_group(bucket, part)
+                    with _phase("sched.prefill_dispatch"):
+                        self._prefill_group(bucket, part)
                     did = True
                 except Exception:
                     # A bad group must not kill the scheduler thread:
@@ -2397,8 +2403,6 @@ class LLMEngine:
         group. Fully async: forward + on-device sampling + scatter into
         the device last-token buffer; NO host fetch — first tokens reach
         the host with the next decode block."""
-        from generativeaiexamples_tpu.obs.tracing import ManualSpan
-
         ps = self.pool.page_size
         n = len(entries)
         # Pad N to a power of two so only log2(max_batch) x buckets
@@ -2424,20 +2428,14 @@ class LLMEngine:
             idxs[j] = slot_idx
         all_greedy = bool(all(temps[:n] <= 0.0))
         flags = (True, False, False) if all_greedy else (False, True, True)
-        if self._debug_timing:
-            _LOG.info("[timing] prefill bucket=%d n=%d padded=%d",
-                      bucket, n, N)
         toks = self._exec_prefill(dict(
             tokens=tokens, lengths=lengths, rows=rows, temps=temps,
             top_ps=top_ps, top_ks=top_ks, idxs=idxs,
             flags=np.asarray(flags)))
         metas = []
         for req, slot_idx, seq, ids in entries:
-            span = ManualSpan("engine.generate", context=req.trace_context,
-                              attributes={"prompt_tokens": len(ids),
-                                          "request_id": req.request_id})
             slot = _Slot(req, seq, StreamDetokenizer(self.tokenizer),
-                         span=span)
+                         span=self._request_span(req, len(ids)))
             self.slots[slot_idx] = slot
             metas.append((slot_idx, slot))
             self.metrics.prefill_tokens += len(ids)
@@ -2460,6 +2458,18 @@ class LLMEngine:
         except AttributeError:
             pass
         self._pending_first.append((toks, metas))
+
+    @staticmethod
+    def _request_span(req: GenRequest, prompt_tokens: int, **attributes):
+        """The request's `engine.generate` span, a child of the context
+        its caller sent; None while tracing is off, so that retirement
+        does no span work at all."""
+        if not tracing.enabled():
+            return None
+        return tracing.ManualSpan(
+            "engine.generate", context=req.trace_context,
+            attributes=dict(attributes, prompt_tokens=prompt_tokens,
+                            request_id=req.request_id))
 
     def _begin_long_prefill(self, req: GenRequest, slot_idx: int,
                             seq: SequencePages, ids: List[int],
@@ -2857,8 +2867,6 @@ class LLMEngine:
         slot for decode. All device work lives in _exec_commit so
         followers replay it from the record alone; only the host-side
         slot/tree bookkeeping stays here."""
-        from generativeaiexamples_tpu.obs.tracing import ManualSpan
-
         ps = self.pool.page_size
         S_total = lp.s_total
         row = np.zeros((S_total // ps,), np.int32)  # padding -> sink 0
@@ -2887,12 +2895,9 @@ class LLMEngine:
             rec["h_ids"] = np.asarray(lp.ids, np.int32)
         tok0 = self._exec_commit(rec)
         self._insert_prefix(lp.ids, lp.seq)
-        span = ManualSpan("engine.generate", context=req.trace_context,
-                          attributes={"prompt_tokens": len(lp.ids),
-                                      "chunked_prefill": True,
-                                      "request_id": req.request_id})
         slot = _Slot(req, lp.seq, StreamDetokenizer(self.tokenizer),
-                     span=span)
+                     span=self._request_span(req, len(lp.ids),
+                                             chunked_prefill=True))
         self.slots[lp.slot_idx] = slot
         # Same early first-token path as bucketed prefill.
         try:
@@ -3120,7 +3125,8 @@ class LLMEngine:
             rec.update(slot=np.int32(lp.slot_idx), chunk_tokens=tok,
                        chunk_valid=np.int32(n_part),
                        fresh=np.bool_(lp.pos == 0))
-        res = self._exec_plan(rec)
+        with _phase("sched.decode_dispatch"):
+            res = self._exec_plan(rec)
         if plan.rider_width:
             self._rider_bookkeeping(lp, n_part)
         self.metrics.decode_steps += K
@@ -3910,31 +3916,4 @@ class LLMEngine:
     def _mark_done(self, slot: _Slot) -> None:
         if slot.span is not None:
             slot.span.set_attribute("tokens_generated", slot.generated)
-            # Device memory stats where the runtime exposes them
-            # (reference parity: system metrics ride every span end;
-            # host CPU/RSS attach inside ManualSpan.end()). The query
-            # can be a blocking runtime RPC on a remote device, so it
-            # is SAMPLED (first retirement, then every
-            # MEMSTATS_SAMPLE_EVERY) and the cached reading decorates
-            # the spans in between — span enrichment should never cost
-            # the scheduler thread a round trip per retired slot.
-            self._memstats_tick += 1
-            if self._memstats_cache is None or \
-                    self._memstats_tick % MEMSTATS_SAMPLE_EVERY == 1:
-                try:
-                    self._memstats_cache = dict(
-                        jax.devices()[0].memory_stats() or {})
-                except Exception:
-                    # Best-effort span enrichment (some backends expose
-                    # no memory_stats) — but never silently: this runs
-                    # on the scheduler thread, where a swallowed error
-                    # pattern would also hide real regressions.
-                    self._memstats_cache = {}
-                    _LOG.debug("device memory_stats unavailable for span",
-                               exc_info=True)
-            for key in ("bytes_in_use", "peak_bytes_in_use",
-                        "bytes_limit"):
-                if key in self._memstats_cache:
-                    slot.span.set_attribute(f"device.{key}",
-                                            self._memstats_cache[key])
             slot.span.end()

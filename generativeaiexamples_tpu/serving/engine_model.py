@@ -103,25 +103,32 @@ def _write_quant_pages(pool, kq, ks, vq, vs, table_flat):
 def _project_qkv(cfg: LlamaConfig, h, w, positions):
     B, S, _ = h.shape
     H, KH, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = mm(h, w["wq"]).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
-    k = mm(h, w["wk"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
-    v = mm(h, w["wv"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+    with jax.named_scope("attn.qkv"):  # metadata only, as in llama._layer
+        q = mm(h, w["wq"]).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
+        k = mm(h, w["wk"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+        v = mm(h, w["wv"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
     return (rope(q, positions, cfg.rope_theta, cfg.rope_scaling),
             rope(k, positions, cfg.rope_theta, cfg.rope_scaling), v)
 
 
 def _finish_block(cfg: LlamaConfig, x, out, w):
     B, S, _ = x.shape
-    x = x + mm(out.transpose(0, 2, 1, 3).reshape(B, S, -1), w["wo"])
+    with jax.named_scope("attn.out"):
+        x = x + mm(out.transpose(0, 2, 1, 3).reshape(B, S, -1), w["wo"])
     h = rms_norm(x, w["ln2"], cfg.rms_eps)
-    return x + mm(jax.nn.silu(mm(h, w["w_gate"])) * mm(h, w["w_up"]), w["w_down"])
+    with jax.named_scope("mlp.gate_up"):
+        h = jax.nn.silu(mm(h, w["w_gate"])) * mm(h, w["w_up"])
+    with jax.named_scope("mlp.down"):
+        return x + mm(h, w["w_down"])
 
 
 def _logits(cfg: LlamaConfig, params, x):
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)
-    if cfg.tie_embeddings:
-        return (x @ params["tok_emb"].T.astype(x.dtype)).astype(jnp.float32)
-    return mm(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            return (x @ params["tok_emb"].T.astype(x.dtype)
+                    ).astype(jnp.float32)
+        return mm(x, params["lm_head"]).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "use_pallas", "mesh"),

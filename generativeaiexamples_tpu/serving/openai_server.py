@@ -25,6 +25,8 @@ from typing import Any, Dict, Optional
 
 from aiohttp import web
 
+from generativeaiexamples_tpu.obs import tracing
+
 _LOG = logging.getLogger(__name__)
 
 
@@ -446,12 +448,21 @@ class OpenAIServer:
         return await self._generate(request, chat=False)
 
     async def _generate(self, request: web.Request, chat: bool) -> web.StreamResponse:
+        received = time.perf_counter()  # the flight recorder's clock
         if self.llm is None:
             return web.json_response({"error": "no LLM engine"}, status=503)
         body = await request.json()
         req = self._gen_request(body, chat, request.headers)
         if not req.session_id:
             req.session_id = request.headers.get("x-session-id", "")
+        # What the flight recorder's submit event carries besides the
+        # engine's own id: the work done here before the engine saw the
+        # request (JSON, chat template, tokenising) and the id the
+        # caller gave, so that a caller's timeline joins the engine's.
+        # The caller's trace context makes `engine.generate` its child.
+        req.received_time = received
+        req.caller_id = request.headers.get("x-request-id", "")[:128]
+        req.trace_context = tracing.extract_context(request.headers)
         # Edge admission: shed past the tier's in-flight bound with
         # 429 + Retry-After BEFORE the engine sees the request —
         # overload must cost the caller one RTT, not an unbounded
@@ -594,6 +605,7 @@ class OpenAIServer:
         })
 
     async def handle_embeddings(self, request: web.Request) -> web.Response:
+        received = time.perf_counter()
         if self.embed is None:
             return web.json_response({"error": "no embedding engine"}, status=503)
         body = await request.json()
@@ -602,9 +614,12 @@ class OpenAIServer:
             inputs = [inputs]
         is_query = body.get("input_type") == "query"  # NIM extension
         loop = asyncio.get_running_loop()
+        timing: Dict[str, float] = {}
         vecs = await loop.run_in_executor(
-            self._executor, lambda: self.embed.embed(inputs, is_query=is_query))
-        return web.json_response({
+            self._executor,
+            lambda: self.embed.embed(inputs, is_query=is_query,
+                                     timing=timing))
+        return self._timed_response(received, timing, {
             "object": "list",
             "model": body.get("model", self.embed_model_name),
             "data": [{"object": "embedding", "index": i, "embedding": v.tolist()}
@@ -612,7 +627,21 @@ class OpenAIServer:
             "usage": {"prompt_tokens": 0, "total_tokens": 0},
         })
 
+    @staticmethod
+    def _timed_response(received: float, timing: Dict[str, float],
+                        payload: Dict) -> web.Response:
+        """The encoders' answers carry their own times in a W3C
+        `Server-Timing` header (ms): `total` (this handler's entry ->
+        the body serialised), and the engine's `tokenize`, `queue` and
+        `ready` (serving/encoders.py). The chain's connector puts them
+        on the stage that made the call."""
+        resp = web.json_response(payload)
+        resp.headers["Server-Timing"] = tracing.format_server_timing(
+            dict(total=(time.perf_counter() - received) * 1e3, **timing))
+        return resp
+
     async def handle_ranking(self, request: web.Request) -> web.Response:
+        received = time.perf_counter()
         if self.rerank is None:
             return web.json_response({"error": "no reranking engine"}, status=503)
         body = await request.json()
@@ -621,12 +650,15 @@ class OpenAIServer:
         passages = [p["text"] if isinstance(p, dict) else p
                     for p in body.get("passages", [])]
         loop = asyncio.get_running_loop()
+        timing: Dict[str, float] = {}
         scores = await loop.run_in_executor(
-            self._executor, lambda: self.rerank.score(query, passages))
+            self._executor,
+            lambda: self.rerank.score(query, passages, timing=timing))
         rankings = sorted(
             ({"index": i, "logit": float(s)} for i, s in enumerate(scores)),
             key=lambda r: -r["logit"])
-        return web.json_response({"rankings": rankings})
+        return self._timed_response(received, timing,
+                                    {"rankings": rankings})
 
 
 def run_server(server: OpenAIServer, host: str = "0.0.0.0", port: int = 8000):

@@ -129,7 +129,8 @@ def test_ranked_hybrid_reachable_via_config(tmp_path, monkeypatch):
 
 def test_tracing_spans_through_generate(tmp_path):
     """ENABLE_TRACING wiring: /generate extracts the W3C traceparent and
-    emits generate + retriever spans into the configured exporter."""
+    emits `generate` and, as its children, the stages' spans into the
+    configured exporter."""
     from generativeaiexamples_tpu.obs import tracing
 
     exporter = tracing.MemoryExporter()
@@ -152,9 +153,11 @@ def test_tracing_spans_through_generate(tmp_path):
         spans = exporter.get_finished_spans()
         names = {s.name for s in spans}
         assert "generate" in names
-        assert "retriever.retrieve" in names
         gen = next(s for s in spans if s.name == "generate")
         assert format(gen.context.trace_id, "032x") == trace_id
+        for stage in ("embed", "search", "assemble"):
+            sp = next(s for s in spans if s.name == stage)
+            assert sp.parent.span_id == gen.context.span_id, stage
         assert gen.attributes["tokens_generated"] > 0
         assert gen.attributes["ttft_ms"] >= 0
     finally:
@@ -193,21 +196,11 @@ def test_engine_emits_generation_spans():
         assert sp.attributes["prompt_tokens"] == 3
         assert sp.attributes["tokens_generated"] == 4
         assert any(e.name == "first_token" for e in sp.events)
-        # System metrics ride every span end (reference parity:
-        # opentelemetry_callback.py:65-102 psutil block).
-        assert sp.attributes["system.memory_rss_mb"] > 0
-        assert "system.cpu_percent" in sp.attributes or \
-            "system.cpu_user_s" in sp.attributes
+        # Span stamps are monotonic; one wall-clock stamp for export.
+        assert sp.start_time <= sp.events[0].timestamp <= sp.end_time
+        assert abs(sp.start_wall - time.time()) < 600
     finally:
         tracing._ENABLED = False
-
-
-def test_span_system_metrics_snapshot():
-    from generativeaiexamples_tpu.obs.tracing import get_system_metrics
-
-    m = get_system_metrics()
-    assert m["system.memory_rss_mb"] > 0
-    assert any(k.startswith("system.cpu") for k in m)
 
 
 @pytest.fixture()
