@@ -2,7 +2,9 @@
 seeded int8 weights on the device, `LLMEngine`, the encoders where the
 configuration has them, `OpenAIServer` in front and, for a chain cell,
 the chain server as a CPU-only child with remote connectors. The sizes
-come from the cell's configuration file, not from `--model-size`.
+come from the cell's configuration file, not from `--model-size`; what
+the model is (its configuration in the program's terms, its weights)
+comes from the file's architecture entry, benchmark/architectures/.
 """
 
 from __future__ import annotations
@@ -29,35 +31,6 @@ OUT_DIR = os.path.join(ROOT, ".bench_out")
 def load_config(bench_dir: str, name: str) -> Dict[str, Any]:
     with open(os.path.join(bench_dir, "configs", name + ".json")) as fh:
         return json.load(fh)
-
-
-def model_dims(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The reference's view of the published config.json keys."""
-    heads = int(config["num_attention_heads"])
-    return {"n_layers": int(config["num_hidden_layers"]), "n_heads": heads,
-            "n_kv_heads": int(config["num_key_value_heads"]),
-            "head_dim": int(config.get("head_dim")
-                            or config["hidden_size"] // heads),
-            "rope_theta": float(config["rope_theta"]),
-            "rms_eps": float(config["rms_norm_eps"]),
-            "tie_embeddings": bool(config.get("tie_word_embeddings", False))}
-
-
-def llama_config(config: Dict[str, Any]):
-    import jax.numpy as jnp
-
-    from generativeaiexamples_tpu.models.llama import LlamaConfig
-
-    d = model_dims(config)
-    return LlamaConfig(
-        vocab_size=int(config["vocab_size"]), dim=int(config["hidden_size"]),
-        n_layers=d["n_layers"], n_heads=d["n_heads"],
-        n_kv_heads=d["n_kv_heads"], head_dim=d["head_dim"],
-        mlp_dim=int(config["intermediate_size"]),
-        rope_theta=d["rope_theta"], rms_eps=d["rms_eps"],
-        max_seq_len=int(config["max_position_embeddings"]),
-        tie_embeddings=d["tie_embeddings"],
-        dtype=jnp.dtype(config["serving"].get("dtype", "bfloat16")))
 
 
 def engine_config(config: Dict[str, Any]):
@@ -96,53 +69,39 @@ class Built:
     rr: Any = None
     mesh: Any = None
     params: Any = None
-    lcfg: Any = None
     tokenizer: Any = None
     phases: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 def build(config: Dict[str, Any], seed: int, devices) -> Built:
-    """Weights from the seed on the device(s), then the engine(s). The
-    llama path is the path every decoder of this repo shares."""
+    """Weights from the seed on the device(s), then the engine(s). What
+    the model is comes from the configuration's architecture entry
+    (benchmark/architectures/)."""
     import jax
-    import jax.numpy as jnp
 
+    from benchmark import architectures
     from benchmark.harness.bench_tokenizer import WordTokenizer
-    from generativeaiexamples_tpu.models import bert, llama
-    from generativeaiexamples_tpu.serving import sharding as shd
     from generativeaiexamples_tpu.serving.engine import LLMEngine
 
     b = Built()
     s = config["serving"]
-    b.lcfg = lcfg = llama_config(config)
+    arch = architectures.load(config)
+    mcfg = arch.model_config(config)
     ecfg = engine_config(config)
-    quantize = ecfg.quantize_weights == "int8"
     t0 = time.monotonic()
-    if len(devices) > 1:
-        from generativeaiexamples_tpu.parallel.mesh import build_mesh
-
-        b.mesh = shd.compatible_mesh(lcfg, build_mesh(devices=devices))
-        b.params = shd.init_sharded_params(lcfg, b.mesh, seed,
-                                           quantize=quantize)
-    else:
-        b.params = llama.init_params_on_device(lcfg, seed, quantize=quantize)
+    b.params, b.mesh = arch.init_params(config, mcfg, seed, devices)
     jax.block_until_ready(b.params)
-    b.tokenizer = WordTokenizer(lcfg.vocab_size)
-    b.llm = LLMEngine(b.params, lcfg, b.tokenizer, ecfg,
+    b.tokenizer = WordTokenizer(int(config["vocab_size"]))
+    b.llm = LLMEngine(b.params, mcfg, b.tokenizer, ecfg,
                       n_pages=s.get("n_pages"), mesh=b.mesh)
     enc = config.get("encoders")
     if enc:
-        def encoder(spec, engine_cls, key):
-            bcfg = dataclasses.replace(
-                getattr(bert.BertConfig, spec["geometry"])(),
-                dtype=jnp.dtype(spec.get("dtype", "bfloat16")),
-                **spec.get("overrides", {}))
-            return engine_cls(
-                bert.init_params(bcfg, jax.random.PRNGKey(key)), bcfg,
-                WordTokenizer(bcfg.vocab_size), **spec.get("engine", {}))
-
         from generativeaiexamples_tpu.serving.encoders import (
             EmbeddingEngine, RerankEngine)
+
+        def encoder(spec, engine_cls, key):
+            return architectures.load_encoder(spec).build(spec, engine_cls,
+                                                          key)
 
         b.emb = encoder(enc["embedder"], EmbeddingEngine, seed % 2**31 + 1)
         if "reranker" in enc:

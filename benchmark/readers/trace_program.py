@@ -2,11 +2,13 @@
 of its work.
 
 per="step": per decode step. Steps are counted in the trace itself: the
-paged-attention kernel runs once per layer per step, so its calls inside
-the program over the number of layers is the number of steps.
+step kernel's calls inside the program over the calls one step makes,
+which the configuration's architecture entry states (for the Llama block
+the paged-attention kernel runs once per layer per step).
 per="ktok": per thousand prompt tokens prefilled while the trace ran
 (the engine's `prefill_tokens` counter read at both ends of the trace).
 """
+from benchmark import architectures
 
 
 def program(ctx, name):
@@ -19,8 +21,9 @@ def program(ctx, name):
 def decode_steps(ctx, prog, step_kernel):
     calls = sum(n for kind, n in prog["kernel_calls"].items()
                 if step_kernel in kind)
-    layers = int(ctx["config"]["num_hidden_layers"])
-    return calls / layers if calls else None
+    per_step = architectures.load(ctx["config"]).step_kernel_calls(
+        ctx["config"])
+    return calls / per_step if calls else None
 
 
 def read(ctx, program_name, per, step_kernel="paged_attention"):

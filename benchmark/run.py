@@ -5,7 +5,8 @@
 Everything about a cell is data that this file finds by the names in
 BENCHMARK.json: benchmark/configs/<config>.json, benchmark/traffic/<mix>.json,
 benchmark/metrics/<metric>.json and the reader it names under
-benchmark/readers/. See benchmark/README.md.
+benchmark/readers/, and benchmark/architectures/<name>.py by the name in
+the configuration file. See benchmark/README.md.
 """
 
 from __future__ import annotations
@@ -15,6 +16,7 @@ import time
 T_PROCESS_START = time.monotonic()  # the restart a user waits for starts here
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -87,16 +89,19 @@ class CompileCounter:
 
 def check_reference(b, url: str, config: dict, seed: int) -> dict:
     """A seeded prompt through the served path, greedy, against the float32
-    reference (benchmark/harness/reference.py), outside the window."""
+    reference of the configuration's architecture entry, outside the
+    window."""
     import numpy as np
 
+    from benchmark import architectures
     from benchmark.harness import reference, system
 
     chk = config.get("reference_check", {})
     n_prompt = int(chk.get("prompt_tokens", 48))
     n_new = int(chk.get("new_tokens", 4))
     rng = np.random.default_rng([int(seed), 0xEF])
-    prompt = [int(t) for t in rng.integers(0, b.lcfg.vocab_size, n_prompt)]
+    vocab = int(config["vocab_size"])
+    prompt = [int(t) for t in rng.integers(0, vocab, n_prompt)]
     status, raw = system.http_json("POST", url + "/v1/completions", {
         "model": "bench", "prompt": prompt, "max_tokens": n_new,
         "temperature": 0.0})
@@ -106,10 +111,12 @@ def check_reference(b, url: str, config: dict, seed: int) -> dict:
     served = b.tokenizer.encode(out["choices"][0]["text"])
     if len(served) != n_new or out["usage"]["completion_tokens"] != n_new:
         return {"ok": False, "why": f"asked {n_new} tokens, got {served}"}
-    ok, worst = reference.check_greedy(
-        b.params, prompt, served, system.model_dims(config),
-        rel_tol=float(chk.get("rel_tol", 0.05)))
-    return {"ok": bool(ok), "worst_shortfall": worst, "served": served}
+    logits = functools.partial(
+        architectures.load(config).reference_logits, config, b.params)
+    rel_tol = float(chk.get("rel_tol", 0.05))
+    ok, worst = reference.check_greedy(logits, prompt, served, rel_tol=rel_tol)
+    return {"ok": bool(ok), "worst_shortfall": worst, "rel_tol": rel_tol,
+            "served": served}
 
 
 def _candidates(records: list, seconds: float) -> dict:
@@ -285,6 +292,22 @@ def run_cell(cell: dict, config: dict, traffic: dict, metrics: list, *,
     return result
 
 
+def check_lines(result: dict) -> list:
+    """Each number `correct` compared, beside its limit: the last lines of
+    standard error, which the driver keeps where a run is not correct."""
+    chk = result["checks"]
+    ref = chk["reference"]
+    return [
+        "check reference.worst_shortfall %s limit %s%s" % (
+            ref.get("worst_shortfall"), ref.get("rel_tol"),
+            " (%s)" % ref["why"] if "why" in ref else ""),
+        "check failed_requests %d limit 0" % result["failed"],
+        "check tokens_generated %d must equal tokens_asked %d" % (
+            chk["tokens_generated"], chk["tokens_asked"]),
+        "check compiles_in_window %d limit 0" % chk["compiles_in_window"],
+        "correct %s" % result["correct"]]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -308,6 +331,7 @@ def main(argv=None) -> int:
         cell_metrics(bench, cell["name"], bool(args.trace)),
         seed=args.seed, seconds=args.seconds, trace=bool(args.trace))
     sys.stdout.flush()
+    print("\n".join(check_lines(result)), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

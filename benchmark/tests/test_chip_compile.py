@@ -6,7 +6,6 @@ temporaries fit one chip's 15.75 GiB. Slow (a 32-layer program takes
 about a minute): `python -m pytest benchmark/tests/test_chip_compile.py -s`.
 """
 
-import functools
 import json
 import os
 
@@ -29,49 +28,26 @@ def topo():
 
 
 def _shapes(config, devices):
-    """(params, pool, mesh, place) as ShapeDtypeStructs with shardings."""
+    """(mcfg, ecfg, params, pool, mesh, arr): the architecture entry's
+    ShapeDtypeStructs with their shardings, and a maker of replicated
+    arguments."""
     import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+    from jax.sharding import NamedSharding, SingleDeviceSharding
     from jax.sharding import PartitionSpec as P
 
+    from benchmark import architectures
     from benchmark.harness import system
-    from generativeaiexamples_tpu.models import llama
-    from generativeaiexamples_tpu.serving import sharding as shd
-    from generativeaiexamples_tpu.serving.kv_cache import PagePool
 
-    lcfg = system.llama_config(config)
     ecfg = system.engine_config(config)
-    init = functools.partial(llama.init_params_on_device, lcfg, quantize=True)
-    pshape = jax.eval_shape(init)
-    pool_shape = jax.eval_shape(lambda: PagePool.zeros(
-        lcfg, config["serving"]["n_pages"], ecfg.page_size, dtype=jnp.int8))
-    if len(devices) > 1:
-        mesh = Mesh(np.asarray(devices).reshape(1, 1, len(devices)),
-                    ("data", "fsdp", "tensor"))
-        psh = shd.param_shardings(pshape, lcfg, mesh)
-        leaves = jax.tree.leaves(pool_shape)
-        pool_sh = jax.tree.unflatten(jax.tree.structure(pool_shape), [
-            NamedSharding(mesh, shd.KV_FUSED_SPEC if l.dtype == jnp.int8
-                          else shd.KV_FUSED_SCALE_SPEC) for l in leaves])
-        rep = NamedSharding(mesh, P())
-    else:
-        mesh = None
-        one = SingleDeviceSharding(devices[0])
-        psh = jax.tree.map(lambda _: one, pshape)
-        pool_sh = jax.tree.map(lambda _: one, pool_shape)
-        rep = one
-
-    def with_sh(shape_tree, sh_tree):
-        return jax.tree.map(lambda s, h: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=h), shape_tree, sh_tree)
+    mcfg, params, pool, mesh = architectures.load(config).compile_shapes(
+        config, ecfg, devices)
+    rep = (NamedSharding(mesh, P()) if mesh is not None
+           else SingleDeviceSharding(devices[0]))
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
 
-    return lcfg, ecfg, with_sh(pshape, psh), with_sh(pool_shape, pool_sh), \
-        mesh, arr
+    return mcfg, ecfg, params, pool, mesh, arr
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -84,21 +60,21 @@ def test_step_programs_compile_and_fit(topo, name):
     with open(os.path.join(BENCH_DIR, "configs", name + ".json")) as fh:
         config = json.load(fh)
     chips = config["serving"]["chips"]
-    lcfg, ecfg, params, pool, mesh, arr = _shapes(
+    mcfg, ecfg, params, pool, mesh, arr = _shapes(
         config, list(topo.devices)[:chips])
     B, ps = ecfg.max_batch_size, ecfg.page_size
     maxp = ecfg.max_seq_len // ps
     key = arr((2,), jnp.uint32)
     greedy = (True, False, False)
     dec = engine_model.decode_multi_step.lower(
-        params, lcfg, pool, arr((B,), jnp.int32), arr((B, maxp), jnp.int32),
+        params, mcfg, pool, arr((B,), jnp.int32), arr((B, maxp), jnp.int32),
         arr((B,), jnp.int32), arr((B,), jnp.bool_), arr((B,), jnp.float32),
         arr((B,), jnp.float32), arr((B,), jnp.int32), key,
         ecfg.decode_steps_per_dispatch, True, sampling_flags=greedy,
         mesh=mesh).compile()
     N, S = ecfg.max_prefill_group, max(ecfg.prefill_buckets)
     pre = engine_model.prefill_batch_step.lower(
-        params, lcfg, pool, arr((N, S), jnp.int32), arr((N,), jnp.int32),
+        params, mcfg, pool, arr((N, S), jnp.int32), arr((N,), jnp.int32),
         arr((N, S // ps), jnp.int32), arr((N,), jnp.float32),
         arr((N,), jnp.float32), arr((N,), jnp.int32), key, True,
         sampling_flags=greedy, mesh=mesh).compile()
