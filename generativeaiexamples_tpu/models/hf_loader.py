@@ -153,10 +153,43 @@ def read_safetensors_dir(path: str) -> Dict[str, np.ndarray]:
     return out
 
 
+# `model_type`s whose tensors carry the HF Llama names `_llama_numpy_tree`
+# and `stream_load_llama` read (a config.json without the key is taken
+# as one of these, as before).
+_LLAMA_NAMED_TYPES = ("llama", "mistral")
+# Families this module can configure but not load: looped decoders.
+_LOOPED_TYPES = ("ouro",)
+
+
+def _require_llama_names(path: str) -> None:
+    """Refuse a snapshot whose `model_type` this loader holds no
+    tensor-name map for, instead of reading it as a Llama: an "ouro"
+    snapshot read that way would drop half its norms (their names,
+    input_layernorm_2 / post_attention_layernorm_2, are unconfirmed
+    here) and silently serve a different model."""
+    cfg_file = os.path.join(path, "config.json")
+    if not os.path.exists(cfg_file):
+        return
+    with open(cfg_file) as fh:
+        mt = json.load(fh).get("model_type")
+    if mt is not None and mt not in _LLAMA_NAMED_TYPES:
+        raise ValueError(
+            f"{path}: model_type {mt!r} has no tensor-name map in "
+            f"models/hf_loader.py (known: {list(_LLAMA_NAMED_TYPES)}); "
+            "refusing to load it as a Llama")
+
+
 def llama_config_from_hf(path: str) -> llama_lib.LlamaConfig:
-    """Derive LlamaConfig from an HF snapshot's config.json."""
+    """Derive LlamaConfig from an HF snapshot's config.json. A
+    `model_type` of "ouro" adds that family's loop: passes from
+    `total_ut_steps`, a norm on each branch's output. (Whether the
+    snapshot's TENSORS can be read is the loaders' question:
+    `_require_llama_names`.)"""
     with open(os.path.join(path, "config.json")) as fh:
         c = json.load(fh)
+    family = {}
+    if c.get("model_type") in _LOOPED_TYPES:
+        family = dict(n_passes=int(c["total_ut_steps"]), post_norms=True)
     rs = c.get("rope_scaling") or None
     scaling = None
     if rs is not None:
@@ -186,6 +219,7 @@ def llama_config_from_hf(path: str) -> llama_lib.LlamaConfig:
         max_seq_len=c.get("max_position_embeddings", 8192),
         tie_embeddings=c.get("tie_word_embeddings", False),
         rope_scaling=scaling,
+        **family,
     )
 
 
@@ -465,6 +499,11 @@ def stream_load_llama(path: str, cfg: llama_lib.LlamaConfig, mesh=None,
 
     from generativeaiexamples_tpu.ops.quant import LLAMA_QUANT_KEYS
 
+    _require_llama_names(path)
+    if cfg.n_passes > 1 or cfg.post_norms:
+        raise ValueError(f"{path}: no tensor-name map for a looped or "
+                         f"post-normed model (n_passes={cfg.n_passes}, "
+                         f"post_norms={cfg.post_norms})")
     dtype = dtype or cfg.dtype
     np_dtype = {jnp.bfloat16: ml_dtypes.bfloat16}.get(dtype, dtype)
     reader = _SnapshotReader(path)

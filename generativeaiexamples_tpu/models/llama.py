@@ -70,6 +70,41 @@ class LlamaConfig:
     tie_embeddings: bool = False
     rope_scaling: Optional[RopeScaling] = None
     dtype: Any = jnp.bfloat16
+    # Looped decoders: the SAME n_layers blocks run n_passes times for
+    # every token (`walk_passes` below says how); each (pass, block) has
+    # a cache row of its own and every pass closes with ln_f.
+    n_passes: int = 1
+    # RMSNorm on each branch's OUTPUT too, before the residual add
+    # (leaves ln1_post / ln2_post), beside the one on its input.
+    post_norms: bool = False
+
+    @property
+    def cache_rows(self) -> int:
+        """Rows of a KV cache: one per (pass, block), row u * n_layers + l.
+        The cache's leading axis is THIS, not the weights' layer axis."""
+        return self.n_layers * self.n_passes
+
+    @property
+    def post_norm_init(self) -> float:
+        """What the seeded initialisers give an output norm's gain: a
+        branch's normed output has unit size times this, and a token's
+        stream takes n_layers x n_passes x 2 of them, so 1/sqrt of that
+        count keeps their sum the size of the stream that carries them
+        (depth-scaled, as a residual branch's gain usually starts). With
+        gains of one every block outweighs the whole carried state and a
+        pass of random weights amplifies any rounding 1.75x (PERF.md,
+        PR 29); a checkpoint's own gains replace these."""
+        return (2.0 * self.n_layers * self.n_passes) ** -0.5
+
+    @property
+    def residual_dtype(self):
+        """The type the residual stream is carried in. A looped model
+        adds n_layers x n_passes x 2 branch outputs of about unit size
+        onto it; in bfloat16 each is rounded to the stream's 8 bits, and
+        at 192 block executions the logits are a fifth off their float32
+        values (PERF.md, PR 29). Only the stream is float32: every
+        matmul still takes and gives `dtype`."""
+        return jnp.float32 if self.n_passes > 1 else self.dtype
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -128,6 +163,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "w_down": norm(k[7], L, M, D),
         },
     }
+    if cfg.post_norms:
+        gain = jnp.full((L, D), cfg.post_norm_init, cfg.dtype)
+        params["layers"]["ln1_post"] = params["layers"]["ln2_post"] = gain
     if not cfg.tie_embeddings:
         params["lm_head"] = norm(k[0], D, cfg.vocab_size, scale=D ** -0.5)
     return params
@@ -165,8 +203,9 @@ def init_params_on_device(cfg: LlamaConfig, seed: int = 0, *,
             sh = None if sh is None else sh[name]
         return sh
 
-    def ones(path, *shape):
-        return draw(lambda k: jnp.ones(shape, cfg.dtype), sharding_at(*path))
+    def ones(path, *shape, value=1.0):
+        return draw(lambda k: jnp.full(shape, value, cfg.dtype),
+                    sharding_at(*path))
 
     def normal(path, *shape, scale):
         return draw(lambda k: jax.random.normal(k, shape, cfg.dtype)
@@ -201,6 +240,10 @@ def init_params_on_device(cfg: LlamaConfig, seed: int = 0, *,
             "w_down": weight(("layers", "w_down"), L, M, D),
         },
     }
+    if cfg.post_norms:  # drawn last: the other leaves keep their keys
+        for name in ("ln1_post", "ln2_post"):
+            params["layers"][name] = ones(("layers", name), L, D,
+                                          value=cfg.post_norm_init)
     if not cfg.tie_embeddings:
         params["lm_head"] = weight(("lm_head",), D, V, scale=D ** -0.5)
     return params
@@ -229,6 +272,9 @@ def param_specs(cfg: LlamaConfig, rules: dict = LLM_RULES) -> Params:
             "w_down": ls("layers", "mlp", "embed_fsdp"),
         },
     }
+    if cfg.post_norms:
+        specs["layers"]["ln1_post"] = ls("layers", None)
+        specs["layers"]["ln2_post"] = ls("layers", None)
     if not cfg.tie_embeddings:
         specs["lm_head"] = ls("embed_fsdp", "vocab")
     return specs
@@ -271,7 +317,9 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
 
 @dataclass
 class KVCache:
-    """Contiguous KV cache: k/v [L, B, KH, S_max, Hd], lengths [B].
+    """Contiguous KV cache: k/v [R, B, KH, S_max, Hd] with R =
+    cfg.cache_rows (the layers, times the passes of a looped model),
+    lengths [B].
 
     `lengths[b]` counts tokens already written. The paged variant for
     continuous-batching serving lives in serving.kv_cache; this one backs
@@ -286,7 +334,7 @@ class KVCache:
     def zeros(cfg: LlamaConfig, batch: int, max_len: Optional[int] = None,
               dtype=None) -> "KVCache":
         S = max_len or cfg.max_seq_len
-        shape = (cfg.n_layers, batch, cfg.n_kv_heads, S, cfg.head_dim)
+        shape = (cfg.cache_rows, batch, cfg.n_kv_heads, S, cfg.head_dim)
         dtype = dtype or cfg.dtype
         return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                        jnp.zeros((batch,), jnp.int32))
@@ -297,11 +345,72 @@ jax.tree_util.register_dataclass(
 )
 
 
-def _layer(cfg: LlamaConfig, x, ln1, ln2, wq, wk, wv, wo, w_gate, w_up, w_down,
-           positions, kv, kv_lengths, attn_lengths, causal, q_offset, use_pallas,
-           mesh=None):
-    """One transformer block. x [B,S,D]. kv: (k_cache, v_cache) for this
-    layer ([B,KH,S_max,Hd]) or None. Returns (x_out, new_kv)."""
+def add_branch(cfg: LlamaConfig, x, y, w, post: str, scope: str):
+    """The residual add that ends a branch: x + y, or, for a family that
+    norms a branch's output too (cfg.post_norms), x + RMSNorm(y; w[post])."""
+    if cfg.post_norms:
+        with jax.named_scope(scope):
+            y = rms_norm(y, w[post], cfg.rms_eps)
+    return x + y
+
+
+def final_norm(cfg: LlamaConfig, params: Params, x):
+    """ln_f before the output head. A looped model's last pass was
+    already closed with it by `walk_passes`: not applied a second time."""
+    if cfg.n_passes > 1:
+        return x.astype(cfg.dtype)
+    return rms_norm(x, params["ln_f"], cfg.rms_eps)
+
+
+def walk_passes(cfg: LlamaConfig, params: Params, x, run_pass, state=None,
+                rolled=False):
+    """THE one place that says how a forward pass walks the model; the
+    contiguous `forward` below and every paged step program of
+    serving/engine_model.py take the walk from here.
+
+    For pass u = 0 .. n_passes-1 it calls
+        x, state, rows_out = run_pass(x, state, u * n_layers)
+    which runs the n_layers blocks once, block l with WEIGHT slice l and
+    CACHE ROW u * n_layers + l (the caller's own scan or unrolled loop).
+    A looped model (n_passes > 1) closes every pass with ln_f, whose
+    output opens the next pass and, after the last, feeds the head
+    directly (`final_norm`). A one-pass model is one call and no norm
+    here, which is the program it always was.
+
+    Returns (x, state, rows_out) with each pass's per-row outputs
+    concatenated along their leading axis into [cache_rows, ...].
+    `rolled`: the passes as ONE `lax.fori_loop` body (row0 is then
+    traced and rows_out is None), for callers whose pass is large to
+    compile and returns no rows: the decode steps."""
+    if cfg.n_passes == 1:
+        return run_pass(x, state, 0)
+
+    def one_pass(row0, x, state):
+        with jax.named_scope("loop.pass"):
+            x, state, out = run_pass(x, state, row0)
+        with jax.named_scope("loop.norm"):
+            x = rms_norm(x, params["ln_f"], cfg.rms_eps)
+        return x, state, out
+
+    if rolled:
+        x, state = jax.lax.fori_loop(
+            0, cfg.n_passes,
+            lambda u, c: one_pass(u * cfg.n_layers, *c)[:2], (x, state))
+        return x, state, None
+    outs = []
+    for u in range(cfg.n_passes):
+        x, state, out = one_pass(u * cfg.n_layers, x, state)
+        outs.append(out)
+    if outs[0] is None:
+        return x, state, None
+    return x, state, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs)
+
+
+def _layer(cfg: LlamaConfig, x, w, positions, kv, kv_lengths, attn_lengths,
+           causal, q_offset, use_pallas, mesh=None):
+    """One transformer block. x [B,S,D]; w: this block's weights. kv:
+    (k_cache, v_cache) for this cache row ([B,KH,S_max,Hd]) or None.
+    Returns (x_out, new_kv)."""
     B, S, D = x.shape
     H, KH, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
@@ -309,11 +418,11 @@ def _layer(cfg: LlamaConfig, x, ln1, ln2, wq, wk, wv, wo, w_gate, w_up, w_down,
     # in serving/engine_model.py's copies of this block) are metadata
     # only: they name the matmuls in a profile's op metadata and change
     # no compiled program and no compile-cache key.
-    h = rms_norm(x, ln1, cfg.rms_eps)
+    h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
     with jax.named_scope("attn.qkv"):
-        q = mm(h, wq).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
-        k = mm(h, wk).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
-        v = mm(h, wv).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+        q = mm(h, w["wq"]).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
+        k = mm(h, w["wk"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+        v = mm(h, w["wv"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
     q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
     k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
@@ -335,12 +444,14 @@ def _layer(cfg: LlamaConfig, x, ln1, ln2, wq, wk, wv, wo, w_gate, w_up, w_down,
 
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * Hd)
     with jax.named_scope("attn.out"):
-        x = x + mm(out, wo)
-    h = rms_norm(x, ln2, cfg.rms_eps)
+        x = add_branch(cfg, x, mm(out, w["wo"]), w, "ln1_post",
+                       "attn.post_norm")
+    h = rms_norm(x, w["ln2"], cfg.rms_eps).astype(cfg.dtype)
     with jax.named_scope("mlp.gate_up"):
-        h = jax.nn.silu(mm(h, w_gate)) * mm(h, w_up)
+        h = jax.nn.silu(mm(h, w["w_gate"])) * mm(h, w["w_up"])
     with jax.named_scope("mlp.down"):
-        x = x + mm(h, w_down)
+        x = add_branch(cfg, x, mm(h, w["w_down"]), w, "ln2_post",
+                       "mlp.post_norm")
     return x, new_kv
 
 
@@ -368,7 +479,7 @@ def forward(
     if positions is None:
         base = kv_cache.lengths[:, None] if kv_cache is not None else 0
         positions = base + jnp.arange(S)[None, :]
-    x = params["tok_emb"][tokens].astype(cfg.dtype)
+    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)
 
     if kv_cache is None:
         attn_lengths = lengths if lengths is not None else jnp.full((B,), S, jnp.int32)
@@ -379,24 +490,30 @@ def forward(
         attn_lengths = new_total
         causal, q_offset, kv_lengths = True, kv_cache.lengths, kv_cache.lengths
 
-    lp = params["layers"]
+    L = cfg.n_layers
+    kv_in = (kv_cache.k, kv_cache.v) if kv_cache is not None else None
+    # The stacked weights as a tuple in this order: the scan's operands
+    # stay in the order they always had (a dict's would be sorted).
+    names = ("ln1", "ln2", "wq", "wk", "wv", "wo", "w_gate", "w_up",
+             "w_down") + (("ln1_post", "ln2_post") if cfg.post_norms else ())
+    weights = tuple(params["layers"][n] for n in names)
 
     def body(x, layer):
-        (ln1, ln2, wq, wk, wv, wo, w_gate, w_up, w_down), kv = layer
-        x, new_kv = _layer(cfg, x, ln1, ln2, wq, wk, wv, wo, w_gate, w_up,
-                           w_down, positions, kv, kv_lengths, attn_lengths,
-                           causal, q_offset, use_pallas, mesh)
-        return x, new_kv
+        ws, kv = layer
+        return _layer(cfg, x, dict(zip(names, ws)), positions, kv,
+                      kv_lengths, attn_lengths, causal, q_offset,
+                      use_pallas, mesh)
 
-    weights = (lp["ln1"], lp["ln2"], lp["wq"], lp["wk"], lp["wv"], lp["wo"],
-               lp["w_gate"], lp["w_up"], lp["w_down"])
-    kv_in = (kv_cache.k, kv_cache.v) if kv_cache is not None else None
-    if kv_in is not None:
-        x, kv_out = jax.lax.scan(body, x, (weights, kv_in))
-    else:
-        x, kv_out = jax.lax.scan(body, x, (weights, None))
+    def run_pass(x, _, row0):  # blocks 0..L-1 over cache rows row0..row0+L-1
+        rows = kv_in
+        if rows is not None and cfg.n_passes > 1:
+            rows = tuple(c[row0:row0 + L] for c in kv_in)
+        x, kv_out = jax.lax.scan(body, x, (weights, rows))
+        return x, None, kv_out
 
-    x = rms_norm(x, params["ln_f"], cfg.rms_eps)
+    x, _, kv_out = walk_passes(cfg, params, x, run_pass)
+
+    x = final_norm(cfg, params, x)
     with jax.named_scope("lm_head"):
         if cfg.tie_embeddings:
             logits = (x @ params["tok_emb"].T.astype(x.dtype)

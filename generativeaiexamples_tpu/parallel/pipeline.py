@@ -74,10 +74,8 @@ def _run_stage(layers, cfg: llama.LlamaConfig, x, positions, lengths):
     local [L/S] layers — same block math as llama.forward's scan)."""
 
     def body(x, w):
-        x, _ = llama._layer(
-            cfg, x, w["ln1"], w["ln2"], w["wq"], w["wk"], w["wv"], w["wo"],
-            w["w_gate"], w["w_up"], w["w_down"], positions, None, None,
-            lengths, True, None, False)
+        x, _ = llama._layer(cfg, x, w, positions, None, None, lengths, True,
+                            None, False)
         return x, None
 
     x, _ = jax.lax.scan(body, x, layers)
@@ -115,6 +113,10 @@ def pipeline_loss(params, cfg: llama.LlamaConfig, tokens, targets, mask, *,
     if cfg.n_layers % n_stages:
         raise ValueError(f"n_layers {cfg.n_layers} not divisible by "
                          f"pipeline stages {n_stages}")
+    if cfg.n_passes > 1:
+        raise ValueError(f"the GPipe schedule runs each stage once a "
+                         f"microbatch; a looped model (n_passes="
+                         f"{cfg.n_passes}) needs trainer.loss_fn")
     mb = B // n_micro
 
     def f(p, tokens, targets, mask):

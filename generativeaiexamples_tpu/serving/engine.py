@@ -302,6 +302,15 @@ class EngineMetrics:
                       for k in flight_mod.HIST_KEYS}
         self.tokens_out = 0
         self.decode_steps = 0
+        # Block executions dispatched by decode steps: a step runs
+        # every block once a pass (cfg.cache_rows of them), so
+        # layer_passes / decode_steps is the depth a token pays for.
+        self.layer_passes = 0
+        # KV pool geometry (set once at engine build): rows of the pool
+        # (layers x passes) and the bytes one cached token takes over
+        # all rows, scales included.
+        self.kv_cache_rows = 0
+        self.kv_bytes_per_token = 0
         self.busy_slots_acc = 0
         # Speculative decoding: committed tokens vs slot-steps, for the
         # acceptance-rate gauge (1.0 = no drafts accepted, k+1 = all).
@@ -455,6 +464,9 @@ class EngineMetrics:
             "ttft_p50_ms": ttft["p50"], "ttft_p95_ms": ttft["p95"],
             "tokens_generated": self.tokens_out,
             "decode_steps": self.decode_steps,
+            "layer_passes": self.layer_passes,
+            "kv_cache_rows": self.kv_cache_rows,
+            "kv_bytes_per_token": self.kv_bytes_per_token,
             "mean_batch_occupancy": occ,
             "tokens_per_sec": self.tokens_per_sec(),
             "prefill_tokens": self.prefill_tokens,
@@ -540,6 +552,33 @@ class EngineMetrics:
         return out
 
 
+# Lanes that index the page pool by layer and have no test against a
+# looped model's reference (benchmark/architectures/ouro.py): option ->
+# what it would run. A model with more than one pass is refused at
+# engine build when one is on, by name; never served as a one-pass model.
+_ONE_PASS_LANES = (
+    ("speculative_k", "the verify and tree-verify steps"),
+    ("step_plans", "the composed step-plan lattice"),
+    ("fused_prefill", "the fused decode + prefill-chunk step"),
+    ("prefix_cache", "prefix-page reuse and the disaggregated KV transfer"),
+    ("kv_pager", "page demotion and promotion"),
+)
+
+
+def _refuse_unwalked_lanes(cfg: LlamaConfig, ecfg: EngineConfig) -> None:
+    if cfg.n_passes == 1:
+        return
+    on = [(name, what) for name, what in _ONE_PASS_LANES
+          if getattr(ecfg, name)]
+    if on:
+        raise ValueError(
+            f"model runs its {cfg.n_layers} blocks n_passes={cfg.n_passes} "
+            f"times a token ({cfg.cache_rows} cache rows); not served with "
+            + ", ".join(f"engine.{name} ({what})" for name, what in on)
+            + ": those lanes are untested against a looped model; turn "
+            "them off")
+
+
 class LLMEngine:
     """Single-host engine over one jax device, or tensor-parallel over a
     device mesh.
@@ -602,6 +641,7 @@ class LLMEngine:
         set_pallas_int8_matmul(
             self.mesh is None and jax.default_backend() == "tpu"
             and os.environ.get("ENGINE_PALLAS_INT8", "0") == "1")
+        _refuse_unwalked_lanes(cfg, self.ecfg)
         ps = self.ecfg.page_size
         if self.ecfg.max_seq_len < ps:
             raise ValueError(
@@ -706,6 +746,15 @@ class LLMEngine:
         self.slots: List[Optional[_Slot]] = [None] * self.ecfg.max_batch_size
         self.waiting: deque[GenRequest] = deque()
         self.metrics = EngineMetrics()
+        self.metrics.kv_cache_rows = cfg.cache_rows
+        self.metrics.kv_bytes_per_token = sum(
+            leaf.nbytes for leaf in jax.tree.leaves(self.pool)
+        ) // (n_pages * ps)
+        _LOG.info("kv pool: %d rows (%d layers x %d passes) x %d pages of "
+                  "%d tokens, %s; %d bytes a cached token",
+                  cfg.cache_rows, cfg.n_layers, cfg.n_passes, n_pages, ps,
+                  jnp.dtype(self.ecfg.kv_dtype).name,
+                  self.metrics.kv_bytes_per_token)
         if self.memory_plan is not None:
             self.metrics.planner_headroom_bytes = (
                 self.memory_plan.headroom_bytes)
@@ -3130,6 +3179,7 @@ class LLMEngine:
         if plan.rider_width:
             self._rider_bookkeeping(lp, n_part)
         self.metrics.decode_steps += K
+        self.metrics.layer_passes += K * self.cfg.cache_rows
         self.metrics.busy_slots_acc += len(active) * K
         if spec_mode:
             for i in active:

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models.llama import (
-    LlamaConfig, rms_norm, rope)
+    LlamaConfig, add_branch, final_norm, rms_norm, rope, walk_passes)
 from generativeaiexamples_tpu.ops import attention as attn_ops
 from generativeaiexamples_tpu.ops.quant import mm
 from generativeaiexamples_tpu.serving.kv_cache import PagePool
@@ -114,16 +114,72 @@ def _project_qkv(cfg: LlamaConfig, h, w, positions):
 def _finish_block(cfg: LlamaConfig, x, out, w):
     B, S, _ = x.shape
     with jax.named_scope("attn.out"):
-        x = x + mm(out.transpose(0, 2, 1, 3).reshape(B, S, -1), w["wo"])
-    h = rms_norm(x, w["ln2"], cfg.rms_eps)
+        x = add_branch(
+            cfg, x, mm(out.transpose(0, 2, 1, 3).reshape(B, S, -1), w["wo"]),
+            w, "ln1_post", "attn.post_norm")
+    h = rms_norm(x, w["ln2"], cfg.rms_eps).astype(cfg.dtype)
     with jax.named_scope("mlp.gate_up"):
         h = jax.nn.silu(mm(h, w["w_gate"])) * mm(h, w["w_up"])
     with jax.named_scope("mlp.down"):
-        return x + mm(h, w["w_down"])
+        return add_branch(cfg, x, mm(h, w["w_down"]), w, "ln2_post",
+                          "mlp.post_norm")
+
+
+def _scanned_pass(params, body):
+    """A prefill's `run_pass` for llama.walk_passes: one scan of
+    `body(x, w) -> (x, this row's k/v)` over the stacked blocks. The
+    scan emits a pass's rows in block order, so the walk's row map
+    (row0 + l) is the order it concatenates them in."""
+    def run_pass(x, state, row0):
+        x, rows = jax.lax.scan(body, x, params["layers"])
+        return x, state, rows
+    return run_pass
+
+
+def _walk_decode(params, cfg: LlamaConfig, x, pools, body):
+    """The decode family's walk: `body(x, pools, w, row) -> (x, pools)`
+    for every (pass, block) llama.walk_passes names, block l's weights
+    with cache row row0 + l, the blocks of a pass unrolled or scanned
+    (_UNROLL_DECODE).
+
+    The passes of a looped model are a `lax.fori_loop` around the
+    blocks (the row is then traced: the int8 kernel takes it as a
+    scalar, the scatters as a dynamic index). Measured on a v5e at
+    Ouro-2.6B's sizes (PERF.md, PR 29): with the passes unrolled too a
+    step is 1 % shorter (94.0 against 94.9 ms in a block of one), the
+    compile 3.5 times longer (120 against 34 s), and a block of eight
+    (1,536 bodies) does not compile in the host's 40 GiB."""
+    L = cfg.n_layers
+
+    def run_pass(x, pools, row0):
+        if _UNROLL_DECODE:
+            from generativeaiexamples_tpu.ops.quant import QuantizedTensor
+
+            def take(t, l):
+                if isinstance(t, QuantizedTensor):
+                    return QuantizedTensor(t.q[l], t.s[l])
+                return t[l]
+
+            for l in range(L):
+                w = {k2: take(v2, l) for k2, v2 in params["layers"].items()}
+                x, pools = body(x, pools, w, row0 + l)
+        else:
+            def scan_body(carry, wl):
+                x, pools = carry
+                w, row = wl
+                return body(x, pools, w, row), None
+
+            (x, pools), _ = jax.lax.scan(
+                scan_body, (x, pools),
+                (params["layers"], jnp.arange(row0, row0 + L)))
+        return x, pools, None
+
+    x, pools, _ = walk_passes(cfg, params, x, run_pass, pools, rolled=True)
+    return x, pools
 
 
 def _logits(cfg: LlamaConfig, params, x):
-    x = rms_norm(x, params["ln_f"], cfg.rms_eps)
+    x = final_norm(cfg, params, x)  # a looped pass is closed by the walk
     with jax.named_scope("lm_head"):
         if cfg.tie_embeddings:
             return (x @ params["tok_emb"].T.astype(x.dtype)
@@ -143,8 +199,9 @@ def prefill_step(
 ) -> Tuple[jax.Array, PagePool]:
     """Prefill one sequence; returns (last-token logits [V], pool).
 
-    The layer scan only READS weights and returns the per-layer k/v
-    ([L, S, KH, Hd], a few MB); the page pool is written once afterwards
+    The layer scan only READS weights and returns the per-row k/v
+    ([R, S, KH, Hd], R = cfg.cache_rows, a few MB); the page pool is
+    written once afterwards, all rows of all passes in the one scatter
     — never re-stacked through scan outputs (that would copy the whole
     pool per call)."""
     _, S = tokens.shape
@@ -154,19 +211,20 @@ def prefill_step(
     positions = jnp.arange(S)[None, :]
     lengths = length[None]
 
-    x = params["tok_emb"][tokens].astype(cfg.dtype)
+    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)
 
     def body(x, w):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps)
+        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
         q, k, v = _project_qkv(cfg, h, w, positions)
         out = attn_ops.attention(q, k, v, causal=True, lengths=lengths,
                                  use_pallas=use_pallas, mesh=mesh)
         x = _finish_block(cfg, x, out, w)
         return x, (k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2))  # [S,KH,Hd]
 
-    x, (k_stack, v_stack) = jax.lax.scan(body, x, params["layers"])
-    # [L, S, KH, Hd] -> canonical pages [L, KH, npages, ps, Hd]; scatter
-    # once into the [L, KH, P, ps, Hd] pool with contiguous advanced
+    x, _, (k_stack, v_stack) = walk_passes(cfg, params, x,
+                                           _scanned_pass(params, body))
+    # [R, S, KH, Hd] -> canonical pages [R, KH, npages, ps, Hd]; scatter
+    # once into the [R, KH, P, ps, Hd] pool with contiguous advanced
     # indices (see _write_prefill_pages).
     L = k_stack.shape[0]
     kw = k_stack.reshape(L, npages, ps, KH, Hd).transpose(0, 3, 1, 2, 4)
@@ -215,10 +273,10 @@ def prefill_batch_step(
         from generativeaiexamples_tpu.serving.paged_attention_int8 import (
             quantize_kv)
 
-    x = params["tok_emb"][tokens].astype(cfg.dtype)
+    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)
 
     def body(x, w):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps)
+        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
         q, k, v = _project_qkv(cfg, h, w, positions)
         out = attn_ops.attention(q, k, v, causal=True, lengths=lengths,
                                  use_pallas=use_pallas, mesh=mesh)
@@ -233,8 +291,8 @@ def prefill_batch_step(
                 quantize_kv(v_t, scale_dtype=pool.s.dtype)
         return x, (k_t, v_t)
 
-    x, kv_out = jax.lax.scan(body, x, params["layers"])
-    L = cfg.n_layers
+    x, _, kv_out = walk_passes(cfg, params, x, _scanned_pass(params, body))
+    L = cfg.cache_rows
 
     def paged(t):  # [L, N, S, KH, ...] -> [L, KH, N*npages, ps, ...]
         rest = t.shape[4:]
@@ -292,7 +350,7 @@ def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
     offset = (lengths - 1) % ps  # [B]
     kh_idx = jnp.arange(cfg.n_kv_heads)[:, None]  # [KH, 1] -> bcast [KH, B]
 
-    x = params["tok_emb"][tokens[:, None]].astype(cfg.dtype)  # [B, 1, D]
+    x = params["tok_emb"][tokens[:, None]].astype(cfg.residual_dtype)  # [B, 1, D]
     quantized = pool.quantized
     if quantized:
         from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
@@ -300,7 +358,7 @@ def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
             quantize_kv)
 
     def body(x, pools, w, l):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps)
+        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
         q, k, v = _project_qkv(cfg, h, w, positions)  # [B, *, 1, Hd]
         k_new = k[:, :, 0, :].transpose(1, 0, 2)  # [KH, B, Hd]
         v_new = v[:, :, 0, :].transpose(1, 0, 2)
@@ -340,26 +398,7 @@ def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
         return x, new_pools
 
     pools = (pool.kv, pool.s) if quantized else (pool.k, pool.v)
-    if _UNROLL_DECODE:
-        from generativeaiexamples_tpu.ops.quant import QuantizedTensor
-
-        def take(t, l):
-            if isinstance(t, QuantizedTensor):
-                return QuantizedTensor(t.q[l], t.s[l])
-            return t[l]
-
-        for l in range(cfg.n_layers):
-            w = {k2: take(v2, l) for k2, v2 in params["layers"].items()}
-            x, pools = body(x, pools, w, l)
-    else:
-        def scan_body(carry, wl):
-            x, pools = carry
-            w, l = wl
-            return body(x, pools, w, l), None
-
-        (x, pools), _ = jax.lax.scan(
-            scan_body, (x, pools),
-            (params["layers"], jnp.arange(cfg.n_layers)))
+    x, pools = _walk_decode(params, cfg, x, pools, body)
     logits = _logits(cfg, params, x)[:, 0]
     if quantized:
         return logits, QuantPagePool(pools[0], pools[1], ps)
@@ -494,7 +533,7 @@ def _decode_verify_once(params, cfg: LlamaConfig, pool: PagePool,
     flat_tables = jnp.repeat(page_tables, r, axis=0)   # [B*r, maxp]
     flat_lengths = (lengths[:, None] + offs).reshape(-1)  # [B*r]
 
-    x = params["tok_emb"][tokens].astype(cfg.dtype)    # [B, r, D]
+    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)    # [B, r, D]
     quantized = pool.quantized
     if quantized:
         from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
@@ -525,7 +564,7 @@ def _decode_verify_once(params, cfg: LlamaConfig, pool: PagePool,
             fused_multi = False
 
     def body(x, pools, w, l):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps)
+        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
         q, k, v = _project_qkv(cfg, h, w, positions)   # [B, *, r, Hd]
         k_new = k.transpose(1, 0, 2, 3)                # [KH, B, r, Hd]
         v_new = v.transpose(1, 0, 2, 3)
@@ -576,26 +615,7 @@ def _decode_verify_once(params, cfg: LlamaConfig, pool: PagePool,
         return x, new_pools
 
     pools = (pool.kv, pool.s) if quantized else (pool.k, pool.v)
-    if _UNROLL_DECODE:
-        from generativeaiexamples_tpu.ops.quant import QuantizedTensor
-
-        def take(t, l):
-            if isinstance(t, QuantizedTensor):
-                return QuantizedTensor(t.q[l], t.s[l])
-            return t[l]
-
-        for l in range(cfg.n_layers):
-            w = {k2: take(v2, l) for k2, v2 in params["layers"].items()}
-            x, pools = body(x, pools, w, l)
-    else:
-        def scan_body(carry, wl):
-            x, pools = carry
-            w, l = wl
-            return body(x, pools, w, l), None
-
-        (x, pools), _ = jax.lax.scan(
-            scan_body, (x, pools),
-            (params["layers"], jnp.arange(cfg.n_layers)))
+    x, pools = _walk_decode(params, cfg, x, pools, body)
     logits = _logits(cfg, params, x)                   # [B, r, V]
     if quantized:
         return logits, QuantPagePool(pools[0], pools[1], ps)
@@ -707,7 +727,7 @@ def _tree_verify_once(params, cfg: LlamaConfig, pool: PagePool,
     offset = slots % ps
     kh_idx = jnp.arange(KH)[:, None, None]
 
-    x = params["tok_emb"][tokens].astype(cfg.dtype)              # [B, r, D]
+    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)              # [B, r, D]
     quantized = pool.quantized
     if quantized:
         from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
@@ -715,7 +735,7 @@ def _tree_verify_once(params, cfg: LlamaConfig, pool: PagePool,
             quantize_kv)
 
     def body(x, pools, w, l):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps)
+        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
         q, k, v = _project_qkv(cfg, h, w, positions)   # [B, *, r, Hd]
         k_new = k.transpose(1, 0, 2, 3)                # [KH, B, r, Hd]
         v_new = v.transpose(1, 0, 2, 3)
@@ -751,26 +771,7 @@ def _tree_verify_once(params, cfg: LlamaConfig, pool: PagePool,
         return x, new_pools
 
     pools = (pool.kv, pool.s) if quantized else (pool.k, pool.v)
-    if _UNROLL_DECODE:
-        from generativeaiexamples_tpu.ops.quant import QuantizedTensor
-
-        def take(t, l):
-            if isinstance(t, QuantizedTensor):
-                return QuantizedTensor(t.q[l], t.s[l])
-            return t[l]
-
-        for l in range(cfg.n_layers):
-            w = {k2: take(v2, l) for k2, v2 in params["layers"].items()}
-            x, pools = body(x, pools, w, l)
-    else:
-        def scan_body(carry, wl):
-            x, pools = carry
-            w, l = wl
-            return body(x, pools, w, l), None
-
-        (x, pools), _ = jax.lax.scan(
-            scan_body, (x, pools),
-            (params["layers"], jnp.arange(cfg.n_layers)))
+    x, pools = _walk_decode(params, cfg, x, pools, body)
     logits = _logits(cfg, params, x)                   # [B, r, V]
     if quantized:
         return logits, QuantPagePool(pools[0], pools[1], ps)
@@ -1281,7 +1282,7 @@ def pool_to_cache(
 
     ps = pool.page_size
     S = table_row.shape[0] * ps
-    L, KH, Hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    L, KH, Hd = cfg.cache_rows, cfg.n_kv_heads, cfg.head_dim
     dt = jnp.dtype(cfg.dtype)
     li, kh, tb = _page_axes(L, KH, table_row)
     if pool.quantized:

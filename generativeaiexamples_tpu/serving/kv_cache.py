@@ -3,8 +3,10 @@
 The TPU-native replacement for what TRT-LLM's paged KV manager does
 inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
 
-- Device: one page pool per model, k/v arrays [L, KH, P, page_size, Hd]
-  (kv-heads outermost after the layer axis: per-layer slices are the
+- Device: one page pool per model, k/v arrays [R, KH, P, page_size, Hd],
+  R = cfg.cache_rows: one row per layer, times the passes of a looped
+  model (a row per (pass, block); written "L" below where a model has
+  one pass) (kv-heads outermost after the row axis: per-layer slices are the
   [KH, P, ps, Hd] layout the JetStream-style multi-page Pallas kernel
   wants, and the TP sharding axis is a leading dim). Page 0 is a
   reserved garbage sink — padding positions in bucketed prefills and
@@ -15,7 +17,7 @@ inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
 - Page tables are [B, max_pages] int32 arrays shipped to the device each
   step (tiny; rides along with the token ids).
 
-Sized so `bytes = L * P * page_size * KH * Hd * 2 dtypes * itemsize`;
+Sized so `bytes = R * P * page_size * KH * Hd * 2 dtypes * itemsize`;
 `PagePool.for_budget` picks P from an HBM byte budget.
 """
 
@@ -59,7 +61,8 @@ class PagePool:
             return QuantPagePool.zeros(cfg, n_pages, page_size,
                                        sharding=sharding,
                                        scale_sharding=scale_sharding)
-        shape = (cfg.n_layers, cfg.n_kv_heads, n_pages, page_size, cfg.head_dim)
+        shape = (cfg.cache_rows, cfg.n_kv_heads, n_pages, page_size,
+                 cfg.head_dim)
         k = _alloc(shape, dtype, sharding)
         v = _alloc(shape, dtype, sharding)
         return PagePool(k, v, page_size)
@@ -72,7 +75,7 @@ class PagePool:
         per_tok = cfg.n_kv_heads * cfg.head_dim * itemsize
         if dtype == jnp.int8:
             per_tok += cfg.n_kv_heads * 4  # narrow f32 scales
-        per_page = cfg.n_layers * page_size * per_tok * 2
+        per_page = cfg.cache_rows * page_size * per_tok * 2
         n_pages = max(2, hbm_bytes // per_page)
         return PagePool.zeros(cfg, int(n_pages), page_size, dtype)
 
@@ -119,7 +122,7 @@ class QuantPagePool:
     @staticmethod
     def zeros(cfg: LlamaConfig, n_pages: int, page_size: int = 64,
               sharding=None, scale_sharding=None) -> "QuantPagePool":
-        shape = (2, cfg.n_layers, cfg.n_kv_heads, n_pages, page_size,
+        shape = (2, cfg.cache_rows, cfg.n_kv_heads, n_pages, page_size,
                  cfg.head_dim)
         kv = _alloc(shape, jnp.int8, sharding)
         s = _alloc(shape[:-1], jnp.float32, scale_sharding)

@@ -213,7 +213,7 @@ def pool_page_bytes_per_device(lcfg: LlamaConfig, ecfg: EngineConfig,
     tp = int(axis_sizes.get("tensor", 1))
     kh = math.ceil(lcfg.n_kv_heads / tp)
     ps = ecfg.page_size
-    base = lcfg.n_layers * kh * ps
+    base = lcfg.cache_rows * kh * ps  # a row per (pass, block)
     if jnp.dtype(ecfg.kv_dtype) == jnp.int8:
         return 2 * base * lcfg.head_dim + 2 * base * 4
     return 2 * base * lcfg.head_dim * jnp.dtype(ecfg.kv_dtype).itemsize
@@ -227,7 +227,7 @@ def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
     # KVCache [L, 1, KH, max_seq_len, Hd] x (k, v) on device
     # (engine._max_long_prefills = 1); counted unsharded — GSPMD may
     # shard it, so this over-counts, never under.
-    long_pf = (2 * lcfg.n_layers * lcfg.n_kv_heads
+    long_pf = (2 * lcfg.cache_rows * lcfg.n_kv_heads
                * ecfg.max_seq_len * lcfg.head_dim * wsize)
     # Warmup/steady-state activation transients: the widest prefill
     # dispatch runs N sequences x the largest bucket through the stack.

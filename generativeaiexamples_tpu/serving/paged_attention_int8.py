@@ -121,7 +121,8 @@ def _tree_keep(pos, length, jrow, r, tree):
     return (rel < 0) | (in_tree & ((rel == 0) | same_chain))
 
 
-def _copy_block(pages_ref, layer, hbm, buf, sem, b, i, slot, *, ppcb, maxp):
+def _copy_block(pages_ref, layer, hbm, buf, sem, b, i, slot, *, ppcb, maxp,
+                split_kv=False):
     """Async copies for compute block i of row b into buffer `slot`:
     one STRIDED descriptor per page covering all kv heads AND both of
     k/v (hbm.at[:, layer, :, pid] on the FULL [2, L, KH, P, ...] pool —
@@ -129,13 +130,28 @@ def _copy_block(pages_ref, layer, hbm, buf, sem, b, i, slot, *, ppcb, maxp):
     per-layer slice of the kv-leading layout is non-contiguous and XLA
     would materialize 32 copies of it). Returns the descriptors
     (recreate-and-wait pattern: semaphores count bytes, so identical
-    descriptors built later can wait)."""
+    descriptors built later can wait).
+
+    `split_kv`: one descriptor for k and one for v. The single one
+    strides from a page's k to its v over L*KH*P*ps*Hd bytes, and a pool
+    whose half is 4 GiB or more (a looped model's 192 rows) reads wrong
+    pages through it (SPLIT_KV_BYTES below)."""
     copies = []
     for j in range(ppcb):
         pid = pages_ref[b * maxp + i * ppcb + j]
-        copies.append(pltpu.make_async_copy(
-            hbm.at[:, layer, :, pid], buf.at[slot, j], sem.at[slot]))
+        if split_kv:
+            copies += [pltpu.make_async_copy(
+                hbm.at[h, layer, :, pid], buf.at[slot, j, h], sem.at[slot])
+                for h in (0, 1)]
+        else:
+            copies.append(pltpu.make_async_copy(
+                hbm.at[:, layer, :, pid], buf.at[slot, j], sem.at[slot]))
     return copies
+
+
+# From this many bytes in ONE half (k or v) of the fused code pool, the
+# kernel copies a page's k and v with a descriptor each (`_copy_block`).
+SPLIT_KV_BYTES = 2 ** 32
 
 
 def _int8_kernel(
@@ -158,6 +174,7 @@ def _int8_kernel(
     batch_size: int,
     q_rep: int = 1,
     tree=None,
+    split_kv: bool = False,
 ):
     """One grid step per BATCH ROW, all kv heads + k and v together.
 
@@ -199,7 +216,7 @@ def _int8_kernel(
 
     def copies(bb, i, slot):
         return (_copy_block(tables_ref, layer, kv_hbm, kv_buf, sem, bb, i,
-                            slot, ppcb=ppcb, maxp=maxp)
+                            slot, ppcb=ppcb, maxp=maxp, split_kv=split_kv)
                 + _copy_block(tables_ref, layer, s_hbm, s_buf, sem, bb, i,
                               slot, ppcb=ppcb, maxp=maxp))
 
@@ -288,7 +305,7 @@ def _pages_per_block(maxp: int, want: int) -> int:
 @functools.partial(jax.jit, static_argnames=("scale",
                                              "pages_per_compute_block",
                                              "q_rep", "tree",
-                                             "interpret"))
+                                             "interpret", "split_kv"))
 def paged_attention_int8(
     q: jax.Array,          # [B, H, Hd], or [B, R, H, Hd] when q_rep=R>1
     kv_pages: jax.Array,   # FULL pool [2, L, KH, P, ps, Hd] int8
@@ -303,6 +320,7 @@ def paged_attention_int8(
     q_rep: int = 1,
     tree=None,
     interpret: bool = False,
+    split_kv: bool | None = None,
 ) -> jax.Array:
     """q_rep > 1 is the speculative-verify form: R consecutive query
     positions per sequence ride the kernel's G axis, so the KV pages
@@ -343,9 +361,11 @@ def paged_attention_int8(
     # any vector relayout.
     s2 = kv_scales.reshape(2, L, KH, P, 1, ps)
 
+    if split_kv is None:  # from the pool's shape alone (_copy_block)
+        split_kv = L * KH * P * ps * Hd >= SPLIT_KV_BYTES
     kernel = functools.partial(_int8_kernel, ppcb=ppcb, maxp=maxp,
                                page_size=ps, batch_size=B, q_rep=q_rep,
-                               tree=tree)
+                               tree=tree, split_kv=split_kv)
     qmap = lambda b, Ln, T, LY, BI, IF: (b, 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
