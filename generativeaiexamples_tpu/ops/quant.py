@@ -9,7 +9,15 @@ quantized engines inside NIM; here it's a pytree transform.
 `QuantizedTensor` is a pytree node, so quantized params flow through
 lax.scan stacking, jit, and device_put exactly like plain arrays, and
 `mm(x, w)` dispatches on leaf type — model code never branches.
-XLA fuses the int8->bf16 convert + scale into the matmul's weight read.
+Where the matmul's result is consumed as it is (`wo`, `w_gate`, `w_up`,
+`w_down`: four projections of a block's seven), XLA fuses the
+int8->bf16 convert + scale into the matmul's weight read: one
+`convolution(bf16 x, s8 w)` that streams the codes from HBM. Where the
+consumer is a head split (`wq`, `wk`, `wv` in a decode step), XLA fuses
+THAT into the dot instead and first stages the weight in VMEM in
+another layout, once a block and layer; serving/engine_model.py
+(`direct_qkv`) says where that pays and where the caller keeps the
+consumer out (PERF.md, PR 30).
 """
 
 from __future__ import annotations

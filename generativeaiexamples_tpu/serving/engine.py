@@ -306,6 +306,11 @@ class EngineMetrics:
         # every block once a pass (cfg.cache_rows of them), so
         # layer_passes / decode_steps is the depth a token pays for.
         self.layer_passes = 0
+        # Decode steps dispatched through a program whose q, k and v
+        # projections are plain matmuls (engine_model.direct_qkv: a
+        # short block, or a looped model): the share of decode_steps
+        # that engages it.
+        self.decode_steps_direct_qkv = 0
         # KV pool geometry (set once at engine build): rows of the pool
         # (layers x passes) and the bytes one cached token takes over
         # all rows, scales included.
@@ -465,6 +470,7 @@ class EngineMetrics:
             "tokens_generated": self.tokens_out,
             "decode_steps": self.decode_steps,
             "layer_passes": self.layer_passes,
+            "decode_steps_direct_qkv": self.decode_steps_direct_qkv,
             "kv_cache_rows": self.kv_cache_rows,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "mean_batch_occupancy": occ,
@@ -3180,6 +3186,11 @@ class LLMEngine:
             self._rider_bookkeeping(lp, n_part)
         self.metrics.decode_steps += K
         self.metrics.layer_passes += K * self.cfg.cache_rows
+        # the plain and the fused decode programs choose their form so;
+        # a speculative engine's programs keep the staged one
+        if not (plan.spec_k or plan.spec_state) \
+                and engine_model.direct_qkv(self.cfg, K):
+            self.metrics.decode_steps_direct_qkv += K
         self.metrics.busy_slots_acc += len(active) * K
         if spec_mode:
             for i in active:

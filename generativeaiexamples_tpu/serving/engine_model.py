@@ -100,13 +100,18 @@ def _write_quant_pages(pool, kq, ks, vq, vs, table_flat):
     return QuantPagePool(kv, s, pool.page_size)
 
 
-def _project_qkv(cfg: LlamaConfig, h, w, positions):
+def _project_qkv(cfg: LlamaConfig, h, w, positions, direct=False):
+    """q, k, v as [B, heads, S, Hd], q and k rotated. `direct` (the
+    caller's choice: direct_qkv, below) holds each matmul's result
+    behind an optimization barrier, so that XLA cannot fuse the head
+    split into the dot; same values, another fusion boundary."""
     B, S, _ = h.shape
     H, KH, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    hold = jax.lax.optimization_barrier if direct else (lambda y: y)
     with jax.named_scope("attn.qkv"):  # metadata only, as in llama._layer
-        q = mm(h, w["wq"]).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
-        k = mm(h, w["wk"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
-        v = mm(h, w["wv"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+        q = hold(mm(h, w["wq"])).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
+        k = hold(mm(h, w["wk"])).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+        v = hold(mm(h, w["wv"])).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
     return (rope(q, positions, cfg.rope_theta, cfg.rope_scaling),
             rope(k, positions, cfg.rope_theta, cfg.rope_scaling), v)
 
@@ -149,19 +154,36 @@ def _walk_decode(params, cfg: LlamaConfig, x, pools, body):
     step is 1 % shorter (94.0 against 94.9 ms in a block of one), the
     compile 3.5 times longer (120 against 34 s), and a block of eight
     (1,536 bodies) does not compile in the host's 40 GiB."""
+    from generativeaiexamples_tpu.ops.quant import QuantizedTensor
+
     L = cfg.n_layers
+
+    def vector(t, l):
+        """Block l's slice of a per-layer vector: a norm's gains, or
+        an int8 weight's scales in the type mm's epilogue reads."""
+        return (t.s[l].astype(cfg.dtype) if isinstance(t, QuantizedTensor)
+                else t[l])
+
+    # Inside the pass loop XLA re-runs every such slice and convert in
+    # every pass (1,700 tiny operations a step at Ouro-2.6B's depth: 0.7 %
+    # of it, PERF.md, PR 30), so a looped model takes them here, once,
+    # as the loop's constants. The int8 codes stay operands of their dots.
+    hoist = cfg.n_passes > 1 and _UNROLL_DECODE
+    vectors = [{k2: vector(v2, l) for k2, v2 in params["layers"].items()
+                if hoist and (isinstance(v2, QuantizedTensor) or v2.ndim == 2)}
+               for l in range(L)]
 
     def run_pass(x, pools, row0):
         if _UNROLL_DECODE:
-            from generativeaiexamples_tpu.ops.quant import QuantizedTensor
-
-            def take(t, l):
+            def take(t, l, held):
                 if isinstance(t, QuantizedTensor):
-                    return QuantizedTensor(t.q[l], t.s[l])
-                return t[l]
+                    return QuantizedTensor(
+                        t.q[l], t.s[l] if held is None else held)
+                return t[l] if held is None else held
 
             for l in range(L):
-                w = {k2: take(v2, l) for k2, v2 in params["layers"].items()}
+                w = {k2: take(v2, l, vectors[l].get(k2))
+                     for k2, v2 in params["layers"].items()}
                 x, pools = body(x, pools, w, row0 + l)
         else:
             def scan_body(carry, wl):
@@ -329,16 +351,51 @@ def set_last_tokens(last_tokens: jax.Array, idxs: jax.Array,
 
 import os
 
-# Layer-loop strategy for the decode step. Unrolled (default) lets XLA
-# fuse each layer's weight-stack slice directly into its matmul instead
-# of materializing per-iteration copies of the sliced operands, which
-# dominates decode time at small batch; scan compiles faster (useful on
-# the CPU test backend). Env knob for benchmarking both.
+# Layer-loop strategy for the decode step. Unrolled (default), a layer's
+# slice of the weight stack is an operand of its matmul: `wo`, `w_gate`,
+# `w_up` and `w_down` are each one `convolution(bf16 x, s8 w)` that
+# streams the codes from the stacked parameter where they lie; scanned
+# (faster to compile: the CPU test backend), every iteration first copies
+# its slices out. Unrolling does NOT avoid that for `wq`, `wk` and `wv`
+# in the form XLA prefers (the head split fused into the dot, below): it
+# only moves their staging in front of the block's first step. Env knob
+# for benchmarking both.
 _UNROLL_DECODE = os.environ.get("ENGINE_UNROLL_DECODE", "1") != "0"
 
 
+# The q, k and v projections of a decode step, two forms (_project_qkv).
+# Staged: the reshape, transpose and rotary embedding that consume a
+# projection are fused into its dot, which XLA then rewrites as a
+# per-head product with the weight in VMEM, contraction-minor; so once a
+# block, for every layer, a `slice_bitcast_fusion` slices `wq`/`wk`/`wv`
+# out of the stack into VMEM transposed, a `copy` turns it back and the
+# result goes to HBM for the later steps to prefetch. A long block
+# amortises that and its steps 2..K read q/k/v weights from VMEM. Direct:
+# an `optimization_barrier` keeps the consumer out, and each projection
+# is a plain [B, d] x [d, n] matmul that streams its int8 weight from
+# HBM, as `wo` does. A looped model's pass loop (`lax.fori_loop`) can
+# prefetch nothing across steps and stages inside EVERY pass, so it
+# always takes the direct form; a one-pass model takes it in blocks of up
+# to DIRECT_QKV_MAX_STEPS steps. The constant is from device time a step
+# of both forms at Mistral-7B's widths on a v5e, 64 slots (PERF.md, PR
+# 30, the `K*` table; scripts/measure_qkv_forms.py measures it again):
+# staging and copies cost 3.4 ms a BLOCK whatever its length, streaming
+# q, k and v 0.59 ms a step more than reading them from VMEM, so the
+# direct form wins by 2.61, 1.28 and 0.39 ms a step in blocks of 1, 2
+# and 4 and loses 0.18 in a block of 8. The compiled forms are pinned in
+# tests/test_chip_compile.py. The choice reads the program's static
+# arguments and nothing else.
+DIRECT_QKV_MAX_STEPS = 4
+
+
+def direct_qkv(cfg: LlamaConfig, n_steps: int) -> bool:
+    """Whether a decode program of `n_steps` steps takes the direct
+    form of the q, k and v projections."""
+    return cfg.n_passes > 1 or n_steps <= DIRECT_QKV_MAX_STEPS
+
+
 def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
-                 lengths, use_pallas, mesh=None):
+                 lengths, use_pallas, mesh=None, direct=False):
     """One decode iteration, write-then-attend: each layer scatters the
     current token's k/v into its pool slice, then paged attention runs
     over the updated pool with `lengths` INCLUDING the current token.
@@ -359,7 +416,7 @@ def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
 
     def body(x, pools, w, l):
         h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        q, k, v = _project_qkv(cfg, h, w, positions)  # [B, *, 1, Hd]
+        q, k, v = _project_qkv(cfg, h, w, positions, direct)  # [B, *, 1, Hd]
         k_new = k[:, :, 0, :].transpose(1, 0, 2)  # [KH, B, Hd]
         v_new = v[:, :, 0, :].transpose(1, 0, 2)
         if quantized:
@@ -417,7 +474,7 @@ def decode_step(
 ) -> Tuple[jax.Array, PagePool]:
     """One decode step for the whole slot batch -> (logits [B, V], pool)."""
     return _decode_once(params, cfg, pool, tokens, page_tables, lengths,
-                        use_pallas, mesh)
+                        use_pallas, mesh, direct_qkv(cfg, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "n_steps", "use_pallas",
@@ -454,9 +511,11 @@ def decode_multi_step(
     all_greedy, any_top_k, any_top_p = sampling_flags
     tokens = last_tokens
     out_tokens = [tokens]
+    direct = direct_qkv(cfg, n_steps)
     for i in range(n_steps):
         logits, pool = _decode_once(
-            params, cfg, pool, tokens, page_tables, lengths, use_pallas, mesh)
+            params, cfg, pool, tokens, page_tables, lengths, use_pallas, mesh,
+            direct)
         rng, key = jax.random.split(rng)
         nxt = sample(logits, sp, key, all_greedy=all_greedy,
                      any_top_k=any_top_k, any_top_p=any_top_p)
@@ -1196,9 +1255,11 @@ def fused_decode_prefill_step(
     all_greedy, any_top_k, any_top_p = sampling_flags
     tokens = last_tokens
     out_tokens = [tokens]
+    direct = direct_qkv(cfg, n_steps)
     for _ in range(n_steps):
         dlogits, pool = _decode_once(
-            params, cfg, pool, tokens, page_tables, lengths, use_pallas, mesh)
+            params, cfg, pool, tokens, page_tables, lengths, use_pallas, mesh,
+            direct)
         rng, key = jax.random.split(rng)
         nxt = sample(dlogits, sp, key, all_greedy=all_greedy,
                      any_top_k=any_top_k, any_top_p=any_top_p)

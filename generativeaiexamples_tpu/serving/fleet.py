@@ -74,8 +74,8 @@ from generativeaiexamples_tpu.serving.router import PrefixLocalityRouter
 _LOG = logging.getLogger(__name__)
 
 _COUNTER_KEYS = (
-    "tokens_generated", "decode_steps", "layer_passes", "prefill_tokens",
-    "fused_steps",
+    "tokens_generated", "decode_steps", "layer_passes",
+    "decode_steps_direct_qkv", "prefill_tokens", "fused_steps",
     "fused_prefill_tokens", "prefill_stall_beats",
     "fused_sample_dispatches", "prefix_hits",
     "prefix_miss", "prefix_evictions", "prefix_hit_tokens",

@@ -98,3 +98,107 @@ def test_kernel_compiles_for_v5e(chip, name):
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the decode program's q, k and v projections (PR 30) -----------------
+# Where the head split and the rotary embedding are fused into the dot,
+# XLA rewrites a projection as a per-head product and wants its weight in
+# VMEM, contraction-minor: once a block and layer a `fusion` slices the
+# int8 weight out of the stacked parameter in a transposed layout and a
+# `copy` turns it back. A long block of a one-pass model amortises that
+# over its steps; a short block and a looped model's pass loop cannot,
+# and take the direct form (engine_model.direct_qkv): there no `fusion`
+# and no `copy` may yield an int8 array of a weight's size. What the
+# memory-space assignment prefetches by itself (`slice-start`/`-done`,
+# `copy-start`/`-done`, their `ConcatBitcast`: asynchronous, and plentiful
+# at two layers, where VMEM has room for everything) is not staging.
+
+def _decoder(looped):
+    import dataclasses
+
+    from generativeaiexamples_tpu.models import llama
+
+    cfg = llama.LlamaConfig(  # Mistral-7B-v0.3's widths, two layers
+        vocab_size=32768, dim=4096, n_layers=2, n_heads=H, n_kv_heads=KH,
+        head_dim=HD, mlp_dim=14336, rope_theta=1e6, rms_eps=1e-5,
+        max_seq_len=32768, dtype=BF16)
+    if looped:  # Ouro-2.6B's widths, two blocks run twice
+        cfg = dataclasses.replace(
+            cfg, vocab_size=49152, dim=2048, n_heads=16, n_kv_heads=16,
+            mlp_dim=5632, n_passes=2, post_norms=True)
+    return cfg
+
+
+def _staged_weights(text, cfg):
+    """(opcode, instruction, type) of every `fusion` and `copy` of the
+    entry computation and the while bodies that yields an int8 array as
+    large as a q/k/v weight or as the stack of them."""
+    import re
+
+    sizes = {cfg.dim * n * l for n in (cfg.n_heads * HD, cfg.n_kv_heads * HD)
+             for l in (1, cfg.n_layers)}
+    comps, bodies, cur = {}, set(), None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = comps.setdefault(head.group(2), [])
+            if head.group(1):
+                bodies.add(head.group(2))
+        elif cur is not None:
+            cur.append(line)
+            bodies.update(re.findall(r"body=%?([\w.\-]+)", line))
+    found = []
+    for name in bodies:
+        for line in comps[name]:
+            m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) (fusion|copy)\(",
+                         line)
+            if not m:
+                continue
+            for dims in re.findall(r"s8\[([\d,]+)\]", m.group(2)):
+                n = 1
+                for d in dims.split(","):
+                    n *= int(d)
+                if n in sizes:
+                    found.append((m.group(3), m.group(1), m.group(2)))
+    return found
+
+
+@pytest.mark.parametrize("looped,n_steps", [
+    (False, 1), (False, 2), (False, 8), (True, 2)])
+def test_decode_projections_stage_no_weight_in_a_short_or_looped_block(
+        chip, looped, n_steps):
+    import functools
+
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.serving import engine_model as em
+    from generativeaiexamples_tpu.serving.kv_cache import PagePool
+
+    cfg = _decoder(looped)
+    slots = 32 if looped else B
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=chip), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = on_chip(jax.eval_shape(functools.partial(
+        llama.init_params_on_device, cfg, quantize=True)))
+    pool = on_chip(jax.eval_shape(lambda: PagePool.zeros(
+        cfg, slots * MAXP + 1, PS, dtype=I8)))
+    text = em.decode_multi_step.lower(
+        params, cfg, pool, arr((slots,), I32), arr((slots, MAXP), I32),
+        arr((slots,), I32), arr((slots,), jnp.bool_), arr((slots,), F32),
+        arr((slots,), F32), arr((slots,), I32), arr((2,), jnp.uint32),
+        n_steps, True, sampling_flags=(True, False, False)).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the choice reads the passes and the block's length, nothing else
+    assert em.direct_qkv(cfg, n_steps) == (
+        looped or n_steps <= em.DIRECT_QKV_MAX_STEPS)
+    assert not em.direct_qkv(_decoder(False), 8)  # the long block: as it was
+    staged = _staged_weights(text, cfg)
+    if em.direct_qkv(cfg, n_steps):
+        assert not staged, staged
+    else:
+        assert {op for op, _, _ in staged} == {"fusion", "copy"}, staged

@@ -343,17 +343,65 @@ def test_the_walks_scopes_are_in_both_copies_of_the_block():
         assert scope in contiguous, scope
 
 
+# -- the direct q/k/v projections (engine_model.direct_qkv) ----------------
+
+@pytest.mark.parametrize("looped", [False, True])
+def test_direct_qkv_serves_the_contiguous_forwards_tokens_and_is_counted(
+        looped):
+    """A tiny int8 model through the engine at blocks of up to 8: the
+    greedy tokens are `llama.forward`'s, whichever form a block's
+    projections took, and `decode_steps_direct_qkv` counts exactly the
+    steps of the blocks that took the direct form: all of a looped
+    model's, and a one-pass model's blocks of up to
+    DIRECT_QKV_MAX_STEPS steps."""
+    cfg = CFG if looped else llama.LlamaConfig.tiny()
+    params = llama.init_params_on_device(cfg, 5, quantize=True)
+    prompt, new = [int(t) for t in IDS[:11]], 14
+    want = list(prompt)
+    for _ in range(new):
+        logits, _ = llama.forward(params, cfg, jnp.asarray([want]))
+        want.append(int(jnp.argmax(logits[0, -1])))
+    eng = LLMEngine(params, cfg, ByteTokenizer(), dataclasses.replace(
+        ECFG, decode_steps_per_dispatch=8, kv_dtype="float32"))
+    blocks, exec_plan = [], eng._exec_plan
+
+    def recording(rec):
+        blocks.append(int(rec["plan_decode_k"]))
+        return exec_plan(rec)
+
+    eng._exec_plan = recording
+    eng.start()
+    try:
+        served = [ev["token_id"] for ev in eng.generate_stream(
+            prompt, max_new_tokens=new) if ev["token_id"] >= 0]
+        snap = eng.metrics.snapshot()
+    finally:
+        eng.stop()
+    assert served == want[len(prompt):]
+    assert max(blocks) == 8 and min(blocks) <= 2, blocks
+    assert snap["decode_steps"] == sum(blocks)
+    assert snap["decode_steps_direct_qkv"] == sum(
+        k for k in blocks if looped or k <= em.DIRECT_QKV_MAX_STEPS)
+    assert em.direct_qkv(cfg, 8) == looped
+
+
 # -- a one-pass model is the program it always was ------------------------
 # sha256 (first 16 hex digits) of the lowered StableHLO of a tiny Llama's
 # step programs, taken on the parent of the PR that added the walk (PR
 # 29) and unchanged by it. A PR that means to change a step program of
 # the Llama block regenerates these (the loop below prints them on a
-# mismatch); a PR that adds a family must not have to.
+# mismatch); a PR that adds a family must not have to. PR 30 moved four
+# on purpose: `decode_step.*` and `decode_multi_step.*` (lowered at 2
+# steps) now hold the optimization barrier of the direct q/k/v form
+# (engine_model.direct_qkv); `decode_multi_step_k8.*`, the long block,
+# were taken on PR 30's PARENT and must not move with it.
 LLAMA_PROGRAMS = {
-    "decode_multi_step.float32": "feba2aae6421e258",
-    "decode_multi_step.int8": "278835185dd2aea9",
-    "decode_step.float32": "126a81ca100274c8",
-    "decode_step.int8": "fa6702c901382515",
+    "decode_multi_step.float32": "4715aca7b36c9762",
+    "decode_multi_step.int8": "a5a299d10d5be839",
+    "decode_multi_step_k8.float32": "3c7bd6c362e3c342",
+    "decode_multi_step_k8.int8": "2cb68fb5cdd29be4",
+    "decode_step.float32": "5d5d387a4ddc2a80",
+    "decode_step.int8": "9ee0516f7403b667",
     "prefill_batch_step.float32": "6ccb880ce282653a",
     "prefill_batch_step.int8": "dd7d84802127b149",
     "prefill_step.float32": "3bd7b8f722eb93dd",
@@ -378,10 +426,12 @@ def test_a_tiny_llamas_step_programs_lower_to_the_text_they_did():
     lowered = {}
     for dt in ("float32", "int8"):
         pool = PagePool.zeros(cfg, 9, PS, dtype=jnp.dtype(dt))
-        lowered[f"decode_multi_step.{dt}"] = em.decode_multi_step.lower(
-            params, cfg, pool, i32(B), i32(B, maxp), i32(B) + 1,
-            jnp.ones((B,), bool), f32(B), f32(B), i32(B), key, 2, False,
-            sampling_flags=greedy)
+        for name, n_steps in (("decode_multi_step", 2),
+                              ("decode_multi_step_k8", 8)):
+            lowered[f"{name}.{dt}"] = em.decode_multi_step.lower(
+                params, cfg, pool, i32(B), i32(B, maxp), i32(B) + 1,
+                jnp.ones((B,), bool), f32(B), f32(B), i32(B), key, n_steps,
+                False, sampling_flags=greedy)
         lowered[f"decode_step.{dt}"] = em.decode_step.lower(
             params, cfg, pool, i32(B), i32(B, maxp), i32(B) + 1, False)
         lowered[f"prefill_batch_step.{dt}"] = em.prefill_batch_step.lower(
