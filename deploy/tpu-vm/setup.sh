@@ -8,7 +8,7 @@
 # Usage:
 #   ./setup.sh create   # create the TPU VM
 #   ./setup.sh install  # install the framework + systemd units on the VM
-#   ./setup.sh bench    # run bench.py on the VM
+#   ./setup.sh bench [cell]  # run one cell of the benchmark on the VM (benchmark/README.md)
 set -euo pipefail
 
 TPU_NAME="${TPU_NAME:-gaie-tpu-v5e}"
@@ -44,12 +44,12 @@ install() {
 }
 
 bench() {
-  run_on_vm "cd gaie-tpu && python bench.py"
+  run_on_vm "cd gaie-tpu && python3 benchmark/run.py --workload $1 --seed 1 --seconds 45 --trace 0"
 }
 
 case "${1:-}" in
   create) create ;;
   install) install ;;
-  bench) bench ;;
-  *) echo "usage: $0 {create|install|bench}" >&2; exit 2 ;;
+  bench) bench "${2:-mistral7b.decode-closed64}" ;;
+  *) echo "usage: $0 {create|install|bench [cell]}" >&2; exit 2 ;;
 esac

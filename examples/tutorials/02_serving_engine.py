@@ -4,7 +4,7 @@
 # What the reference outsources to NIM/TRT-LLM, driven directly:
 # continuous batching, paged KV cache, device-side sampling. Runs on
 # the CPU backend with a tiny model so it executes anywhere; the same
-# code serves llama3-8b int8 on a v5e (see `bench.py`).
+# code serves Mistral-7B int8 on a v5e (`benchmark/README.md`).
 
 # %%
 import os

@@ -406,25 +406,57 @@ def walk_passes(cfg: LlamaConfig, params: Params, x, run_pass, state=None,
     return x, state, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs)
 
 
+# The transformer block, in two halves around the attention a caller
+# supplies: `_layer` below (the contiguous cache) and every paged step
+# program of serving/engine_model.py are written with them, so a named
+# scope, a fusion boundary or the next architecture's change to the
+# block is written once. The named scopes are metadata only: they name
+# the matmuls in a profile's op metadata and change no compiled program
+# and no compile-cache key.
+
+
+def project_qkv(cfg: LlamaConfig, h, w, positions, direct=False):
+    """The block up to its attention: q, k, v of the normed stream `h`
+    as [B, heads, S, Hd], q and k rotated. `direct` (the caller's
+    choice: engine_model.direct_qkv) holds each matmul's result behind
+    an optimization barrier, so that XLA cannot fuse the head split
+    into the dot; same values, another fusion boundary."""
+    B, S, _ = h.shape
+    H, KH, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    hold = jax.lax.optimization_barrier if direct else (lambda y: y)
+    with jax.named_scope("attn.qkv"):
+        q = hold(mm(h, w["wq"])).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
+        k = hold(mm(h, w["wk"])).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+        v = hold(mm(h, w["wv"])).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
+    return (rope(q, positions, cfg.rope_theta, cfg.rope_scaling),
+            rope(k, positions, cfg.rope_theta, cfg.rope_scaling), v)
+
+
+def finish_block(cfg: LlamaConfig, x, out, w):
+    """The block from its attention's output `out` [B, H, S, Hd] on:
+    the output projection and the feed-forward, each added to the
+    stream `x` (add_branch)."""
+    B, S, _ = x.shape
+    with jax.named_scope("attn.out"):
+        x = add_branch(
+            cfg, x, mm(out.transpose(0, 2, 1, 3).reshape(B, S, -1), w["wo"]),
+            w, "ln1_post", "attn.post_norm")
+    h = rms_norm(x, w["ln2"], cfg.rms_eps).astype(cfg.dtype)
+    with jax.named_scope("mlp.gate_up"):
+        h = jax.nn.silu(mm(h, w["w_gate"])) * mm(h, w["w_up"])
+    with jax.named_scope("mlp.down"):
+        return add_branch(cfg, x, mm(h, w["w_down"]), w, "ln2_post",
+                          "mlp.post_norm")
+
+
 def _layer(cfg: LlamaConfig, x, w, positions, kv, kv_lengths, attn_lengths,
            causal, q_offset, use_pallas, mesh=None):
     """One transformer block. x [B,S,D]; w: this block's weights. kv:
     (k_cache, v_cache) for this cache row ([B,KH,S_max,Hd]) or None.
     Returns (x_out, new_kv)."""
-    B, S, D = x.shape
-    H, KH, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-
-    # The named scopes around the weight matmuls (here, in forward() and
-    # in serving/engine_model.py's copies of this block) are metadata
-    # only: they name the matmuls in a profile's op metadata and change
-    # no compiled program and no compile-cache key.
+    B, S, _ = x.shape
     h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-    with jax.named_scope("attn.qkv"):
-        q = mm(h, w["wq"]).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
-        k = mm(h, w["wk"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
-        v = mm(h, w["wv"]).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
-    q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-    k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+    q, k, v = project_qkv(cfg, h, w, positions)
 
     if kv is None:
         out = attn_ops.attention(q, k, v, causal=causal, lengths=attn_lengths,
@@ -441,18 +473,7 @@ def _layer(cfg: LlamaConfig, x, w, positions, kv, kv_lengths, attn_lengths,
                                  lengths=attn_lengths, q_offset=q_offset,
                                  use_pallas=use_pallas, mesh=mesh)
         new_kv = (kc, vc)
-
-    out = out.transpose(0, 2, 1, 3).reshape(B, S, H * Hd)
-    with jax.named_scope("attn.out"):
-        x = add_branch(cfg, x, mm(out, w["wo"]), w, "ln1_post",
-                       "attn.post_norm")
-    h = rms_norm(x, w["ln2"], cfg.rms_eps).astype(cfg.dtype)
-    with jax.named_scope("mlp.gate_up"):
-        h = jax.nn.silu(mm(h, w["w_gate"])) * mm(h, w["w_up"])
-    with jax.named_scope("mlp.down"):
-        x = add_branch(cfg, x, mm(h, w["w_down"]), w, "ln2_post",
-                       "mlp.post_norm")
-    return x, new_kv
+    return finish_block(cfg, x, out, w), new_kv
 
 
 def forward(

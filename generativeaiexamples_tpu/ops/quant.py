@@ -57,45 +57,9 @@ def quantize_tensor(w: jax.Array, contract_axis: int = -2) -> QuantizedTensor:
     return QuantizedTensor(q, jnp.squeeze(s, axis=contract_axis))
 
 
-# When True, 2D QuantizedTensor matmuls go through the Pallas
-# int8-dequant kernel (ops/int8_matmul.py) — the XLA convert+dot path
-# reads int8 weights at bf16-weight speed, wasting the bandwidth the
-# quantization exists to save. Enabled by the serving engine on
-# single-device TPU (under a TP mesh the kernel would need shard_map;
-# GSPMD handles the XLA path there).
-_PALLAS_INT8_MM = False
-
-
-def set_pallas_int8_matmul(enabled: bool) -> None:
-    global _PALLAS_INT8_MM
-    _PALLAS_INT8_MM = bool(enabled)
-
-
-def _mm_quantized_pallas(x: jax.Array, w: "QuantizedTensor") -> jax.Array:
-    from generativeaiexamples_tpu.ops.int8_matmul import int8_matmul
-
-    lead = x.shape[:-1]
-    rows = 1
-    for d in lead:
-        rows *= d
-    x2 = x.reshape(rows, x.shape[-1])
-    pad = (-rows) % 8
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    y = int8_matmul(x2, w.q, w.s)
-    if pad:
-        y = y[:rows]
-    return y.reshape(*lead, w.q.shape[-1])
-
-
 def mm(x: jax.Array, w) -> jax.Array:
     """x @ w where w is a plain array or a QuantizedTensor."""
     if isinstance(w, QuantizedTensor):
-        if _PALLAS_INT8_MM and w.q.ndim == 2:
-            try:
-                return _mm_quantized_pallas(x, w)
-            except (ValueError, RuntimeError):
-                pass  # untileable shape: XLA path below
         y = x @ w.q.astype(x.dtype)
         return y * w.s.astype(x.dtype)
     return x @ w

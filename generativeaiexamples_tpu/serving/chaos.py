@@ -1,13 +1,13 @@
 """Chaos harness: seeded fault injection for the serving fleet.
 
-BENCH_FLEET/BENCH_QOS replay traffic against a STATIC, HEALTHY
+Fleet and QoS load replays drive traffic against a STATIC, HEALTHY
 topology — which proves peak behavior and nothing about the
 operational story. This module injects the production failure shapes
 into a live fleet, on a schedule, deterministically (seeded RNG, fixed
 event times), so the trace harness (serving/qos.py
 run_trace_on_engine) can measure the goodput FLOOR through a replica
 kill, a probe blackhole, a slow replica, and submit-time faults —
-the BENCH_CHAOS scenario and scripts/smoke_chaos.py CPU gate.
+the scripts/smoke_chaos.py CPU gate.
 
 Injector kinds (ChaosEvent.kind):
 
@@ -247,7 +247,7 @@ def run_chaos_trace(fleet: EngineFleet, trace, events: Sequence[ChaosEvent],
                     time_scale: float = 1.0, seed: int = 0,
                     timeout_s: float = 300.0):
     """Replay a qos.bursty_trace-style trace against a fleet WHILE a
-    chaos schedule fires (the BENCH_CHAOS inner loop). Returns
+    chaos schedule fires. Returns
     (results, monkey) — results in run_trace_on_engine's shape, the
     monkey carrying stats + the "chaos" flight lane. The undo-scaled
     clock matches the trace clock, so an event at t=1.0 lands mid-

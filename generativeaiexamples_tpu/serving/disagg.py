@@ -88,11 +88,9 @@ def page_geometry(pool) -> Tuple[tuple, np.dtype, Optional[tuple]]:
     """(codes_shape, codes_dtype, scales_shape|None) of ONE page of
     `pool` in pool_to_pages' page-major layout — the shared contract
     between export, import, the KV pager and the wire format."""
-    if pool.quantized:
-        _, L, KH, _, ps, Hd = pool.kv.shape
-        return (2, L, KH, ps, Hd), np.dtype(np.int8), (2, L, KH, ps)
-    L, KH, _, ps, Hd = pool.k.shape
-    return (2, L, KH, ps, Hd), np.dtype(pool.k.dtype), None
+    g = pool.geometry
+    codes = (2, g.rows, g.kv_heads, g.page_size, g.head_dim)
+    return codes, np.dtype(g.dtype), codes[:-1] if pool.quantized else None
 
 
 def _resolve_dtype(name: str) -> np.dtype:

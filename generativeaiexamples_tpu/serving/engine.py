@@ -440,8 +440,7 @@ class EngineMetrics:
         prefill ramp before the first emission and the final drain; on
         a saturated steady state the two agree, on a short burst the
         gauge reads a few percent higher (r4 VERDICT weak #6 — the two
-        meters measured different things, both correctly). bench.py
-        prints both with this provenance."""
+        meters measured different things, both correctly)."""
         window_s = window_s or self.RATE_WINDOW_S
         now = time.perf_counter()
         cutoff = now - window_s
@@ -635,18 +634,6 @@ class LLMEngine:
             self._mh_log = mh.DispatchLog()
             self._mh_leader = jax.process_index() == 0
         self._mh_stop_sent = False
-        # Experimental opt-in: int8 weights through the Pallas
-        # dequant-matmul kernel. Measured on v5e (llama3-8b int8, B=64):
-        # XLA path 1811 tok/s vs kernel 1424-1458 — XLA's convert+dot
-        # already saturates this platform's effective HBM bandwidth, so
-        # the kernel stays off by default. Set EXPLICITLY (true or
-        # false) per engine so a TP engine built after a single-device
-        # one never traces through the unsupported-under-GSPMD path.
-        from generativeaiexamples_tpu.ops.quant import set_pallas_int8_matmul
-
-        set_pallas_int8_matmul(
-            self.mesh is None and jax.default_backend() == "tpu"
-            and os.environ.get("ENGINE_PALLAS_INT8", "0") == "1")
         _refuse_unwalked_lanes(cfg, self.ecfg)
         ps = self.ecfg.page_size
         if self.ecfg.max_seq_len < ps:

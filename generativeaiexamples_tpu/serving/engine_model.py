@@ -1,8 +1,9 @@
 """Paged llama forward: the jitted prefill/decode steps of the engine.
 
-Mirrors models.llama's transformer block (rms_norm/rope/mm are imported
-from there; the block math must stay in lockstep — tests assert paged
-forward == contiguous forward) but reads/writes the serving PagePool:
+models.llama's transformer block (its two halves, project_qkv and
+finish_block, around the attention each step supplies; tests assert
+paged forward == contiguous forward) over the serving PagePool, whose
+layout only serving/kv_cache.py and the attention kernels know:
 
 - `prefill_step`: one sequence at a bucketed length S; causal flash
   attention over the prompt; k/v written into the sequence's pages
@@ -18,6 +19,7 @@ Both are shape-stable: prefill compiles once per bucket, decode once per
 from __future__ import annotations
 
 import functools
+import os
 import time
 from typing import NamedTuple, Optional, Tuple
 
@@ -25,10 +27,11 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models.llama import (
-    LlamaConfig, add_branch, final_norm, rms_norm, rope, walk_passes)
+    LlamaConfig, final_norm, finish_block, project_qkv, rms_norm,
+    walk_passes)
 from generativeaiexamples_tpu.ops import attention as attn_ops
 from generativeaiexamples_tpu.ops.quant import mm
-from generativeaiexamples_tpu.serving.kv_cache import PagePool
+from generativeaiexamples_tpu.serving.kv_cache import PagePool, token_slots
 from generativeaiexamples_tpu.serving.paged_attention import (
     paged_attention_dispatch)
 from generativeaiexamples_tpu.utils.platform import log_kernel_declined
@@ -50,86 +53,6 @@ def _replicate_tokens(mesh, *arrs):
     return out if len(out) > 1 else out[0]
 
 
-def _page_axes(L, KH, table_flat):
-    li = jnp.arange(L)[:, None, None]
-    kh = jnp.arange(KH)[None, :, None]
-    return li, kh, table_flat[None, None, :]
-
-
-def _write_prefill_pages(pool, kw, vw, table_flat):
-    """Scatter page-shaped prefill k/v (canonical layout
-    [L, KH, M, ps, Hd], pages flattened across the group) into the
-    pool; int8 pools quantize per (kv-head, token) row with narrow
-    scales and write into the fused pool
-    (serving/paged_attention_int8.py, kv_cache.QuantPagePool).
-
-    ALL advanced indices are contiguous from axis 0 ([li, kh, pages] /
-    [0, li, kh, pages]) — the old bracketed form `at[li, :, pages]`
-    made XLA materialize a full copy of the donated pool once the
-    group had >1 row, which is +3.3 GB HBM at the B=128 deployment
-    shape and an OOM at long-context pool sizes."""
-    L, KH = kw.shape[:2]
-    li, kh, tb = _page_axes(L, KH, table_flat)
-    if pool.quantized:
-        from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-            quantize_kv)
-
-        kq, ks = quantize_kv(kw, scale_dtype=pool.s.dtype)
-        vq, vs = quantize_kv(vw, scale_dtype=pool.s.dtype)
-        return _write_quant_pages(pool, kq, ks, vq, vs, table_flat)
-    return PagePool(pool.k.at[li, kh, tb].set(kw.astype(pool.k.dtype)),
-                    pool.v.at[li, kh, tb].set(vw.astype(pool.v.dtype)),
-                    pool.page_size)
-
-
-def _write_quant_pages(pool, kq, ks, vq, vs, table_flat):
-    """Scatter pre-quantized page-shaped k/v codes ([L, KH, M, ps, Hd])
-    + narrow scales ([L, KH, M, ps]) into the fused pool. TWO scatters
-    (k then v) with a scalar leading index: a single stacked [2, ...]
-    update drives XLA to a transposed pool layout whose conversion
-    copies the whole 3 GB pool (OOM); separate scatters with contiguous
-    advanced indices keep the natural layout and alias in place."""
-    from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
-
-    L, KH = kq.shape[:2]
-    li, kh, tb = _page_axes(L, KH, table_flat)
-    kv = pool.kv.at[0, li, kh, tb].set(kq)
-    kv = kv.at[1, li, kh, tb].set(vq)
-    s = pool.s.at[0, li, kh, tb].set(ks)
-    s = s.at[1, li, kh, tb].set(vs)
-    return QuantPagePool(kv, s, pool.page_size)
-
-
-def _project_qkv(cfg: LlamaConfig, h, w, positions, direct=False):
-    """q, k, v as [B, heads, S, Hd], q and k rotated. `direct` (the
-    caller's choice: direct_qkv, below) holds each matmul's result
-    behind an optimization barrier, so that XLA cannot fuse the head
-    split into the dot; same values, another fusion boundary."""
-    B, S, _ = h.shape
-    H, KH, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    hold = jax.lax.optimization_barrier if direct else (lambda y: y)
-    with jax.named_scope("attn.qkv"):  # metadata only, as in llama._layer
-        q = hold(mm(h, w["wq"])).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
-        k = hold(mm(h, w["wk"])).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
-        v = hold(mm(h, w["wv"])).reshape(B, S, KH, Hd).transpose(0, 2, 1, 3)
-    return (rope(q, positions, cfg.rope_theta, cfg.rope_scaling),
-            rope(k, positions, cfg.rope_theta, cfg.rope_scaling), v)
-
-
-def _finish_block(cfg: LlamaConfig, x, out, w):
-    B, S, _ = x.shape
-    with jax.named_scope("attn.out"):
-        x = add_branch(
-            cfg, x, mm(out.transpose(0, 2, 1, 3).reshape(B, S, -1), w["wo"]),
-            w, "ln1_post", "attn.post_norm")
-    h = rms_norm(x, w["ln2"], cfg.rms_eps).astype(cfg.dtype)
-    with jax.named_scope("mlp.gate_up"):
-        h = jax.nn.silu(mm(h, w["w_gate"])) * mm(h, w["w_up"])
-    with jax.named_scope("mlp.down"):
-        return add_branch(cfg, x, mm(h, w["w_down"]), w, "ln2_post",
-                          "mlp.post_norm")
-
-
 def _scanned_pass(params, body):
     """A prefill's `run_pass` for llama.walk_passes: one scan of
     `body(x, w) -> (x, this row's k/v)` over the stacked blocks. The
@@ -141,15 +64,21 @@ def _scanned_pass(params, body):
     return run_pass
 
 
-def _walk_decode(params, cfg: LlamaConfig, x, pools, body):
-    """The decode family's walk: `body(x, pools, w, row) -> (x, pools)`
+def _walk_decode(params, cfg: LlamaConfig, x, pool, body):
+    """The decode family's walk: `body(x, pool, w, row) -> (x, pool)`
     for every (pass, block) llama.walk_passes names, block l's weights
-    with cache row row0 + l, the blocks of a pass unrolled or scanned
-    (_UNROLL_DECODE).
+    with cache row row0 + l.
+
+    The blocks of a pass are UNROLLED: a layer's slice of the weight
+    stack is then an operand of its matmul (`wo`, `w_gate`, `w_up` and
+    `w_down` are each one `convolution(bf16 x, s8 w)` that streams the
+    codes from the stacked parameter where they lie), where a scan
+    copies every layer's slices out in every step. For `wq`, `wk` and
+    `wv` that takes the direct form too (direct_qkv, below).
 
     The passes of a looped model are a `lax.fori_loop` around the
     blocks (the row is then traced: the int8 kernel takes it as a
-    scalar, the scatters as a dynamic index). Measured on a v5e at
+    scalar, the pool's append as a dynamic index). Measured on a v5e at
     Ouro-2.6B's sizes (PERF.md, PR 29): with the passes unrolled too a
     step is 1 % shorter (94.0 against 94.9 ms in a block of one), the
     compile 3.5 times longer (120 against 34 s), and a block of eight
@@ -168,36 +97,25 @@ def _walk_decode(params, cfg: LlamaConfig, x, pools, body):
     # every pass (1,700 tiny operations a step at Ouro-2.6B's depth: 0.7 %
     # of it, PERF.md, PR 30), so a looped model takes them here, once,
     # as the loop's constants. The int8 codes stay operands of their dots.
-    hoist = cfg.n_passes > 1 and _UNROLL_DECODE
+    hoist = cfg.n_passes > 1
     vectors = [{k2: vector(v2, l) for k2, v2 in params["layers"].items()
                 if hoist and (isinstance(v2, QuantizedTensor) or v2.ndim == 2)}
                for l in range(L)]
 
-    def run_pass(x, pools, row0):
-        if _UNROLL_DECODE:
-            def take(t, l, held):
-                if isinstance(t, QuantizedTensor):
-                    return QuantizedTensor(
-                        t.q[l], t.s[l] if held is None else held)
-                return t[l] if held is None else held
+    def take(t, l, held):
+        if isinstance(t, QuantizedTensor):
+            return QuantizedTensor(t.q[l], t.s[l] if held is None else held)
+        return t[l] if held is None else held
 
-            for l in range(L):
-                w = {k2: take(v2, l, vectors[l].get(k2))
-                     for k2, v2 in params["layers"].items()}
-                x, pools = body(x, pools, w, row0 + l)
-        else:
-            def scan_body(carry, wl):
-                x, pools = carry
-                w, row = wl
-                return body(x, pools, w, row), None
+    def run_pass(x, pool, row0):
+        for l in range(L):
+            w = {k2: take(v2, l, vectors[l].get(k2))
+                 for k2, v2 in params["layers"].items()}
+            x, pool = body(x, pool, w, row0 + l)
+        return x, pool, None
 
-            (x, pools), _ = jax.lax.scan(
-                scan_body, (x, pools),
-                (params["layers"], jnp.arange(row0, row0 + L)))
-        return x, pools, None
-
-    x, pools, _ = walk_passes(cfg, params, x, run_pass, pools, rolled=True)
-    return x, pools
+    x, pool, _ = walk_passes(cfg, params, x, run_pass, pool, rolled=True)
+    return x, pool
 
 
 def _logits(cfg: LlamaConfig, params, x):
@@ -237,21 +155,19 @@ def prefill_step(
 
     def body(x, w):
         h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        q, k, v = _project_qkv(cfg, h, w, positions)
+        q, k, v = project_qkv(cfg, h, w, positions)
         out = attn_ops.attention(q, k, v, causal=True, lengths=lengths,
                                  use_pallas=use_pallas, mesh=mesh)
-        x = _finish_block(cfg, x, out, w)
+        x = finish_block(cfg, x, out, w)
         return x, (k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2))  # [S,KH,Hd]
 
     x, _, (k_stack, v_stack) = walk_passes(cfg, params, x,
                                            _scanned_pass(params, body))
-    # [R, S, KH, Hd] -> canonical pages [R, KH, npages, ps, Hd]; scatter
-    # once into the [R, KH, P, ps, Hd] pool with contiguous advanced
-    # indices (see _write_prefill_pages).
+    # [R, S, KH, Hd] -> page-shaped [R, KH, npages, ps, Hd], written once.
     L = k_stack.shape[0]
     kw = k_stack.reshape(L, npages, ps, KH, Hd).transpose(0, 3, 1, 2, 4)
     vw = v_stack.reshape(L, npages, ps, KH, Hd).transpose(0, 3, 1, 2, 4)
-    pool = _write_prefill_pages(pool, kw, vw, table_row)
+    pool = pool.write_pages(pool.encode_pages(kw, vw), table_row)
     last = jnp.take_along_axis(
         x, (length - 1).reshape(1, 1, 1).astype(jnp.int32), axis=1)  # [1,1,D]
     logits = _logits(cfg, params, last)[0, 0]
@@ -290,28 +206,20 @@ def prefill_batch_step(
     npages = S // ps
     KH, Hd = cfg.n_kv_heads, cfg.head_dim
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (N, S))
-    quantized = pool.quantized
-    if quantized:
-        from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-            quantize_kv)
 
     x = params["tok_emb"][tokens].astype(cfg.residual_dtype)
 
     def body(x, w):
         h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        q, k, v = _project_qkv(cfg, h, w, positions)
+        q, k, v = project_qkv(cfg, h, w, positions)
         out = attn_ops.attention(q, k, v, causal=True, lengths=lengths,
                                  use_pallas=use_pallas, mesh=mesh)
-        x = _finish_block(cfg, x, out, w)
-        k_t = k.transpose(0, 2, 1, 3)  # [N, S, KH, Hd]
-        v_t = v.transpose(0, 2, 1, 3)
-        if quantized:
-            # Quantize INSIDE the scan: the stacked bf16 k/v ([L, N, S,
-            # KH, Hd] x2 — 2.1 GB at the N=128 deployment shape) never
-            # materializes; the scan emits int8 codes + narrow scales.
-            return x, quantize_kv(k_t, scale_dtype=pool.s.dtype) + \
-                quantize_kv(v_t, scale_dtype=pool.s.dtype)
-        return x, (k_t, v_t)
+        x = finish_block(cfg, x, out, w)
+        # Encoded INSIDE the scan: for an int8 pool the stacked bf16 k/v
+        # ([L, N, S, KH, Hd] x2 — 2.1 GB at the N=128 deployment shape)
+        # never materializes; the scan emits int8 codes + narrow scales.
+        return x, pool.encode_pages(  # of [N, S, KH, Hd]
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
 
     x, _, kv_out = walk_passes(cfg, params, x, _scanned_pass(params, body))
     L = cfg.cache_rows
@@ -323,12 +231,7 @@ def prefill_batch_step(
         return t.transpose(*order).reshape(L, KH, N * npages, ps, *rest)
 
     flat_rows = table_rows.reshape(-1)
-    if quantized:
-        kq, ks, vq, vs = (paged(t) for t in kv_out)
-        pool = _write_quant_pages(pool, kq, ks, vq, vs, flat_rows)
-    else:
-        kw, vw = (paged(t) for t in kv_out)
-        pool = _write_prefill_pages(pool, kw, vw, flat_rows)
+    pool = pool.write_pages(tuple(paged(t) for t in kv_out), flat_rows)
     last = jnp.take_along_axis(
         x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
     logits = _logits(cfg, params, last)[:, 0]  # [N, V]
@@ -349,21 +252,7 @@ def set_last_tokens(last_tokens: jax.Array, idxs: jax.Array,
                                     mode="drop")
 
 
-import os
-
-# Layer-loop strategy for the decode step. Unrolled (default), a layer's
-# slice of the weight stack is an operand of its matmul: `wo`, `w_gate`,
-# `w_up` and `w_down` are each one `convolution(bf16 x, s8 w)` that
-# streams the codes from the stacked parameter where they lie; scanned
-# (faster to compile: the CPU test backend), every iteration first copies
-# its slices out. Unrolling does NOT avoid that for `wq`, `wk` and `wv`
-# in the form XLA prefers (the head split fused into the dot, below): it
-# only moves their staging in front of the block's first step. Env knob
-# for benchmarking both.
-_UNROLL_DECODE = os.environ.get("ENGINE_UNROLL_DECODE", "1") != "0"
-
-
-# The q, k and v projections of a decode step, two forms (_project_qkv).
+# The q, k and v projections of a decode step, two forms (llama.project_qkv).
 # Staged: the reshape, transpose and rotary embedding that consume a
 # projection are fused into its dot, which XLA then rewrites as a
 # per-head product with the weight in VMEM, contraction-minor; so once a
@@ -394,72 +283,58 @@ def direct_qkv(cfg: LlamaConfig, n_steps: int) -> bool:
     return cfg.n_passes > 1 or n_steps <= DIRECT_QKV_MAX_STEPS
 
 
+def _decode_rows(params, cfg: LlamaConfig, pool: PagePool, tokens, positions,
+                 slots, attend, direct=False):
+    """One forward of the decode family, write-then-attend: every block
+    appends its new K and V to the pool at `slots` and THEN attends, so
+    `attend(q [B, H, r, Hd], pool, row) -> [B, H, r, Hd]` (the caller's:
+    which kernel, over which rows) sees the rows just written.
+
+    tokens, positions [B, r]; slots = token_slots of the rows' (page,
+    offset), each [B, r] — or [B] where a slot has the one row: plain
+    decode's K and V then go to the pool as [KH, B, Hd], the shape its
+    pinned program has. Returns (logits [B, r, V], pool)."""
+    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)  # [B, r, D]
+
+    def rows(t):  # [B, KH, r, Hd] -> [KH, B, r, Hd], as the slots are shaped
+        if slots.page_idx.ndim == 1:
+            return t[:, :, 0, :].transpose(1, 0, 2)
+        return t.transpose(1, 0, 2, 3)
+
+    def body(x, pool, w, row):
+        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
+        q, k, v = project_qkv(cfg, h, w, positions, direct)
+        pool = pool.append(row, slots, rows(k), rows(v))
+        return finish_block(cfg, x, attend(q, pool, row), w), pool
+
+    x, pool = _walk_decode(params, cfg, x, pool, body)
+    return _logits(cfg, params, x), pool
+
+
 def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
                  lengths, use_pallas, mesh=None, direct=False):
-    """One decode iteration, write-then-attend: each layer scatters the
-    current token's k/v into its pool slice, then paged attention runs
-    over the updated pool with `lengths` INCLUDING the current token.
+    """One decode iteration: the current token's k/v goes to
+    (page_table[len // ps], len % ps), and paged attention runs over
+    the updated pool with `lengths` INCLUDING the current token.
     Returns (logits [B, V], updated pool)."""
     B = tokens.shape[0]
     ps = pool.page_size
     positions = (lengths - 1)[:, None]  # [B, 1]
     page_idx = page_tables[jnp.arange(B), (lengths - 1) // ps]  # [B]
     offset = (lengths - 1) % ps  # [B]
-    kh_idx = jnp.arange(cfg.n_kv_heads)[:, None]  # [KH, 1] -> bcast [KH, B]
+    slots = token_slots(cfg.n_kv_heads, page_idx, offset)
 
-    x = params["tok_emb"][tokens[:, None]].astype(cfg.residual_dtype)  # [B, 1, D]
-    quantized = pool.quantized
-    if quantized:
-        from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
-        from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-            quantize_kv)
+    def attend(q, pool, row):
+        q = q[:, :, 0, :]
+        k_pages, v_pages, k_scales, layer = pool.attention_operands(row)
+        out = paged_attention_dispatch(
+            q, k_pages, v_pages, page_tables, lengths, k_scales=k_scales,
+            layer=layer, use_pallas=use_pallas, mesh=mesh)
+        return out[:, :, None, :]
 
-    def body(x, pools, w, l):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        q, k, v = _project_qkv(cfg, h, w, positions, direct)  # [B, *, 1, Hd]
-        k_new = k[:, :, 0, :].transpose(1, 0, 2)  # [KH, B, Hd]
-        v_new = v[:, :, 0, :].transpose(1, 0, 2)
-        if quantized:
-            kv_pool, s_pool = pools
-            kq, ksc = quantize_kv(k_new, scale_dtype=s_pool.dtype)
-            vq, vsc = quantize_kv(v_new, scale_dtype=s_pool.dtype)
-            # TWO scatters (k then v), all advanced indices adjacent
-            # (scalar kv-index + scalar layer + kh/page/offset) -> plain
-            # in-place scatters with natural layouts; a single stacked
-            # [2, ...] update makes XLA transpose the whole pool (OOM).
-            kv_pool = kv_pool.at[
-                0, l, kh_idx, page_idx[None, :], offset[None, :], :].set(kq)
-            kv_pool = kv_pool.at[
-                1, l, kh_idx, page_idx[None, :], offset[None, :], :].set(vq)
-            s_pool = s_pool.at[
-                0, l, kh_idx, page_idx[None, :], offset[None, :]].set(ksc)
-            s_pool = s_pool.at[
-                1, l, kh_idx, page_idx[None, :], offset[None, :]].set(vsc)
-            out = paged_attention_dispatch(
-                q[:, :, 0, :], kv_pool, None, page_tables, lengths,
-                k_scales=s_pool, layer=l, use_pallas=use_pallas, mesh=mesh)
-            new_pools = (kv_pool, s_pool)
-        else:
-            k_pool, v_pool = pools
-            k_pool = k_pool.at[
-                l, kh_idx, page_idx[None, :], offset[None, :], :].set(
-                k_new.astype(k_pool.dtype))
-            v_pool = v_pool.at[
-                l, kh_idx, page_idx[None, :], offset[None, :], :].set(
-                v_new.astype(v_pool.dtype))
-            out = paged_attention_dispatch(
-                q[:, :, 0, :], k_pool[l], v_pool[l], page_tables, lengths,
-                use_pallas=use_pallas, mesh=mesh)
-            new_pools = (k_pool, v_pool)
-        x = _finish_block(cfg, x, out[:, :, None, :], w)
-        return x, new_pools
-
-    pools = (pool.kv, pool.s) if quantized else (pool.k, pool.v)
-    x, pools = _walk_decode(params, cfg, x, pools, body)
-    logits = _logits(cfg, params, x)[:, 0]
-    if quantized:
-        return logits, QuantPagePool(pools[0], pools[1], ps)
-    return logits, PagePool(pools[0], pools[1], ps)
+    logits, pool = _decode_rows(params, cfg, pool, tokens[:, None], positions,
+                                slots, attend, direct)
+    return logits[:, 0], pool
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "use_pallas", "mesh"),
@@ -582,29 +457,21 @@ def _decode_verify_once(params, cfg: LlamaConfig, pool: PagePool,
     B, r = tokens.shape
     ps = pool.page_size
     maxp = page_tables.shape[1]
-    KH = cfg.n_kv_heads
     offs = jnp.arange(r)[None, :]
     positions = (lengths - 1)[:, None] + offs          # [B, r]
     page_idx = jnp.take_along_axis(
         page_tables, jnp.clip(positions // ps, 0, maxp - 1), axis=1)  # [B,r]
     offset = positions % ps                            # [B, r]
-    kh_idx = jnp.arange(KH)[:, None, None]             # [KH,1,1]
+    slots = token_slots(cfg.n_kv_heads, page_idx, offset)
     flat_tables = jnp.repeat(page_tables, r, axis=0)   # [B*r, maxp]
     flat_lengths = (lengths[:, None] + offs).reshape(-1)  # [B*r]
-
-    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)    # [B, r, D]
-    quantized = pool.quantized
-    if quantized:
-        from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
-        from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-            quantize_kv)
 
     # The fused multi-query kernel streams each sequence's KV pages
     # ONCE for all r positions (folding positions into the batch costs
     # r x the KV traffic and r x the kernel's DMA issues). Single-device
     # TPU with the Pallas-eligible head_dim only; everything else takes
     # the flat-batch path through the normal dispatch.
-    fused_multi = quantized and (
+    fused_multi = pool.quantized and (
         use_pallas if use_pallas is not None
         else jax.default_backend() == "tpu")
     if fused_multi:
@@ -622,63 +489,24 @@ def _decode_verify_once(params, cfg: LlamaConfig, pool: PagePool,
                 "the flat-batch route (r x the KV traffic)", why)
             fused_multi = False
 
-    def body(x, pools, w, l):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        q, k, v = _project_qkv(cfg, h, w, positions)   # [B, *, r, Hd]
-        k_new = k.transpose(1, 0, 2, 3)                # [KH, B, r, Hd]
-        v_new = v.transpose(1, 0, 2, 3)
-        qf = q.transpose(0, 2, 1, 3).reshape(B * r, cfg.n_heads,
-                                             cfg.head_dim)
-        if quantized:
-            kv_pool, s_pool = pools
-            kq, ksc = quantize_kv(k_new, scale_dtype=s_pool.dtype)
-            vq, vsc = quantize_kv(v_new, scale_dtype=s_pool.dtype)
-            kv_pool = kv_pool.at[
-                0, l, kh_idx, page_idx[None], offset[None], :].set(kq)
-            kv_pool = kv_pool.at[
-                1, l, kh_idx, page_idx[None], offset[None], :].set(vq)
-            s_pool = s_pool.at[
-                0, l, kh_idx, page_idx[None], offset[None]].set(ksc)
-            s_pool = s_pool.at[
-                1, l, kh_idx, page_idx[None], offset[None]].set(vsc)
-            if fused_multi:
-                from generativeaiexamples_tpu.serving.paged_attention_int8 \
-                    import paged_attention_int8
+    def attend(q, pool, row):
+        qm = q.transpose(0, 2, 1, 3)                   # [B, r, H, Hd]
+        k_pages, v_pages, k_scales, layer = pool.attention_operands(row)
+        if fused_multi:
+            from generativeaiexamples_tpu.serving.paged_attention_int8 \
+                import paged_attention_int8
 
-                qm = q.transpose(0, 2, 1, 3)  # [B, r, H, Hd]
-                out = paged_attention_int8(
-                    qm, kv_pool, s_pool, page_tables, lengths, l,
-                    q_rep=r)
-                out = out.reshape(B * r, cfg.n_heads, cfg.head_dim)
-            else:
-                out = paged_attention_dispatch(
-                    qf, kv_pool, None, flat_tables, flat_lengths,
-                    k_scales=s_pool, layer=l, use_pallas=use_pallas,
-                    mesh=mesh)
-            new_pools = (kv_pool, s_pool)
+            out = paged_attention_int8(
+                qm, k_pages, k_scales, page_tables, lengths, layer, q_rep=r)
         else:
-            k_pool, v_pool = pools
-            k_pool = k_pool.at[
-                l, kh_idx, page_idx[None], offset[None], :].set(
-                k_new.astype(k_pool.dtype))
-            v_pool = v_pool.at[
-                l, kh_idx, page_idx[None], offset[None], :].set(
-                v_new.astype(v_pool.dtype))
             out = paged_attention_dispatch(
-                qf, k_pool[l], v_pool[l], flat_tables, flat_lengths,
-                use_pallas=use_pallas, mesh=mesh)
-            new_pools = (k_pool, v_pool)
+                qm.reshape(B * r, cfg.n_heads, cfg.head_dim), k_pages,
+                v_pages, flat_tables, flat_lengths, k_scales=k_scales,
+                layer=layer, use_pallas=use_pallas, mesh=mesh)
         out = out.reshape(B, r, cfg.n_heads, cfg.head_dim)
-        out = out.transpose(0, 2, 1, 3)                # [B, H, r, Hd]
-        x = _finish_block(cfg, x, out, w)
-        return x, new_pools
+        return out.transpose(0, 2, 1, 3)               # [B, H, r, Hd]
 
-    pools = (pool.kv, pool.s) if quantized else (pool.k, pool.v)
-    x, pools = _walk_decode(params, cfg, x, pools, body)
-    logits = _logits(cfg, params, x)                   # [B, r, V]
-    if quantized:
-        return logits, QuantPagePool(pools[0], pools[1], ps)
-    return logits, PagePool(pools[0], pools[1], ps)
+    return _decode_rows(params, cfg, pool, tokens, positions, slots, attend)
 
 
 def ngram_tree_draft(history: jax.Array, lengths: jax.Array, t0: jax.Array,
@@ -777,64 +605,25 @@ def _tree_verify_once(params, cfg: LlamaConfig, pool: PagePool,
     B, r = tokens.shape
     ps = pool.page_size
     maxp = page_tables.shape[1]
-    KH = cfg.n_kv_heads
     depth = jnp.asarray(depth, jnp.int32)
     positions = (lengths - 1)[:, None] + depth[None, :]          # [B, r]
     slots = (lengths - 1)[:, None] + jnp.arange(r)[None, :]      # [B, r]
     page_idx = jnp.take_along_axis(
         page_tables, jnp.clip(slots // ps, 0, maxp - 1), axis=1)
     offset = slots % ps
-    kh_idx = jnp.arange(KH)[:, None, None]
+    slots = token_slots(cfg.n_kv_heads, page_idx, offset)
 
-    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)              # [B, r, D]
-    quantized = pool.quantized
-    if quantized:
-        from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
-        from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-            quantize_kv)
+    def attend(q, pool, row):
+        k_pages, v_pages, k_scales, layer = pool.attention_operands(row)
+        if pool.quantized:
+            return paged_tree_attention_int8_dispatch(
+                q, k_pages, k_scales, page_tables, lengths, anc_mask,
+                spec_k, n_branches, layer, use_pallas=use_pallas, mesh=mesh)
+        return paged_tree_attention_dispatch(
+            q, k_pages, v_pages, page_tables, lengths, anc_mask,
+            spec_k, n_branches, use_pallas=use_pallas, mesh=mesh)
 
-    def body(x, pools, w, l):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        q, k, v = _project_qkv(cfg, h, w, positions)   # [B, *, r, Hd]
-        k_new = k.transpose(1, 0, 2, 3)                # [KH, B, r, Hd]
-        v_new = v.transpose(1, 0, 2, 3)
-        if quantized:
-            kv_pool, s_pool = pools
-            kq, ksc = quantize_kv(k_new, scale_dtype=s_pool.dtype)
-            vq, vsc = quantize_kv(v_new, scale_dtype=s_pool.dtype)
-            kv_pool = kv_pool.at[
-                0, l, kh_idx, page_idx[None], offset[None], :].set(kq)
-            kv_pool = kv_pool.at[
-                1, l, kh_idx, page_idx[None], offset[None], :].set(vq)
-            s_pool = s_pool.at[
-                0, l, kh_idx, page_idx[None], offset[None]].set(ksc)
-            s_pool = s_pool.at[
-                1, l, kh_idx, page_idx[None], offset[None]].set(vsc)
-            out = paged_tree_attention_int8_dispatch(
-                q, kv_pool, s_pool, page_tables, lengths, anc_mask,
-                spec_k, n_branches, l, use_pallas=use_pallas, mesh=mesh)
-            new_pools = (kv_pool, s_pool)
-        else:
-            k_pool, v_pool = pools
-            k_pool = k_pool.at[
-                l, kh_idx, page_idx[None], offset[None], :].set(
-                k_new.astype(k_pool.dtype))
-            v_pool = v_pool.at[
-                l, kh_idx, page_idx[None], offset[None], :].set(
-                v_new.astype(v_pool.dtype))
-            out = paged_tree_attention_dispatch(
-                q, k_pool[l], v_pool[l], page_tables, lengths, anc_mask,
-                spec_k, n_branches, use_pallas=use_pallas, mesh=mesh)
-            new_pools = (k_pool, v_pool)
-        x = _finish_block(cfg, x, out, w)              # out [B, H, r, Hd]
-        return x, new_pools
-
-    pools = (pool.kv, pool.s) if quantized else (pool.k, pool.v)
-    x, pools = _walk_decode(params, cfg, x, pools, body)
-    logits = _logits(cfg, params, x)                   # [B, r, V]
-    if quantized:
-        return logits, QuantPagePool(pools[0], pools[1], ps)
-    return logits, PagePool(pools[0], pools[1], ps)
+    return _decode_rows(params, cfg, pool, tokens, positions, slots, attend)
 
 
 def _tree_relocate_commit(pool: PagePool, cfg: LlamaConfig,
@@ -860,33 +649,7 @@ def _tree_relocate_commit(pool: PagePool, cfg: LlamaConfig,
         page_tables, jnp.clip(dst_slot // ps, 0, maxp - 1), axis=1)
     src_off = src_slot % ps
     dst_off = dst_slot % ps
-    if pool.quantized:
-        from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
-
-        L = pool.kv.shape[1]
-        KH = pool.kv.shape[2]
-        kvi = jnp.arange(2)[:, None, None, None, None]
-        li = jnp.arange(L)[None, :, None, None, None]
-        kh = jnp.arange(KH)[None, None, :, None, None]
-        vals = pool.kv[kvi, li, kh, src_pi[None, None, None],
-                       src_off[None, None, None], :]
-        svals = pool.s[kvi, li, kh, src_pi[None, None, None],
-                       src_off[None, None, None]]
-        kv = pool.kv.at[kvi, li, kh, dst_pi[None, None, None],
-                        dst_off[None, None, None], :].set(vals)
-        s = pool.s.at[kvi, li, kh, dst_pi[None, None, None],
-                      dst_off[None, None, None]].set(svals)
-        return QuantPagePool(kv, s, ps)
-    L, KH = pool.k.shape[0], pool.k.shape[1]
-    li = jnp.arange(L)[:, None, None, None]
-    kh = jnp.arange(KH)[None, :, None, None]
-    kvals = pool.k[li, kh, src_pi[None, None], src_off[None, None], :]
-    vvals = pool.v[li, kh, src_pi[None, None], src_off[None, None], :]
-    kp = pool.k.at[li, kh, dst_pi[None, None], dst_off[None, None], :].set(
-        kvals)
-    vp = pool.v.at[li, kh, dst_pi[None, None], dst_off[None, None], :].set(
-        vvals)
-    return PagePool(kp, vp, ps)
+    return pool.move_tokens((src_pi, src_off), (dst_pi, dst_off))
 
 
 def _spec_verify_loop(params, cfg: LlamaConfig, pool, history, last_tokens,
@@ -1224,7 +987,8 @@ def fused_decode_prefill_step(
     The interleaved lane dispatches each prefill chunk as its own
     batch-of-1 program that serializes AHEAD of decode blocks on the
     device queue — while an 8k prefill is in flight, concurrent short
-    streams' inter-token gaps degrade ~7x (BENCH_r05). Folding the
+    streams' inter-token gaps degrade ~7x (read on an earlier
+    attachment of the chip; no cell measures it yet). Folding the
     chunk into the decode dispatch removes the standalone program: the
     device runs one step that advances every live stream by n_steps
     tokens and the prefill by chunk_valid prompt tokens, so decode
@@ -1341,19 +1105,9 @@ def pool_to_cache(
     values decode attention reads for those pages."""
     from generativeaiexamples_tpu.models.llama import KVCache
 
-    ps = pool.page_size
-    S = table_row.shape[0] * ps
+    S = table_row.shape[0] * pool.page_size
     L, KH, Hd = cfg.cache_rows, cfg.n_kv_heads, cfg.head_dim
-    dt = jnp.dtype(cfg.dtype)
-    li, kh, tb = _page_axes(L, KH, table_row)
-    if pool.quantized:
-        k = (pool.kv[0, li, kh, tb].astype(dt)
-             * pool.s[0, li, kh, tb][..., None].astype(dt))
-        v = (pool.kv[1, li, kh, tb].astype(dt)
-             * pool.s[1, li, kh, tb][..., None].astype(dt))
-    else:
-        k = pool.k[li, kh, tb].astype(dt)
-        v = pool.v[li, kh, tb].astype(dt)
+    k, v = pool.read_pages(table_row, jnp.dtype(cfg.dtype))
     # [L, KH, npages, ps, Hd] -> the cache's [L, B=1, KH, S, Hd]
     k = k.reshape(L, KH, S, Hd)[:, None]
     v = v.reshape(L, KH, S, Hd)[:, None]
@@ -1378,18 +1132,7 @@ def pool_to_pages(pool: PagePool, table_row: jax.Array):
     Compiles per table_row width — callers pad to a power of two with
     sink-page zeros (page 0 gathers garbage; the host side slices the
     valid prefix)."""
-    # pytree-static branch: the pool's TYPE (PagePool vs
-    # QuantPagePool) selects it, not a traced value — the same
-    # shape pool_to_cache carries in lint-baseline.json.
-    if pool.quantized:  # graftlint: ignore[GL101]
-        li, kh, tb = _page_axes(pool.kv.shape[1], pool.kv.shape[2],
-                                table_row)
-        codes = pool.kv[:, li, kh, tb]  # [2, L, KH, n, ps, Hd]
-        scales = pool.s[:, li, kh, tb]  # [2, L, KH, n, ps]
-        return jnp.moveaxis(codes, 3, 0), jnp.moveaxis(scales, 3, 0)
-    li, kh, tb = _page_axes(pool.k.shape[0], pool.k.shape[1], table_row)
-    codes = jnp.stack([pool.k[li, kh, tb], pool.v[li, kh, tb]])
-    return jnp.moveaxis(codes, 3, 0), None
+    return pool.export_pages(table_row)
 
 
 @functools.partial(jax.jit, donate_argnames=("pool",))
@@ -1403,22 +1146,7 @@ def pages_to_pool(pool: PagePool, codes: jax.Array,
     `codes`/`scales` are exactly pool_to_pages' layout (int8 codes +
     narrow scales verbatim for quantized pools — never re-quantized).
     Padding rows carry page id 0 and scatter into the garbage sink."""
-    # pytree-static branch: the pool's TYPE (PagePool vs
-    # QuantPagePool) selects it, not a traced value — the same
-    # shape pool_to_cache carries in lint-baseline.json.
-    if pool.quantized:  # graftlint: ignore[GL101]
-        kq = jnp.moveaxis(codes[:, 0], 0, 2)  # [L, KH, n, ps, Hd]
-        vq = jnp.moveaxis(codes[:, 1], 0, 2)
-        ks = jnp.moveaxis(scales[:, 0], 0, 2)  # [L, KH, n, ps]
-        vs = jnp.moveaxis(scales[:, 1], 0, 2)
-        return _write_quant_pages(pool, kq, vq=vq, ks=ks, vs=vs,
-                                  table_flat=table_row)
-    kw = jnp.moveaxis(codes[:, 0], 0, 2)
-    vw = jnp.moveaxis(codes[:, 1], 0, 2)
-    li, kh, tb = _page_axes(pool.k.shape[0], pool.k.shape[1], table_row)
-    return PagePool(pool.k.at[li, kh, tb].set(kw.astype(pool.k.dtype)),
-                    pool.v.at[li, kh, tb].set(vw.astype(pool.v.dtype)),
-                    pool.page_size)
+    return pool.import_pages(codes, scales, table_row)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -1435,7 +1163,7 @@ def cache_to_pool(
     # Already in the canonical [L, KH, npages, ps, Hd] order.
     kw = cache.k[:, 0].reshape(L, KH, npages, ps, Hd)
     vw = cache.v[:, 0].reshape(L, KH, npages, ps, Hd)
-    return _write_prefill_pages(pool, kw, vw, table_row)
+    return pool.write_pages(pool.encode_pages(kw, vw), table_row)
 
 
 # ---------------------------------------------------------------------------

@@ -378,9 +378,7 @@ class LocalReplica:
     def transfer_device_set(self):
         """Devices holding this engine's KV pool — the device-path
         colocation check's input (mesh.devices_colocated)."""
-        pool = self.engine.pool
-        arr = pool.kv if getattr(pool, "quantized", False) else pool.k
-        return set(arr.devices())
+        return set(self.engine.pool.devices())
 
 
 class HttpReplica:

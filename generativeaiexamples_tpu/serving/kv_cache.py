@@ -24,7 +24,7 @@ Sized so `bytes = R * P * page_size * KH * Hd * 2 dtypes * itemsize`;
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -33,9 +33,49 @@ import numpy as np
 from generativeaiexamples_tpu.models.llama import LlamaConfig
 
 
+class PoolGeometry(NamedTuple):
+    """What a pool holds for one page, whatever its layout."""
+
+    rows: int       # cfg.cache_rows
+    kv_heads: int
+    page_size: int
+    head_dim: int
+    dtype: jnp.dtype  # of the stored K and V: the codes' for an int8 pool
+
+
+def _page_axes(rows, kv_heads, table_flat):
+    """The advanced indices of whole pages, every (row, kv head) of them."""
+    li = jnp.arange(rows)[:, None, None]
+    kh = jnp.arange(kv_heads)[None, :, None]
+    return li, kh, table_flat[None, None, :]
+
+
+class TokenSlots(NamedTuple):
+    """Where a step's new tokens go: token [b, ...] of every kv head
+    lands in page `page_idx[b, ...]` at `offset[b, ...]`."""
+
+    kh: jax.Array        # [KH, 1, ...]: the kv heads, over the slots
+    page_idx: jax.Array  # [B, ...]
+    offset: jax.Array    # [B, ...]
+
+
+def token_slots(kv_heads: int, page_idx: jax.Array,
+                offset: jax.Array) -> TokenSlots:
+    """The slots `append` writes (any rank: one row a slot, or r).
+    Taken once a step, outside the layer walk: every layer writes the
+    same slots."""
+    kh = jnp.arange(kv_heads)[(slice(None),) + (None,) * page_idx.ndim]
+    return TokenSlots(kh, page_idx, offset)
+
+
 @dataclasses.dataclass
 class PagePool:
-    """Device-side page pool (a pytree leaf pair) + geometry."""
+    """Device-side page pool (a pytree leaf pair) + geometry.
+
+    Everything that indexes into `k` and `v` is a method here (or an
+    attention kernel): the step programs of serving/engine_model.py
+    call `append`, `write_pages`, `move_tokens` and the reads, and know
+    no axis order. QuantPagePool has the same methods."""
 
     k: jax.Array  # [L, KH, P, page_size, Hd]
     v: jax.Array
@@ -48,6 +88,86 @@ class PagePool:
     @property
     def quantized(self) -> bool:
         return False
+
+    @property
+    def geometry(self) -> PoolGeometry:
+        L, KH, _, ps, Hd = self.k.shape
+        return PoolGeometry(L, KH, ps, Hd, self.k.dtype)
+
+    def devices(self):
+        return self.k.devices()
+
+    def attention_operands(self, row):
+        """Cache row `row` as the attention dispatchers take it:
+        (k_pages, v_pages, k_scales, layer), the last two None here."""
+        return self.k[row], self.v[row], None, None
+
+    def append(self, row, slots, k_new, v_new) -> "PagePool":
+        """Write a step's new K and V ([KH, B, ..., Hd], `slots` from
+        token_slots for the same [B, ...]) into cache row `row`, a
+        Python int or a traced scalar (the looped walk's)."""
+        kh, page_idx, offset = slots
+        k = self.k.at[row, kh, page_idx[None], offset[None], :].set(
+            k_new.astype(self.k.dtype))
+        v = self.v.at[row, kh, page_idx[None], offset[None], :].set(
+            v_new.astype(self.v.dtype))
+        return PagePool(k, v, self.page_size)
+
+    def encode_pages(self, k, v):
+        """K and V as `write_pages` stores them: as they are."""
+        return k, v
+
+    def write_pages(self, pages, table_flat) -> "PagePool":
+        """Scatter page-shaped K and V (`pages` = encode_pages' pair,
+        each [L, KH, M, ps, Hd]) into pages `table_flat` [M].
+
+        ALL advanced indices are contiguous from axis 0 ([li, kh,
+        pages]) — the old bracketed form `at[li, :, pages]` made XLA
+        materialize a full copy of the donated pool once the group had
+        >1 row, which is +3.3 GB HBM at the B=128 deployment shape and
+        an OOM at long-context pool sizes."""
+        kw, vw = pages
+        li, kh, tb = _page_axes(*kw.shape[:2], table_flat)
+        return PagePool(self.k.at[li, kh, tb].set(kw.astype(self.k.dtype)),
+                        self.v.at[li, kh, tb].set(vw.astype(self.v.dtype)),
+                        self.page_size)
+
+    def read_pages(self, table_row, dtype):
+        """Pages `table_row` [n] as (k, v), each [L, KH, n, ps, Hd] in
+        `dtype`: the values decode attention reads for those pages."""
+        L, KH = self.k.shape[:2]
+        li, kh, tb = _page_axes(L, KH, table_row)
+        return (self.k[li, kh, tb].astype(dtype),
+                self.v[li, kh, tb].astype(dtype))
+
+    def export_pages(self, table_row):
+        """Pages `table_row` [n] VERBATIM and page-major: (codes
+        [n, 2, L, KH, ps, Hd] with [:, 0] = k and [:, 1] = v, None)."""
+        L, KH = self.k.shape[:2]
+        li, kh, tb = _page_axes(L, KH, table_row)
+        codes = jnp.stack([self.k[li, kh, tb], self.v[li, kh, tb]])
+        return jnp.moveaxis(codes, 3, 0), None
+
+    def import_pages(self, codes, scales, table_row) -> "PagePool":
+        """export_pages' inverse: scatter its arrays into `table_row`."""
+        del scales
+        return self.write_pages((jnp.moveaxis(codes[:, 0], 0, 2),
+                                 jnp.moveaxis(codes[:, 1], 0, 2)), table_row)
+
+    def move_tokens(self, src, dst) -> "PagePool":
+        """Copy tokens from `src` to `dst`, each a (page, offset) pair
+        of [B, n], in every (row, kv head): ONE gather and one scatter
+        per array, over all rows."""
+        L, KH = self.k.shape[:2]
+        li = jnp.arange(L)[:, None, None, None]
+        kh = jnp.arange(KH)[None, :, None, None]
+
+        def at(slots):
+            return (li, kh) + tuple(t[None, None] for t in slots)
+
+        k, v = self.k[at(src)], self.v[at(src)]
+        return PagePool(self.k.at[at(dst)].set(k), self.v.at[at(dst)].set(v),
+                        self.page_size)
 
     @staticmethod
     def zeros(cfg: LlamaConfig, n_pages: int, page_size: int = 64,
@@ -118,6 +238,108 @@ class QuantPagePool:
     @property
     def quantized(self) -> bool:
         return True
+
+    @property
+    def geometry(self) -> PoolGeometry:
+        _, L, KH, _, ps, Hd = self.kv.shape
+        return PoolGeometry(L, KH, ps, Hd, self.kv.dtype)
+
+    def devices(self):
+        return self.kv.devices()
+
+    def attention_operands(self, row):
+        """PagePool.attention_operands: the WHOLE fused pool as
+        k_pages, no v_pages, the scales, and the row as `layer`, which
+        the kernel indexes inside its DMA descriptors (a host-side
+        slice of the kv-leading layout is not contiguous)."""
+        return self.kv, None, self.s, row
+
+    def _quantize(self, x):
+        from generativeaiexamples_tpu.serving.paged_attention_int8 import (
+            quantize_kv)
+
+        return quantize_kv(x, scale_dtype=self.s.dtype)
+
+    def append(self, row, slots, k_new, v_new) -> "QuantPagePool":
+        """PagePool.append for the fused pool: codes and scales of the
+        new K and V, one scale a (kv head, token).
+
+        TWO scatters per array (k then v), all advanced indices adjacent
+        (scalar kv-index + scalar row + kh/page/offset) -> plain
+        in-place scatters with natural layouts; a single stacked
+        [2, ...] update makes XLA transpose the whole pool (OOM). Four
+        XLA scatters a layer, the first bottleneck of every cell
+        (PERF.md section 5): this function is what a kernel replaces."""
+        kh, page_idx, offset = slots
+        kq, ks = self._quantize(k_new)
+        vq, vs = self._quantize(v_new)
+        kv = self.kv.at[0, row, kh, page_idx[None], offset[None], :].set(kq)
+        kv = kv.at[1, row, kh, page_idx[None], offset[None], :].set(vq)
+        s = self.s.at[0, row, kh, page_idx[None], offset[None]].set(ks)
+        s = s.at[1, row, kh, page_idx[None], offset[None]].set(vs)
+        return QuantPagePool(kv, s, self.page_size)
+
+    def encode_pages(self, k, v):
+        """K and V ([..., Hd]) as `write_pages` stores them: (k codes,
+        k scales, v codes, v scales). A prefill calls it INSIDE its
+        layer scan, so that the stacked bf16 K and V never materialize."""
+        return self._quantize(k) + self._quantize(v)
+
+    def write_pages(self, pages, table_flat) -> "QuantPagePool":
+        """Scatter page-shaped codes ([L, KH, M, ps, Hd]) and scales
+        ([L, KH, M, ps]), `pages` = encode_pages' four, into pages
+        `table_flat` [M]. TWO scatters per array (k then v) with a
+        scalar leading index: a single stacked [2, ...] update drives
+        XLA to a transposed pool layout whose conversion copies the
+        whole 3 GB pool (OOM); separate scatters with contiguous
+        advanced indices keep the natural layout and alias in place."""
+        kq, ks, vq, vs = pages
+        li, kh, tb = _page_axes(*kq.shape[:2], table_flat)
+        kv = self.kv.at[0, li, kh, tb].set(kq)
+        kv = kv.at[1, li, kh, tb].set(vq)
+        s = self.s.at[0, li, kh, tb].set(ks)
+        s = s.at[1, li, kh, tb].set(vs)
+        return QuantPagePool(kv, s, self.page_size)
+
+    def read_pages(self, table_row, dtype):
+        """PagePool.read_pages, dequantized with the narrow scales."""
+        li, kh, tb = _page_axes(*self.kv.shape[1:3], table_row)
+        k = (self.kv[0, li, kh, tb].astype(dtype)
+             * self.s[0, li, kh, tb][..., None].astype(dtype))
+        v = (self.kv[1, li, kh, tb].astype(dtype)
+             * self.s[1, li, kh, tb][..., None].astype(dtype))
+        return k, v
+
+    def export_pages(self, table_row):
+        """PagePool.export_pages: int8 codes and [n, 2, L, KH, ps]
+        scales, untouched (no dequantize: import_pages scatters the
+        exact bytes back)."""
+        li, kh, tb = _page_axes(*self.kv.shape[1:3], table_row)
+        codes = self.kv[:, li, kh, tb]  # [2, L, KH, n, ps, Hd]
+        scales = self.s[:, li, kh, tb]  # [2, L, KH, n, ps]
+        return jnp.moveaxis(codes, 3, 0), jnp.moveaxis(scales, 3, 0)
+
+    def import_pages(self, codes, scales, table_row) -> "QuantPagePool":
+        kq = jnp.moveaxis(codes[:, 0], 0, 2)  # [L, KH, n, ps, Hd]
+        vq = jnp.moveaxis(codes[:, 1], 0, 2)
+        ks = jnp.moveaxis(scales[:, 0], 0, 2)  # [L, KH, n, ps]
+        vs = jnp.moveaxis(scales[:, 1], 0, 2)
+        return self.write_pages((kq, ks, vq, vs), table_row)
+
+    def move_tokens(self, src, dst) -> "QuantPagePool":
+        """PagePool.move_tokens; codes and scales move verbatim (no
+        requantization error)."""
+        L, KH = self.kv.shape[1:3]
+        kvi = jnp.arange(2)[:, None, None, None, None]
+        li = jnp.arange(L)[None, :, None, None, None]
+        kh = jnp.arange(KH)[None, None, :, None, None]
+
+        def at(slots):
+            return (kvi, li, kh) + tuple(t[None, None, None] for t in slots)
+
+        kv, s = self.kv[at(src)], self.s[at(src)]
+        return QuantPagePool(self.kv.at[at(dst)].set(kv),
+                             self.s.at[at(dst)].set(s), self.page_size)
 
     @staticmethod
     def zeros(cfg: LlamaConfig, n_pages: int, page_size: int = 64,

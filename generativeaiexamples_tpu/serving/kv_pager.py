@@ -62,6 +62,7 @@ from typing import Callable, List, Optional
 import numpy as np
 
 from generativeaiexamples_tpu.serving import engine_model
+from generativeaiexamples_tpu.serving.disagg import page_geometry
 from generativeaiexamples_tpu.serving.kv_cache import PageAllocator
 from generativeaiexamples_tpu.serving.prefix_cache import (
     TIER_DEVICE, TIER_DISK, TIER_HOST, TIER_PENDING, RadixPrefixCache)
@@ -120,20 +121,12 @@ class KVPager:
     def __init__(self, pool, *, host_budget_mb: int = 256,
                  spill_dir: str = "", put: Optional[Callable] = None,
                  max_batch_pages: int = 0):
-        # Page geometry from the live pool: codes are [2, L, KH, ps,
-        # Hd] per page ([0]=k, [1]=v) in the pool dtype (int8 codes
-        # for quantized pools, which also carry [2, L, KH, ps] f32
-        # narrow scales).
-        if pool.quantized:
-            _, L, KH, _, ps, Hd = pool.kv.shape
-            self.codes_dtype = np.dtype(np.int8)
-            self.scales_shape: Optional[tuple] = (2, L, KH, ps)
-        else:
-            L, KH, _, ps, Hd = pool.k.shape
-            self.codes_dtype = np.dtype(pool.k.dtype)
-            self.scales_shape = None
-        self.codes_shape = (2, L, KH, ps, Hd)
-        self.page_size = ps
+        # One page as pool_to_pages hands it over: codes [2, L, KH, ps,
+        # Hd] ([0]=k, [1]=v) in the pool dtype (int8 codes for quantized
+        # pools, which also carry [2, L, KH, ps] f32 narrow scales).
+        (self.codes_shape, self.codes_dtype,
+         self.scales_shape) = page_geometry(pool)
+        self.page_size = pool.page_size
         self.quantized = bool(pool.quantized)
         self._codes_bytes = int(np.prod(self.codes_shape)
                                 * self.codes_dtype.itemsize)

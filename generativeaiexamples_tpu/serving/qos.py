@@ -33,8 +33,7 @@ Pieces:
   429 + Retry-After BEFORE it queues on the engine, so overload costs
   the caller one RTT instead of an unbounded wait.
 - `bursty_trace` / `run_trace_on_engine` / `goodput` — the seeded,
-  replayable load harness behind the BENCH_QOS scenario,
-  scripts/smoke_qos.py and tests: Poisson(+burst) arrivals,
+  replayable load harness behind scripts/smoke_qos.py and tests: Poisson(+burst) arrivals,
   bounded-Pareto prompt/output lengths, per-tier SLO evaluation.
 
 Thread model: `TierScheduler` is engine-scheduler-thread-only (called

@@ -19,8 +19,8 @@ def setup_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache (a cold llama3-8b boot
     compiles for minutes; a warm one reads the programs back) and return
     its directory. Called once by each entry point that compiles — the
-    engine server, a chain server with in-process engines, bench.py,
-    chip_smoke.py's children — before the first compile.
+    engine server, a chain server with in-process engines,
+    benchmark/run.py, chip_smoke.py's children — before the first compile.
 
     Where `JAX_COMPILATION_CACHE_DIR` is set the directory is the
     operator's: JAX reads the variable itself and no directory is set

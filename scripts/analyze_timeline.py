@@ -24,7 +24,7 @@ dumped by bench/smoke under build/). Attribution per replica lane:
 Categories partition [first event, last event] exactly, so the
 attribution always sums to 100% of wall — "unattributed" time cannot
 exist, only honestly-named idle. Turning the next headline regression
-into one command is the point: run it on a BENCH_FUSED artifact and
+into one command is the point: run it on a flight-recorder dump and
 read which category grew.
 
 Usage:

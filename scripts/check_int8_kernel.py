@@ -1,9 +1,9 @@
-"""Thin forwarding shim — the int8 kernel check moved into the ONE
-kernel-parity entry point, scripts/bench_kernels.py --verify (which
-also covers the tree-attention twins and the fused sampling tail).
+"""The kernel-parity suite of scripts/smoke_kernels.py (run_verify: the
+int8 linear kernel vs the dequant oracle, both tree kernels vs the XLA
+gather references, the fused sampling tail) on whatever backend is
+attached: on a TPU the kernels run on hardware.
 
 Usage:  python scripts/check_int8_kernel.py [B] [maxp]
-        == python scripts/bench_kernels.py --verify [B] [maxp]
 """
 
 from __future__ import annotations
@@ -15,9 +15,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main():
-    from scripts import bench_kernels
+    from scripts import smoke_kernels
 
-    bench_kernels.main(["--verify"] + sys.argv[1:])
+    smoke_kernels.run_verify(*(int(a) for a in sys.argv[1:3]))
 
 
 if __name__ == "__main__":

@@ -181,7 +181,8 @@ def main() -> int:
     def to_bf16(a):
         return jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
 
-    real_finish = em._finish_block
+    # models/llama.py's block half, under the name the step programs call
+    real_finish = em.finish_block
 
     def fp8_finish(cfg, x, out, w):
         y = real_finish(cfg, x, out, w)
@@ -213,7 +214,7 @@ def main() -> int:
         return type(pool)(pool.kv, to_bf16(pool.s), pool.page_size)
 
     def patch_fp8(on):
-        em._finish_block = fp8_finish if on else real_finish
+        em.finish_block = fp8_finish if on else real_finish
 
     def patch_scales(on):
         pa8.quantize_kv = bf16_scale_quantize if on else real_quantize

@@ -109,7 +109,7 @@ fi
 
 step "ruff (scripts/lint.py --ruff; skips when absent)"
 if command -v ruff >/dev/null 2>&1; then
-    ruff check generativeaiexamples_tpu/ scripts/ tests/ bench.py || fail=1
+    ruff check generativeaiexamples_tpu/ scripts/ tests/ || fail=1
 else
     echo "ruff not installed — skipping"
 fi

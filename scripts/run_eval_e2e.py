@@ -9,7 +9,7 @@ tools/evaluation/llm_answer_generator.py.
 
 What is SCRIPTED: QA synthesis and metric/judge LLM calls use the
 hermetic fakes. This environment has no downloaded weights (tiny
-random-init model — bench.py records the same limitation), and a
+random-init model), and a
 random-weight judge would emit noise; the reference's harness likewise
 depends on an external capable LLM endpoint for these stages
 (rag_evaluator/evaluator.py:95-232). Point --server/--judge-url at

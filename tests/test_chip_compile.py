@@ -21,7 +21,6 @@ from jax.sharding import SingleDeviceSharding
 
 from generativeaiexamples_tpu.ops.attention import flash_attention
 from generativeaiexamples_tpu.ops.encoder_attention import encoder_attention
-from generativeaiexamples_tpu.ops.int8_matmul import int8_matmul
 from generativeaiexamples_tpu.serving.paged_attention import paged_attention
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
     paged_attention_int8)
@@ -85,9 +84,6 @@ KERNELS = {
         + [((8,), I32)]),
     "encoder_attention_arctic_l": (
         encoder_attention, [((32, 16, 512, 64), BF16)] * 3 + [((32,), I32)]),
-    "int8_matmul_mlp": (  # opt-in (ENGINE_PALLAS_INT8), same guard
-        int8_matmul,
-        [((64, 4096), BF16), ((4096, 14336), I8), ((14336,), F32)]),
 }
 
 

@@ -394,7 +394,13 @@ def test_direct_qkv_serves_the_contiguous_forwards_tokens_and_is_counted(
 # on purpose: `decode_step.*` and `decode_multi_step.*` (lowered at 2
 # steps) now hold the optimization barrier of the direct q/k/v form
 # (engine_model.direct_qkv); `decode_multi_step_k8.*`, the long block,
-# were taken on PR 30's PARENT and must not move with it.
+# were taken on PR 30's PARENT and must not move with it. PR 31 wrote the
+# decode family's body once (engine_model._decode_rows) and left the
+# eleven as they were; it added the speculative, tree and fused programs,
+# every other site of the pool's append, taken on its PARENT: the tree
+# and fused four are the parent's, the linear verify's two are not
+# (parent f561f077d4665964 / dce4c8fc3d923554): the same operations,
+# with the queries' reshape after the append instead of before it.
 LLAMA_PROGRAMS = {
     "decode_multi_step.float32": "4715aca7b36c9762",
     "decode_multi_step.int8": "a5a299d10d5be839",
@@ -407,6 +413,12 @@ LLAMA_PROGRAMS = {
     "prefill_step.float32": "3bd7b8f722eb93dd",
     "prefill_step.int8": "914f0780433eb1eb",
     "prefill_chunk_step": "57bcbf373ce97da4",
+    "decode_spec_multi_step.float32": "8959164beef5d022",
+    "decode_spec_multi_step.int8": "184775e26b722ef1",
+    "decode_spec_multi_step_tree.float32": "25b9e7244cb6053d",
+    "decode_spec_multi_step_tree.int8": "d8ceb5a339d9845b",
+    "fused_decode_prefill_step.float32": "2fa8a6c474d096f0",
+    "fused_decode_prefill_step.int8": "f44397680782bca8",
 }
 
 
@@ -439,6 +451,18 @@ def test_a_tiny_llamas_step_programs_lower_to_the_text_they_did():
             f32(2), i32(2), key, False, sampling_flags=greedy)
         lowered[f"prefill_step.{dt}"] = em.prefill_step.lower(
             params, cfg, pool, i32(1, 16), jnp.int32(3), i32(2), False)
+        for name, n_branches in (("decode_spec_multi_step", 0),
+                                 ("decode_spec_multi_step_tree", 2)):
+            lowered[f"{name}.{dt}"] = em.decode_spec_multi_step.lower(
+                params, cfg, pool, i32(B, 32), i32(B), i32(B) + 1,
+                i32(B, maxp), jnp.ones((B,), bool), n_steps=2, k=3,
+                n_branches=n_branches, use_pallas=False)
+        lowered[f"fused_decode_prefill_step.{dt}"] = (
+            em.fused_decode_prefill_step.lower(
+                params, cfg, pool, i32(B), i32(B, maxp), i32(B) + 1,
+                jnp.ones((B,), bool), f32(B), f32(B), i32(B), key,
+                llama.KVCache.zeros(cfg, 1, max_len=32), i32(1, 8),
+                jnp.int32(5), 2, False, sampling_flags=greedy))
     lowered["prefill_chunk_step"] = em.prefill_chunk_step.lower(
         params, cfg, llama.KVCache.zeros(cfg, 1, max_len=32), i32(1, 8),
         jnp.int32(5), False)

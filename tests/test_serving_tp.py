@@ -12,6 +12,9 @@ without hardware (conftest forces JAX_PLATFORMS=cpu with 8 virtual
 devices).
 """
 
+import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,7 +97,6 @@ def test_tp8_speculative_engine_matches_single_device(eight_dev_mesh):
     the mesh (flat verify path; the fused multi-query kernel is
     single-device-only) and tokens must match the non-spec single-
     device engine exactly — greedy is greedy."""
-    import dataclasses
 
     cfg = tp_cfg()
     params = llama.init_params(cfg, jax.random.PRNGKey(2))
@@ -145,6 +147,38 @@ def test_quantized_spec_pairs():
     assert tuple(qs.s) == (None, "tensor")
 
 
+# The decode walk is unrolled (engine_model._walk_decode), so the CPU
+# backend compiles one body a (layer, step): at the 70B's 80 layers a
+# block of 8 does not finish in ten minutes here. Both AOT tests below
+# compile the 70B's widths at this depth, and the fit test adds the
+# ARGUMENTS of the layers left out exactly, from their shapes. What a
+# cut depth cannot show is the unrolled decode block's temporaries at 80
+# layers: this backend keeps every unrolled layer's converted weights
+# live (2.35 GiB at 4 layers, 3.94 at 8), where the TPU's compiler
+# streams the int8 codes into the dot (PERF.md section 5); the scanned
+# prefill's do not grow (1.009 and 1.011 GiB).
+CUT_LAYERS = 4
+
+
+def _cut(cfg):
+    return dataclasses.replace(cfg, n_layers=CUT_LAYERS)
+
+
+def _sharded_int8_shapes(cfg, mesh):
+    params = jax.eval_shape(
+        lambda k: quantize_llama_params(llama.init_params(cfg, k)),
+        jax.random.PRNGKey(0))
+    shardings = shd.param_shardings(params, cfg, mesh)
+    return jax.tree.map(
+        lambda l, s: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=s),
+        params, shardings)
+
+
+def _bytes_per_chip(tree):
+    return sum(math.prod(l.sharding.shard_shape(l.shape)) * l.dtype.itemsize
+               for l in jax.tree.leaves(tree))
+
+
 def test_llama3_70b_int8_tp8_decode_compiles(eight_dev_mesh):
     """AOT proof that the 70B int8 TP=8 paged decode partitions: lower +
     compile the engine's decode graph from ShapeDtypeStructs — no 70 GB
@@ -154,14 +188,8 @@ def test_llama3_70b_int8_tp8_decode_compiles(eight_dev_mesh):
     from generativeaiexamples_tpu.serving.kv_cache import PagePool
 
     mesh = eight_dev_mesh
-    cfg = llama.LlamaConfig.llama3_70b()
-    params = jax.eval_shape(
-        lambda k: quantize_llama_params(llama.init_params(cfg, k)),
-        jax.random.PRNGKey(0))
-    shardings = shd.param_shardings(params, cfg, mesh)
-    p_shapes = jax.tree.map(
-        lambda l, s: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=s),
-        params, shardings)
+    cfg = _cut(llama.LlamaConfig.llama3_70b())
+    p_shapes = _sharded_int8_shapes(cfg, mesh)
 
     B, ps, maxp = 8, 64, 4
     kv_sh = jax.sharding.NamedSharding(mesh, shd.KV_POOL_SPEC)
@@ -172,30 +200,28 @@ def test_llama3_70b_int8_tp8_decode_compiles(eight_dev_mesh):
     rep = shd.replicated(mesh)
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)  # noqa: E731
 
-    prev = engine_model._UNROLL_DECODE
-    engine_model._UNROLL_DECODE = False  # scan: one layer body to compile
-    try:
-        lowered = engine_model.decode_multi_step.lower(
-            p_shapes, cfg, pool, arg((B,), jnp.int32), arg((B, maxp), jnp.int32),
-            arg((B,), jnp.int32), arg((B,), jnp.bool_), arg((B,), jnp.float32),
-            arg((B,), jnp.float32), arg((B,), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
-            n_steps=2, use_pallas=False, sampling_flags=(True, False, False),
-            mesh=None)
-        compiled = lowered.compile()
-    finally:
-        engine_model._UNROLL_DECODE = prev
+    lowered = engine_model.decode_multi_step.lower(
+        p_shapes, cfg, pool, arg((B,), jnp.int32), arg((B, maxp), jnp.int32),
+        arg((B,), jnp.int32), arg((B,), jnp.bool_), arg((B,), jnp.float32),
+        arg((B,), jnp.float32), arg((B,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
+        n_steps=2, use_pallas=False, sampling_flags=(True, False, False),
+        mesh=None)
+    compiled = lowered.compile()
     # The partitioned executable exists and its per-device argument
     # shards are 1/8th of the weight bytes on the tensor axis.
     assert compiled is not None
 
 
-def _hbm_budget_check(compiled, label, budget_gib=16.0):
+def _hbm_budget_check(compiled, label, budget_gib=16.0, more_args=0):
     """Per-chip HBM accounting from XLA's own compiled memory analysis:
     arguments + outputs + temps - donated aliases must fit a v5e chip.
-    (VERDICT r4 #8: the compile proof showed partitioning, not FIT.)"""
+    (VERDICT r4 #8: the compile proof showed partitioning, not FIT.)
+    `more_args`: bytes a chip of arguments the compiled program was cut
+    by (weights and pool rows of layers left out: the pool's are donated
+    and aliased, so they add to nothing else)."""
     ma = compiled.memory_analysis()
-    args = ma.argument_size_in_bytes
+    args = ma.argument_size_in_bytes + more_args
     outs = ma.output_size_in_bytes
     temps = ma.temp_size_in_bytes
     alias = ma.alias_size_in_bytes
@@ -213,20 +239,16 @@ def test_llama3_70b_int8_tp8_serving_fits_16gib_per_chip(eight_dev_mesh):
     """70B int8 TP=8 at SERVING shapes (B=16, page 128, 2k context,
     fused int8 KV pool): XLA's compiled memory analysis must show
     per-chip arguments + temps within the 16 GiB v5e budget for BOTH
-    the decode block and a bucketed prefill dispatch. Numbers recorded
-    in docs/support-matrix.md."""
+    the decode block and a bucketed prefill dispatch: the arguments of
+    all 80 layers, the temporaries of a CUT_LAYERS-deep program (above).
+    Numbers recorded in docs/support-matrix.md."""
     from generativeaiexamples_tpu.serving import engine_model
     from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
 
     mesh = eight_dev_mesh
-    cfg = llama.LlamaConfig.llama3_70b()
-    params = jax.eval_shape(
-        lambda k: quantize_llama_params(llama.init_params(cfg, k)),
-        jax.random.PRNGKey(0))
-    shardings = shd.param_shardings(params, cfg, mesh)
-    p_shapes = jax.tree.map(
-        lambda l, s: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=s),
-        params, shardings)
+    full = llama.LlamaConfig.llama3_70b()
+    cfg = _cut(full)
+    p_shapes = _sharded_int8_shapes(cfg, mesh)
 
     # Serving config: B=16 slots, page 128, max_seq 2048 (16 pages per
     # sequence), one sequence of slack + sink — the engine's default
@@ -235,37 +257,40 @@ def test_llama3_70b_int8_tp8_serving_fits_16gib_per_chip(eight_dev_mesh):
     n_pages = B * maxp + maxp + 1
     kv_sh = jax.sharding.NamedSharding(mesh, shd.KV_FUSED_SPEC)
     sc_sh = jax.sharding.NamedSharding(mesh, shd.KV_FUSED_SCALE_SPEC)
-    kv_shape = (2, cfg.n_layers, cfg.n_kv_heads, n_pages, ps, cfg.head_dim)
-    pool = QuantPagePool(
-        jax.ShapeDtypeStruct(kv_shape, jnp.int8, sharding=kv_sh),
-        jax.ShapeDtypeStruct(kv_shape[:-1], jnp.float32, sharding=sc_sh),
-        ps)
+
+    def pool_of(c):
+        kv_shape = (2, c.n_layers, c.n_kv_heads, n_pages, ps, c.head_dim)
+        return QuantPagePool(
+            jax.ShapeDtypeStruct(kv_shape, jnp.int8, sharding=kv_sh),
+            jax.ShapeDtypeStruct(kv_shape[:-1], jnp.float32, sharding=sc_sh),
+            ps)
+
+    pool = pool_of(cfg)
+    left_out = (
+        _bytes_per_chip((_sharded_int8_shapes(full, mesh), pool_of(full)))
+        - _bytes_per_chip((p_shapes, pool)))
     rep = shd.replicated(mesh)
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)  # noqa: E731
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
 
-    prev = engine_model._UNROLL_DECODE
-    engine_model._UNROLL_DECODE = False
-    try:
-        decode = engine_model.decode_multi_step.lower(
-            p_shapes, cfg, pool, arg((B,), jnp.int32),
-            arg((B, maxp), jnp.int32), arg((B,), jnp.int32),
-            arg((B,), jnp.bool_), arg((B,), jnp.float32),
-            arg((B,), jnp.float32), arg((B,), jnp.int32), key,
-            n_steps=8, use_pallas=False,
-            sampling_flags=(True, False, False), mesh=None).compile()
-        d = _hbm_budget_check(decode, "decode B=16 K=8")
-        bucket, group = 512, 4
-        prefill = engine_model.prefill_batch_step.lower(
-            p_shapes, cfg, pool, arg((group, bucket), jnp.int32),
-            arg((group,), jnp.int32),
-            arg((group, bucket // ps), jnp.int32),
-            arg((group,), jnp.float32), arg((group,), jnp.float32),
-            arg((group,), jnp.int32), key, use_pallas=False,
-            sampling_flags=(True, False, False), mesh=None).compile()
-        p = _hbm_budget_check(prefill, "prefill group=4 bucket=512")
-    finally:
-        engine_model._UNROLL_DECODE = prev
+    decode = engine_model.decode_multi_step.lower(
+        p_shapes, cfg, pool, arg((B,), jnp.int32),
+        arg((B, maxp), jnp.int32), arg((B,), jnp.int32),
+        arg((B,), jnp.bool_), arg((B,), jnp.float32),
+        arg((B,), jnp.float32), arg((B,), jnp.int32), key,
+        n_steps=8, use_pallas=False,
+        sampling_flags=(True, False, False), mesh=None).compile()
+    d = _hbm_budget_check(decode, "decode B=16 K=8", more_args=left_out)
+    bucket, group = 512, 4
+    prefill = engine_model.prefill_batch_step.lower(
+        p_shapes, cfg, pool, arg((group, bucket), jnp.int32),
+        arg((group,), jnp.int32),
+        arg((group, bucket // ps), jnp.int32),
+        arg((group,), jnp.float32), arg((group,), jnp.float32),
+        arg((group,), jnp.int32), key, use_pallas=False,
+        sampling_flags=(True, False, False), mesh=None).compile()
+    p = _hbm_budget_check(prefill, "prefill group=4 bucket=512",
+                          more_args=left_out)
     # Keep the support-matrix numbers honest: weights dominate at
     # ~8.8 GiB/chip int8; everything together must clear 16 GiB.
     assert d["argument_gib"] > 8.0, d  # sanity: weights really counted
