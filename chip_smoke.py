@@ -778,6 +778,32 @@ def child_device_ops(args) -> None:
             (qT, *pool8),
             ref(lambda *a: paged_tree_attention_int8_reference_fused(
                 *a, anc), qT, *layer8))
+    # the decode step's append: the kernel's pool against the scatters'
+    # (QuantPagePool.append's two forms) must hold the same BYTES
+    from jax.experimental.pallas import tpu as pltpu
+
+    from generativeaiexamples_tpu.serving.kv_cache import (
+        QuantPagePool, token_slots)
+
+    k_new, v_new = rand(KH, B, Hd), rand(KH, B, Hd)
+
+    def appended(use_pallas, kv, sc, k_new, v_new):
+        slots = token_slots(KH, table[:, 0], lengths % ps, use_pallas)
+        pool = QuantPagePool(kv, sc, ps).append(layer, slots, k_new, v_new)
+        return pool.kv, pool.s
+
+    appended = jax.jit(appended, static_argnums=0)
+    if interpret:
+        pltpu.set_tpu_interpret_mode()
+    got, secs = _timed(lambda: appended(True, kv, sc, k_new, v_new))
+    pltpu.set_tpu_interpret_mode(None)
+    want = appended(False, kv, sc, k_new, v_new)
+    ok = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want)) \
+        and not bool(jnp.array_equal(got[0], kv))
+    print(f"[device-ops] int8 K/V append (kernel vs scatters): the same "
+          f"bytes, {secs * 1e3:.3f} ms {'ok' if ok else 'FAILED'}", flush=True)
+    if not ok:
+        failures.append("int8 K/V append")
     want = ref(paged_attention_reference, q1, *pool16)
     compare("bf16 paged decode (in-repo kernel)",
             lambda *a: paged_attention(*a, interpret=interpret),

@@ -66,7 +66,7 @@ from generativeaiexamples_tpu.models.llama import LlamaConfig
 from generativeaiexamples_tpu.obs import tracing
 from generativeaiexamples_tpu.serving import engine_model
 from generativeaiexamples_tpu.serving.kv_cache import (
-    PageAllocator, PagePool, SequencePages)
+    PageAllocator, PagePool, SequencePages, kernel_append)
 from generativeaiexamples_tpu.serving import flight as flight_mod
 from generativeaiexamples_tpu.serving.multihost import (
     fetch_addressable as mh_fetch_addressable,
@@ -311,6 +311,11 @@ class EngineMetrics:
         # short block, or a looped model): the share of decode_steps
         # that engages it.
         self.decode_steps_direct_qkv = 0
+        # Decode steps dispatched through a program whose K/V append is
+        # the in-place Pallas kernel (kv_cache.kernel_append: an int8
+        # pool, one new row a slot, kernels on) and not XLA's scatters:
+        # the share of decode_steps that engages it.
+        self.decode_steps_kernel_append = 0
         # KV pool geometry (set once at engine build): rows of the pool
         # (layers x passes) and the bytes one cached token takes over
         # all rows, scales included.
@@ -470,6 +475,7 @@ class EngineMetrics:
             "decode_steps": self.decode_steps,
             "layer_passes": self.layer_passes,
             "decode_steps_direct_qkv": self.decode_steps_direct_qkv,
+            "decode_steps_kernel_append": self.decode_steps_kernel_append,
             "kv_cache_rows": self.kv_cache_rows,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "mean_batch_occupancy": occ,
@@ -3178,6 +3184,9 @@ class LLMEngine:
         if not (plan.spec_k or plan.spec_state) \
                 and engine_model.direct_qkv(self.cfg, K):
             self.metrics.decode_steps_direct_qkv += K
+        # every decode program but the verifies writes one row a slot
+        if not plan.spec_k and kernel_append(self.pool, self.use_pallas):
+            self.metrics.decode_steps_kernel_append += K
         self.metrics.busy_slots_acc += len(active) * K
         if spec_mode:
             for i in active:

@@ -322,7 +322,8 @@ def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
     positions = (lengths - 1)[:, None]  # [B, 1]
     page_idx = page_tables[jnp.arange(B), (lengths - 1) // ps]  # [B]
     offset = (lengths - 1) % ps  # [B]
-    slots = token_slots(cfg.n_kv_heads, page_idx, offset)
+    # one row a slot: where kernels are on, the pool's append is one too
+    slots = token_slots(cfg.n_kv_heads, page_idx, offset, use_pallas, mesh)
 
     def attend(q, pool, row):
         q = q[:, :, 0, :]

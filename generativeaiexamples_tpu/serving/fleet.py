@@ -75,7 +75,8 @@ _LOG = logging.getLogger(__name__)
 
 _COUNTER_KEYS = (
     "tokens_generated", "decode_steps", "layer_passes",
-    "decode_steps_direct_qkv", "prefill_tokens", "fused_steps",
+    "decode_steps_direct_qkv", "decode_steps_kernel_append",
+    "prefill_tokens", "fused_steps",
     "fused_prefill_tokens", "prefill_stall_beats",
     "fused_sample_dispatches", "prefix_hits",
     "prefix_miss", "prefix_evictions", "prefix_hit_tokens",

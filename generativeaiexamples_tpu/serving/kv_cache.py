@@ -16,6 +16,13 @@ inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
   scheduler thread owns it; no device sync needed to allocate).
 - Page tables are [B, max_pages] int32 arrays shipped to the device each
   step (tiny; rides along with the token ids).
+- A decode step's new row a slot goes into the int8 pool through ONE
+  in-place Pallas call a cache row where kernels are on
+  (serving/kv_append_int8.py; `kernel_append` decides, from the pool,
+  the slots' rank and the step program's `use_pallas`), and through
+  XLA's scatters everywhere else: off the chip, a bf16 pool, a verify's
+  r rows a slot. Both forms write the same bytes
+  (QuantPagePool.append; tests/test_kv_append_kernel.py).
 
 Sized so `bytes = R * P * page_size * KH * Hd * 2 dtypes * itemsize`;
 `PagePool.for_budget` picks P from an HBM byte budget.
@@ -31,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from generativeaiexamples_tpu.models.llama import LlamaConfig
+from generativeaiexamples_tpu.utils.platform import log_kernel_declined
 
 
 class PoolGeometry(NamedTuple):
@@ -52,20 +60,46 @@ def _page_axes(rows, kv_heads, table_flat):
 
 class TokenSlots(NamedTuple):
     """Where a step's new tokens go: token [b, ...] of every kv head
-    lands in page `page_idx[b, ...]` at `offset[b, ...]`."""
+    lands in page `page_idx[b, ...]` at `offset[b, ...]`; and what the
+    step program was told of kernels and the mesh, which decides the
+    form of an int8 pool's append (`kernel_append`)."""
 
     kh: jax.Array        # [KH, 1, ...]: the kv heads, over the slots
     page_idx: jax.Array  # [B, ...]
     offset: jax.Array    # [B, ...]
+    use_pallas: Optional[bool] = None  # the step program's; None: on a TPU
+    mesh: Optional[jax.sharding.Mesh] = None
 
 
-def token_slots(kv_heads: int, page_idx: jax.Array,
-                offset: jax.Array) -> TokenSlots:
+def token_slots(kv_heads: int, page_idx: jax.Array, offset: jax.Array,
+                use_pallas: Optional[bool] = None, mesh=None) -> TokenSlots:
     """The slots `append` writes (any rank: one row a slot, or r).
     Taken once a step, outside the layer walk: every layer writes the
     same slots."""
     kh = jnp.arange(kv_heads)[(slice(None),) + (None,) * page_idx.ndim]
-    return TokenSlots(kh, page_idx, offset)
+    return TokenSlots(kh, page_idx, offset, use_pallas, mesh)
+
+
+def kernel_append(pool, use_pallas: Optional[bool] = None,
+                  rank: int = 1) -> bool:
+    """Whether `pool.append` for slots of `rank` is the Pallas kernel
+    (serving/kv_append_int8.py) and not XLA's scatters: an int8 pool,
+    one new row a slot, kernels on (`use_pallas`; None means on a TPU),
+    and a page and head size its DMAs can tile (else the log says so,
+    once). From what the step program can observe and nothing else; the
+    engine counts `decode_steps_kernel_append` by the same function."""
+    if not pool.quantized or rank != 1:
+        return False
+    if not ((jax.default_backend() == "tpu") if use_pallas is None
+            else use_pallas):
+        return False
+    _, _, ps, Hd, _ = pool.geometry
+    if ps % 128 or Hd % 128:
+        log_kernel_declined(
+            "kv_append_int8", "XLA's four scatters a cache row",
+            f"page_size {ps} and head_dim {Hd} must both be multiples of 128")
+        return False
+    return True
 
 
 @dataclasses.dataclass
@@ -106,7 +140,7 @@ class PagePool:
         """Write a step's new K and V ([KH, B, ..., Hd], `slots` from
         token_slots for the same [B, ...]) into cache row `row`, a
         Python int or a traced scalar (the looped walk's)."""
-        kh, page_idx, offset = slots
+        kh, page_idx, offset = slots[:3]
         k = self.k.at[row, kh, page_idx[None], offset[None], :].set(
             k_new.astype(self.k.dtype))
         v = self.v.at[row, kh, page_idx[None], offset[None], :].set(
@@ -262,21 +296,61 @@ class QuantPagePool:
 
     def append(self, row, slots, k_new, v_new) -> "QuantPagePool":
         """PagePool.append for the fused pool: codes and scales of the
-        new K and V, one scale a (kv head, token).
+        new K and V, one scale a (kv head, token), in one of two forms
+        that write the same bytes (tests/test_kv_append_kernel.py).
 
-        TWO scatters per array (k then v), all advanced indices adjacent
-        (scalar kv-index + scalar row + kh/page/offset) -> plain
-        in-place scatters with natural layouts; a single stacked
-        [2, ...] update makes XLA transpose the whole pool (OOM). Four
-        XLA scatters a layer, the first bottleneck of every cell
-        (PERF.md section 5): this function is what a kernel replaces."""
-        kh, page_idx, offset = slots
+        A step's ONE new row a slot (slots of rank 1: `decode_step`,
+        `decode_multi_step`, the fused step's decode half, the
+        spec-state walk) with kernels on: ONE Pallas call that patches,
+        in place, the int8 tile and the scale row the new token lives
+        in (serving/kv_append_int8.py; under a tensor-parallel mesh
+        through `shard_map` on the kv heads, as the attention kernel).
+        `kernel_append` decides, from the pool, the slots' rank and the
+        step program's `use_pallas`: no option selects the form.
+
+        Everything else keeps XLA's scatters, the reference form: off
+        the chip (every CPU-lowered program), a page or head size the
+        kernel cannot tile, and slots of rank 2 (the linear and the tree
+        verify: a slot's r rows share a tile and would race in a per-row
+        read-modify-write; no benchmark cell speculates). TWO scatters
+        per array (k then v), all advanced indices adjacent (scalar
+        kv-index + scalar row + kh/page/offset) -> plain in-place
+        scatters with natural layouts; a single stacked [2, ...] update
+        makes XLA transpose the whole pool (OOM). Each is a serial loop
+        over KV heads x slots index tuples, 57-70 us whatever a tuple
+        carries: they were 36 % of a Mistral-7B decode step and 56 % of
+        an Ouro step (PERF.md section 5)."""
+        kh, page_idx, offset, use_pallas, mesh = slots
         kq, ks = self._quantize(k_new)
         vq, vs = self._quantize(v_new)
+        if kernel_append(self, use_pallas, page_idx.ndim):
+            return self._append_kernel(row, page_idx, offset, mesh,
+                                       jnp.stack([kq, vq]),
+                                       jnp.stack([ks, vs]))
         kv = self.kv.at[0, row, kh, page_idx[None], offset[None], :].set(kq)
         kv = kv.at[1, row, kh, page_idx[None], offset[None], :].set(vq)
         s = self.s.at[0, row, kh, page_idx[None], offset[None]].set(ks)
         s = s.at[1, row, kh, page_idx[None], offset[None]].set(vs)
+        return QuantPagePool(kv, s, self.page_size)
+
+    def _append_kernel(self, row, page_idx, offset, mesh, codes, scales):
+        """append's kernel form: codes [2, KH, B, Hd], scales [2, KH, B]."""
+        from jax.sharding import PartitionSpec as P
+
+        from generativeaiexamples_tpu.serving.kv_append_int8 import (
+            kv_append_int8)
+
+        fn = kv_append_int8
+        if mesh is not None and mesh.shape.get("tensor", 1) > 1:
+            # kv heads (the TP axis) at axis 2 of the pool, 1 of the new
+            # rows: paged_attention_dispatch's specs for the fused pool
+            fused_s, new_s = P(None, None, "tensor"), P(None, "tensor")
+            fn = jax.shard_map(
+                kv_append_int8, mesh=mesh,
+                in_specs=(fused_s, fused_s, P(), P(), P(), new_s, new_s),
+                out_specs=(fused_s, fused_s), check_vma=False)
+        kv, s = fn(self.kv, self.s, jnp.asarray(row, jnp.int32), page_idx,
+                   offset, codes, scales)
         return QuantPagePool(kv, s, self.page_size)
 
     def encode_pages(self, k, v):

@@ -10,6 +10,7 @@ runs: this says nothing about results or speed (chip_smoke.py does).
 Skipped, not failed, where the topology cannot be described.
 """
 
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
@@ -36,10 +37,10 @@ BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip; the persistent compile cache off around
-    the compiles (an entry written for a described chip cannot be read
-    back without one, and warns on every later run)."""
+def topo():
+    """The described v5e:2x2 topology; the persistent compile cache off
+    around the compiles (an entry written for a described chip cannot be
+    read back without one, and warns on every later run)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -51,9 +52,15 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e chip."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 _POOL_INT8 = [((2, L, KH, P, PS, HD), I8), ((2, L, KH, P, PS), F32)]
@@ -159,12 +166,10 @@ def _staged_weights(text, cfg):
     return found
 
 
-@pytest.mark.parametrize("looped,n_steps", [
-    (False, 1), (False, 2), (False, 8), (True, 2)])
-def test_decode_projections_stage_no_weight_in_a_short_or_looped_block(
-        chip, looped, n_steps):
-    import functools
-
+@functools.cache
+def _compiled_decode(chip, looped, n_steps):
+    """(cfg, compiled text, memory analysis, the pool's bytes) of the
+    int8 `decode_multi_step` with kernels on, compiled once a module."""
     from generativeaiexamples_tpu.models import llama
     from generativeaiexamples_tpu.serving import engine_model as em
     from generativeaiexamples_tpu.serving.kv_cache import PagePool
@@ -183,11 +188,22 @@ def test_decode_projections_stage_no_weight_in_a_short_or_looped_block(
         llama.init_params_on_device, cfg, quantize=True)))
     pool = on_chip(jax.eval_shape(lambda: PagePool.zeros(
         cfg, slots * MAXP + 1, PS, dtype=I8)))
-    text = em.decode_multi_step.lower(
+    compiled = em.decode_multi_step.lower(
         params, cfg, pool, arr((slots,), I32), arr((slots, MAXP), I32),
         arr((slots,), I32), arr((slots,), jnp.bool_), arr((slots,), F32),
         arr((slots,), F32), arr((slots,), I32), arr((2,), jnp.uint32),
-        n_steps, True, sampling_flags=(True, False, False)).compile().as_text()
+        n_steps, True, sampling_flags=(True, False, False)).compile()
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    return cfg, compiled.as_text(), compiled.memory_analysis(), pool_bytes
+
+
+@pytest.mark.parametrize("looped,n_steps", [
+    (False, 1), (False, 2), (False, 8), (True, 2)])
+def test_decode_projections_stage_no_weight_in_a_short_or_looped_block(
+        chip, looped, n_steps):
+    from generativeaiexamples_tpu.serving import engine_model as em
+
+    cfg, text, _, _ = _compiled_decode(chip, looped, n_steps)
     assert "tpu_custom_call" in text
     # the choice reads the passes and the block's length, nothing else
     assert em.direct_qkv(cfg, n_steps) == (
@@ -198,3 +214,81 @@ def test_decode_projections_stage_no_weight_in_a_short_or_looped_block(
         assert not staged, staged
     else:
         assert {op for op, _, _ in staged} == {"fusion", "copy"}, staged
+
+
+@pytest.mark.parametrize("looped,n_steps", [
+    (False, 2), (False, 8), (True, 2)])
+def test_decode_program_appends_with_the_kernel_and_no_scatter(
+        chip, looped, n_steps):
+    """The served decode programs (the short block, the long one, a
+    looped model's) write the new K and V through kv_append_int8, once a
+    step and cache row; no XLA scatter is left anywhere in them, and
+    nothing the size of the pool is a temporary."""
+    import re
+
+    cfg, text, mem, pool_bytes = _compiled_decode(chip, looped, n_steps)
+    assert " scatter(" not in text
+    calls = len(re.findall(r"^\s*(?:ROOT )?%?kv_append_int8[\w.]* = ", text,
+                           re.M))
+    # a looped model's passes are a loop around its blocks
+    assert calls == n_steps * cfg.n_layers, calls
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # (staged weights and logits are a quarter of this small pool)
+    assert mem.temp_size_in_bytes < pool_bytes // 2, mem.temp_size_in_bytes
+
+
+# -- the decode step's K/V append (PR 32) ---------------------------------
+# One in-place Pallas call a cache row (serving/kv_append_int8.py) where
+# XLA ran four scatters. What interpret mode cannot see: that the tile and
+# scale-row DMAs are aligned to the chip's tiling at the cells' shapes,
+# that the pool is ALIASED through the call (no temporary: a copy of a 6
+# or 11 GB pool is "two scatter traps" of docs/ENGINEERING_NOTES.md in a
+# new coat), and that it partitions under shard_map.
+
+# (cache rows, kv heads, slots, pages): the three shapes the cells run
+APPEND_SHAPES = {
+    "mistral-7b": (32, 8, 64, 768),
+    "ouro-2.6b": (192, 16, 32, 112),       # a half over SPLIT_KV_BYTES
+    "mistral-small-tp4": (40, 8, 64, 3072),  # 2 kv heads a chip
+}
+
+
+@pytest.mark.parametrize("name", sorted(APPEND_SHAPES))
+def test_kv_append_kernel_compiles_in_place_for_v5e(topo, chip, name):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
+
+    rows, kv_heads, slots, pages = APPEND_SHAPES[name]
+    mesh = None
+    if name.endswith("tp4"):  # the 2x2 topology, kv heads on "tensor"
+        mesh = Mesh(np.array(topo.devices), ("tensor",))
+    specs = {"pool": PartitionSpec(None, None, "tensor"),
+             "new": PartitionSpec(None, "tensor"), None: PartitionSpec()}
+
+    def arr(shape, dtype, kind=None):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=(
+            NamedSharding(mesh, specs[kind]) if mesh is not None else chip))
+
+    def append(kv, s, row, page_idx, offset, codes, scales):
+        # through the pool's own dispatch: shard_map under the mesh
+        pool = QuantPagePool(kv, s, PS)._append_kernel(
+            row, page_idx, offset, mesh, codes, scales)
+        return pool.kv, pool.s
+
+    compiled = jax.jit(append, donate_argnums=(0, 1)).lower(
+        arr((2, rows, kv_heads, pages, PS, HD), I8, "pool"),
+        arr((2, rows, kv_heads, pages, PS), F32, "pool"),
+        arr((), I32), arr((slots,), I32), arr((slots,), I32),
+        arr((2, kv_heads, slots, HD), I8, "new"),
+        arr((2, kv_heads, slots), F32, "new")).compile()
+    text = compiled.as_text()
+    assert "kv_append_int8" in text and "tpu_custom_call" in text
+    assert " scatter(" not in text
+    mem = compiled.memory_analysis()  # of one device
+    pool_bytes = 2 * rows * kv_heads * pages * PS * (HD + 4)
+    if mesh is not None:
+        pool_bytes //= mesh.size
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 1000, mem

@@ -1,0 +1,309 @@
+"""The int8 pool's append as a Pallas kernel (serving/kv_append_int8.py)
+against XLA's scatters, the reference form that every program lowered off
+the chip keeps (kv_cache.QuantPagePool.append): the kernel interpreted on
+the CPU, the pools compared BYTE FOR BYTE. Pools start from random
+contents, so a tile written back to another place, a neighbour touched or
+a stale read of a tile fails the comparison. What interpret mode cannot
+see (tiling, VMEM, aliasing without a copy) is tests/test_chip_compile.py's.
+
+The interpreter is Pallas's plain one (`interpret=True`: the kernel's body
+as JAX operations inside the calling computation). The TPU interpret mode
+(`pltpu.force_tpu_interpret_mode`) runs a kernel through host callbacks
+that dispatch JAX operations of their own, and deadlocks when another
+thread dispatches to the device meanwhile (the test's next line, or an
+engine's scheduler): under `-n 6` it hung this file in every whole run.
+"""
+
+import contextlib
+import functools
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import pallas as pl
+
+from generativeaiexamples_tpu.serving import kv_cache
+from generativeaiexamples_tpu.serving.kv_append_int8 import kv_append_int8
+from generativeaiexamples_tpu.serving.kv_cache import (
+    PagePool, QuantPagePool, kernel_append, token_slots)
+
+R, PS, HD = 3, 128, 128
+EDGES = (0, 31, 32, 127)  # first and last row of a tile, of a page
+
+
+def _pool(kv_heads, pages, seed=0, rows=R):
+    rng = np.random.default_rng(seed)
+    kv = rng.integers(-127, 128, (2, rows, kv_heads, pages, PS, HD),
+                      dtype=np.int8)
+    s = rng.random((2, rows, kv_heads, pages, PS), dtype=np.float32)
+    return QuantPagePool(jnp.asarray(kv), jnp.asarray(s), PS)
+
+
+def _new_rows(kv_heads, slots, seed=1):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(x, jnp.bfloat16) for x in
+                 rng.standard_normal((2, kv_heads, slots, HD)) * 3)
+
+
+def _same(got, want):
+    np.testing.assert_array_equal(np.asarray(got.kv), np.asarray(want.kv))
+    np.testing.assert_array_equal(np.asarray(got.s), np.asarray(want.s))
+
+
+@contextlib.contextmanager
+def interpreted():
+    """Every `pl.pallas_call` made inside, by whatever module and in
+    whatever thread, is interpreted: the served path has no `interpret`
+    argument to hand down (on the chip nothing is interpreted)."""
+    call = pl.pallas_call
+
+    @functools.wraps(call)
+    def interpreted_call(*args, **kwargs):
+        return call(*args, **{**kwargs, "interpret": True})
+
+    with mock.patch.object(pl, "pallas_call", interpreted_call):
+        yield
+
+
+def _both(pool, row, page_idx, offset, k_new, v_new):
+    """(the kernel's pool, the scatters' pool) for one append."""
+    KH = pool.geometry.kv_heads
+    page_idx, offset = jnp.asarray(page_idx), jnp.asarray(offset)
+    with interpreted():
+        got = pool.append(row, token_slots(KH, page_idx, offset, True),
+                          k_new, v_new)
+    want = pool.append(row, token_slots(KH, page_idx, offset, False),
+                       k_new, v_new)
+    return got, want
+
+
+@pytest.mark.parametrize("kv_heads,slots", [(8, 64), (16, 32), (2, 64)])
+def test_kernel_writes_the_bytes_the_scatters_write(kv_heads, slots):
+    """The three shapes the cells run, every slot on a page of its own,
+    the offsets all over a page and the tile edges among them."""
+    rng = np.random.default_rng(kv_heads)
+    pool = _pool(kv_heads, slots + 1)
+    page_idx = 1 + rng.permutation(slots)
+    offset = rng.integers(0, PS, slots)
+    offset[:4] = EDGES
+    got, want = _both(pool, 1, page_idx, offset, *_new_rows(kv_heads, slots))
+    _same(got, want)
+    assert not np.array_equal(np.asarray(got.kv), np.asarray(pool.kv))
+
+
+@pytest.mark.parametrize("offset", EDGES)
+def test_kernel_at_the_edges_of_a_tile_and_a_page(offset):
+    pool = _pool(2, 4)
+    got, want = _both(pool, 0, [2, 1, 3], [offset] * 3, *_new_rows(2, 3))
+    _same(got, want)
+    touched = np.argwhere(np.asarray(got.kv) != np.asarray(pool.kv))
+    assert set(touched[:, 4]) == {offset} and set(touched[:, 1]) == {0}
+
+
+def test_a_second_append_reads_the_tile_the_first_one_wrote():
+    """Two steps into the same tile: the second call's read-modify-write
+    keeps the first call's row."""
+    pool = _pool(2, 3)
+    k1, v1 = _new_rows(2, 2, seed=1)
+    k2, v2 = _new_rows(2, 2, seed=2)
+    got, want = _both(pool, 2, [1, 2], [5, 63], k1, v1)
+    got2, _ = _both(got, 2, [1, 2], [6, 64], k2, v2)
+    _, want2 = _both(want, 2, [1, 2], [6, 64], k2, v2)
+    _same(got2, want2)
+    np.testing.assert_array_equal(np.asarray(got2.kv)[:, 2, :, 1, 5],
+                                  np.asarray(got.kv)[:, 2, :, 1, 5])
+
+
+def test_a_traced_row_inside_a_fori_loop():
+    """The looped walk's: the row is the loop's counter, and the pool
+    its carry."""
+    KH, B = 2, 3
+    pool = _pool(KH, 4, rows=4)
+    page_idx, offset = jnp.asarray([3, 1, 2]), jnp.asarray([127, 0, 40])
+    k_new, v_new = _new_rows(KH, B)
+
+    def walk(pool, use_pallas):
+        slots = token_slots(KH, page_idx, offset, use_pallas)
+        return jax.lax.fori_loop(
+            1, 4, lambda row, p: p.append(
+                row, slots, k_new * row.astype(jnp.bfloat16), v_new), pool)
+
+    with interpreted():
+        got = jax.jit(walk, static_argnums=1)(pool, True)
+    want = jax.jit(walk, static_argnums=1)(pool, False)
+    _same(got, want)
+    np.testing.assert_array_equal(np.asarray(got.kv)[:, 0],
+                                  np.asarray(pool.kv)[:, 0])
+
+
+@pytest.mark.parametrize("split_kv", [False, True])
+def test_split_descriptors_write_the_same(split_kv):
+    """One descriptor for a tile's K and V, or one each (a pool whose
+    half reaches SPLIT_KV_BYTES: chosen from the shape, forced here)."""
+    KH, B = 2, 3
+    pool = _pool(KH, 4)
+    page_idx, offset = jnp.asarray([1, 3, 2]), jnp.asarray([33, 95, 0])
+    k_new, v_new = _new_rows(KH, B)
+    want = pool.append(1, token_slots(KH, page_idx, offset, False),
+                       k_new, v_new)
+    (kq, ks), (vq, vs) = pool._quantize(k_new), pool._quantize(v_new)
+    kv, s = kv_append_int8(pool.kv, pool.s, 1, page_idx, offset,
+                           jnp.stack([kq, vq]), jnp.stack([ks, vs]),
+                           interpret=True, split_kv=split_kv)
+    _same(QuantPagePool(kv, s, PS), want)
+
+
+def test_inactive_slots_share_the_sink_page_and_harm_no_one():
+    """As the engine sends them: inactive slots at (page 0, offset 0).
+    Their tiles race among themselves; every live slot's bytes are the
+    scatters', and the sink holds one of the inactive slots' rows."""
+    KH, B = 2, 6
+    pool = _pool(KH, 4)
+    page_idx, offset = [2, 0, 0, 3, 0, 1], [17, 0, 0, 127, 0, 32]
+    k_new, v_new = _new_rows(KH, B)
+    got, want = _both(pool, 1, page_idx, offset, k_new, v_new)
+    g_kv, w_kv = np.array(got.kv), np.array(want.kv)
+    g_s, w_s = np.array(got.s), np.array(want.s)
+    kq = np.asarray(pool._quantize(k_new)[0])  # [KH, B, Hd]
+    assert any(np.array_equal(g_kv[0, 1, :, 0, 0], kq[:, b])
+               for b in (1, 2, 4))
+    g_kv[:, 1, :, 0, 0] = w_kv[:, 1, :, 0, 0]
+    g_s[:, 1, :, 0, 0] = w_s[:, 1, :, 0, 0]
+    np.testing.assert_array_equal(g_kv, w_kv)
+    np.testing.assert_array_equal(g_s, w_s)
+
+
+def test_slots_of_rank_two_keep_the_scatters(monkeypatch):
+    """A verify's r rows a slot share a tile: they never reach the
+    kernel, whatever use_pallas says; nor does a bf16 pool, nor a page
+    the kernel's DMAs cannot tile."""
+    def no_kernel(*a, **k):
+        raise AssertionError("the kernel was reached")
+
+    monkeypatch.setattr(QuantPagePool, "_append_kernel", no_kernel)
+    KH, B, r = 2, 2, 3
+    pool = _pool(KH, 4)
+    page_idx = jnp.asarray([[1, 1, 1], [2, 2, 3]])
+    offset = jnp.asarray([[4, 5, 6], [126, 127, 0]])
+    rng = np.random.default_rng(3)
+    k_new, v_new = (jnp.asarray(x, jnp.bfloat16) for x in
+                    rng.standard_normal((2, KH, B, r, HD)))
+    got = pool.append(0, token_slots(KH, page_idx, offset, True),
+                      k_new, v_new)
+    _same(got, pool.append(0, token_slots(KH, page_idx, offset, False),
+                           k_new, v_new))
+    assert not kernel_append(pool, True, rank=2)
+    assert kernel_append(pool, True) and not kernel_append(pool, False)
+    assert not kernel_append(pool, None)  # no TPU here
+    bf16 = PagePool(jnp.zeros((R, KH, 4, PS, HD), jnp.bfloat16),
+                    jnp.zeros((R, KH, 4, PS, HD), jnp.bfloat16), PS)
+    assert not kernel_append(bf16, True)
+    said = []
+    monkeypatch.setattr(kv_cache, "log_kernel_declined",
+                        lambda *a: said.append(a))
+    small = QuantPagePool(jnp.zeros((2, R, KH, 4, 16, HD), jnp.int8),
+                          jnp.zeros((2, R, KH, 4, 16), jnp.float32), 16)
+    assert not kernel_append(small, True)
+    assert said and said[0][0] == "kv_append_int8"
+
+
+# -- through the step program and the engine -------------------------------
+
+def _tiny(looped=False):
+    import dataclasses
+
+    from generativeaiexamples_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=96, dim=256, n_layers=2, n_heads=2, n_kv_heads=2,
+        head_dim=128, mlp_dim=128, max_seq_len=256, dtype=jnp.float32)
+    if looped:
+        cfg = dataclasses.replace(cfg, n_passes=2, post_norms=True)
+    return cfg
+
+
+@pytest.mark.parametrize("looped", [False, True])
+def test_decode_multi_step_with_the_kernel_gives_the_scatters_tokens_and_pool(
+        looped, monkeypatch):
+    """One tiny int8 `decode_multi_step` with kernels on (interpreted),
+    its append the kernel, against the same program made to keep the
+    scatters: the same tokens, and the same pool byte for byte outside
+    the sink page. (Against the program the CPU serves the tokens are
+    the same too; its attention is the XLA reference, whose rounding
+    moves a later layer's scales by an ulp.)"""
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.serving import engine_model as em
+
+    cfg = _tiny(looped)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    B, maxp, K = 4, 2, 3
+    tables = np.zeros((B, maxp), np.int32)
+    tables[0], tables[1], tables[3] = (1, 2), (3, 4), (5, 6)
+    lengths = np.array([127, 5, 1, 32], np.int32)  # slot 2 is inactive
+    active = np.array([True, True, False, True])
+
+    def run(use_pallas):
+        rng = np.random.default_rng(0)
+        shape = (2, cfg.cache_rows, cfg.n_kv_heads, 7, PS, HD)
+        pool = QuantPagePool(
+            jnp.asarray(rng.integers(-127, 128, shape, np.int8)),
+            jnp.asarray(rng.random(shape[:-1], np.float32) * 0.02), PS)
+        step = jax.jit(em.decode_multi_step.__wrapped__, static_argnames=(
+            "cfg", "n_steps", "use_pallas", "sampling_flags"))  # traced anew
+        with interpreted():
+            return step(
+                params, cfg, pool, jnp.asarray([3, 9, 0, 27], jnp.int32),
+                jnp.asarray(tables), jnp.asarray(lengths),
+                jnp.asarray(active), jnp.zeros((B,), jnp.float32),
+                jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
+                jax.random.PRNGKey(1), K, use_pallas,
+                sampling_flags=(True, False, False))
+
+    block_k, last_k, pool_k = run(True)
+    monkeypatch.setattr(kv_cache, "kernel_append", lambda *a, **k: False)
+    block_s, last_s, pool_s = run(True)
+    np.testing.assert_array_equal(np.asarray(block_k), np.asarray(block_s))
+    np.testing.assert_array_equal(np.asarray(last_k), np.asarray(last_s))
+    np.testing.assert_array_equal(np.asarray(pool_k.kv)[:, :, :, 1:],
+                                  np.asarray(pool_s.kv)[:, :, :, 1:])
+    np.testing.assert_array_equal(np.asarray(pool_k.s)[:, :, :, 1:],
+                                  np.asarray(pool_s.s)[:, :, :, 1:])
+    block_cpu, _, _ = run(False)  # the program the CPU serves
+    np.testing.assert_array_equal(np.asarray(block_k), np.asarray(block_cpu))
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas):
+    """`decode_steps_kernel_append` equals `decode_steps` for a plain
+    int8 engine with kernels on and is 0, never absent, with them off;
+    it is in snapshot(), in /metrics and among the fleet's sums."""
+    from generativeaiexamples_tpu.config.schema import EngineConfig
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.serving import fleet
+    from generativeaiexamples_tpu.serving.engine import LLMEngine
+    from generativeaiexamples_tpu.serving.flight import prometheus_text
+    from generativeaiexamples_tpu.utils.tokenizer import ByteTokenizer
+
+    cfg = _tiny()
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    ecfg = EngineConfig(max_batch_size=2, max_seq_len=256, page_size=PS,
+                        kv_dtype="int8", prefill_buckets=(128,),
+                        decode_steps_per_dispatch=2,
+                        pace_emission_max_streams=0)
+    with interpreted() if use_pallas else contextlib.nullcontext():
+        eng = LLMEngine(params, cfg, ByteTokenizer(), ecfg,
+                        use_pallas=use_pallas).start()
+        try:
+            served = [ev["token_id"] for ev in eng.generate_stream(
+                [5, 6, 7, 8], max_new_tokens=6) if ev["token_id"] >= 0]
+            snap = eng.metrics.snapshot()
+        finally:
+            eng.stop()
+    assert len(served) == 6
+    assert snap["decode_steps"] > 0
+    assert snap["decode_steps_kernel_append"] == (
+        snap["decode_steps"] if use_pallas else 0)
+    assert "decode_steps_kernel_append" in fleet._COUNTER_KEYS
+    assert "decode_steps_kernel_append" in prometheus_text(snap)
