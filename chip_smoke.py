@@ -804,6 +804,45 @@ def child_device_ops(args) -> None:
           f"bytes, {secs * 1e3:.3f} ms {'ok' if ok else 'FAILED'}", flush=True)
     if not ok:
         failures.append("int8 K/V append")
+    # latent attention's absorbed paged kernel and the held experts'
+    # grouped int8 matmul (A.X-K1's widths on the chip), each against
+    # its XLA form
+    from generativeaiexamples_tpu.ops import moe
+    from generativeaiexamples_tpu.ops.quant import QuantizedTensor
+    from generativeaiexamples_tpu.serving.paged_attention_mla import (
+        paged_attention_mla, paged_attention_mla_reference)
+
+    mH, mC, mW = (4, 128, 256) if interpret else (64, 512, 640)
+    mla_pool = rand(2, P, ps, mW).at[..., mW - 64:].set(0)
+    mla_q = (rand(B, mH, mW) * 0.1).at[..., mW - 64:].set(0)
+    mla = dict(latent=mC, scale=mW ** -0.5)
+    compare("latent paged decode (absorbed)",
+            lambda q, pool, t, ln: paged_attention_mla(
+                q, pool, layer, t, ln, interpret=interpret, **mla),
+            (mla_q, mla_pool, table, lengths),
+            ref(lambda q, pool, t, ln: paged_attention_mla_reference(
+                q, pool, layer, t, ln, **mla), mla_q, mla_pool, table,
+                lengths))
+    gE, gK, gN, gT, gk = (4, 128, 256, 16, 4) if interpret \
+        else (12, 2048, 7168, 128, 8)
+    local = jnp.asarray(rng.integers(0, 16 * gE, (gT, gk)), jnp.int32)
+    plan = moe.dispatch_plan(jnp.minimum(local, gE), gE)  # 1 in 16 held
+    gx = rand(plan.rows.shape[0], gK)
+    gw = QuantizedTensor(
+        jnp.asarray(rng.integers(-127, 128, (2, gE, gK, gN)), jnp.int8),
+        jnp.full((2, gE, gN), gK ** -0.5 * 3 ** 0.5 / 127, jnp.float32))
+    used = np.repeat(np.arange(plan.tile_group.shape[0])
+                     < int(plan.n_tiles[0]), plan.tm)[:, None]
+    compare("grouped int8 expert matmul",
+            lambda x, q, s, *pl_: jnp.where(used, moe.grouped_matmul_pallas(
+                x, QuantizedTensor(q, s), layer,
+                moe.DispatchPlan(None, None, *pl_, None, plan.tm),
+                interpret=interpret), 0),
+            (gx, gw.q, gw.s, plan.tile_group, plan.n_tiles),
+            np.where(used, ref(lambda x, q, s, *pl_: moe.grouped_matmul_reference(
+                x, QuantizedTensor(q, s), layer,
+                moe.DispatchPlan(None, None, *pl_, None, plan.tm)),
+                gx, gw.q, gw.s, plan.tile_group, plan.n_tiles), 0))
     want = ref(paged_attention_reference, q1, *pool16)
     compare("bf16 paged decode (in-repo kernel)",
             lambda *a: paged_attention(*a, interpret=interpret),

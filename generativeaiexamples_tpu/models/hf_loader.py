@@ -187,6 +187,12 @@ def llama_config_from_hf(path: str) -> llama_lib.LlamaConfig:
     `_require_llama_names`.)"""
     with open(os.path.join(path, "config.json")) as fh:
         c = json.load(fh)
+    if "kv_lora_rank" in c:
+        raise ValueError(
+            f"{path}: model_type {c.get('model_type')!r} has latent "
+            "attention (kv_lora_rank): it is no LlamaConfig; its "
+            "configuration is models/latent_moe.py's LatentMoeConfig, and "
+            "this loader holds no tensor-name map for it")
     family = {}
     if c.get("model_type") in _LOOPED_TYPES:
         family = dict(n_passes=int(c["total_ut_steps"]), post_norms=True)

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -56,6 +57,56 @@ class RopeScaling:
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling as the DeepSeek-V3 family's code reads the HF
+    keys (`rope_scaling` with `type: "yarn"`): every dimension's
+    frequency is a blend of the original and the interpolated one
+    (divided by `factor`), along a linear ramp between the correction
+    dimensions of `beta_fast` and `beta_slow` rotations at the original
+    length. `softmax_mscale` multiplies the attention's softmax scale
+    (the family squares it: once for q, once for k); `cos_sin_scale`
+    multiplies cos and sin."""
+
+    factor: float = 32.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+    original_max_position_embeddings: int = 4096
+
+    @staticmethod
+    def _mscale(factor: float, m: float) -> float:
+        return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    @property
+    def softmax_mscale(self) -> float:
+        return self._mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+
+def yarn_freqs(head_dim: int, theta: float, s: YarnScaling) -> jax.Array:
+    """Inverse frequencies [Hd/2] under YaRN."""
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(s.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(s.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(s.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                      / head_dim)
+    return extra / s.factor * ramp + extra * (1.0 - ramp)
+
+
+@dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 128256
     dim: int = 4096
@@ -83,6 +134,12 @@ class LlamaConfig:
         """Rows of a KV cache: one per (pass, block), row u * n_layers + l.
         The cache's leading axis is THIS, not the weights' layer axis."""
         return self.n_layers * self.n_passes
+
+    @property
+    def latent_row(self):
+        """What a cache row holds when it is not K and V per head: None
+        here (serving/kv_cache.py builds the pool from this)."""
+        return None
 
     @property
     def post_norm_init(self) -> float:
@@ -288,7 +345,9 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
 
 def rope_freqs(head_dim: int, theta: float,
                scaling: Optional[RopeScaling] = None) -> jax.Array:
-    """Inverse frequencies [Hd/2], with optional llama3 scaling."""
+    """Inverse frequencies [Hd/2], with optional llama3 or YaRN scaling."""
+    if isinstance(scaling, YarnScaling):
+        return yarn_freqs(head_dim, theta, scaling)
     freqs = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     if scaling is None:
         return freqs
@@ -310,6 +369,8 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
     freqs = rope_freqs(Hd, theta, scaling)  # [Hd/2]
     angles = positions[:, None, :, None].astype(jnp.float32) * freqs  # [B,1,S,Hd/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if isinstance(scaling, YarnScaling) and scaling.cos_sin_scale != 1.0:
+        cos, sin = cos * scaling.cos_sin_scale, sin * scaling.cos_sin_scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -436,17 +497,28 @@ def finish_block(cfg: LlamaConfig, x, out, w):
     """The block from its attention's output `out` [B, H, S, Hd] on:
     the output projection and the feed-forward, each added to the
     stream `x` (add_branch)."""
+    x = attn_out(cfg, x, out, w)
+    h = rms_norm(x, w["ln2"], cfg.rms_eps).astype(cfg.dtype)
+    return add_branch(cfg, x, swiglu(h, w), w, "ln2_post", "mlp.post_norm")
+
+
+def attn_out(cfg: LlamaConfig, x, out, w):
+    """The attention branch's end: heads `out` [B, H, S, Hd] through the
+    output projection, added to the stream `x`."""
     B, S, _ = x.shape
     with jax.named_scope("attn.out"):
-        x = add_branch(
+        return add_branch(
             cfg, x, mm(out.transpose(0, 2, 1, 3).reshape(B, S, -1), w["wo"]),
             w, "ln1_post", "attn.post_norm")
-    h = rms_norm(x, w["ln2"], cfg.rms_eps).astype(cfg.dtype)
+
+
+def swiglu(h, w):
+    """SwiGLU of the normed stream `h` with w_gate / w_up / w_down: the
+    dense feed-forward, and a sparse layer's shared expert."""
     with jax.named_scope("mlp.gate_up"):
         h = jax.nn.silu(mm(h, w["w_gate"])) * mm(h, w["w_up"])
     with jax.named_scope("mlp.down"):
-        return add_branch(cfg, x, mm(h, w["w_down"]), w, "ln2_post",
-                          "mlp.post_norm")
+        return mm(h, w["w_down"])
 
 
 def _layer(cfg: LlamaConfig, x, w, positions, kv, kv_lengths, attn_lengths,

@@ -179,6 +179,7 @@ def flash_attention(
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     KH = k.shape[1]
+    Dv = v.shape[3]  # a latent-attention prompt: values narrower than keys
     group = H // KH
     scale = scale if scale is not None else D ** -0.5
     # Shrink blocks to the largest power-of-two divisor (callers run
@@ -211,22 +212,22 @@ def flash_attention(
                          lambda b, h, qi, ki, L, O: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, qi, ki, L, O: (b, h // group, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
+            pl.BlockSpec((1, 1, block_k, Dv),
                          lambda b, h, qi, ki, L, O: (b, h // group, ki, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, block_q, D), lambda b, h, qi, ki, L, O: (b, h, qi, 0)
+            (1, 1, block_q, Dv), lambda b, h, qi, ki, L, O: (b, h, qi, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
         interpret=interpret,
     )(lengths.astype(jnp.int32), q_offset.astype(jnp.int32), q, k, v)
 

@@ -73,9 +73,9 @@ from generativeaiexamples_tpu.serving.multihost import (
     fetch_replicated as mh_fetch_replicated)
 from generativeaiexamples_tpu.serving.flight import (
     EV_ADMIT, EV_ADMIT_RETRY, EV_FIRST_TOKEN, EV_KV_DEMOTE, EV_KV_PROMOTE,
-    EV_KV_TRANSFER, EV_PREFILL_CHUNK, EV_PREFILL_DISPATCH, EV_QOS_PAUSE,
-    EV_QOS_PICK, EV_QOS_RESUME, EV_RETIRE, EV_SUBMIT, RETIRE_CODES,
-    ExpHistogram, FlightRecorder)
+    EV_KV_TRANSFER, EV_MOE_LOAD, EV_PREFILL_CHUNK, EV_PREFILL_DISPATCH,
+    EV_QOS_PAUSE, EV_QOS_PICK, EV_QOS_RESUME, EV_RETIRE, EV_SUBMIT,
+    RETIRE_CODES, ExpHistogram, FlightRecorder)
 from generativeaiexamples_tpu.serving.qos import request_tier, tier_id
 from generativeaiexamples_tpu.utils.tokenizer import StreamDetokenizer
 
@@ -321,6 +321,12 @@ class EngineMetrics:
         # all rows, scales included.
         self.kv_cache_rows = 0
         self.kv_bytes_per_token = 0
+        # Sparse experts (0 for a model without them): token-expert
+        # pairs the router chose in decode steps, those that fell on
+        # experts held here and were computed, and the experts held.
+        self.moe_pairs_routed = 0
+        self.moe_pairs_local = 0
+        self.experts_held = 0
         self.busy_slots_acc = 0
         # Speculative decoding: committed tokens vs slot-steps, for the
         # acceptance-rate gauge (1.0 = no drafts accepted, k+1 = all).
@@ -478,6 +484,9 @@ class EngineMetrics:
             "decode_steps_kernel_append": self.decode_steps_kernel_append,
             "kv_cache_rows": self.kv_cache_rows,
             "kv_bytes_per_token": self.kv_bytes_per_token,
+            "moe_pairs_routed": self.moe_pairs_routed,
+            "moe_pairs_local": self.moe_pairs_local,
+            "experts_held": self.experts_held,
             "mean_batch_occupancy": occ,
             "tokens_per_sec": self.tokens_per_sec(),
             "prefill_tokens": self.prefill_tokens,
@@ -576,7 +585,29 @@ _ONE_PASS_LANES = (
 )
 
 
-def _refuse_unwalked_lanes(cfg: LlamaConfig, ecfg: EngineConfig) -> None:
+def _refuse_unwalked_lanes(cfg: LlamaConfig, ecfg: EngineConfig,
+                           mesh=None) -> None:
+    if cfg.latent_row is not None:
+        # A latent page pool (kv_cache.LatentPagePool) is written by the
+        # prefill and decode programs only: nothing reads its pages
+        # back, moves or shares them, and no step but those two has the
+        # latent block.
+        on = [(name, what) for name, what in _ONE_PASS_LANES
+              if getattr(ecfg, name)]
+        if mesh is not None:
+            on.append(("mesh", "tensor parallelism over heads: a latent "
+                       "row is one vector for all heads"))
+        if jnp.dtype(ecfg.kv_dtype) == jnp.int8:
+            on.append(("kv_dtype int8", "an int8 latent pool"))
+        if ecfg.multihost:
+            on.append(("multihost", "the multi-host replay"))
+        if on:
+            raise ValueError(
+                f"model caches a latent row of {sum(cfg.latent_row)} values "
+                f"a token and layer (latent attention); not served with "
+                + ", ".join(f"engine.{name} ({what})" for name, what in on)
+                + ": those lanes have no latent form; turn them off")
+        return
     if cfg.n_passes == 1:
         return
     on = [(name, what) for name, what in _ONE_PASS_LANES
@@ -640,7 +671,7 @@ class LLMEngine:
             self._mh_log = mh.DispatchLog()
             self._mh_leader = jax.process_index() == 0
         self._mh_stop_sent = False
-        _refuse_unwalked_lanes(cfg, self.ecfg)
+        _refuse_unwalked_lanes(cfg, self.ecfg, self.mesh)
         ps = self.ecfg.page_size
         if self.ecfg.max_seq_len < ps:
             raise ValueError(
@@ -746,6 +777,9 @@ class LLMEngine:
         self.waiting: deque[GenRequest] = deque()
         self.metrics = EngineMetrics()
         self.metrics.kv_cache_rows = cfg.cache_rows
+        self._load_rows = engine_model.expert_load_rows(cfg)
+        self.metrics.experts_held = (cfg.experts_held if self._load_rows
+                                     else 0)
         self.metrics.kv_bytes_per_token = sum(
             leaf.nbytes for leaf in jax.tree.leaves(self.pool)
         ) // (n_pages * ps)
@@ -1491,6 +1525,10 @@ class LLMEngine:
         # scatter into the page pool), so the real ceiling is the page
         # capacity minus one generated token.
         max_prompt = self.max_pages * self.ecfg.page_size - 1
+        if self.cfg.latent_row is not None:
+            # the chunked long-prompt lane (a contiguous scratch cache of
+            # K and V per head) has no latent form
+            max_prompt = min(max_prompt, self.buckets[-1])
         if len(req.prompt_ids) > max_prompt:
             if not req.truncate_prompt:
                 raise PromptTooLongError(
@@ -1965,6 +2003,8 @@ class LLMEngine:
             with _phase("sched.fetch"):
                 host = self._fetch_block_host(fl)
             t_ready = time.perf_counter()
+            if self._load_rows and not isinstance(host, tuple):
+                host = self._note_expert_load(fl, host, t_ready)
             with _phase("sched.emit"):
                 self._process_block_host(fl, host)
         except Exception:
@@ -1983,6 +2023,20 @@ class LLMEngine:
             self._note_prefill_stalls()
             self._record_beat(fl, t_ready,
                               self.metrics.tokens_out - tokens_before)
+
+    def _note_expert_load(self, fl: _InFlight, host, t_ready: float):
+        """A landed decode block of a model with experts carries, below
+        its token rows, the pairs each held expert of each expert block
+        took in each step (engine_model.expert_load_rows): count them,
+        write the block's `moe_load` event, hand back the token rows."""
+        load = host[-self._load_rows:, 1:]        # [Lm * E, K]
+        pairs = int(load.sum())
+        self.metrics.moe_pairs_local += pairs
+        busiest = float(load.sum(axis=1).max())   # one expert of one block
+        self.flight.record_event(
+            EV_MOE_LOAD, t_ready, a=pairs / (fl.K * self.cfg.n_moe_layers),
+            b=busiest * self._load_rows / pairs if pairs else 0.0)
+        return host[:-self._load_rows]
 
     # graftlint: hot-path
     def _record_beat(self, fl: _InFlight, t_ready: float,
@@ -3182,8 +3236,13 @@ class LLMEngine:
         # the plain and the fused decode programs choose their form so;
         # a speculative engine's programs keep the staged one
         if not (plan.spec_k or plan.spec_state) \
+                and self.cfg.latent_row is None \
                 and engine_model.direct_qkv(self.cfg, K):
             self.metrics.decode_steps_direct_qkv += K
+        if self._load_rows:  # every live slot's token, in every expert block
+            self.metrics.moe_pairs_routed += (
+                len(active) * K * self.cfg.n_moe_layers
+                * self.cfg.n_experts_per_tok)
         # every decode program but the verifies writes one row a slot
         if not plan.spec_k and kernel_append(self.pool, self.use_pallas):
             self.metrics.decode_steps_kernel_append += K
@@ -3931,9 +3990,10 @@ class LLMEngine:
         if spacing < 0.004:
             # First block, or blocks landing fast enough that bursts
             # are already smooth — pacing would only add wakeup churn.
-            for ev in slot.pace_buf:
-                slot.req.stream.put(ev)
-            slot.pace_buf = []
+            # What the pacer still holds of a SLOWER block before this
+            # one goes first: putting this burst past it handed a
+            # stream its tokens out of order (ROADMAP D7).
+            self._pace_flush(slot)
             return
         with self._pace_lock:
             prev = self._pace_entries.pop(id(slot), None)

@@ -26,8 +26,9 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from generativeaiexamples_tpu.models import latent_moe
 from generativeaiexamples_tpu.models.llama import (
-    LlamaConfig, final_norm, finish_block, project_qkv, rms_norm,
+    LlamaConfig, attn_out, final_norm, finish_block, project_qkv, rms_norm,
     walk_passes)
 from generativeaiexamples_tpu.ops import attention as attn_ops
 from generativeaiexamples_tpu.ops.quant import mm
@@ -127,6 +128,98 @@ def _logits(cfg: LlamaConfig, params, x):
         return mm(x, params["lm_head"]).astype(jnp.float32)
 
 
+# -- latent attention and sparse experts (models/latent_moe.py) ------------
+#
+# A model whose cache row is a latent (cfg.latent_row) runs the SAME four
+# programs (prefill_step, prefill_batch_step, decode_step,
+# decode_multi_step), scheduler and page allocator; what differs is the
+# block, so each program takes the two functions below where a Llama
+# takes its own. The speculative, fused and chunked programs have no
+# latent form: LLMEngine refuses those lanes by name at construction.
+
+
+def _latent_prefill(params, cfg, pool, tokens, lengths, table_rows,
+                    use_pallas):
+    """Prompts [N, S] in their un-absorbed form (keys and values built
+    from the prompt's own latent rows); the rows of every layer go to
+    the slots' pages in one write. -> (last-position logits [N, V], pool)."""
+    N, S = tokens.shape
+    ps = pool.page_size
+    x, rows, _ = latent_moe.walk_prompt(params, cfg, tokens, lengths,
+                                        use_pallas)
+    pages = pool.encode_pages(rows)  # [R, N, S, W]
+    pages = pages.reshape(pages.shape[0], N * (S // ps), ps, -1)
+    pool = pool.write_pages(pages, table_rows.reshape(-1))
+    last = jnp.take_along_axis(
+        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
+    return latent_moe.logits_of(cfg, params, last)[:, 0], pool
+
+
+def _latent_decode_once(params, cfg, pool, tokens, page_tables, lengths,
+                        use_pallas, mask=None):
+    """_decode_once for a latent model, write-then-attend: every block
+    appends the new token's [c_kv ; k_rope] and attends in the absorbed
+    form through the paged kernel (serving/paged_attention_mla.py); the
+    blocks unrolled, each weight an operand of its matmul and the held
+    experts' stacks read where they lie. `mask` [B]: the slots whose
+    token-expert pairs count and are computed (idle slots: none).
+    Returns (logits [B, V], pool, pairs each held expert took in each
+    expert block [Lm, E], the router's choices [Lm, B, k])."""
+    from generativeaiexamples_tpu.serving.paged_attention_mla import (
+        paged_attention_mla_dispatch)
+
+    B = tokens.shape[0]
+    ps = pool.page_size
+    C, _ = cfg.latent_row
+    positions = (lengths - 1)[:, None]
+    slots = token_slots(1, page_tables[jnp.arange(B), (lengths - 1) // ps],
+                        (lengths - 1) % ps)
+    x = params["tok_emb"][tokens][:, None].astype(cfg.residual_dtype)
+
+    def block(x, pool, w, row, experts=None, layer=None):
+        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
+        q_nope, q_rope, new = latent_moe.project_latent(cfg, h, w, positions)
+        pool = pool.append(row, slots, new[:, 0])
+
+        def attend(q):
+            c, r = pool.attention_operands(row)
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, c.shape[-1] - q.shape[-1])))
+            return paged_attention_mla_dispatch(
+                q, c, r, page_tables, lengths, latent=C,
+                scale=cfg.softmax_scale, use_pallas=use_pallas)
+
+        out = latent_moe.attend_cached(cfg, q_nope[:, 0], q_rope[:, 0], w,
+                                       attend)
+        x = attn_out(cfg, x, out[:, :, None, :], w)
+        x, counts, idx = latent_moe.feed_forward(cfg, x, w, experts, layer,
+                                                 use_pallas, mask)
+        return x, pool, counts, idx
+
+    for l in range(cfg.n_dense_layers):
+        x, pool, _, _ = block(x, pool, latent_moe.take_layer(
+            params["dense"], l), l)
+    counts, choices = [], []
+    _, experts = latent_moe.split_experts(params["layers"])
+    for l in range(cfg.n_moe_layers):
+        w = latent_moe.take_layer(params["layers"], l,
+                                  skip=latent_moe.EXPERT_WEIGHTS)
+        x, pool, n, idx = block(x, pool, w, cfg.n_dense_layers + l, experts, l)
+        counts.append(n)
+        choices.append(idx[:, 0])
+    logits = latent_moe.logits_of(cfg, params, x)[:, 0]
+    return logits, pool, jnp.stack(counts), jnp.stack(choices)
+
+
+def expert_load_rows(cfg) -> int:
+    """Rows a decode block carries below its B token rows: one per
+    (expert block, held expert), column 1 + i the pairs it took in step
+    i; 0 for a model without experts. The engine splits them off where
+    the block lands (one readback, as before)."""
+    if cfg.latent_row is None:
+        return 0
+    return cfg.n_moe_layers * cfg.experts_held
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "use_pallas", "mesh"),
                    donate_argnames=("pool",))
 def prefill_step(
@@ -144,6 +237,10 @@ def prefill_step(
     written once afterwards, all rows of all passes in the one scatter
     — never re-stacked through scan outputs (that would copy the whole
     pool per call)."""
+    if cfg.latent_row is not None:
+        logits, pool = _latent_prefill(params, cfg, pool, tokens,
+                                       length[None], table_row, use_pallas)
+        return logits[0], pool
     _, S = tokens.shape
     ps = pool.page_size
     npages = S // ps
@@ -201,6 +298,13 @@ def prefill_batch_step(
     caller. Compiles per (N_bucket, S_bucket)."""
     from generativeaiexamples_tpu.serving.sampling import SamplingParams, sample
 
+    all_greedy, any_top_k, any_top_p = sampling_flags
+    sp = SamplingParams(temperature, top_p, top_k)
+    if cfg.latent_row is not None:
+        logits, pool = _latent_prefill(params, cfg, pool, tokens, lengths,
+                                       table_rows, use_pallas)
+        return sample(logits, sp, key, all_greedy=all_greedy,
+                      any_top_k=any_top_k, any_top_p=any_top_p), pool
     N, S = tokens.shape
     ps = pool.page_size
     npages = S // ps
@@ -235,8 +339,6 @@ def prefill_batch_step(
     last = jnp.take_along_axis(
         x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
     logits = _logits(cfg, params, last)[:, 0]  # [N, V]
-    all_greedy, any_top_k, any_top_p = sampling_flags
-    sp = SamplingParams(temperature, top_p, top_k)
     toks = sample(logits, sp, key, all_greedy=all_greedy,
                   any_top_k=any_top_k, any_top_p=any_top_p)
     return _replicate_tokens(mesh, toks), pool
@@ -349,6 +451,9 @@ def decode_step(
     mesh=None,
 ) -> Tuple[jax.Array, PagePool]:
     """One decode step for the whole slot batch -> (logits [B, V], pool)."""
+    if cfg.latent_row is not None:
+        return _latent_decode_once(params, cfg, pool, tokens, page_tables,
+                                   lengths, use_pallas)[:2]
     return _decode_once(params, cfg, pool, tokens, page_tables, lengths,
                         use_pallas, mesh, direct_qkv(cfg, 1))
 
@@ -387,19 +492,30 @@ def decode_multi_step(
     all_greedy, any_top_k, any_top_p = sampling_flags
     tokens = last_tokens
     out_tokens = [tokens]
-    direct = direct_qkv(cfg, n_steps)
+    latent = cfg.latent_row is not None
+    direct = not latent and direct_qkv(cfg, n_steps)
+    loads = []  # a model with experts: the pairs each took, step by step
     for i in range(n_steps):
-        logits, pool = _decode_once(
-            params, cfg, pool, tokens, page_tables, lengths, use_pallas, mesh,
-            direct)
+        if latent:
+            logits, pool, load, _ = _latent_decode_once(
+                params, cfg, pool, tokens, page_tables, lengths, use_pallas,
+                mask=active)
+            loads.append(load.reshape(-1))
+        else:
+            logits, pool = _decode_once(
+                params, cfg, pool, tokens, page_tables, lengths, use_pallas,
+                mesh, direct)
         rng, key = jax.random.split(rng)
         nxt = sample(logits, sp, key, all_greedy=all_greedy,
                      any_top_k=any_top_k, any_top_p=any_top_p)
         tokens = jnp.where(active, nxt, tokens)
         out_tokens.append(tokens)
         lengths = jnp.where(active, lengths + 1, lengths)
-    block, tokens = _replicate_tokens(
-        mesh, jnp.stack(out_tokens, axis=1), tokens)
+    block = jnp.stack(out_tokens, axis=1)
+    if loads:  # expert_load_rows(cfg) rows below the slots' (one readback)
+        block = jnp.concatenate([block, jnp.stack(
+            [jnp.zeros_like(loads[0])] + loads, axis=1).astype(block.dtype)])
+    block, tokens = _replicate_tokens(mesh, block, tokens)
     return block, tokens, pool
 
 

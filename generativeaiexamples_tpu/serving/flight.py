@@ -72,6 +72,11 @@ EV_CHAOS = 17          # chaos injection (aux = "<kind>:<rid>")
 # control op), so the single-writer ring invariant holds and the
 # analyzer attributes the beat gap it causes to "disagg".
 EV_KV_TRANSFER = 18
+# Sparse experts: one a landed decode block of a model that has them
+# (scheduler thread). a = token-expert pairs computed on the experts
+# held here, per step and expert layer; b = the pairs of the busiest
+# held expert of any one layer over the mean (1.0 = even load).
+EV_MOE_LOAD = 19
 
 EVENT_NAMES = {
     EV_SUBMIT: "submit", EV_QOS_PICK: "qos_pick", EV_ADMIT: "admit",
@@ -83,6 +88,7 @@ EVENT_NAMES = {
     EV_SCALE_UP: "scale_up", EV_SCALE_DOWN: "scale_down",
     EV_SCALE_WAKE: "scale_wake", EV_UPGRADE: "upgrade",
     EV_CHAOS: "chaos", EV_KV_TRANSFER: "kv_transfer",
+    EV_MOE_LOAD: "moe_load",
 }
 
 # Retire reason codes (EV_RETIRE.code); anything unknown maps to -1.

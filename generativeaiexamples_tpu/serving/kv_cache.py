@@ -3,7 +3,11 @@
 The TPU-native replacement for what TRT-LLM's paged KV manager does
 inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
 
-- Device: one page pool per model, k/v arrays [R, KH, P, page_size, Hd],
+- Device: one page pool per model, built from what the model says a row
+  caches. A latent-attention model (cfg.latent_row) caches one vector
+  per token, shared by all heads: LatentPagePool, [R, P, page_size, W].
+  Every other model caches K and V per head: k/v arrays
+  [R, KH, P, page_size, Hd],
   R = cfg.cache_rows: one row per layer, times the passes of a looped
   model (a row per (pass, block); written "L" below where a model has
   one pass) (kv-heads outermost after the row axis: per-layer slices are the
@@ -211,6 +215,13 @@ class PagePool:
         whole mesh must never materialize on one device first.
         `dtype="int8"` returns the fused QuantPagePool."""
         dtype = jnp.dtype(dtype or cfg.dtype)
+        if cfg.latent_row is not None:  # the model says what a row caches
+            if dtype == jnp.int8:
+                raise ValueError(
+                    "engine.kv_dtype int8: a latent page pool "
+                    "(kv_cache.LatentPagePool) has no int8 form yet")
+            return LatentPagePool.zeros(cfg, n_pages, page_size, dtype,
+                                        sharding)
         if dtype == jnp.int8:
             return QuantPagePool.zeros(cfg, n_pages, page_size,
                                        sharding=sharding,
@@ -425,8 +436,93 @@ class QuantPagePool:
         return QuantPagePool(kv, s, page_size)
 
 
+LANES = 128  # a DMA's slice of the minor dimension is tiled by this
+
+
+def latent_lanes(latent_row) -> int:
+    """The lanes a latent row (C, R) takes: whole tiles."""
+    return -(-sum(latent_row) // LANES) * LANES
+
+
+@dataclasses.dataclass
+class LatentPagePool:
+    """The pool of a latent-attention model (cfg.latent_row = (C, R)):
+    ONE row per cached token and layer, shared by all heads,
+    `[c_kv (C) ; k_rope (R)]`, where PagePool holds K and V per head.
+    `c` is [rows, P, page_size, W] in the model's type, W = C + R
+    rounded up to 128 lanes: the TPU tiles the minor dimension by 128
+    (a [.., 576] array takes the bytes of [.., 640] anyway) and the
+    kernel's page DMA must slice whole tiles; the spare lanes stay zero.
+
+    The decode kernel (serving/paged_attention_mla.py) takes the WHOLE
+    array and the row, as the int8 kernel does: a slice of one row
+    handed to a kernel would be copied out first. Only this class and
+    that kernel index `c`. The lanes that read pages back, move them
+    or share them (prefix reuse, the pager, the disaggregated transfer,
+    the verifies' relocation, the long-prompt scratch cache) have no
+    method here: LLMEngine refuses them by name for such a model."""
+
+    c: jax.Array
+    page_size: int
+
+    @property
+    def n_pages(self) -> int:
+        return self.c.shape[1]
+
+    @property
+    def quantized(self) -> bool:
+        return False
+
+    @property
+    def geometry(self) -> PoolGeometry:
+        R, _, ps, W = self.c.shape
+        return PoolGeometry(R, 1, ps, W, self.c.dtype)
+
+    def devices(self):
+        return self.c.devices()
+
+    def attention_operands(self, row):
+        """(pool, row) for paged_attention_mla_dispatch."""
+        return self.c, row
+
+    def _padded(self, x):
+        """[..., C + R] in the pool's type and width."""
+        spare = self.c.shape[-1] - x.shape[-1]
+        return jnp.pad(x.astype(self.c.dtype),
+                       [(0, 0)] * (x.ndim - 1) + [(0, spare)])
+
+    def append(self, row, slots, c_new) -> "LatentPagePool":
+        """A step's new rows `c_new` [B, C + R] into cache row `row` at
+        the slots' (page, offset): one scatter, the scalar row and the
+        two index vectors adjacent."""
+        c = self.c.at[row, slots.page_idx, slots.offset, :].set(
+            self._padded(c_new))
+        return dataclasses.replace(self, c=c)
+
+    def encode_pages(self, c):
+        return self._padded(c)
+
+    def write_pages(self, pages, table_flat) -> "LatentPagePool":
+        """Page-shaped rows [rows, M, ps, W] (encode_pages') into pages
+        `table_flat` [M]."""
+        li = jnp.arange(pages.shape[0])[:, None]
+        return dataclasses.replace(
+            self, c=self.c.at[li, table_flat[None, :]].set(pages))
+
+    @staticmethod
+    def zeros(cfg, n_pages: int, page_size: int = 64, dtype=None,
+              sharding=None) -> "LatentPagePool":
+        shape = (cfg.cache_rows, n_pages, page_size,
+                 latent_lanes(cfg.latent_row))
+        return LatentPagePool(_alloc(shape, jnp.dtype(dtype or cfg.dtype),
+                                     sharding), page_size)
+
+
 jax.tree_util.register_dataclass(
     PagePool, data_fields=["k", "v"], meta_fields=["page_size"]
+)
+jax.tree_util.register_dataclass(
+    LatentPagePool, data_fields=["c"], meta_fields=["page_size"]
 )
 jax.tree_util.register_dataclass(
     QuantPagePool, data_fields=["kv", "s"], meta_fields=["page_size"]

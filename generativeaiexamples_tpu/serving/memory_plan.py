@@ -174,6 +174,19 @@ def weight_bytes_per_device(lcfg: LlamaConfig, axis_sizes: Dict[str, int],
     from generativeaiexamples_tpu.models import llama
     from generativeaiexamples_tpu.ops.quant import LLAMA_QUANT_KEYS
 
+    if lcfg.latent_row is not None:
+        # models/latent_moe.py: whole on one chip (its share of the
+        # experts is the configuration's, not a mesh axis's)
+        if any(int(n) > 1 for n in axis_sizes.values()):
+            raise MemoryPlanError(
+                "a latent-attention model has no tensor-parallel layout: "
+                f"mesh axes {axis_sizes}")
+        from generativeaiexamples_tpu.models import latent_moe
+
+        shapes = jax.eval_shape(lambda: latent_moe.init_params_on_device(
+            lcfg, quantize=quantize))
+        return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                   for leaf in jax.tree.leaves(shapes))
     shapes = jax.eval_shape(lambda: llama.init_params(
         lcfg, jax.random.PRNGKey(0)))
     specs = llama.param_specs(lcfg)
@@ -210,9 +223,17 @@ def pool_page_bytes_per_device(lcfg: LlamaConfig, ecfg: EngineConfig,
     int8 + scales [2, L, KH, P, ps] f32, kv-heads on tensor
     (KV_FUSED_SPEC / KV_FUSED_SCALE_SPEC).
     """
+    ps = ecfg.page_size
+    if lcfg.latent_row is not None:
+        # kv_cache.LatentPagePool: ONE vector a token and row for all
+        # heads, [c_kv ; k_rope] in whole 128-lane tiles; not
+        # n_kv_heads * head_dim, and no second array for V
+        from generativeaiexamples_tpu.serving.kv_cache import latent_lanes
+
+        return (lcfg.cache_rows * ps * latent_lanes(lcfg.latent_row)
+                * jnp.dtype(ecfg.kv_dtype).itemsize)
     tp = int(axis_sizes.get("tensor", 1))
     kh = math.ceil(lcfg.n_kv_heads / tp)
-    ps = ecfg.page_size
     base = lcfg.cache_rows * kh * ps  # a row per (pass, block)
     if jnp.dtype(ecfg.kv_dtype) == jnp.int8:
         return 2 * base * lcfg.head_dim + 2 * base * 4
@@ -227,8 +248,11 @@ def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
     # KVCache [L, 1, KH, max_seq_len, Hd] x (k, v) on device
     # (engine._max_long_prefills = 1); counted unsharded — GSPMD may
     # shard it, so this over-counts, never under.
-    long_pf = (2 * lcfg.cache_rows * lcfg.n_kv_heads
-               * ecfg.max_seq_len * lcfg.head_dim * wsize)
+    if lcfg.latent_row is not None:
+        long_pf = 0  # no long-prompt scratch: the engine refuses the lane
+    else:
+        long_pf = (2 * lcfg.cache_rows * lcfg.n_kv_heads
+                   * ecfg.max_seq_len * lcfg.head_dim * wsize)
     # Warmup/steady-state activation transients: the widest prefill
     # dispatch runs N sequences x the largest bucket through the stack.
     # XLA reuses buffers; ~4 hidden-width + 2 mlp-width live copies is
@@ -366,6 +390,8 @@ def smallest_fitting_mesh(lcfg: LlamaConfig, ecfg: EngineConfig,
     `sharding.validate_tp` would accept — in increasing order and
     returns the first geometry that holds at least one max-length
     sequence, or None."""
+    if lcfg.latent_row is not None:
+        return None  # whole on one chip: weight_bytes_per_device
     g = math.gcd(math.gcd(lcfg.n_heads, lcfg.n_kv_heads),
                  math.gcd(lcfg.mlp_dim, lcfg.vocab_size))
     max_pages = ecfg.max_seq_len // ecfg.page_size

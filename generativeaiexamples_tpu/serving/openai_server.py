@@ -19,6 +19,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import queue
 import time
 import uuid
 from typing import Any, Dict, Optional
@@ -71,6 +72,18 @@ class StopStream:
         out = self.full[self.sent:]
         self.sent = len(self.full)
         return out
+
+
+def _take_ready(stream) -> list:
+    """Block for one event of a request's stream, then take whatever
+    else is already there."""
+    events = [stream.get()]
+    try:
+        while not events[-1]["finished"]:
+            events.append(stream.get_nowait())
+    except queue.Empty:
+        pass
+    return events
 
 
 class OpenAIServer:
@@ -178,13 +191,21 @@ class OpenAIServer:
         )
 
     async def _events(self, req):
-        """Async iterator over engine events for one request."""
+        """Async iterator over engine events for one request. One trip
+        to the executor takes EVERY event that is ready: an unpaced
+        stream's tokens land a decode block at a time, and a thread
+        handoff per token saturates this one event loop near 4,000
+        tokens/s (128 streams of a 5,000 tokens/s engine then wait
+        seconds for their last frame and the closed loop starves the
+        slots; PERF.md, PR 33). A paced stream (one event at a time)
+        sees no change."""
         loop = asyncio.get_running_loop()
         while True:
-            ev = await loop.run_in_executor(self._executor, req.stream.get)
-            yield ev
-            if ev["finished"]:
-                return
+            for ev in await loop.run_in_executor(self._executor,
+                                                 _take_ready, req.stream):
+                yield ev
+                if ev["finished"]:
+                    return
 
     @staticmethod
     def _stop_strings(body: Dict) -> list:

@@ -20,11 +20,15 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from generativeaiexamples_tpu.ops import moe
 from generativeaiexamples_tpu.ops.attention import flash_attention
 from generativeaiexamples_tpu.ops.encoder_attention import encoder_attention
 from generativeaiexamples_tpu.serving.paged_attention import paged_attention
+from generativeaiexamples_tpu.ops.quant import QuantizedTensor
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
     paged_attention_int8)
+from generativeaiexamples_tpu.serving.paged_attention_mla import (
+    paged_attention_mla)
 from generativeaiexamples_tpu.serving.paged_attention_tree import (
     paged_tree_attention)
 
@@ -91,7 +95,35 @@ KERNELS = {
         + [((8,), I32)]),
     "encoder_attention_arctic_l": (
         encoder_attention, [((32, 16, 512, 64), BF16)] * 3 + [((32,), I32)]),
+    # A.X-K1's widths (benchmark/configs/ax-k1-int8-ep16.json): a latent
+    # prompt's keys of 192 (padded to 256 lanes) against values of 128;
+    # the absorbed paged kernel, 128 slots of 64 heads over rows of 576
+    # values in 640 lanes; the grouped matmul over 12 held experts, a
+    # decode step's tiles of 32 rows and a prefill's of 128
+    "flash_prefill_latent_256_128": (
+        lambda q, k, v, ln: flash_attention(q, k, v, causal=True,
+                                            lengths=ln, scale=0.13),
+        [((4, 64, 384, 256), BF16)] * 2 + [((4, 64, 384, 128), BF16),
+                                           ((4,), I32)]),
+    "paged_decode_latent": (
+        lambda q, pool, t, ln: paged_attention_mla(
+            q, pool, 3, t, ln, latent=512, scale=0.13),
+        [((128, 64, 640), BF16), ((15, 1408, PS, 640), BF16),
+         ((128, 11), I32), ((128,), I32)]),
+    "grouped_expert_matmul_decode": (
+        lambda *a: _grouped(32, *a),
+        [((1024 + 12 * 32, 7168), BF16), ((14, 12, 7168, 4096), I8),
+         ((14, 12, 4096), F32), ((44,), I32), ((1,), I32)]),
+    "grouped_expert_matmul_prefill": (
+        lambda *a: _grouped(128, *a),
+        [((12288 + 12 * 128, 2048), BF16), ((14, 12, 2048, 7168), I8),
+         ((14, 12, 7168), F32), ((108,), I32), ((1,), I32)]),
 }
+
+
+def _grouped(tm, x, q, s, tile_group, n_tiles):
+    plan = moe.DispatchPlan(None, None, tile_group, n_tiles, None, tm)
+    return moe.grouped_matmul_pallas(x, QuantizedTensor(q, s), 5, plan)
 
 
 @pytest.mark.parametrize("name", sorted(KERNELS))

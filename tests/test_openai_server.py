@@ -474,3 +474,59 @@ def test_replica_submit_fault_maps_to_503(server):
     status, data = _client_call((FaultyFleet(), emb, rr), body)
     assert status == 503
     assert data["error"]["code"] == "replica_submit_failed"
+
+
+def test_take_ready_takes_every_ready_event_and_stops_at_the_last():
+    """One executor trip per burst, not per token (`_events`): what is
+    already queued comes back in order, nothing past the finishing
+    event, and an empty queue blocks for the next one only."""
+    import queue
+    import threading
+
+    from generativeaiexamples_tpu.serving.openai_server import _take_ready
+
+    q = queue.Queue()
+    for i in range(5):
+        q.put({"token_id": i, "finished": False})
+    assert [e["token_id"] for e in _take_ready(q)] == [0, 1, 2, 3, 4]
+    q.put({"token_id": 5, "finished": False})
+    q.put({"token_id": 6, "finished": True})
+    q.put({"token_id": 7, "finished": False})  # never read: the stream ended
+    assert [e["token_id"] for e in _take_ready(q)] == [5, 6]
+    q = queue.Queue()
+    threading.Timer(0.05, q.put, [{"token_id": 9, "finished": False}]).start()
+    assert [e["token_id"] for e in _take_ready(q)] == [9]
+
+
+@pytest.mark.parametrize("stop, text, finish", [
+    (None, "abcdef", "length"), (["de"], "abc", "stop")],
+    ids=["to-the-end", "cut-mid-burst"])
+def test_a_burst_of_events_is_streamed_in_order(server, stop, text, finish):
+    """What `_take_ready` took in one trip leaves a frame per event, in
+    order; a stop string that ends inside the burst cuts it there."""
+    llm, emb, rr = server
+
+    class Burst:
+        tokenizer = llm.tokenizer
+        metrics = llm.metrics
+
+        def submit(self, req):
+            for i, t in enumerate("abcdef"):
+                req.stream.put({"text": t, "token_id": i, "finished": False,
+                                "finish_reason": None})
+            req.stream.put({"text": "", "token_id": -1, "finished": True,
+                            "finish_reason": "length"})
+
+    async def body(c):
+        r = await c.post("/v1/completions", json={
+            "prompt": [5] * 4, "max_tokens": 6, "stream": True,
+            **({"stop": stop} if stop else {})})
+        return (await r.read()).decode()
+
+    raw = _client_call((Burst(), emb, rr), body)
+    frames = [ln[6:] for ln in raw.splitlines() if ln.startswith("data: ")]
+    assert frames[-1] == "[DONE]"
+    parsed = [json.loads(f)["choices"][0] for f in frames[:-1]]
+    assert "".join(p["text"] for p in parsed) == text
+    assert [p["finish_reason"] for p in parsed] == \
+        [None] * (len(parsed) - 1) + [finish]

@@ -382,10 +382,11 @@ class TestSpeculativeDecode:
     def _engine(self, spec_k=2):
         params = llama.init_params(TINY, jax.random.PRNGKey(0))
         # pace_emission_max_streams=0: these tests assert EXACT token
-        # equality vs offline greedy on random weights (near-tie logit
-        # gaps); the pacer thread's GIL scheduling can perturb XLA CPU
-        # execution under host contention and flip ties (bisected in
-        # r5 on the TP twin suite). Pacing has its own test class.
+        # equality vs offline greedy, and until PR 33 the pacer could
+        # hand a stream its tokens out of order under host contention
+        # (a fast block's burst put past a slower block's pending one:
+        # ROADMAP D7; r5 had read it as flipped ties). Pacing has its
+        # own test class.
         ecfg = EngineConfig(max_batch_size=4, max_seq_len=64, page_size=8,
                             prefill_buckets=(16,),
                             decode_steps_per_dispatch=4,
@@ -643,6 +644,35 @@ class TestEmissionPacing:
             assert [e["token_id"] for e in got] == [0, 1, 2, 3]
             gaps = [b - a for a, b in zip(times, times[1:])]
             assert sum(1 for g in gaps if g >= 0.02) >= 2, gaps
+        finally:
+            eng.stop()
+
+    def test_fast_block_behind_a_slow_one_keeps_the_order(self):
+        """A block that landed slowly is still with the pacer when the
+        next lands at once (under 4 ms a token: not paced): its tokens
+        go out first, not behind the fast block's (the byte-identity
+        pins that failed now and then under load, ROADMAP D7)."""
+        eng = self._engine().start()
+        try:
+            req = GenRequest(prompt_ids=[1, 2], max_new_tokens=99)
+            from generativeaiexamples_tpu.serving import engine as em
+            seq = SequencePages(eng.allocator, eng.pool.page_size,
+                                eng.max_pages)
+            slot = em._Slot(req, seq, None)
+
+            def evs(ids):
+                return [{"text": str(j), "token_id": j, "finished": False,
+                         "finish_reason": None} for j in ids]
+
+            now = time.perf_counter()
+            slot.pace_buf = evs(range(4))
+            slot.pace_last_land = now - 8.0  # slow: 100 ms a token
+            eng._pace_commit(slot, now)
+            slot.pace_buf = evs(range(4, 8))
+            eng._pace_commit(slot, now + 0.004)  # 1 ms a token
+            got = [req.stream.get(timeout=5)["token_id"] for _ in range(8)]
+            assert got == list(range(8))
+            assert not eng._pace_entries
         finally:
             eng.stop()
 
