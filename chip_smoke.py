@@ -711,7 +711,7 @@ def child_device_ops(args) -> None:
         fB, fS, eB, eH, eS, eD = 2, 2048, 32, 16, 512, 64
     R, TREE = 4, (3, 4)
     r = 1 + TREE[0] * TREE[1]
-    P = B * maxp + 1
+    P = B * maxp + 2  # page 0 the sink, the last one the int8 POISON
     rng = np.random.default_rng(args.seed)
     keys = iter(jax.random.split(jax.random.key(args.seed), 16))
 
@@ -719,16 +719,26 @@ def child_device_ops(args) -> None:
         return jax.random.normal(next(keys), shape, jnp.float32).astype(dt)
 
     k_pages, v_pages = rand(KH, P, ps, Hd), rand(KH, P, ps, Hd)
-    kq, ks = quantize_kv(k_pages.astype(jnp.float32))
-    vq, vs = quantize_kv(v_pages.astype(jnp.float32))
+    kq, ks = (x.at[:, P - 1].set(bad) for x, bad in zip(
+        quantize_kv(k_pages.astype(jnp.float32)), (127, jnp.nan)))
+    vq, vs = (x.at[:, P - 1].set(bad) for x, bad in zip(
+        quantize_kv(v_pages.astype(jnp.float32)), (127, jnp.nan)))
     # fused int8 pool [2, L, KH, P, ps, Hd] + scales; the kernels index
     # the layer inside their DMA descriptors: attend layer 1 of 2
     kv = jnp.stack([jnp.stack([kq, vq]), jnp.stack([kq, vq])], axis=1)
     sc = jnp.stack([jnp.stack([ks, vs]), jnp.stack([ks, vs])], axis=1)
     layer = 1
-    table = jnp.asarray(rng.permutation(np.arange(1, P))[:B * maxp]
-                        .reshape(B, maxp), jnp.int32)
-    lengths = jnp.asarray(rng.integers(1, maxp * ps - r, (B,)), jnp.int32)
+    # ragged: one token, a page and one, the whole table, then random.
+    # A table entry past the last page the longest form's span reaches
+    # is DEAD: the int8 kernels get the poison page there (a dead page
+    # copied or multiplied is a NaN), the references the sink.
+    lengths = rng.integers(1, maxp * ps - r, (B,))
+    lengths[:3] = 1, ps + 1, maxp * ps - r + 1
+    dead = np.arange(maxp) >= -(-(lengths[:, None] + r - 1) // ps)
+    own = rng.permutation(np.arange(1, P - 1)).reshape(B, maxp)
+    table = jnp.asarray(np.where(dead, 0, own), jnp.int32)
+    poisoned = jnp.asarray(np.where(dead, P - 1, own), jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
     q1, qR, qT = rand(B, H, Hd), rand(B, R, H, Hd), rand(B, H, r, Hd)
     _, anc = _tree_layout(*TREE)
 
@@ -756,7 +766,7 @@ def child_device_ops(args) -> None:
         if not ok:
             failures.append(name)
 
-    pool8 = (kv, sc, table, lengths)
+    pool8 = (kv, sc, poisoned, lengths)
     layer8 = (kv[:, layer], sc[:, layer], table, lengths)
     pool16 = (k_pages, v_pages, table, lengths)
     compare("int8 paged decode",
@@ -798,7 +808,8 @@ def child_device_ops(args) -> None:
     got, secs = _timed(lambda: appended(True, kv, sc, k_new, v_new))
     pltpu.set_tpu_interpret_mode(None)
     want = appended(False, kv, sc, k_new, v_new)
-    ok = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want)) \
+    ok = all(bool(jnp.array_equal(g, w, equal_nan=True))  # the poison's
+             for g, w in zip(got, want)) \
         and not bool(jnp.array_equal(got[0], kv))
     print(f"[device-ops] int8 K/V append (kernel vs scatters): the same "
           f"bytes, {secs * 1e3:.3f} ms {'ok' if ok else 'FAILED'}", flush=True)

@@ -68,6 +68,7 @@ from generativeaiexamples_tpu.serving import engine_model
 from generativeaiexamples_tpu.serving.kv_cache import (
     PageAllocator, PagePool, SequencePages, kernel_append)
 from generativeaiexamples_tpu.serving import flight as flight_mod
+from generativeaiexamples_tpu.serving.paged_attention_int8 import page_counts
 from generativeaiexamples_tpu.serving.multihost import (
     fetch_addressable as mh_fetch_addressable,
     fetch_replicated as mh_fetch_replicated)
@@ -316,6 +317,15 @@ class EngineMetrics:
         # pool, one new row a slot, kernels on) and not XLA's scatters:
         # the share of decode_steps that engages it.
         self.decode_steps_kernel_append = 0
+        # Over the same steps (their attention is then
+        # serving/paged_attention_int8.py), summed over the B rows a
+        # step: the pages the rows HAVE, which is what the kernel copies
+        # and multiplies, and what whole blocks over them would cover,
+        # which is what it walked before it stopped at a row's last
+        # page (paged_attention_int8.page_counts). live / walked is the
+        # share of page copies that remain.
+        self.decode_attn_pages_live = 0
+        self.decode_attn_pages_walked = 0
         # KV pool geometry (set once at engine build): rows of the pool
         # (layers x passes) and the bytes one cached token takes over
         # all rows, scales included.
@@ -482,6 +492,8 @@ class EngineMetrics:
             "layer_passes": self.layer_passes,
             "decode_steps_direct_qkv": self.decode_steps_direct_qkv,
             "decode_steps_kernel_append": self.decode_steps_kernel_append,
+            "decode_attn_pages_live": self.decode_attn_pages_live,
+            "decode_attn_pages_walked": self.decode_attn_pages_walked,
             "kv_cache_rows": self.kv_cache_rows,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "moe_pairs_routed": self.moe_pairs_routed,
@@ -3246,6 +3258,13 @@ class LLMEngine:
         # every decode program but the verifies writes one row a slot
         if not plan.spec_k and kernel_append(self.pool, self.use_pallas):
             self.metrics.decode_steps_kernel_append += K
+            # ... and attends through paged_attention_int8: a live
+            # row is one token longer every step of the block
+            live_pages, walked = page_counts(
+                lengths + np.arange(K)[:, None] * active_mask,
+                self.pool.page_size, self.max_pages)
+            self.metrics.decode_attn_pages_live += live_pages
+            self.metrics.decode_attn_pages_walked += walked
         self.metrics.busy_slots_acc += len(active) * K
         if spec_mode:
             for i in active:

@@ -19,14 +19,23 @@ Layouts (per layer, matching kv_cache.QuantPagePool):
   lengths    [B] int32           valid tokens INCLUDING the current one
 
 Kernel shape: grid (B,) — ONE grid step per batch row covering ALL kv
-heads, as a fori_loop over compute blocks of `pages_per_compute_block`
-pages. Each page's k AND v move HBM->VMEM as a SINGLE DMA descriptor
-strided across the (KH, 2) axes, and both scale rows as one more —
-2 descriptors per page instead of the 4 an unfused pool needs and the
-8 a per-head grid pays. Descriptor issue count, not bandwidth, is the
-measured floor at decode shapes (scripts/decompose_decode.py;
-docs/ENGINEERING_NOTES.md r3 notes). The next block's copies start
-while the current one computes (cross-grid-step double buffering).
+heads, as a fori_loop over blocks of PAGES_PER_BLOCK pages. Each page's
+k AND v move HBM->VMEM as a SINGLE DMA descriptor strided across the
+(KH, 2) axes, and both scale rows as one more — 2 descriptors per page
+instead of the 4 an unfused pool needs and the 8 a per-head grid pays.
+The copies of the next BLOCKS_AHEAD blocks, this row's and then the next
+rows', are in flight while the current one computes (cross-grid-step
+buffering).
+
+A row has n = clip(cdiv(length + q_rep - 1, ps), 1, maxp) pages, and a
+page past its last is neither copied nor multiplied: a block's count of
+live pages picks the body that starts, waits for and multiplies exactly
+those (`_int8_kernel`), so no table entry past n is read and a block
+need not divide the table's width. The kernel streams the bytes a batch
+HAS: on a v5e rows of whole blocks run at 93 % of the HBM's rate, and a
+short or idle row costs its pages and about 0.3 us, where walking whole
+blocks read page 0 for every page the row lacked
+(scripts/measure_paged_attention.py; PERF.md section 5, PR 34).
 
 Dequantization never touches head_dim: K scales multiply the score
 columns ((q @ k_q^T) * ks == q @ (k_q * ks)^T), V scales fold into the
@@ -43,6 +52,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -121,57 +131,44 @@ def _tree_keep(pos, length, jrow, r, tree):
     return (rel < 0) | (in_tree & ((rel == 0) | same_chain))
 
 
-def _copy_block(pages_ref, layer, hbm, buf, sem, b, i, slot, *, ppcb, maxp,
-                split_kv=False):
-    """Async copies for compute block i of row b into buffer `slot`:
-    one STRIDED descriptor per page covering all kv heads AND both of
-    k/v (hbm.at[:, layer, :, pid] on the FULL [2, L, KH, P, ...] pool —
-    the layer is indexed inside the descriptor because a host-side
-    per-layer slice of the kv-leading layout is non-contiguous and XLA
-    would materialize 32 copies of it). Returns the descriptors
-    (recreate-and-wait pattern: semaphores count bytes, so identical
-    descriptors built later can wait).
-
-    `split_kv`: one descriptor for k and one for v. The single one
-    strides from a page's k to its v over L*KH*P*ps*Hd bytes, and a pool
-    whose half is 4 GiB or more (a looped model's 192 rows) reads wrong
-    pages through it (SPLIT_KV_BYTES below)."""
-    copies = []
-    for j in range(ppcb):
-        pid = pages_ref[b * maxp + i * ppcb + j]
-        if split_kv:
-            copies += [pltpu.make_async_copy(
-                hbm.at[h, layer, :, pid], buf.at[slot, j, h], sem.at[slot])
-                for h in (0, 1)]
-        else:
-            copies.append(pltpu.make_async_copy(
-                hbm.at[:, layer, :, pid], buf.at[slot, j], sem.at[slot]))
-    return copies
-
-
 # From this many bytes in ONE half (k or v) of the fused code pool, the
-# kernel copies a page's k and v with a descriptor each (`_copy_block`).
+# kernel copies a page's k and v with a descriptor each (`_int8_kernel`'s
+# `copies`).
 SPLIT_KV_BYTES = 2 ** 32
+
+# Pages a block copies together and multiplies in one unrolled body, and
+# blocks whose copies are in flight while one is multiplied (VMEM holds
+# one buffer more). Read on a v5e at the cells' shapes (PERF.md section
+# 5, PR 34): 4 and 5 pages read alike and 8 a tenth slower on rows of 20
+# pages (a block waits for all its copies), and 4 makes the fewest
+# bodies; a second block in flight takes a fifth off Mistral-7B's mix
+# and an eighth off Ouro's, a third nothing more.
+PAGES_PER_BLOCK = 4
+BLOCKS_AHEAD = 2
+
+# The kernel's state in SMEM, carried from one grid step to the next:
+# the buffer and the (row, block) to ask for next, the buffer to take.
+_ASK_SLOT, _ASK_ROW, _ASK_BLOCK, _TAKE_SLOT = range(4)
 
 
 def _int8_kernel(
     lengths_ref,   # scalar prefetch [B]
     tables_ref,    # scalar prefetch [B * maxp]
     layer_ref,     # scalar prefetch [1] — which layer's pool slice
-    buf_idx_ref,   # scalar prefetch [1] — persists ACROSS grid steps
-    init_ref,      # scalar prefetch [1] — 1 on the very first grid step
     q_ref,         # [1, KH, G, Hd] f32 (scale pre-folded)
     kv_hbm,        # [2, L, KH, P, ps, Hd] int8 (ANY)
     s_hbm,         # [2, L, KH, P, 1, ps] f32 (ANY)
     o_ref,         # [1, KH, G, Hd]
-    kv_buf,        # VMEM [2, ppcb, 2, KH, ps, Hd] int8
-    s_buf,         # VMEM [2, ppcb, 2, KH, 1, ps] f32
-    sem,           # DMA sems [2]
+    kv_buf,        # VMEM [ahead + 1, ppcb, 2, KH, ps, Hd] int8
+    s_buf,         # VMEM [ahead + 1, ppcb, 2, KH, 1, ps] f32
+    sem,           # DMA sems [ahead + 1]
+    state,         # SMEM [4]: _ASK_SLOT, _ASK_ROW, _ASK_BLOCK, _TAKE_SLOT
     *,
     ppcb: int,
     maxp: int,
     page_size: int,
     batch_size: int,
+    ahead: int,
     q_rep: int = 1,
     tree=None,
     split_kv: bool = False,
@@ -195,66 +192,119 @@ def _int8_kernel(
     (Pallas kernels cannot capture vector constants). The KV stream
     is identical to linear verify: the tree only edits the mask.
 
-    Design rules, measured on a v5e through the real decode path
-    (scripts/decompose_decode.py):
-    1. DMA-issue count is the floor — fused pages cut it to 2
-       descriptors per page.
-    2. Latency hiding is CROSS-grid-step (the JetStream scheme): while
-       row b's block computes, the next block's copies are already in
-       flight in the other buffer; buf_idx/init persist in SMEM across
+    Design rules, measured on a v5e (scripts/measure_paged_attention.py):
+    1. Only the pages a row has: a page past the row's last is not
+       copied, not waited for and not multiplied (what the buffer holds
+       there is whatever an earlier row left: a stale SCALE times a zero
+       weight would be NaN, so skipping the copy alone is not enough).
+       A block's count of live pages picks one of ppcb bodies, each
+       unrolled over exactly its pages, for the copies' starts, for
+       their waits and for the multiplies alike: a loop of that trip
+       count runs a page's chain of dot, max, exp and dot one after the
+       other, and rows of whole blocks then take a sixth longer.
+    2. Fused pages: 2 descriptors per page.
+    3. Latency hiding is CROSS-grid-step (the JetStream scheme): while
+       a block is multiplied, the copies of the `ahead` blocks after it
+       (this row's, then the next rows') are in flight in the other
+       buffers; what was asked for and taken persists in SMEM across
        grid steps."""
     b = pl.program_id(0)
     ps = page_size
-    bk = ppcb * ps
-    length = lengths_ref[b]
-    span = length + (q_rep - 1)  # kv entries the LAST query row sees
-    nblk = lax.div(span + bk - 1, bk)
     KH, G, Hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
     g_base = G // q_rep
-
     layer = layer_ref[0]
 
-    def copies(bb, i, slot):
-        return (_copy_block(tables_ref, layer, kv_hbm, kv_buf, sem, bb, i,
-                            slot, ppcb=ppcb, maxp=maxp, split_kv=split_kv)
-                + _copy_block(tables_ref, layer, s_hbm, s_buf, sem, bb, i,
-                              slot, ppcb=ppcb, maxp=maxp))
+    def pages_of(row):
+        """The row's pages: those the LAST query row's span reaches."""
+        span = lengths_ref[row] + (q_rep - 1)
+        return jnp.clip(lax.div(span + (ps - 1), ps), 1, maxp)
 
-    def next_block(i):
-        """Block after (b, i-1): block i of this row if still inside
-        the sequence, else the next row's first block (lengths >= 1, so
-        every row has at least one block)."""
-        return lax.cond(i * bk < span,
-                        lambda: (b, i),
-                        lambda: (b + 1, jnp.int32(0)))
+    def by_live_count(n_row, i, branch, *operands):
+        """`branch(count)(*operands)` for the count of pages a row of
+        n_row has in its block i. The switch lowers to a chain of tests,
+        a single page first: the idle slots' case, and a short row is
+        where a test's 25 ns shows (a whole block hides it behind its
+        copies)."""
+        live = jnp.minimum(ppcb, n_row - i * ppcb)
+        return lax.switch(live - 1,
+                          [branch(c) for c in range(1, ppcb + 1)], *operands)
 
-    @pl.when(init_ref[0] == 1)
+    def after(slot):
+        return jnp.where(slot == ahead, 0, slot + 1)
+
+    def copies(row, i, slot, count, act):
+        """`act` (start or wait) on the copies of the first `count` pages
+        (static) of row's block i, into buffer `slot`. Per page ONE
+        STRIDED descriptor covering all kv heads AND both of k/v
+        (hbm.at[:, layer, :, pid] on the FULL [2, L, KH, P, ...] pool —
+        the layer is indexed inside the descriptor because a host-side
+        per-layer slice of the kv-leading layout is non-contiguous and
+        XLA would materialize 32 copies of it) and one more for its scale
+        rows. The semaphores count bytes, so an identical descriptor
+        built later waits for the one that was started: starts and waits
+        must run for the same `count`.
+
+        `split_kv`: one descriptor for k and one for v. The single one
+        strides from a page's k to its v over L*KH*P*ps*Hd bytes, and a
+        pool whose half is 4 GiB or more (a looped model's 192 rows)
+        reads wrong pages through it (SPLIT_KV_BYTES)."""
+        for j in range(count):
+            pid = tables_ref[row * maxp + i * ppcb + j]
+            if split_kv:
+                for h in (0, 1):
+                    act(pltpu.make_async_copy(
+                        kv_hbm.at[h, layer, :, pid], kv_buf.at[slot, j, h],
+                        sem.at[slot]))
+            else:
+                act(pltpu.make_async_copy(
+                    kv_hbm.at[:, layer, :, pid], kv_buf.at[slot, j],
+                    sem.at[slot]))
+            act(pltpu.make_async_copy(
+                s_hbm.at[:, layer, :, pid], s_buf.at[slot, j], sem.at[slot]))
+
+    def start(c):
+        c.start()
+
+    def wait(c):
+        c.wait()
+
+    def ask():
+        """Start the copies of the next block not yet asked for, where
+        a row is left: this row's next if it has one, else the next
+        row's first (lengths >= 1, so every row has a block)."""
+        row, i = state[_ASK_ROW], state[_ASK_BLOCK]
+
+        @pl.when(row < batch_size)
+        def _():
+            n_row = pages_of(row)
+            slot = state[_ASK_SLOT]
+            by_live_count(n_row, i, lambda count: lambda: copies(
+                row, i, slot, count, start))
+            state[_ASK_SLOT] = after(slot)
+            more = (i + 1) * ppcb < n_row
+            state[_ASK_ROW] = jnp.where(more, row, row + 1)
+            state[_ASK_BLOCK] = jnp.where(more, i + 1, 0)
+
+    @pl.when(b == 0)
     def _first():
-        init_ref[0] = 0
-        for c in copies(b, 0, buf_idx_ref[0]):
-            c.start()
+        for k in range(4):  # the first block goes into buffer 0
+            state[k] = 0
+        for _ in range(ahead):
+            ask()
 
+    length = lengths_ref[b]
+    n = pages_of(b)
     q = q_ref[0].astype(jnp.float32)  # [KH, G, Hd]
 
     def body(i, carry):
-        slot = buf_idx_ref[0]
-        nxt_b, nxt_i = next_block(i + 1)
+        slot = state[_TAKE_SLOT]
+        ask()  # into the buffer the block before this one was taken from
 
-        @pl.when(nxt_b < batch_size)
-        def _prefetch():
-            nslot = 1 - slot
-            for c in copies(nxt_b, nxt_i, nslot):
-                c.start()
-            buf_idx_ref[0] = nslot
-
-        for c in copies(b, i, slot):
-            c.wait()
-        # Per-page online softmax (static unroll over ppcb), all kv
-        # heads batched: shapes stay <= 3-D with the head axis leading —
-        # no Mosaic relayouts, and each dot is KH x (G x ps x Hd).
-        carry_i = carry
-        for j in range(ppcb):
-            m_prev, l_prev, acc = carry_i
+        def page(j, carry):
+            """One live page's online-softmax update, all kv heads
+            batched: shapes stay <= 3-D with the head axis leading — no
+            Mosaic relayouts, and each dot is KH x (G x ps x Hd)."""
+            m_prev, l_prev, acc = carry
             kq = kv_buf[slot, j, 0].astype(jnp.float32)  # [KH, ps, Hd]
             vq = kv_buf[slot, j, 1].astype(jnp.float32)
             ks = s_buf[slot, j, 0]                       # [KH, 1, ps]
@@ -262,7 +312,8 @@ def _int8_kernel(
             s = jax.lax.dot_general(
                 q, kq, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32) * ks  # [KH, G, ps]
-            pos = i * bk + j * ps + lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            pos = ((i * ppcb + j) * ps
+                   + lax.broadcasted_iota(jnp.int32, s.shape, 2))
             if tree is not None:
                 s = jnp.where(
                     _tree_keep(pos, length,
@@ -284,22 +335,39 @@ def _int8_kernel(
             pv = jax.lax.dot_general(
                 p * vs, vq, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)  # [KH, G, Hd]
-            carry_i = (m_new, l_new, acc * alpha + pv)
-        return carry_i
+            return m_new, l_new, acc * alpha + pv
+
+        def block(count):
+            def run(carry):
+                copies(b, i, slot, count, wait)
+                for j in range(count):
+                    carry = page(j, carry)
+                return carry
+            return run
+
+        carry = by_live_count(n, i, block, carry)
+        state[_TAKE_SLOT] = after(slot)
+        return carry
 
     init = (jnp.full((KH, G, 1), NEG_INF, jnp.float32),
             jnp.zeros((KH, G, 1), jnp.float32),
             jnp.zeros((KH, G, Hd), jnp.float32))
-    m, l, acc = lax.fori_loop(0, nblk, body, init)
+    m, l, acc = lax.fori_loop(0, pl.cdiv(n, ppcb), body, init)
     denom = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / denom).astype(o_ref.dtype)
 
 
-def _pages_per_block(maxp: int, want: int) -> int:
-    for g in range(min(want, maxp), 0, -1):
-        if maxp % g == 0:
-            return g
-    return 1
+def page_counts(lengths, page_size: int, max_pages: int,
+                block: int | None = None) -> tuple[int, int]:
+    """On the host, for a batch's `lengths` (numpy, any shape): the pages
+    the kernel copies and multiplies (each row's n, an idle row's one),
+    and what whole blocks over the same rows would cover, which is what
+    it walked before it stopped at n. The engine's
+    `decode_attn_pages_live` / `decode_attn_pages_walked`."""
+    block = min(block or PAGES_PER_BLOCK, max_pages)
+    n = np.clip(-(-np.asarray(lengths, np.int64) // page_size), 1, max_pages)
+    walked = np.minimum(-(-n // block) * block, max_pages)
+    return int(n.sum()), int(walked.sum())
 
 
 @functools.partial(jax.jit, static_argnames=("scale",
@@ -355,20 +423,21 @@ def paged_attention_int8(
             B, KH, G, Hd)
     else:
         qk = (q.astype(jnp.float32) * s).reshape(B, KH, G, Hd)
-    ppcb = _pages_per_block(maxp, pages_per_compute_block or 8)
+    ppcb = min(pages_per_compute_block or PAGES_PER_BLOCK, maxp)
     # Scale pages as 2-D [1, ps] tiles (metadata-only reshape of the
     # CONTIGUOUS full array): the kernel DMAs and consumes them without
     # any vector relayout.
     s2 = kv_scales.reshape(2, L, KH, P, 1, ps)
 
-    if split_kv is None:  # from the pool's shape alone (_copy_block)
+    if split_kv is None:  # from the pool's shape alone
         split_kv = L * KH * P * ps * Hd >= SPLIT_KV_BYTES
+    ahead = BLOCKS_AHEAD
     kernel = functools.partial(_int8_kernel, ppcb=ppcb, maxp=maxp,
-                               page_size=ps, batch_size=B, q_rep=q_rep,
-                               tree=tree, split_kv=split_kv)
-    qmap = lambda b, Ln, T, LY, BI, IF: (b, 0, 0, 0)  # noqa: E731
+                               page_size=ps, batch_size=B, ahead=ahead,
+                               q_rep=q_rep, tree=tree, split_kv=split_kv)
+    qmap = lambda b, Ln, T, LY: (b, 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, KH, G, Hd), qmap),
@@ -377,30 +446,28 @@ def paged_attention_int8(
         ],
         out_specs=pl.BlockSpec((1, KH, G, Hd), qmap),
         scratch_shapes=[
-            pltpu.VMEM((2, ppcb, 2, KH, ps, Hd), jnp.int8),
-            pltpu.VMEM((2, ppcb, 2, KH, 1, ps), kv_scales.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((ahead + 1, ppcb, 2, KH, ps, Hd), jnp.int8),
+            pltpu.VMEM((ahead + 1, ppcb, 2, KH, 1, ps), kv_scales.dtype),
+            pltpu.SemaphoreType.DMA((ahead + 1,)),
+            pltpu.SMEM((4,), jnp.int32),
         ],
     )
-    # The kernel's cross-row prefetch assumes every row owns >= 1 block
-    # (next_block falls through to row b+1 block 0 otherwise, which would
-    # leave the following row consuming a stale buffer). Clamp rather than
-    # assert: a length-0 row attends over one masked page and its output
-    # is ignored by the engine for inactive slots.
+    # The blocks are asked for in one chain over the rows, and every row
+    # takes at least one: a length-0 row takes one page, masked but for
+    # its first token, and its output is ignored by the engine for
+    # inactive slots. Clamp rather than assert.
     lengths = jnp.maximum(lengths.astype(jnp.int32), 1)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, Hd), jnp.float32),
-        # Sequential grid: the prefetch buffer index threads through SMEM
-        # from one grid step to the next.
+        # Sequential grid: what was asked for and taken threads through
+        # SMEM from one grid step to the next.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lengths, page_table.reshape(-1).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
-      qk, kv_pages, s2)
+      jnp.asarray(layer, jnp.int32).reshape(1), qk, kv_pages, s2)
     if q_rep > 1:
         return out.reshape(B, KH, q_rep, H // KH, Hd).transpose(
             0, 2, 1, 3, 4).reshape(B, q_rep, H, Hd).astype(q.dtype)

@@ -59,8 +59,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from generativeaiexamples_tpu.serving.paged_attention import _pages_per_block
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-    _pages_per_block, _tree_keep, paged_attention_int8)
+    _tree_keep, paged_attention_int8)
 from generativeaiexamples_tpu.utils.platform import log_kernel_declined
 
 NEG_INF = -1e30

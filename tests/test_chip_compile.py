@@ -83,6 +83,22 @@ KERNELS = {
         lambda q, kv, s, t, ln: paged_attention_int8(
             q, kv, s, t, ln, 1, q_rep=TREE_R, tree=TREE),
         [((B, TREE_R, H, HD), BF16)] + _POOL_INT8 + _TABLE),
+    # the benchmark cells' other shapes (PR 34: a block's live count picks
+    # one of PAGES_PER_BLOCK unrolled bodies, all of which must fit):
+    # Mistral-7B's tables of 20 pages, Ouro's 16 KV heads with a descriptor
+    # each for k and v, one chip's 2 KV heads of Mistral-Small-24B at TP=4
+    "paged_decode_int8_tables_of_20": (
+        lambda q, kv, s, t, ln: paged_attention_int8(q, kv, s, t, ln, 1),
+        [((B, H, HD), BF16)] + _POOL_INT8 + [((B, 20), I32), ((B,), I32)]),
+    "paged_decode_int8_16_kv_heads_split": (
+        lambda q, kv, s, t, ln: paged_attention_int8(q, kv, s, t, ln, 1,
+                                                     split_kv=True),
+        [((32, 16, HD), BF16), ((2, L, 16, P, PS, HD), I8),
+         ((2, L, 16, P, PS), F32), ((32, MAXP), I32), ((32,), I32)]),
+    "paged_decode_int8_2_kv_heads": (
+        lambda q, kv, s, t, ln: paged_attention_int8(q, kv, s, t, ln, 1),
+        [((B, 8, HD), BF16), ((2, L, 2, P, PS, HD), I8),
+         ((2, L, 2, P, PS), F32)] + _TABLE),
     "paged_decode_bf16": (
         paged_attention, [((B, H, HD), BF16)] + _POOL_BF16 + _TABLE),
     "paged_tree_bf16_3x4": (

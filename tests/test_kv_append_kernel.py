@@ -277,12 +277,14 @@ def test_decode_multi_step_with_the_kernel_gives_the_scatters_tokens_and_pool(
 @pytest.mark.parametrize("use_pallas", [True, False])
 def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas):
     """`decode_steps_kernel_append` equals `decode_steps` for a plain
-    int8 engine with kernels on and is 0, never absent, with them off;
-    it is in snapshot(), in /metrics and among the fleet's sums."""
+    int8 engine with kernels on and is 0, never absent, with them off,
+    and so are the pages its attention kernel reads and would have walked;
+    they are in snapshot(), in /metrics and among the fleet's sums."""
     from generativeaiexamples_tpu.config.schema import EngineConfig
     from generativeaiexamples_tpu.models import llama
     from generativeaiexamples_tpu.serving import fleet
-    from generativeaiexamples_tpu.serving.engine import LLMEngine
+    from generativeaiexamples_tpu.serving.engine import (
+        EngineMetrics, LLMEngine)
     from generativeaiexamples_tpu.serving.flight import prometheus_text
     from generativeaiexamples_tpu.utils.tokenizer import ByteTokenizer
 
@@ -305,5 +307,14 @@ def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas):
     assert snap["decode_steps"] > 0
     assert snap["decode_steps_kernel_append"] == (
         snap["decode_steps"] if use_pallas else 0)
-    assert "decode_steps_kernel_append" in fleet._COUNTER_KEYS
-    assert "decode_steps_kernel_append" in prometheus_text(snap)
+    # the same steps attend through paged_attention_int8: a step's two
+    # rows (one live under a page, one idle) HAVE a page each where whole
+    # blocks over a table of two would cover both pages of both
+    steps = snap["decode_steps"] if use_pallas else 0
+    assert snap["decode_attn_pages_live"] == 2 * steps
+    assert snap["decode_attn_pages_walked"] == 4 * steps
+    for name in ("decode_steps_kernel_append", "decode_attn_pages_live",
+                 "decode_attn_pages_walked"):
+        assert name in fleet._COUNTER_KEYS
+        assert name in prometheus_text(snap)
+        assert EngineMetrics().snapshot()[name] == 0

@@ -4,9 +4,10 @@ Covers: quantize/dequantize numerics, the dequant oracle vs the float
 reference, the engine's paged prefill/decode write path with a
 quantized pool (logits close to the bf16-pool run), end-to-end engine
 generation, and the TP shard_map dispatch on the emulated 8-device
-mesh. The TPU kernel itself (serving/paged_attention_int8.py) is
-validated against the oracle on hardware by scripts/check_int8_kernel.py
-— Pallas async-copy kernels don't run under CPU interpret mode.
+mesh. The TPU kernel itself (serving/paged_attention_int8.py) runs
+interpreted against the oracle in tests/test_paged_attention_int8_pages.py
+and tests/test_tree_kernel.py, compiles for the chip in
+tests/test_chip_compile.py and runs on it in chip_smoke.py.
 """
 
 import jax

@@ -1,0 +1,109 @@
+"""The int8 paged-attention kernel reads only the pages a row HAS
+(serving/paged_attention_int8.py): interpreted on the CPU as
+tests/test_tree_kernel.py runs it, against the XLA gather reference.
+
+Every table entry past a row's n = clip(cdiv(length + q_rep - 1, ps), 1,
+maxp) points at a POISON page (codes 127, scales NaN) and the reference
+is taken over the live entries alone, so a dead page that is copied or
+multiplied fails by NaN. The interpreter starts a scratch buffer at NaN
+too: a page multiplied without having been copied fails the same way.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from generativeaiexamples_tpu.serving import engine_model
+from generativeaiexamples_tpu.serving import paged_attention_int8 as pa8
+from generativeaiexamples_tpu.serving.paged_attention import (
+    paged_tree_attention_int8_reference_fused)
+
+PS, HD, KH, H, LAYERS, LAYER = 8, 16, 2, 4, 2, 1
+TREE = (2, 2)  # k, branches: 5 packed nodes
+FORMS = {"q_rep1": (1, None), "q_rep4": (4, None),
+         "tree": (1 + TREE[0] * TREE[1], TREE)}
+# name: (table width, pages a block or None for the kernel's own, the
+# rows' lengths as a function of the query rows r). `full` is a row whose
+# LAST query position sits on the table's last token.
+LENGTHS = {
+    "one": (4, None, lambda r: [1, 1, 1]),
+    "page": (4, None, lambda r: [PS, PS, PS]),
+    "page_plus_one": (4, None, lambda r: [PS + 1, PS + 1, PS + 1]),
+    "ragged": (4, None, lambda r: [1, 5, PS, PS + 1, 17, 4 * PS - r + 1]),
+    "full": (4, None, lambda r: [4 * PS - r + 1] * 3),
+    "idle_between_live": (4, None, lambda r: [13, 0, 22]),
+    "several_blocks": (20, 5, lambda r: [3, 6 * PS - r + 1, 20 * PS - r + 1]),
+    "block_not_a_divisor": (20, 8, lambda r: [20 * PS - r + 1, PS + 2,
+                                              11 * PS]),
+}
+
+
+def _pool(pages, seed):
+    """A fused pool of LAYERS layers whose last page is the poison."""
+    rng = np.random.default_rng(seed)
+    shape = (2, LAYERS, KH, pages, PS, HD)
+    kv = rng.integers(-127, 128, shape, dtype=np.int8)
+    s = rng.random(shape[:-1], dtype=np.float32) * 0.05 + 0.01
+    kv[:, :, :, 0] = 0          # the sink
+    kv[:, :, :, -1] = 127
+    s[:, :, :, -1] = np.nan
+    return jnp.asarray(kv), jnp.asarray(s)
+
+
+def _reference(q, kv, s, table, lengths, q_rep, tree):
+    kv, s = kv[:, LAYER], s[:, LAYER]
+    if tree is not None:
+        _, anc = engine_model._tree_layout(*tree)
+        return paged_tree_attention_int8_reference_fused(
+            q.transpose(0, 2, 1, 3), kv, s, table, lengths,
+            anc).transpose(0, 2, 1, 3)
+    if q_rep == 1:
+        return pa8.paged_attention_int8_reference_fused(q, kv, s, table,
+                                                        lengths)
+    return jnp.stack([pa8.paged_attention_int8_reference_fused(
+        q[:, j], kv, s, table, lengths + j) for j in range(q_rep)], axis=1)
+
+
+@pytest.mark.parametrize("split_kv", [False, True])
+@pytest.mark.parametrize("form", list(FORMS))
+@pytest.mark.parametrize("case", list(LENGTHS))
+def test_a_page_past_a_rows_last_is_neither_copied_nor_multiplied(
+        case, form, split_kv):
+    q_rep, tree = FORMS[form]
+    maxp, block, lengths_of = LENGTHS[case]
+    lengths = np.asarray(lengths_of(q_rep), np.int32)
+    B = len(lengths)
+    pages = B * maxp + 2
+    kv, s = _pool(pages, seed=len(case) + q_rep)
+    n = np.clip(-(-(lengths + q_rep - 1) // PS), 1, maxp)
+    live = np.arange(maxp)[None, :] < n[:, None]
+    own = 1 + np.arange(B * maxp).reshape(B, maxp)
+    poisoned = jnp.asarray(np.where(live, own, pages - 1), jnp.int32)
+    clean = jnp.asarray(np.where(live, own, 0), jnp.int32)
+    shape = (B, H, HD) if q_rep == 1 else (B, q_rep, H, HD)
+    q = jax.random.normal(jax.random.PRNGKey(q_rep), shape, jnp.float32)
+
+    got = np.asarray(pa8.paged_attention_int8(
+        q, kv, s, poisoned, jnp.asarray(lengths), LAYER, q_rep=q_rep,
+        tree=tree, pages_per_compute_block=block, split_kv=split_kv,
+        interpret=True))
+    assert np.isfinite(got).all(), "a dead page was copied or multiplied"
+    served = lengths > 0  # an idle row's output is nobody's
+    want = np.asarray(_reference(q, kv, s, clean, jnp.asarray(lengths),
+                                 q_rep, tree))
+    np.testing.assert_allclose(got[served], want[served], atol=2e-5,
+                               rtol=2e-5)
+
+
+def test_page_counts_of_a_known_batch():
+    """What the engine's two counters add a step: the rows' pages, and
+    what whole blocks over the same rows cover."""
+    lengths = np.array([1, 128, 129, 0, 640, 2560, 4000], np.int32)
+    live, walked = pa8.page_counts(lengths, page_size=128, max_pages=20,
+                                   block=5)
+    assert (live, walked) == (1 + 1 + 2 + 1 + 5 + 20 + 20,
+                              5 + 5 + 5 + 5 + 5 + 20 + 20)
+    # a block that does not divide the table's width stops at the width
+    assert pa8.page_counts(lengths[-1:], 128, 20, block=8) == (20, 20)
+    assert pa8.page_counts(lengths[:0], 128, 20, block=8) == (0, 0)
