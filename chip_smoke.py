@@ -854,6 +854,34 @@ def child_device_ops(args) -> None:
                 x, QuantizedTensor(q, s), layer,
                 moe.DispatchPlan(None, None, *pl_, None, plan.tm)),
                 gx, gw.q, gw.s, plan.tile_group, plan.n_tiles), 0))
+    # a state-space layer's in-place state update (granite-4.0-h-small's
+    # widths on the chip) against its XLA form: live slots, idle ones
+    from generativeaiexamples_tpu.serving import ssm_state_update as ssm
+
+    sB, sH, sP, sN = (3, 8, 16, 128) if interpret else (16, 128, 64, 128)
+    def s_rand(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    s_state = s_rand(2, sB, sH, sP, sN)
+    s_live = jnp.asarray(rng.integers(0, 2, (sB,)) > 0).at[0].set(True)
+    s_step = jnp.asarray(rng.uniform(0.001, 0.1, (sB, sH)), jnp.float32)
+    s_x, s_b, s_c = (s_rand(sB, sH, sP).astype(dt), s_rand(sB, sN).astype(dt),
+                     s_rand(sB, sN).astype(dt))
+    s_args = (s_state, s_live, s_step, -4.0 * s_step, s_x, s_b, s_c)
+
+    def ssm_form(on):
+        def run(state, *a):  # (state, y) -> one array to compare
+            state, y = ssm.ssm_state_update(state, layer, *a, use_pallas=on)
+            return jnp.concatenate([state[layer].reshape(-1), y.reshape(-1)])
+        return run
+
+    if interpret:
+        real = ssm.ssm_state_update_pallas
+        ssm.ssm_state_update_pallas = lambda *a: real(*a, interpret=True)
+    compare("state-space state update (in place)", ssm_form(True), s_args,
+            ref(ssm_form(False), *s_args))
+    if interpret:
+        ssm.ssm_state_update_pallas = real
     want = ref(paged_attention_reference, q1, *pool16)
     compare("bf16 paged decode (in-repo kernel)",
             lambda *a: paged_attention(*a, interpret=interpret),

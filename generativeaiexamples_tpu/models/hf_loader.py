@@ -193,6 +193,13 @@ def llama_config_from_hf(path: str) -> llama_lib.LlamaConfig:
             "attention (kv_lora_rank): it is no LlamaConfig; its "
             "configuration is models/latent_moe.py's LatentMoeConfig, and "
             "this loader holds no tensor-name map for it")
+    if "mamba_n_heads" in c:
+        raise ValueError(
+            f"{path}: model_type {c.get('model_type')!r} has state-space "
+            "layers (mamba_n_heads): it is no LlamaConfig; "
+            "its configuration is models/hybrid_ssm.py's HybridSsmConfig, "
+            "and this loader holds no tensor-name map for it (in_proj, "
+            "conv1d, dt_bias, A_log, D, the expert stacks)")
     family = {}
     if c.get("model_type") in _LOOPED_TYPES:
         family = dict(n_passes=int(c["total_ut_steps"]), post_norms=True)

@@ -142,6 +142,16 @@ class LlamaConfig:
         return None
 
     @property
+    def recurrent_state(self):
+        """What the model carries per SEQUENCE beside its cache rows
+        (a state-space layer's state): nothing here
+        (serving/kv_cache.py builds a HybridPool where there is)."""
+        return None
+
+    # sparse experts whose weights live here: none (serving/engine.py)
+    experts_held = 0
+
+    @property
     def post_norm_init(self) -> float:
         """What the seeded initialisers give an output norm's gain: a
         branch's normed output has unit size times this, and a token's

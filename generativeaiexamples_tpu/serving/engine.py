@@ -67,6 +67,7 @@ from generativeaiexamples_tpu.obs import tracing
 from generativeaiexamples_tpu.serving import engine_model
 from generativeaiexamples_tpu.serving.kv_cache import (
     PageAllocator, PagePool, SequencePages, kernel_append)
+from generativeaiexamples_tpu.serving.ssm_state_update import kernel_update
 from generativeaiexamples_tpu.serving import flight as flight_mod
 from generativeaiexamples_tpu.serving.paged_attention_int8 import page_counts
 from generativeaiexamples_tpu.serving.multihost import (
@@ -337,6 +338,15 @@ class EngineMetrics:
         self.moe_pairs_routed = 0
         self.moe_pairs_local = 0
         self.experts_held = 0
+        # Recurrent state beside the cache (0 for a model without it):
+        # the bytes a decode slot's state-space rows take and the layers
+        # that have them (gauges), the slots a prefill wrote whole, and
+        # the decode steps whose state update ran as the in-place kernel
+        # (ssm_state_update.kernel_update).
+        self.ssm_state_bytes_per_slot = 0
+        self.ssm_layers = 0
+        self.ssm_slot_writes = 0
+        self.ssm_steps_kernel = 0
         self.busy_slots_acc = 0
         # Speculative decoding: committed tokens vs slot-steps, for the
         # acceptance-rate gauge (1.0 = no drafts accepted, k+1 = all).
@@ -499,6 +509,10 @@ class EngineMetrics:
             "moe_pairs_routed": self.moe_pairs_routed,
             "moe_pairs_local": self.moe_pairs_local,
             "experts_held": self.experts_held,
+            "ssm_state_bytes_per_slot": self.ssm_state_bytes_per_slot,
+            "ssm_layers": self.ssm_layers,
+            "ssm_slot_writes": self.ssm_slot_writes,
+            "ssm_steps_kernel": self.ssm_steps_kernel,
             "mean_batch_occupancy": occ,
             "tokens_per_sec": self.tokens_per_sec(),
             "prefill_tokens": self.prefill_tokens,
@@ -620,6 +634,31 @@ def _refuse_unwalked_lanes(cfg: LlamaConfig, ecfg: EngineConfig,
                 + ", ".join(f"engine.{name} ({what})" for name, what in on)
                 + ": those lanes have no latent form; turn them off")
         return
+    if cfg.recurrent_state is not None:
+        # The per-slot rows of a kv_cache.HybridPool are written by the
+        # prefill and decode programs only: nothing snapshots them beside
+        # a shared page, moves them with a sequence or rolls them back,
+        # and no step but those two has the state-space block.
+        on = [(name, what) for name, what in _ONE_PASS_LANES
+              if getattr(ecfg, name)]
+        if mesh is not None:
+            on.append(("mesh", "tensor parallelism: state-space heads have "
+                       "no sharded form"))
+        if ecfg.multihost:
+            on.append(("multihost", "the multi-host replay"))
+        if ecfg.qos and ecfg.qos_preempt_prefill:
+            on.append(("qos_preempt_prefill", "pausing and resuming a "
+                       "sequence's prefill"))
+        if on:
+            rs = cfg.recurrent_state
+            raise ValueError(
+                f"model carries recurrent state ({rs.layers} state-space "
+                f"layers, {rs.bytes_per_slot} bytes a sequence) beside its "
+                f"cache; not served with "
+                + ", ".join(f"engine.{name} ({what})" for name, what in on)
+                + ": those lanes re-read, share, move or roll back cache "
+                "and would have to carry the state too; turn them off")
+        return
     if cfg.n_passes == 1:
         return
     on = [(name, what) for name, what in _ONE_PASS_LANES
@@ -739,7 +778,8 @@ class LLMEngine:
         self.pool = PagePool.zeros(cfg, n_pages, ps,
                                    dtype=jnp.dtype(self.ecfg.kv_dtype),
                                    sharding=kv_sharding,
-                                   scale_sharding=scale_sharding)
+                                   scale_sharding=scale_sharding,
+                                   slots=self.ecfg.max_batch_size)
         self.allocator = PageAllocator(n_pages)
         # Cross-request prefix KV reuse (serving/prefix_cache.py):
         # scheduler-thread-owned, like the allocator. The allocator's
@@ -792,9 +832,17 @@ class LLMEngine:
         self._load_rows = engine_model.expert_load_rows(cfg)
         self.metrics.experts_held = (cfg.experts_held if self._load_rows
                                      else 0)
+        rs = cfg.recurrent_state
+        paged = self.pool if rs is None else self.pool.pages
         self.metrics.kv_bytes_per_token = sum(
-            leaf.nbytes for leaf in jax.tree.leaves(self.pool)
+            leaf.nbytes for leaf in jax.tree.leaves(paged)
         ) // (n_pages * ps)
+        if rs is not None:
+            self.metrics.ssm_layers = rs.layers
+            self.metrics.ssm_state_bytes_per_slot = rs.bytes_per_slot
+            _LOG.info("state pool: %d state-space layers x %d slots, %d "
+                      "bytes a slot", rs.layers, self.ecfg.max_batch_size,
+                      rs.bytes_per_slot)
         _LOG.info("kv pool: %d rows (%d layers x %d passes) x %d pages of "
                   "%d tokens, %s; %d bytes a cached token",
                   cfg.cache_rows, cfg.n_layers, cfg.n_passes, n_pages, ps,
@@ -1090,7 +1138,9 @@ class LLMEngine:
                         self._put(np.ones((n,), np.float32)),
                         self._put(np.zeros((n,), np.int32)),
                         key, self.use_pallas, sampling_flags=flags,
-                        mesh=self.mesh)
+                        mesh=self.mesh,
+                        state_slots=self._state_slots(
+                            np.full((n,), len(self.slots), np.int32)))
                     # The admission scatter compiles per group size;
                     # out-of-bounds indices drop, so this writes nothing.
                     self._last_tokens = engine_model.set_last_tokens(
@@ -1537,9 +1587,11 @@ class LLMEngine:
         # scatter into the page pool), so the real ceiling is the page
         # capacity minus one generated token.
         max_prompt = self.max_pages * self.ecfg.page_size - 1
-        if self.cfg.latent_row is not None:
+        if self.cfg.latent_row is not None \
+                or self.cfg.recurrent_state is not None:
             # the chunked long-prompt lane (a contiguous scratch cache of
-            # K and V per head) has no latent form
+            # K and V per head) has no latent form and carries no
+            # recurrent state from chunk to chunk
             max_prompt = min(max_prompt, self.buckets[-1])
         if len(req.prompt_ids) > max_prompt:
             if not req.truncate_prompt:
@@ -2553,6 +2605,8 @@ class LLMEngine:
             self.slots[slot_idx] = slot
             metas.append((slot_idx, slot))
             self.metrics.prefill_tokens += len(ids)
+            if self.cfg.recurrent_state is not None:
+                self.metrics.ssm_slot_writes += 1
             if self.flight.enabled:
                 self.flight.record_event(
                     EV_PREFILL_DISPATCH, time.perf_counter(),
@@ -3249,12 +3303,16 @@ class LLMEngine:
         # a speculative engine's programs keep the staged one
         if not (plan.spec_k or plan.spec_state) \
                 and self.cfg.latent_row is None \
+                and self.cfg.recurrent_state is None \
                 and engine_model.direct_qkv(self.cfg, K):
             self.metrics.decode_steps_direct_qkv += K
         if self._load_rows:  # every live slot's token, in every expert block
             self.metrics.moe_pairs_routed += (
                 len(active) * K * self.cfg.n_moe_layers
                 * self.cfg.n_experts_per_tok)
+        if self.cfg.recurrent_state is not None \
+                and kernel_update(self.pool.state, self.use_pallas):
+            self.metrics.ssm_steps_kernel += K
         # every decode program but the verifies writes one row a slot
         if not plan.spec_k and kernel_append(self.pool, self.use_pallas):
             self.metrics.decode_steps_kernel_append += K
@@ -3397,6 +3455,15 @@ class LLMEngine:
     # never enters an executor: only its outputs — launch order and
     # host scalars — cross the wire (the GL703 invariant).
 
+    def _state_slots(self, idxs):
+        """A prefill's `state_slots`: the decode slots its rows were
+        admitted to, for a model whose recurrent state a prefill writes
+        (a padding row's index is past the last slot and dropped); None
+        for every other model, whose programs then lower as they did."""
+        if self.cfg.recurrent_state is None:
+            return None
+        return self._put(idxs)
+
     def _exec_prefill(self, rec: Dict[str, Any]):
         """Execute one `prefill` record: the batched prefill forward +
         on-device sampling, the first-token scatter, and (speculative
@@ -3414,7 +3481,8 @@ class LLMEngine:
             self._put(rec["lengths"]), self._put(rec["rows"]),
             self._put(rec["temps"]), self._put(rec["top_ps"]),
             self._put(rec["top_ks"]), self._next_key(), self.use_pallas,
-            sampling_flags=flags, mesh=self.mesh)
+            sampling_flags=flags, mesh=self.mesh,
+            state_slots=self._state_slots(rec["idxs"]))
         # Scatter the first-tokens into the device buffer (padding rows'
         # out-of-bounds indices are dropped on device).
         self._last_tokens = engine_model.set_last_tokens(

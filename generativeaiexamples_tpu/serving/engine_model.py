@@ -18,6 +18,7 @@ Both are shape-stable: prefill compiles once per bucket, decode once per
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 import time
@@ -26,12 +27,13 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from generativeaiexamples_tpu.models import latent_moe
+from generativeaiexamples_tpu.models import hybrid_ssm, latent_moe
 from generativeaiexamples_tpu.models.llama import (
     LlamaConfig, attn_out, final_norm, finish_block, project_qkv, rms_norm,
     walk_passes)
 from generativeaiexamples_tpu.ops import attention as attn_ops
 from generativeaiexamples_tpu.ops.quant import mm
+from generativeaiexamples_tpu.serving import ssm_state_update as ssm_update
 from generativeaiexamples_tpu.serving.kv_cache import PagePool, token_slots
 from generativeaiexamples_tpu.serving.paged_attention import (
     paged_attention_dispatch)
@@ -210,12 +212,120 @@ def _latent_decode_once(params, cfg, pool, tokens, page_tables, lengths,
     return logits, pool, jnp.stack(counts), jnp.stack(choices)
 
 
+# -- state-space layers beside attention (models/hybrid_ssm.py) ------------
+#
+# A model with recurrent state (cfg.recurrent_state) runs the same four
+# programs over a kv_cache.HybridPool: its attention layers' K and V go
+# to the pool's pages as a Llama's do, and every state-space layer's
+# state and convolution tail live in the pool's per-SLOT rows, which a
+# prefill writes whole (so it takes the slots, beside the page tables)
+# and a decode step updates in place.
+
+
+def _hybrid_prefill(params, cfg, pool, tokens, lengths, table_rows,
+                    state_slots, use_pallas):
+    """Prompts [N, S] through every block's prompt form; the attention
+    layers' K and V go to the rows' pages and each state-space layer's
+    state after the row's LAST REAL token (the padding does not advance
+    it) to decode slots `state_slots` [N] (None: row i's to slot i),
+    whole. -> (last-position logits [N, V], pool)."""
+    N, S = tokens.shape
+    if state_slots is None:  # row i of the group is decode slot i
+        state_slots = jnp.arange(N, dtype=jnp.int32)
+    ps = pool.page_size
+    x, kv, states, tails, _ = hybrid_ssm.walk_prompt(params, cfg, tokens,
+                                                     lengths, use_pallas)
+
+    def paged(t):  # [La, N, KH, S, Hd] -> [La, KH, N * npages, ps, Hd]
+        La, _, KH, _, Hd = t.shape
+        t = t.reshape(La, N, KH, S // ps, ps, Hd).transpose(0, 2, 1, 3, 4, 5)
+        return t.reshape(La, KH, N * (S // ps), ps, Hd)
+
+    pages = pool.pages.write_pages(
+        pool.pages.encode_pages(paged(kv[0]), paged(kv[1])),
+        table_rows.reshape(-1))
+    pool = dataclasses.replace(pool, pages=pages).write_slots(
+        state_slots.reshape(-1), states, tails)
+    last = jnp.take_along_axis(
+        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
+    return hybrid_ssm.logits_of(cfg, params, last)[:, 0], pool
+
+
+def _hybrid_decode_once(params, cfg, pool, tokens, page_tables, lengths,
+                        use_pallas, mask=None):
+    """_decode_once for a model with recurrent state, the blocks
+    unrolled: a state-space block reads and rewrites its slots' rows of
+    the pool (the convolution's tail here, the state in place through
+    serving/ssm_state_update.py), an attention block appends K and V and
+    attends through the paged kernel. `mask` [B]: the live slots; an idle
+    slot's state, tail and expert pairs are left alone. Returns (logits
+    [B, V], pool, pairs each expert took in each block [L, E], the
+    router's choices [L, B, k])."""
+    B = tokens.shape[0]
+    ps = pool.page_size
+    pages, state, tail = pool.pages, pool.state, pool.tail
+    slots = token_slots(
+        cfg.n_kv_heads, page_tables[jnp.arange(B), (lengths - 1) // ps],
+        (lengths - 1) % ps, use_pallas)
+    x = hybrid_ssm.embed(cfg, params, tokens)[:, None]  # [B, 1, D]
+    sliced, experts = hybrid_ssm.split_experts(params["ffn"])
+    counts, choices = [], []
+    for l, (kind, i) in enumerate(hybrid_ssm.layer_plan(cfg)):
+        if kind == hybrid_ssm.MAMBA:
+            w = hybrid_ssm.take_layer(params["ssm"], i)
+            h = rms_norm(x[:, 0], w["ln1"], cfg.rms_eps).astype(cfg.dtype)
+            z, xbc, dt = hybrid_ssm.ssm_project(cfg, h, w)
+            xbc, window = hybrid_ssm.conv_step(cfg, xbc, tail[i], w)
+            if mask is not None:
+                window = jnp.where(mask[None, :, None], window, tail[i])
+            tail = tail.at[i].set(window)
+            xs, Bv, Cv = hybrid_ssm.split_xbc(cfg, xbc)
+            step, log_a = hybrid_ssm.step_and_decay(w, dt)
+            with jax.named_scope("ssm.update"):
+                state, y = ssm_update.ssm_state_update(
+                    state, i, mask, step, log_a, xs, Bv, Cv, use_pallas)
+                y = y + w["D"][:, None] * xs.astype(jnp.float32)
+            x = hybrid_ssm.branch(
+                cfg, x, hybrid_ssm.gate_and_project(cfg, y, z, w)[:, None])
+        else:
+            w = hybrid_ssm.take_layer(params["attn"], i)
+            h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
+            q, k, v = hybrid_ssm.project_qkv(cfg, h, w)
+            pages = pages.append(i, slots, k[:, :, 0].transpose(1, 0, 2),
+                                 v[:, :, 0].transpose(1, 0, 2))
+            k_pages, v_pages, k_scales, layer = pages.attention_operands(i)
+            out = paged_attention_dispatch(
+                q[:, :, 0], k_pages, v_pages, page_tables, lengths,
+                scale=cfg.attention_multiplier, k_scales=k_scales,
+                layer=layer, use_pallas=use_pallas)
+            x = hybrid_ssm.attn_out(cfg, x, out[:, :, None, :], w)
+        x, n, idx = hybrid_ssm.feed_forward(
+            cfg, x, hybrid_ssm.take_layer(sliced, l), experts, l, use_pallas,
+            mask)
+        counts.append(n)
+        choices.append(idx[:, 0])
+    logits = hybrid_ssm.logits_of(cfg, params, x)[:, 0]
+    pool = dataclasses.replace(pool, pages=pages, state=state, tail=tail)
+    return logits, pool, jnp.stack(counts), jnp.stack(choices)
+
+
+def _expert_decode_once(cfg):
+    """The decode body of a model the Llama walk does not run, or None:
+    `(params, cfg, pool, tokens, page_tables, lengths, use_pallas, mask)
+    -> (logits, pool, expert pair counts, choices)`."""
+    if cfg.latent_row is not None:
+        return _latent_decode_once
+    if cfg.recurrent_state is not None:
+        return _hybrid_decode_once
+    return None
+
+
 def expert_load_rows(cfg) -> int:
     """Rows a decode block carries below its B token rows: one per
     (expert block, held expert), column 1 + i the pairs it took in step
     i; 0 for a model without experts. The engine splits them off where
     the block lands (one readback, as before)."""
-    if cfg.latent_row is None:
+    if not cfg.experts_held:
         return 0
     return cfg.n_moe_layers * cfg.experts_held
 
@@ -229,8 +339,11 @@ def prefill_step(
     table_row: jax.Array,   # [S_bucket // page_size] page ids (0-padded)
     use_pallas: Optional[bool] = None,
     mesh=None,
+    state_slot: Optional[jax.Array] = None,  # [] the decode slot
 ) -> Tuple[jax.Array, PagePool]:
     """Prefill one sequence; returns (last-token logits [V], pool).
+    `state_slot`: where a model with recurrent state leaves the prompt's
+    (no other model reads it).
 
     The layer scan only READS weights and returns the per-row k/v
     ([R, S, KH, Hd], R = cfg.cache_rows, a few MB); the page pool is
@@ -240,6 +353,11 @@ def prefill_step(
     if cfg.latent_row is not None:
         logits, pool = _latent_prefill(params, cfg, pool, tokens,
                                        length[None], table_row, use_pallas)
+        return logits[0], pool
+    if cfg.recurrent_state is not None:
+        logits, pool = _hybrid_prefill(params, cfg, pool, tokens,
+                                       length[None], table_row, state_slot,
+                                       use_pallas)
         return logits[0], pool
     _, S = tokens.shape
     ps = pool.page_size
@@ -286,6 +404,7 @@ def prefill_batch_step(
     use_pallas: Optional[bool] = None,
     sampling_flags: Tuple[bool, bool, bool] = (True, False, False),
     mesh=None,
+    state_slots: Optional[jax.Array] = None,  # [N] decode slots
 ) -> Tuple[jax.Array, PagePool]:
     """Prefill N sequences in ONE dispatch and sample each one's first
     token on device. Under burst admission this reads the weights once
@@ -295,7 +414,9 @@ def prefill_batch_step(
 
     Padding rows (lengths=1, table page 0) are computed and their k/v
     land in the sink page; their sampled tokens are ignored by the
-    caller. Compiles per (N_bucket, S_bucket)."""
+    caller. Compiles per (N_bucket, S_bucket). `state_slots`: the decode
+    slot each row's recurrent state goes to, for a model that has one
+    (a padding row's is past the last slot and dropped)."""
     from generativeaiexamples_tpu.serving.sampling import SamplingParams, sample
 
     all_greedy, any_top_k, any_top_p = sampling_flags
@@ -303,6 +424,11 @@ def prefill_batch_step(
     if cfg.latent_row is not None:
         logits, pool = _latent_prefill(params, cfg, pool, tokens, lengths,
                                        table_rows, use_pallas)
+        return sample(logits, sp, key, all_greedy=all_greedy,
+                      any_top_k=any_top_k, any_top_p=any_top_p), pool
+    if cfg.recurrent_state is not None:
+        logits, pool = _hybrid_prefill(params, cfg, pool, tokens, lengths,
+                                       table_rows, state_slots, use_pallas)
         return sample(logits, sp, key, all_greedy=all_greedy,
                       any_top_k=any_top_k, any_top_p=any_top_p), pool
     N, S = tokens.shape
@@ -451,9 +577,10 @@ def decode_step(
     mesh=None,
 ) -> Tuple[jax.Array, PagePool]:
     """One decode step for the whole slot batch -> (logits [B, V], pool)."""
-    if cfg.latent_row is not None:
-        return _latent_decode_once(params, cfg, pool, tokens, page_tables,
-                                   lengths, use_pallas)[:2]
+    once = _expert_decode_once(cfg)
+    if once is not None:
+        return once(params, cfg, pool, tokens, page_tables, lengths,
+                    use_pallas)[:2]
     return _decode_once(params, cfg, pool, tokens, page_tables, lengths,
                         use_pallas, mesh, direct_qkv(cfg, 1))
 
@@ -492,12 +619,12 @@ def decode_multi_step(
     all_greedy, any_top_k, any_top_p = sampling_flags
     tokens = last_tokens
     out_tokens = [tokens]
-    latent = cfg.latent_row is not None
-    direct = not latent and direct_qkv(cfg, n_steps)
+    once = _expert_decode_once(cfg)
+    direct = once is None and direct_qkv(cfg, n_steps)
     loads = []  # a model with experts: the pairs each took, step by step
     for i in range(n_steps):
-        if latent:
-            logits, pool, load, _ = _latent_decode_once(
+        if once is not None:
+            logits, pool, load, _ = once(
                 params, cfg, pool, tokens, page_tables, lengths, use_pallas,
                 mask=active)
             loads.append(load.reshape(-1))

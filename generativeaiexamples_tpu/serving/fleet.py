@@ -78,6 +78,7 @@ _COUNTER_KEYS = (
     "decode_steps_direct_qkv", "decode_steps_kernel_append",
     "decode_attn_pages_live", "decode_attn_pages_walked",
     "moe_pairs_routed", "moe_pairs_local",
+    "ssm_slot_writes", "ssm_steps_kernel",
     "prefill_tokens", "fused_steps",
     "fused_prefill_tokens", "prefill_stall_beats",
     "fused_sample_dispatches", "prefix_hits",

@@ -16,6 +16,14 @@ inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
   reserved garbage sink — padding positions in bucketed prefills and
   unused page-table slots point at it, so scatter/gather never needs
   dynamic shapes.
+- A model with recurrent state (cfg.recurrent_state: state-space layers
+  beside its attention layers) gets a HybridPool: the page pool of its
+  attention layers' rows (`pages`, any of the pools above) and, beside
+  it, a pool of per-SLOT state that is neither paged nor per token: for
+  every state-space layer and decode slot a float32 state and the last
+  inputs of the layer's convolution. A prefill writes a slot's state
+  whole; a decode step updates it in place
+  (serving/ssm_state_update.py).
 - Host: PageAllocator hands out page ids (plain Python free list — the
   scheduler thread owns it; no device sync needed to allocate).
 - Page tables are [B, max_pages] int32 arrays shipped to the device each
@@ -209,12 +217,19 @@ class PagePool:
 
     @staticmethod
     def zeros(cfg: LlamaConfig, n_pages: int, page_size: int = 64,
-              dtype=None, sharding=None, scale_sharding=None):
+              dtype=None, sharding=None, scale_sharding=None, slots=None):
         """With `sharding`, each buffer is allocated ALREADY sharded
         (jit with out_shardings) — a TP-serving pool sized to fill the
         whole mesh must never materialize on one device first.
-        `dtype="int8"` returns the fused QuantPagePool."""
+        `dtype="int8"` returns the fused QuantPagePool. A model with
+        recurrent state gets a HybridPool with room for `slots` decode
+        slots beside the pages."""
         dtype = jnp.dtype(dtype or cfg.dtype)
+        if cfg.recurrent_state is not None:  # the model says what it carries
+            if slots is None:
+                raise ValueError("a model with recurrent state keeps it per "
+                                 "decode slot: PagePool.zeros needs `slots`")
+            return HybridPool.zeros(cfg, n_pages, page_size, dtype, slots)
         if cfg.latent_row is not None:  # the model says what a row caches
             if dtype == jnp.int8:
                 raise ValueError(
@@ -518,6 +533,93 @@ class LatentPagePool:
                                      sharding), page_size)
 
 
+@dataclasses.dataclass
+class HybridPool:
+    """The pool of a model with recurrent state (cfg.recurrent_state):
+    two kinds of state in one donated tree.
+
+    `pages` is the page pool of the ATTENTION layers' rows (cfg.cache_rows
+    of them; a PagePool or a QuantPagePool, with every method those have);
+    the page allocator, the page tables and the attention kernels see
+    only it. `state` [Ls, slots, H, P, N] float32 and `tail` [Ls, T,
+    slots, W] (the convolution's last T inputs, oldest first, in the
+    model's type; the slots before the channels so that T = 3 is not
+    padded to a tile) belong to DECODE SLOTS, not to pages: slot b's rows
+    are whatever sequence occupies slot b. Nothing allocates them: a
+    prefill writes a slot's rows whole (`write_slots`), so a reused slot
+    never sees its predecessor's, and a decode step updates them in place
+    (`state` through serving/ssm_state_update.py).
+
+    The lanes that re-read, share, move, snapshot or roll back cache
+    (prefix reuse, the pager, the disaggregated transfer, speculation,
+    the long-prompt scratch cache) would have to carry this state too and
+    do not: LLMEngine refuses them by name for such a model."""
+
+    pages: "PagePool | QuantPagePool"
+    state: jax.Array
+    tail: jax.Array
+
+    @property
+    def page_size(self) -> int:
+        return self.pages.page_size
+
+    @property
+    def n_pages(self) -> int:
+        return self.pages.n_pages
+
+    @property
+    def quantized(self) -> bool:
+        return self.pages.quantized
+
+    @property
+    def geometry(self) -> PoolGeometry:
+        return self.pages.geometry
+
+    @property
+    def slots(self) -> int:
+        return self.state.shape[1]
+
+    def devices(self):
+        return self.pages.devices()
+
+    def write_slots(self, slots, state, tail) -> "HybridPool":
+        """A prefill's end: `state` [Ls, n, H, P, N] and `tail` [Ls, n, T,
+        W] into decode slots `slots` [n], whole (an index past the last
+        slot is dropped: a padding row). One scatter an array, every
+        advanced index adjacent and leading."""
+        Ls, n = state.shape[:2]
+        li = jnp.arange(Ls)[:, None]
+        new = self.state.at[li, slots[None, :]].set(
+            state.astype(self.state.dtype), mode="drop")
+        T = tail.shape[2]
+        li3 = jnp.arange(Ls)[:, None, None]
+        ti = jnp.arange(T)[None, :, None]
+        new_tail = self.tail.at[li3, ti, slots[None, None, :]].set(
+            tail.transpose(0, 2, 1, 3).astype(self.tail.dtype), mode="drop")
+        return dataclasses.replace(self, state=new, tail=new_tail)
+
+    @staticmethod
+    def zeros(cfg, n_pages: int, page_size: int, dtype,
+              slots: int) -> "HybridPool":
+        rs = cfg.recurrent_state
+        if dtype == jnp.int8:
+            pages = QuantPagePool.zeros(cfg, n_pages, page_size)
+        else:
+            shape = (cfg.cache_rows, cfg.n_kv_heads, n_pages, page_size,
+                     cfg.head_dim)
+            pages = PagePool(_alloc(shape, dtype, None),
+                             _alloc(shape, dtype, None), page_size)
+        return HybridPool(
+            pages,
+            _alloc((rs.layers, slots, rs.heads, rs.head_dim, rs.state),
+                   jnp.float32, None),
+            _alloc((rs.layers, rs.tail, slots, rs.conv_width), cfg.dtype,
+                   None))
+
+
+jax.tree_util.register_dataclass(
+    HybridPool, data_fields=["pages", "state", "tail"], meta_fields=[]
+)
 jax.tree_util.register_dataclass(
     PagePool, data_fields=["k", "v"], meta_fields=["page_size"]
 )

@@ -162,6 +162,19 @@ def _shard_numel(shape, spec, axis_sizes: Dict[str, int]) -> int:
     return n
 
 
+def _one_chip(lcfg) -> bool:
+    """A model whose parameters have no sharded layout."""
+    return lcfg.latent_row is not None or lcfg.recurrent_state is not None
+
+
+def state_pool_bytes_per_device(lcfg, ecfg: EngineConfig) -> int:
+    """Bytes of the per-slot recurrent state beside the pages
+    (kv_cache.HybridPool.state and .tail): a fixed cost of
+    max_batch_size slots, not paged; 0 for a model without it."""
+    rs = lcfg.recurrent_state
+    return 0 if rs is None else ecfg.max_batch_size * rs.bytes_per_slot
+
+
 def weight_bytes_per_device(lcfg: LlamaConfig, axis_sizes: Dict[str, int],
                             quantize: bool = False) -> int:
     """Exact per-device bytes of the (possibly int8) sharded param tree.
@@ -174,16 +187,18 @@ def weight_bytes_per_device(lcfg: LlamaConfig, axis_sizes: Dict[str, int],
     from generativeaiexamples_tpu.models import llama
     from generativeaiexamples_tpu.ops.quant import LLAMA_QUANT_KEYS
 
-    if lcfg.latent_row is not None:
-        # models/latent_moe.py: whole on one chip (its share of the
-        # experts is the configuration's, not a mesh axis's)
+    if _one_chip(lcfg):
+        # models/latent_moe.py, models/hybrid_ssm.py: whole on one chip
+        # (a share of the experts is the configuration's, not a mesh
+        # axis's)
         if any(int(n) > 1 for n in axis_sizes.values()):
             raise MemoryPlanError(
-                "a latent-attention model has no tensor-parallel layout: "
-                f"mesh axes {axis_sizes}")
-        from generativeaiexamples_tpu.models import latent_moe
+                "a model with latent attention or recurrent state has no "
+                f"tensor-parallel layout: mesh axes {axis_sizes}")
+        from generativeaiexamples_tpu.models import hybrid_ssm, latent_moe
 
-        shapes = jax.eval_shape(lambda: latent_moe.init_params_on_device(
+        model = latent_moe if lcfg.latent_row is not None else hybrid_ssm
+        shapes = jax.eval_shape(lambda: model.init_params_on_device(
             lcfg, quantize=quantize))
         return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
                    for leaf in jax.tree.leaves(shapes))
@@ -248,7 +263,7 @@ def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
     # KVCache [L, 1, KH, max_seq_len, Hd] x (k, v) on device
     # (engine._max_long_prefills = 1); counted unsharded — GSPMD may
     # shard it, so this over-counts, never under.
-    if lcfg.latent_row is not None:
+    if _one_chip(lcfg):
         long_pf = 0  # no long-prompt scratch: the engine refuses the lane
     else:
         long_pf = (2 * lcfg.cache_rows * lcfg.n_kv_heads
@@ -262,7 +277,9 @@ def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
     n_seq = max(1, min(group, ecfg.max_batch_size))
     bucket = max(ecfg.prefill_buckets) if ecfg.prefill_buckets else 128
     tokens = n_seq * bucket
-    mlp = math.ceil(lcfg.mlp_dim / tp)
+    # (a state-space model's widest activation is its mixer's input
+    # projection, which plays the feed-forward's part here)
+    mlp = math.ceil(getattr(lcfg, "mlp_dim", 0) / tp) or 2 * lcfg.d_inner
     acts = tokens * (4 * lcfg.dim + 2 * mlp) * wsize
     logits = n_seq * math.ceil(lcfg.vocab_size / tp) * 4
     return (
@@ -333,6 +350,11 @@ def plan_engine_memory(
         lcfg, sizes, quantize=quantize), True,
         "int8 + f32 scales" if quantize else str(lcfg.dtype)),
     ) + _scratch_lines(lcfg, ecfg, sizes)
+    if lcfg.recurrent_state is not None:
+        lines += (PlanLine(
+            "state_pool", state_pool_bytes_per_device(lcfg, ecfg), False,
+            f"{ecfg.max_batch_size} slots x "
+            f"{lcfg.recurrent_state.bytes_per_slot} B, not paged"),)
 
     page = pool_page_bytes_per_device(lcfg, ecfg, sizes)
     fixed = sum(l.bytes_per_device for l in lines)
@@ -390,7 +412,7 @@ def smallest_fitting_mesh(lcfg: LlamaConfig, ecfg: EngineConfig,
     `sharding.validate_tp` would accept — in increasing order and
     returns the first geometry that holds at least one max-length
     sequence, or None."""
-    if lcfg.latent_row is not None:
+    if _one_chip(lcfg):
         return None  # whole on one chip: weight_bytes_per_device
     g = math.gcd(math.gcd(lcfg.n_heads, lcfg.n_kv_heads),
                  math.gcd(lcfg.mlp_dim, lcfg.vocab_size))

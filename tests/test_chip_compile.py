@@ -31,6 +31,8 @@ from generativeaiexamples_tpu.serving.paged_attention_mla import (
     paged_attention_mla)
 from generativeaiexamples_tpu.serving.paged_attention_tree import (
     paged_tree_attention)
+from generativeaiexamples_tpu.serving.ssm_state_update import (
+    ssm_state_update_pallas)
 
 # llama3-8b decode: 64 slots, 32 query / 8 kv heads of 128, pages of 128.
 B, H, KH, HD, PS, MAXP, L = 64, 32, 8, 128, 128, 4, 2
@@ -134,6 +136,35 @@ KERNELS = {
         lambda *a: _grouped(128, *a),
         [((12288 + 12 * 128, 2048), BF16), ((14, 12, 2048, 7168), I8),
          ((14, 12, 7168), F32), ((108,), I32), ((1,), I32)]),
+    # granite-4.0-h-small's widths
+    # (benchmark/configs/granite-4.0-h-small-int8.json): the in-place
+    # state update of one state-space layer over the per-slot pool, 96
+    # slots of [128, 64, 128] float32; and the grouped matmul's second
+    # shape, 72 whole experts of 768, a decode step's 960 pairs
+    "ssm_state_update_96_slots": (
+        lambda state, o, n, a, xdt, bv, cv: ssm_state_update_pallas(
+            state, 4, o, n, a, xdt, bv, cv),
+        [((9, 96, 128, 64, 128), F32), ((96,), I32), ((1,), I32),
+         ((96, 128, 128), F32), ((96, 128, 64), F32), ((96, 128), F32),
+         ((96, 128), F32)]),
+    "grouped_expert_matmul_decode_72_of_768": (
+        lambda *a: _grouped(32, *a),
+        [((960 + 72 * 32, 4096), BF16), ((10, 72, 4096, 1536), I8),
+         ((10, 72, 1536), F32), ((102,), I32), ((1,), I32)]),
+    # a prompt group of 4 x 384 tokens in tiles of 64
+    # (hybrid_ssm.PREFILL_TILE_ROWS)
+    "grouped_expert_matmul_prefill_72_of_768": (
+        lambda *a: _grouped(64, *a),
+        [((15360 + 72 * 64, 4096), BF16), ((10, 72, 4096, 1536), I8),
+         ((10, 72, 1536), F32), ((312,), I32), ((1,), I32)]),
+    "grouped_expert_matmul_prefill_down_72_of_768": (
+        lambda *a: _grouped(64, *a),
+        [((15360 + 72 * 64, 768), BF16), ((10, 72, 768, 4096), I8),
+         ((10, 72, 4096), F32), ((312,), I32), ((1,), I32)]),
+    "grouped_expert_matmul_down_72_of_768": (
+        lambda *a: _grouped(32, *a),
+        [((960 + 72 * 32, 768), BF16), ((10, 72, 768, 4096), I8),
+         ((10, 72, 4096), F32), ((102,), I32), ((1,), I32)]),
 }
 
 
