@@ -98,6 +98,7 @@ def _flash_kernel(
     scale: float,
     block_q: int,
     block_k: int,
+    num_q_blocks: int,
     num_k_blocks: int,
 ):
     b = pl.program_id(0)
@@ -141,10 +142,21 @@ def _flash_kernel(
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
+    # Skip what contributes exact zeros: k-blocks strictly above the
+    # causal diagonal (the offset shifts the diagonal for cached-
+    # continuation prefill) and, where the grid has more than one block
+    # (a one-block call has no dead one and stays the kernel it was),
+    # k-blocks that start at or past `lengths[b]` and q-blocks whose
+    # first row does. A dead q-block's output is zeros; nobody reads it.
+    live = []
     if causal:
-        # Skip k-blocks strictly above the causal diagonal (the offset
-        # shifts the diagonal for cached-continuation prefill).
-        pl.when(k_start <= q_start + q_offs_ref[b] + block_q - 1)(_body)
+        live.append(k_start <= q_start + q_offs_ref[b] + block_q - 1)
+    if num_k_blocks > 1:
+        live.append(k_start < lengths_ref[b])
+    if num_q_blocks > 1:
+        live.append(q_start + q_offs_ref[b] < lengths_ref[b])
+    if live:
+        pl.when(functools.reduce(jnp.logical_and, live))(_body)
     else:
         _body()
 
@@ -202,6 +214,7 @@ def flash_attention(
         scale=scale,
         block_q=block_q,
         block_k=block_k,
+        num_q_blocks=nq,
         num_k_blocks=nk,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -327,6 +327,15 @@ class EngineMetrics:
         # share of page copies that remain.
         self.decode_attn_pages_live = 0
         self.decode_attn_pages_walked = 0
+        # Over the batched prefill programs dispatched, summed over the
+        # N rows of every group: the rows the program computed (a
+        # Llama's single prompt rounded up to one of
+        # engine_model.prefill_row_counts; whole buckets for a group of
+        # several and for a model whose prefill has no such form) and
+        # the rows of its bucket. live / bucket is the share of bucket
+        # rows still computed.
+        self.prefill_rows_live = 0
+        self.prefill_rows_bucket = 0
         # KV pool geometry (set once at engine build): rows of the pool
         # (layers x passes) and the bytes one cached token takes over
         # all rows, scales included.
@@ -504,6 +513,8 @@ class EngineMetrics:
             "decode_steps_kernel_append": self.decode_steps_kernel_append,
             "decode_attn_pages_live": self.decode_attn_pages_live,
             "decode_attn_pages_walked": self.decode_attn_pages_walked,
+            "prefill_rows_live": self.prefill_rows_live,
+            "prefill_rows_bucket": self.prefill_rows_bucket,
             "kv_cache_rows": self.kv_cache_rows,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "moe_pairs_routed": self.moe_pairs_routed,
@@ -2594,6 +2605,12 @@ class LLMEngine:
             idxs[j] = slot_idx
         all_greedy = bool(all(temps[:n] <= 0.0))
         flags = (True, False, False) if all_greedy else (False, True, True)
+        live = bucket  # the rows the program computes of each prompt
+        if self.cfg.latent_row is None and self.cfg.recurrent_state is None:
+            live = engine_model.prefill_row_counts(bucket, ps, N)[
+                int(engine_model.prefill_live_index(lengths, bucket, ps))]
+        self.metrics.prefill_rows_live += N * live
+        self.metrics.prefill_rows_bucket += N * bucket
         toks = self._exec_prefill(dict(
             tokens=tokens, lengths=lengths, rows=rows, temps=temps,
             top_ps=top_ps, top_ks=top_ks, idxs=idxs,

@@ -26,6 +26,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from generativeaiexamples_tpu.models import hybrid_ssm, latent_moe
 from generativeaiexamples_tpu.models.llama import (
@@ -330,6 +331,56 @@ def expert_load_rows(cfg) -> int:
     return cfg.n_moe_layers * cfg.experts_held
 
 
+# A prefill program that holds ONE prompt computes, attends over and
+# writes only its LIVE rows: the prompt rounded up to one of the bucket's
+# few heights (prefill_row_counts), picked INSIDE the program from
+# `lengths` (prefill_live_index) by one lax.switch over the program on
+# tokens[:, :S_k]. The dispatch shape stays [N, S_bucket]: one compiled
+# program a group size and bucket. A bucket of one height lowers to the
+# program it always was.
+#
+# What a height buys and costs, on a v5e at Mistral-7B's widths (PERF.md,
+# PR 38; scripts/measure_prefill_rows.py measures the first again): a
+# program's time goes with its rows (a prompt of 1,590 in the 2,048
+# bucket: 209.4 ms whole, 177.8 at 1,792 rows; one of 130 in the 512
+# bucket: 47.5 and 25.1 at 256), and every height beyond the first adds
+# 2.5 s to a program's cold compile, 6 MiB to its executable and HALF A
+# SECOND to every warm restart (the branch is traced and lowered again
+# for the compile cache's key, and read back), for every group size that
+# has it. So the heights are the multiples of PREFILL_ROW_STEP (never
+# finer than an eighth of the bucket) in the bucket's TOP HALF (what lies
+# under it belongs to the bucket below, and pays at most half a bucket
+# where there is none), five at most, and only for N = 1 (with eight
+# heights a group size, rag.chain-open's restart grew from 72.7 to 81.8
+# s); a group of several runs its whole bucket, where the flash kernel
+# still skips each row's dead blocks. Sixteen heights would also make XLA
+# copy the donated page pool through the conditional (6 GB: the program
+# no longer fits beside the weights); tests/test_chip_compile.py holds
+# that the pool stays in place.
+PREFILL_ROW_STEP = 256
+
+
+def prefill_row_counts(bucket: int, page_size: int,
+                       group: int = 1) -> Tuple[int, ...]:
+    """The row counts a prefill program of `group` prompts of `bucket`
+    rows can run at, ascending; the last is the bucket."""
+    if group > 1:
+        return (bucket,)
+    step = max(PREFILL_ROW_STEP, bucket // 8)
+    step = -(-step // page_size) * page_size  # whole pages
+    return tuple(h for h in range(step, bucket, step)
+                 if 2 * h >= bucket) + (bucket,)
+
+
+def prefill_live_index(lengths, bucket: int, page_size: int):
+    """Which of prefill_row_counts(bucket, page_size, N) covers the
+    longest of `lengths` [N]: the heights below it, counted. The program
+    calls it on its traced operand and the engine's counters on the
+    host's numpy copy: they cannot disagree."""
+    counts = prefill_row_counts(bucket, page_size, lengths.shape[0])
+    return (lengths.max() > np.asarray(counts[:-1], np.int32)).sum()
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "use_pallas", "mesh"),
                    donate_argnames=("pool",))
 def prefill_step(
@@ -414,7 +465,10 @@ def prefill_batch_step(
 
     Padding rows (lengths=1, table page 0) are computed and their k/v
     land in the sink page; their sampled tokens are ignored by the
-    caller. Compiles per (N_bucket, S_bucket). `state_slots`: the decode
+    caller. Compiles per (N_bucket, S_bucket); a Llama's program of ONE
+    prompt runs its live rows only (prefill_row_counts, above): the rows
+    past them are not embedded, multiplied, attended or written, and a
+    table entry past the live pages is not read. `state_slots`: the decode
     slot each row's recurrent state goes to, for a model that has one
     (a padding row's is past the last slot and dropped)."""
     from generativeaiexamples_tpu.serving.sampling import SamplingParams, sample
@@ -433,37 +487,58 @@ def prefill_batch_step(
                       any_top_k=any_top_k, any_top_p=any_top_p), pool
     N, S = tokens.shape
     ps = pool.page_size
-    npages = S // ps
     KH, Hd = cfg.n_kv_heads, cfg.head_dim
-    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (N, S))
 
-    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)
+    def first_rows(S_k, pool, tokens, table_rows):
+        """The whole program on the prompts' first `S_k` rows: block by
+        block, then the page write, then each prompt's last row. At
+        S_k = S it is the program this always was, op for op."""
+        if S_k < S:
+            tokens = tokens[:, :S_k]
+            table_rows = table_rows[:, :S_k // ps]
+        npages = S_k // ps
+        positions = jnp.broadcast_to(jnp.arange(S_k)[None, :], (N, S_k))
 
-    def body(x, w):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        q, k, v = project_qkv(cfg, h, w, positions)
-        out = attn_ops.attention(q, k, v, causal=True, lengths=lengths,
-                                 use_pallas=use_pallas, mesh=mesh)
-        x = finish_block(cfg, x, out, w)
-        # Encoded INSIDE the scan: for an int8 pool the stacked bf16 k/v
-        # ([L, N, S, KH, Hd] x2 — 2.1 GB at the N=128 deployment shape)
-        # never materializes; the scan emits int8 codes + narrow scales.
-        return x, pool.encode_pages(  # of [N, S, KH, Hd]
-            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        x = params["tok_emb"][tokens].astype(cfg.residual_dtype)
 
-    x, _, kv_out = walk_passes(cfg, params, x, _scanned_pass(params, body))
-    L = cfg.cache_rows
+        def body(x, w):
+            h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
+            q, k, v = project_qkv(cfg, h, w, positions)
+            out = attn_ops.attention(q, k, v, causal=True, lengths=lengths,
+                                     use_pallas=use_pallas, mesh=mesh)
+            x = finish_block(cfg, x, out, w)
+            # Encoded INSIDE the scan: for an int8 pool the stacked bf16
+            # k/v ([L, N, S, KH, Hd] x2 — 2.1 GB at the N=128 deployment
+            # shape) never materializes; the scan emits int8 codes +
+            # narrow scales.
+            return x, pool.encode_pages(  # of [N, S_k, KH, Hd]
+                k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
 
-    def paged(t):  # [L, N, S, KH, ...] -> [L, KH, N*npages, ps, ...]
-        rest = t.shape[4:]
-        t = t.reshape(L, N, npages, ps, KH, *rest)
-        order = (0, 4, 1, 2, 3) + tuple(5 + i for i in range(len(rest)))
-        return t.transpose(*order).reshape(L, KH, N * npages, ps, *rest)
+        x, _, kv_out = walk_passes(cfg, params, x,
+                                   _scanned_pass(params, body))
+        L = cfg.cache_rows
 
-    flat_rows = table_rows.reshape(-1)
-    pool = pool.write_pages(tuple(paged(t) for t in kv_out), flat_rows)
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
+        def paged(t):  # [L, N, S_k, KH, ...] -> [L, KH, N*npages, ps, ...]
+            rest = t.shape[4:]
+            t = t.reshape(L, N, npages, ps, KH, *rest)
+            order = (0, 4, 1, 2, 3) + tuple(5 + i for i in range(len(rest)))
+            return t.transpose(*order).reshape(L, KH, N * npages, ps, *rest)
+
+        flat_rows = table_rows.reshape(-1)
+        pool = pool.write_pages(tuple(paged(t) for t in kv_out), flat_rows)
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].astype(jnp.int32),
+            axis=1)  # [N,1,D]
+        return last, pool
+
+    counts = prefill_row_counts(S, ps, N)
+    if len(counts) == 1:
+        last, pool = first_rows(S, pool, tokens, table_rows)
+    else:
+        last, pool = jax.lax.switch(
+            prefill_live_index(lengths, S, ps),
+            [functools.partial(first_rows, S_k) for S_k in counts],
+            pool, tokens, table_rows)
     logits = _logits(cfg, params, last)[:, 0]  # [N, V]
     toks = sample(logits, sp, key, all_greedy=all_greedy,
                   any_top_k=any_top_k, any_top_p=any_top_p)

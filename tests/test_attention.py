@@ -60,7 +60,12 @@ def test_flash_attention_interpret_matches_reference(causal):
         block_q=32, block_k=32, interpret=True,
     )
     want = attn.mha_reference(q, k, v, causal=causal, lengths=lengths)
-    np.testing.assert_allclose(got, want, atol=2e-5)
+    # every row up to the end of the block that holds a sequence's last
+    # token; a q block wholly past `lengths` is skipped and reads zeros
+    # (nobody reads a padding row: tests/test_prefill_live_rows.py)
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5)
+    np.testing.assert_allclose(got[1, :, :96], want[1, :, :96], atol=2e-5)
+    assert not np.asarray(got[1, :, 96:]).any()
 
 
 def test_decode_attention_matches_prefill_last_row():

@@ -371,3 +371,99 @@ def test_kv_append_kernel_compiles_in_place_for_v5e(topo, chip, name):
         pool_bytes //= mesh.size
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 1000, mem
+
+
+# -- a prefill program computes only its live rows (PR 38) -----------------
+# engine_model.prefill_batch_step with ONE prompt picks, by one lax.switch
+# on its length, the program on tokens[:, :S_k] for the few S_k of its
+# bucket (engine_model.prefill_row_counts). What only the chip's compiler
+# says: that the page pool still goes through the conditional IN PLACE (a
+# copy of a 6 GB pool would not fit), at the two configurations that have
+# the 2,048 bucket; that a group of several is the one program it was; and
+# that the 128 bucket, with its one height, lowers to the parent's text.
+
+
+def _prefill_lowered(chip, config_name, n, bucket):
+    import json
+
+    from benchmark import architectures
+    from benchmark.harness import system
+    from generativeaiexamples_tpu.serving import engine_model as em
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           config_name + ".json")) as fh:
+        config = json.load(fh)
+    ecfg = system.engine_config(config)
+    mcfg, params, pool, _ = architectures.load(config).compile_shapes(
+        config, ecfg, [next(iter(chip.device_set))])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    lowered = em.prefill_batch_step.lower(
+        params, mcfg, pool, arr((n, bucket), I32), arr((n,), I32),
+        arr((n, bucket // ecfg.page_size), I32), arr((n,), F32),
+        arr((n,), F32), arr((n,), I32), arr((2,), jnp.uint32), True,
+        sampling_flags=(True, False, False))
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    return lowered, pool_bytes, ecfg.page_size
+
+
+@pytest.mark.parametrize("config_name,n", [
+    ("mistral-7b-v0.3-int8", 1), ("mistral-7b-v0.3-int8", 4),
+    ("rag-arctic-l-mistral-7b", 1), ("rag-arctic-l-mistral-7b", 4)])
+def test_prefill_2048_switches_between_its_heights_in_place(
+        chip, config_name, n):
+    from generativeaiexamples_tpu.serving import engine_model as em
+
+    lowered, pool_bytes, ps = _prefill_lowered(chip, config_name, n, 2048)
+    heights = em.prefill_row_counts(2048, ps, n)
+    assert heights == ((1024, 1280, 1536, 1792, 2048) if n == 1 else (2048,))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == (n == 1)
+    # every height holds its own flash kernel
+    assert text.count("tpu_custom_call") == len(heights)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # activations only (0.27 GiB for one row of 2,048, 1.1 GiB for four)
+    assert mem.temp_size_in_bytes < pool_bytes // 4, mem.temp_size_in_bytes
+
+
+def _without_kernel_payload(text):
+    """A lowered program's text less the serialized Mosaic kernel of its
+    custom calls (which holds the kernel's source lines)."""
+    import re
+
+    return re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
+
+
+# taken on PR 38's PARENT (31359e9), by this file's own functions
+PARENT_PREFILL_128 = {1: "f6cd2d59cc3fef48", 4: "57275932bba9e5c5"}
+PARENT_FLASH_ONE_BLOCK = "323dd06547d6f8a1"
+
+
+@pytest.mark.parametrize("n", sorted(PARENT_PREFILL_128))
+def test_prefill_128_lowers_to_the_text_the_parent_did(chip, n):
+    import hashlib
+
+    lowered, _, _ = _prefill_lowered(chip, "mistral-7b-v0.3-int8", n, 128)
+    text = _without_kernel_payload(lowered.as_text())
+    assert "stablehlo.case" not in text and "tpu_custom_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENT_PREFILL_128[n]
+
+
+def test_a_one_block_flash_call_is_the_kernel_the_parent_had():
+    """... and the kernel inside it: with one q block and one k block no
+    test on `lengths` is added (the jaxpr holds no source line)."""
+    import hashlib
+
+    q = jax.ShapeDtypeStruct((1, H, 128, HD), BF16)
+    kv = jax.ShapeDtypeStruct((1, KH, 128, HD), BF16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, ln: flash_attention(
+        q, k, v, causal=True, lengths=ln))(
+            q, kv, kv, jax.ShapeDtypeStruct((1,), I32))
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] == \
+        PARENT_FLASH_ONE_BLOCK
