@@ -26,14 +26,52 @@ import numpy as np
 from generativeaiexamples_tpu.models import bert
 from generativeaiexamples_tpu.serving.batcher import (
     MicroBatcher, MicroBatcherClosed, MicroBatchHost)
+from generativeaiexamples_tpu.serving.flight import (
+    PROG_ENCODER, ProgramLedger)
 
 
 # One request's own times, ms by name, for the surface's Server-Timing
 # header (serving/openai_server.py): `tokenize`, `queue` (the wait in
 # the micro-batcher where one is on, else for the engine's lock: another
 # caller's forward) and `ready` (dispatch -> result on the host: device
-# time PLUS the wait behind what the device was already running).
+# time PLUS the wait behind what the device was already running; the
+# program ledger splits the two, below).
 Timing = Optional[Dict[str, float]]
+
+# Rows of an encoder's own ledger while no engine drains it (an encoder
+# served alone): the oldest is dropped, nothing reads them.
+_OWN_LEDGER_ROWS = 256
+
+
+def _enqueue_forward(engine, phase: str, rows: int, tokens: int, S: int,
+                     forward: Callable[[], Any]):
+    """One forward through the ledger's stamp (serving/flight.py): a
+    sequence number and t_enqueue just before the dispatch call. The
+    encoder's threads never write a flight ring; the rows are the
+    hand-off the engine's scheduler drains (`engine.programs`, which an
+    OpenAIServer points at the LLM engine's ledger)."""
+    ledger = engine.programs
+    prog = ledger.enqueue(PROG_ENCODER, rows, tokens,
+                          f"{engine.max_batch}x{S}")
+    try:
+        with jax.profiler.TraceAnnotation(phase, seq=prog.seq):
+            out = forward()
+    except BaseException:
+        ledger.cancel(prog)
+        raise
+    try:
+        out.copy_to_host_async()
+    except AttributeError:
+        pass
+    return prog, out
+
+
+def _fetch_forward(engine, prog, out) -> np.ndarray:
+    """Block for one forward's result; the wait ends on this thread, so
+    its clock reading is the program's t_ready."""
+    host = np.asarray(out)
+    engine.programs.ready(prog)
+    return host
 
 
 def _forward_timed(engine, rows, forward: Callable[[Any, Timing], Any],
@@ -108,6 +146,7 @@ class EmbeddingEngine(MicroBatchHost):
         self.buckets = [min(b, cfg.max_position) for b in buckets]
         self.use_pallas = use_pallas
         self._lock = threading.Lock()
+        self.programs = ProgramLedger(capacity=_OWN_LEDGER_ROWS)
         self._fwd = jax.jit(
             lambda p, t, l: bert.forward(p, cfg, t, lengths=l,
                                          use_pallas=use_pallas)[1])
@@ -199,15 +238,14 @@ class EmbeddingEngine(MicroBatchHost):
                     n = max(1, len(ids[i]))
                     toks[row, : len(ids[i])] = ids[i]
                     lens[row] = n
-                vecs_dev = self._fwd(self.params, jnp.asarray(toks),
-                                     jnp.asarray(lens))
-                try:
-                    vecs_dev.copy_to_host_async()
-                except AttributeError:
-                    pass
-                pending.append((vecs_dev, chunk))
-            for vecs_dev, chunk in pending:
-                vecs = np.asarray(vecs_dev)
+                prog, vecs_dev = _enqueue_forward(
+                    self, "encoder.embed", len(chunk),
+                    int(lens[:len(chunk)].sum()), S,
+                    lambda: self._fwd(self.params, jnp.asarray(toks),
+                                      jnp.asarray(lens)))
+                pending.append((prog, vecs_dev, chunk))
+            for prog, vecs_dev, chunk in pending:
+                vecs = _fetch_forward(self, prog, vecs_dev)
                 for row, i in enumerate(chunk):
                     out[i] = vecs[row]
             _note_forward(timing, t_wait, t_lock)
@@ -231,6 +269,7 @@ class RerankEngine(MicroBatchHost):
         self.max_batch = max_batch
         self.buckets = [min(b, cfg.max_position) for b in buckets]
         self._lock = threading.Lock()
+        self.programs = ProgramLedger(capacity=_OWN_LEDGER_ROWS)
         self._fwd = jax.jit(
             lambda p, t, l, tt: bert.forward(p, cfg, t, lengths=l,
                                              token_types=tt,
@@ -316,15 +355,15 @@ class RerankEngine(MicroBatchHost):
                     toks[row, : len(ids)] = ids
                     lens[row] = max(1, len(ids))
                     types[row, sep: len(ids)] = 1  # segment B = passage
-                scores_dev = self._fwd(self.params, jnp.asarray(toks),
-                                       jnp.asarray(lens), jnp.asarray(types))
-                try:
-                    scores_dev.copy_to_host_async()
-                except AttributeError:
-                    pass
-                pending.append((scores_dev, start, len(chunk)))
-            for scores_dev, start, n in pending:
-                scores = np.asarray(scores_dev)
+                prog, scores_dev = _enqueue_forward(
+                    self, "encoder.rerank", len(chunk),
+                    int(lens[:len(chunk)].sum()), S,
+                    lambda: self._fwd(self.params, jnp.asarray(toks),
+                                      jnp.asarray(lens),
+                                      jnp.asarray(types)))
+                pending.append((prog, scores_dev, start, len(chunk)))
+            for prog, scores_dev, start, n in pending:
+                scores = _fetch_forward(self, prog, scores_dev)
                 out[start: start + n] = scores[:n, 0]
             _note_forward(timing, t_wait, t_lock)
         return out

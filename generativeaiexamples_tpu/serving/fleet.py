@@ -104,6 +104,9 @@ _COUNTER_KEYS = (
     # replicas; the per-lane rings themselves are served by
     # /debug/timeline (one Perfetto lane per local replica).
     "flight_beats", "flight_events",
+    # Programs whose device time passed flight.STALL_FACTOR times the
+    # running median of their class and shape (one WARNING line each).
+    "program_stalls",
     # stop()-path joins that timed out (engine.py stop); the fleet
     # adds its own control-thread stuck joins on top of this sum.
     "stuck_thread_joins",

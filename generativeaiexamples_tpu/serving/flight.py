@@ -13,19 +13,28 @@ scripts/smoke_flight.py and reported as a bench extra). On top of it:
   queue wait per tier, beat gap, promote ms/page) replacing the old
   sliding p50/p95 window: mergeable across a fleet, exportable in
   native Prometheus histogram form, always present in `snapshot()`.
+- `ProgramLedger` — every program the process enqueues on the device
+  (decode block, prefill group, chunk / commit, encoder forward) gets
+  a sequence number and three instants on one clock: enqueue, ready
+  (stamped by the thread on which the wait for its result ends) and
+  start = max(enqueue, the previous program's ready): one device
+  queue runs in order. The scheduler drains it into `program` events.
 - `chrome_trace()` — the recorder rings rendered as Chrome trace-event
   JSON (Perfetto loads it directly): one process lane per replica, one
-  slice per beat (dispatch -> host-ready), request spans correlated to
-  beats via rid, instant markers for the known gap causes (admission
-  retry, qos pause, pager promote/demote, prefill chunks).
+  slice per beat and per prefill / encoder program on the device lane
+  (start -> ready, as the ledger infers it), request spans correlated
+  to beats via rid, instant markers for the known gap causes
+  (admission retry, qos pause, pager promote/demote, prefill chunks).
 - `scripts/analyze_timeline.py` consumes that JSON and splits wall
-  time into device-busy / host-gap / idle with named gap causes — the
-  r04->r05 headline-regression archaeology as one command.
+  time into device-busy (by program class) / host-gap / idle with
+  named gap causes.
 
 Thread model (deliberately lock-free): every `record_*` call happens on
 the engine scheduler thread (submit-time events are recorded
 RETROACTIVELY at admission pop, stamped with `req.submit_time`, so no
-server thread ever writes). Readers (`/metrics`, `/debug/timeline`)
+server thread ever writes; an encoder's threads and the engine's
+completion waiters stamp `ProgramLedger` rows, which the scheduler
+drains into the ring the same way). Readers (`/metrics`, `/debug/timeline`)
 copy the rings without a lock; each row carries a double sequence
 stamp (`seq` written first, `seq2` last) and snapshot() drops rows
 whose stamps disagree or fall outside the live window — a torn row is
@@ -37,7 +46,11 @@ from __future__ import annotations
 
 import bisect
 import math
-from typing import Any, Dict, List, Optional, Tuple
+import statistics
+import threading
+import time
+from collections import deque
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -77,6 +90,30 @@ EV_KV_TRANSFER = 18
 # held here, per step and expert layer; b = the pairs of the busiest
 # held expert of any one layer over the mean (1.0 = even load).
 EV_MOE_LOAD = 19
+# One a program the device finished (`ProgramLedger`, below), written
+# by the scheduler thread when the completion is known. ts = t_ready;
+# code = class (PROGRAM_CLASSES); a = t_ready - t_enqueue ms (what the
+# host waited); b = t_ready - t_start ms (what the device ran); slot =
+# rows that were live (decode: slots; prefill: prompts; encoder:
+# texts); aux = "seq=<n> n=<steps or real tokens> shape=<shape>".
+EV_PROGRAM = 20
+# The first decode block in which a request's slot is live was
+# enqueued: ts = that block's t_enqueue, b = its sequence number.
+EV_DECODE_JOIN = 21
+
+# Program classes (EV_PROGRAM.code).
+PROG_DECODE = 0    # a decode block (n = steps K)
+PROG_PREFILL = 1   # a bucketed prefill group (n = real prompt tokens)
+PROG_CHUNK = 2     # a prefill chunk (n = real tokens) or a commit (n = 0)
+PROG_ENCODER = 3   # an encoder forward (n = real tokens)
+PROGRAM_CLASSES = ("decode", "prefill", "chunk", "encoder")
+
+# A program whose device time exceeds this many times the running
+# median of its class and shape is a stall: counted (`program_stalls`)
+# and logged once by the engine.
+STALL_FACTOR = 8.0
+STALL_MEDIAN_WINDOW = 32   # the median's last samples, a class and shape
+STALL_MIN_SAMPLES = 4      # fewer than these say nothing yet
 
 EVENT_NAMES = {
     EV_SUBMIT: "submit", EV_QOS_PICK: "qos_pick", EV_ADMIT: "admit",
@@ -88,7 +125,8 @@ EVENT_NAMES = {
     EV_SCALE_UP: "scale_up", EV_SCALE_DOWN: "scale_down",
     EV_SCALE_WAKE: "scale_wake", EV_UPGRADE: "upgrade",
     EV_CHAOS: "chaos", EV_KV_TRANSFER: "kv_transfer",
-    EV_MOE_LOAD: "moe_load",
+    EV_MOE_LOAD: "moe_load", EV_PROGRAM: "program",
+    EV_DECODE_JOIN: "decode_join",
 }
 
 # Retire reason codes (EV_RETIRE.code); anything unknown maps to -1.
@@ -114,11 +152,16 @@ BEAT_DTYPE = np.dtype([
     # per-record seqlock).
     ("seq", "<i8"),
     ("t_dispatch", "<f8"),    # perf_counter when the block's dispatch returned
-    ("t_ready", "<f8"),       # when its results reached the host
-    ("t_prev_ready", "<f8"),  # previous beat's t_ready (0 on the first)
+    # The block's ledger stamps: when its result was complete (the
+    # clock of the thread that waited on it) and the t_ready of the
+    # PROGRAM enqueued just before it, a prefill or an encoder forward
+    # too (0 on the first). max(t_dispatch, t_prev_ready) -> t_ready is what
+    # the device ran for this block and nothing else.
+    ("t_ready", "<f8"),
+    ("t_prev_ready", "<f8"),
     # StepPlan lattice point of the landed dispatch.
     ("decode_k", "<i2"), ("spec_k", "<i2"), ("tree_branches", "<i2"),
-    ("rider_width", "<i4"), ("rider_s_total", "<i4"),
+    ("rider_width", "<i4"),
     ("spec_state", "?"), ("fused_rider", "?"), ("qos_paused", "?"),
     # Busy slots and waiting-queue depth per QoS tier at landing.
     ("busy_latency", "<i2"), ("busy_standard", "<i2"), ("busy_batch", "<i2"),
@@ -149,6 +192,9 @@ HIST_KEYS = (
     "hist_queue_wait_ms_batch",
     "hist_beat_gap_ms", "hist_kv_promote_ms_per_page",
     "hist_kv_transfer_ms_per_page",
+    # From the program ledger, a prefill group's t_start - t_enqueue
+    # (what the blocks in flight cost a new arrival) and its device time.
+    "hist_device_queue_ms", "hist_program_ms_prefill",
 )
 
 
@@ -313,7 +359,7 @@ class FlightRecorder:
     def record_beat(self, t_dispatch: float, t_ready: float,
                     t_prev_ready: float, decode_k: int, spec_k: int,
                     tree_branches: int, rider_width: int,
-                    rider_s_total: int, spec_state: bool,
+                    spec_state: bool,
                     fused_rider: bool, qos_paused: bool,
                     busy: Tuple[int, int, int],
                     wait: Tuple[int, int, int], tokens_emitted: int,
@@ -330,7 +376,6 @@ class FlightRecorder:
         row["spec_k"] = spec_k
         row["tree_branches"] = tree_branches
         row["rider_width"] = rider_width
-        row["rider_s_total"] = rider_s_total
         row["spec_state"] = spec_state
         row["fused_rider"] = fused_rider
         row["qos_paused"] = qos_paused
@@ -419,10 +464,172 @@ class FlightRecorder:
 
 
 # ---------------------------------------------------------------------------
+# The program ledger
+# ---------------------------------------------------------------------------
+
+
+class Program:
+    """One program enqueued on the device. `seq` and `t_enqueue` are
+    written by `ProgramLedger.enqueue`, `t_ready` by whichever thread
+    the wait for its result ends on, the rest by `drain`."""
+
+    __slots__ = ("seq", "cls", "rows", "n", "shape", "t_enqueue", "t_ready",
+                 "t_start", "t_prev_ready", "cancelled", "stalled")
+
+    def __init__(self, seq: int, cls: int, rows: int, n: int, shape: str,
+                 t_enqueue: float):
+        self.seq = seq
+        self.cls = cls
+        self.rows = rows
+        self.n = n
+        self.shape = shape
+        self.t_enqueue = t_enqueue
+        self.t_ready = 0.0
+        self.t_start = 0.0
+        self.t_prev_ready = 0.0
+        self.cancelled = False
+        self.stalled = False
+
+    @property
+    def waited_ms(self) -> float:
+        """enqueue -> ready: what the host waited (the event's `a`)."""
+        return (self.t_ready - self.t_enqueue) * 1e3
+
+    @property
+    def ran_ms(self) -> float:
+        """start -> ready: what the device ran (the event's `b`)."""
+        return (self.t_ready - self.t_start) * 1e3
+
+    @property
+    def queued_ms(self) -> float:
+        """enqueue -> start: the wait behind the programs before it."""
+        return (self.t_start - self.t_enqueue) * 1e3
+
+    def aux(self) -> str:
+        return f"seq={self.seq} n={self.n} shape={self.shape}"
+
+
+def parse_program_aux(aux: str) -> Dict[str, str]:
+    """`seq=12 n=8 shape=K8` -> {"seq": "12", "n": "8", "shape": "K8"}."""
+    return dict(kv.split("=", 1) for kv in aux.split() if "=" in kv)
+
+
+class ProgramLedger:
+    """Every program one process enqueues on one device, in enqueue
+    order. ANY thread stamps: `enqueue` just before its dispatch call
+    (the sequence number and `t_enqueue` under one short lock, so both
+    run in the same order), `ready` where its wait for the result ends.
+    ONE thread, the engine's scheduler, calls `drain`, which hands back
+    the programs whose start can be inferred, oldest first:
+
+        t_start = max(t_enqueue, t_ready of the program enqueued before)
+
+    because one device queue runs in order. The open rows are the
+    hand-off between the stamping threads and the flight ring's single
+    writer; they are bounded, and with nobody draining (an encoder
+    served with no engine beside it) the oldest row is dropped.
+
+    Two stamps can be a little out of the device's order: a thread
+    that waits on another clock tick, or an encoder thread and the
+    scheduler racing between stamp and dispatch. A start is therefore
+    clamped to its own program's ready (a device time is never
+    negative) and the "previous ready" only moves forward."""
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter,
+                 capacity: int = 1024):
+        self._clock = clock
+        self._capacity = max(2, int(capacity))
+        self._lock = threading.Lock()
+        self._open: Deque[Program] = deque()
+        self._n = 0
+        self._prev_ready = 0.0
+        self.dropped = 0
+        # (class, shape) -> the last device times, for the stall rule.
+        self._recent: Dict[Tuple[int, str], Deque[float]] = {}
+
+    # -- stamps (any thread) -----------------------------------------------
+
+    # graftlint: hot-path
+    def enqueue(self, cls: int, rows: int = 0, n: int = 0,
+                shape: str = "") -> Program:
+        with self._lock:
+            prog = Program(self._n, cls, rows, n, shape, self._clock())
+            self._n += 1
+            self._open.append(prog)
+            if len(self._open) > self._capacity:
+                self._open.popleft()
+                self.dropped += 1
+        return prog
+
+    # graftlint: hot-path
+    def ready(self, prog: Program, t: Optional[float] = None) -> None:
+        """The program's result is complete; the first stamp stands."""
+        if not prog.t_ready:
+            prog.t_ready = t if t else self._clock()
+
+    def cancel(self, prog: Program) -> None:
+        """Its dispatch raised: nothing was enqueued."""
+        prog.cancelled = True
+
+    @property
+    def enqueued(self) -> int:
+        return self._n
+
+    # -- the drain (the scheduler thread only) ------------------------------
+
+    # graftlint: hot-path
+    def drain(self, proved: int = -1) -> List[Program]:
+        """The programs at the head of the queue whose completion is
+        known, resolved. `proved` is the sequence number of a program
+        the caller has SEEN complete (a landed block): the in-order
+        queue then proves every earlier program complete as well, and
+        one of those that nobody stamped takes the ready of the next
+        stamped program (an upper bound: a waiter that has not run
+        yet, or a driver with no waiter thread)."""
+        out: List[Program] = []
+        if not self._open:      # the usual poll: nothing to take a lock for
+            return out
+        with self._lock:
+            while self._open:
+                head = self._open[0]
+                if not (head.t_ready or head.cancelled
+                        or head.seq <= proved):
+                    break
+                if not head.t_ready and not head.cancelled:
+                    nxt = next((p.t_ready for p in self._open
+                                if p.t_ready and p.seq <= proved), 0.0)
+                    if not nxt:
+                        break
+                    head.t_ready = nxt
+                self._open.popleft()
+                if not head.cancelled:
+                    out.append(head)
+        for prog in out:
+            self._resolve(prog)
+        return out
+
+    def _resolve(self, prog: Program) -> None:
+        prev = self._prev_ready
+        prog.t_prev_ready = prev
+        prog.t_start = min(max(prog.t_enqueue, prev), prog.t_ready)
+        self._prev_ready = max(prev, prog.t_ready)
+        recent = self._recent.get((prog.cls, prog.shape))
+        if recent is None:
+            recent = self._recent[(prog.cls, prog.shape)] = deque(
+                maxlen=STALL_MEDIAN_WINDOW)
+        ran = prog.ran_ms
+        if len(recent) >= STALL_MIN_SAMPLES \
+                and ran > STALL_FACTOR * statistics.median(recent):
+            prog.stalled = True
+        recent.append(ran)
+
+
+# ---------------------------------------------------------------------------
 # Chrome trace-event export (Perfetto-loadable)
 # ---------------------------------------------------------------------------
 
-# tid layout inside each replica lane: 0 = beat slices, 1 = scheduler
+# tid layout inside each replica lane: 0 = the device lane (a slice a
+# beat, and one a prefill, chunk or encoder program), 1 = scheduler
 # instants (gap causes), 16 + slot = request spans (a slot serves one
 # request at a time, so spans on one tid never overlap).
 TID_BEATS = 0
@@ -456,11 +663,13 @@ def _beat_events(pid: int, beats: np.ndarray,
         prev = float(b["t_prev_ready"])
         prev = prev - base if prev else 0.0
         host_gap_ms = max(0.0, (t_d - prev) * 1e3) if prev else 0.0
-        # Slice = the VISIBLE device interval: pipelined dispatches
-        # overlap the previous block's readback, so the slice starts
-        # at max(dispatch, previous ready) — lanes stay non-
-        # overlapping (Perfetto-clean) and the union still equals
-        # device-busy time. The raw dispatch stamp rides in args.
+        # Slice = what the device ran for THIS block: the queue runs
+        # in order, so it starts at max(dispatch, the previous
+        # PROGRAM's ready) — a prefill or an encoder forward enqueued
+        # before the block has its own slice (_program_events) and is
+        # not charged here. Lanes stay non-overlapping (Perfetto-
+        # clean); device-busy time is the union of beat AND program
+        # slices. The raw dispatch stamp rides in args.
         t_vis = max(t_d, prev)
         # Round the ENDPOINTS and subtract (rounding ts and dur
         # independently would let adjacent slices overlap by one
@@ -490,6 +699,35 @@ def _beat_events(pid: int, beats: np.ndarray,
                 "kv_demote_pages": int(b["kv_demote_pages"]),
                 "kv_promote_pages": int(b["kv_promote_pages"]),
             },
+        })
+    return out
+
+
+def _program_start(ev: Dict[str, Any]) -> float:
+    """A `program` event's inferred device start, on the event's clock."""
+    return ev["ts"] - ev["b"] / 1e3
+
+
+def _program_events(pid: int, events: List[Dict[str, Any]],
+                    base: float) -> List[Dict[str, Any]]:
+    """The prefill, chunk and encoder programs as slices on the device
+    lane, start -> ready (a decode block's slice is its beat's)."""
+    out: List[Dict[str, Any]] = []
+    for ev in events:
+        if ev["kind"] != EV_PROGRAM or ev["code"] == PROG_DECODE:
+            continue
+        cls = PROGRAM_CLASSES[ev["code"]] \
+            if 0 <= ev["code"] < len(PROGRAM_CLASSES) else str(ev["code"])
+        aux = parse_program_aux(ev["aux"])
+        ts_us = round((_program_start(ev) - base) * 1e6, 1)
+        end_us = round((ev["ts"] - base) * 1e6, 1)
+        out.append({
+            "name": f"{cls} {aux.get('shape', '')}".strip(),
+            "cat": "program", "ph": "X", "pid": pid, "tid": TID_BEATS,
+            "ts": ts_us, "dur": max(0.0, round(end_us - ts_us, 1)),
+            "args": {"class": cls, "seq": int(aux.get("seq", -1)),
+                     "n": int(aux.get("n", 0)), "rows": ev["slot"],
+                     "waited_ms": round(ev["a"], 3)},
         })
     return out
 
@@ -601,17 +839,19 @@ def chrome_trace(recorders: Dict[str, FlightRecorder]) -> Dict[str, Any]:
     # earliest timestamp (a first-entry base would go negative).
     stamps = [float(b["t_dispatch"]) for bs, _ in snaps.values()
               for b in bs]
-    stamps += [ev["ts"] for _, evs in snaps.values() for ev in evs]
+    stamps += [_program_start(ev) if ev["kind"] == EV_PROGRAM else ev["ts"]
+               for _, evs in snaps.values() for ev in evs]
     base = min(stamps) if stamps else 0.0
     for pid, name in enumerate(sorted(snaps)):
         beats, evs = snaps[name]
         events.append({"ph": "M", "name": "process_name", "pid": pid,
                        "tid": 0, "args": {"name": f"replica {name}"}})
-        for tid, tname in ((TID_BEATS, "scheduler beats"),
+        for tid, tname in ((TID_BEATS, "device programs"),
                            (TID_SCHED, "scheduler events")):
             events.append({"ph": "M", "name": "thread_name", "pid": pid,
                            "tid": tid, "args": {"name": tname}})
         events.extend(_beat_events(pid, beats, base))
+        events.extend(_program_events(pid, evs, base))
         events.extend(_request_events(pid, evs, base))
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 

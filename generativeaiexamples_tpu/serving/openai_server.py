@@ -97,6 +97,15 @@ class OpenAIServer:
         self.llm = llm_engine
         self.embed = embed_engine
         self.rerank = rerank_engine
+        # One ledger of every program the process puts on the device
+        # (serving/flight.py::ProgramLedger): the encoders behind this
+        # surface stamp the LLM engine's, whose scheduler drains their
+        # rows into its flight ring. A fleet has no single ledger and
+        # an encoder served alone keeps its own.
+        ledger = getattr(llm_engine, "programs", None)
+        for enc in (embed_engine, rerank_engine):
+            if ledger is not None and hasattr(enc, "programs"):
+                enc.programs = ledger
         self.model_name = model_name
         self.embed_model_name = embed_model_name
         scfg = serving_cfg or ServingConfig()

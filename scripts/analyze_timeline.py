@@ -4,10 +4,17 @@ into device-busy / host-gap / idle and NAME the top gap causes.
 Input is the Chrome trace-event JSON served at `/debug/timeline` (or
 dumped by bench/smoke under build/). Attribution per replica lane:
 
-- **device_busy** — the union of beat slices (dispatch -> host-ready:
-  device queue + compute + readback for the oldest in-flight block).
-  Pipelined dispatches overlap, so the interval UNION is the honest
-  device-side claim.
+- **device_busy** — the union of PROGRAM slices on the device lane: a
+  beat's slice (a decode block) and the slice of every prefill group,
+  chunk / commit and encoder forward, each from its inferred device
+  start (the later of its enqueue and the completion of the program
+  enqueued before it: one queue runs in order) to its completion, as
+  the engine's program ledger stamps them (`serving/flight.py`). A
+  beat's slice is therefore the block's own device time: the prefill
+  that ran before it is not charged to it. `device_busy_by_class`
+  splits the total into decode / prefill / chunk / encoder. What the
+  slices cannot show: time the device spent on a program of ANOTHER
+  process, and a completion stamped up to a thread switch late.
 - gaps between busy intervals are charged to the FIRST known cause
   whose marker falls inside the gap (priority order): **qos_pause**
   (a latency-tier TTFT phase paused lower-tier prefills),
@@ -66,17 +73,37 @@ def _merge_intervals(iv: List[Tuple[float, float]]
     return out
 
 
+def busy_by_class(beats: List[Dict[str, Any]],
+                  programs: List[Dict[str, Any]],
+                  span: Tuple[float, float]) -> Dict[str, float]:
+    """Program class -> microseconds of its slices inside the span (the
+    slices of one device lane do not overlap, so the classes add up to
+    device_busy)."""
+    t0, t1 = span
+    out: Dict[str, float] = {}
+    for ev in list(beats) + list(programs):
+        cls = "decode" if ev.get("cat") == "beat" \
+            else ev.get("args", {}).get("class", "program")
+        lo, hi = max(ev["ts"], t0), min(ev["ts"] + ev.get("dur", 0.0), t1)
+        if hi > lo:
+            out[cls] = out.get(cls, 0.0) + hi - lo
+    return out
+
+
 def attribute_lane(beats: List[Dict[str, Any]],
                    instants: List[Dict[str, Any]],
                    span: Tuple[float, float],
-                   host_gap_us: float) -> Dict[str, float]:
+                   host_gap_us: float,
+                   programs: Tuple[Dict[str, Any], ...] = ()
+                   ) -> Dict[str, float]:
     """Category -> microseconds over one lane's [t0, t1] span."""
     out = {c: 0.0 for c in CATEGORIES}
     t0, t1 = span
     if t1 <= t0:
         return out
     busy = _merge_intervals(
-        [(b["ts"], b["ts"] + b.get("dur", 0.0)) for b in beats])
+        [(b["ts"], b["ts"] + b.get("dur", 0.0))
+         for b in list(beats) + list(programs)])
     busy = [(max(lo, t0), min(hi, t1)) for lo, hi in busy
             if hi > t0 and lo < t1]
     out["device_busy"] = sum(hi - lo for lo, hi in busy)
@@ -126,33 +153,42 @@ def analyze(trace: Dict[str, Any], host_gap_ms: float = 50.0,
         pid = int(ev.get("pid", 0))
         if lane is not None and pid != lane:
             continue
-        d = by_pid.setdefault(pid, {"beats": [], "instants": [],
-                                    "all_ts": []})
+        d = by_pid.setdefault(pid, {"beats": [], "programs": [],
+                                    "instants": [], "all_ts": []})
         ts = float(ev.get("ts", 0.0))
         end = ts + float(ev.get("dur", 0.0) or 0.0)
         d["all_ts"] += [ts, end]
         if ev.get("cat") == "beat" and ev.get("ph") == "X":
             d["beats"].append(ev)
+        elif ev.get("cat") == "program" and ev.get("ph") == "X":
+            d["programs"].append(ev)
         elif ev.get("cat") == "gap-cause" and ev.get("ph") == "i":
             d["instants"].append(ev)
     lanes: Dict[str, Any] = {}
     total = {c: 0.0 for c in CATEGORIES}
+    by_class: Dict[str, float] = {}
     wall_us = 0.0
     for pid, d in sorted(by_pid.items()):
         if not d["all_ts"]:
             continue
         span = (min(d["all_ts"]), max(d["all_ts"]))
         cats = attribute_lane(d["beats"], d["instants"], span,
-                              host_gap_ms * 1e3)
+                              host_gap_ms * 1e3, tuple(d["programs"]))
+        classes = busy_by_class(d["beats"], d["programs"], span)
         lane_wall = span[1] - span[0]
         lanes[str(pid)] = {
             "wall_ms": round(lane_wall / 1e3, 3),
             "beats": len(d["beats"]),
+            "programs": len(d["programs"]),
             "categories": {c: round(v / 1e3, 3)
                            for c, v in cats.items() if v > 0},
+            "device_busy_by_class": {c: round(v / 1e3, 3)
+                                     for c, v in classes.items()},
         }
         for c, v in cats.items():
             total[c] += v
+        for c, v in classes.items():
+            by_class[c] = by_class.get(c, 0.0) + v
         wall_us += lane_wall
     cats_out = {}
     for c in CATEGORIES:
@@ -170,6 +206,10 @@ def analyze(trace: Dict[str, Any], host_gap_ms: float = 50.0,
         "overall": {
             "wall_ms": round(wall_us / 1e3, 3),
             "categories": cats_out,
+            "device_busy_by_class": {
+                c: {"ms": round(v / 1e3, 3),
+                    "pct": round(100.0 * v / wall_us, 2) if wall_us else 0.0}
+                for c, v in sorted(by_class.items())},
             # Partition of [first, last] by construction — ~100 up to
             # rounding; the smoke gate pins >= 95.
             "attributed_pct": round(attributed, 2),
@@ -201,6 +241,8 @@ def main() -> int:
     print(f"{'category':<18}{'ms':>12}{'pct':>8}")
     for c, v in sorted(ov["categories"].items(), key=lambda kv: -kv[1]["ms"]):
         print(f"{c:<18}{v['ms']:>12.1f}{v['pct']:>7.1f}%")
+    for c, v in ov["device_busy_by_class"].items():
+        print(f"  device_busy.{c:<11}{v['ms']:>10.1f}{v['pct']:>7.1f}%")
     if ov["top_causes"]:
         print("top gap causes: " + ", ".join(ov["top_causes"]))
     return 0

@@ -140,7 +140,7 @@ class TestExpHistogram:
 def _beat_kwargs(i: float):
     return dict(t_dispatch=i, t_ready=i + 0.5, t_prev_ready=i - 0.5,
                 decode_k=2, spec_k=0, tree_branches=0, rider_width=0,
-                rider_s_total=0, spec_state=False, fused_rider=False,
+                spec_state=False, fused_rider=False,
                 qos_paused=False, busy=(0, 1, 0), wait=(0, 0, 0),
                 tokens_emitted=3, kv_demote_pages=0, kv_promote_pages=0)
 
@@ -247,7 +247,7 @@ def _synthetic_recorder():
         rec.record_beat(t_dispatch=lo, t_ready=lo + 0.08,
                         t_prev_ready=lo - 0.02 if i else 0.0,
                         decode_k=2, spec_k=0, tree_branches=0,
-                        rider_width=0, rider_s_total=0, spec_state=False,
+                        rider_width=0, spec_state=False,
                         fused_rider=False, qos_paused=False,
                         busy=(0, 1, 0), wait=(0, 0, 0), tokens_emitted=2,
                         kv_demote_pages=0, kv_promote_pages=0)

@@ -262,6 +262,33 @@ class TestTailChunkBucketing:
         assert eng._chunk_buf(8) is not first
         assert set(eng._chunk_staging) == {8, 16}
 
+    def test_the_device_never_sees_the_staging_buffer_itself(self):
+        """jnp.asarray aliases a 64-byte-aligned numpy buffer on the
+        CPU, and a chunk's program reads its input after the dispatch
+        returned: what goes to the device must not be the buffer that
+        _chunk_buf refills for the next chunk."""
+        eng = _engine()
+        seen = []
+        put = eng._put
+
+        def spy(x):
+            seen.append(x)
+            return put(x)
+
+        eng._put = spy
+        req = GenRequest(prompt_ids=[(i * 7) % TINY.vocab_size
+                                     for i in range(40)], max_new_tokens=2)
+        eng.submit(req)
+        for _ in range(6):
+            _step(eng)
+        staged = list(eng._chunk_staging.values())
+        chunks = [x for x in seen if isinstance(x, np.ndarray)
+                  and x.ndim == 2 and x.shape[0] == 1
+                  and x.dtype == np.int32]
+        assert staged and chunks
+        for x in chunks:
+            assert not any(np.shares_memory(x, buf) for buf in staged)
+
     def test_pick_chunk_width_respects_warmed_set(self):
         eng = _engine()
         # No warmup: plain power-of-two >= n, capped at the chunk.
