@@ -330,13 +330,19 @@ class EngineMetrics:
         self.decode_steps_kernel_append = 0
         # Over the same steps (their attention is then
         # serving/paged_attention_int8.py), summed over the B rows a
-        # step: the pages the rows HAVE, which is what the kernel copies
-        # and multiplies, and what whole blocks over them would cover,
-        # which is what it walked before it stopped at a row's last
-        # page (paged_attention_int8.page_counts). live / walked is the
+        # step: the pages the LIVE rows have, which is what the kernel
+        # copies and multiplies, and what whole blocks over every row
+        # would cover, which is what it walked before it stopped at a
+        # row's last page and at the live rows
+        # (paged_attention_int8.page_counts). live / walked is the
         # share of page copies that remain.
         self.decode_attn_pages_live = 0
         self.decode_attn_pages_walked = 0
+        # Over the same steps again: the idle rows that both int8 pool
+        # kernels left out, (B - live slots) a step of a program that
+        # hands them its `active` mask (decode_multi_step): how often
+        # walking the live slots alone engages.
+        self.decode_attn_rows_skipped = 0
         # Over the batched prefill programs dispatched, summed over the
         # N rows of every group: the rows the program computed (a
         # Llama's single prompt rounded up to one of
@@ -527,6 +533,7 @@ class EngineMetrics:
             "decode_steps_kernel_append": self.decode_steps_kernel_append,
             "decode_attn_pages_live": self.decode_attn_pages_live,
             "decode_attn_pages_walked": self.decode_attn_pages_walked,
+            "decode_attn_rows_skipped": self.decode_attn_rows_skipped,
             "prefill_rows_live": self.prefill_rows_live,
             "prefill_rows_bucket": self.prefill_rows_bucket,
             "kv_cache_rows": self.kv_cache_rows,
@@ -3520,12 +3527,19 @@ class LLMEngine:
         if not plan.spec_k and kernel_append(self.pool, self.use_pallas):
             self.metrics.decode_steps_kernel_append += K
             # ... and attends through paged_attention_int8: a live
-            # row is one token longer every step of the block
+            # row is one token longer every step of the block, and
+            # decode_multi_step's kernels walk the live rows alone (the
+            # fused and the spec-state lanes hand them no mask)
+            masked = engine_model.masks_pool_kernels(plan)
             live_pages, walked = page_counts(
                 lengths + np.arange(K)[:, None] * active_mask,
-                self.pool.page_size, self.max_pages)
+                self.pool.page_size, self.max_pages,
+                mask=active_mask if masked else None)
             self.metrics.decode_attn_pages_live += live_pages
             self.metrics.decode_attn_pages_walked += walked
+            if masked:
+                self.metrics.decode_attn_rows_skipped += (
+                    B - len(active)) * K
         self.metrics.busy_slots_acc += len(active) * K
         if spec_mode:
             for i in active:

@@ -35,7 +35,8 @@ from generativeaiexamples_tpu.models.llama import (
 from generativeaiexamples_tpu.ops import attention as attn_ops
 from generativeaiexamples_tpu.ops.quant import mm
 from generativeaiexamples_tpu.serving import ssm_state_update as ssm_update
-from generativeaiexamples_tpu.serving.kv_cache import PagePool, token_slots
+from generativeaiexamples_tpu.serving.kv_cache import (
+    PagePool, kernel_live_rows, token_slots)
 from generativeaiexamples_tpu.serving.paged_attention import (
     paged_attention_dispatch)
 from generativeaiexamples_tpu.utils.platform import log_kernel_declined
@@ -259,15 +260,17 @@ def _hybrid_decode_once(params, cfg, pool, tokens, page_tables, lengths,
     the pool (the convolution's tail here, the state in place through
     serving/ssm_state_update.py), an attention block appends K and V and
     attends through the paged kernel. `mask` [B]: the live slots; an idle
-    slot's state, tail and expert pairs are left alone. Returns (logits
-    [B, V], pool, pairs each expert took in each block [L, E], the
-    router's choices [L, B, k])."""
+    slot's state, tail and expert pairs are left alone, and where the
+    int8 pool's kernels are on they walk the live slots only. Returns
+    (logits [B, V], pool, pairs each expert took in each block [L, E],
+    the router's choices [L, B, k])."""
     B = tokens.shape[0]
     ps = pool.page_size
     pages, state, tail = pool.pages, pool.state, pool.tail
     slots = token_slots(
         cfg.n_kv_heads, page_tables[jnp.arange(B), (lengths - 1) // ps],
-        (lengths - 1) % ps, use_pallas)
+        (lengths - 1) % ps, use_pallas,
+        live=kernel_live_rows(pages, mask, use_pallas))
     x = hybrid_ssm.embed(cfg, params, tokens)[:, None]  # [B, 1, D]
     sliced, experts = hybrid_ssm.split_experts(params["ffn"])
     counts, choices = [], []
@@ -298,7 +301,7 @@ def _hybrid_decode_once(params, cfg, pool, tokens, page_tables, lengths,
             out = paged_attention_dispatch(
                 q[:, :, 0], k_pages, v_pages, page_tables, lengths,
                 scale=cfg.attention_multiplier, k_scales=k_scales,
-                layer=layer, use_pallas=use_pallas)
+                layer=layer, use_pallas=use_pallas, live=slots.live)
             x = hybrid_ssm.attn_out(cfg, x, out[:, :, None, :], w)
         x, n, idx = hybrid_ssm.feed_forward(
             cfg, x, hybrid_ssm.take_layer(sliced, l), experts, l, use_pallas,
@@ -615,25 +618,32 @@ def _decode_rows(params, cfg: LlamaConfig, pool: PagePool, tokens, positions,
 
 
 def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
-                 lengths, use_pallas, mesh=None, direct=False):
+                 lengths, use_pallas, mesh=None, direct=False, active=None):
     """One decode iteration: the current token's k/v goes to
     (page_table[len // ps], len % ps), and paged attention runs over
     the updated pool with `lengths` INCLUDING the current token.
+    `active` [B]: the live slots (None: every one). Where the int8
+    pool's kernels are on, the append and the attention walk those
+    alone: an idle slot writes nothing, attends to nothing, and its
+    logits are what zeros attended give (its token is discarded).
     Returns (logits [B, V], updated pool)."""
     B = tokens.shape[0]
     ps = pool.page_size
     positions = (lengths - 1)[:, None]  # [B, 1]
     page_idx = page_tables[jnp.arange(B), (lengths - 1) // ps]  # [B]
     offset = (lengths - 1) % ps  # [B]
-    # one row a slot: where kernels are on, the pool's append is one too
-    slots = token_slots(cfg.n_kv_heads, page_idx, offset, use_pallas, mesh)
+    # one row a slot: where kernels are on, the pool's append is one too;
+    # and the live list, once a step: every layer's append and attention
+    # walk `slots.live`
+    slots = token_slots(cfg.n_kv_heads, page_idx, offset, use_pallas, mesh,
+                        kernel_live_rows(pool, active, use_pallas))
 
     def attend(q, pool, row):
         q = q[:, :, 0, :]
         k_pages, v_pages, k_scales, layer = pool.attention_operands(row)
         out = paged_attention_dispatch(
             q, k_pages, v_pages, page_tables, lengths, k_scales=k_scales,
-            layer=layer, use_pallas=use_pallas, mesh=mesh)
+            layer=layer, use_pallas=use_pallas, mesh=mesh, live=slots.live)
         return out[:, :, None, :]
 
     logits, pool = _decode_rows(params, cfg, pool, tokens[:, None], positions,
@@ -706,7 +716,7 @@ def decode_multi_step(
         else:
             logits, pool = _decode_once(
                 params, cfg, pool, tokens, page_tables, lengths, use_pallas,
-                mesh, direct)
+                mesh, direct, active)
         rng, key = jax.random.split(rng)
         nxt = sample(logits, sp, key, all_greedy=all_greedy,
                      any_top_k=any_top_k, any_top_p=any_top_p)
@@ -1521,6 +1531,19 @@ class StepPlan(NamedTuple):
     rider_s_total: int = 0
     spec_state: bool = False
     rider_sample: bool = False
+
+
+def masks_pool_kernels(plan: StepPlan) -> bool:
+    """Whether the program `_plan_step` lowers `plan` to hands its
+    `active` mask to the int8 pool's two kernels (`_decode_once(active=)`,
+    the hybrid body's `mask`), which then walk the live slots alone:
+    decode_multi_step does, and no other. The fused rider lane, the
+    spec-state lane and the verifies walk every slot, as they did. The
+    engine's `decode_attn_rows_skipped` reads this, so a lane that starts
+    to pass its mask changes it here
+    (tests/test_kv_append_kernel.py holds the two together)."""
+    return bool(plan.decode_k) and not (
+        plan.spec_k or plan.spec_state or plan.rider_width)
 
 
 def plan_to_record(plan: StepPlan) -> dict:

@@ -15,7 +15,9 @@ inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
   wants, and the TP sharding axis is a leading dim). Page 0 is a
   reserved garbage sink — padding positions in bucketed prefills and
   unused page-table slots point at it, so scatter/gather never needs
-  dynamic shapes.
+  dynamic shapes. Nothing reads it: XLA's scatters leave an idle decode
+  slot's row there, and the int8 kernels, which walk the live slots
+  alone, neither write nor read it in a decode step.
 - A model with recurrent state (cfg.recurrent_state: state-space layers
   beside its attention layers) gets a HybridPool: the page pool of its
   attention layers' rows (`pages`, any of the pools above) and, beside
@@ -33,8 +35,11 @@ inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
   (serving/kv_append_int8.py; `kernel_append` decides, from the pool,
   the slots' rank and the step program's `use_pallas`), and through
   XLA's scatters everywhere else: off the chip, a bf16 pool, a verify's
-  r rows a slot. Both forms write the same bytes
-  (QuantPagePool.append; tests/test_kv_append_kernel.py).
+  r rows a slot. Both forms write the same bytes outside the sink page
+  (QuantPagePool.append; tests/test_kv_append_kernel.py). Where the
+  kernels are on, the step's `active` mask reaches them as
+  `TokenSlots.live` (`kernel_live_rows`, once a step): the append and the
+  attention kernel walk the live slots and no other.
 
 Sized so `bytes = R * P * page_size * KH * Hd * 2 dtypes * itemsize`;
 `PagePool.for_budget` picks P from an HBM byte budget.
@@ -43,7 +48,7 @@ Sized so `bytes = R * P * page_size * KH * Hd * 2 dtypes * itemsize`;
 from __future__ import annotations
 
 import dataclasses
-from typing import List, NamedTuple, Optional, Sequence
+from typing import TYPE_CHECKING, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +56,9 @@ import numpy as np
 
 from generativeaiexamples_tpu.models.llama import LlamaConfig
 from generativeaiexamples_tpu.utils.platform import log_kernel_declined
+
+if TYPE_CHECKING:
+    from generativeaiexamples_tpu.serving.paged_attention_int8 import LiveRows
 
 
 class PoolGeometry(NamedTuple):
@@ -74,22 +82,42 @@ class TokenSlots(NamedTuple):
     """Where a step's new tokens go: token [b, ...] of every kv head
     lands in page `page_idx[b, ...]` at `offset[b, ...]`; and what the
     step program was told of kernels and the mesh, which decides the
-    form of an int8 pool's append (`kernel_append`)."""
+    form of an int8 pool's append (`kernel_append`), and the slots the
+    kernels walk."""
 
     kh: jax.Array        # [KH, 1, ...]: the kv heads, over the slots
     page_idx: jax.Array  # [B, ...]
     offset: jax.Array    # [B, ...]
     use_pallas: Optional[bool] = None  # the step program's; None: on a TPU
     mesh: Optional[jax.sharding.Mesh] = None
+    live: Optional["LiveRows"] = None  # kernel_live_rows(pool, active, ...)
 
 
 def token_slots(kv_heads: int, page_idx: jax.Array, offset: jax.Array,
-                use_pallas: Optional[bool] = None, mesh=None) -> TokenSlots:
+                use_pallas: Optional[bool] = None, mesh=None,
+                live=None) -> TokenSlots:
     """The slots `append` writes (any rank: one row a slot, or r).
     Taken once a step, outside the layer walk: every layer writes the
     same slots."""
     kh = jnp.arange(kv_heads)[(slice(None),) + (None,) * page_idx.ndim]
-    return TokenSlots(kh, page_idx, offset, use_pallas, mesh)
+    return TokenSlots(kh, page_idx, offset, use_pallas, mesh, live)
+
+
+def kernel_live_rows(pool, active, use_pallas: Optional[bool] = None):
+    """A step's `active` mask [B] as the int8 pool's two kernels take it
+    (paged_attention_int8.LiveRows: the live slots' indices first, and
+    their count), made on the device, or None where the kernels are off
+    (`kernel_append`: the attention kernel runs under the same
+    conditions; never for a bf16 or a latent pool) or the caller has no
+    mask: the XLA forms compute every slot, as they did. Taken once a
+    step, outside the layer walk, by the decode bodies that
+    `decode_multi_step` hands its `active` (engine_model._decode_once,
+    _hybrid_decode_once)."""
+    if active is None or not kernel_append(pool, use_pallas):
+        return None
+    from generativeaiexamples_tpu.serving import paged_attention_int8
+
+    return paged_attention_int8.live_rows(active)
 
 
 def kernel_append(pool, use_pallas: Optional[bool] = None,
@@ -334,6 +362,9 @@ class QuantPagePool:
         `kernel_append` decides, from the pool, the slots' rank and the
         step program's `use_pallas`: no option selects the form.
 
+        With `slots.live` the kernel reads and writes the live slots'
+        tiles alone; an idle slot's (page 0, the sink) is left as it is.
+
         Everything else keeps XLA's scatters, the reference form: off
         the chip (every CPU-lowered program), a page or head size the
         kernel cannot tile, and slots of rank 2 (the linear and the tree
@@ -346,21 +377,23 @@ class QuantPagePool:
         over KV heads x slots index tuples, 57-70 us whatever a tuple
         carries: they were 36 % of a Mistral-7B decode step and 56 % of
         an Ouro step (PERF.md section 5)."""
-        kh, page_idx, offset, use_pallas, mesh = slots
+        kh, page_idx, offset, use_pallas, mesh, live = slots
         kq, ks = self._quantize(k_new)
         vq, vs = self._quantize(v_new)
         if kernel_append(self, use_pallas, page_idx.ndim):
             return self._append_kernel(row, page_idx, offset, mesh,
                                        jnp.stack([kq, vq]),
-                                       jnp.stack([ks, vs]))
+                                       jnp.stack([ks, vs]), live)
         kv = self.kv.at[0, row, kh, page_idx[None], offset[None], :].set(kq)
         kv = kv.at[1, row, kh, page_idx[None], offset[None], :].set(vq)
         s = self.s.at[0, row, kh, page_idx[None], offset[None]].set(ks)
         s = s.at[1, row, kh, page_idx[None], offset[None]].set(vs)
         return QuantPagePool(kv, s, self.page_size)
 
-    def _append_kernel(self, row, page_idx, offset, mesh, codes, scales):
-        """append's kernel form: codes [2, KH, B, Hd], scales [2, KH, B]."""
+    def _append_kernel(self, row, page_idx, offset, mesh, codes, scales,
+                       live=None):
+        """append's kernel form: codes [2, KH, B, Hd], scales [2, KH, B];
+        `live`: the slots it writes (None: every one)."""
         from jax.sharding import PartitionSpec as P
 
         from generativeaiexamples_tpu.serving.kv_append_int8 import (
@@ -373,10 +406,11 @@ class QuantPagePool:
             fused_s, new_s = P(None, None, "tensor"), P(None, "tensor")
             fn = jax.shard_map(
                 kv_append_int8, mesh=mesh,
-                in_specs=(fused_s, fused_s, P(), P(), P(), new_s, new_s),
+                in_specs=(fused_s, fused_s, P(), P(), P(), new_s, new_s,
+                          P()),  # the mask is replicated
                 out_specs=(fused_s, fused_s), check_vma=False)
         kv, s = fn(self.kv, self.s, jnp.asarray(row, jnp.int32), page_idx,
-                   offset, codes, scales)
+                   offset, codes, scales, live)
         return QuantPagePool(kv, s, self.page_size)
 
     def encode_pages(self, k, v):

@@ -301,8 +301,8 @@ def _paged_tpu(q, k_pages, v_pages, page_table, lengths, *, scale,
                            scale=scale, interpret=interpret)
 
 
-def _paged_tpu_int8(q, kv_pages, kv_scales, page_table, lengths, layer, *,
-                    scale, pages_per_compute_block):
+def _paged_tpu_int8(q, kv_pages, kv_scales, page_table, lengths, layer,
+                    live=None, *, scale, pages_per_compute_block):
     from generativeaiexamples_tpu.serving.paged_attention_int8 import (
         paged_attention_int8, paged_attention_int8_reference_fused)
 
@@ -313,7 +313,8 @@ def _paged_tpu_int8(q, kv_pages, kv_scales, page_table, lengths, layer, *,
     if ps % 128 == 0 and Hd % 128 == 0:
         return paged_attention_int8(
             q, kv_pages, kv_scales, page_table, lengths, layer,
-            scale=scale, pages_per_compute_block=pages_per_compute_block)
+            scale=scale, pages_per_compute_block=pages_per_compute_block,
+            live=live)
     log_kernel_declined(
         "paged_attention_int8", "the XLA gather reference",
         f"page_size {ps} and head_dim {Hd} must both be multiples of 128")
@@ -327,10 +328,14 @@ def paged_attention_dispatch(
     k_scales=None, layer=None,
     use_pallas: Optional[bool] = None, mesh=None, interpret: bool = False,
     pages_per_compute_block: Optional[int] = None,
+    live=None,
 ):
     """Pick the fastest available implementation for the current
     backend/mesh. `lengths` INCLUDES the current token, whose k/v must
-    already be written to the pool (write-then-attend decode).
+    already be written to the pool (write-then-attend decode). `live`
+    (kv_cache.kernel_live_rows of the step's `active` mask, or None):
+    the rows the int8 kernel walks, an idle row's output zeros; no other
+    form reads it.
 
     Quantized (fused) form: `v_pages=None`, `k_pages` holds the FULL
     fused int8 pool [2, L, KH, P, ps, Hd], `k_scales` the full narrow
@@ -360,14 +365,14 @@ def paged_attention_dispatch(
             # axis) at axis 2.
             fused_s = P(None, None, "tensor")
             fn = jax.shard_map(
-                lambda q_, kvp_, s_, t_, ln_, ly_: _paged_tpu_int8(
-                    q_, kvp_, s_, t_, ln_, ly_, scale=scale,
+                functools.partial(
+                    _paged_tpu_int8, scale=scale,
                     pages_per_compute_block=pages_per_compute_block),
-                mesh=mesh,
-                in_specs=(hs, fused_s, fused_s, P(), P(), P()),
+                mesh=mesh,  # the mask is replicated, as the tables are
+                in_specs=(hs, fused_s, fused_s, P(), P(), P(), P()),
                 out_specs=hs, check_vma=False)
             return fn(q, k_pages, k_scales, page_table, lengths,
-                      jnp.asarray(layer, jnp.int32))
+                      jnp.asarray(layer, jnp.int32), live)
         pool_s = P("tensor", None, None, None)
         fn = jax.shard_map(
             lambda q_, kp_, vp_, t_, ln_: _paged_tpu(
@@ -378,7 +383,7 @@ def paged_attention_dispatch(
         return fn(q, k_pages, v_pages, page_table, lengths)
     if quantized:
         return _paged_tpu_int8(q, k_pages, k_scales, page_table, lengths,
-                               layer, scale=scale,
+                               layer, live, scale=scale,
                                pages_per_compute_block=pages_per_compute_block)
     return _paged_tpu(q, k_pages, v_pages, page_table, lengths, scale=scale,
                       interpret=interpret,

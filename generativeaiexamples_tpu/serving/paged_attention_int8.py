@@ -17,25 +17,31 @@ Layouts (per layer, matching kv_cache.QuantPagePool):
   kv_scales  [2, KH, P, ps]      bf16/f32 (amax/127 over Hd at write)
   page_table [B, maxp] int32     page ids (0 = garbage sink)
   lengths    [B] int32           valid tokens INCLUDING the current one
+  live       LiveRows or None    the step's `active` mask as the kernels
+                                 prefetch it (live_rows); None: every row
 
-Kernel shape: grid (B,) — ONE grid step per batch row covering ALL kv
-heads, as a fori_loop over blocks of PAGES_PER_BLOCK pages. Each page's
+Kernel shape: grid (n_live,), a dynamic bound — ONE grid step per LIVE
+batch row (grid step k serves row order[k]) covering ALL kv heads, as a
+fori_loop over blocks of PAGES_PER_BLOCK pages. Each page's
 k AND v move HBM->VMEM as a SINGLE DMA descriptor strided across the
 (KH, 2) axes, and both scale rows as one more — 2 descriptors per page
 instead of the 4 an unfused pool needs and the 8 a per-head grid pays.
 The copies of the next BLOCKS_AHEAD blocks, this row's and then the next
-rows', are in flight while the current one computes (cross-grid-step
+LIVE rows', are in flight while the current one computes (cross-grid-step
 buffering).
 
-A row has n = clip(cdiv(length + q_rep - 1, ps), 1, maxp) pages, and a
-page past its last is neither copied nor multiplied: a block's count of
+A live row has n = clip(cdiv(length + q_rep - 1, ps), 1, maxp) pages, and
+an idle one (a decode slot nobody occupies: `active` False) none: it is
+never asked for, waited for or multiplied, and its output is zeros. A
+page past a row's last is neither copied nor multiplied: a block's count of
 live pages picks the body that starts, waits for and multiplies exactly
 those (`_int8_kernel`), so no table entry past n is read and a block
 need not divide the table's width. The kernel streams the bytes a batch
 HAS: on a v5e rows of whole blocks run at 93 % of the HBM's rate, and a
-short or idle row costs its pages and about 0.3 us, where walking whole
-blocks read page 0 for every page the row lacked
-(scripts/measure_paged_attention.py; PERF.md section 5, PR 34).
+short row costs its pages and about 0.3 us, where walking whole blocks
+read page 0 for every page the row lacked, and an idle row took a page
+and 0.55 us until the mask reached the kernel
+(scripts/measure_paged_attention.py; PERF.md section 5, PR 34, PR 41).
 
 Dequantization never touches head_dim: K scales multiply the score
 columns ((q @ k_q^T) * ks == q @ (k_q * ks)^T), V scales fold into the
@@ -49,6 +55,7 @@ TRT-LLM inside NIM (SURVEY.md §2.3).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -147,14 +154,42 @@ PAGES_PER_BLOCK = 4
 BLOCKS_AHEAD = 2
 
 # The kernel's state in SMEM, carried from one grid step to the next:
-# the buffer and the (row, block) to ask for next, the buffer to take.
+# the buffer and the (place in `order`, block) to ask for next, the
+# buffer to take.
 _ASK_SLOT, _ASK_ROW, _ASK_BLOCK, _TAKE_SLOT = range(4)
+
+
+class LiveRows(NamedTuple):
+    """A decode step's `active` mask as the two int8 pool kernels (this
+    one and serving/kv_append_int8.py) prefetch it: they walk
+    `order[0 .. n_live)` and never touch another row."""
+
+    mask: jax.Array    # [B] bool
+    order: jax.Array   # [B] int32: the live rows' indices first, stable
+    n_live: jax.Array  # [1] int32
+
+
+def live_rows(mask: jax.Array) -> LiveRows:
+    """Taken once a step, outside the layer walk: every layer's calls
+    walk the same rows."""
+    mask = mask.astype(bool)
+    order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
+    return LiveRows(mask, order, jnp.sum(mask, dtype=jnp.int32).reshape(1))
+
+
+def every_row(n_rows: int) -> LiveRows:
+    """What a caller without a mask gets: the parent's walk, row by row."""
+    return LiveRows(jnp.ones((n_rows,), bool),
+                    jnp.arange(n_rows, dtype=jnp.int32),
+                    jnp.full((1,), n_rows, jnp.int32))
 
 
 def _int8_kernel(
     lengths_ref,   # scalar prefetch [B]
     tables_ref,    # scalar prefetch [B * maxp]
     layer_ref,     # scalar prefetch [1] — which layer's pool slice
+    order_ref,     # scalar prefetch [B] — LiveRows.order
+    n_live_ref,    # scalar prefetch [1] — the rows to walk: the grid's size
     q_ref,         # [1, KH, G, Hd] f32 (scale pre-folded)
     kv_hbm,        # [2, L, KH, P, ps, Hd] int8 (ANY)
     s_hbm,         # [2, L, KH, P, 1, ps] f32 (ANY)
@@ -167,13 +202,14 @@ def _int8_kernel(
     ppcb: int,
     maxp: int,
     page_size: int,
-    batch_size: int,
     ahead: int,
     q_rep: int = 1,
     tree=None,
     split_kv: bool = False,
 ):
-    """One grid step per BATCH ROW, all kv heads + k and v together.
+    """One grid step per LIVE BATCH ROW, all kv heads + k and v together:
+    step k serves row order[k] through the q and o index maps, and the
+    grid ends at n_live.
 
     q_rep > 1 (speculative verify): the G axis carries q_rep query
     positions per head group, j-major (row = j * G_base + g); query
@@ -205,10 +241,18 @@ def _int8_kernel(
     2. Fused pages: 2 descriptors per page.
     3. Latency hiding is CROSS-grid-step (the JetStream scheme): while
        a block is multiplied, the copies of the `ahead` blocks after it
-       (this row's, then the next rows') are in flight in the other
+       (this row's, then the next live rows') are in flight in the other
        buffers; what was asked for and taken persists in SMEM across
-       grid steps."""
-    b = pl.program_id(0)
+       grid steps.
+    4. Only the live rows, at no scalar test more for one of them (25 ns
+       each, PR 34): the chain of copies ends at n_live where it ended
+       at B, and so does the grid, so there is no dead step to guard: no
+       `pl.when` around the body, no index map that has to hold the last
+       live row's blocks. With all of 64 rows idle a call is 1.84 us
+       where it was 35.33 (PERF.md section 5, PR 41)."""
+    k = pl.program_id(0)
+    n_live = n_live_ref[0]
+    b = order_ref[k]
     ps = page_size
     KH, G, Hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
     g_base = G // q_rep
@@ -270,25 +314,27 @@ def _int8_kernel(
 
     def ask():
         """Start the copies of the next block not yet asked for, where
-        a row is left: this row's next if it has one, else the next
-        row's first (lengths >= 1, so every row has a block)."""
-        row, i = state[_ASK_ROW], state[_ASK_BLOCK]
+        a live row is left: this row's next if it has one, else the next
+        live row's first (a live row's length is >= 1, so it has a
+        block; an idle row is not in order[0 .. n_live))."""
+        at, i = state[_ASK_ROW], state[_ASK_BLOCK]
 
-        @pl.when(row < batch_size)
+        @pl.when(at < n_live)
         def _():
+            row = order_ref[at]
             n_row = pages_of(row)
             slot = state[_ASK_SLOT]
             by_live_count(n_row, i, lambda count: lambda: copies(
                 row, i, slot, count, start))
             state[_ASK_SLOT] = after(slot)
             more = (i + 1) * ppcb < n_row
-            state[_ASK_ROW] = jnp.where(more, row, row + 1)
+            state[_ASK_ROW] = jnp.where(more, at, at + 1)
             state[_ASK_BLOCK] = jnp.where(more, i + 1, 0)
 
-    @pl.when(b == 0)
+    @pl.when(k == 0)
     def _first():
-        for k in range(4):  # the first block goes into buffer 0
-            state[k] = 0
+        for field in range(4):  # the first block goes into buffer 0
+            state[field] = 0
         for _ in range(ahead):
             ask()
 
@@ -358,15 +404,19 @@ def _int8_kernel(
 
 
 def page_counts(lengths, page_size: int, max_pages: int,
-                block: int | None = None) -> tuple[int, int]:
-    """On the host, for a batch's `lengths` (numpy, any shape): the pages
-    the kernel copies and multiplies (each row's n, an idle row's one),
-    and what whole blocks over the same rows would cover, which is what
-    it walked before it stopped at n. The engine's
-    `decode_attn_pages_live` / `decode_attn_pages_walked`."""
+                block: int | None = None, mask=None) -> tuple[int, int]:
+    """On the host, for a batch's `lengths` (numpy, any shape) and its
+    `mask` of live rows (broadcast against them; None: every row): the
+    pages the kernel copies and multiplies (each live row's n, an idle
+    row's none), and what whole blocks over EVERY row would cover, which
+    is what it walked before it stopped at n and at the live rows (an
+    idle row walked a block). The engine's `decode_attn_pages_live` /
+    `decode_attn_pages_walked`."""
     block = min(block or PAGES_PER_BLOCK, max_pages)
     n = np.clip(-(-np.asarray(lengths, np.int64) // page_size), 1, max_pages)
     walked = np.minimum(-(-n // block) * block, max_pages)
+    if mask is not None:
+        n = n * np.asarray(mask, bool)
     return int(n.sum()), int(walked.sum())
 
 
@@ -389,8 +439,13 @@ def paged_attention_int8(
     tree=None,
     interpret: bool = False,
     split_kv: bool | None = None,
+    live: Optional[LiveRows] = None,
 ) -> jax.Array:
-    """q_rep > 1 is the speculative-verify form: R consecutive query
+    """`live` (live_rows of the step's `active` mask): the kernel walks
+    those rows alone, and an idle row's output is zeros, whatever its
+    length and table row say. None: every row is live.
+
+    q_rep > 1 is the speculative-verify form: R consecutive query
     positions per sequence ride the kernel's G axis, so the KV pages
     stream from HBM ONCE per sequence instead of once per position
     (folding positions into the batch costs R x the KV traffic AND
@@ -433,12 +488,20 @@ def paged_attention_int8(
         split_kv = L * KH * P * ps * Hd >= SPLIT_KV_BYTES
     ahead = BLOCKS_AHEAD
     kernel = functools.partial(_int8_kernel, ppcb=ppcb, maxp=maxp,
-                               page_size=ps, batch_size=B, ahead=ahead,
+                               page_size=ps, ahead=ahead,
                                q_rep=q_rep, tree=tree, split_kv=split_kv)
-    qmap = lambda b, Ln, T, LY: (b, 0, 0, 0)  # noqa: E731
+
+    def qmap(k, Ln, T, LY, order, n_walk):
+        return (order[k], 0, 0, 0)
+
+    rows = every_row(B) if live is None else live
+    # the grid is as long as the walk; with nobody live it serves row
+    # order[0] alone (an idle one: its page, the sink, is read and the
+    # select below discards what comes of it)
+    n_walk = jnp.maximum(rows.n_live, 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B,),
+        num_scalar_prefetch=5,
+        grid=(n_walk[0],),
         in_specs=[
             pl.BlockSpec((1, KH, G, Hd), qmap),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -452,10 +515,9 @@ def paged_attention_int8(
             pltpu.SMEM((4,), jnp.int32),
         ],
     )
-    # The blocks are asked for in one chain over the rows, and every row
-    # takes at least one: a length-0 row takes one page, masked but for
-    # its first token, and its output is ignored by the engine for
-    # inactive slots. Clamp rather than assert.
+    # The blocks are asked for in one chain over the live rows, and each
+    # of those takes at least one: a live row of length 0 takes one page,
+    # masked but for its first token. Clamp rather than assert.
     lengths = jnp.maximum(lengths.astype(jnp.int32), 1)
     out = pl.pallas_call(
         kernel,
@@ -467,7 +529,12 @@ def paged_attention_int8(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lengths, page_table.reshape(-1).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), qk, kv_pages, s2)
+      jnp.asarray(layer, jnp.int32).reshape(1), rows.order, n_walk,
+      qk, kv_pages, s2)
+    if live is not None:
+        # a row the grid never served holds whatever the buffer held: a
+        # select, so that no stale NaN reaches a router or a sampler
+        out = jnp.where(live.mask[:, None, None, None], out, 0.0)
     if q_rep > 1:
         return out.reshape(B, KH, q_rep, H // KH, Hd).transpose(
             0, 2, 1, 3, 4).reshape(B, q_rep, H, Hd).astype(q.dtype)

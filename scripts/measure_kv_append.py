@@ -2,11 +2,19 @@
 """Both forms of the int8 pool's append (kv_cache.QuantPagePool.append),
 on the chip, a decode step's worth of calls on a pool of the real size:
 
-    chiprun -- python3 scripts/measure_kv_append.py [--shapes mistral,ouro,tp4]
+    chiprun -- python3 scripts/measure_kv_append.py \
+        [--shapes mistral,ouro,tp4] [--live 3,5,16,60,64] [--parent DIR]
 
 `scatter`: XLA's four scatters a cache row, what every program lowered
 off the chip keeps. `kernel`: one in-place Pallas call a row
-(serving/kv_append_int8.py). The shapes are the benchmark cells':
+(serving/kv_append_int8.py), given the step's mask: it walks the live
+slots alone. `parent` (with `--parent DIR`, a `git archive` of another
+commit, e.g. .scratch/parent): that tree's kernel as it is, called with
+the codes this tree's pool quantizes; one that takes no mask writes every
+slot, an idle one to the sink page. Every form runs at each of `--live`
+counts of live slots (capped at the shape's slots), the idle ones as the
+engine sends them: page 0, offset 0, `active` False. The shapes are the
+benchmark cells':
 
     mistral  32 rows,  8 KV heads, 64 slots,  768 pages   (Mistral-7B)
     ouro    192 rows, 16 KV heads, 32 slots,  112 pages   (Ouro-2.6B: 48
@@ -21,10 +29,11 @@ and V row of every slot to every cache row, as a decode step does
 `memory_analysis()`'s temporary bytes (a temporary the size of the pool
 is the copy trap of docs/ENGINEERING_NOTES.md, "two scatter traps"),
 checks that both forms leave the SAME bytes in every tile and scale row
-they touch and the same sums over the whole pool, times `--reps`
-executions by the host's clock, then traces three and sums device time
-by operation. The forms are chosen through `token_slots(use_pallas=...)`,
-the step programs' own argument; nothing else is switched.
+the LIVE slots touch and the same sums over the whole pool less the sink
+page, times `--reps` executions by the host's clock, then traces three
+and sums device time by operation. This tree's forms are chosen through
+`token_slots(use_pallas=...)`, the step programs' own argument; nothing
+else is switched.
 
 One JSON object a line on stdout and in chiprun_out/kv_append/probe.jsonl;
 never a measurement on the CPU (`--rehearse` is the same control flow
@@ -35,6 +44,8 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import importlib.util
+import inspect
 import json
 import os
 import shutil
@@ -58,6 +69,10 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--shapes", default="mistral,ouro,tp4")
     ap.add_argument("--forms", default="scatter,kernel")
+    ap.add_argument("--live", default="3,5,16,60,64",
+                    help="counts of live slots to run every form at")
+    ap.add_argument("--parent", default=None,
+                    help="a checkout whose kernel is measured beside them")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
@@ -71,8 +86,20 @@ def main() -> int:
 
     from benchmark.harness import xplane
     from generativeaiexamples_tpu.serving.kv_cache import (
-        QuantPagePool, kernel_append, token_slots)
+        QuantPagePool, kernel_append, kernel_live_rows, token_slots)
     from scripts.measure_qkv_forms import by_operation
+
+    forms = args.forms.split(",")
+    parent_append = None
+    if args.parent:
+        spec = importlib.util.spec_from_file_location(
+            "parent_kv_append_int8", os.path.join(
+                args.parent,
+                "generativeaiexamples_tpu/serving/kv_append_int8.py"))
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+        parent_append = parent.kv_append_int8
+        forms.append("parent")
 
     dev = jax.devices()[0]
     if not args.rehearse and dev.platform != "tpu":
@@ -108,18 +135,33 @@ def main() -> int:
             return QuantPagePool(kv.astype(jnp.int8), s * 0.01 + 0.25, PS)
 
         def step(form):
-            def kv_append_step(pool, page_idx, offset, k_new, v_new):
-                slots = token_slots(KH, page_idx, offset,
-                                    use_pallas=(form == "kernel"))
-                assert kernel_append(pool, slots.use_pallas) == (
-                    form == "kernel")
+            def kv_append_step(pool, page_idx, offset, k_new, v_new, active):
+                on = form == "kernel"
+                # the mask becomes the walk's order once, outside the rows
+                slots = token_slots(KH, page_idx, offset, use_pallas=on,
+                                    live=kernel_live_rows(pool, active, on))
+                assert kernel_append(pool, slots.use_pallas) == on
+                kw = {}
+                if form == "parent" and "live" in inspect.signature(
+                        parent_append).parameters:
+                    kw["live"] = kernel_live_rows(pool, active, True)
+
+                def append(pool, row, k, v):
+                    if form != "parent":
+                        return pool.append(row, slots, k, v)
+                    (kq, ks), (vq, vs) = pool._quantize(k), pool._quantize(v)
+                    kv, s = parent_append(
+                        pool.kv, pool.s, row, page_idx, offset,
+                        jnp.stack([kq, vq]), jnp.stack([ks, vs]),
+                        interpret=args.rehearse, **kw)
+                    return QuantPagePool(kv, s, PS)
 
                 def one_pass(p, pool):
                     for l in range(blocks):
                         row = p * blocks + l
                         add = jnp.asarray(row, jnp.float32) / R
-                        pool = pool.append(
-                            row, slots, (k_new + add).astype(jnp.bfloat16),
+                        pool = append(
+                            pool, row, (k_new + add).astype(jnp.bfloat16),
                             (v_new - add).astype(jnp.bfloat16))
                     return pool
 
@@ -130,7 +172,9 @@ def main() -> int:
 
         @jax.jit
         def digest(pool, page_idx, offset):
-            """What a call may have touched, and sums over the rest."""
+            """What a call may have touched (an idle slot's entry is the
+            sink's first tile: skipped by the comparison), and sums over
+            the rest less the sink page."""
             # a slice a slot: a gather of this shape copies the pool
             tiles = [jax.lax.dynamic_slice(
                 pool.kv, (0, 0, 0, page_idx[b], offset[b] // 32 * 32, 0),
@@ -139,32 +183,44 @@ def main() -> int:
                 pool.s, (0, 0, 0, page_idx[b], 0), (2, R, KH, 1, PS))
                 for b in range(B)]
             return (jnp.concatenate(tiles, 3), jnp.concatenate(scales, 3),
-                    jnp.sum(pool.kv.astype(jnp.int32)), jnp.sum(pool.s))
+                    jnp.sum(pool.kv[:, :, :, 1:].astype(jnp.int32)),
+                    jnp.sum(pool.s[:, :, :, 1:]))
 
         rng = np.random.default_rng(7)
-        page_idx = jnp.asarray(1 + rng.permutation(P - 1)[:B], jnp.int32)
+        pages = 1 + rng.permutation(P - 1)[:B]
         base = rng.integers(0, PS, B)
         base[:4] = (0, 31, 32, 127)
         k_new, v_new = (jnp.asarray(rng.standard_normal((KH, B, HD)),
                                     jnp.float32) for _ in range(2))
-
-        def offset(i):
-            return jnp.asarray((base + i) % PS, jnp.int32)
-
+        spread = rng.permutation(B)  # the live slots, apart as a batch's are
+        counts = sorted({min(int(x), B) for x in args.live.split(",")})
         digests = {}
-        for form in args.forms.split(","):
+        compiled_forms = {}
+        for form, n_live in [(f, n) for n in counts for f in forms]:
+            mask = np.zeros((B,), bool)
+            mask[np.sort(spread[:n_live])] = True
+            active = jnp.asarray(mask)
+            page_idx = jnp.asarray(np.where(mask, pages, 0), jnp.int32)
+
+            def offset(i):
+                return jnp.asarray(np.where(mask, (base + i) % PS, 0),
+                                   jnp.int32)
+
             pool = fresh_pool()
             jax.block_until_ready(pool)
-            t0 = time.perf_counter()
-            with interpreted():
-                compiled = step(form).lower(
-                    pool, page_idx, offset(0), k_new, v_new).compile()
-            compile_s = time.perf_counter() - t0
+            if form not in compiled_forms:
+                t0 = time.perf_counter()
+                with interpreted():
+                    c = step(form).lower(pool, page_idx, offset(0), k_new,
+                                         v_new, active).compile()
+                compiled_forms[form] = (c, time.perf_counter() - t0)
+            compiled, compile_s = compiled_forms[form]
             mem = compiled.memory_analysis()
             text = compiled.as_text()
 
             def run(pool, i):
-                pool = compiled(pool, page_idx, offset(i), k_new, v_new)
+                pool = compiled(pool, page_idx, offset(i), k_new, v_new,
+                                active)
                 # the TPU interpret mode's callbacks dispatch operations
                 # of their own and deadlock against this thread's next
                 # dispatch: a rehearsal lets each step finish first
@@ -172,8 +228,9 @@ def main() -> int:
 
             # two steps running into the same tiles, then what they left
             pool = run(run(pool, 0), 1)
-            digests[form] = [np.asarray(x) for x in
-                             digest(pool, page_idx, offset(0))]
+            got = [np.asarray(x) for x in digest(pool, page_idx, offset(0))]
+            digests.setdefault(n_live, {})[form] = [
+                got[0][:, :, :, mask], got[1][:, :, :, mask]] + got[2:]
             t0 = time.perf_counter()
             for i in range(args.reps):
                 pool = run(pool, 2 + i)
@@ -181,6 +238,7 @@ def main() -> int:
             host_ms = (time.perf_counter() - t0) * 1e3 / args.reps
             line = dict(
                 shape=name, form=form, rows=R, kv_heads=KH, slots=B, pages=P,
+                slots_live=n_live,
                 pool_bytes=int(np.prod(shape)) + int(np.prod(shape[:-1])) * 4,
                 temp_bytes=mem.temp_size_in_bytes,
                 alias_bytes=mem.alias_size_in_bytes,
@@ -202,11 +260,13 @@ def main() -> int:
                                             for k, v in red["ops"].items()})
             say(**line)
             del pool
-        if len(digests) == 2:
-            a, b = digests.values()
-            say(shape=name, same_bytes=all(
-                x.shape == y.shape and np.array_equal(x, y)
-                for x, y in zip(a, b)))
+        for n_live, by_form in digests.items():
+            first = next(iter(by_form.values()))
+            say(shape=name, slots_live=n_live, forms=list(by_form),
+                same_bytes=all(
+                    x.shape == y.shape and np.array_equal(x, y)
+                    for other in by_form.values()
+                    for x, y in zip(first, other)))
     return 0
 
 

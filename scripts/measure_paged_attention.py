@@ -12,17 +12,25 @@ on the chip, over pools of the benchmark cells' real shapes:
             (one chip's share of Mistral-Small-24B under TP=4, no mesh)
 
 For every shape it compiles one program a form that calls the kernel once
-a cache row, as a decode step does, and runs it over four sets of lengths:
-`one` (every row 1: what an idle slot costs), `mix` (the closed cells'
-contexts: a prompt of 32-128 plus a uniform share of an answer of 192-320,
-mean about 208), `full` (every row at the table's width: what the guards
-cost where nothing is dead) and `chain` (three rows of 1,700 tokens, the
-rest idle: the open cells). The forms: the kernel of this tree at each of
-`--blocks` pages a block, and with `--parent DIR` (a checkout of another
-commit, e.g. `git archive` into .scratch/parent) that tree's kernel as it
-is. Times are the device's: `--reps` executions by the host's clock around
+a cache row, as a decode step does, and runs it over six sets of lengths
+and live rows: `one` (every row idle, as the engine sends an idle slot:
+length 1, `active` False; what an idle slot costs), `mix` (the closed
+cells' contexts: a prompt of 32-128 plus a uniform share of an answer of
+192-320, mean about 208, every row live), `mix60` (the same with 60 of 64
+rows live, or 30 of 32: a closed cell's occupancy), `full` (every row at
+the table's width: what the guards cost where nothing is dead), `chain`
+(three rows of 1,700 tokens, the rest idle: `rag.chain-open`) and `open`
+(three rows of 250 tokens, two pages each, apart among idle ones: the
+open mix, `mistral7b.chat-open`; the append's side of both mixes is
+scripts/measure_kv_append.py `--live 3,60,64`). The
+forms: the kernel of this tree at each of `--blocks` pages a block, given
+the step's mask, and with `--parent DIR` (a checkout of another commit,
+e.g. `git archive` into .scratch/parent) that tree's kernel as it is; a
+parent that takes no mask computes every row, an idle one at length 1.
+Times are the device's: `--reps` executions by the host's clock around
 `block_until_ready`, and three traced ones summed by operation. It also
-reads how far each form's output is from the first form's.
+reads how far each form's output is from the first form's, over the live
+rows.
 
 One JSON object a line on stdout and in chiprun_out/paged_attention/
 probe.jsonl; never a measurement on the CPU (`--rehearse` is the same
@@ -33,6 +41,7 @@ from __future__ import annotations
 
 import argparse
 import importlib.util
+import inspect
 import json
 import os
 import shutil
@@ -54,13 +63,24 @@ TINY = {"mistral": (2, 2, 4, 4, 9, 5), "ouro": (3, 2, 2, 4, 7, 4),
 
 
 def length_sets(rng, slots: int, width: int) -> dict:
+    """name: (lengths, live rows). An idle row is what the engine sends:
+    length 1, `active` False."""
     top = width * PS
     prompt = rng.integers(32, 129, slots)
     answer = rng.integers(192, 321, slots)
-    mix = prompt + (rng.random(slots) * answer).astype(int)
+    mix = [min(int(x), top)
+           for x in prompt + (rng.random(slots) * answer).astype(int)]
+    every = [True] * slots
+    idle = set(rng.permutation(slots)[:slots // 16].tolist())  # 4 of 64
+    live60 = [b not in idle for b in range(slots)]
     chain = [min(1700 + 13 * b, top) if b < 3 else 1 for b in range(slots)]
-    return {"one": [1] * slots, "mix": [min(int(x), top) for x in mix],
-            "full": [top] * slots, "chain": chain}
+    apart = set(range(2, slots, max(slots // 3, 1))[:3])  # three, apart
+    open_mix = [min(250 + b, top) if b in apart else 1 for b in range(slots)]
+    return {"one": ([1] * slots, [False] * slots), "mix": (mix, every),
+            "mix60": ([x if a else 1 for x, a in zip(mix, live60)], live60),
+            "full": ([top] * slots, every),
+            "chain": (chain, [x > 1 for x in chain]),
+            "open": (open_mix, [x > 1 for x in open_mix])}
 
 
 def main() -> int:
@@ -130,44 +150,55 @@ def main() -> int:
         sets = length_sets(rng, B, width)
         first = {}
         for form, (fn, blk) in forms.items():
-            def attend_rows(q, kv, s, table, lengths, fn=fn, blk=blk):
+            takes_mask = "live" in inspect.signature(fn).parameters
+
+            def attend_rows(q, kv, s, table, lengths, active, fn=fn, blk=blk,
+                            takes_mask=takes_mask):
+                # the mask becomes the walk's order once, outside the
+                # rows, as a step program takes it outside its layers
+                kw = ({"live": pa8.live_rows(active)} if takes_mask else {})
+
                 def row(l, acc):
                     return acc + fn(
                         q, kv, s, table, lengths, l,
                         pages_per_compute_block=blk,
-                        interpret=args.rehearse).astype(jnp.float32)
+                        interpret=args.rehearse, **kw).astype(jnp.float32)
                 return jax.lax.fori_loop(
                     0, rows, row, jnp.zeros((B, H, HD), jnp.float32))
 
             t0 = time.perf_counter()
             compiled = jax.jit(attend_rows).lower(
-                q, kv, s, table, jnp.zeros((B,), jnp.int32)).compile()
+                q, kv, s, table, jnp.zeros((B,), jnp.int32),
+                jnp.zeros((B,), bool)).compile()
             compile_s = time.perf_counter() - t0
-            for set_name, lens in sets.items():
+            for set_name, (lens, mask) in sets.items():
                 lengths = jnp.asarray(lens, jnp.int32)
-                got = np.asarray(compiled(q, kv, s, table, lengths))
+                active = jnp.asarray(mask)
+                got = np.asarray(compiled(q, kv, s, table, lengths,
+                                          active))[np.asarray(mask)]
                 want = first.setdefault(set_name, got)
                 t0 = time.perf_counter()
                 for _ in range(args.reps):
-                    res = compiled(q, kv, s, table, lengths)
+                    res = compiled(q, kv, s, table, lengths, active)
                 jax.block_until_ready(res)
                 host_us = (time.perf_counter() - t0) * 1e6 / args.reps / rows
                 live, walked = pa8.page_counts(
-                    np.asarray(lens), PS, width,
-                    blk or parent._pages_per_block(width, 8))
+                    np.asarray(lens), PS, width, blk,
+                    mask=np.asarray(mask) if takes_mask else None)
                 line = dict(
                     shape=name, form=form, lengths=set_name, rows=rows,
                     kv_heads=KH, slots=B, table_width=width,
+                    rows_live=int(np.sum(mask)) if takes_mask else B,
                     pages_live=live, pages_in_whole_blocks=walked,
                     compile_s=round(compile_s, 1), host_us_per_call=host_us,
                     max_abs_diff_to_first_form=float(
-                        np.max(np.abs(got - want))),
+                        np.max(np.abs(got - want), initial=0.0)),
                     finite=bool(np.isfinite(got).all()))
                 if dev.platform == "tpu":
                     tdir = tempfile.mkdtemp(prefix="paged_attention_trace_")
                     with jax.profiler.trace(tdir):
                         for _ in range(3):
-                            res = compiled(q, kv, s, table, lengths)
+                            res = compiled(q, kv, s, table, lengths, active)
                         jax.block_until_ready(res)
                     red = by_operation(xplane.find_xplane(tdir),
                                        "attend_rows", {})

@@ -26,7 +26,7 @@ from generativeaiexamples_tpu.ops.encoder_attention import encoder_attention
 from generativeaiexamples_tpu.serving.paged_attention import paged_attention
 from generativeaiexamples_tpu.ops.quant import QuantizedTensor
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-    paged_attention_int8)
+    live_rows, paged_attention_int8)
 from generativeaiexamples_tpu.serving.paged_attention_mla import (
     paged_attention_mla)
 from generativeaiexamples_tpu.serving.paged_attention_tree import (
@@ -72,6 +72,15 @@ def chip(topo):
 _POOL_INT8 = [((2, L, KH, P, PS, HD), I8), ((2, L, KH, P, PS), F32)]
 _POOL_BF16 = [((KH, P, PS, HD), BF16)] * 2
 _TABLE = [((B, MAXP), I32), ((B,), I32)]
+_MASK = [((B,), jnp.bool_)]
+
+
+def _masked(split_kv=None):
+    """The kernel as a decode step calls it: with the step's `active`
+    mask, turned into the walk's order inside the program (PR 41)."""
+    return lambda q, kv, s, t, ln, m: paged_attention_int8(
+        q, kv, s, t, ln, 1, split_kv=split_kv, live=live_rows(m))
+
 
 KERNELS = {
     "paged_decode_int8": (
@@ -101,6 +110,19 @@ KERNELS = {
         lambda q, kv, s, t, ln: paged_attention_int8(q, kv, s, t, ln, 1),
         [((B, 8, HD), BF16), ((2, L, 2, P, PS, HD), I8),
          ((2, L, 2, P, PS), F32)] + _TABLE),
+    # the same three with the step's mask (PR 41: the grid ends at the
+    # live rows' count, a bound the program computes)
+    "paged_decode_int8_masked_tables_of_20": (
+        _masked(), [((B, H, HD), BF16)] + _POOL_INT8
+        + [((B, 20), I32), ((B,), I32)] + _MASK),
+    "paged_decode_int8_masked_16_kv_heads_split": (
+        _masked(split_kv=True),
+        [((32, 16, HD), BF16), ((2, L, 16, P, PS, HD), I8),
+         ((2, L, 16, P, PS), F32), ((32, MAXP), I32), ((32,), I32),
+         ((32,), jnp.bool_)]),
+    "paged_decode_int8_masked_2_kv_heads": (
+        _masked(), [((B, 8, HD), BF16), ((2, L, 2, P, PS, HD), I8),
+                    ((2, L, 2, P, PS), F32)] + _TABLE + _MASK),
     "paged_decode_bf16": (
         paged_attention, [((B, H, HD), BF16)] + _POOL_BF16 + _TABLE),
     "paged_tree_bf16_3x4": (
@@ -311,6 +333,10 @@ def test_decode_program_appends_with_the_kernel_and_no_scatter(
                            re.M))
     # a looped model's passes are a loop around its blocks
     assert calls == n_steps * cfg.n_layers, calls
+    # the step's mask is turned into the kernels' walk ONCE a program
+    # (`active` does not change inside a block): one sort, whatever the
+    # steps and layers (PR 41)
+    assert len(re.findall(r" sort\(", text)) == 1, "live_rows, once"
     assert mem.alias_size_in_bytes >= pool_bytes
     # (staged weights and logits are a quarter of this small pool)
     assert mem.temp_size_in_bytes < pool_bytes // 2, mem.temp_size_in_bytes
@@ -332,8 +358,11 @@ APPEND_SHAPES = {
 }
 
 
+@pytest.mark.parametrize("masked", [False, True], ids=["", "masked"])
 @pytest.mark.parametrize("name", sorted(APPEND_SHAPES))
-def test_kv_append_kernel_compiles_in_place_for_v5e(topo, chip, name):
+def test_kv_append_kernel_compiles_in_place_for_v5e(topo, chip, name, masked):
+    """... and with the step's mask (PR 41: the loops run over the live
+    slots), replicated under the mesh."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -350,10 +379,11 @@ def test_kv_append_kernel_compiles_in_place_for_v5e(topo, chip, name):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=(
             NamedSharding(mesh, specs[kind]) if mesh is not None else chip))
 
-    def append(kv, s, row, page_idx, offset, codes, scales):
+    def append(kv, s, row, page_idx, offset, codes, scales, active):
         # through the pool's own dispatch: shard_map under the mesh
         pool = QuantPagePool(kv, s, PS)._append_kernel(
-            row, page_idx, offset, mesh, codes, scales)
+            row, page_idx, offset, mesh, codes, scales,
+            live_rows(active) if masked else None)
         return pool.kv, pool.s
 
     compiled = jax.jit(append, donate_argnums=(0, 1)).lower(
@@ -361,7 +391,8 @@ def test_kv_append_kernel_compiles_in_place_for_v5e(topo, chip, name):
         arr((2, rows, kv_heads, pages, PS), F32, "pool"),
         arr((), I32), arr((slots,), I32), arr((slots,), I32),
         arr((2, kv_heads, slots, HD), I8, "new"),
-        arr((2, kv_heads, slots), F32, "new")).compile()
+        arr((2, kv_heads, slots), F32, "new"),
+        arr((slots,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert "kv_append_int8" in text and "tpu_custom_call" in text
     assert " scatter(" not in text
@@ -371,6 +402,41 @@ def test_kv_append_kernel_compiles_in_place_for_v5e(topo, chip, name):
         pool_bytes //= mesh.size
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 1000, mem
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["", "masked"])
+def test_paged_attention_int8_partitions_over_the_kv_heads(topo, masked):
+    """Mistral-Small-24B's decode attention under TP=4 through the
+    dispatch's shard_map, 2 KV heads a chip, with and without the step's
+    mask (replicated, as the tables are)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from generativeaiexamples_tpu.serving.paged_attention import (
+        paged_attention_dispatch)
+
+    mesh = Mesh(np.array(topo.devices), ("tensor",))
+    specs = {"heads": PartitionSpec(None, "tensor"),
+             "pool": PartitionSpec(None, None, "tensor"),
+             None: PartitionSpec()}
+
+    def arr(shape, dtype, kind=None):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, specs[kind]))
+
+    def attend(q, kv, s, table, lengths, active):
+        return paged_attention_dispatch(
+            q, kv, None, table, lengths, k_scales=s, layer=1,
+            use_pallas=True, mesh=mesh,
+            live=live_rows(active) if masked else None)
+
+    text = jax.jit(attend).lower(
+        arr((B, H, HD), BF16, "heads"),
+        arr((2, L, KH, P, PS, HD), I8, "pool"),
+        arr((2, L, KH, P, PS), F32, "pool"), arr((B, MAXP), I32),
+        arr((B,), I32), arr((B,), jnp.bool_)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text and "all-reduce" not in text
 
 
 # -- a prefill program computes only its live rows (PR 38) -----------------
@@ -467,3 +533,62 @@ def test_a_one_block_flash_call_is_the_kernel_the_parent_had():
             q, kv, kv, jax.ShapeDtypeStruct((1,), I32))
     assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] == \
         PARENT_FLASH_ONE_BLOCK
+
+
+# -- the live-row walk leaves a latent model's programs alone (PR 41) -------
+# The step's mask reaches the int8 pool's two kernels through
+# engine_model._decode_once and _hybrid_decode_once alone. A model whose
+# cache row is a latent runs neither kernel (`ax-k1-ep16.decode-closed128`,
+# the cell that refused PR 40 by 0.17 point): its decode programs and its
+# prefill programs, lowered for the chip with kernels on at A.X-K1's served
+# sizes, are the text they were on PR 41's PARENT (d9d8463), taken there by
+# these functions.
+PARENT_LATENT = {"decode_multi_step_k8": "cb1fb18d90994585",
+                 "decode_step": "bb7290bf71fda8b3",
+                 "prefill_1x128": "c15570a7b01b3675",
+                 "prefill_4x384": "7d5d82e589e7ad08"}
+
+
+def _latent_lowered(chip):
+    import json
+
+    from benchmark import architectures
+    from benchmark.harness import system
+    from generativeaiexamples_tpu.serving import engine_model as em
+
+    name = "ax-k1-int8-ep16"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           name + ".json")) as fh:
+        config = json.load(fh)
+    ecfg = system.engine_config(config)
+    mcfg, params, pool, _ = architectures.load(config).compile_shapes(
+        config, ecfg, [next(iter(chip.device_set))])
+    assert mcfg.latent_row is not None
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, maxp = ecfg.max_batch_size, ecfg.max_seq_len // ecfg.page_size
+    K = ecfg.decode_steps_per_dispatch
+    state = (arr((slots,), I32), arr((slots, maxp), I32), arr((slots,), I32))
+    return {
+        f"decode_multi_step_k{K}": em.decode_multi_step.lower(
+            params, mcfg, pool, *state, arr((slots,), jnp.bool_),
+            arr((slots,), F32), arr((slots,), F32), arr((slots,), I32),
+            arr((2,), jnp.uint32), K, True,
+            sampling_flags=(True, False, False)),
+        "decode_step": em.decode_step.lower(params, mcfg, pool, *state, True),
+        "prefill_1x128": _prefill_lowered(chip, name, 1, 128)[0],
+        "prefill_4x384": _prefill_lowered(chip, name, 4, 384)[0],
+    }
+
+
+def test_a_latent_models_programs_lower_to_the_text_the_parent_did(chip):
+    import hashlib
+    import json
+
+    got = {k: hashlib.sha256(_without_kernel_payload(
+        v.as_text()).encode()).hexdigest()[:16]
+        for k, v in _latent_lowered(chip).items()}
+    assert got == PARENT_LATENT, json.dumps(got)

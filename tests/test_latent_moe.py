@@ -342,6 +342,8 @@ def test_the_engine_serves_the_forwards_tokens_and_counts_the_pairs(params):
     assert served == seq[len(ids):]
     snap = eng.metrics.snapshot()
     assert snap["experts_held"] == 4 and snap["kv_cache_rows"] == 3
+    # a latent pool runs neither int8 pool kernel: no row is skipped
+    assert snap["decode_attn_rows_skipped"] == 0
     # 40 values a token and layer in 128 lanes, float32, 3 rows
     assert snap["kv_bytes_per_token"] == 3 * 128 * 4
     steps = snap["decode_steps"]

@@ -7,6 +7,10 @@ maxp) points at a POISON page (codes 127, scales NaN) and the reference
 is taken over the live entries alone, so a dead page that is copied or
 multiplied fails by NaN. The interpreter starts a scratch buffer at NaN
 too: a page multiplied without having been copied fails the same way.
+
+With a mask (`live_rows` of a step's `active`) the kernel walks the live
+rows alone: an idle row's WHOLE table row, its first entry too, points at
+the poison page, its output is zeros, and the live rows read as before.
 """
 
 import jax
@@ -36,6 +40,20 @@ LENGTHS = {
     "several_blocks": (20, 5, lambda r: [3, 6 * PS - r + 1, 20 * PS - r + 1]),
     "block_not_a_divisor": (20, 8, lambda r: [20 * PS - r + 1, PS + 2,
                                               11 * PS]),
+}
+# name: (table width, pages a block, lengths, the rows that are live)
+MASKED = {
+    "idle_scattered_among_live": (
+        4, None, lambda r: [13, 1, 4 * PS - r + 1, 1, 1, PS + 1, 22],
+        [True, False, True, False, False, True, True]),
+    "all_idle": (4, None, lambda r: [1, 9, 1], [False] * 3),
+    "last_row_idle": (4, None, lambda r: [PS, 2 * PS + 3, 1],
+                      [True, True, False]),
+    "first_rows_idle_several_blocks": (
+        20, 5, lambda r: [1, 1, 6 * PS - r + 1, 1, 20 * PS - r + 1],
+        [False, False, True, False, True]),
+    "one_live_row": (4, None, lambda r: [1, 1, 3 * PS, 1],
+                     [False, False, True, False]),
 }
 
 
@@ -94,6 +112,109 @@ def test_a_page_past_a_rows_last_is_neither_copied_nor_multiplied(
                                  q_rep, tree))
     np.testing.assert_allclose(got[served], want[served], atol=2e-5,
                                rtol=2e-5)
+    # every row live, said with a mask: the same walk, bit for bit
+    masked = np.asarray(pa8.paged_attention_int8(
+        q, kv, s, poisoned, jnp.asarray(lengths), LAYER, q_rep=q_rep,
+        tree=tree, pages_per_compute_block=block, split_kv=split_kv,
+        interpret=True, live=pa8.live_rows(jnp.ones((B,), bool))))
+    np.testing.assert_array_equal(masked, got)
+
+
+@pytest.mark.parametrize("split_kv", [False, True])
+@pytest.mark.parametrize("form", list(FORMS))
+@pytest.mark.parametrize("case", list(MASKED))
+def test_an_idle_row_is_never_asked_for_and_reads_zeros(case, form, split_kv):
+    """As the engine sends an idle slot (length 1, a table row of page 0)
+    but with its table row on the poison page: nothing of it may be
+    copied, and a row the grid never served must not show stale VMEM."""
+    q_rep, tree = FORMS[form]
+    maxp, block, lengths_of, mask = MASKED[case]
+    lengths = np.asarray(lengths_of(q_rep), np.int32)
+    mask = np.asarray(mask)
+    B = len(lengths)
+    pages = B * maxp + 2
+    kv, s = _pool(pages, seed=len(case) + q_rep)
+    n = np.clip(-(-(lengths + q_rep - 1) // PS), 1, maxp)
+    live = (np.arange(maxp)[None, :] < n[:, None]) & mask[:, None]
+    own = 1 + np.arange(B * maxp).reshape(B, maxp)
+    poisoned = jnp.asarray(np.where(live, own, pages - 1), jnp.int32)
+    clean = jnp.asarray(np.where(live, own, 0), jnp.int32)
+    shape = (B, H, HD) if q_rep == 1 else (B, q_rep, H, HD)
+    q = jax.random.normal(jax.random.PRNGKey(q_rep), shape, jnp.float32)
+
+    got = np.asarray(pa8.paged_attention_int8(
+        q, kv, s, poisoned, jnp.asarray(lengths), LAYER, q_rep=q_rep,
+        tree=tree, pages_per_compute_block=block, split_kv=split_kv,
+        interpret=True, live=pa8.live_rows(jnp.asarray(mask))))
+    assert np.isfinite(got).all(), "an idle row's page was copied"
+    assert not got[~mask].any(), "an idle row's output is zeros"
+    if mask.any():
+        want = np.asarray(_reference(q, kv, s, clean, jnp.asarray(lengths),
+                                     q_rep, tree))
+        np.testing.assert_allclose(got[mask], want[mask], atol=2e-5,
+                                   rtol=2e-5)
+        # ... and bit for bit what the same rows read with nobody idle
+        alone = np.asarray(pa8.paged_attention_int8(
+            q[mask], kv, s, poisoned[mask], jnp.asarray(lengths[mask]),
+            LAYER, q_rep=q_rep, tree=tree, pages_per_compute_block=block,
+            split_kv=split_kv, interpret=True))
+        np.testing.assert_array_equal(got[mask], alone)
+
+
+# a decode batch of 64 slots as the cells send it: name -> live slots
+LIVE_OF_64 = {"3_of_64": 3, "60_of_64": 60, "1_of_64": 1, "all_64": 64}
+
+
+@pytest.mark.parametrize("case", list(LIVE_OF_64))
+def test_a_batch_of_64_attends_for_its_live_slots_alone(case):
+    """3, 60, 1 and all of 64 slots live, scattered among the idle ones
+    and never a prefix of the batch; an idle slot as the engine sends it
+    (length 1) but with its table row on the poison page. The live rows
+    read what the reference reads, the idle ones zeros, and no mask at
+    all is everyone live, bit for bit."""
+    n_live, B, maxp = LIVE_OF_64[case], 64, 4
+    rng = np.random.default_rng(n_live)
+    mask = np.zeros((B,), bool)
+    mask[1 + rng.permutation(B - 1)[:n_live]] = True
+    if n_live == B:
+        mask[:] = True
+    assert mask.sum() == n_live and (n_live == B or not mask[0])
+    lengths = np.where(mask, rng.integers(1, maxp * PS + 1, B), 1).astype(
+        np.int32)
+    pages = B * maxp + 2
+    kv, s = _pool(pages, seed=n_live)
+    n = np.clip(-(-lengths // PS), 1, maxp)
+    live = (np.arange(maxp)[None, :] < n[:, None]) & mask[:, None]
+    own = 1 + np.arange(B * maxp).reshape(B, maxp)
+    poisoned = jnp.asarray(np.where(live, own, pages - 1), jnp.int32)
+    clean = jnp.asarray(np.where(live, own, 0), jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(n_live), (B, H, HD),
+                          jnp.float32)
+
+    got = np.asarray(pa8.paged_attention_int8(
+        q, kv, s, poisoned, jnp.asarray(lengths), LAYER, interpret=True,
+        live=pa8.live_rows(jnp.asarray(mask))))
+    assert np.isfinite(got).all(), "an idle row's page was copied"
+    assert not got[~mask].any(), "an idle row's output is zeros"
+    want = np.asarray(_reference(q, kv, s, clean, jnp.asarray(lengths), 1,
+                                 None))
+    np.testing.assert_allclose(got[mask], want[mask], atol=2e-5, rtol=2e-5)
+    if mask.all():  # `live=None` is everyone live
+        np.testing.assert_array_equal(got, np.asarray(pa8.paged_attention_int8(
+            q, kv, s, poisoned, jnp.asarray(lengths), LAYER, interpret=True)))
+
+
+def test_live_rows_lists_the_live_rows_first_and_in_order():
+    mask = jnp.asarray([False, True, True, False, True, False])
+    rows = pa8.live_rows(mask)
+    assert np.asarray(rows.order).tolist() == [1, 2, 4, 0, 3, 5]
+    assert np.asarray(rows.n_live).tolist() == [3]
+    assert rows.order.dtype == jnp.int32 and rows.n_live.dtype == jnp.int32
+    every = pa8.every_row(4)
+    assert np.asarray(every.order).tolist() == [0, 1, 2, 3]
+    assert np.asarray(every.n_live).tolist() == [4] and every.mask.all()
+    none = pa8.live_rows(jnp.zeros((3,), bool))
+    assert np.asarray(none.n_live).tolist() == [0]
 
 
 def test_page_counts_of_a_known_batch():
@@ -107,3 +228,12 @@ def test_page_counts_of_a_known_batch():
     # a block that does not divide the table's width stops at the width
     assert pa8.page_counts(lengths[-1:], 128, 20, block=8) == (20, 20)
     assert pa8.page_counts(lengths[:0], 128, 20, block=8) == (0, 0)
+    # with the step's mask an idle row has no page to copy; what whole
+    # blocks over every row covered is the walk it is compared with
+    mask = np.array([True, False, True, False, True, False, True])
+    assert pa8.page_counts(lengths, 128, 20, block=5, mask=mask) == (
+        1 + 2 + 5 + 20, walked)
+    # a block of K steps: [K, B] lengths against the [B] mask
+    assert pa8.page_counts(np.stack([lengths, lengths + 1]), 128, 20,
+                           block=5, mask=mask) == (
+        (1 + 2 + 5 + 20) + (1 + 2 + 6 + 20), 2 * walked + 5)
