@@ -200,6 +200,14 @@ def llama_config_from_hf(path: str) -> llama_lib.LlamaConfig:
             "its configuration is models/hybrid_ssm.py's HybridSsmConfig, "
             "and this loader holds no tensor-name map for it (in_proj, "
             "conv1d, dt_bias, A_log, D, the expert stacks)")
+    if "sa_config" in c:
+        raise ValueError(
+            f"{path}: model_type {c.get('model_type')!r} has learned "
+            "sparse attention (sa_config: an indexer beside the "
+            "attention): it is no LlamaConfig; its configuration is "
+            "models/sparse_attn_moe.py's SparseAttnMoeConfig, and this "
+            "loader holds no tensor-name map for it (q_norm, k_norm, the "
+            "indexer's projections, the expert stacks)")
     family = {}
     if c.get("model_type") in _LOOPED_TYPES:
         family = dict(n_passes=int(c["total_ut_steps"]), post_norms=True)

@@ -104,6 +104,7 @@ class HybridSsmConfig:
     n_passes = 1
     post_norms = False
     latent_row = None
+    index_row = None
     expert_offset = 0
 
     def __post_init__(self):

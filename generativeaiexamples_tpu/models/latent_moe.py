@@ -83,6 +83,7 @@ class LatentMoeConfig:
     n_passes = 1
     post_norms = False
     recurrent_state = None
+    index_row = None
 
     def __post_init__(self):
         if not 0 < self.experts_held <= self.n_routed_experts \

@@ -150,6 +150,9 @@ class LlamaConfig:
 
     # sparse experts whose weights live here: none (serving/engine.py)
     experts_held = 0
+    # an indexer's key cached beside K and V (learned sparse attention):
+    # none (serving/kv_cache.py builds a SparseIndexPool where there is)
+    index_row = None
 
     @property
     def post_norm_init(self) -> float:

@@ -79,6 +79,7 @@ from generativeaiexamples_tpu.serving.multihost import (
 from generativeaiexamples_tpu.serving.flight import (
     EV_ADMIT, EV_ADMIT_RETRY, EV_DECODE_JOIN, EV_FIRST_TOKEN, EV_KV_DEMOTE,
     EV_KV_PROMOTE, EV_KV_TRANSFER, EV_MOE_LOAD, EV_PREFILL_CHUNK,
+    EV_SPARSE_SELECT,
     EV_PREFILL_DISPATCH, EV_PROGRAM, EV_QOS_PAUSE, EV_QOS_PICK,
     EV_QOS_RESUME, EV_RETIRE, EV_SUBMIT, PROG_CHUNK, PROG_DECODE,
     PROG_PREFILL, PROGRAM_CLASSES, RETIRE_CODES, ExpHistogram,
@@ -216,7 +217,8 @@ class _InFlight:
     """One dispatched-but-unprocessed decode block."""
 
     __slots__ = ("block", "metas", "K", "releases", "spec_worst",
-                 "plain_spec", "t_dispatch", "plan", "prog", "t_ready")
+                 "plain_spec", "t_dispatch", "plan", "prog", "t_ready",
+                 "sparse")
 
     def __init__(self, block, metas, K, spec_worst: int = 0,
                  plain_spec: bool = False):
@@ -230,6 +232,10 @@ class _InFlight:
         # off) and the clock reading of the thread its fetch ended on.
         self.prog: Optional[Program] = None
         self.t_ready = 0.0
+        # A model with learned sparse attention: the block's
+        # `sparse_select` event (a, b), from the lengths it was
+        # dispatched with (_note_sparse_select); None for every other.
+        self.sparse = None
         # Plain blocks: device [B, K+1]. Speculative blocks: a
         # (targets [B, K, r], counts [B, K]) tuple.
         self.block = block
@@ -376,6 +382,17 @@ class EngineMetrics:
         self.ssm_layers = 0
         self.ssm_slot_writes = 0
         self.ssm_steps_kernel = 0
+        # Learned sparse attention (0 for a model without an indexer):
+        # the bytes of index key a cached token takes over all layers and
+        # the tokens a query attends to at most (gauges); index keys
+        # scored (the live slots' lengths, summed over steps and layers),
+        # rows attended (min(length, topk) the same way), and slot-steps
+        # whose context was within topk, where selection is the identity.
+        self.index_bytes_per_token = 0
+        self.sparse_topk = 0
+        self.sparse_keys_scored = 0
+        self.sparse_rows_attended = 0
+        self.sparse_steps_dense = 0
         self.busy_slots_acc = 0
         # Speculative decoding: committed tokens vs slot-steps, for the
         # acceptance-rate gauge (1.0 = no drafts accepted, k+1 = all).
@@ -545,6 +562,11 @@ class EngineMetrics:
             "ssm_layers": self.ssm_layers,
             "ssm_slot_writes": self.ssm_slot_writes,
             "ssm_steps_kernel": self.ssm_steps_kernel,
+            "index_bytes_per_token": self.index_bytes_per_token,
+            "sparse_topk": self.sparse_topk,
+            "sparse_keys_scored": self.sparse_keys_scored,
+            "sparse_rows_attended": self.sparse_rows_attended,
+            "sparse_steps_dense": self.sparse_steps_dense,
             "mean_batch_occupancy": occ,
             "tokens_per_sec": self.tokens_per_sec(),
             "prefill_tokens": self.prefill_tokens,
@@ -691,6 +713,34 @@ def _refuse_unwalked_lanes(cfg: LlamaConfig, ecfg: EngineConfig,
                 + ", ".join(f"engine.{name} ({what})" for name, what in on)
                 + ": those lanes re-read, share, move or roll back cache "
                 "and would have to carry the state too; turn them off")
+        return
+    if cfg.index_row is not None:
+        # The index rows of a kv_cache.SparseIndexPool are written by the
+        # prefill and decode programs only: nothing snapshots them beside
+        # a shared page, moves them with a sequence or rolls them back,
+        # and no step but those two has the indexer and the selection.
+        on = [(name, what) for name, what in _ONE_PASS_LANES
+              if getattr(ecfg, name)]
+        if mesh is not None:
+            on.append(("mesh", "tensor parallelism: the indexer's one key "
+                       "head and the selection have no sharded form"))
+        if jnp.dtype(ecfg.kv_dtype) != jnp.int8:
+            on.append((f"kv_dtype {jnp.dtype(ecfg.kv_dtype).name}",
+                       "K and V beside the index rows in another type than "
+                       "int8"))
+        if ecfg.multihost:
+            on.append(("multihost", "the multi-host replay"))
+        if ecfg.qos and ecfg.qos_preempt_prefill:
+            on.append(("qos_preempt_prefill", "pausing and resuming a "
+                       "sequence's prefill"))
+        if on:
+            raise ValueError(
+                f"model caches an index key of {cfg.index_row} values a "
+                f"token and layer beside K and V (learned sparse attention, "
+                f"top {cfg.index_topk}); not served with "
+                + ", ".join(f"engine.{name} ({what})" for name, what in on)
+                + ": those lanes re-read, share, move or roll back cache "
+                "and would have to carry the index rows too; turn them off")
         return
     if cfg.n_passes == 1:
         return
@@ -866,10 +916,20 @@ class LLMEngine:
         self.metrics.experts_held = (cfg.experts_held if self._load_rows
                                      else 0)
         rs = cfg.recurrent_state
-        paged = self.pool if rs is None else self.pool.pages
+        paged = self.pool
+        if rs is not None or cfg.index_row is not None:
+            paged = self.pool.pages  # K and V alone
         self.metrics.kv_bytes_per_token = sum(
             leaf.nbytes for leaf in jax.tree.leaves(paged)
         ) // (n_pages * ps)
+        if cfg.index_row is not None:
+            self.metrics.index_bytes_per_token = (
+                self.pool.idx.nbytes // (n_pages * ps))
+            self.metrics.sparse_topk = cfg.index_topk
+            _LOG.info("index rows: %d values a token and layer, %d bytes a "
+                      "cached token; a query attends to %d tokens at most",
+                      cfg.index_row, self.metrics.index_bytes_per_token,
+                      cfg.index_topk)
         if rs is not None:
             self.metrics.ssm_layers = rs.layers
             self.metrics.ssm_state_bytes_per_slot = rs.bytes_per_slot
@@ -1639,10 +1699,11 @@ class LLMEngine:
         # capacity minus one generated token.
         max_prompt = self.max_pages * self.ecfg.page_size - 1
         if self.cfg.latent_row is not None \
-                or self.cfg.recurrent_state is not None:
+                or self.cfg.recurrent_state is not None \
+                or self.cfg.index_row is not None:
             # the chunked long-prompt lane (a contiguous scratch cache of
-            # K and V per head) has no latent form and carries no
-            # recurrent state from chunk to chunk
+            # K and V per head) has no latent form, carries no recurrent
+            # state from chunk to chunk and holds no index rows
             max_prompt = min(max_prompt, self.buckets[-1])
         if len(req.prompt_ids) > max_prompt:
             if not req.truncate_prompt:
@@ -2119,6 +2180,10 @@ class LLMEngine:
                 host = self._fetch_block_host(fl)
             if self._load_rows and not isinstance(host, tuple):
                 host = self._note_expert_load(fl, host, time.perf_counter())
+            if fl.sparse is not None:
+                self.flight.record_event(EV_SPARSE_SELECT,
+                                         time.perf_counter(),
+                                         a=fl.sparse[0], b=fl.sparse[1])
             # The landed block proves every program enqueued before it
             # complete: their rows resolve now, the block's own with
             # them, before the beat row that reads it.
@@ -2141,6 +2206,28 @@ class LLMEngine:
             self._note_prefill_stalls()
             self._record_beat(fl, fl.t_ready,
                               self.metrics.tokens_out - tokens_before)
+
+    def _note_sparse_select(self, lengths, active_mask, K: int):
+        """A decode block of a model with learned sparse attention, from
+        the lengths the host dispatches it with: every live slot scores
+        all its cached index keys in every layer and step (a slot is one
+        token longer each step) and attends to min(length, topk) of
+        them. Counts them and returns the block's `sparse_select` event
+        (a = keys scored a live slot, step and layer; b = rows attended
+        over keys scored); None for every other model."""
+        if self.cfg.index_row is None:
+            return None
+        live = np.asarray(lengths, np.int64)[np.asarray(active_mask, bool)]
+        ctx = live[None, :] + np.arange(K)[:, None]          # [K, n_live]
+        topk = self.cfg.index_topk
+        scored = int(ctx.sum())
+        attended = int(np.minimum(ctx, topk).sum())
+        L = self.cfg.n_layers
+        self.metrics.sparse_keys_scored += scored * L
+        self.metrics.sparse_rows_attended += attended * L
+        self.metrics.sparse_steps_dense += int((ctx <= topk).sum())
+        return (scored / max(ctx.size, 1),
+                attended / scored if scored else 0.0)
 
     def _note_expert_load(self, fl: _InFlight, host, t_ready: float):
         """A landed decode block of a model with experts carries, below
@@ -2769,7 +2856,8 @@ class LLMEngine:
         all_greedy = bool(all(temps[:n] <= 0.0))
         flags = (True, False, False) if all_greedy else (False, True, True)
         live = bucket  # the rows the program computes of each prompt
-        if self.cfg.latent_row is None and self.cfg.recurrent_state is None:
+        if self.cfg.latent_row is None and self.cfg.recurrent_state is None \
+                and self.cfg.index_row is None:
             live = engine_model.prefill_row_counts(bucket, ps, N)[
                 int(engine_model.prefill_live_index(lengths, bucket, ps))]
         self.metrics.prefill_rows_live += N * live
@@ -3514,6 +3602,7 @@ class LLMEngine:
         if not (plan.spec_k or plan.spec_state) \
                 and self.cfg.latent_row is None \
                 and self.cfg.recurrent_state is None \
+                and self.cfg.index_row is None \
                 and engine_model.direct_qkv(self.cfg, K):
             self.metrics.decode_steps_direct_qkv += K
         if self._load_rows:  # every live slot's token, in every expert block
@@ -3523,6 +3612,7 @@ class LLMEngine:
         if self.cfg.recurrent_state is not None \
                 and kernel_update(self.pool.state, self.use_pallas):
             self.metrics.ssm_steps_kernel += K
+        sparse = self._note_sparse_select(lengths, active_mask, K)
         # every decode program but the verifies writes one row a slot
         if not plan.spec_k and kernel_append(self.pool, self.use_pallas):
             self.metrics.decode_steps_kernel_append += K
@@ -3587,6 +3677,7 @@ class LLMEngine:
             fl.t_dispatch = res.get("t_dispatch") or time.perf_counter()
             fl.plan = plan
             fl.prog = prog
+            fl.sparse = sparse
             self._await_program(prog, block)
             self._inflight.append(fl)
         return True

@@ -28,7 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from generativeaiexamples_tpu.models import hybrid_ssm, latent_moe
+from generativeaiexamples_tpu.models import (
+    hybrid_ssm, latent_moe, sparse_attn_moe)
 from generativeaiexamples_tpu.models.llama import (
     LlamaConfig, attn_out, final_norm, finish_block, project_qkv, rms_norm,
     walk_passes)
@@ -39,6 +40,11 @@ from generativeaiexamples_tpu.serving.kv_cache import (
     PagePool, kernel_live_rows, token_slots)
 from generativeaiexamples_tpu.serving.paged_attention import (
     paged_attention_dispatch)
+from generativeaiexamples_tpu.serving.paged_attention_sparse import (
+    paged_attention_sparse)
+from generativeaiexamples_tpu.serving.sparse_index_scores import (
+    sparse_index_scores)
+from generativeaiexamples_tpu.serving.sparse_select import sparse_select
 from generativeaiexamples_tpu.utils.platform import log_kernel_declined
 
 
@@ -313,6 +319,87 @@ def _hybrid_decode_once(params, cfg, pool, tokens, page_tables, lengths,
     return logits, pool, jnp.stack(counts), jnp.stack(choices)
 
 
+# -- learned sparse attention (models/sparse_attn_moe.py) ------------------
+#
+# A model whose tokens cache an index key beside K and V (cfg.index_row)
+# runs the same four programs over a kv_cache.SparseIndexPool. A prompt
+# is walked in tiles (sparse_attn_moe.sparse_attend_prompt) and its K, V
+# and index keys go to its pages in one write; a decode step, in every
+# layer, appends the three, scores ALL of the slot's cached index keys
+# (serving/sparse_index_scores.py), selects, and attends over what was
+# selected (serving/paged_attention_sparse.py).
+
+
+def _sparse_prefill(params, cfg, pool, tokens, lengths, table_rows,
+                    use_pallas):
+    """Prompts [N, S]: every layer's K, V and index keys go to the rows'
+    pages (a padded row's to the sink). -> (last-position logits [N, V],
+    pool)."""
+    N, S = tokens.shape
+    ps = pool.page_size
+    x, (k, v, ki), _ = sparse_attn_moe.walk_prompt(params, cfg, tokens,
+                                                   lengths, use_pallas)
+
+    def paged(t):  # [L, N, KH, S, Hd] -> [L, KH, N * npages, ps, Hd]
+        L, _, KH, _, Hd = t.shape
+        t = t.reshape(L, N, KH, S // ps, ps, Hd).transpose(0, 2, 1, 3, 4, 5)
+        return t.reshape(L, KH, N * (S // ps), ps, Hd)
+
+    ki = ki.reshape(ki.shape[0], N * (S // ps), ps, -1)
+    pool = pool.write_pages(pool.encode_pages(paged(k), paged(v), ki),
+                            table_rows.reshape(-1))
+    last = jnp.take_along_axis(
+        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
+    return sparse_attn_moe.logits_of(cfg, params, last)[:, 0], pool
+
+
+def _sparse_decode_once(params, cfg, pool, tokens, page_tables, lengths,
+                        use_pallas, mask=None):
+    """_decode_once for a model with learned sparse attention, the blocks
+    unrolled: append K, V and the index key, score the slot's cached index
+    keys, select, attend over the selected tokens. `mask` [B]: the live
+    slots; where the kernels are on they walk those alone, so an idle slot
+    costs no score and no read and its expert pairs are left out. Returns
+    (logits [B, V], pool, pairs each expert took in each block [L, E], the
+    router's choices [L, B, k])."""
+    B = tokens.shape[0]
+    ps = pool.page_size
+    positions = (lengths - 1)[:, None]
+    slots = token_slots(
+        cfg.n_kv_heads, page_tables[jnp.arange(B), (lengths - 1) // ps],
+        (lengths - 1) % ps, use_pallas,
+        live=kernel_live_rows(pool, mask, use_pallas))
+    x = sparse_attn_moe.embed(cfg, params, tokens)[:, None]  # [B, 1, D]
+    sliced, experts = sparse_attn_moe.split_experts(params["layers"])
+    counts, choices = [], []
+    for l in range(cfg.n_layers):
+        w = sparse_attn_moe.take_layer(sliced, l)
+        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
+        q, k, v = sparse_attn_moe.project_qkv(cfg, h, w, positions)
+        qi, ki, wt = sparse_attn_moe.project_index(cfg, h, w, positions)
+        pool = pool.append(l, slots, k[:, :, 0].transpose(1, 0, 2),
+                           v[:, :, 0].transpose(1, 0, 2), ki[:, 0])
+        with jax.named_scope("index.scores"):
+            scores = sparse_index_scores(
+                qi[:, 0], wt[:, 0], pool.idx, l, page_tables, lengths,
+                use_pallas=use_pallas, live=slots.live)
+        with jax.named_scope("index.select"):
+            selected = sparse_select(scores, lengths, cfg.index_topk, ps,
+                                     use_pallas=use_pallas, live=slots.live)
+        with jax.named_scope("attn.sparse"):
+            kv, _, kv_scales, layer = pool.attention_operands(l)
+            out = paged_attention_sparse(
+                q[:, :, 0], kv, kv_scales, page_tables, lengths, selected,
+                layer, use_pallas=use_pallas, live=slots.live)
+        x = sparse_attn_moe.attn_out(cfg, x, out[:, :, None, :], w)
+        x, n, idx = sparse_attn_moe.feed_forward(cfg, x, w, experts, l,
+                                                 use_pallas, mask)
+        counts.append(n)
+        choices.append(idx[:, 0])
+    logits = sparse_attn_moe.logits_of(cfg, params, x)[:, 0]
+    return logits, pool, jnp.stack(counts), jnp.stack(choices)
+
+
 def _expert_decode_once(cfg):
     """The decode body of a model the Llama walk does not run, or None:
     `(params, cfg, pool, tokens, page_tables, lengths, use_pallas, mask)
@@ -321,6 +408,8 @@ def _expert_decode_once(cfg):
         return _latent_decode_once
     if cfg.recurrent_state is not None:
         return _hybrid_decode_once
+    if cfg.index_row is not None:
+        return _sparse_decode_once
     return None
 
 
@@ -413,6 +502,10 @@ def prefill_step(
                                        length[None], table_row, state_slot,
                                        use_pallas)
         return logits[0], pool
+    if cfg.index_row is not None:
+        logits, pool = _sparse_prefill(params, cfg, pool, tokens,
+                                       length[None], table_row, use_pallas)
+        return logits[0], pool
     _, S = tokens.shape
     ps = pool.page_size
     npages = S // ps
@@ -486,6 +579,11 @@ def prefill_batch_step(
     if cfg.recurrent_state is not None:
         logits, pool = _hybrid_prefill(params, cfg, pool, tokens, lengths,
                                        table_rows, state_slots, use_pallas)
+        return sample(logits, sp, key, all_greedy=all_greedy,
+                      any_top_k=any_top_k, any_top_p=any_top_p), pool
+    if cfg.index_row is not None:
+        logits, pool = _sparse_prefill(params, cfg, pool, tokens, lengths,
+                                       table_rows, use_pallas)
         return sample(logits, sp, key, all_greedy=all_greedy,
                       any_top_k=any_top_k, any_top_p=any_top_p), pool
     N, S = tokens.shape

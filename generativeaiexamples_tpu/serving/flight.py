@@ -100,6 +100,13 @@ EV_PROGRAM = 20
 # The first decode block in which a request's slot is live was
 # enqueued: ts = that block's t_enqueue, b = its sequence number.
 EV_DECODE_JOIN = 21
+# Learned sparse attention: one a landed decode block of a model with an
+# indexer (scheduler thread), from the lengths the host dispatched the
+# block with. a = index keys scored a live slot, step and layer (the
+# block's mean context); b = rows attended over keys scored
+# (min(length, topk) / length, summed: 1.0 while every context is
+# within topk).
+EV_SPARSE_SELECT = 22
 
 # Program classes (EV_PROGRAM.code).
 PROG_DECODE = 0    # a decode block (n = steps K)
@@ -126,7 +133,7 @@ EVENT_NAMES = {
     EV_SCALE_WAKE: "scale_wake", EV_UPGRADE: "upgrade",
     EV_CHAOS: "chaos", EV_KV_TRANSFER: "kv_transfer",
     EV_MOE_LOAD: "moe_load", EV_PROGRAM: "program",
-    EV_DECODE_JOIN: "decode_join",
+    EV_DECODE_JOIN: "decode_join", EV_SPARSE_SELECT: "sparse_select",
 }
 
 # Retire reason codes (EV_RETIRE.code); anything unknown maps to -1.

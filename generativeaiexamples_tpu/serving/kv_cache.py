@@ -26,6 +26,12 @@ inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
   inputs of the layer's convolution. A prefill writes a slot's state
   whole; a decode step updates it in place
   (serving/ssm_state_update.py).
+- A model with learned sparse attention (cfg.index_row: an indexer whose
+  key every token caches beside its K and V) gets a SparseIndexPool: the
+  int8 pool of its K and V (`pages`) and a third kind of row under the
+  SAME page table, the index keys, written by the same append and the
+  same page write and freed with the same pages
+  (serving/sparse_index_scores.py reads them).
 - Host: PageAllocator hands out page ids (plain Python free list — the
   scheduler thread owns it; no device sync needed to allocate).
 - Page tables are [B, max_pages] int32 arrays shipped to the device each
@@ -253,6 +259,13 @@ class PagePool:
         recurrent state gets a HybridPool with room for `slots` decode
         slots beside the pages."""
         dtype = jnp.dtype(dtype or cfg.dtype)
+        if cfg.index_row is not None:  # the model says what a token caches
+            if dtype != jnp.int8:
+                raise ValueError(
+                    f"engine.kv_dtype {dtype.name}: the pool of a model "
+                    "with learned sparse attention "
+                    "(kv_cache.SparseIndexPool) holds K and V in int8 only")
+            return SparseIndexPool.zeros(cfg, n_pages, page_size)
         if cfg.recurrent_state is not None:  # the model says what it carries
             if slots is None:
                 raise ValueError("a model with recurrent state keeps it per "
@@ -651,6 +664,92 @@ class HybridPool:
                    None))
 
 
+@dataclasses.dataclass
+class SparseIndexPool:
+    """The pool of a model with learned sparse attention (cfg.index_row):
+    three kinds of row under ONE page table in one donated tree.
+
+    `pages` is the QuantPagePool of K and V (every method it has; the
+    attention kernel serving/paged_attention_sparse.py reads it as
+    paged_attention_int8 does). `idx` [R, P, Di, page_size] bf16 holds the
+    indexer's key of every cached token, a page TRANSPOSED (the values
+    before the tokens): a page is then [Di, 128], whole 128-lane tiles (a
+    token-major [128, 64] page would be padded to 128 lanes and take twice
+    its bytes), contiguous, and the operand `q @ page` takes as it lies.
+    Page p of `idx` belongs to whoever holds page p of `pages`: nothing
+    allocates or frees it apart.
+
+    The lanes that re-read, share, move, snapshot or roll back cache
+    (prefix reuse, the pager, the disaggregated transfer, speculation,
+    the long-prompt scratch cache) would have to carry the index rows too
+    and do not: LLMEngine refuses them by name for such a model."""
+
+    pages: "QuantPagePool"
+    idx: jax.Array
+
+    @property
+    def page_size(self) -> int:
+        return self.pages.page_size
+
+    @property
+    def n_pages(self) -> int:
+        return self.pages.n_pages
+
+    @property
+    def quantized(self) -> bool:
+        return True
+
+    @property
+    def geometry(self) -> PoolGeometry:
+        return self.pages.geometry
+
+    def devices(self):
+        return self.pages.devices()
+
+    def attention_operands(self, row):
+        return self.pages.attention_operands(row)
+
+    def append(self, row, slots, k_new, v_new, ki_new) -> "SparseIndexPool":
+        """QuantPagePool.append (its kernel and live list where they are
+        on) and, with it, the step's new index keys `ki_new` [B, Di] at
+        the same (page, offset): each slot's page is read, the one column
+        patched and the page written back whole, B index tuples where a
+        scatter of single values would take B x Di. An idle slot's page is
+        the sink."""
+        pages = self.pages.append(row, slots, k_new, v_new)
+        page = self.idx[row, slots.page_idx]              # [B, Di, ps]
+        at = jnp.arange(page.shape[-1])[None, None, :] \
+            == slots.offset[:, None, None]
+        page = jnp.where(at, ki_new.astype(page.dtype)[:, :, None], page)
+        return SparseIndexPool(pages,
+                               self.idx.at[row, slots.page_idx].set(page))
+
+    def encode_pages(self, k, v, ki):
+        """K and V ([..., Hd]) and index keys [..., ps, Di] as
+        `write_pages` stores them."""
+        return self.pages.encode_pages(k, v) + (
+            jnp.swapaxes(ki, -1, -2).astype(self.idx.dtype),)
+
+    def write_pages(self, pages, table_flat) -> "SparseIndexPool":
+        """QuantPagePool.write_pages' four and the index pages
+        [R, M, Di, ps] into pages `table_flat` [M]."""
+        *kv, ki = pages
+        li = jnp.arange(ki.shape[0])[:, None]
+        return SparseIndexPool(
+            self.pages.write_pages(tuple(kv), table_flat),
+            self.idx.at[li, table_flat[None, :]].set(ki))
+
+    @staticmethod
+    def zeros(cfg, n_pages: int, page_size: int) -> "SparseIndexPool":
+        return SparseIndexPool(
+            QuantPagePool.zeros(cfg, n_pages, page_size),
+            _alloc((cfg.cache_rows, n_pages, cfg.index_row, page_size),
+                   jnp.bfloat16, None))
+
+
+jax.tree_util.register_dataclass(
+    SparseIndexPool, data_fields=["pages", "idx"], meta_fields=[]
+)
 jax.tree_util.register_dataclass(
     HybridPool, data_fields=["pages", "state", "tail"], meta_fields=[]
 )

@@ -164,7 +164,8 @@ def _shard_numel(shape, spec, axis_sizes: Dict[str, int]) -> int:
 
 def _one_chip(lcfg) -> bool:
     """A model whose parameters have no sharded layout."""
-    return lcfg.latent_row is not None or lcfg.recurrent_state is not None
+    return lcfg.latent_row is not None or lcfg.recurrent_state is not None \
+        or lcfg.index_row is not None
 
 
 def state_pool_bytes_per_device(lcfg, ecfg: EngineConfig) -> int:
@@ -188,16 +189,20 @@ def weight_bytes_per_device(lcfg: LlamaConfig, axis_sizes: Dict[str, int],
     from generativeaiexamples_tpu.ops.quant import LLAMA_QUANT_KEYS
 
     if _one_chip(lcfg):
-        # models/latent_moe.py, models/hybrid_ssm.py: whole on one chip
-        # (a share of the experts is the configuration's, not a mesh
-        # axis's)
+        # models/latent_moe.py, models/hybrid_ssm.py,
+        # models/sparse_attn_moe.py: whole on one chip (a share of the
+        # experts is the configuration's, not a mesh axis's)
         if any(int(n) > 1 for n in axis_sizes.values()):
             raise MemoryPlanError(
-                "a model with latent attention or recurrent state has no "
-                f"tensor-parallel layout: mesh axes {axis_sizes}")
-        from generativeaiexamples_tpu.models import hybrid_ssm, latent_moe
+                "a model with latent attention, recurrent state or an "
+                f"indexer has no tensor-parallel layout: mesh axes "
+                f"{axis_sizes}")
+        from generativeaiexamples_tpu.models import (
+            hybrid_ssm, latent_moe, sparse_attn_moe)
 
-        model = latent_moe if lcfg.latent_row is not None else hybrid_ssm
+        model = (latent_moe if lcfg.latent_row is not None
+                 else hybrid_ssm if lcfg.recurrent_state is not None
+                 else sparse_attn_moe)
         shapes = jax.eval_shape(lambda: model.init_params_on_device(
             lcfg, quantize=quantize))
         return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
@@ -250,6 +255,11 @@ def pool_page_bytes_per_device(lcfg: LlamaConfig, ecfg: EngineConfig,
     tp = int(axis_sizes.get("tensor", 1))
     kh = math.ceil(lcfg.n_kv_heads / tp)
     base = lcfg.cache_rows * kh * ps  # a row per (pass, block)
+    if lcfg.index_row is not None:
+        # kv_cache.SparseIndexPool: the int8 K and V and a bf16 index key
+        # a token and row
+        return 2 * base * lcfg.head_dim + 2 * base * 4 \
+            + lcfg.cache_rows * ps * lcfg.index_row * 2
     if jnp.dtype(ecfg.kv_dtype) == jnp.int8:
         return 2 * base * lcfg.head_dim + 2 * base * 4
     return 2 * base * lcfg.head_dim * jnp.dtype(ecfg.kv_dtype).itemsize
@@ -279,7 +289,9 @@ def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
     tokens = n_seq * bucket
     # (a state-space model's widest activation is its mixer's input
     # projection, which plays the feed-forward's part here)
-    mlp = math.ceil(getattr(lcfg, "mlp_dim", 0) / tp) or 2 * lcfg.d_inner
+    mlp = math.ceil(getattr(lcfg, "mlp_dim", 0) / tp) \
+        or 2 * getattr(lcfg, "d_inner", 0) \
+        or 2 * lcfg.moe_mlp_dim * lcfg.n_experts_per_tok
     acts = tokens * (4 * lcfg.dim + 2 * mlp) * wsize
     logits = n_seq * math.ceil(lcfg.vocab_size / tp) * 4
     return (

@@ -29,6 +29,12 @@ from generativeaiexamples_tpu.serving.paged_attention_int8 import (
     live_rows, paged_attention_int8)
 from generativeaiexamples_tpu.serving.paged_attention_mla import (
     paged_attention_mla)
+from generativeaiexamples_tpu.serving.paged_attention_sparse import (
+    paged_attention_sparse_pallas)
+from generativeaiexamples_tpu.serving.sparse_index_scores import (
+    sparse_index_scores_pallas)
+from generativeaiexamples_tpu.serving.sparse_select import (
+    sparse_select_pallas)
 from generativeaiexamples_tpu.serving.paged_attention_tree import (
     paged_tree_attention)
 from generativeaiexamples_tpu.serving.ssm_state_update import (
@@ -187,6 +193,43 @@ KERNELS = {
         lambda *a: _grouped(32, *a),
         [((960 + 72 * 32, 768), BF16), ((10, 72, 768, 4096), I8),
          ((10, 72, 4096), F32), ((102,), I32), ((1,), I32)]),
+    # Keye-VL-2.0-30B-A3B's stage
+    # (benchmark/configs/keye-vl-2.0-30b-a3b-int8.json): 16 slots, tables
+    # of 152 pages, 2,688 pages of 12 rows; a decode step's three kernels
+    # with the step's mask, as _sparse_decode_once calls them: the index
+    # scores over the transposed bf16 index pages, the selection of 2,048
+    # of 19,456, the walk of the int8 pool under the selection's mask
+    "sparse_index_scores_masked_tables_of_152": (
+        lambda q, w, idx, t, ln, m: sparse_index_scores_pallas(
+            q, w, idx, 7, t, ln, live_rows(m)),
+        [((16, 16, 64), BF16), ((16, 16), F32), ((12, 2688, 64, PS), BF16),
+         ((16, 152), I32), ((16,), I32), ((16,), jnp.bool_)]),
+    "sparse_select_masked_2048_of_19456": (
+        lambda sc, ln, m: sparse_select_pallas(sc, ln, live_rows(m),
+                                               topk=2048),
+        [((16, 152, PS), F32), ((16,), I32), ((16,), jnp.bool_)]),
+    "paged_attention_sparse_masked_tables_of_152": (
+        lambda q, kv, s, t, ln, sel, m: paged_attention_sparse_pallas(
+            q, kv, s, t, ln, sel, 7, live_rows(m)),
+        [((16, 32, HD), BF16), ((2, 12, 4, 2688, PS, HD), I8),
+         ((2, 12, 4, 2688, PS), F32), ((16, 152), I32), ((16,), I32),
+         ((16, 152 * PS), jnp.bool_), ((16,), jnp.bool_)]),
+    # and the grouped matmul's third shape, 128 whole experts of 768: a
+    # decode step's 128 pairs (one a hit expert: tiles mostly padding),
+    # and a prompt's 4,096 tokens in tiles of 64
+    # (sparse_attn_moe.PREFILL_TILE_ROWS, PREFILL_MOE_ROWS)
+    "grouped_expert_matmul_decode_128_of_768": (
+        lambda *a: _grouped(32, *a),
+        [((128 + 128 * 32, 2048), BF16), ((12, 128, 2048, 1536), I8),
+         ((12, 128, 1536), F32), ((132,), I32), ((1,), I32)]),
+    "grouped_expert_matmul_prefill_128_of_768": (
+        lambda *a: _grouped(64, *a),
+        [((32768 + 128 * 64, 2048), BF16), ((12, 128, 2048, 1536), I8),
+         ((12, 128, 1536), F32), ((640,), I32), ((1,), I32)]),
+    "grouped_expert_matmul_prefill_down_128_of_768": (
+        lambda *a: _grouped(64, *a),
+        [((32768 + 128 * 64, 768), BF16), ((12, 128, 768, 2048), I8),
+         ((12, 128, 2048), F32), ((640,), I32), ((1,), I32)]),
 }
 
 
@@ -592,3 +635,49 @@ def test_a_latent_models_programs_lower_to_the_text_the_parent_did(chip):
         v.as_text()).encode()).hexdigest()[:16]
         for k, v in _latent_lowered(chip).items()}
     assert got == PARENT_LATENT, json.dumps(got)
+
+
+# -- learned sparse attention: the configuration's own shapes (PR 42) -------
+# The decode program of `keye-vl-2.0-30b-a3b-int8`, lowered for the chip
+# from its architecture entry's `compile_shapes` with kernels on: every
+# layer appends through the int8 pool's kernel and runs the three new ones,
+# each under the name the benchmark's readers look for, and the attention's
+# name is not the index kernel's (`trace_kernel` matches by substring).
+def test_the_sparse_decode_program_runs_its_kernels_under_their_names(chip):
+    import json
+
+    from benchmark import architectures
+    from benchmark.harness import system
+    from generativeaiexamples_tpu.serving import engine_model as em
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye-vl-2.0-30b-a3b-int8.json")) as fh:
+        config = json.load(fh)
+    ecfg = system.engine_config(config)
+    mcfg, params, pool, mesh = architectures.load(config).compile_shapes(
+        config, ecfg, [next(iter(chip.device_set))])
+    assert mesh is None and mcfg.index_row == 64
+    assert pool.idx.shape == (12, 2688, 64, 128)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, maxp = ecfg.max_batch_size, ecfg.max_seq_len // ecfg.page_size
+    assert (slots, maxp) == (16, 152)
+    text = em.decode_multi_step.lower(
+        params, mcfg, pool, arr((slots,), I32), arr((slots, maxp), I32),
+        arr((slots,), I32), arr((slots,), jnp.bool_), arr((slots,), F32),
+        arr((slots,), F32), arr((slots,), I32), arr((2,), jnp.uint32), 1,
+        True, sampling_flags=(True, False, False)).as_text()
+    # (the three are jitted functions of their own: one body each in the
+    # text, called once a layer; the grouped matmul is inlined, two a layer)
+    for kernel, bodies in (("sparse_index_scores", 1), ("sparse_select", 1),
+                           ("paged_attention_sparse", 1),
+                           ("kv_append_int8", 1),
+                           ("moe_grouped_matmul_int8", 24)):
+        assert text.count(f'kernel_name = "{kernel}"') == bodies, kernel
+    for fn in ("sparse_index_scores_pallas", "sparse_select_pallas",
+               "paged_attention_sparse_pallas"):
+        assert text.count(f"call @{fn}") == 12, fn
+    assert "paged_attention" not in "sparse_index_scores sparse_select"

@@ -646,19 +646,25 @@ class TestInjectedStall:
                              logger=engine_mod._LOG.name):
             drive_inline(eng, [req])
         assert len(tokens(req)) == 30
-        assert eng.metrics.snapshot()["program_stalls"] == before + 1
+        # every stall is counted once and logged once ...
+        stalls = eng.metrics.snapshot()["program_stalls"] - before
         lines = [r.getMessage() for r in caplog.records
                  if "device program stalled" in r.getMessage()]
-        assert len(lines) == 1
-        assert "class=decode" in lines[0] and "shape=K2" in lines[0]
+        assert len(lines) == stalls >= 1
+        # ... and the late program is one of them, once. (Held on ITS row
+        # alone: while other workers load the host, any other program of
+        # these few milliseconds that runs over 8 x its class's median
+        # counts too, and rightly.)
         rows = program_rows(eng)
         first = int(events(eng, EV_PREFILL_DISPATCH)[-1]["b"])
         rows = {s: r for s, r in rows.items() if s >= first}
         longest = max(rows.values(), key=lambda r: r[0]["b"])[0]
         assert 400.0 <= longest["b"] < 1500.0
         seq = int(parse_program_aux(longest["aux"])["seq"])
-        assert f"seq={seq} " in lines[0]
-        assert f"b={longest['b']:.1f} ms" in lines[0]
+        mine = [line for line in lines if f"seq={seq} " in line]
+        assert len(mine) == 1
+        assert "class=decode" in mine[0] and "shape=K2" in mine[0]
+        assert f"b={longest['b']:.1f} ms" in mine[0]
         # the block behind the late one waited, and ran its own time
         nxt = rows[seq + 1][0]
         assert nxt["b"] < 200.0

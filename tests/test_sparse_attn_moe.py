@@ -1,0 +1,533 @@
+"""Learned sparse attention over whole experts (models/sparse_attn_moe.py,
+serving/sparse_index_scores.py, serving/sparse_select.py,
+serving/paged_attention_sparse.py, kv_cache.SparseIndexPool) at a tiny size
+on the CPU, seeded weights, against the benchmark's plain reference
+(benchmark/architectures/keyevl2.py: the whole [S, S] score matrix, top_k a
+row, a dense masked softmax; no code shared with the program).
+
+Selection is a discontinuity, so the comparison has three parts, each with
+its tolerance and the reason for it beside it (SCORE_TOL, the sets, MEDIAN_TOL
+/ FLIP_TOL), and a negative control: the reference with selection switched
+off must MISS what the program gives past `topk`."""
+
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.architectures import keyevl2 as ref
+from benchmark.tests.test_keyevl2 import tiny_file
+from generativeaiexamples_tpu.config.schema import EngineConfig
+from generativeaiexamples_tpu.models import llama
+from generativeaiexamples_tpu.models import sparse_attn_moe as sm
+from generativeaiexamples_tpu.ops import moe
+from generativeaiexamples_tpu.serving import engine_model as em
+from generativeaiexamples_tpu.serving import memory_plan
+from generativeaiexamples_tpu.serving.engine import LLMEngine
+from generativeaiexamples_tpu.serving.kv_cache import (
+    PagePool, QuantPagePool, SparseIndexPool)
+from generativeaiexamples_tpu.serving.paged_attention_int8 import (
+    live_rows, quantize_kv)
+from generativeaiexamples_tpu.serving.paged_attention_sparse import (
+    paged_attention_sparse, paged_attention_sparse_pallas)
+from generativeaiexamples_tpu.serving.sparse_index_scores import (
+    sparse_index_scores, sparse_index_scores_pallas)
+from generativeaiexamples_tpu.serving.sparse_select import (
+    sparse_select, sparse_select_pallas)
+
+PS = 8
+FILE = tiny_file()
+CFG = ref.model_config(FILE)
+TOPK = CFG.index_topk  # 16
+
+# (a) The program's index scores against the reference's, elementwise, as
+# a share of the largest score of the matrix: the program multiplies bf16
+# queries and keys (2^-9 a value, 8 products a head, 4 heads) where the
+# reference keeps float32; 0.0041 is the most these seeds read.
+SCORE_TOL = 0.006
+# (c) Logits. The contiguous forward differs from the reference by the
+# index key's bf16 alone; through the cache K and V are int8 besides (one
+# scale a head and token, 0.4 % a value), which moves a deeper layer's
+# scores and now and then flips a near-tie of the selection or of the
+# router. A flipped token is one of 16 here and a flipped expert one of 2
+# (one of 2,048 and of 8 at the published size), so a row that holds a
+# flip may miss by half the largest logit while the MEDIAN row agrees to a
+# few thousandths: the comparison holds the median row to MEDIAN_TOL and
+# the SHARE of rows further off than FLIP_TOL to FLIP_SHARE. All are
+# shares of the largest logit.
+MEDIAN_TOL = 0.02
+FLIP_TOL = 0.10
+FLIP_SHARE = 0.15
+
+
+def _logits_hold(rel):
+    rel = np.asarray(rel)
+    return bool(np.median(rel) <= MEDIAN_TOL
+                and np.mean(rel > FLIP_TOL) <= FLIP_SHARE)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return sm.init_params_on_device(CFG, 7, quantize=True)
+
+
+def prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(1, 512, n).astype(np.int32)
+
+
+def _rel(got, want):
+    """Per-row largest difference as a share of the largest logit."""
+    return np.abs(np.asarray(got) - np.asarray(want)).max(-1) \
+        / np.abs(np.asarray(want)).max()
+
+
+# -- the kernels: the XLA form against the Pallas form, interpreted ---------
+
+def _paged_case(seed, lengths, mask, B=5, L=3, P=70, maxp=12):
+    rng = np.random.default_rng(seed)
+    Hi, Di, KH, H, Hd = 4, 8, 2, 4, 16
+    table = rng.permutation(np.arange(1, P))[:B * maxp].reshape(B, maxp)
+    return dict(
+        idx=jnp.asarray(rng.normal(size=(L, P, Di, PS)), jnp.bfloat16),
+        q=jnp.asarray(rng.normal(size=(B, Hi, Di)), jnp.bfloat16),
+        w=jnp.asarray(rng.normal(size=(B, Hi)), jnp.float32),
+        kv=quantize_kv(jnp.asarray(
+            rng.normal(size=(2, L, KH, P, PS, Hd)), jnp.float32)),
+        qa=jnp.asarray(rng.normal(size=(B, H, Hd)), jnp.float32),
+        table=jnp.asarray(table, jnp.int32),
+        lengths=jnp.asarray(lengths, jnp.int32),
+        live=None if mask is None else live_rows(jnp.asarray(mask)))
+
+
+CASES = {
+    "every-slot": ((1, 37, 96, 64, 9), None),
+    "an-idle-slot": ((1, 37, 96, 64, 9), (True, True, True, False, True)),
+    "one-live": ((5, 5, 80, 5, 5), (False, False, True, False, False)),
+    "nobody": ((1, 1, 1, 1, 1), (False,) * 5),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_three_kernels_are_their_xla_forms(case):
+    """Index scores, selection and the selected attention, each in both
+    forms on the same pool: pages in any order, a length of one, a full
+    table, ties, an idle slot among live ones (never walked, and what it
+    leaves is nothing scored, nothing selected, zeros attended)."""
+    lengths, mask = CASES[case]
+    c = _paged_case(len(case), lengths, mask)
+    want = sparse_index_scores(c["q"], c["w"], c["idx"], 1, c["table"],
+                               c["lengths"], use_pallas=False, live=c["live"])
+    got = sparse_index_scores_pallas(c["q"], c["w"], c["idx"], 1, c["table"],
+                                     c["lengths"], c["live"], interpret=True)
+    assert np.array_equal(np.isfinite(want), np.isfinite(got))
+    np.testing.assert_allclose(np.where(np.isfinite(want), got, 0),
+                               np.where(np.isfinite(want), want, 0),
+                               rtol=1e-5, atol=1e-6)
+    n = np.asarray(c["lengths"])
+    live = np.ones(5, bool) if mask is None else np.asarray(mask)
+    assert np.array_equal(np.isfinite(want).sum(-1), n * live)
+    # ties at the threshold: three equal scores in the longest slot
+    want = want.at[2, 5].set(want[2, 7]).at[2, 9].set(want[2, 7])
+    for topk in (3, TOPK, 200):
+        a = sparse_select(want, c["lengths"], topk, PS, use_pallas=False,
+                          live=c["live"])
+        b = sparse_select_pallas(want.reshape(5, -1, PS), c["lengths"],
+                                 c["live"], topk=topk, interpret=True)
+        assert np.array_equal(a, np.asarray(b).reshape(5, -1) > 0.5), topk
+        assert np.array_equal(a.sum(-1), np.minimum(n, topk) * live)
+    sel = sparse_select(want, c["lengths"], TOPK, PS, use_pallas=False,
+                        live=c["live"])
+    kv, s = c["kv"]
+    want_o = paged_attention_sparse(c["qa"], kv, s, c["table"], c["lengths"],
+                                    sel, 1, use_pallas=False, live=c["live"])
+    got_o = paged_attention_sparse_pallas(c["qa"], kv, s, c["table"],
+                                          c["lengths"], sel, 1, c["live"],
+                                          interpret=True)
+    np.testing.assert_allclose(got_o, want_o, rtol=1e-5, atol=1e-6)
+    assert not np.asarray(want_o)[~live].any()
+
+
+@pytest.mark.parametrize("scores,topk", [
+    ([1., 2, 2, 2, 0, 2, 5, -1], 3),      # ties at the threshold
+    ([0., -0., 0, 0, -0., 0], 2),         # zeros of both signs are one value
+    ([3., 1, 2], 5),                      # fewer than topk: all of them
+    ([-1., -3, -2, -2, -7], 3),           # negative scores
+    ([4., 4, 4, 4], 4),
+])
+def test_a_tie_goes_to_the_earlier_token_as_top_k_orders_them(scores, topk):
+    s = jnp.asarray([scores], jnp.float32) + 0.0
+    got = np.nonzero(np.asarray(sm.select_mask(
+        s, jnp.ones_like(s, bool), topk))[0])[0]
+    _, want = jax.lax.top_k(s[0], min(topk, len(scores)))
+    assert sorted(got.tolist()) == sorted(np.asarray(want).tolist())
+    # and a token outside `valid` is never taken, whatever its score
+    valid = jnp.arange(len(scores))[None, :] != int(np.argmax(scores))
+    assert not np.asarray(sm.select_mask(s, valid, topk))[
+        0, int(np.argmax(scores))]
+
+
+# -- the program's forward against the plain reference ----------------------
+
+@pytest.mark.parametrize("n", [12, 40, 96])
+def test_forward_against_the_reference_in_three_parts(params, n):
+    ids = prompt(n, seed=n)
+    want, kept, choice = ref.reference_forward(FILE, params, ids,
+                                               keep_layers=(0,))
+    got, mine = sm.forward(params, CFG, jnp.asarray(ids)[None],
+                           use_pallas=False)
+    # (a) layer 0's index scores, whose inputs are the same on both sides
+    w0 = sm.take_layer(sm.split_experts(params["layers"])[0], 0)
+    x = sm.embed(CFG, params, jnp.asarray(ids)[None])
+    h = llama.rms_norm(x, w0["ln1"], CFG.rms_eps).astype(CFG.dtype)
+    qi, ki, wt = sm.project_index(CFG, h, w0, jnp.arange(n)[None])
+    scores = np.asarray(sm.index_scores(qi, wt, ki)[0])
+    ref_scores, ref_sets = (np.asarray(t) for t in kept[0])
+    causal = np.tril(np.ones((n, n), bool))
+    top = np.abs(ref_scores[causal]).max()
+    assert np.abs(scores - ref_scores)[causal].max() <= SCORE_TOL * top
+    # (b) the selected sets: equal wherever the reference's gap between
+    # its topk-th and next score exceeds (a)'s tolerance twice over; the
+    # rows where it does not are counted, not hidden
+    valid = jnp.asarray(causal)[None]
+    sets = np.asarray(sm.select_mask(jnp.asarray(scores)[None], valid,
+                                     TOPK))[0]
+    ordered = -np.sort(-np.where(causal, ref_scores, -np.inf), axis=-1)
+    close = np.zeros(n, bool)
+    if n > TOPK:
+        gap = ordered[TOPK:, TOPK - 1] - ordered[TOPK:, TOPK]
+        close[TOPK:] = gap <= 2 * SCORE_TOL * top
+    assert np.array_equal(sets[~close], ref_sets[~close])
+    assert np.array_equal(sets.sum(-1), np.minimum(np.arange(n) + 1, TOPK))
+    # (scores of 8-value keys lie close: the share of rows past topk whose
+    # margin is inside the tolerance is large here, 0.3 to 0.6; what a
+    # close row may differ by is the tokens at the margin)
+    if n > TOPK:
+        print(f"rows past topk inside the margin: {close[TOPK:].mean():.2f}")
+        assert (~close[TOPK:]).sum() >= 5
+        assert (sets != ref_sets).sum(-1).max() <= 6
+    # (c) the logits, and every router's top-2 set on the median row
+    rel = _rel(got[0], want)
+    assert _logits_hold(rel), (np.median(rel), np.mean(rel > FLIP_TOL))
+    agree = (np.sort(np.asarray(mine)[:, 0], -1)
+             == np.sort(np.asarray(choice), -1)).all(-1).mean()
+    assert agree > 0.9, agree
+
+
+def test_the_dense_reference_misses_past_topk(params):
+    """The negative control: with selection switched off the reference is
+    another model from position topk on, by more than (c)'s tolerance on
+    the median row, and the same one before it."""
+    ids = prompt(96, seed=3)
+    got, _ = sm.forward(params, CFG, jnp.asarray(ids)[None], use_pallas=False)
+    dense = ref.reference_forward(FILE, params, ids, sparse=False)[0]
+    rel = _rel(got[0], dense)
+    assert rel[:TOPK].max() <= MEDIAN_TOL
+    assert np.median(rel[TOPK:]) > 5 * MEDIAN_TOL, np.median(rel[TOPK:])
+    assert (rel[TOPK:] > MEDIAN_TOL).mean() > 0.9
+
+
+# -- prefill, then decode, through the pool ---------------------------------
+
+def _table(rows, maxp=16):
+    """Slot b's pages: distinct, never page 0."""
+    t = np.zeros((len(rows), maxp), np.int32)
+    for b, n in enumerate(rows):
+        t[b, :n] = 1 + b * maxp + np.arange(n)
+    return t
+
+
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["xla", "kernels-declined"])
+def test_prefill_then_decode_is_the_contiguous_forward(params, use_pallas):
+    """Padded prompts (13 tokens in a bucket of 32, 27 in 32) into slots 1
+    and 2, slots 0 and 3 idle beside them; then 24 decode steps under the
+    block's mask, which carry slot 1 across topk (14 -> 38 tokens). With
+    pages of 8 the kernels decline themselves (their tiles are 128 wide)
+    and the program is the XLA form either way."""
+    a, b = prompt(40, seed=1), prompt(60, seed=2)
+    want_a, _ = sm.forward(params, CFG, jnp.asarray(a)[None])
+    want_b, _ = sm.forward(params, CFG, jnp.asarray(b)[None])
+    pool = PagePool.zeros(CFG, 80, PS, dtype="int8")
+    assert isinstance(pool, SparseIndexPool)
+    table = _table([0, 8, 8, 0])   # an idle slot's row points at the sink
+    for slot, (ids, n) in ((1, (a, 13)), (2, (b, 27))):
+        toks = np.zeros((1, 32), np.int32)
+        toks[0, :n] = ids[:n]
+        logits, pool = em.prefill_step(
+            params, CFG, pool, jnp.asarray(toks), jnp.int32(n),
+            jnp.asarray(table[slot, :4]), use_pallas)
+        want = (want_a if slot == 1 else want_b)[0, n - 1]
+        assert _rel(logits, want) <= MEDIAN_TOL  # prefill reads no int8
+    # the padded rows wrote nothing a later read sees: page 0 is the sink
+    lengths = np.asarray([1, 14, 28, 1], np.int32)
+    active = jnp.asarray([False, True, True, False])
+    last = jnp.asarray([0, a[13], b[27], 0], jnp.int32)
+    greedy = (True, False, False)
+    rel = []
+    for _ in range(24):   # teacher forced, step by step
+        # the logits of a step on a copy, every slot computed ...
+        logits, _ = em.decode_step(
+            params, CFG, jax.tree.map(jnp.copy, pool), last,
+            jnp.asarray(table), jnp.asarray(lengths), use_pallas)
+        # ... and the step itself with the block's mask: idle slots 0 and 3
+        toks, _, pool = em.decode_multi_step(
+            params, CFG, pool, last, jnp.asarray(table),
+            jnp.asarray(lengths), active, jnp.zeros(4), jnp.ones(4),
+            jnp.zeros(4, jnp.int32), jax.random.PRNGKey(0), 1, use_pallas,
+            sampling_flags=greedy)
+        assert toks.shape == (4 + em.expert_load_rows(CFG), 2)
+        assert [int(t) for t in toks[1:3, 1]] == [
+            int(t) for t in jnp.argmax(logits[1:3], -1)]
+        assert int(toks[4:, 1].sum()) == 2 * 3 * 2  # two live slots' pairs
+        rel += [_rel(logits[1], want_a[0, lengths[1] - 1]),
+                _rel(logits[2], want_b[0, lengths[2] - 1])]
+        last = jnp.asarray([0, a[lengths[1]], b[lengths[2]], 0], jnp.int32)
+        lengths = lengths + np.asarray([0, 1, 1, 0], np.int32)
+    assert _logits_hold(rel), (np.median(rel), np.mean(np.asarray(rel)
+                                                       > FLIP_TOL))
+    # only the sink and the live slots' pages hold anything
+    held = np.nonzero(np.asarray(pool.idx).any(axis=(0, 2, 3)))[0]
+    assert set(held) <= {0} | set(table[1]) | set(table[2])
+
+
+def test_a_context_crosses_topk_inside_a_decode_block(params):
+    """One block of four steps from 14 cached tokens: steps at 15 and 16
+    attend to everything, 17 and 18 select; the block's tokens are the
+    four single steps'."""
+    ids = prompt(14, seed=5)
+    pool = PagePool.zeros(CFG, 40, PS, dtype="int8")
+    table = _table([4])
+    toks = np.zeros((1, 32), np.int32)
+    toks[0, :14] = ids
+    logits, pool = em.prefill_step(params, CFG, pool, jnp.asarray(toks),
+                                   jnp.int32(14), jnp.asarray(table[0, :4]),
+                                   False)
+    first = jnp.argmax(logits)[None].astype(jnp.int32)
+    args = (jnp.asarray(table), jnp.asarray([15], jnp.int32),
+            jnp.asarray([True]), jnp.zeros(1), jnp.ones(1),
+            jnp.zeros(1, jnp.int32), jax.random.PRNGKey(0))
+    copy = jax.tree.map(jnp.copy, pool)
+    block, _, _ = em.decode_multi_step(params, CFG, copy, first, *args, 4,
+                                       False,
+                                       sampling_flags=(True, False, False))
+    seq, tok, n = [], first, 15
+    for _ in range(4):
+        logits, pool = em.decode_step(params, CFG, pool, tok,
+                                      jnp.asarray(table),
+                                      jnp.asarray([n], jnp.int32), False)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        seq.append(int(tok[0]))
+        n += 1
+    assert [int(t) for t in block[0, 1:]] == seq
+
+
+def test_the_pool_writes_all_three_rows_with_one_append():
+    pool = PagePool.zeros(CFG, 6, PS, dtype="int8")
+    assert isinstance(pool.pages, QuantPagePool) and pool.quantized
+    assert pool.idx.shape == (3, 6, CFG.index_row, PS)
+    assert pool.idx.dtype == jnp.bfloat16 and pool.geometry.rows == 3
+    from generativeaiexamples_tpu.serving.kv_cache import token_slots
+    slots = token_slots(2, jnp.asarray([2, 4]), jnp.asarray([3, 0]), False)
+    rng = np.random.default_rng(0)
+    k, v = (jnp.asarray(rng.normal(size=(2, 2, 16)), jnp.float32)
+            for _ in range(2))
+    ki = jnp.asarray(rng.normal(size=(2, 8)), jnp.float32)
+    new = pool.append(1, slots, k, v, ki)
+    assert np.asarray(new.pages.kv[:, 1, :, 2, 3]).any()
+    got = np.asarray(new.idx[1, 2, :, 3], np.float32)
+    np.testing.assert_array_equal(got, np.asarray(ki[0].astype(jnp.bfloat16),
+                                                  np.float32))
+    only = np.zeros(new.idx.shape, bool)
+    only[1, 2, :, 3] = only[1, 4, :, 0] = True
+    assert not np.asarray(new.idx)[~only].any()
+    with pytest.raises(ValueError, match="int8 only"):
+        PagePool.zeros(CFG, 6, PS, dtype="bfloat16")
+
+
+def test_dispatch_plan_with_every_expert_held(params):
+    """dispatch_plan(local, n): no pair falls elsewhere, every token's
+    pairs are computed, and a masked token takes none."""
+    _, experts = sm.split_experts(params["layers"])
+    w = sm.take_layer(sm.split_experts(params["layers"])[0], 1)
+    h = jnp.asarray(np.random.default_rng(0).normal(size=(19, 64)),
+                    jnp.float32)
+    y, counts, idx = sm.moe_branch(CFG, h, w, experts, 1, False)
+    assert int(counts.sum()) == 19 * 2 and counts.shape == (8,)
+    plan = moe.dispatch_plan(idx, 8)
+    assert int((plan.pos < plan.rows.shape[0]).sum()) == 19 * 2
+    logits = np.asarray(h) @ np.asarray(w["router"], np.float32)
+    top = np.sort(np.argsort(-logits, -1)[:, :2], -1)
+    np.testing.assert_array_equal(np.sort(np.asarray(idx), -1), top)
+    assert np.isfinite(np.asarray(y)).all()
+    _, counts, _ = sm.moe_branch(CFG, h, w, experts, 1, False,
+                                 jnp.arange(19) < 7)
+    assert int(counts.sum()) == 7 * 2
+
+
+# -- the engine ---------------------------------------------------------------
+
+def _engine(params, **over):
+    from benchmark.harness import system
+    from benchmark.harness.bench_tokenizer import WordTokenizer
+
+    ecfg = dataclasses.replace(system.engine_config(FILE), **over)
+    return LLMEngine(params, CFG, WordTokenizer(512), ecfg, n_pages=64)
+
+
+def _greedy_ok(params, ids, served):
+    """The served greedy tokens against the contiguous forward, teacher
+    forced (benchmark/harness/reference.py's comparison): each is the
+    forward's choice up to a near-tie."""
+    seq = list(ids) + list(served)
+    logits, _ = sm.forward(params, CFG, jnp.asarray([seq[:-1]], jnp.int32),
+                           use_pallas=False)
+    rows = np.asarray(logits[0, len(ids) - 1:])
+    short = (rows.max(-1) - rows[np.arange(len(served)), served]) \
+        / np.abs(rows).max(-1)
+    return short.max() <= 0.05
+
+
+def test_the_engine_serves_the_forwards_tokens_and_counts(params):
+    eng = _engine(params)
+    assert isinstance(eng.pool, SparseIndexPool)
+    eng.start()
+    try:
+        ids = [int(t) for t in prompt(11, seed=9)]
+        served = [ev["token_id"] for ev in eng.generate_stream(
+            ids, max_new_tokens=12, temperature=0.0)]
+    finally:
+        eng.stop()
+    assert len(served) == 12 and _greedy_ok(params, ids, served)
+    snap = eng.metrics.snapshot()
+    assert snap["experts_held"] == 8 and snap["kv_cache_rows"] == 3
+    assert snap["kv_bytes_per_token"] == 3 * 2 * 2 * (16 + 4)   # int8 + f32
+    assert snap["index_bytes_per_token"] == 3 * 8 * 2           # bf16
+    assert snap["sparse_topk"] == TOPK
+    steps = snap["decode_steps"]
+    assert snap["moe_pairs_routed"] == steps * 3 * 2
+    assert 0 < snap["moe_pairs_local"] <= snap["moe_pairs_routed"]
+    # a slot of 12 cached tokens is one longer every step: every key of
+    # every layer scored, min(length, 16) attended
+    ctx = 12 + np.arange(steps)
+    assert snap["sparse_keys_scored"] == 3 * ctx.sum()
+    assert snap["sparse_rows_attended"] == 3 * np.minimum(ctx, TOPK).sum()
+    assert snap["sparse_steps_dense"] == (ctx <= TOPK).sum() > 0
+    events = [e for e in eng.flight.snapshot_events() if e["kind"] == 22]
+    assert events and events[0]["b"] == 1.0 and events[-1]["b"] < 1.0
+    assert events[0]["a"] == pytest.approx(np.mean(ctx[:2]))
+    from generativeaiexamples_tpu.serving import flight
+    assert flight.EVENT_NAMES[flight.EV_SPARSE_SELECT] == "sparse_select"
+    assert [e for e in eng.flight.snapshot_events() if e["kind"] == 19]
+
+
+def test_a_reused_slot_gives_what_a_fresh_engine_gives(params):
+    """One slot, two requests one after the other: the second finds its
+    predecessor's index keys in the pages it is handed and must not see
+    them (its own length bounds what is scored)."""
+    a = [int(t) for t in prompt(30, seed=1)]
+    b = [int(t) for t in prompt(9, seed=2)]
+
+    def serve(eng, ids):
+        return [ev["token_id"] for ev in eng.generate_stream(
+            ids, max_new_tokens=10, temperature=0.0)]
+
+    eng = _engine(params, max_batch_size=1)
+    eng.start()
+    try:
+        first, second = serve(eng, a), serve(eng, b)
+    finally:
+        eng.stop()
+    fresh = _engine(params, max_batch_size=1)
+    fresh.start()
+    try:
+        alone = serve(fresh, b)
+    finally:
+        fresh.stop()
+    assert second == alone and _greedy_ok(params, b, second)
+    assert _greedy_ok(params, a, first)
+
+
+def test_a_llamas_engine_reports_the_sparse_counters_as_zero():
+    cfg = llama.LlamaConfig.tiny()
+    from benchmark.harness.bench_tokenizer import WordTokenizer
+    eng = LLMEngine(llama.init_params(cfg, jax.random.PRNGKey(0)), cfg,
+                    WordTokenizer(256), EngineConfig(
+                        max_batch_size=2, max_seq_len=32, page_size=8,
+                        prefill_buckets=(16,)))
+    eng.start()
+    try:
+        list(eng.generate_stream([3, 4, 5], max_new_tokens=4,
+                                 temperature=0.0))
+    finally:
+        eng.stop()
+    snap = eng.metrics.snapshot()
+    assert [snap[k] for k in (
+        "index_bytes_per_token", "sparse_topk", "sparse_keys_scored",
+        "sparse_rows_attended", "sparse_steps_dense")] == [0] * 5
+    assert not [e for e in eng.flight.snapshot_events() if e["kind"] == 22]
+    assert cfg.index_row is None
+    from generativeaiexamples_tpu.models import hybrid_ssm, latent_moe
+    assert hybrid_ssm.HybridSsmConfig.tiny().index_row is None
+    assert latent_moe.LatentMoeConfig.index_row is None
+    from generativeaiexamples_tpu.serving import fleet
+    assert {"sparse_keys_scored", "sparse_rows_attended",
+            "sparse_steps_dense"} <= set(fleet._COUNTER_KEYS)
+
+
+@pytest.mark.parametrize("lane,over", [
+    ("speculative_k", dict(speculative_k=2)),
+    ("step_plans", dict(step_plans=True)),
+    ("fused_prefill", dict(fused_prefill=True)),
+    ("prefix_cache", dict(prefix_cache=True)),
+    ("kv_pager", dict(prefix_cache=True, kv_pager=True)),
+    ("qos_preempt_prefill", dict(qos=True)),
+    ("kv_dtype bfloat16", dict(kv_dtype="bfloat16")),
+])
+def test_lanes_that_would_have_to_carry_the_index_rows_are_refused_by_name(
+        params, lane, over):
+    with pytest.raises(ValueError, match=f"engine.{lane}.*index rows"):
+        _engine(params, **over)
+
+
+def test_a_mesh_and_the_multihost_replay_are_refused_by_name():
+    from generativeaiexamples_tpu.serving.engine import (
+        _refuse_unwalked_lanes)
+    ecfg = EngineConfig(kv_dtype="int8")
+    with pytest.raises(ValueError, match="engine.mesh.*one key head"):
+        _refuse_unwalked_lanes(CFG, ecfg, mesh=object())
+    with pytest.raises(ValueError, match="engine.multihost"):
+        _refuse_unwalked_lanes(CFG, dataclasses.replace(ecfg, multihost=True))
+    _refuse_unwalked_lanes(CFG, dataclasses.replace(
+        ecfg, qos=True, qos_preempt_prefill=False))
+
+
+def test_a_prompt_past_the_largest_bucket_is_refused(params):
+    from generativeaiexamples_tpu.serving.engine import (
+        GenRequest, PromptTooLongError)
+    eng = _engine(params)
+    with pytest.raises(PromptTooLongError):
+        eng.submit(GenRequest(prompt_ids=list(range(1, 70))))
+
+
+def test_memory_plan_counts_the_index_rows(params):
+    ecfg = dataclasses.replace(EngineConfig(), page_size=PS,
+                               kv_dtype="int8", max_seq_len=64,
+                               max_batch_size=4, prefill_buckets=(16,))
+    pool = PagePool.zeros(CFG, 5, PS, dtype=jnp.int8)
+    page = memory_plan.pool_page_bytes_per_device(CFG, ecfg, {})
+    assert page == sum(x.nbytes for x in jax.tree.leaves(pool)) // 5
+    weights = memory_plan.weight_bytes_per_device(CFG, {}, quantize=True)
+    assert weights == sum(x.nbytes for x in jax.tree.leaves(params))
+    with pytest.raises(memory_plan.MemoryPlanError, match="tensor"):
+        memory_plan.weight_bytes_per_device(CFG, {"tensor": 2}, quantize=True)
+
+
+def test_hf_loader_refuses_a_keyevl2_snapshot(tmp_path):
+    from generativeaiexamples_tpu.models import hf_loader
+    (tmp_path / "config.json").write_text(json.dumps(
+        {k: v for k, v in FILE.items() if k != "serving"}))
+    with pytest.raises(ValueError, match="learned sparse attention"):
+        hf_loader.llama_config_from_hf(str(tmp_path))
