@@ -73,6 +73,7 @@ from generativeaiexamples_tpu.serving.kv_cache import (
 from generativeaiexamples_tpu.serving.ssm_state_update import kernel_update
 from generativeaiexamples_tpu.serving import flight as flight_mod
 from generativeaiexamples_tpu.serving.paged_attention_int8 import page_counts
+from generativeaiexamples_tpu.serving.paged_attention_sparse import walk_counts
 from generativeaiexamples_tpu.serving.multihost import (
     fetch_addressable as mh_fetch_addressable,
     fetch_replicated as mh_fetch_replicated)
@@ -387,12 +388,19 @@ class EngineMetrics:
         # the tokens a query attends to at most (gauges); index keys
         # scored (the live slots' lengths, summed over steps and layers),
         # rows attended (min(length, topk) the same way), and slot-steps
-        # whose context was within topk, where selection is the identity.
+        # whose context was within topk, where selection is the identity;
+        # the pages serving/paged_attention_sparse.py copies and multiplies
+        # for them (each live slot's cdiv(length, page_size)) and the
+        # blocks it walks them in, a softmax update and a loop turn each
+        # (paged_attention_sparse.walk_counts): pages / blocks is the
+        # pages an update covers.
         self.index_bytes_per_token = 0
         self.sparse_topk = 0
         self.sparse_keys_scored = 0
         self.sparse_rows_attended = 0
         self.sparse_steps_dense = 0
+        self.sparse_attn_pages_walked = 0
+        self.sparse_attn_blocks_walked = 0
         self.busy_slots_acc = 0
         # Speculative decoding: committed tokens vs slot-steps, for the
         # acceptance-rate gauge (1.0 = no drafts accepted, k+1 = all).
@@ -567,6 +575,8 @@ class EngineMetrics:
             "sparse_keys_scored": self.sparse_keys_scored,
             "sparse_rows_attended": self.sparse_rows_attended,
             "sparse_steps_dense": self.sparse_steps_dense,
+            "sparse_attn_pages_walked": self.sparse_attn_pages_walked,
+            "sparse_attn_blocks_walked": self.sparse_attn_blocks_walked,
             "mean_batch_occupancy": occ,
             "tokens_per_sec": self.tokens_per_sec(),
             "prefill_tokens": self.prefill_tokens,
@@ -2212,7 +2222,8 @@ class LLMEngine:
         the lengths the host dispatches it with: every live slot scores
         all its cached index keys in every layer and step (a slot is one
         token longer each step) and attends to min(length, topk) of
-        them. Counts them and returns the block's `sparse_select` event
+        them, walking all its pages for it in blocks (walk_counts).
+        Counts them and returns the block's `sparse_select` event
         (a = keys scored a live slot, step and layer; b = rows attended
         over keys scored); None for every other model."""
         if self.cfg.index_row is None:
@@ -2226,6 +2237,9 @@ class LLMEngine:
         self.metrics.sparse_keys_scored += scored * L
         self.metrics.sparse_rows_attended += attended * L
         self.metrics.sparse_steps_dense += int((ctx <= topk).sum())
+        pages, blocks = walk_counts(ctx, self.pool.page_size, self.max_pages)
+        self.metrics.sparse_attn_pages_walked += pages * L
+        self.metrics.sparse_attn_blocks_walked += blocks * L
         return (scored / max(ctx.size, 1),
                 attended / scored if scored else 0.0)
 

@@ -3,7 +3,8 @@
 (Keye-VL-2.0-30B-A3B's stage, `keye-vl-2.0-30b-a3b-int8`):
 
     chiprun -- timeout 3000 python3 scripts/check_sparse_on_chip.py \
-        [--phases hazard,kernels,compare,step] [--seeds 1]
+        [--phases hazard,kernels,compare,step] [--seeds 1] \
+        [--attn-widths 4,8,16] [--parent DIR]
 
 Four phases, one JSON line each result (also chiprun_out/sparse/check.jsonl):
 
@@ -18,7 +19,15 @@ Four phases, one JSON line each result (also chiprun_out/sparse/check.jsonl):
            index scores; the selection as the kernel, as the XLA bitwise
            partial sort and as `jax.lax.top_k`; the selected attention as
            the kernel that walks the slot's pages whole under the mask and
-           as a GATHER of the 2,048 selected rows in XLA.
+           as a GATHER of the 2,048 selected rows in XLA. The walk's line
+           says the width, tail and look-ahead it ran with and us a page
+           beside us a call; `--attn-widths 4,8,16` reads it again at each
+           of those widths (an entry `width/tail/ahead` sets all three of
+           `paged_attention_sparse._walk`; left out, the tail is the
+           width, or 4 past a width of 8, and the look-ahead the
+           module's), and `--parent DIR` (a `git archive` of another
+           commit, e.g. `.scratch/parent`) that commit's kernel beside
+           them.
   compare  ISSUE 42's three-part comparison at the published widths on what
            the step programs produce (a 4,096-token prompt through
            `prefill_step` in the 6,144 bucket, then eight decode steps
@@ -38,6 +47,7 @@ from __future__ import annotations
 
 import argparse
 import functools
+import importlib.util
 import json
 import os
 import sys
@@ -57,6 +67,12 @@ def main() -> int:
     ap.add_argument("--seeds", type=int, default=1)
     ap.add_argument("--hazard-runs", type=int, default=20)
     ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--attn-widths", default="",
+                    help="widths (or width/tail/ahead) to time the selected "
+                         "attention's walk at, beside the module's own")
+    ap.add_argument("--parent", default=None,
+                    help="a checkout of another commit: its "
+                         "paged_attention_sparse timed beside this one's")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     if args.rehearse:
@@ -74,6 +90,7 @@ def main() -> int:
     from generativeaiexamples_tpu.serving.kv_cache import PagePool
     from generativeaiexamples_tpu.serving.paged_attention_int8 import (
         every_row, paged_attention_int8)
+    from generativeaiexamples_tpu.serving import paged_attention_sparse as pas
     from generativeaiexamples_tpu.serving.paged_attention_sparse import (
         paged_attention_sparse)
     from generativeaiexamples_tpu.serving.sparse_index_scores import (
@@ -188,6 +205,39 @@ def main() -> int:
                 q, pools[0], pools[1], table, ln, sel, l,
                 use_pallas=use_pallas, live=live)
 
+        def walk_with(walk):
+            """The kernel at another (width, tail, ahead) than the module's
+            (off the chip it is interpreted, at the rehearsal's size)."""
+            def one(pools, l, sel, ln):
+                return pas.paged_attention_sparse_pallas(
+                    q, pools[0], pools[1], table, ln, sel, l, live,
+                    walk=walk, interpret=args.rehearse)
+            return one
+
+        walks = []
+        for item in filter(None, args.attn_widths.split(",")):
+            # width[/tail[/ahead]]; a width past 8 gets a tail of 4 (a
+            # switch of more than nine bodies does not compile)
+            width, *rest = (int(x) for x in item.split("/"))
+            tail = rest[0] if rest else (width if width <= 8 else 4)
+            ahead = rest[1] if len(rest) > 1 else pas.BLOCKS_AHEAD
+            walk = pas._walk(maxp, (width, tail, ahead))
+            walks.append((walk, twelve(walk_with(walk))))
+        parent_walk = None
+        if args.parent:
+            spec = importlib.util.spec_from_file_location(
+                "parent_paged_attention_sparse", os.path.join(
+                    args.parent, "generativeaiexamples_tpu", "serving",
+                    "paged_attention_sparse.py"))
+            parent = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(parent)
+
+            @twelve
+            def parent_walk(pools, l, sel, ln):
+                return parent.paged_attention_sparse_pallas(
+                    q, pools[0], pools[1], table, ln, sel, l, live,
+                    interpret=args.rehearse)
+
         def gather_attention(pools, l, sel_idx, ln):
             """The other form: the selected rows gathered, then dense
             attention over them. sel_idx [B, topk] token positions."""
@@ -228,8 +278,26 @@ def main() -> int:
                 np.array_equal(m, masks["select_kernel_us"])
                 for m in masks.values()))
             sel = jnp.asarray(masks["select_kernel_us"])
+            pages = B * -(-context // ps)
             line["attention_walk_us"] = timed(
                 twelve(walk_attention), pools, sel, ln) / L * 1e6
+            width, tail, ahead = pas._walk(maxp)
+            line["attention_walk"] = dict(
+                width=width, tail=tail, ahead=ahead, pages=pages,
+                blocks=pas.walk_counts(np.asarray(ln), ps, maxp)[1],
+                us_per_page=line["attention_walk_us"] / pages)
+            line["attention_walks"] = []
+            for walk, fn in walks:
+                us = timed(fn, pools, sel, ln) / L * 1e6
+                line["attention_walks"].append(dict(
+                    width=walk[0], tail=walk[1], ahead=walk[2],
+                    blocks=pas.walk_counts(np.asarray(ln), ps, maxp,
+                                           walk=walk)[1],
+                    us_per_call=us, us_per_page=us / pages))
+            if parent_walk is not None:
+                us = timed(parent_walk, pools, sel, ln) / L * 1e6
+                line["attention_walk_parent"] = dict(
+                    us_per_call=us, us_per_page=us / pages)
             line["attention_dense_int8_us"] = timed(twelve(
                 lambda pools, l, ln: paged_attention_int8(
                     q, pools[0], pools[1], table, ln, l, live=live,

@@ -31,6 +31,7 @@ from generativeaiexamples_tpu.serving.kv_cache import (
     PagePool, QuantPagePool, SparseIndexPool)
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
     live_rows, quantize_kv)
+from generativeaiexamples_tpu.serving import paged_attention_sparse as pas
 from generativeaiexamples_tpu.serving.paged_attention_sparse import (
     paged_attention_sparse, paged_attention_sparse_pallas)
 from generativeaiexamples_tpu.serving.sparse_index_scores import (
@@ -148,6 +149,163 @@ def test_the_three_kernels_are_their_xla_forms(case):
                                           interpret=True)
     np.testing.assert_allclose(got_o, want_o, rtol=1e-5, atol=1e-6)
     assert not np.asarray(want_o)[~live].any()
+
+
+# -- the selected attention's walk: a block is the unit of its softmax -------
+
+WALK_MAXP = 24
+# the served walk, and a probe's: whole blocks of 8, the rest in 4s, 1 ahead
+WALKS = {"served": None, "wide-8-tail-4": (8, 4, 1)}
+
+
+def _walk_case(lengths, mask=None, seed=0):
+    """A pool of rows of up to WALK_MAXP pages, pages in any order."""
+    rng = np.random.default_rng(seed)
+    B, L, KH, H, Hd = len(lengths), 2, 2, 4, 16
+    P = B * WALK_MAXP + 1
+    kv, s = quantize_kv(jnp.asarray(
+        rng.normal(size=(2, L, KH, P, PS, Hd)), jnp.float32))
+    table = rng.permutation(np.arange(1, P))[:B * WALK_MAXP]
+    return dict(
+        kv=kv, s=s, q=jnp.asarray(rng.normal(size=(B, H, Hd)), jnp.float32),
+        table=jnp.asarray(table.reshape(B, WALK_MAXP), jnp.int32),
+        lengths=jnp.asarray(lengths, jnp.int32),
+        live=None if mask is None else live_rows(jnp.asarray(mask)))
+
+
+def _walk_holds(c, selected, walk):
+    """The interpreted kernel is the XLA form on the case; -> the output."""
+    selected = jnp.asarray(selected)
+    want = paged_attention_sparse(c["q"], c["kv"], c["s"], c["table"],
+                                  c["lengths"], selected, 1,
+                                  use_pallas=False, live=c["live"])
+    got = paged_attention_sparse_pallas(
+        c["q"], c["kv"], c["s"], c["table"], c["lengths"], selected, 1,
+        c["live"], walk=walk, interpret=True)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    return np.asarray(got)
+
+
+def _pages_at(name, width):
+    return {"one": 1, "width-1": width - 1, "width": width,
+            "width+1": width + 1, "2-width+3": 2 * width + 3,
+            "maxp": WALK_MAXP}[name]
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+@pytest.mark.parametrize("pages", ["one", "width-1", "width", "width+1",
+                                   "2-width+3", "maxp"])
+def test_the_walk_at_a_rows_page_count(pages, walk):
+    """A row of that many pages, its last one whole, a token into it and
+    a token short of whole, every other token selected: the row's last
+    block is the partial one, a short row is one partial block."""
+    width = pas._walk(WALK_MAXP, WALKS[walk])[0]
+    n = _pages_at(pages, width)
+    lengths = (n * PS, (n - 1) * PS + 1, n * PS - 1)
+    c = _walk_case(lengths, seed=n)
+    at = np.arange(WALK_MAXP * PS)[None]
+    out = _walk_holds(c, (at < np.asarray(lengths)[:, None]) & (at % 2 == 0),
+                      WALKS[walk])
+    assert np.abs(out).sum(axis=(1, 2)).all()
+
+
+def _selected_blocks(pattern, width):
+    """A row of len(pattern) whole blocks of `width` pages: three tokens
+    of each block whose letter is `x`, none of a block whose letter is
+    `.`; -> (length, the row's selection)."""
+    row = np.zeros(WALK_MAXP * PS, bool)
+    for i, letter in enumerate(pattern):
+        if letter == "x":
+            row[i * width * PS + np.array([0, PS + 3, width * PS - 1])] = True
+    return len(pattern) * width * PS, row
+
+
+WALK_SCENES = {
+    # a block in which NOTHING is selected before one in which something
+    # is (the guard: m is still NEG_INF), between two such, and after
+    "nothing-selected-in-a-block": ("..x", ".x.", "x..", "x.x"),
+    "nothing-selected-at-all": ("...", "x", ".", "xx"),
+}
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+@pytest.mark.parametrize("scene", sorted(WALK_SCENES))
+def test_the_walk_over_blocks_that_select_nothing(scene, walk):
+    width = pas._walk(WALK_MAXP, WALKS[walk])[0]   # three blocks fit a row
+    rows = [_selected_blocks(p, width) for p in WALK_SCENES[scene]]
+    c = _walk_case([n for n, _ in rows], seed=len(scene))
+    out = _walk_holds(c, np.stack([row for _, row in rows]), WALKS[walk])
+    for got, (_, row) in zip(out, rows):
+        assert bool(np.abs(got).sum()) == bool(row.any())
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_the_walk_when_only_the_last_partial_block_selects(walk):
+    """Whole blocks that select nothing, then a last block of one, two
+    and width - 1 pages that holds the row's only selected tokens."""
+    width = pas._walk(WALK_MAXP, WALKS[walk])[0]
+    pages = np.array([width + 1, 2 * width + 2, 2 * width - 1])
+    lengths = pages * PS - 3
+    selected = np.zeros((3, WALK_MAXP * PS), bool)
+    for b, n in enumerate(pages):
+        selected[b, [(n - 1) * PS, lengths[b] - 1]] = True
+    out = _walk_holds(_walk_case(lengths, seed=5), selected, WALKS[walk])
+    assert np.abs(out).sum(axis=(1, 2)).all()
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+@pytest.mark.parametrize("mask", [
+    (False, True, False, False, True, True, False),
+    (True, False, True, False, False, False, True),
+    (False,) * 7], ids=["idle-first", "idle-between", "every-row-idle"])
+def test_the_walk_over_the_live_list(mask, walk):
+    """Idle rows between live ones are never asked for, whatever their
+    lengths and table rows say, and leave zeros; with every row idle the
+    kernel still runs (one row, discarded)."""
+    lengths = (190, 1, 77, 8, 33, 192, 100)
+    c = _walk_case(lengths, mask, seed=11)
+    at = np.arange(WALK_MAXP * PS)[None]
+    out = _walk_holds(c, (at < np.asarray(lengths)[:, None]) & (at % 3 != 1),
+                      WALKS[walk])
+    live = np.asarray(mask)
+    assert not out[~live].any()
+    assert np.abs(out[live]).sum(axis=(1, 2)).all()
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_the_hosts_block_count_is_the_block_bodies_the_kernel_runs(
+        walk, monkeypatch):
+    """`walk_counts` (the engine's sparse_attn_pages_walked and
+    sparse_attn_blocks_walked) against the interpreted kernel on the same
+    lengths: every block body that runs says how many pages it has."""
+    ran = []
+    fold = pas._fold_block
+
+    def counted(q, page, count, carry):
+        jax.debug.callback(lambda: ran.append(count))
+        return fold(q, page, count, carry)
+
+    monkeypatch.setattr(pas, "_fold_block", counted)
+    paged_attention_sparse_pallas.clear_cache()
+    try:
+        lengths = (190, 1, 77, 8, 33, 192, 100)
+        at = np.arange(WALK_MAXP * PS)[None]
+        selected = at < np.asarray(lengths)[:, None]
+        for mask in (None, (True, False, True, True, False, True, True)):
+            ran.clear()
+            _walk_holds(_walk_case(lengths, mask), selected, WALKS[walk])
+            jax.effects_barrier()
+            pages, blocks = pas.walk_counts(
+                np.asarray(lengths), PS, WALK_MAXP, mask=mask,
+                walk=WALKS[walk])
+            assert (sum(ran), len(ran)) == (pages, blocks)
+            live = np.ones(7, bool) if mask is None else np.asarray(mask)
+            assert pages == (-(-np.asarray(lengths) // PS) * live).sum()
+    finally:
+        paged_attention_sparse_pallas.clear_cache()
+    # the served rule: blocks of BLOCK_PAGES, a row's last one partial
+    assert pas.walk_counts(np.array([[1, 32], [33, 1000]]), PS, WALK_MAXP,
+                           mask=np.array([True, False])) == (1 + 5, 1 + 2)
 
 
 @pytest.mark.parametrize("scores,topk", [
@@ -415,6 +573,11 @@ def test_the_engine_serves_the_forwards_tokens_and_counts(params):
     assert snap["sparse_keys_scored"] == 3 * ctx.sum()
     assert snap["sparse_rows_attended"] == 3 * np.minimum(ctx, TOPK).sum()
     assert snap["sparse_steps_dense"] == (ctx <= TOPK).sum() > 0
+    # ... and every page of the slot walked, BLOCK_PAGES an update
+    assert (snap["sparse_attn_pages_walked"],
+            snap["sparse_attn_blocks_walked"]) == tuple(
+        3 * n for n in pas.walk_counts(ctx, PS, eng.max_pages))
+    assert snap["sparse_attn_pages_walked"] == 3 * (-(-ctx // PS)).sum()
     events = [e for e in eng.flight.snapshot_events() if e["kind"] == 22]
     assert events and events[0]["b"] == 1.0 and events[-1]["b"] < 1.0
     assert events[0]["a"] == pytest.approx(np.mean(ctx[:2]))
@@ -466,7 +629,8 @@ def test_a_llamas_engine_reports_the_sparse_counters_as_zero():
     snap = eng.metrics.snapshot()
     assert [snap[k] for k in (
         "index_bytes_per_token", "sparse_topk", "sparse_keys_scored",
-        "sparse_rows_attended", "sparse_steps_dense")] == [0] * 5
+        "sparse_rows_attended", "sparse_steps_dense",
+        "sparse_attn_pages_walked", "sparse_attn_blocks_walked")] == [0] * 7
     assert not [e for e in eng.flight.snapshot_events() if e["kind"] == 22]
     assert cfg.index_row is None
     from generativeaiexamples_tpu.models import hybrid_ssm, latent_moe
@@ -474,7 +638,8 @@ def test_a_llamas_engine_reports_the_sparse_counters_as_zero():
     assert latent_moe.LatentMoeConfig.index_row is None
     from generativeaiexamples_tpu.serving import fleet
     assert {"sparse_keys_scored", "sparse_rows_attended",
-            "sparse_steps_dense"} <= set(fleet._COUNTER_KEYS)
+            "sparse_steps_dense", "sparse_attn_pages_walked",
+            "sparse_attn_blocks_walked"} <= set(fleet._COUNTER_KEYS)
 
 
 @pytest.mark.parametrize("lane,over", [
