@@ -208,6 +208,14 @@ def llama_config_from_hf(path: str) -> llama_lib.LlamaConfig:
             "models/sparse_attn_moe.py's SparseAttnMoeConfig, and this "
             "loader holds no tensor-name map for it (q_norm, k_norm, the "
             "indexer's projections, the expert stacks)")
+    if "sliding_window_layout" in c:
+        raise ValueError(
+            f"{path}: model {c.get('model_name') or c.get('model_type')!r} "
+            "has window layers beside global ones (sliding_window_layout, "
+            "rope_layout) and a router before its attention: it is no "
+            "LlamaConfig; its configuration is models/window_attn_moe.py's "
+            "WindowAttnMoeConfig, and this loader holds no tensor-name map "
+            "for it (the router, the expert stacks)")
     family = {}
     if c.get("model_type") in _LOOPED_TYPES:
         family = dict(n_passes=int(c["total_ut_steps"]), post_norms=True)

@@ -105,6 +105,7 @@ class HybridSsmConfig:
     post_norms = False
     latent_row = None
     index_row = None
+    window_rows = None
     expert_offset = 0
 
     def __post_init__(self):

@@ -84,6 +84,7 @@ class LatentMoeConfig:
     post_norms = False
     recurrent_state = None
     index_row = None
+    window_rows = None
 
     def __post_init__(self):
         if not 0 < self.experts_held <= self.n_routed_experts \

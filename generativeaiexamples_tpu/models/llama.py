@@ -153,6 +153,10 @@ class LlamaConfig:
     # an indexer's key cached beside K and V (learned sparse attention):
     # none (serving/kv_cache.py builds a SparseIndexPool where there is)
     index_row = None
+    # window layers beside global ones, each group of cache rows under a
+    # page table of its own: none (serving/kv_cache.py builds a WindowPool
+    # where there are)
+    window_rows = None
 
     @property
     def post_norm_init(self) -> float:

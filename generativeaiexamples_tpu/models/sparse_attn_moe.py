@@ -101,6 +101,7 @@ class SparseAttnMoeConfig:
     post_norms = False
     latent_row = None
     recurrent_state = None
+    window_rows = None
     expert_offset = 0
 
     def __post_init__(self):

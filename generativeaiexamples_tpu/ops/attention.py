@@ -50,11 +50,17 @@ def mha_reference(
     lengths: Optional[jax.Array] = None,
     q_offset: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
+    kv_start: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Scaled-dot-product attention, GQA-aware, fp32 softmax.
 
     q: [B, H, Sq, D]; k/v: [B, KH, Sk, D]; lengths: [B] valid kv length;
     q_offset: [B] absolute position of q[0] (for decode: Sq=1, offset=pos).
+    window (causal only): a query at t sees s with t - s < window, its
+    own position among them. kv_start: [B] the first kv position a row
+    sees (a decode step of a window layer, whose query has no position
+    here).
     """
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
@@ -72,6 +78,10 @@ def mha_reference(
         off = q_offset if q_offset is not None else jnp.zeros((B,), jnp.int32)
         q_pos = jnp.arange(Sq)[None, None, :, None] + off[:, None, None, None]
         mask &= kv_pos <= q_pos
+        if window is not None:
+            mask &= q_pos - kv_pos < window
+    if kv_start is not None:
+        mask &= kv_pos >= kv_start[:, None, None, None]
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(jnp.float32))
@@ -100,6 +110,7 @@ def _flash_kernel(
     block_k: int,
     num_q_blocks: int,
     num_k_blocks: int,
+    window: Optional[int] = None,
 ):
     b = pl.program_id(0)
     qi = pl.program_id(2)
@@ -127,6 +138,8 @@ def _flash_kernel(
             q_pos = q_start + q_offs_ref[b] \
                 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             valid &= kv_pos <= q_pos
+            if window is not None:
+                valid &= q_pos - kv_pos < window
         s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_ref[:, :1]  # [bq, 1]
@@ -148,9 +161,14 @@ def _flash_kernel(
     # (a one-block call has no dead one and stays the kernel it was),
     # k-blocks that start at or past `lengths[b]` and q-blocks whose
     # first row does. A dead q-block's output is zeros; nobody reads it.
+    # Under a window, also the k-blocks wholly BEHIND it: the block's last
+    # key is out of the reach of the q-block's first row.
     live = []
     if causal:
         live.append(k_start <= q_start + q_offs_ref[b] + block_q - 1)
+        if window is not None:
+            live.append(k_start + block_k - 1
+                        > q_start + q_offs_ref[b] - window)
     if num_k_blocks > 1:
         live.append(k_start < lengths_ref[b])
     if num_q_blocks > 1:
@@ -179,8 +197,13 @@ def flash_attention(
     block_q: int = 256,
     block_k: int = 256,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Pallas TPU flash attention. q [B,H,Sq,D], k/v [B,KH,Sk,D].
+
+    `window` (with `causal`): row t sees s <= t with t - s < window; the
+    k-blocks wholly behind a q-block's window are skipped, not masked.
+    None: the kernel it was.
 
     `q_offset` [B] is the absolute position of q[0] (cached-continuation
     prefill: queries continue at the cache length while keys cover the
@@ -216,6 +239,7 @@ def flash_attention(
         block_k=block_k,
         num_q_blocks=nq,
         num_k_blocks=nk,
+        window=window if causal else None,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -272,9 +296,11 @@ def on_tpu() -> bool:
 def attention(
     q, k, v, *, causal=True, lengths=None, q_offset=None, scale=None,
     use_pallas: Optional[bool] = None, mesh=None, interpret: bool = False,
-    block_q: int = 256, block_k: int = 256,
+    block_q: int = 256, block_k: int = 256, window: Optional[int] = None,
 ):
     """Dispatch: Pallas flash kernel on TPU, XLA reference elsewhere.
+    `window`: a window layer's prompt form (None: every key at or before
+    the query).
 
     With a multi-device `mesh`, the Pallas kernel is wrapped in a
     shard_map over the "tensor" axis — attention is head-parallel under
@@ -306,14 +332,15 @@ def attention(
                 lambda q_, k_, v_, ln_, off_: flash_attention(
                     q_, k_, v_, causal=causal, lengths=ln_, q_offset=off_,
                     scale=scale, interpret=interpret,
-                    block_q=block_q, block_k=block_k),
+                    block_q=block_q, block_k=block_k, window=window),
                 mesh=mesh, in_specs=(hs, hs, hs, P(), P()), out_specs=hs,
                 check_vma=False)
             return fn(q, k, v, ln, off)
         return flash_attention(q, k, v, causal=causal, lengths=ln,
                                q_offset=off, scale=scale,
                                interpret=interpret,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k,
+                               window=window)
     return mha_reference(
-        q, k, v, causal=causal, lengths=lengths, q_offset=q_offset, scale=scale
-    )
+        q, k, v, causal=causal, lengths=lengths, q_offset=q_offset,
+        scale=scale, window=window)

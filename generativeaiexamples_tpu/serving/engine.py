@@ -69,7 +69,9 @@ from generativeaiexamples_tpu.models.llama import LlamaConfig
 from generativeaiexamples_tpu.obs import tracing
 from generativeaiexamples_tpu.serving import engine_model
 from generativeaiexamples_tpu.serving.kv_cache import (
-    PageAllocator, PagePool, SequencePages, kernel_append)
+    PageAllocator, PagePool, SequencePages, WindowPool, WindowSequencePages,
+    WindowTables, engine_window_table_pages, kernel_append,
+    window_pool_pages)
 from generativeaiexamples_tpu.serving.ssm_state_update import kernel_update
 from generativeaiexamples_tpu.serving import flight as flight_mod
 from generativeaiexamples_tpu.serving.paged_attention_int8 import page_counts
@@ -80,7 +82,7 @@ from generativeaiexamples_tpu.serving.multihost import (
 from generativeaiexamples_tpu.serving.flight import (
     EV_ADMIT, EV_ADMIT_RETRY, EV_DECODE_JOIN, EV_FIRST_TOKEN, EV_KV_DEMOTE,
     EV_KV_PROMOTE, EV_KV_TRANSFER, EV_MOE_LOAD, EV_PREFILL_CHUNK,
-    EV_SPARSE_SELECT,
+    EV_SPARSE_SELECT, EV_WINDOW_CACHE,
     EV_PREFILL_DISPATCH, EV_PROGRAM, EV_QOS_PAUSE, EV_QOS_PICK,
     EV_QOS_RESUME, EV_RETIRE, EV_SUBMIT, PROG_CHUNK, PROG_DECODE,
     PROG_PREFILL, PROGRAM_CLASSES, RETIRE_CODES, ExpHistogram,
@@ -219,7 +221,7 @@ class _InFlight:
 
     __slots__ = ("block", "metas", "K", "releases", "spec_worst",
                  "plain_spec", "t_dispatch", "plan", "prog", "t_ready",
-                 "sparse")
+                 "sparse", "window")
 
     def __init__(self, block, metas, K, spec_worst: int = 0,
                  plain_spec: bool = False):
@@ -237,6 +239,11 @@ class _InFlight:
         # `sparse_select` event (a, b), from the lengths it was
         # dispatched with (_note_sparse_select); None for every other.
         self.sparse = None
+        # A model with window layers: the block's `window_cache` event
+        # and, a live slot, the position its window pages are released
+        # behind when the block lands (_note_window_cache); None for
+        # every other.
+        self.window = None
         # Plain blocks: device [B, K+1]. Speculative blocks: a
         # (targets [B, K, r], counts [B, K]) tuple.
         self.block = block
@@ -401,6 +408,18 @@ class EngineMetrics:
         self.sparse_steps_dense = 0
         self.sparse_attn_pages_walked = 0
         self.sparse_attn_blocks_walked = 0
+        # Window and global layers in one model (0 for a model without
+        # window rows): the window in tokens and the bytes a cached token
+        # takes in a window row's pages over all window layers (gauges;
+        # kv_bytes_per_token counts the global rows alone); window pages
+        # given back to their allocator behind a sliding window, pages
+        # the live sequences hold there now (a gauge), and pages the
+        # window rows' attention calls walked (a page a call).
+        self.window_tokens = 0
+        self.window_bytes_per_token = 0
+        self.window_pages_released = 0
+        self.window_pages_held = 0
+        self.decode_attn_window_pages_walked = 0
         self.busy_slots_acc = 0
         # Speculative decoding: committed tokens vs slot-steps, for the
         # acceptance-rate gauge (1.0 = no drafts accepted, k+1 = all).
@@ -577,6 +596,12 @@ class EngineMetrics:
             "sparse_steps_dense": self.sparse_steps_dense,
             "sparse_attn_pages_walked": self.sparse_attn_pages_walked,
             "sparse_attn_blocks_walked": self.sparse_attn_blocks_walked,
+            "window_tokens": self.window_tokens,
+            "window_bytes_per_token": self.window_bytes_per_token,
+            "window_pages_released": self.window_pages_released,
+            "window_pages_held": self.window_pages_held,
+            "decode_attn_window_pages_walked":
+                self.decode_attn_window_pages_walked,
             "mean_batch_occupancy": occ,
             "tokens_per_sec": self.tokens_per_sec(),
             "prefill_tokens": self.prefill_tokens,
@@ -752,6 +777,36 @@ def _refuse_unwalked_lanes(cfg: LlamaConfig, ecfg: EngineConfig,
                 + ": those lanes re-read, share, move or roll back cache "
                 "and would have to carry the index rows too; turn them off")
         return
+    if cfg.window_rows is not None:
+        # A kv_cache.WindowPool has two page tables a sequence, and a
+        # window row's pages are given back while the sequence lives:
+        # every lane below knows one table and pages held to the end.
+        # Nobody has said yet what a prefix hit, a snapshot or a rollback
+        # means for a page that slid out.
+        on = [(name, what) for name, what in _ONE_PASS_LANES
+              if getattr(ecfg, name)]
+        if mesh is not None:
+            on.append(("mesh", "tensor parallelism: the window rows' kernel "
+                       "call has no sharded form"))
+        if jnp.dtype(ecfg.kv_dtype) != jnp.int8:
+            on.append((f"kv_dtype {jnp.dtype(ecfg.kv_dtype).name}",
+                       "window rows in another type than int8"))
+        if ecfg.multihost:
+            on.append(("multihost", "the multi-host replay"))
+        if ecfg.qos and ecfg.qos_preempt_prefill:
+            on.append(("qos_preempt_prefill", "pausing and resuming a "
+                       "sequence's prefill"))
+        if on:
+            wr = cfg.window_rows
+            raise ValueError(
+                f"model has {wr.n_window} window layers ({wr.window} tokens) "
+                f"beside {wr.n_global} global ones, each group of cache "
+                f"rows under a page table of its own; not served with "
+                + ", ".join(f"engine.{name} ({what})" for name, what in on)
+                + ": those lanes re-read, share, move or roll back cache "
+                "through ONE table a sequence whose pages are held to its "
+                "end; turn them off")
+        return
     if cfg.n_passes == 1:
         return
     on = [(name, what) for name, what in _ONE_PASS_LANES
@@ -868,12 +923,29 @@ class LLMEngine:
                                                shd.KV_FUSED_SCALE_SPEC)
             else:
                 kv_sharding = NamedSharding(self.mesh, shd.KV_POOL_SPEC)
-        self.pool = PagePool.zeros(cfg, n_pages, ps,
-                                   dtype=jnp.dtype(self.ecfg.kv_dtype),
-                                   sharding=kv_sharding,
-                                   scale_sharding=scale_sharding,
-                                   slots=self.ecfg.max_batch_size)
-        self.allocator = PageAllocator(n_pages)
+        # A model with window layers: a second pool, allocator and page
+        # table for their rows. A sequence holds at most
+        # `_window_table_pages` of its pages: the window's and those the
+        # decode blocks in flight write ahead of a release.
+        self.window_allocator = None
+        self._window_table_pages = 0
+        if cfg.window_rows is not None:
+            self._window_table_pages = engine_window_table_pages(
+                cfg.window_rows.window, self.ecfg)
+            n_window_pages = window_pool_pages(cfg.window_rows.window,
+                                               self.ecfg)
+            self.pool = WindowPool.zeros(cfg, n_pages, n_window_pages, ps)
+            self.window_allocator = PageAllocator(n_window_pages,
+                                                  name="window-row KV")
+        else:
+            self.pool = PagePool.zeros(cfg, n_pages, ps,
+                                       dtype=jnp.dtype(self.ecfg.kv_dtype),
+                                       sharding=kv_sharding,
+                                       scale_sharding=scale_sharding,
+                                       slots=self.ecfg.max_batch_size)
+        self.allocator = PageAllocator(
+            n_pages, name="global-row KV" if cfg.window_rows is not None
+            else "KV")
         # Cross-request prefix KV reuse (serving/prefix_cache.py):
         # scheduler-thread-owned, like the allocator. The allocator's
         # reclaim hook LRU-evicts cached pages whenever live traffic
@@ -929,6 +1001,8 @@ class LLMEngine:
         paged = self.pool
         if rs is not None or cfg.index_row is not None:
             paged = self.pool.pages  # K and V alone
+        if cfg.window_rows is not None:
+            paged = self.pool.glob  # the global rows; the window rows below
         self.metrics.kv_bytes_per_token = sum(
             leaf.nbytes for leaf in jax.tree.leaves(paged)
         ) // (n_pages * ps)
@@ -940,6 +1014,20 @@ class LLMEngine:
                       "cached token; a query attends to %d tokens at most",
                       cfg.index_row, self.metrics.index_bytes_per_token,
                       cfg.index_topk)
+        if cfg.window_rows is not None:
+            wr, win = cfg.window_rows, self.pool.win
+            self.metrics.window_tokens = wr.window
+            self.metrics.window_bytes_per_token = sum(
+                leaf.nbytes for leaf in jax.tree.leaves(win)
+            ) // (win.n_pages * ps)
+            _LOG.info("window rows: %d layers see %d tokens, %d pages of %d "
+                      "tokens (a sequence holds %d at most), %d bytes a "
+                      "cached token; the %d global rows take %d bytes a "
+                      "cached token in the pool below",
+                      wr.n_window, wr.window, win.n_pages, ps,
+                      self._window_table_pages,
+                      self.metrics.window_bytes_per_token, wr.n_global,
+                      self.metrics.kv_bytes_per_token)
         if rs is not None:
             self.metrics.ssm_layers = rs.layers
             self.metrics.ssm_state_bytes_per_slot = rs.bytes_per_slot
@@ -1248,7 +1336,9 @@ class LLMEngine:
                         self.params, self.cfg, self.pool,
                         self._put(np.zeros((n, bucket), np.int32)),
                         self._put(np.ones((n,), np.int32)),
-                        self._put(np.zeros((n, bucket // ps), np.int32)),
+                        self._tables(np.zeros((n, bucket // ps), np.int32),
+                                     np.zeros((n, bucket // ps), np.int32)
+                                     if self.window_allocator else None),
                         self._put(np.zeros((n,), np.float32)),
                         self._put(np.ones((n,), np.float32)),
                         self._put(np.zeros((n,), np.int32)),
@@ -1328,7 +1418,7 @@ class LLMEngine:
                 _, self._last_tokens, self.pool =                     engine_model.decode_multi_step(
                         self.params, self.cfg, self.pool,
                         self._last_tokens,
-                        self._put(np.zeros((B, self.max_pages), np.int32)),
+                        self._tables(np.zeros((B, self.max_pages), np.int32)),
                         self._put(np.ones((B,), np.int32)),
                         self._put(np.zeros((B,), bool)),
                         self._put(np.zeros((B,), np.float32)),
@@ -1710,10 +1800,12 @@ class LLMEngine:
         max_prompt = self.max_pages * self.ecfg.page_size - 1
         if self.cfg.latent_row is not None \
                 or self.cfg.recurrent_state is not None \
-                or self.cfg.index_row is not None:
+                or self.cfg.index_row is not None \
+                or self.cfg.window_rows is not None:
             # the chunked long-prompt lane (a contiguous scratch cache of
             # K and V per head) has no latent form, carries no recurrent
-            # state from chunk to chunk and holds no index rows
+            # state from chunk to chunk, holds no index rows and writes
+            # one page table
             max_prompt = min(max_prompt, self.buckets[-1])
         if len(req.prompt_ids) > max_prompt:
             if not req.truncate_prompt:
@@ -2194,6 +2286,8 @@ class LLMEngine:
                 self.flight.record_event(EV_SPARSE_SELECT,
                                          time.perf_counter(),
                                          a=fl.sparse[0], b=fl.sparse[1])
+            if fl.window is not None:
+                self._land_window_cache(fl.window)
             # The landed block proves every program enqueued before it
             # complete: their rows resolve now, the block's own with
             # them, before the beat row that reads it.
@@ -2210,6 +2304,7 @@ class LLMEngine:
             for seq in fl.releases:
                 seq.release()
             fl.releases = []
+            self._gauge_window_pages()
         with _phase("sched.retire"):
             self._reap_starved()
             self._beat += 1
@@ -2242,6 +2337,51 @@ class LLMEngine:
         self.metrics.sparse_attn_blocks_walked += blocks * L
         return (scored / max(ctx.size, 1),
                 attended / scored if scored else 0.0)
+
+    def _note_window_cache(self, lengths, active, win_base, K: int):
+        """A decode block of a model with window layers, from the lengths
+        and tables the host dispatches it with: in every step a live slot
+        attends its whole context in each global layer and the last
+        `window` tokens of it in each window layer, whose kernel call
+        walks the slot's window table from its first page. Counts the
+        window pages walked and returns what `_land_window_cache` needs
+        when the block lands: the block's `window_cache` event (a =
+        cached tokens its attention calls see over layers x context, what
+        one kind of row would have seen; b = pages x rows the live slots
+        hold in both pools over what one table for every row would hold;
+        aux = the window pages walked and the calls that walked them, a
+        call being one window layer's of one step) and, a live slot, its
+        sequence and the position no later step reads behind; None for
+        every other model."""
+        if self.window_allocator is None:
+            return None
+        wr = self.cfg.window_rows
+        ps = self.pool.page_size
+        live = np.asarray(lengths, np.int64)[active]
+        ctx = live[None, :] + np.arange(K)[:, None]          # [K, n_live]
+        seen = wr.n_global * ctx + wr.n_window * np.minimum(ctx, wr.window)
+        walked = int((-(-(ctx - win_base[active]) // ps)).sum()) * wr.n_window
+        self.metrics.decode_attn_window_pages_walked += walked
+        seqs = [self.slots[i].seq for i in active]
+        held = sum(wr.n_global * len(q.pages)
+                   + wr.n_window * len(q.window_pages) for q in seqs)
+        one = (wr.n_global + wr.n_window) * sum(len(q.pages) for q in seqs)
+        event = (float(seen.sum()) / float(self.cfg.n_layers * ctx.sum()),
+                 held / one, f"window_pages={walked} calls={K * wr.n_window}")
+        # the block's last step has length `live + K - 1`; the next
+        # block's first is one longer, and its window starts there
+        return event, [(q, int(n) + K - wr.window)
+                       for q, n in zip(seqs, live)]
+
+    def _land_window_cache(self, window) -> None:
+        """A landed decode block of a model with window layers: every
+        step that read the pages behind its slots' windows has run, so
+        they go back to the window allocator; then the block's event."""
+        (a, b, aux), slides = window
+        for seq, start in slides:
+            self.metrics.window_pages_released += seq.slide(start)
+        self.flight.record_event(EV_WINDOW_CACHE, time.perf_counter(),
+                                 a=a, b=b, aux=aux)
 
     def _note_expert_load(self, fl: _InFlight, host, t_ready: float):
         """A landed decode block of a model with experts carries, below
@@ -2703,8 +2843,7 @@ class LLMEngine:
                 # the lane.
                 self._release_hit_pin(hit)
                 hit, demoted = None, True
-            seq = SequencePages(self.allocator, self.pool.page_size,
-                                self.max_pages)
+            seq = self._new_sequence()
             try:
                 if hit is not None:
                     seq.adopt(hit[0], hit[1])
@@ -2815,6 +2954,29 @@ class LLMEngine:
                         self._fail_request(req, slot_idx, seq)
         return did
 
+    def _new_sequence(self) -> SequencePages:
+        """A sequence's page bookkeeping: for a model with window layers,
+        over both allocators."""
+        ps = self.pool.page_size
+        if self.window_allocator is None:
+            return SequencePages(self.allocator, ps, self.max_pages)
+        return WindowSequencePages(
+            self.allocator, self.window_allocator, ps, self.max_pages,
+            self.cfg.window_rows.window, self._window_table_pages)
+
+    def _tables(self, glob, win=None, base=None):
+        """Page tables as a step program takes them: the one array, or a
+        model with window layers' WindowTables (`win` None: all zeros,
+        a warm-up's)."""
+        if self.window_allocator is None:
+            return self._put(glob)
+        if win is None:
+            win = np.zeros(glob.shape[:-1] + (self._window_table_pages,),
+                           np.int32)
+            base = np.zeros(glob.shape[:-1], np.int32)
+        return WindowTables(self._put(glob), self._put(win),
+                            None if base is None else self._put(base))
+
     def _fail_request(self, req: GenRequest, slot_idx: int,
                       seq: SequencePages) -> None:
         """Fail one request before it reached decodable state: free the
@@ -2854,6 +3016,10 @@ class LLMEngine:
         tokens = np.zeros((N, bucket), np.int32)
         lengths = np.ones((N,), np.int32)
         rows = np.zeros((N, bucket // ps), np.int32)
+        # a model with window layers: their rows' pages, indexed as `rows`
+        # (the pages behind the window stay the sink)
+        win_rows = None if self.window_allocator is None \
+            else np.zeros_like(rows)
         temps = np.zeros((N,), np.float32)
         top_ps = np.ones((N,), np.float32)
         top_ks = np.zeros((N,), np.int32)
@@ -2863,6 +3029,10 @@ class LLMEngine:
             tokens[j, : len(ids)] = ids
             lengths[j] = len(ids)
             rows[j, : len(seq.pages)] = seq.pages
+            if win_rows is not None:
+                first = seq.window_first
+                win_rows[j, first: first + len(seq.window_pages)] = \
+                    seq.window_pages
             temps[j] = req.temperature
             top_ps[j] = req.top_p
             top_ks[j] = req.top_k
@@ -2871,7 +3041,8 @@ class LLMEngine:
         flags = (True, False, False) if all_greedy else (False, True, True)
         live = bucket  # the rows the program computes of each prompt
         if self.cfg.latent_row is None and self.cfg.recurrent_state is None \
-                and self.cfg.index_row is None:
+                and self.cfg.index_row is None \
+                and self.cfg.window_rows is None:
             live = engine_model.prefill_row_counts(bucket, ps, N)[
                 int(engine_model.prefill_live_index(lengths, bucket, ps))]
         self.metrics.prefill_rows_live += N * live
@@ -2879,10 +3050,13 @@ class LLMEngine:
         with self._enqueue("sched.prefill_dispatch", PROG_PREFILL, n,
                            int(lengths[:n].sum()),
                            f"{N}x{bucket}") as prog:
-            toks = self._exec_prefill(dict(
+            rec = dict(
                 tokens=tokens, lengths=lengths, rows=rows, temps=temps,
                 top_ps=top_ps, top_ks=top_ks, idxs=idxs,
-                flags=np.asarray(flags)))
+                flags=np.asarray(flags))
+            if win_rows is not None:
+                rec["win_rows"] = win_rows
+            toks = self._exec_prefill(rec)
         self._await_program(prog, toks)
         seq_no = float(prog.seq) if prog is not None else 0.0
         metas = []
@@ -3452,6 +3626,10 @@ class LLMEngine:
         K = max(1, self.ecfg.decode_steps_per_dispatch)
         lengths = np.ones((B,), np.int32)
         tables = np.zeros((B, self.max_pages), np.int32)
+        win_tables = win_base = None
+        if self.window_allocator is not None:
+            win_tables = np.zeros((B, self._window_table_pages), np.int32)
+            win_base = np.zeros((B,), np.int32)
         temps = np.zeros((B,), np.float32)
         top_ps = np.ones((B,), np.float32)
         top_ks = np.zeros((B,), np.int32)
@@ -3544,6 +3722,8 @@ class LLMEngine:
                 active_mask[i] = True
                 s.no_capacity = False  # capacity proven; undo stale starve
                 tables[i] = s.seq.table_row()
+                if win_tables is not None:
+                    win_tables[i], win_base[i] = s.seq.window_row()
                 if spec_mode:
                     metas.append((i, s, base))
                 else:
@@ -3582,6 +3762,8 @@ class LLMEngine:
         rec.update(tables=tables, lengths=lengths,
                    active_mask=active_mask, temps=temps, top_ps=top_ps,
                    top_ks=top_ks, flags=np.asarray(flags))
+        if win_tables is not None:
+            rec.update(win_tables=win_tables, win_base=win_base)
         n_part = 0
         if plan.rider_width:
             part = lp.ids[lp.pos:lp.pos + plan.rider_width]
@@ -3627,6 +3809,7 @@ class LLMEngine:
                 and kernel_update(self.pool.state, self.use_pallas):
             self.metrics.ssm_steps_kernel += K
         sparse = self._note_sparse_select(lengths, active_mask, K)
+        window = self._note_window_cache(lengths, active, win_base, K)
         # every decode program but the verifies writes one row a slot
         if not plan.spec_k and kernel_append(self.pool, self.use_pallas):
             self.metrics.decode_steps_kernel_append += K
@@ -3692,6 +3875,7 @@ class LLMEngine:
             fl.plan = plan
             fl.prog = prog
             fl.sparse = sparse
+            fl.window = window
             self._await_program(prog, block)
             self._inflight.append(fl)
         return True
@@ -3804,7 +3988,8 @@ class LLMEngine:
         flags = tuple(bool(f) for f in rec["flags"])
         toks, self.pool = engine_model.prefill_batch_step(
             self.params, self.cfg, self.pool, self._put(rec["tokens"]),
-            self._put(rec["lengths"]), self._put(rec["rows"]),
+            self._put(rec["lengths"]),
+            self._tables(rec["rows"], rec.get("win_rows")),
             self._put(rec["temps"]), self._put(rec["top_ps"]),
             self._put(rec["top_ks"]), self._next_key(), self.use_pallas,
             sampling_flags=flags, mesh=self.mesh,
@@ -3838,7 +4023,9 @@ class LLMEngine:
         kw = dict(use_pallas=self.use_pallas, mesh=self.mesh)
         if plan.decode_k:
             kw.update(pool=self.pool, last_tokens=self._last_tokens,
-                      page_tables=self._put(rec["tables"]),
+                      page_tables=self._tables(rec["tables"],
+                                               rec.get("win_tables"),
+                                               rec.get("win_base")),
                       active=self._put(rec["active_mask"]))
             if plan.spec_k or plan.spec_state:
                 kw.update(history=self._history,
@@ -4143,7 +4330,12 @@ class LLMEngine:
         ps = self.pool.page_size
         table_cap = self.max_pages * ps - used
         in_page = len(slot.seq.pages) * ps - used
-        return table_cap, in_page + self.allocator.n_free * ps
+        avail = in_page + self.allocator.n_free * ps
+        if self.window_allocator is not None:  # the shorter of the two
+            seq = slot.seq
+            held = (seq.window_first + len(seq.window_pages)) * ps - used
+            avail = min(avail, held + self.window_allocator.n_free * ps)
+        return table_cap, avail
 
     def _starve(self, slot_idx: int) -> None:
         """The dispatcher can't advance this slot. If blocks are still in
@@ -4435,6 +4627,14 @@ class LLMEngine:
             self._inflight[-1].releases.append(seq)
         else:
             seq.release()
+            self._gauge_window_pages()
+
+    def _gauge_window_pages(self) -> None:
+        """`window_pages_held`: after a landed block's slides and
+        releases, and after a release that waited for none."""
+        if self.window_allocator is not None:
+            wa = self.window_allocator
+            self.metrics.window_pages_held = wa.n_pages - 1 - wa.n_free
 
     def _finish(self, slot_idx: int, reason: str, emit: bool = True) -> None:
         slot = self.slots[slot_idx]

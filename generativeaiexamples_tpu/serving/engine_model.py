@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from generativeaiexamples_tpu.models import (
-    hybrid_ssm, latent_moe, sparse_attn_moe)
+    hybrid_ssm, latent_moe, sparse_attn_moe, window_attn_moe)
 from generativeaiexamples_tpu.models.llama import (
     LlamaConfig, attn_out, final_norm, finish_block, project_qkv, rms_norm,
     walk_passes)
@@ -400,6 +400,114 @@ def _sparse_decode_once(params, cfg, pool, tokens, page_tables, lengths,
     return logits, pool, jnp.stack(counts), jnp.stack(choices)
 
 
+# -- window and full attention in one model (models/window_attn_moe.py) ----
+#
+# A model with window layers beside global ones (cfg.window_rows) runs the
+# same four programs over a kv_cache.WindowPool, and takes a
+# kv_cache.WindowTables where every other model takes one page table: the
+# global layers' rows are written and read through `glob` as a Llama's
+# are; the window layers' rows through `win`, which holds only the pages
+# that reach into the window.
+
+
+def _window_prefill(params, cfg, pool, tokens, lengths, tables, use_pallas):
+    """Prompts [N, S]: every layer's K and V go to its group's pages, a
+    window layer's through `tables.win`, whose entries behind the window
+    point at the sink (as a padded row's do). -> (last-position logits
+    [N, V], pool)."""
+    N, S = tokens.shape
+    ps = pool.page_size
+    x, kv, _ = window_attn_moe.walk_prompt(
+        params, cfg, tokens, lengths, use_pallas,
+        encode=pool.glob.encode_pages)  # [L, N, KH, S, ...] x 4
+
+    def paged(t):  # [R, N, KH, S, ...] -> [R, KH, N * npages, ps, ...]
+        R, _, KH = t.shape[:3]
+        rest = t.shape[4:]
+        t = t.reshape(R, N, KH, S // ps, ps, *rest)
+        order = (0, 2, 1, 3, 4) + tuple(5 + i for i in range(len(rest)))
+        return t.transpose(*order).reshape(R, KH, N * (S // ps), ps, *rest)
+
+    def write(rows_pool, kind, table):
+        layers = np.asarray([l for l, (k, _) in enumerate(
+            window_attn_moe.layer_plan(cfg)) if k == kind])
+        return rows_pool.write_pages(tuple(paged(t[layers]) for t in kv),
+                                     table.reshape(-1))
+
+    pool = dataclasses.replace(
+        pool, glob=write(pool.glob, window_attn_moe.GLOBAL, tables.glob),
+        win=write(pool.win, window_attn_moe.WINDOW, tables.win))
+    last = jnp.take_along_axis(
+        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
+    return window_attn_moe.logits_of(cfg, params, last)[:, 0], pool
+
+
+def _window_decode_once(params, cfg, pool, tokens, tables, lengths,
+                        use_pallas, mask=None):
+    """_decode_once for a model with window layers, the blocks unrolled in
+    published order: a global layer appends and attends through
+    `tables.glob` as a Llama's does; a window layer through `tables.win`,
+    with the slot's length and its window's first token counted from the
+    table's first page (`tables.base`): the kernel walks the pages the
+    table holds and masks, inside the first, the tokens that slid out.
+    The router reads the ATTENTION's input, so a layer's experts and gates
+    depend on nothing its attention computes. `mask` [B]: the live slots;
+    where the kernels are on they walk those alone. Returns (logits
+    [B, V], pool, pairs each expert took in each block [L, E], the
+    router's choices [L, B, k])."""
+    B = tokens.shape[0]
+    ps = pool.page_size
+    rows = jnp.arange(B)
+    positions = (lengths - 1)[:, None]
+    live = kernel_live_rows(pool, mask, use_pallas)
+    rel = lengths - tables.base  # counted from the window table's first page
+    starts = jnp.maximum(lengths - cfg.window, 0) - tables.base
+    slots = {
+        window_attn_moe.GLOBAL: token_slots(
+            cfg.n_kv_heads, tables.glob[rows, (lengths - 1) // ps],
+            (lengths - 1) % ps, use_pallas, live=live),
+        window_attn_moe.WINDOW: token_slots(
+            cfg.n_kv_heads, tables.win[rows, (rel - 1) // ps],
+            (rel - 1) % ps, use_pallas, live=live)}
+    groups = {window_attn_moe.GLOBAL: pool.glob,
+              window_attn_moe.WINDOW: pool.win}
+    x = window_attn_moe.embed(cfg, params, tokens)[:, None]  # [B, 1, D]
+    sliced, experts = window_attn_moe.split_experts(params["layers"])
+    counts, choices = [], []
+    for l, (kind, row) in enumerate(window_attn_moe.layer_plan(cfg)):
+        w = window_attn_moe.take_layer(sliced, l)
+        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
+        idx, gates = window_attn_moe.route(cfg, h[:, 0], w["router"])
+        q, k, v = window_attn_moe.project_qkv(cfg, h, w, positions,
+                                              cfg.rope_layout[l])
+        pages = groups[kind].append(row, slots[kind],
+                                    k[:, :, 0].transpose(1, 0, 2),
+                                    v[:, :, 0].transpose(1, 0, 2))
+        groups[kind] = pages
+        kv, _, kv_scales, layer = pages.attention_operands(row)
+        if kind == window_attn_moe.WINDOW:
+            with jax.named_scope("attn.window"):
+                out = paged_attention_dispatch(
+                    q[:, :, 0], kv, None, tables.win, rel,
+                    k_scales=kv_scales, layer=layer, use_pallas=use_pallas,
+                    live=live, starts=starts)
+        else:
+            with jax.named_scope("attn.global"):
+                out = paged_attention_dispatch(
+                    q[:, :, 0], kv, None, tables.glob, lengths,
+                    k_scales=kv_scales, layer=layer, use_pallas=use_pallas,
+                    live=live)
+        x = window_attn_moe.attn_out(cfg, x, out[:, :, None, :], w)
+        x, n = window_attn_moe.feed_forward(cfg, x, w, experts, l, idx,
+                                            gates, use_pallas, mask)
+        counts.append(n)
+        choices.append(idx)
+    logits = window_attn_moe.logits_of(cfg, params, x)[:, 0]
+    pool = dataclasses.replace(pool, glob=groups[window_attn_moe.GLOBAL],
+                               win=groups[window_attn_moe.WINDOW])
+    return logits, pool, jnp.stack(counts), jnp.stack(choices)
+
+
 def _expert_decode_once(cfg):
     """The decode body of a model the Llama walk does not run, or None:
     `(params, cfg, pool, tokens, page_tables, lengths, use_pallas, mask)
@@ -410,6 +518,8 @@ def _expert_decode_once(cfg):
         return _hybrid_decode_once
     if cfg.index_row is not None:
         return _sparse_decode_once
+    if cfg.window_rows is not None:
+        return _window_decode_once
     return None
 
 
@@ -506,6 +616,10 @@ def prefill_step(
         logits, pool = _sparse_prefill(params, cfg, pool, tokens,
                                        length[None], table_row, use_pallas)
         return logits[0], pool
+    if cfg.window_rows is not None:
+        logits, pool = _window_prefill(params, cfg, pool, tokens,
+                                       length[None], table_row, use_pallas)
+        return logits[0], pool
     _, S = tokens.shape
     ps = pool.page_size
     npages = S // ps
@@ -583,6 +697,11 @@ def prefill_batch_step(
                       any_top_k=any_top_k, any_top_p=any_top_p), pool
     if cfg.index_row is not None:
         logits, pool = _sparse_prefill(params, cfg, pool, tokens, lengths,
+                                       table_rows, use_pallas)
+        return sample(logits, sp, key, all_greedy=all_greedy,
+                      any_top_k=any_top_k, any_top_p=any_top_p), pool
+    if cfg.window_rows is not None:
+        logits, pool = _window_prefill(params, cfg, pool, tokens, lengths,
                                        table_rows, use_pallas)
         return sample(logits, sp, key, all_greedy=all_greedy,
                       any_top_k=any_top_k, any_top_p=any_top_p), pool

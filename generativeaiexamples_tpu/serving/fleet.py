@@ -82,6 +82,7 @@ _COUNTER_KEYS = (
     "moe_pairs_routed", "moe_pairs_local",
     "ssm_slot_writes", "ssm_steps_kernel",
     "sparse_keys_scored", "sparse_rows_attended", "sparse_steps_dense",
+    "window_pages_released", "decode_attn_window_pages_walked",
     "sparse_attn_pages_walked", "sparse_attn_blocks_walked",
     "prefill_tokens", "fused_steps",
     "fused_prefill_tokens", "prefill_stall_beats",

@@ -107,6 +107,15 @@ EV_DECODE_JOIN = 21
 # (min(length, topk) / length, summed: 1.0 while every context is
 # within topk).
 EV_SPARSE_SELECT = 22
+# Window and global layers in one model: one a landed decode block of a
+# model with window rows (scheduler thread), from the lengths and tables
+# the host dispatched the block with. a = cached tokens the block's
+# attention calls see over layers x context of its live slots (what one
+# kind of row would have seen); b = pages x rows the live slots hold in
+# both pools over what one table for every row would hold; aux =
+# "window_pages=<n> calls=<m>", the pages (a page a live slot and call)
+# the window rows' kernel calls walked in the block, and those calls.
+EV_WINDOW_CACHE = 23
 
 # Program classes (EV_PROGRAM.code).
 PROG_DECODE = 0    # a decode block (n = steps K)
@@ -134,6 +143,7 @@ EVENT_NAMES = {
     EV_CHAOS: "chaos", EV_KV_TRANSFER: "kv_transfer",
     EV_MOE_LOAD: "moe_load", EV_PROGRAM: "program",
     EV_DECODE_JOIN: "decode_join", EV_SPARSE_SELECT: "sparse_select",
+    EV_WINDOW_CACHE: "window_cache",
 }
 
 # Retire reason codes (EV_RETIRE.code); anything unknown maps to -1.

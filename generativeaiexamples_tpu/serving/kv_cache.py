@@ -32,6 +32,13 @@ inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
   SAME page table, the index keys, written by the same append and the
   same page write and freed with the same pages
   (serving/sparse_index_scores.py reads them).
+- A model with window layers beside global ones (cfg.window_rows) gets a
+  WindowPool: TWO int8 pools, each with its own PageAllocator and its own
+  page table a sequence. The global layers' rows grow with the sequence
+  as every pool above; the window layers' rows hold only the pages that
+  reach into the window, and a sequence's WindowSequencePages gives the
+  page that slid out back to the window allocator when the decode block
+  that moved the window past it has landed.
 - Host: PageAllocator hands out page ids (plain Python free list — the
   scheduler thread owns it; no device sync needed to allocate).
 - Page tables are [B, max_pages] int32 arrays shipped to the device each
@@ -259,6 +266,10 @@ class PagePool:
         recurrent state gets a HybridPool with room for `slots` decode
         slots beside the pages."""
         dtype = jnp.dtype(dtype or cfg.dtype)
+        if cfg.window_rows is not None:  # the model says what rows it has
+            raise ValueError(
+                "a model with window layers has two pools of pages: "
+                "WindowPool.zeros(cfg, n_pages, n_window_pages, page_size)")
         if cfg.index_row is not None:  # the model says what a token caches
             if dtype != jnp.int8:
                 raise ValueError(
@@ -747,6 +758,106 @@ class SparseIndexPool:
                    jnp.bfloat16, None))
 
 
+class WindowTables(NamedTuple):
+    """A WindowPool's page tables as the step programs take them where
+    every other pool's take one array. `glob` is that array, for the
+    global rows. A decode block's `win` [B, window_table_pages] is a
+    slot's window table, the pages it holds oldest first, and `base` [B]
+    the position of the first token of its first page (a multiple of the
+    page size): token t of the sequence lies in `win[(t - base) // ps]`.
+    A prefill's `win` [N, S // ps] is indexed like `glob`, the pages
+    behind the window pointing at the sink, and `base` is None."""
+
+    glob: jax.Array
+    win: jax.Array
+    base: Optional[jax.Array] = None
+
+
+@dataclasses.dataclass
+class WindowPool:
+    """The pool of a model with window layers beside global ones
+    (cfg.window_rows): two int8 pools in one donated tree, `glob` with a
+    row a global layer and `win` with a row a window layer
+    (window_attn_moe.layer_plan maps a layer to its row), each with its
+    own number of pages, its own PageAllocator and its own page table a
+    sequence (WindowTables). A page id means nothing across the two.
+
+    The lanes that re-read, share, move, snapshot or roll back cache
+    (prefix reuse, the pager, the disaggregated transfer, speculation,
+    the long-prompt scratch cache) know one table a sequence and pages
+    that are never given back while it lives: LLMEngine refuses them by
+    name for such a model."""
+
+    glob: "QuantPagePool"
+    win: "QuantPagePool"
+
+    @property
+    def page_size(self) -> int:
+        return self.glob.page_size
+
+    @property
+    def n_pages(self) -> int:
+        return self.glob.n_pages
+
+    @property
+    def quantized(self) -> bool:
+        return True
+
+    @property
+    def geometry(self) -> PoolGeometry:
+        """Of a page of either group's row; `rows` counts both."""
+        g = self.glob.geometry
+        return g._replace(rows=g.rows + self.win.geometry.rows)
+
+    def devices(self):
+        return self.glob.devices()
+
+    @staticmethod
+    def zeros(cfg, n_pages: int, n_window_pages: int,
+              page_size: int) -> "WindowPool":
+        wr = cfg.window_rows
+
+        def rows(n_rows, pages):
+            shape = (2, n_rows, cfg.n_kv_heads, pages, page_size,
+                     cfg.head_dim)
+            return QuantPagePool(_alloc(shape, jnp.int8, None),
+                                 _alloc(shape[:-1], jnp.float32, None),
+                                 page_size)
+
+        return WindowPool(rows(wr.n_global, n_pages),
+                          rows(wr.n_window, n_window_pages))
+
+
+def window_table_pages(window: int, page_size: int, ahead: int) -> int:
+    """Pages a sequence's window table holds at most: those that reach
+    into a window of `window` tokens, at any alignment (window //
+    page_size + 1 where the window starts inside a page), and `ahead`
+    tokens more, written by decode blocks that were dispatched before
+    the page behind them was released (a block of K steps, `depth` of
+    them in flight: ahead = depth * K; a run of K tokens that straddles
+    a page boundary takes the one page more)."""
+    return -(-(window + ahead) // page_size) + 1
+
+
+def engine_window_table_pages(window: int, ecfg) -> int:
+    """window_table_pages for an engine configuration: its decode blocks
+    of K steps, `pipeline_depth` of them in flight."""
+    return window_table_pages(
+        window, ecfg.page_size, max(1, ecfg.pipeline_depth)
+        * max(1, ecfg.decode_steps_per_dispatch))
+
+
+def window_pool_pages(window: int, ecfg) -> int:
+    """Pages of a WindowPool's window rows for an engine configuration:
+    every slot's table full, one sequence of slack (a retired slot's
+    pages free when its parked block lands) and the sink."""
+    return (ecfg.max_batch_size + 1) \
+        * engine_window_table_pages(window, ecfg) + 1
+
+
+jax.tree_util.register_dataclass(
+    WindowPool, data_fields=["glob", "win"], meta_fields=[]
+)
 jax.tree_util.register_dataclass(
     SparseIndexPool, data_fields=["pages", "idx"], meta_fields=[]
 )
@@ -781,8 +892,9 @@ class PageAllocator:
     eviction here so cold cached pages always yield to live traffic.
     """
 
-    def __init__(self, n_pages: int):
+    def __init__(self, n_pages: int, name: str = "KV"):
         self.n_pages = n_pages
+        self.name = name  # which pool's pages, in alloc's MemoryError
         self._free: List[int] = list(range(n_pages - 1, 0, -1))
         self._rc: dict = {}  # page id -> refcount (allocated pages only)
         self.reclaim = None
@@ -798,8 +910,8 @@ class PageAllocator:
         if n > len(self._free) and self.reclaim is not None:
             self.reclaim(n - len(self._free))
         if n > len(self._free):
-            raise MemoryError(f"KV page pool exhausted: want {n}, have "
-                              f"{len(self._free)} of {self.n_pages}")
+            raise MemoryError(f"{self.name} page pool exhausted: want {n}, "
+                              f"have {len(self._free)} of {self.n_pages}")
         out = [self._free.pop() for _ in range(n)]
         for p in out:
             self._rc[p] = 1
@@ -892,3 +1004,69 @@ class SequencePages:
         self.n_shared = 0
         if pages:
             self.allocator.release(pages)
+
+
+class WindowSequencePages(SequencePages):
+    """SequencePages for a model with window layers: the global rows'
+    pages as every sequence holds them (`pages`, `table_row`), and beside
+    them the WINDOW rows' pages from a second allocator: only those that
+    reach into `[length - window, length)`. `window_first` is the index,
+    in the sequence, of the first page held; `slide` gives the pages
+    behind a position back. A live sequence holds at most
+    `max_window_pages` of them (window_table_pages)."""
+
+    def __init__(self, allocator: PageAllocator,
+                 window_allocator: PageAllocator, page_size: int,
+                 max_pages: int, window: int, max_window_pages: int):
+        super().__init__(allocator, page_size, max_pages)
+        self.window_allocator = window_allocator
+        self.window = window
+        self.max_window_pages = max_window_pages
+        self.window_pages: List[int] = []
+        self.window_first = 0
+
+    def adopt(self, pages, n_tokens):
+        raise NotImplementedError("a prefix-cache hit has no meaning for a "
+                                  "window row's pages yet")
+
+    def ensure(self, new_length: int) -> None:
+        """Grow BOTH page lists to cover new_length tokens. The first
+        call (a prompt's) takes no window page that lies wholly behind
+        the window of the first decode step, which is at new_length."""
+        ps = self.page_size
+        if not self.window_pages:
+            self.window_first = max(0, new_length + 1 - self.window) // ps
+        more = -(-new_length // ps) - self.window_first \
+            - len(self.window_pages)
+        if len(self.window_pages) + more > self.max_window_pages:
+            raise MemoryError(
+                f"sequence needs {len(self.window_pages) + more} window "
+                f"pages > its table's {self.max_window_pages}")
+        if more > 0:
+            self.window_pages.extend(self.window_allocator.alloc(more))
+        super().ensure(new_length)
+
+    def slide(self, start: int) -> int:
+        """Release the window pages that lie wholly before token `start`
+        (never the last one). Returns how many went."""
+        n = min(max(0, start) // self.page_size - self.window_first,
+                len(self.window_pages) - 1)
+        if n <= 0:
+            return 0
+        gone, self.window_pages = self.window_pages[:n], self.window_pages[n:]
+        self.window_first += n
+        self.window_allocator.release(gone)
+        return n
+
+    def window_row(self):
+        """(the window table's row [max_window_pages], padding -> page 0;
+        the position of its first page's first token)."""
+        row = np.zeros((self.max_window_pages,), np.int32)
+        row[: len(self.window_pages)] = self.window_pages
+        return row, self.window_first * self.page_size
+
+    def release(self) -> None:
+        pages, self.window_pages = self.window_pages, []
+        if pages:
+            self.window_allocator.release(pages)
+        super().release()

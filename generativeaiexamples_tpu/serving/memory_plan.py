@@ -165,7 +165,7 @@ def _shard_numel(shape, spec, axis_sizes: Dict[str, int]) -> int:
 def _one_chip(lcfg) -> bool:
     """A model whose parameters have no sharded layout."""
     return lcfg.latent_row is not None or lcfg.recurrent_state is not None \
-        or lcfg.index_row is not None
+        or lcfg.index_row is not None or lcfg.window_rows is not None
 
 
 def state_pool_bytes_per_device(lcfg, ecfg: EngineConfig) -> int:
@@ -174,6 +174,19 @@ def state_pool_bytes_per_device(lcfg, ecfg: EngineConfig) -> int:
     max_batch_size slots, not paged; 0 for a model without it."""
     rs = lcfg.recurrent_state
     return 0 if rs is None else ecfg.max_batch_size * rs.bytes_per_slot
+
+
+def window_pool_bytes_per_device(lcfg, ecfg: EngineConfig) -> int:
+    """Bytes of the window layers' pool beside the global layers' pages
+    (kv_cache.WindowPool.win): a fixed cost of max_batch_size slots'
+    window tables, sized by the engine's own function; 0 for a model
+    without window layers."""
+    if lcfg.window_rows is None:
+        return 0
+    from generativeaiexamples_tpu.serving.kv_cache import window_pool_pages
+
+    return (window_pool_pages(lcfg.window_rows.window, ecfg) * ecfg.page_size
+            * pool_token_bytes(lcfg, ecfg, {})["window rows"])
 
 
 def weight_bytes_per_device(lcfg: LlamaConfig, axis_sizes: Dict[str, int],
@@ -190,19 +203,21 @@ def weight_bytes_per_device(lcfg: LlamaConfig, axis_sizes: Dict[str, int],
 
     if _one_chip(lcfg):
         # models/latent_moe.py, models/hybrid_ssm.py,
-        # models/sparse_attn_moe.py: whole on one chip (a share of the
-        # experts is the configuration's, not a mesh axis's)
+        # models/sparse_attn_moe.py, models/window_attn_moe.py: whole on
+        # one chip (a share of the experts is the configuration's, not a
+        # mesh axis's)
         if any(int(n) > 1 for n in axis_sizes.values()):
             raise MemoryPlanError(
-                "a model with latent attention, recurrent state or an "
-                f"indexer has no tensor-parallel layout: mesh axes "
-                f"{axis_sizes}")
+                "a model with latent attention, recurrent state, an "
+                f"indexer or window layers has no tensor-parallel layout: "
+                f"mesh axes {axis_sizes}")
         from generativeaiexamples_tpu.models import (
-            hybrid_ssm, latent_moe, sparse_attn_moe)
+            hybrid_ssm, latent_moe, sparse_attn_moe, window_attn_moe)
 
         model = (latent_moe if lcfg.latent_row is not None
                  else hybrid_ssm if lcfg.recurrent_state is not None
-                 else sparse_attn_moe)
+                 else sparse_attn_moe if lcfg.index_row is not None
+                 else window_attn_moe)
         shapes = jax.eval_shape(lambda: model.init_params_on_device(
             lcfg, quantize=quantize))
         return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
@@ -234,35 +249,55 @@ def weight_bytes_per_device(lcfg: LlamaConfig, axis_sizes: Dict[str, int],
     return total
 
 
-def pool_page_bytes_per_device(lcfg: LlamaConfig, ecfg: EngineConfig,
-                               axis_sizes: Dict[str, int]) -> int:
-    """Exact per-device bytes of ONE pool page.
+def pool_token_bytes(lcfg: LlamaConfig, ecfg: EngineConfig,
+                     axis_sizes: Dict[str, int]) -> Dict[str, int]:
+    """Exact per-device bytes ONE cached token takes in each pool of
+    pages, by the pool's name, over all of that pool's rows.
 
     bf16 PagePool: k/v each [L, KH, P, ps, Hd], kv-heads on tensor
     (sharding.KV_POOL_SPEC). Fused int8: codes [2, L, KH, P, ps, Hd]
     int8 + scales [2, L, KH, P, ps] f32, kv-heads on tensor
     (KV_FUSED_SPEC / KV_FUSED_SCALE_SPEC).
     """
-    ps = ecfg.page_size
     if lcfg.latent_row is not None:
         # kv_cache.LatentPagePool: ONE vector a token and row for all
         # heads, [c_kv ; k_rope] in whole 128-lane tiles; not
         # n_kv_heads * head_dim, and no second array for V
         from generativeaiexamples_tpu.serving.kv_cache import latent_lanes
 
-        return (lcfg.cache_rows * ps * latent_lanes(lcfg.latent_row)
-                * jnp.dtype(ecfg.kv_dtype).itemsize)
+        return {"latent rows": lcfg.cache_rows * latent_lanes(lcfg.latent_row)
+                * jnp.dtype(ecfg.kv_dtype).itemsize}
     tp = int(axis_sizes.get("tensor", 1))
     kh = math.ceil(lcfg.n_kv_heads / tp)
-    base = lcfg.cache_rows * kh * ps  # a row per (pass, block)
+    int8 = jnp.dtype(ecfg.kv_dtype) == jnp.int8 \
+        or lcfg.index_row is not None or lcfg.window_rows is not None
+
+    def kv(rows):  # K and V of `rows` rows (a row per (pass, block))
+        if int8:  # codes and a float32 scale a (head, token), each twice
+            return rows * kh * (2 * lcfg.head_dim + 2 * 4)
+        return rows * kh * 2 * lcfg.head_dim \
+            * jnp.dtype(ecfg.kv_dtype).itemsize
+
+    if lcfg.window_rows is not None:
+        # kv_cache.WindowPool: two int8 pools, each with pages of its own
+        return {"global rows": kv(lcfg.window_rows.n_global),
+                "window rows": kv(lcfg.window_rows.n_window)}
+    pools = {"K and V": kv(lcfg.cache_rows)}
     if lcfg.index_row is not None:
-        # kv_cache.SparseIndexPool: the int8 K and V and a bf16 index key
-        # a token and row
-        return 2 * base * lcfg.head_dim + 2 * base * 4 \
-            + lcfg.cache_rows * ps * lcfg.index_row * 2
-    if jnp.dtype(ecfg.kv_dtype) == jnp.int8:
-        return 2 * base * lcfg.head_dim + 2 * base * 4
-    return 2 * base * lcfg.head_dim * jnp.dtype(ecfg.kv_dtype).itemsize
+        # kv_cache.SparseIndexPool: a bf16 index key a token and row
+        pools["index keys"] = lcfg.cache_rows * lcfg.index_row * 2
+    return pools
+
+
+def pool_page_bytes_per_device(lcfg: LlamaConfig, ecfg: EngineConfig,
+                               axis_sizes: Dict[str, int]) -> int:
+    """Exact per-device bytes of ONE page of the pool that grows with a
+    sequence (`pool_token_bytes` over the pools under its page table; a
+    window layer's rows have pages of their own and a fixed number of
+    them: `window_pool_bytes_per_device`)."""
+    pools = pool_token_bytes(lcfg, ecfg, axis_sizes)
+    pools.pop("window rows", None)
+    return ecfg.page_size * sum(pools.values())
 
 
 def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
@@ -367,6 +402,14 @@ def plan_engine_memory(
             "state_pool", state_pool_bytes_per_device(lcfg, ecfg), False,
             f"{ecfg.max_batch_size} slots x "
             f"{lcfg.recurrent_state.bytes_per_slot} B, not paged"),)
+
+    if lcfg.window_rows is not None:
+        per = pool_token_bytes(lcfg, ecfg, sizes)
+        lines += (PlanLine(
+            "window_pool", window_pool_bytes_per_device(lcfg, ecfg), False,
+            f"{ecfg.max_batch_size} slots' window tables, "
+            f"{per['window rows']} B a cached token (the paged pool below: "
+            f"{per['global rows']} B)"),)
 
     page = pool_page_bytes_per_device(lcfg, ecfg, sizes)
     fixed = sum(l.bytes_per_device for l in lines)

@@ -48,8 +48,11 @@ _KERNEL_CHOICE = os.environ.get("ENGINE_PAGED_KERNEL", "auto")
 def paged_attention_reference(
     q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     page_table: jax.Array, lengths: jax.Array, *, scale: Optional[float] = None,
+    starts: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Gather-based paged attention (any backend; the numerics oracle)."""
+    """Gather-based paged attention (any backend; the numerics oracle).
+    `starts` [B]: the first token a row sees (a window row's, counted
+    from its table's first page as `lengths` is); None: every token."""
     B, H, Hd = q.shape
     KH, P, ps, _ = k_pages.shape
     maxp = page_table.shape[1]
@@ -64,7 +67,7 @@ def paged_attention_reference(
     from generativeaiexamples_tpu.ops.attention import mha_reference
 
     out = mha_reference(q[:, :, None, :], k, v, causal=False, lengths=lengths,
-                        scale=scale)
+                        scale=scale, kv_start=starts)
     return out[:, :, 0, :]
 
 
@@ -302,25 +305,30 @@ def _paged_tpu(q, k_pages, v_pages, page_table, lengths, *, scale,
 
 
 def _paged_tpu_int8(q, kv_pages, kv_scales, page_table, lengths, layer,
-                    live=None, *, scale, pages_per_compute_block):
+                    live=None, starts=None, *, scale, pages_per_compute_block):
     from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-        paged_attention_int8, paged_attention_int8_reference_fused)
+        paged_attention_int8, paged_attention_int8_reference_fused,
+        paged_attention_int8_window)
 
     ps, Hd = kv_pages.shape[-2], kv_pages.shape[-1]
     # Mosaic DMA slices must be 128-lane aligned: the kernel needs
     # page_size % 128 == 0 (scale pages are (1, ps) f32 tiles) and
     # head_dim % 128 == 0. int8 serving configs use page_size=128.
     if ps % 128 == 0 and Hd % 128 == 0:
+        kw = dict(scale=scale, live=live,
+                  pages_per_compute_block=pages_per_compute_block)
+        if starts is not None:
+            return paged_attention_int8_window(
+                q, kv_pages, kv_scales, page_table, lengths, layer, starts,
+                **kw)
         return paged_attention_int8(
-            q, kv_pages, kv_scales, page_table, lengths, layer,
-            scale=scale, pages_per_compute_block=pages_per_compute_block,
-            live=live)
+            q, kv_pages, kv_scales, page_table, lengths, layer, **kw)
     log_kernel_declined(
         "paged_attention_int8", "the XLA gather reference",
         f"page_size {ps} and head_dim {Hd} must both be multiples of 128")
     return paged_attention_int8_reference_fused(
         q, kv_pages[:, layer], kv_scales[:, layer], page_table, lengths,
-        scale=scale)
+        scale=scale, starts=starts)
 
 
 def paged_attention_dispatch(
@@ -328,14 +336,17 @@ def paged_attention_dispatch(
     k_scales=None, layer=None,
     use_pallas: Optional[bool] = None, mesh=None, interpret: bool = False,
     pages_per_compute_block: Optional[int] = None,
-    live=None,
+    live=None, starts=None,
 ):
     """Pick the fastest available implementation for the current
     backend/mesh. `lengths` INCLUDES the current token, whose k/v must
     already be written to the pool (write-then-attend decode). `live`
     (kv_cache.kernel_live_rows of the step's `active` mask, or None):
     the rows the int8 kernel walks, an idle row's output zeros; no other
-    form reads it.
+    form reads it. `starts` [B] (a WINDOW row of an int8 pool on one
+    chip, kv_cache.WindowPool): `page_table` is then the sequence's
+    window table and `lengths` and `starts` count from its first page;
+    the row attends tokens [starts, lengths).
 
     Quantized (fused) form: `v_pages=None`, `k_pages` holds the FULL
     fused int8 pool [2, L, KH, P, ps, Hd], `k_scales` the full narrow
@@ -353,9 +364,12 @@ def paged_attention_dispatch(
 
             return paged_attention_int8_reference_fused(
                 q, k_pages[:, layer], k_scales[:, layer], page_table,
-                lengths, scale=scale)
+                lengths, scale=scale, starts=starts)
+        assert starts is None, "a window row lives in an int8 pool"
         return paged_attention_reference(q, k_pages, v_pages, page_table,
                                          lengths, scale=scale)
+    assert starts is None or (quantized and mesh is None), (
+        "a window row lives in an int8 pool on one chip")
     if mesh is not None and mesh.shape.get("tensor", 1) > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -383,7 +397,7 @@ def paged_attention_dispatch(
         return fn(q, k_pages, v_pages, page_table, lengths)
     if quantized:
         return _paged_tpu_int8(q, k_pages, k_scales, page_table, lengths,
-                               layer, live, scale=scale,
+                               layer, live, starts, scale=scale,
                                pages_per_compute_block=pages_per_compute_block)
     return _paged_tpu(q, k_pages, v_pages, page_table, lengths, scale=scale,
                       interpret=interpret,

@@ -19,6 +19,9 @@ Layouts (per layer, matching kv_cache.QuantPagePool):
   lengths    [B] int32           valid tokens INCLUDING the current one
   live       LiveRows or None    the step's `active` mask as the kernels
                                  prefetch it (live_rows); None: every row
+  starts     [B] int32 or None   a WINDOW row's first visible token (as
+                                 `lengths`, counted from the table's
+                                 first page); None: every token
 
 Kernel shape: grid (n_live,), a dynamic bound — ONE grid step per LIVE
 batch row (grid step k serves row order[k]) covering ALL kv heads, as a
@@ -84,24 +87,27 @@ def dequantize_pages(q_pages: jax.Array, scales: jax.Array,
 
 
 def paged_attention_int8_reference(q, k_pages, k_scales, v_pages, v_scales,
-                                   page_table, lengths, *, scale=None):
+                                   page_table, lengths, *, scale=None,
+                                   starts=None):
     """Dequantize-then-attend oracle over UNFUSED pages (any backend;
-    numerics tests build k/v separately)."""
+    numerics tests build k/v separately). `starts` [B]: the first token
+    a row sees (a window row's); None: every token."""
     from generativeaiexamples_tpu.serving.paged_attention import (
         paged_attention_reference)
 
     k = dequantize_pages(k_pages, k_scales)
     v = dequantize_pages(v_pages, v_scales)
     return paged_attention_reference(q, k, v, page_table, lengths,
-                                     scale=scale).astype(q.dtype)
+                                     scale=scale,
+                                     starts=starts).astype(q.dtype)
 
 
 def paged_attention_int8_reference_fused(q, kv_pages, kv_scales, page_table,
-                                         lengths, *, scale=None):
+                                         lengths, *, scale=None, starts=None):
     """Oracle over the fused [2, KH, P, ps, Hd] layout."""
     return paged_attention_int8_reference(
         q, kv_pages[0], kv_scales[0], kv_pages[1], kv_scales[1],
-        page_table, lengths, scale=scale)
+        page_table, lengths, scale=scale, starts=starts)
 
 
 def fuse_kv(kq, ks, vq, vs):
@@ -184,6 +190,14 @@ def every_row(n_rows: int) -> LiveRows:
                     jnp.full((1,), n_rows, jnp.int32))
 
 
+def _int8_window_kernel(lengths_ref, tables_ref, layer_ref, order_ref,
+                        n_live_ref, starts_ref, *refs, **static):
+    """_int8_kernel for a WINDOW row: one scalar prefetch more, a row's
+    first visible token (`starts` [B])."""
+    _int8_kernel(lengths_ref, tables_ref, layer_ref, order_ref, n_live_ref,
+                 *refs, starts_ref=starts_ref, **static)
+
+
 def _int8_kernel(
     lengths_ref,   # scalar prefetch [B]
     tables_ref,    # scalar prefetch [B * maxp]
@@ -206,6 +220,7 @@ def _int8_kernel(
     q_rep: int = 1,
     tree=None,
     split_kv: bool = False,
+    starts_ref=None,
 ):
     """One grid step per LIVE BATCH ROW, all kv heads + k and v together:
     step k serves row order[k] through the q and o index maps, and the
@@ -249,7 +264,12 @@ def _int8_kernel(
        at B, and so does the grid, so there is no dead step to guard: no
        `pl.when` around the body, no index map that has to hold the last
        live row's blocks. With all of 64 rows idle a call is 1.84 us
-       where it was 35.33 (PERF.md section 5, PR 41)."""
+       where it was 35.33 (PERF.md section 5, PR 41).
+    5. A WINDOW row (`starts_ref`; q_rep 1, no tree): its table holds only
+       the pages that reach into the window, so the walk is the table's
+       from its first page, and one compare more masks the tokens of that
+       page that slid out already: exact to the token. No other row
+       compiles the compare or the prefetch."""
     k = pl.program_id(0)
     n_live = n_live_ref[0]
     b = order_ref[k]
@@ -371,7 +391,10 @@ def _int8_kernel(
                 if q_rep > 1:
                     limit = length + lax.broadcasted_iota(
                         jnp.int32, s.shape, 1) // g_base
-                s = jnp.where(pos < limit, s, NEG_INF)
+                keep = pos < limit
+                if starts_ref is not None:
+                    keep &= pos >= starts_ref[b]
+                s = jnp.where(keep, s, NEG_INF)
 
             m_curr = jnp.max(s, axis=2, keepdims=True)  # [KH, G, 1]
             m_new = jnp.maximum(m_prev, m_curr)
@@ -420,11 +443,31 @@ def page_counts(lengths, page_size: int, max_pages: int,
     return int(n.sum()), int(walked.sum())
 
 
-@functools.partial(jax.jit, static_argnames=("scale",
-                                             "pages_per_compute_block",
-                                             "q_rep", "tree",
-                                             "interpret", "split_kv"))
-def paged_attention_int8(
+_STATIC = ("scale", "pages_per_compute_block", "q_rep", "tree", "interpret",
+           "split_kv")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def paged_attention_int8_window(q, kv_pages, kv_scales, page_table, lengths,
+                                layer, starts, **kw):
+    """paged_attention_int8 for a WINDOW row of a kv_cache.WindowPool:
+    `page_table` [B, maxw] is the sequence's window table (the pages
+    that reach into the window, oldest first), `lengths` and `starts`
+    [B] count from that table's first page: the row attends tokens
+    [starts, lengths). A program of its own under a name of its own, so
+    that a trace tells the window rows' calls from the global rows'."""
+    return _paged_attention_int8(q, kv_pages, kv_scales, page_table, lengths,
+                                 layer, starts=starts, **kw)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def paged_attention_int8(q, kv_pages, kv_scales, page_table, lengths, layer,
+                         **kw):
+    return _paged_attention_int8(q, kv_pages, kv_scales, page_table, lengths,
+                                 layer, **kw)
+
+
+def _paged_attention_int8(
     q: jax.Array,          # [B, H, Hd], or [B, R, H, Hd] when q_rep=R>1
     kv_pages: jax.Array,   # FULL pool [2, L, KH, P, ps, Hd] int8
     kv_scales: jax.Array,  # FULL scales [2, L, KH, P, ps] f32
@@ -440,8 +483,12 @@ def paged_attention_int8(
     interpret: bool = False,
     split_kv: bool | None = None,
     live: Optional[LiveRows] = None,
+    starts: Optional[jax.Array] = None,  # [B] int32: a window row's
 ) -> jax.Array:
-    """`live` (live_rows of the step's `active` mask): the kernel walks
+    """What `paged_attention_int8` (starts None) and
+    `paged_attention_int8_window` run.
+
+    `live` (live_rows of the step's `active` mask): the kernel walks
     those rows alone, and an idle row's output is zeros, whatever its
     length and table row say. None: every row is live.
 
@@ -487,11 +534,13 @@ def paged_attention_int8(
     if split_kv is None:  # from the pool's shape alone
         split_kv = L * KH * P * ps * Hd >= SPLIT_KV_BYTES
     ahead = BLOCKS_AHEAD
-    kernel = functools.partial(_int8_kernel, ppcb=ppcb, maxp=maxp,
-                               page_size=ps, ahead=ahead,
-                               q_rep=q_rep, tree=tree, split_kv=split_kv)
+    assert starts is None or (q_rep == 1 and tree is None), (q_rep, tree)
+    kernel = functools.partial(
+        _int8_kernel if starts is None else _int8_window_kernel, ppcb=ppcb,
+        maxp=maxp, page_size=ps, ahead=ahead, q_rep=q_rep, tree=tree,
+        split_kv=split_kv)
 
-    def qmap(k, Ln, T, LY, order, n_walk):
+    def qmap(k, Ln, T, LY, order, *_):
         return (order[k], 0, 0, 0)
 
     rows = every_row(B) if live is None else live
@@ -500,7 +549,7 @@ def paged_attention_int8(
     # select below discards what comes of it)
     n_walk = jnp.maximum(rows.n_live, 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=5 if starts is None else 6,
         grid=(n_walk[0],),
         in_specs=[
             pl.BlockSpec((1, KH, G, Hd), qmap),
@@ -528,8 +577,10 @@ def paged_attention_int8(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name=None if starts is None else "paged_attention_int8_window",
     )(lengths, page_table.reshape(-1).astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), rows.order, n_walk,
+      *(() if starts is None else (starts.astype(jnp.int32),)),
       qk, kv_pages, s2)
     if live is not None:
         # a row the grid never served holds whatever the buffer held: a

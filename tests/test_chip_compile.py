@@ -26,7 +26,7 @@ from generativeaiexamples_tpu.ops.encoder_attention import encoder_attention
 from generativeaiexamples_tpu.serving.paged_attention import paged_attention
 from generativeaiexamples_tpu.ops.quant import QuantizedTensor
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-    live_rows, paged_attention_int8)
+    live_rows, paged_attention_int8, paged_attention_int8_window)
 from generativeaiexamples_tpu.serving.paged_attention_mla import (
     paged_attention_mla)
 from generativeaiexamples_tpu.serving.paged_attention_sparse import (
@@ -230,6 +230,37 @@ KERNELS = {
         lambda *a: _grouped(64, *a),
         [((32768 + 128 * 64, 768), BF16), ((12, 128, 768, 2048), I8),
          ((12, 128, 2048), F32), ((640,), I32), ((1,), I32)]),
+    # SmallThinker-21BA3B's stage
+    # (benchmark/configs/smallthinker-21b-a3b-int8.json): 64 slots; a
+    # WINDOW row's call with the step's mask, as _window_decode_once makes
+    # it: a table of 34 pages over the 9-row window pool, a start a row;
+    # a prompt's flash attention under the window (blocks behind it
+    # skipped); the grouped matmul's fourth shape, 64 whole experts of 768
+    # at a width of 2,560: a decode step's 384 pairs and a prompt's 4,096
+    # tokens of 6 pairs in tiles of 64
+    "paged_decode_int8_window_masked_tables_of_34": (
+        lambda q, kv, s, t, ln, st, m: paged_attention_int8_window(
+            q, kv, s, t, ln, 7, st, live=live_rows(m)),
+        [((64, 28, HD), BF16), ((2, 9, 4, 2211, PS, HD), I8),
+         ((2, 9, 4, 2211, PS), F32), ((64, 34), I32), ((64,), I32),
+         ((64,), I32), ((64,), jnp.bool_)]),
+    "flash_prefill_window_4096_of_8192": (
+        lambda q, k, v, ln: flash_attention(q, k, v, causal=True,
+                                            lengths=ln, window=4096),
+        [((1, 28, 8192, HD), BF16)] + [((1, 4, 8192, HD), BF16)] * 2
+        + [((1,), I32)]),
+    "grouped_expert_matmul_decode_64_of_768": (
+        lambda *a: _grouped(32, *a),
+        [((384 + 64 * 32, 2560), BF16), ((12, 64, 2560, 1536), I8),
+         ((12, 64, 1536), F32), ((76,), I32), ((1,), I32)]),
+    "grouped_expert_matmul_prefill_64_of_768": (
+        lambda *a: _grouped(64, *a),
+        [((24576 + 64 * 64, 2560), BF16), ((12, 64, 2560, 1536), I8),
+         ((12, 64, 1536), F32), ((448,), I32), ((1,), I32)]),
+    "grouped_expert_matmul_prefill_down_64_of_768": (
+        lambda *a: _grouped(64, *a),
+        [((24576 + 64 * 64, 768), BF16), ((12, 64, 768, 2560), I8),
+         ((12, 64, 2560), F32), ((448,), I32), ((1,), I32)]),
 }
 
 
@@ -681,3 +712,59 @@ def test_the_sparse_decode_program_runs_its_kernels_under_their_names(chip):
                "paged_attention_sparse_pallas"):
         assert text.count(f"call @{fn}") == 12, fn
     assert "paged_attention" not in "sparse_index_scores sparse_select"
+
+
+# -- window and full attention in one model: the configuration's own shapes
+# (PR 44). The decode program of `smallthinker-21b-a3b-int8`, lowered for
+# the chip from its architecture entry's `compile_shapes` with kernels on:
+# every layer appends through the int8 pool's kernel into its own group
+# of rows, a global layer attends through the kernel every int8 pool has
+# and a window layer through the same body under a name of its own, which
+# the benchmark's readers tell apart (`trace_kernel` matches by substring:
+# `paged_attention` finds both, `paged_attention_int8_window` the one).
+def test_the_window_decode_program_runs_its_kernels_under_their_names(chip):
+    import json
+
+    from benchmark import architectures
+    from benchmark.harness import system
+    from generativeaiexamples_tpu.serving import engine_model as em
+    from generativeaiexamples_tpu.serving.kv_cache import WindowTables
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "smallthinker-21b-a3b-int8.json")) as fh:
+        config = json.load(fh)
+    ecfg = system.engine_config(config)
+    mcfg, params, pool, mesh = architectures.load(config).compile_shapes(
+        config, ecfg, [next(iter(chip.device_set))])
+    assert mesh is None and tuple(mcfg.window_rows) == (4096, 3, 9)
+    assert pool.glob.kv.shape == (2, 3, 4, 8448, 128, 128)
+    assert pool.win.kv.shape == (2, 9, 4, 2211, 128, 128)
+    # each half of each group stays under the one-descriptor limit
+    from generativeaiexamples_tpu.serving.paged_attention_int8 import (
+        SPLIT_KV_BYTES)
+    import math
+    assert math.prod(pool.glob.kv.shape[1:]) < SPLIT_KV_BYTES
+    assert math.prod(pool.win.kv.shape[1:]) < SPLIT_KV_BYTES
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, maxp = ecfg.max_batch_size, ecfg.max_seq_len // ecfg.page_size
+    assert (slots, maxp) == (64, 128)
+    tables = WindowTables(arr((slots, maxp), I32), arr((slots, 34), I32),
+                          arr((slots,), I32))
+    text = em.decode_multi_step.lower(
+        params, mcfg, pool, arr((slots,), I32), tables,
+        arr((slots,), I32), arr((slots,), jnp.bool_), arr((slots,), F32),
+        arr((slots,), F32), arr((slots,), I32), arr((2,), jnp.uint32), 1,
+        True, sampling_flags=(True, False, False)).as_text()
+    # (a jitted function is one body in the text, called once a layer of
+    # its kind; the append has a body a group of rows, whose shapes
+    # differ; the grouped matmul is inlined, two a layer)
+    for kernel, bodies in (("paged_attention_int8_window", 1),
+                           ("_int8_kernel", 1), ("kv_append_int8", 2),
+                           ("moe_grouped_matmul_int8", 24)):
+        assert text.count(f'kernel_name = "{kernel}"') == bodies, kernel
+    assert text.count("call @paged_attention_int8_window") == 9
+    assert text.count("call @paged_attention_int8(") == 3

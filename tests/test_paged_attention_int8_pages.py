@@ -237,3 +237,85 @@ def test_page_counts_of_a_known_batch():
     assert pa8.page_counts(np.stack([lengths, lengths + 1]), 128, 20,
                            block=5, mask=mask) == (
         (1 + 2 + 5 + 20) + (1 + 2 + 6 + 20), 2 * walked + 5)
+
+
+# -- a WINDOW row's start (kv_cache.WindowPool; PR 44) ----------------------
+# name: (lengths, starts, the rows that are live or None), each counted
+# from the row's table's first page as the step program counts them. The
+# start lies at zero, inside the first page, on a page boundary, inside a
+# later page (the table then holds a page wholly behind the window: two
+# blocks in flight), and on the row's last token.
+WINDOWED = {
+    "at_zero": ([13, 3 * PS, 1], [0, 0, 0], None),
+    "inside_the_first_page": ([2 * PS + 3, 4 * PS, PS], [3, PS - 1, 5],
+                              None),
+    "on_a_page_boundary": ([3 * PS, 2 * PS + 1, 4 * PS], [PS, 2 * PS, PS],
+                           None),
+    "a_page_wholly_behind": ([4 * PS, 3 * PS + 2, 9], [PS + 2, 2 * PS + 1, 0],
+                             None),
+    "the_last_token_alone": ([17, 4 * PS, 1], [16, 4 * PS - 1, 0], None),
+    "an_idle_row_between": ([2 * PS + 3, 1, 4 * PS], [3, 0, PS + 1],
+                            [True, False, True]),
+    "several_blocks": ([20 * PS, 11 * PS + 5, 6 * PS], [2 * PS + 5, PS, 7],
+                       None),
+}
+
+
+@pytest.mark.parametrize("split_kv", [False, True])
+@pytest.mark.parametrize("case", list(WINDOWED))
+def test_a_window_rows_start_masks_to_the_token(case, split_kv):
+    """paged_attention_int8_window against the gather reference under a
+    DENSE mask `starts <= s < lengths`: exact to the token wherever the
+    start lies; pages past the row's last are still never touched."""
+    lengths, starts, mask = WINDOWED[case]
+    lengths, starts = np.asarray(lengths, np.int32), np.asarray(starts,
+                                                                np.int32)
+    B = len(lengths)
+    maxp, block = (20, 5) if case == "several_blocks" else (4, None)
+    pages = B * maxp + 2
+    kv, s = _pool(pages, seed=len(case))
+    n = np.clip(-(-lengths // PS), 1, maxp)
+    live = np.arange(maxp)[None, :] < n[:, None]
+    if mask is not None:
+        live &= np.asarray(mask)[:, None]
+    own = 1 + np.arange(B * maxp).reshape(B, maxp)
+    poisoned = jnp.asarray(np.where(live, own, pages - 1), jnp.int32)
+    clean = jnp.asarray(np.where(live, own, 0), jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(7), (B, H, HD), jnp.float32)
+    rows = None if mask is None else pa8.live_rows(jnp.asarray(mask))
+    got = np.asarray(pa8.paged_attention_int8_window(
+        q, kv, s, poisoned, jnp.asarray(lengths), LAYER, jnp.asarray(starts),
+        pages_per_compute_block=block, split_kv=split_kv, interpret=True,
+        live=rows))
+    assert np.isfinite(got).all(), "a dead page was copied or multiplied"
+    want = np.asarray(pa8.paged_attention_int8_reference_fused(
+        q, kv[:, LAYER], s[:, LAYER], clean, jnp.asarray(lengths),
+        starts=jnp.asarray(starts)))
+    served = np.ones(B, bool) if mask is None else np.asarray(mask)
+    np.testing.assert_allclose(got[served], want[served], atol=2e-5,
+                               rtol=2e-5)
+    assert not got[~served].any()
+    # the dense mask by hand, one row: softmax over tokens [start, length)
+    b = int(np.flatnonzero(served)[-1])
+    flat = np.asarray(clean)[b]
+    k = (np.asarray(kv[0, LAYER, 0][flat], np.float32)
+         * np.asarray(s[0, LAYER, 0][flat])[..., None]).reshape(-1, HD)
+    v = (np.asarray(kv[1, LAYER, 0][flat], np.float32)
+         * np.asarray(s[1, LAYER, 0][flat])[..., None]).reshape(-1, HD)
+    sc = (np.asarray(q[b, 0]) @ k.T) * HD ** -0.5
+    keep = (np.arange(len(sc)) >= starts[b]) & (np.arange(len(sc))
+                                                < lengths[b])
+    p = np.where(keep, np.exp(sc - sc[keep].max()), 0.0)
+    np.testing.assert_allclose(got[b, 0], (p / p.sum()) @ v, atol=2e-5,
+                               rtol=2e-5)
+    # a start of zero is the kernel every other row runs, bit for bit
+    if not starts.any():
+        plain = np.asarray(pa8.paged_attention_int8(
+            q, kv, s, poisoned, jnp.asarray(lengths), LAYER,
+            pages_per_compute_block=block, split_kv=split_kv,
+            interpret=True, live=rows))
+        np.testing.assert_array_equal(plain, got)
+    else:  # ... and a start past zero is another answer than none
+        plain = np.asarray(pa8.paged_attention_int8_reference_fused(
+            q, kv[:, LAYER], s[:, LAYER], clean, jnp.asarray(lengths)))
+        assert not np.allclose(plain[served], got[served], atol=1e-3)
