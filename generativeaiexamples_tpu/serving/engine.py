@@ -74,7 +74,8 @@ from generativeaiexamples_tpu.serving.kv_cache import (
     window_pool_pages)
 from generativeaiexamples_tpu.serving.ssm_state_update import kernel_update
 from generativeaiexamples_tpu.serving import flight as flight_mod
-from generativeaiexamples_tpu.serving.paged_attention_int8 import page_counts
+from generativeaiexamples_tpu.serving.paged_attention_int8 import (
+    PAGES_PER_BLOCK, fold_pages, page_counts)
 from generativeaiexamples_tpu.serving.paged_attention_sparse import walk_counts
 from generativeaiexamples_tpu.serving.multihost import (
     fetch_addressable as mh_fetch_addressable,
@@ -349,9 +350,14 @@ class EngineMetrics:
         # would cover, which is what it walked before it stopped at a
         # row's last page and at the live rows
         # (paged_attention_int8.page_counts). live / walked is the
-        # share of page copies that remain.
+        # share of page copies that remain. And the online-softmax
+        # updates the kernel folds the live pages into
+        # (paged_attention_int8.fold_pages of this model's score tile):
+        # live / updates is the pages an update, 1.0 while every page was
+        # an update of its own and up to a block's 4 on long rows.
         self.decode_attn_pages_live = 0
         self.decode_attn_pages_walked = 0
+        self.decode_attn_updates = 0
         # Over the same steps again: the idle rows that both int8 pool
         # kernels left out, (B - live slots) a step of a program that
         # hands them its `active` mask (decode_multi_step): how often
@@ -577,6 +583,7 @@ class EngineMetrics:
             "decode_steps_kernel_append": self.decode_steps_kernel_append,
             "decode_attn_pages_live": self.decode_attn_pages_live,
             "decode_attn_pages_walked": self.decode_attn_pages_walked,
+            "decode_attn_updates": self.decode_attn_updates,
             "decode_attn_rows_skipped": self.decode_attn_rows_skipped,
             "prefill_rows_live": self.prefill_rows_live,
             "prefill_rows_bucket": self.prefill_rows_bucket,
@@ -2312,6 +2319,14 @@ class LLMEngine:
             self._record_beat(fl, fl.t_ready,
                               self.metrics.tokens_out - tokens_before)
 
+    def _attn_fold(self, table_width: int) -> int:
+        """`fold_pages` of a decode step's int8 attention calls over
+        tables of `table_width` pages: the score tile one chip sees."""
+        tensor = 1 if self.mesh is None else self.mesh.shape.get("tensor", 1)
+        return fold_pages(self.cfg.n_kv_heads // tensor,
+                          self.cfg.n_heads // self.cfg.n_kv_heads,
+                          min(PAGES_PER_BLOCK, table_width))
+
     def _note_sparse_select(self, lengths, active_mask, K: int):
         """A decode block of a model with learned sparse attention, from
         the lengths the host dispatches it with: every live slot scores
@@ -2349,10 +2364,10 @@ class LLMEngine:
         cached tokens its attention calls see over layers x context, what
         one kind of row would have seen; b = pages x rows the live slots
         hold in both pools over what one table for every row would hold;
-        aux = the window pages walked and the calls that walked them, a
-        call being one window layer's of one step) and, a live slot, its
-        sequence and the position no later step reads behind; None for
-        every other model."""
+        aux = the window pages walked, the calls that walked them, a call
+        being one window layer's of one step, and the softmax updates they
+        were folded into) and, a live slot, its sequence and the position
+        no later step reads behind; None for every other model."""
         if self.window_allocator is None:
             return None
         wr = self.cfg.window_rows
@@ -2360,14 +2375,18 @@ class LLMEngine:
         live = np.asarray(lengths, np.int64)[active]
         ctx = live[None, :] + np.arange(K)[:, None]          # [K, n_live]
         seen = wr.n_global * ctx + wr.n_window * np.minimum(ctx, wr.window)
-        walked = int((-(-(ctx - win_base[active]) // ps)).sum()) * wr.n_window
+        maxw = self._window_table_pages
+        pages, _, updates = page_counts(ctx - win_base[active], ps, maxw,
+                                        fold=self._attn_fold(maxw))
+        walked, updates = pages * wr.n_window, updates * wr.n_window
         self.metrics.decode_attn_window_pages_walked += walked
         seqs = [self.slots[i].seq for i in active]
         held = sum(wr.n_global * len(q.pages)
                    + wr.n_window * len(q.window_pages) for q in seqs)
         one = (wr.n_global + wr.n_window) * sum(len(q.pages) for q in seqs)
         event = (float(seen.sum()) / float(self.cfg.n_layers * ctx.sum()),
-                 held / one, f"window_pages={walked} calls={K * wr.n_window}")
+                 held / one, f"window_pages={walked} calls={K * wr.n_window} "
+                 f"updates={updates}")
         # the block's last step has length `live + K - 1`; the next
         # block's first is one longer, and its window starts there
         return event, [(q, int(n) + K - wr.window)
@@ -3818,12 +3837,14 @@ class LLMEngine:
             # decode_multi_step's kernels walk the live rows alone (the
             # fused and the spec-state lanes hand them no mask)
             masked = engine_model.masks_pool_kernels(plan)
-            live_pages, walked = page_counts(
+            live_pages, walked, updates = page_counts(
                 lengths + np.arange(K)[:, None] * active_mask,
                 self.pool.page_size, self.max_pages,
-                mask=active_mask if masked else None)
+                mask=active_mask if masked else None,
+                fold=self._attn_fold(self.max_pages))
             self.metrics.decode_attn_pages_live += live_pages
             self.metrics.decode_attn_pages_walked += walked
+            self.metrics.decode_attn_updates += updates
             if masked:
                 self.metrics.decode_attn_rows_skipped += (
                     B - len(active)) * K

@@ -77,6 +77,7 @@ _COUNTER_KEYS = (
     "tokens_generated", "decode_steps", "layer_passes",
     "decode_steps_direct_qkv", "decode_steps_kernel_append",
     "decode_attn_pages_live", "decode_attn_pages_walked",
+    "decode_attn_updates",
     "decode_attn_rows_skipped",
     "prefill_rows_live", "prefill_rows_bucket",
     "moe_pairs_routed", "moe_pairs_local",

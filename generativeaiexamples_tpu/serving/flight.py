@@ -113,8 +113,9 @@ EV_SPARSE_SELECT = 22
 # attention calls see over layers x context of its live slots (what one
 # kind of row would have seen); b = pages x rows the live slots hold in
 # both pools over what one table for every row would hold; aux =
-# "window_pages=<n> calls=<m>", the pages (a page a live slot and call)
-# the window rows' kernel calls walked in the block, and those calls.
+# "window_pages=<n> calls=<m> updates=<u>", the pages (a page a live slot
+# and call) the window rows' kernel calls walked in the block, those
+# calls, and the softmax updates the pages were folded into.
 EV_WINDOW_CACHE = 23
 
 # Program classes (EV_PROGRAM.code).

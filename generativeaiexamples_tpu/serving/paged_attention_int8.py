@@ -31,7 +31,30 @@ k AND v move HBM->VMEM as a SINGLE DMA descriptor strided across the
 instead of the 4 an unfused pool needs and the 8 a per-head grid pays.
 The copies of the next BLOCKS_AHEAD blocks, this row's and then the next
 LIVE rows', are in flight while the current one computes (cross-grid-step
-buffering).
+buffering). A block's live pages are folded into the online softmax in
+ONE update (`_fold_block`, which serving/paged_attention_sparse.py runs
+too): all their score tiles first, one maximum, one `alpha`, one sum, one
+rescaled accumulator, where a page at a time made a chain of those a
+page, each waiting for the one before. Alone on a v5e, parent (an update
+a page) -> this kernel, us a call (PERF.md section 5, PR 45;
+scripts/measure_paged_attention.py and scripts/check_window_on_chip.py
+--phases kernels, each with --parent, read them again):
+
+  pool (score tile KH x G; page)      closed mix     every row full   3 long rows
+  Mistral-7B   (8 x 4; 270 KB)        60.5 -> 55.7   459.5 -> 459.2   17.3 -> 16.9
+  Ouro         (16 x 1; 541 KB)       53.4 -> 53.2    94.0 ->  93.8   11.1 -> 11.0
+  a chip of TP4 (2 x 4; 68 KB)        46.9 -> 35.4    75.0 ->  45.2    4.1 ->  2.7
+  SmallThinker (4 x 7; 135 KB), 64 rows at 5k | 9k | 16k:
+    window rows, tables of 34   578.4 | 578.0 | 578.9 -> 406.4 | 406.8 | 408.0
+    global rows, tables of 128  735.8 | 1315.4 | 2327.7 -> 506.3 | 897.2 | 1581.4
+
+The smaller the page, the less its copy hid the chain: 0.274-0.291 us a
+page became 0.181-0.191 us a page and 0.375 us a row at SmallThinker's
+shape (84-86 % of the HBM's rate by a page's bytes), a quarter to two
+fifths of a call went at TP4's, a twelfth of Mistral-7B's closed mix, and
+nothing where rows of whole blocks of 270 KB pages were the bytes'
+already. Folding 2 or 3 pages an update reads between the two at every
+shape, so `fold_pages` is the block's width for every tile.
 
 A live row has n = clip(cdiv(length + q_rep - 1, ps), 1, maxp) pages, and
 an idle one (a decode slot nobody occupies: `active` False) none: it is
@@ -158,6 +181,8 @@ SPLIT_KV_BYTES = 2 ** 32
 # and an eighth off Ouro's, a third nothing more.
 PAGES_PER_BLOCK = 4
 BLOCKS_AHEAD = 2
+# Pages folded into one online-softmax update (`fold_pages`): the block's.
+FOLD_PAGES = 4
 
 # The kernel's state in SMEM, carried from one grid step to the next:
 # the buffer and the (place in `order`, block) to ask for next, the
@@ -190,6 +215,59 @@ def every_row(n_rows: int) -> LiveRows:
                     jnp.full((1,), n_rows, jnp.int32))
 
 
+def fold_pages(kv_heads: int, group: int, ppcb: int) -> int:
+    """The pages `_int8_kernel` folds into ONE online-softmax update, from
+    a page's score tile [kv_heads, group, ps] (group: query heads a KV head
+    x q_rep): a block makes cdiv(count, width) updates over its `count`
+    live pages, and a width of 1 is a chain a page through the same
+    function. A wider fold holds more score tiles in registers at once
+    (about kv_heads x cdiv(group, 8) vregs a page), and the tile that could
+    have lost by that, Ouro's 16 x 1, did not: at every tile a cell has, 2
+    x 4 to 16 x 1, the whole block read fastest or within 0.1 % of it at
+    every set of lengths with a live row (the module's table), so the rule
+    is a constant and the tile decides nothing yet. A tile that reads otherwise gets its
+    width here, and nowhere else: no caller sets it."""
+    return min(FOLD_PAGES, ppcb)
+
+
+def _fold_block(q, page, count: int, carry):
+    """ONE online-softmax update over the `count` (static) pages of a
+    block. `page(j)` -> (kq, vq [KH, ps, Hd] f32, ks, vs [KH, 1, ps], keep),
+    `keep(shape)` the page's mask at its score tile's shape [KH, G, ps]
+    (bool; asked for once the scores are there); q [KH, G, Hd]; carry (m, l
+    [KH, G, 1], acc [KH, G, Hd]). The pages' score tiles depend neither on
+    the carry nor on each other: one maximum over all of them, one `alpha`,
+    one sum, one rescaled accumulator, where a page at a time made `count`
+    such chains, each waiting for the one before."""
+    m_prev, l_prev, acc = carry
+    scores, keeps, values = [], [], []
+    for j in range(count):
+        kq, vq, ks, vs, keep = page(j)
+        s = jax.lax.dot_general(
+            q, kq, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * ks      # [KH, G, ps]
+        keep = keep(s.shape)
+        scores.append(jnp.where(keep, s, NEG_INF))
+        keeps.append(keep)
+        values.append((vq, vs))
+    top = functools.reduce(jnp.maximum, scores)
+    m_new = jnp.maximum(m_prev, jnp.max(top, axis=2, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # (a block with nothing kept before anything was, as a window row's
+    # whose first page slid out whole: every score is NEG_INF and so is
+    # m_new, and exp(0) would count)
+    weights = [jnp.where(keep, jnp.exp(s - m_new), 0.0)
+               for s, keep in zip(scores, keeps)]
+    l_new = alpha * l_prev + jnp.sum(
+        functools.reduce(jnp.add, weights), axis=2, keepdims=True)
+    pv = functools.reduce(jnp.add, [
+        jax.lax.dot_general(
+            p * vs, vq, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # [KH, G, Hd]
+        for p, (vq, vs) in zip(weights, values)])
+    return m_new, l_new, acc * alpha + pv
+
+
 def _int8_window_kernel(lengths_ref, tables_ref, layer_ref, order_ref,
                         n_live_ref, starts_ref, *refs, **static):
     """_int8_kernel for a WINDOW row: one scalar prefetch more, a row's
@@ -214,6 +292,7 @@ def _int8_kernel(
     state,         # SMEM [4]: _ASK_SLOT, _ASK_ROW, _ASK_BLOCK, _TAKE_SLOT
     *,
     ppcb: int,
+    fold: int,
     maxp: int,
     page_size: int,
     ahead: int,
@@ -252,7 +331,11 @@ def _int8_kernel(
        unrolled over exactly its pages, for the copies' starts, for
        their waits and for the multiplies alike: a loop of that trip
        count runs a page's chain of dot, max, exp and dot one after the
-       other, and rows of whole blocks then take a sixth longer.
+       other, and rows of whole blocks then take a sixth longer. A body
+       folds its pages into the softmax `fold` at a time (`_fold_block`;
+       `fold_pages`: the whole block), under each page's own mask: a
+       page none of whose tokens a row sees (a window row's first, slid
+       out whole; a tree's future) weighs nothing, not exp(0).
     2. Fused pages: 2 descriptors per page.
     3. Latency hiding is CROSS-grid-step (the JetStream scheme): while
        a block is multiplied, the copies of the `ahead` blocks after it
@@ -366,51 +449,38 @@ def _int8_kernel(
         slot = state[_TAKE_SLOT]
         ask()  # into the buffer the block before this one was taken from
 
-        def page(j, carry):
-            """One live page's online-softmax update, all kv heads
+        def page(j):
+            """A live page of the block and its mask, all kv heads
             batched: shapes stay <= 3-D with the head axis leading — no
             Mosaic relayouts, and each dot is KH x (G x ps x Hd)."""
-            m_prev, l_prev, acc = carry
-            kq = kv_buf[slot, j, 0].astype(jnp.float32)  # [KH, ps, Hd]
-            vq = kv_buf[slot, j, 1].astype(jnp.float32)
-            ks = s_buf[slot, j, 0]                       # [KH, 1, ps]
-            vs = s_buf[slot, j, 1]
-            s = jax.lax.dot_general(
-                q, kq, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32) * ks  # [KH, G, ps]
-            pos = ((i * ppcb + j) * ps
-                   + lax.broadcasted_iota(jnp.int32, s.shape, 2))
-            if tree is not None:
-                s = jnp.where(
-                    _tree_keep(pos, length,
-                               lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                               // g_base, q_rep, tree),
-                    s, NEG_INF)
-            else:
+            def keep(shape):
+                pos = ((i * ppcb + j) * ps
+                       + lax.broadcasted_iota(jnp.int32, shape, 2))
+                if tree is not None:
+                    return _tree_keep(
+                        pos, length,
+                        lax.broadcasted_iota(jnp.int32, shape, 1) // g_base,
+                        q_rep, tree)
                 limit = length
                 if q_rep > 1:
                     limit = length + lax.broadcasted_iota(
-                        jnp.int32, s.shape, 1) // g_base
-                keep = pos < limit
+                        jnp.int32, shape, 1) // g_base
+                kept = pos < limit
                 if starts_ref is not None:
-                    keep &= pos >= starts_ref[b]
-                s = jnp.where(keep, s, NEG_INF)
+                    kept &= pos >= starts_ref[b]
+                return kept
 
-            m_curr = jnp.max(s, axis=2, keepdims=True)  # [KH, G, 1]
-            m_new = jnp.maximum(m_prev, m_curr)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)  # padded cols: exp(NEG_INF - m) == 0
-            l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
-            pv = jax.lax.dot_general(
-                p * vs, vq, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)  # [KH, G, Hd]
-            return m_new, l_new, acc * alpha + pv
+            return (kv_buf[slot, j, 0].astype(jnp.float32),  # [KH, ps, Hd]
+                    kv_buf[slot, j, 1].astype(jnp.float32),
+                    s_buf[slot, j, 0], s_buf[slot, j, 1],    # [KH, 1, ps]
+                    keep)
 
         def block(count):
             def run(carry):
                 copies(b, i, slot, count, wait)
-                for j in range(count):
-                    carry = page(j, carry)
+                for at in range(0, count, fold):
+                    carry = _fold_block(q, lambda j, at=at: page(at + j),
+                                        min(fold, count - at), carry)
                 return carry
             return run
 
@@ -427,20 +497,26 @@ def _int8_kernel(
 
 
 def page_counts(lengths, page_size: int, max_pages: int,
-                block: int | None = None, mask=None) -> tuple[int, int]:
+                block: int | None = None, mask=None,
+                fold: int | None = None) -> tuple[int, int, int]:
     """On the host, for a batch's `lengths` (numpy, any shape) and its
     `mask` of live rows (broadcast against them; None: every row): the
     pages the kernel copies and multiplies (each live row's n, an idle
-    row's none), and what whole blocks over EVERY row would cover, which
+    row's none), what whole blocks over EVERY row would cover, which
     is what it walked before it stopped at n and at the live rows (an
-    idle row walked a block). The engine's `decode_attn_pages_live` /
-    `decode_attn_pages_walked`."""
+    idle row walked a block), and the softmax updates it makes for the
+    live rows' pages at `fold` pages an update (`fold_pages` of the
+    caller's tile; None: a whole block), each block's live pages apart.
+    The engine's `decode_attn_pages_live` / `decode_attn_pages_walked` /
+    `decode_attn_updates`."""
     block = min(block or PAGES_PER_BLOCK, max_pages)
+    fold = min(fold or block, block)
     n = np.clip(-(-np.asarray(lengths, np.int64) // page_size), 1, max_pages)
     walked = np.minimum(-(-n // block) * block, max_pages)
     if mask is not None:
         n = n * np.asarray(mask, bool)
-    return int(n.sum()), int(walked.sum())
+    updates = n // block * -(-block // fold) + -(-(n % block) // fold)
+    return int(n.sum()), int(walked.sum()), int(updates.sum())
 
 
 _STATIC = ("scale", "pages_per_compute_block", "q_rep", "tree", "interpret",
@@ -537,8 +613,8 @@ def _paged_attention_int8(
     assert starts is None or (q_rep == 1 and tree is None), (q_rep, tree)
     kernel = functools.partial(
         _int8_kernel if starts is None else _int8_window_kernel, ppcb=ppcb,
-        maxp=maxp, page_size=ps, ahead=ahead, q_rep=q_rep, tree=tree,
-        split_kv=split_kv)
+        fold=fold_pages(KH, G, ppcb), maxp=maxp, page_size=ps, ahead=ahead,
+        q_rep=q_rep, tree=tree, split_kv=split_kv)
 
     def qmap(k, Ln, T, LY, order, *_):
         return (order[k], 0, 0, 0)

@@ -10,12 +10,13 @@ each is as it is) with that row where the other masks by length: a slot's
 pages are streamed WHOLE, 16 KB a head and page, and a token that was not
 selected gets no weight.
 
-What is this kernel's own is the unit of the softmax: ONE online update a
-BLOCK of pages (`_fold_block`), where the walk it was copied from makes
-one a page, each waiting for the one before (the maximum, `alpha`, the
-weights, their sum, the weights' dot, the rescaled accumulator). A row
-here has 40 to 150 pages where a row there has 1 to 20, and the chain was
-a third of a page's time. Alone on a v5e at 16 slots, a pool of the Keye
+The unit of the softmax is the BLOCK of pages: ONE online update a block
+(`paged_attention_int8._fold_block`, first written here, PR 43; since PR
+45 that kernel folds its blocks through it too), where the walk this was
+copied from made one a page, each waiting for the one before (the
+maximum, `alpha`, the weights, their sum, the weights' dot, the rescaled
+accumulator). A row here has 40 to 150 pages, and the chain was a third
+of a page's time. Alone on a v5e at 16 slots, a pool of the Keye
 cell's shape, twelve calls a program (PERF.md section 5, PR 43;
 scripts/check_sparse_on_chip.py --phases kernels --attn-widths 4,8,16
 reads them again), us a call at contexts of 6k | 10k | 16k (us a page):
@@ -55,12 +56,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-    NEG_INF, SPLIT_KV_BYTES, LiveRows, every_row)
+    NEG_INF, SPLIT_KV_BYTES, LiveRows, _fold_block, every_row)
 from generativeaiexamples_tpu.utils.platform import log_kernel_declined
 
 # This kernel's own walk, read on a v5e at the Keye cell's shape (the table
-# above; paged_attention_int8 keeps its two constants, read on rows of 1 to
-# 20 pages where a row here has 40 to 150): the pages a block copies
+# above; paged_attention_int8 keeps its constants, read at its cells'
+# shapes): the pages a block copies
 # together and folds into the softmax in ONE update, by a body unrolled
 # over exactly its pages, and the blocks whose copies are in flight while
 # one is multiplied (VMEM holds one buffer more). With one update a block
@@ -124,42 +125,6 @@ def paged_attention_sparse_reference(q, kv_pages, kv_scales, page_table,
     o = jnp.einsum("bkgs,kbsd->bkgd", p, kv[1])
     return (o / jnp.where(denom == 0.0, 1.0, denom)).reshape(
         B, H, Hd).astype(q.dtype)
-
-
-def _fold_block(q, page, count: int, carry):
-    """ONE online-softmax update over the `count` (static) pages of a
-    block. `page(j)` -> (kq, vq [KH, ps, Hd] f32, ks, vs [KH, 1, ps], keep
-    [1, ps] bool), q [KH, G, Hd]; carry (m, l [KH, G, 1], acc [KH, G, Hd]).
-    The pages' score tiles depend neither on the carry nor on each other:
-    one maximum over all of them, one `alpha`, one sum, one rescaled
-    accumulator, where a page at a time made `count` such chains, each
-    waiting for the one before."""
-    m_prev, l_prev, acc = carry
-    scores, keeps, values = [], [], []
-    for j in range(count):
-        kq, vq, ks, vs, keep = page(j)
-        s = jax.lax.dot_general(
-            q, kq, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * ks      # [KH, G, ps]
-        keep = jnp.broadcast_to(keep[None], s.shape)
-        scores.append(jnp.where(keep, s, NEG_INF))
-        keeps.append(keep)
-        values.append((vq, vs))
-    top = functools.reduce(jnp.maximum, scores)
-    m_new = jnp.maximum(m_prev, jnp.max(top, axis=2, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    # (a block with nothing selected before anything was: every score is
-    # NEG_INF and so is m_new, and exp(0) would count)
-    weights = [jnp.where(keep, jnp.exp(s - m_new), 0.0)
-               for s, keep in zip(scores, keeps)]
-    l_new = alpha * l_prev + jnp.sum(
-        functools.reduce(jnp.add, weights), axis=2, keepdims=True)
-    pv = functools.reduce(jnp.add, [
-        jax.lax.dot_general(
-            p * vs, vq, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)           # [KH, G, Hd]
-        for p, (vq, vs) in zip(weights, values)])
-    return m_new, l_new, acc * alpha + pv
 
 
 def _sparse_kernel(
@@ -269,10 +234,11 @@ def _sparse_kernel(
 
         def block(first, count):
             def page(j):
-                return (kv_buf[slot, j, 0].astype(jnp.float32),
-                        kv_buf[slot, j, 1].astype(jnp.float32),
-                        s_buf[slot, j, 0], s_buf[slot, j, 1],
-                        sel_ref[0, pl.ds(first + j, 1), :] > 0.5)
+                kv = (kv_buf[slot, j, 0].astype(jnp.float32),
+                      kv_buf[slot, j, 1].astype(jnp.float32),
+                      s_buf[slot, j, 0], s_buf[slot, j, 1])
+                chosen = sel_ref[0, pl.ds(first + j, 1), :] > 0.5  # [1, ps]
+                return *kv, lambda shape: jnp.broadcast_to(chosen[None], shape)
 
             def run(carry):
                 copies(b, first, slot, count, wait)

@@ -4,7 +4,7 @@
 
     chiprun -- timeout 3000 python3 scripts/check_window_on_chip.py \
         [--phases buckets,kernels,compare,step] [--seeds 1] \
-        [--buckets 4608,8192,8704]
+        [--buckets 4608,8192,8704] [--parent DIR] [--folds 1,2]
 
 Four phases, one JSON line each result (also chiprun_out/window/check.jsonl):
 
@@ -20,7 +20,11 @@ Four phases, one JSON line each result (also chiprun_out/window/check.jsonl):
            `paged_attention_int8_window` over the window rows (a table of
            34 pages a slot, the start inside its first page) and three of
            `paged_attention_int8` over the global rows; us a call, the
-           pages walked and the bytes' share of the HBM's rate.
+           pages walked and the bytes' share of the HBM's rate. With
+           `--parent DIR` (a `git archive` of another commit, e.g.
+           .scratch/parent) that tree's kernel beside this one's, and with
+           `--folds` this one's at those pages a softmax update in place
+           of its own rule's (scripts/measure_paged_attention.py::folding).
   compare  the logits of the step programs at the published widths (a
            4,608-token prompt through `prefill_step`, past the window: four
            window pages are never taken; then four decode steps through
@@ -64,6 +68,11 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--buckets", default="",
                     help="prefill buckets to time; default: the file's")
+    ap.add_argument("--parent", default=None,
+                    help="a checkout whose int8 kernel `kernels` times too")
+    ap.add_argument("--folds", default="",
+                    help="pages a softmax update `kernels` tries besides "
+                         "the kernel's own rule")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     if args.rehearse:
@@ -80,10 +89,9 @@ def main() -> int:
     from generativeaiexamples_tpu.serving.kv_cache import (
         PageAllocator, WindowPool, WindowSequencePages, WindowTables,
         engine_window_table_pages, window_pool_pages)
-    from generativeaiexamples_tpu.serving.paged_attention import (
-        paged_attention_dispatch)
-    from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-        every_row)
+    from generativeaiexamples_tpu.serving import paged_attention_int8 as pa8
+    from scripts.measure_paged_attention import (
+        folding, load_kernel, pages_an_update)
     from generativeaiexamples_tpu.utils.platform import setup_compile_cache
 
     dev = jax.devices()[0]
@@ -197,31 +205,44 @@ def main() -> int:
         new_pool()
         q = jnp.asarray(rng.normal(size=(B, mcfg.n_heads, mcfg.head_dim)),
                         jnp.bfloat16)
-        live = every_row(B)
+        live = pa8.every_row(B)
         page_bytes = entry.kv_bytes_per_token_layer(config) * ps
+        forms = {"change": (pa8, None)}       # name: (module, fold)
+        if args.parent:
+            forms = {"parent": (load_kernel(args.parent), None), **forms}
+        for fold in sorted({int(x) for x in args.folds.split(",") if x}):
+            forms[f"change_f{fold}"] = (pa8, fold)
 
-        @jax.jit
-        def window_calls(pool, q, tables, lengths):
-            rel = lengths - tables.base
-            starts = jnp.maximum(lengths - wr.window, 0) - tables.base
-            acc = jnp.zeros_like(q, jnp.float32)
-            for row in range(wr.n_window):
-                kv, _, s, layer = pool.win.attention_operands(row)
-                acc += paged_attention_dispatch(
-                    q, kv, None, tables.win, rel, k_scales=s, layer=layer,
-                    use_pallas=use_pallas, live=live, starts=starts)
-            return acc
+        def calls_of(mod):
+            """The step's two attention calls through `mod`'s kernel, a
+            program each: (name, program, calls)."""
+            @jax.jit
+            def window_calls(pool, q, tables, lengths):
+                rel = lengths - tables.base
+                starts = jnp.maximum(lengths - wr.window, 0) - tables.base
+                acc = jnp.zeros_like(q, jnp.float32)
+                for row in range(wr.n_window):
+                    kv, _, s, layer = pool.win.attention_operands(row)
+                    acc += mod.paged_attention_int8_window(
+                        q, kv, s, tables.win, rel, layer, starts, live=live,
+                        interpret=args.rehearse)
+                return acc
 
-        @jax.jit
-        def global_calls(pool, q, tables, lengths):
-            acc = jnp.zeros_like(q, jnp.float32)
-            for row in range(wr.n_global):
-                kv, _, s, layer = pool.glob.attention_operands(row)
-                acc += paged_attention_dispatch(
-                    q, kv, None, tables.glob, lengths, k_scales=s,
-                    layer=layer, use_pallas=use_pallas, live=live)
-            return acc
+            @jax.jit
+            def global_calls(pool, q, tables, lengths):
+                acc = jnp.zeros_like(q, jnp.float32)
+                for row in range(wr.n_global):
+                    kv, _, s, layer = pool.glob.attention_operands(row)
+                    acc += mod.paged_attention_int8(
+                        q, kv, s, tables.glob, lengths, layer, live=live,
+                        interpret=args.rehearse)
+                return acc
 
+            return (("window", window_calls, wr.n_window),
+                    ("global", global_calls, wr.n_global))
+
+        G = mcfg.n_heads // mcfg.n_kv_heads
+        programs = {form: calls_of(mod) for form, (mod, _) in forms.items()}
         for ctx in contexts:
             # contexts spread a page either side, so that starts fall
             # anywhere inside a page
@@ -229,18 +250,23 @@ def main() -> int:
                               maxp * ps).astype(np.int32)
             tables = slot_tables(lengths)
             ln = jnp.asarray(lengths)
-            w_pages = int((-(-(lengths - np.asarray(tables.base)) // ps)
-                           ).sum())
-            g_pages = int((-(-lengths // ps)).sum())
-            for name, fn, calls, pages in (
-                    ("window", window_calls, wr.n_window, w_pages),
-                    ("global", global_calls, wr.n_global, g_pages)):
-                sec = timed(fn, state["pool"], q, tables, ln,
-                            reps=20) / calls
-                say(phase="kernels", kernel=name, context=int(ctx),
-                    us_per_call=sec * 1e6, pages_per_call=pages,
-                    us_per_page=sec * 1e6 / pages,
-                    gbytes_per_s=pages * page_bytes / sec / 1e9)
+            rows = {"window": (lengths - np.asarray(tables.base), maxw),
+                    "global": (lengths, maxp)}
+            for form, (mod, fold) in forms.items():
+                for name, fn, calls in programs[form]:
+                    with folding(pa8, fold):  # a program's first call traces
+                        sec = timed(fn, state["pool"], q, tables, ln,
+                                    reps=20) / calls
+                    row_lengths, width = rows[name]
+                    pages, _, updates = pa8.page_counts(
+                        row_lengths, ps, width, fold=pages_an_update(
+                            mod, fold, mcfg.n_kv_heads, G,
+                            min(pa8.PAGES_PER_BLOCK, width)))
+                    say(phase="kernels", kernel=name, form=form,
+                        context=int(ctx), us_per_call=sec * 1e6,
+                        pages_per_call=pages, us_per_page=sec * 1e6 / pages,
+                        pages_an_update=pages / updates,
+                        gbytes_per_s=pages * page_bytes / sec / 1e9)
 
     if "compare" in phases:
         for seed in range(args.seeds):

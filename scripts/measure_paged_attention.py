@@ -3,7 +3,8 @@
 on the chip, over pools of the benchmark cells' real shapes:
 
     chiprun -- python3 scripts/measure_paged_attention.py \
-        [--shapes mistral,ouro,tp4] [--blocks 4,5,8] [--parent DIR]
+        [--shapes mistral,ouro,tp4] [--blocks 4,5,8] [--folds 1,2] \
+        [--parent DIR]
 
     mistral  32 rows,  8 KV heads of 32, 64 slots,  768 pages, tables of 20
     ouro    192 rows, 16 KV heads of 16, 32 slots,  112 pages, tables of 4
@@ -24,7 +25,11 @@ the table's width: what the guards cost where nothing is dead), `chain`
 open mix, `mistral7b.chat-open`; the append's side of both mixes is
 scripts/measure_kv_append.py `--live 3,60,64`). The
 forms: the kernel of this tree at each of `--blocks` pages a block, given
-the step's mask, and with `--parent DIR` (a checkout of another commit,
+the step's mask (`change4`: the served one), at the first of those also
+with each of `--folds` pages a softmax update in place of what the
+kernel's own rule gives the shape (`change4f1` is a chain a page; the
+probe patches `fold_pages`, which no caller can set), and with
+`--parent DIR` (a checkout of another commit,
 e.g. `git archive` into .scratch/parent) that tree's kernel as it is; a
 parent that takes no mask computes every row, an idle one at length 1.
 Times are the device's: `--reps` executions by the host's clock around
@@ -40,8 +45,8 @@ control flow there at a tiny size, the kernel interpreted).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import importlib.util
-import inspect
 import json
 import os
 import shutil
@@ -60,6 +65,42 @@ SHAPES = {"mistral": (32, 8, 32, 64, 768, 20),
           "tp4": (40, 2, 8, 64, 3072, 4)}
 TINY = {"mistral": (2, 2, 4, 4, 9, 5), "ouro": (3, 2, 2, 4, 7, 4),
         "tp4": (2, 1, 2, 4, 9, 4)}
+
+
+@contextlib.contextmanager
+def folding(pa8, width):
+    """The kernel module's programs traced inside fold `width` pages into a
+    softmax update whatever `fold_pages` gives their shape (None: as it
+    is); a probe's way in, since the rule is no caller's to set."""
+    rule = pa8.fold_pages
+    programs = (pa8.paged_attention_int8, pa8.paged_attention_int8_window)
+    if width is not None:
+        pa8.fold_pages = lambda kv_heads, group, ppcb: min(width, ppcb)
+    for fn in programs:
+        fn.clear_cache()
+    try:
+        yield
+    finally:
+        pa8.fold_pages = rule
+        for fn in programs:
+            fn.clear_cache()
+
+
+def load_kernel(checkout: str):
+    """The int8 kernel's module as another checkout has it."""
+    spec = importlib.util.spec_from_file_location(
+        "parent_paged_attention_int8", os.path.join(checkout, KERNEL))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def pages_an_update(mod, fold, kv_heads: int, group: int, ppcb: int) -> int:
+    """What a form folds into a softmax update: the probe's `fold`, else
+    the rule of the form's own module; a checkout from before the rule
+    made every page an update."""
+    return fold or getattr(mod, "fold_pages", lambda *_: 1)(kv_heads, group,
+                                                            ppcb)
 
 
 def length_sets(rng, slots: int, width: int) -> dict:
@@ -88,6 +129,9 @@ def main() -> int:
     ap.add_argument("--shapes", default="mistral,ouro,tp4")
     ap.add_argument("--blocks", default="4,5,8",
                     help="pages a block to try for this tree's kernel")
+    ap.add_argument("--folds", default="",
+                    help="pages a softmax update to try in place of the "
+                         "rule's, at the first of --blocks")
     ap.add_argument("--parent", default=None,
                     help="a checkout whose kernel is measured beside it")
     ap.add_argument("--reps", type=int, default=20)
@@ -109,11 +153,7 @@ def main() -> int:
         raise SystemExit("measure_paged_attention: no TPU; refusing")
     parent_form = {}
     if args.parent:
-        spec = importlib.util.spec_from_file_location(
-            "parent_paged_attention_int8", os.path.join(args.parent, KERNEL))
-        parent = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(parent)
-        parent_form["parent"] = (parent.paged_attention_int8, None)
+        parent_form["parent"] = (load_kernel(args.parent), None, None)
     shapes = TINY if args.rehearse else SHAPES
     out_dir = os.path.join(ROOT, "chiprun_out", "paged_attention")
     os.makedirs(out_dir, exist_ok=True)
@@ -129,9 +169,12 @@ def main() -> int:
     for name in args.shapes.split(","):
         rows, KH, H, B, P, width = shapes[name]
         forms = dict(parent_form)
-        for blk in sorted({min(int(x), width)
-                           for x in args.blocks.split(",")}):
-            forms[f"change{blk}"] = (pa8.paged_attention_int8, blk)
+        blocks = sorted({min(int(x), width) for x in args.blocks.split(",")})
+        for blk in blocks:
+            forms[f"change{blk}"] = (pa8, blk, None)
+        for fold in sorted({min(int(x), blocks[0])
+                            for x in args.folds.split(",") if x}):
+            forms[f"change{blocks[0]}f{fold}"] = (pa8, blocks[0], fold)
         shape = (2, rows, KH, P, PS, HD)
 
         @jax.jit
@@ -149,8 +192,9 @@ def main() -> int:
         table = jnp.asarray(rng.integers(1, P, (B, width)), jnp.int32)
         sets = length_sets(rng, B, width)
         first = {}
-        for form, (fn, blk) in forms.items():
-            takes_mask = "live" in inspect.signature(fn).parameters
+        for form, (mod, blk, fold) in forms.items():
+            fn = mod.paged_attention_int8
+            takes_mask = hasattr(mod, "live_rows")
 
             def attend_rows(q, kv, s, table, lengths, active, fn=fn, blk=blk,
                             takes_mask=takes_mask):
@@ -167,10 +211,12 @@ def main() -> int:
                     0, rows, row, jnp.zeros((B, H, HD), jnp.float32))
 
             t0 = time.perf_counter()
-            compiled = jax.jit(attend_rows).lower(
-                q, kv, s, table, jnp.zeros((B,), jnp.int32),
-                jnp.zeros((B,), bool)).compile()
+            with folding(pa8, fold):
+                compiled = jax.jit(attend_rows).lower(
+                    q, kv, s, table, jnp.zeros((B,), jnp.int32),
+                    jnp.zeros((B,), bool)).compile()
             compile_s = time.perf_counter() - t0
+            folded = pages_an_update(mod, fold, KH, H // KH, blk or width)
             for set_name, (lens, mask) in sets.items():
                 lengths = jnp.asarray(lens, jnp.int32)
                 active = jnp.asarray(mask)
@@ -182,14 +228,16 @@ def main() -> int:
                     res = compiled(q, kv, s, table, lengths, active)
                 jax.block_until_ready(res)
                 host_us = (time.perf_counter() - t0) * 1e6 / args.reps / rows
-                live, walked = pa8.page_counts(
+                live, walked, updates = pa8.page_counts(
                     np.asarray(lens), PS, width, blk,
-                    mask=np.asarray(mask) if takes_mask else None)
+                    mask=np.asarray(mask) if takes_mask else None,
+                    fold=folded)
                 line = dict(
                     shape=name, form=form, lengths=set_name, rows=rows,
                     kv_heads=KH, slots=B, table_width=width,
                     rows_live=int(np.sum(mask)) if takes_mask else B,
                     pages_live=live, pages_in_whole_blocks=walked,
+                    pages_an_update=folded, softmax_updates=updates,
                     compile_s=round(compile_s, 1), host_us_per_call=host_us,
                     max_abs_diff_to_first_form=float(
                         np.max(np.abs(got - want), initial=0.0)),

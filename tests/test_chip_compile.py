@@ -609,6 +609,37 @@ def test_a_one_block_flash_call_is_the_kernel_the_parent_had():
         PARENT_FLASH_ONE_BLOCK
 
 
+# -- the fold moved, the sparse kernel stayed (PR 45) ------------------------
+# `_fold_block` lives in serving/paged_attention_int8.py since PR 45 and
+# takes a page's mask through a callable; `paged_attention_sparse` at the
+# Keye cell's shapes (16 slots, tables of 128, 32/4 heads of 128, twelve
+# cache rows) still traces to the jaxpr it had on PR 45's PARENT
+# (7ac0c47), kernel body and all: the same operations in the same order.
+PARENT_SPARSE_KERNEL = "a7a076a0b6b09518"
+
+
+def test_the_sparse_kernel_is_the_jaxpr_the_parent_had():
+    import hashlib
+
+    from generativeaiexamples_tpu.serving.paged_attention_sparse import (
+        paged_attention_sparse_pallas)
+
+    B, L, P, maxp = 16, 12, 2048, 128
+    jaxpr = jax.make_jaxpr(
+        lambda q, kv, s, t, ln, sel: paged_attention_sparse_pallas(
+            q, kv, s, t, ln, sel, 3))(
+        jax.ShapeDtypeStruct((B, 32, HD), BF16),
+        jax.ShapeDtypeStruct((2, L, 4, P, 128, HD), jnp.int8),
+        jax.ShapeDtypeStruct((2, L, 4, P, 128), F32),
+        jax.ShapeDtypeStruct((B, maxp), I32),
+        jax.ShapeDtypeStruct((B,), I32),
+        jax.ShapeDtypeStruct((B, maxp * 128), jnp.bool_))
+    text = str(jaxpr)
+    assert "exp" in text and "dot_general" in text  # the body is in it
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENT_SPARSE_KERNEL
+
+
 # -- the live-row walk leaves a latent model's programs alone (PR 41) -------
 # The step's mask reaches the int8 pool's two kernels through
 # engine_model._decode_once and _hybrid_decode_once alone. A model whose

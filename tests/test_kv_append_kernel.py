@@ -592,8 +592,11 @@ def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas):
     assert snap["decode_attn_pages_live"] == steps
     assert snap["decode_attn_pages_walked"] == 4 * steps
     assert snap["decode_attn_rows_skipped"] == steps
+    # ... and a row of one page is one softmax update, whatever the fold
+    assert snap["decode_attn_updates"] == steps
     for name in ("decode_steps_kernel_append", "decode_attn_pages_live",
-                 "decode_attn_pages_walked", "decode_attn_rows_skipped"):
+                 "decode_attn_pages_walked", "decode_attn_rows_skipped",
+                 "decode_attn_updates"):
         assert name in fleet._COUNTER_KEYS
         assert name in prometheus_text(snap)
         assert EngineMetrics().snapshot()[name] == 0

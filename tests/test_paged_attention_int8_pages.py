@@ -22,6 +22,7 @@ from generativeaiexamples_tpu.serving import engine_model
 from generativeaiexamples_tpu.serving import paged_attention_int8 as pa8
 from generativeaiexamples_tpu.serving.paged_attention import (
     paged_tree_attention_int8_reference_fused)
+from scripts.measure_paged_attention import folding
 
 PS, HD, KH, H, LAYERS, LAYER = 8, 16, 2, 4, 2, 1
 TREE = (2, 2)  # k, branches: 5 packed nodes
@@ -40,6 +41,14 @@ LENGTHS = {
     "several_blocks": (20, 5, lambda r: [3, 6 * PS - r + 1, 20 * PS - r + 1]),
     "block_not_a_divisor": (20, 8, lambda r: [20 * PS - r + 1, PS + 2,
                                               11 * PS]),
+    # one softmax update a block (PR 45): a last block of 1, 2, 3 and 4
+    # pages behind two whole ones
+    "last_block_of_1_2_3_4": (12, None, lambda r: [
+        n * PS - r + 1 for n in (9, 10, 11, 12)]),
+    # a first block that is partly FUTURE to the earlier query rows: the
+    # later rows' tokens, a page that only the last row reaches
+    "first_block_partly_future": (4, None, lambda r: [
+        1, 2, PS - 1, PS, 2 * PS - 1, 3 * PS]),
 }
 # name: (table width, pages a block, lengths, the rows that are live)
 MASKED = {
@@ -218,25 +227,56 @@ def test_live_rows_lists_the_live_rows_first_and_in_order():
 
 
 def test_page_counts_of_a_known_batch():
-    """What the engine's two counters add a step: the rows' pages, and
-    what whole blocks over the same rows cover."""
+    """What the engine's three counters add a step: the rows' pages, what
+    whole blocks over the same rows cover, and the softmax updates the
+    kernel folds the rows' pages into."""
     lengths = np.array([1, 128, 129, 0, 640, 2560, 4000], np.int32)
-    live, walked = pa8.page_counts(lengths, page_size=128, max_pages=20,
-                                   block=5)
+    live, walked, updates = pa8.page_counts(lengths, page_size=128,
+                                            max_pages=20, block=5)
     assert (live, walked) == (1 + 1 + 2 + 1 + 5 + 20 + 20,
                               5 + 5 + 5 + 5 + 5 + 20 + 20)
+    assert updates == 1 + 1 + 1 + 1 + 1 + 4 + 4  # a block an update
     # a block that does not divide the table's width stops at the width
-    assert pa8.page_counts(lengths[-1:], 128, 20, block=8) == (20, 20)
-    assert pa8.page_counts(lengths[:0], 128, 20, block=8) == (0, 0)
+    assert pa8.page_counts(lengths[-1:], 128, 20, block=8) == (20, 20, 3)
+    assert pa8.page_counts(lengths[:0], 128, 20, block=8) == (0, 0, 0)
     # with the step's mask an idle row has no page to copy; what whole
     # blocks over every row covered is the walk it is compared with
     mask = np.array([True, False, True, False, True, False, True])
     assert pa8.page_counts(lengths, 128, 20, block=5, mask=mask) == (
-        1 + 2 + 5 + 20, walked)
+        1 + 2 + 5 + 20, walked, 1 + 1 + 1 + 4)
     # a block of K steps: [K, B] lengths against the [B] mask
     assert pa8.page_counts(np.stack([lengths, lengths + 1]), 128, 20,
                            block=5, mask=mask) == (
-        (1 + 2 + 5 + 20) + (1 + 2 + 6 + 20), 2 * walked + 5)
+        (1 + 2 + 5 + 20) + (1 + 2 + 6 + 20), 2 * walked + 5,
+        (1 + 1 + 1 + 4) + (1 + 1 + 2 + 4))
+
+
+# rows of 1, 4, 6, 7, 11 and 20 pages; name: (pages a block, pages an
+# update, the updates each row makes)
+UPDATES = {
+    "a_page_an_update": (4, 1, [1, 4, 6, 7, 11, 20]),
+    "two_pages_an_update": (4, 2, [1, 2, 3, 4, 6, 10]),
+    "three_of_a_block_of_four": (4, 3, [1, 2, 3, 3, 5, 10]),
+    "a_block_an_update": (4, 4, [1, 1, 2, 2, 3, 5]),
+    "no_width_given_is_a_block": (4, None, [1, 1, 2, 2, 3, 5]),
+    "a_width_past_the_block_is_the_block": (4, 8, [1, 1, 2, 2, 3, 5]),
+    "four_of_a_block_of_five": (5, 4, [1, 1, 3, 3, 5, 8]),
+    "the_tables_width_bounds_the_block": (32, 16, [1, 1, 1, 1, 1, 2]),
+}
+
+
+@pytest.mark.parametrize("case", list(UPDATES))
+def test_page_counts_updates_follow_the_kernels_fold(case):
+    """`page_counts`' third count is the kernel's own rule: a block's
+    live pages go in updates of `fold`, and no update spans two blocks."""
+    block, fold, want = UPDATES[case]
+    lengths = np.array([1, 4, 6, 7, 11, 20]) * 128 - 3
+    for row, n in zip(lengths, want):
+        assert pa8.page_counts(row, 128, 20, block=block, fold=fold)[2] == n
+    mask = np.array([True, True, False, True, False, True])
+    assert pa8.page_counts(lengths, 128, 20, block=block, fold=fold,
+                           mask=mask)[2] == sum(
+        n for n, m in zip(want, mask) if m)
 
 
 # -- a WINDOW row's start (kv_cache.WindowPool; PR 44) ----------------------
@@ -258,6 +298,23 @@ WINDOWED = {
                             [True, False, True]),
     "several_blocks": ([20 * PS, 11 * PS + 5, 6 * PS], [2 * PS + 5, PS, 7],
                        None),
+    # rows of several blocks of 4, one update a block (PR 45): the start
+    # in the first page, on its boundary, past the whole first page (the
+    # block's leading page gives no weight) and past the whole first
+    # BLOCK (an update in which nothing is kept before one in which
+    # something is); last blocks of 1, 2, 3 and 4 pages
+    "blocks_start_in_the_first_page": (
+        [9 * PS, 10 * PS - 3, 11 * PS + 1 - PS, 12 * PS], [3, PS - 1, 1, 5],
+        None),
+    "blocks_start_on_a_page_boundary": (
+        [9 * PS - 1, 10 * PS, 11 * PS, 12 * PS], [PS, PS, 2 * PS, 3 * PS],
+        None),
+    "blocks_first_page_wholly_behind": (
+        [9 * PS, 10 * PS, 11 * PS - 4, 12 * PS],
+        [PS + 1, 2 * PS - 1, PS + 3, 3 * PS + 2], None),
+    "blocks_first_block_wholly_behind": (
+        [9 * PS, 10 * PS - 1, 11 * PS, 12 * PS],
+        [4 * PS, 4 * PS + 3, 5 * PS - 1, 8 * PS + 1], None),
 }
 
 
@@ -271,7 +328,9 @@ def test_a_window_rows_start_masks_to_the_token(case, split_kv):
     lengths, starts = np.asarray(lengths, np.int32), np.asarray(starts,
                                                                 np.int32)
     B = len(lengths)
-    maxp, block = (20, 5) if case == "several_blocks" else (4, None)
+    maxp, block = ((20, 5) if case == "several_blocks"
+                   else (12, None) if case.startswith("blocks_")
+                   else (4, None))
     pages = B * maxp + 2
     kv, s = _pool(pages, seed=len(case))
     n = np.clip(-(-lengths // PS), 1, maxp)
@@ -319,3 +378,101 @@ def test_a_window_rows_start_masks_to_the_token(case, split_kv):
         plain = np.asarray(pa8.paged_attention_int8_reference_fused(
             q, kv[:, LAYER], s[:, LAYER], clean, jnp.asarray(lengths)))
         assert not np.allclose(plain[served], got[served], atol=1e-3)
+
+
+# -- one softmax update a BLOCK of pages (PR 45) -----------------------------
+# `fold_pages` gives the pages of an update from the score tile's shape;
+# whatever width it gives, 1 (a chain a page) to the block's, the kernel
+# reads the same to float32 rounding, under every mask it has. `folding`
+# is the probes' way to another width than the rule's.
+FOLDED = {"q_rep1": (1, None, False), "q_rep4": (4, None, False),
+          "tree": (1 + TREE[0] * TREE[1], TREE, False),
+          "window": (1, None, True)}
+
+
+@pytest.mark.parametrize("width", [1, 2, 3])
+@pytest.mark.parametrize("form", list(FOLDED))
+def test_every_width_the_rule_can_return_reads_the_same(width, form):
+    """Rows of 1 to 12 pages (last blocks of every count, a first block
+    partly future to the early query rows, a window's start in the first
+    page, past it and past the first block) at each width under the
+    block's 4, which every other test of this file runs: each is the
+    reference to float32 rounding, so all agree."""
+    q_rep, tree, window = FOLDED[form]
+    maxp = 12
+    lengths = np.asarray([1, PS - 1, 2 * PS + 3, 5 * PS, 6 * PS + 1,
+                          7 * PS - q_rep, 11 * PS - q_rep - 2,
+                          12 * PS - q_rep + 1], np.int32)
+    starts = np.asarray([0, 3, PS, PS + 2, 4 * PS + 1, 5, 8 * PS,
+                         2 * PS - 1], np.int32)
+    B = len(lengths)
+    pages = B * maxp + 2
+    kv, s = _pool(pages, seed=q_rep)
+    n = np.clip(-(-(lengths + q_rep - 1) // PS), 1, maxp)
+    live = np.arange(maxp)[None, :] < n[:, None]
+    own = 1 + np.arange(B * maxp).reshape(B, maxp)
+    poisoned = jnp.asarray(np.where(live, own, pages - 1), jnp.int32)
+    clean = jnp.asarray(np.where(live, own, 0), jnp.int32)
+    shape = (B, H, HD) if q_rep == 1 else (B, q_rep, H, HD)
+    q = jax.random.normal(jax.random.PRNGKey(3), shape, jnp.float32)
+    with folding(pa8, width):
+        if window:
+            got = pa8.paged_attention_int8_window(
+                q, kv, s, poisoned, jnp.asarray(lengths), LAYER,
+                jnp.asarray(starts), interpret=True)
+        else:
+            got = pa8.paged_attention_int8(
+                q, kv, s, poisoned, jnp.asarray(lengths), LAYER, q_rep=q_rep,
+                tree=tree, interpret=True)
+    if window:
+        want = pa8.paged_attention_int8_reference_fused(
+            q, kv[:, LAYER], s[:, LAYER], clean, jnp.asarray(lengths),
+            starts=jnp.asarray(starts))
+    else:
+        want = _reference(q, kv, s, clean, jnp.asarray(lengths), q_rep, tree)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+
+
+@pytest.mark.parametrize("width", [1, 3, 4])
+def test_the_hosts_update_count_is_the_folds_the_kernel_runs(
+        width, monkeypatch):
+    """`page_counts`' updates (the engine's decode_attn_updates) against
+    the interpreted kernel on the same lengths and mask: every fold that
+    runs says how many pages it took."""
+    ran = []
+    fold = pa8._fold_block
+
+    def counted(q, page, count, carry):
+        jax.debug.callback(lambda: ran.append(count))
+        return fold(q, page, count, carry)
+
+    monkeypatch.setattr(pa8, "_fold_block", counted)
+    maxp = 12
+    lengths = np.asarray([1, 3 * PS, 4 * PS + 1, 7 * PS, 1, 12 * PS,
+                          10 * PS - 1], np.int32)
+    B = len(lengths)
+    kv, s = _pool(B * maxp + 2, seed=width)
+    table = jnp.asarray(1 + np.arange(B * maxp).reshape(B, maxp), jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(0), (B, H, HD), jnp.float32)
+    with folding(pa8, width):  # nothing traced in here outlives it
+        for mask in ([True] * B, [True, False, True, True, False, True, True]):
+            ran.clear()
+            jax.block_until_ready(pa8.paged_attention_int8(
+                q, kv, s, table, jnp.asarray(lengths), LAYER, interpret=True,
+                live=pa8.live_rows(jnp.asarray(mask))))
+            jax.effects_barrier()
+            pages, _, updates = pa8.page_counts(
+                lengths, PS, maxp, mask=np.asarray(mask), fold=width)
+            assert (sum(ran), len(ran)) == (pages, updates)
+            assert max(ran) <= width
+
+
+def test_the_rule_is_a_width_the_block_can_hold():
+    """`fold_pages` for every tile a cell has (KV heads, query heads a KV
+    head) and the speculative forms' larger groups: 1 .. the block's."""
+    for kv_heads, group in [(4, 7), (4, 8), (8, 4), (2, 4), (16, 1),
+                            (8, 16), (8, 20), (2, 2)]:
+        for ppcb in (1, 2, 4, 5, 8):
+            assert 1 <= pa8.fold_pages(kv_heads, group, ppcb) <= ppcb

@@ -225,6 +225,9 @@ WALK_SCENES = {
     # is (the guard: m is still NEG_INF), between two such, and after
     "nothing-selected-in-a-block": ("..x", ".x.", "x..", "x.x"),
     "nothing-selected-at-all": ("...", "x", ".", "xx"),
+    # the fold is paged_attention_int8's now (PR 45): EVERY row's first
+    # block selects nothing, so each starts from a guarded update
+    "nothing-selected-in-the-first-block": (".x", "..x", ".xx", ".x."),
 }
 
 

@@ -510,6 +510,10 @@ def test_the_engine_serves_through_both_tables_and_gives_the_pages_back(
         assert 0.25 < e["a"] < 1.0 and 0.25 < e["b"] <= 1.0
         aux = dict(kv.split("=") for kv in e["aux"].split())
         assert int(aux["calls"]) in (3, 6) and int(aux["window_pages"]) > 0
+        # a call's 2 to 4 pages are one block: cdiv(pages, fold) updates
+        assert int(aux["calls"]) <= int(aux["updates"]) \
+            <= int(aux["window_pages"]) \
+            <= int(aux["updates"]) * eng._attn_fold(4)
     ctx = 14 + np.arange(2)           # the first block: two steps
     assert events[0]["a"] == pytest.approx(
         (ctx + 3 * np.minimum(ctx, W)).sum() / (4 * ctx.sum()))
