@@ -341,9 +341,13 @@ class EngineMetrics:
         # Decode steps dispatched through a program whose K/V append is
         # the in-place Pallas kernel (kv_cache.kernel_append: an int8
         # pool, one new row a slot, kernels on) and not XLA's scatters:
-        # the share of decode_steps that engages it.
+        # the share of decode_steps that engages it in a launch of its
+        # own; and the steps whose attention call writes the row itself
+        # (engine_model.fuses_append: a looped model's walk), which
+        # launch no append at all. A step counts under one of the two.
         self.decode_steps_kernel_append = 0
-        # Over the same steps (their attention is then
+        self.decode_steps_fused_append = 0
+        # Over the steps of both (their attention is then
         # serving/paged_attention_int8.py), summed over the B rows a
         # step: the pages the LIVE rows have, which is what the kernel
         # copies and multiplies, and what whole blocks over every row
@@ -581,6 +585,7 @@ class EngineMetrics:
             "layer_passes": self.layer_passes,
             "decode_steps_direct_qkv": self.decode_steps_direct_qkv,
             "decode_steps_kernel_append": self.decode_steps_kernel_append,
+            "decode_steps_fused_append": self.decode_steps_fused_append,
             "decode_attn_pages_live": self.decode_attn_pages_live,
             "decode_attn_pages_walked": self.decode_attn_pages_walked,
             "decode_attn_updates": self.decode_attn_updates,
@@ -3831,7 +3836,11 @@ class LLMEngine:
         window = self._note_window_cache(lengths, active, win_base, K)
         # every decode program but the verifies writes one row a slot
         if not plan.spec_k and kernel_append(self.pool, self.use_pallas):
-            self.metrics.decode_steps_kernel_append += K
+            if engine_model.fuses_append(self.cfg, self.pool,
+                                         self.use_pallas):
+                self.metrics.decode_steps_fused_append += K
+            else:
+                self.metrics.decode_steps_kernel_append += K
             # ... and attends through paged_attention_int8: a live
             # row is one token longer every step of the block, and
             # decode_multi_step's kernels walk the live rows alone (the

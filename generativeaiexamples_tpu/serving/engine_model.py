@@ -37,7 +37,7 @@ from generativeaiexamples_tpu.ops import attention as attn_ops
 from generativeaiexamples_tpu.ops.quant import mm
 from generativeaiexamples_tpu.serving import ssm_state_update as ssm_update
 from generativeaiexamples_tpu.serving.kv_cache import (
-    PagePool, kernel_live_rows, token_slots)
+    PagePool, kernel_append, kernel_live_rows, token_slots)
 from generativeaiexamples_tpu.serving.paged_attention import (
     paged_attention_dispatch)
 from generativeaiexamples_tpu.serving.paged_attention_sparse import (
@@ -806,12 +806,39 @@ def direct_qkv(cfg: LlamaConfig, n_steps: int) -> bool:
     return cfg.n_passes > 1 or n_steps <= DIRECT_QKV_MAX_STEPS
 
 
+def fuses_append(cfg: LlamaConfig, pool, use_pallas) -> bool:
+    """Whether a decode step of one new row a slot (`_decode_once`) hands
+    the row to its attention call, which writes it (kv_cache.
+    QuantPagePool.attend_appending), where every other step appends
+    first: the pool's append would be the kernel (kv_cache.kernel_append:
+    an int8 pool, kernels on, tiles the DMAs take; then the dispatch's
+    form is the plain paged_attention_int8), the block is the Llama one
+    and not one of the drawn blocks with a call site of their own, and the
+    walk is a LOOPED model's (`cfg.n_passes` > 1: the blocks inside a
+    `lax.fori_loop`, the program `direct_qkv` singles out too). There a
+    step is the time of its operations and the launch saved shows (Ouro,
+    192 rows a step: +1.6 to +1.8 % tokens). A one-pass model's unrolled
+    step streams its weights at the HBM's rate and XLA prefetches them
+    under the two kernels: with one Pallas call a layer fewer its
+    schedule changes and the step reads LONGER though the pair alone is
+    shorter (Mistral-7B: 13.13 ms for 12.88 in blocks of 8, whose 96
+    staging fusions XLA then merges into 6; `gap_p50_ms` 10.665 for
+    10.60 in blocks of 2), so those keep the two calls (PERF.md section
+    6, PR 46). From the step program's static arguments and the pool and
+    nothing else; the engine counts `decode_steps_fused_append` by this
+    function."""
+    return (cfg.n_passes > 1 and _expert_decode_once(cfg) is None
+            and kernel_append(pool, use_pallas))
+
+
 def _decode_rows(params, cfg: LlamaConfig, pool: PagePool, tokens, positions,
-                 slots, attend, direct=False):
+                 slots, attend, direct=False, fused=False):
     """One forward of the decode family, write-then-attend: every block
     appends its new K and V to the pool at `slots` and THEN attends, so
     `attend(q [B, H, r, Hd], pool, row) -> [B, H, r, Hd]` (the caller's:
-    which kernel, over which rows) sees the rows just written.
+    which kernel, over which rows) sees the rows just written. `fused`
+    (`fuses_append`): the attention writes the row itself, and
+    `attend(q, pool, row, k, v) -> ([B, H, r, Hd], pool)`.
 
     tokens, positions [B, r]; slots = token_slots of the rows' (page,
     offset), each [B, r] — or [B] where a slot has the one row: plain
@@ -827,8 +854,12 @@ def _decode_rows(params, cfg: LlamaConfig, pool: PagePool, tokens, positions,
     def body(x, pool, w, row):
         h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
         q, k, v = project_qkv(cfg, h, w, positions, direct)
-        pool = pool.append(row, slots, rows(k), rows(v))
-        return finish_block(cfg, x, attend(q, pool, row), w), pool
+        if fused:
+            out, pool = attend(q, pool, row, rows(k), rows(v))
+        else:
+            pool = pool.append(row, slots, rows(k), rows(v))
+            out = attend(q, pool, row)
+        return finish_block(cfg, x, out, w), pool
 
     x, pool = _walk_decode(params, cfg, x, pool, body)
     return _logits(cfg, params, x), pool
@@ -842,7 +873,10 @@ def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
     `active` [B]: the live slots (None: every one). Where the int8
     pool's kernels are on, the append and the attention walk those
     alone: an idle slot writes nothing, attends to nothing, and its
-    logits are what zeros attended give (its token is discarded).
+    logits are what zeros attended give (its token is discarded); and
+    in a looped model's walk the two are ONE call a block, the
+    attention's, which writes the new row into the page it reads
+    (`fuses_append`).
     Returns (logits [B, V], updated pool)."""
     B = tokens.shape[0]
     ps = pool.page_size
@@ -855,16 +889,24 @@ def _decode_once(params, cfg: LlamaConfig, pool: PagePool, tokens, page_tables,
     slots = token_slots(cfg.n_kv_heads, page_idx, offset, use_pallas, mesh,
                         kernel_live_rows(pool, active, use_pallas))
 
-    def attend(q, pool, row):
+    fused = fuses_append(cfg, pool, use_pallas)
+
+    def attend(q, pool, row, *new):
         q = q[:, :, 0, :]
-        k_pages, v_pages, k_scales, layer = pool.attention_operands(row)
-        out = paged_attention_dispatch(
-            q, k_pages, v_pages, page_tables, lengths, k_scales=k_scales,
-            layer=layer, use_pallas=use_pallas, mesh=mesh, live=slots.live)
-        return out[:, :, None, :]
+
+        def over(k_pages, v_pages, k_scales, layer, new=None):
+            return paged_attention_dispatch(
+                q, k_pages, v_pages, page_tables, lengths, k_scales=k_scales,
+                layer=layer, use_pallas=use_pallas, mesh=mesh,
+                live=slots.live, new=new)
+
+        if fused:
+            out, pool = pool.attend_appending(row, *new, over)
+            return out[:, :, None, :], pool
+        return over(*pool.attention_operands(row))[:, :, None, :]
 
     logits, pool = _decode_rows(params, cfg, pool, tokens[:, None], positions,
-                                slots, attend, direct)
+                                slots, attend, direct, fused)
     return logits[:, 0], pool
 
 

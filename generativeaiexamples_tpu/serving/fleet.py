@@ -76,6 +76,7 @@ _LOG = logging.getLogger(__name__)
 _COUNTER_KEYS = (
     "tokens_generated", "decode_steps", "layer_passes",
     "decode_steps_direct_qkv", "decode_steps_kernel_append",
+    "decode_steps_fused_append",
     "decode_attn_pages_live", "decode_attn_pages_walked",
     "decode_attn_updates",
     "decode_attn_rows_skipped",

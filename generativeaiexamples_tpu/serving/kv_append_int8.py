@@ -27,6 +27,13 @@ slots of one call never share a tile. Slots of rank 2 (a verify's r rows
 a slot) WOULD share live tiles and race; append keeps them on the
 scatters.
 
+Who runs it (PR 46): every decode step of one row a slot but a LOOPED
+model's, whose attention call takes the new row as an operand and writes
+it from the page it has just copied
+(serving/paged_attention_int8.py, rule 6; engine_model.fuses_append
+decides), the same bytes by the same compare and select
+(tests/test_paged_attention_int8_pages.py).
+
 The codes and scales are the caller's, from the same `quantize_kv` as
 the scatter form writes: the pool is byte for byte what it would be
 everywhere but on the sink page, where the scatters leave an idle slot's
@@ -44,10 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
-    SPLIT_KV_BYTES, LiveRows, every_row)
-
-# Rows of an int8 tile: what one DMA descriptor can address in the pool.
-TILE_ROWS = 32
+    SPLIT_KV_BYTES, TILE_ROWS, LiveRows, every_row)
 
 
 def _append_kernel(

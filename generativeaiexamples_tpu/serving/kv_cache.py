@@ -45,12 +45,16 @@ inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
   step (tiny; rides along with the token ids).
 - A decode step's new row a slot goes into the int8 pool through ONE
   in-place Pallas call a cache row where kernels are on
-  (serving/kv_append_int8.py; `kernel_append` decides, from the pool,
-  the slots' rank and the step program's `use_pallas`), and through
-  XLA's scatters everywhere else: off the chip, a bf16 pool, a verify's
-  r rows a slot. Both forms write the same bytes outside the sink page
-  (QuantPagePool.append; tests/test_kv_append_kernel.py). Where the
-  kernels are on, the step's `active` mask reaches them as
+  (`kernel_append` decides, from the pool, the slots' rank and the step
+  program's `use_pallas`): the ATTENTION's own call in a looped model's
+  walk (QuantPagePool.attend_appending, serving/paged_attention_int8.py;
+  engine_model.fuses_append decides), the append's
+  (serving/kv_append_int8.py) in a one-pass model's blocks and in the
+  drawn blocks, whose call sites append first; and through XLA's
+  scatters everywhere else: off the chip, a bf16 pool, a verify's r rows
+  a slot. All three write the same bytes outside the sink page
+  (QuantPagePool.append; tests/test_kv_append_kernel.py,
+  tests/test_paged_attention_int8_pages.py). Where the kernels are on, the step's `active` mask reaches them as
   `TokenSlots.live` (`kernel_live_rows`, once a step): the append and the
   attention kernel walk the live slots and no other.
 
@@ -377,14 +381,16 @@ class QuantPagePool:
         new K and V, one scale a (kv head, token), in one of two forms
         that write the same bytes (tests/test_kv_append_kernel.py).
 
-        A step's ONE new row a slot (slots of rank 1: `decode_step`,
-        `decode_multi_step`, the fused step's decode half, the
-        spec-state walk) with kernels on: ONE Pallas call that patches,
-        in place, the int8 tile and the scale row the new token lives
-        in (serving/kv_append_int8.py; under a tensor-parallel mesh
-        through `shard_map` on the kv heads, as the attention kernel).
-        `kernel_append` decides, from the pool, the slots' rank and the
-        step program's `use_pallas`: no option selects the form.
+        A step's ONE new row a slot (slots of rank 1) with kernels on:
+        ONE Pallas call that patches, in place, the int8 tile and the
+        scale row the new token lives in (serving/kv_append_int8.py;
+        under a tensor-parallel mesh through `shard_map` on the kv
+        heads, as the attention kernel). `kernel_append` decides, from
+        the pool, the slots' rank and the step program's `use_pallas`:
+        no option selects the form. A LOOPED model's programs never call
+        `append` where this form would apply: their attention call
+        writes the row (`attend_appending`; engine_model.fuses_append,
+        PR 46).
 
         With `slots.live` the kernel reads and writes the live slots'
         tiles alone; an idle slot's (page 0, the sink) is left as it is.
@@ -402,17 +408,37 @@ class QuantPagePool:
         carries: they were 36 % of a Mistral-7B decode step and 56 % of
         an Ouro step (PERF.md section 5)."""
         kh, page_idx, offset, use_pallas, mesh, live = slots
-        kq, ks = self._quantize(k_new)
-        vq, vs = self._quantize(v_new)
         if kernel_append(self, use_pallas, page_idx.ndim):
             return self._append_kernel(row, page_idx, offset, mesh,
-                                       jnp.stack([kq, vq]),
-                                       jnp.stack([ks, vs]), live)
+                                       *self.new_row(k_new, v_new), live)
+        kq, ks = self._quantize(k_new)
+        vq, vs = self._quantize(v_new)
         kv = self.kv.at[0, row, kh, page_idx[None], offset[None], :].set(kq)
         kv = kv.at[1, row, kh, page_idx[None], offset[None], :].set(vq)
         s = self.s.at[0, row, kh, page_idx[None], offset[None]].set(ks)
         s = s.at[1, row, kh, page_idx[None], offset[None]].set(vs)
         return QuantPagePool(kv, s, self.page_size)
+
+    def new_row(self, k_new, v_new):
+        """A step's new K and V ([KH, B, Hd]) as the pool's kernels take
+        one row a slot: (codes [2, KH, B, Hd] int8, scales [2, KH, B])."""
+        kq, ks = self._quantize(k_new)
+        vq, vs = self._quantize(v_new)
+        return jnp.stack([kq, vq]), jnp.stack([ks, vs])
+
+    def attend_appending(self, row, k_new, v_new, attend) -> tuple:
+        """append's third form, for a looped model's step, whose attention
+        is the plain int8 kernel (`engine_model.fuses_append`): `attend(
+        *attention_operands(row), new) -> (out, kv, s)` is that call with
+        the new row as an operand (`new_row`), and the kernel writes the
+        row into the page it has just copied, where the append's kernel
+        read the tile again in a launch of its own
+        (serving/paged_attention_int8.py, rule 6; the same bytes:
+        tests/test_paged_attention_int8_pages.py). -> (out, the pool with
+        the row in it)."""
+        out, kv, s = attend(*self.attention_operands(row),
+                            self.new_row(k_new, v_new))
+        return out, QuantPagePool(kv, s, self.page_size)
 
     def _append_kernel(self, row, page_idx, offset, mesh, codes, scales,
                        live=None):

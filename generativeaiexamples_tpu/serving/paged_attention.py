@@ -305,7 +305,8 @@ def _paged_tpu(q, k_pages, v_pages, page_table, lengths, *, scale,
 
 
 def _paged_tpu_int8(q, kv_pages, kv_scales, page_table, lengths, layer,
-                    live=None, starts=None, *, scale, pages_per_compute_block):
+                    live=None, starts=None, new=None, *, scale,
+                    pages_per_compute_block):
     from generativeaiexamples_tpu.serving.paged_attention_int8 import (
         paged_attention_int8, paged_attention_int8_reference_fused,
         paged_attention_int8_window)
@@ -315,7 +316,7 @@ def _paged_tpu_int8(q, kv_pages, kv_scales, page_table, lengths, layer,
     # page_size % 128 == 0 (scale pages are (1, ps) f32 tiles) and
     # head_dim % 128 == 0. int8 serving configs use page_size=128.
     if ps % 128 == 0 and Hd % 128 == 0:
-        kw = dict(scale=scale, live=live,
+        kw = dict(scale=scale, live=live, new=new,  # -> (out, pool) with it
                   pages_per_compute_block=pages_per_compute_block)
         if starts is not None:
             return paged_attention_int8_window(
@@ -323,6 +324,7 @@ def _paged_tpu_int8(q, kv_pages, kv_scales, page_table, lengths, layer,
                 **kw)
         return paged_attention_int8(
             q, kv_pages, kv_scales, page_table, lengths, layer, **kw)
+    assert new is None, "kv_cache.kernel_append: the same two multiples"
     log_kernel_declined(
         "paged_attention_int8", "the XLA gather reference",
         f"page_size {ps} and head_dim {Hd} must both be multiples of 128")
@@ -336,11 +338,15 @@ def paged_attention_dispatch(
     k_scales=None, layer=None,
     use_pallas: Optional[bool] = None, mesh=None, interpret: bool = False,
     pages_per_compute_block: Optional[int] = None,
-    live=None, starts=None,
+    live=None, starts=None, new=None,
 ):
     """Pick the fastest available implementation for the current
     backend/mesh. `lengths` INCLUDES the current token, whose k/v must
-    already be written to the pool (write-then-attend decode). `live`
+    already be written to the pool (write-then-attend decode), unless
+    the caller hands it over as `new` (codes [2, KH, B, Hd], scales [2,
+    KH, B]; only where kv_cache.kernel_append holds, so that the form is
+    the int8 kernel, which then writes the row itself): the result is
+    then (out, the pool's codes, its scales). `live`
     (kv_cache.kernel_live_rows of the step's `active` mask, or None):
     the rows the int8 kernel walks, an idle row's output zeros; no other
     form reads it. `starts` [B] (a WINDOW row of an int8 pool on one
@@ -357,6 +363,8 @@ def paged_attention_dispatch(
     quantized = k_scales is not None
     use_pallas = (jax.default_backend() == "tpu") if use_pallas is None \
         else use_pallas
+    assert new is None or (quantized and use_pallas and starts is None), (
+        "the new row rides the int8 kernel alone")
     if not use_pallas:
         if quantized:
             from generativeaiexamples_tpu.serving.paged_attention_int8 import (
@@ -378,15 +386,19 @@ def paged_attention_dispatch(
             # Full fused pool [2, L, KH, P, ...]: kv-heads (the TP
             # axis) at axis 2.
             fused_s = P(None, None, "tensor")
+            # ... and at axis 1 of a new row's codes and scales
+            new_s = None if new is None else P(None, "tensor")
             fn = jax.shard_map(
                 functools.partial(
                     _paged_tpu_int8, scale=scale,
                     pages_per_compute_block=pages_per_compute_block),
                 mesh=mesh,  # the mask is replicated, as the tables are
-                in_specs=(hs, fused_s, fused_s, P(), P(), P(), P()),
-                out_specs=hs, check_vma=False)
+                in_specs=(hs, fused_s, fused_s, P(), P(), P(), P(), None,
+                          new_s),
+                out_specs=hs if new is None else (hs, fused_s, fused_s),
+                check_vma=False)
             return fn(q, k_pages, k_scales, page_table, lengths,
-                      jnp.asarray(layer, jnp.int32), live)
+                      jnp.asarray(layer, jnp.int32), live, None, new)
         pool_s = P("tensor", None, None, None)
         fn = jax.shard_map(
             lambda q_, kp_, vp_, t_, ln_: _paged_tpu(
@@ -397,7 +409,7 @@ def paged_attention_dispatch(
         return fn(q, k_pages, v_pages, page_table, lengths)
     if quantized:
         return _paged_tpu_int8(q, k_pages, k_scales, page_table, lengths,
-                               layer, live, starts, scale=scale,
+                               layer, live, starts, new, scale=scale,
                                pages_per_compute_block=pages_per_compute_block)
     return _paged_tpu(q, k_pages, v_pages, page_table, lengths, scale=scale,
                       interpret=interpret,

@@ -22,6 +22,21 @@ Layouts (per layer, matching kv_cache.QuantPagePool):
   starts     [B] int32 or None   a WINDOW row's first visible token (as
                                  `lengths`, counted from the table's
                                  first page); None: every token
+  new        (codes [2, KH, B, Hd] int8, scales [2, KH, B]) or None
+                                 a decode step's ONE new row a slot, not
+                                 in the pool yet: the call writes it
+
+Who writes a step's new row (PR 46). A LOOPED model's decode step hands
+it to this call as `new` (engine_model.fuses_append decides, from the
+pool and the step program's static arguments; kv_cache.QuantPagePool.
+attend_appending makes the call): the kernel has the row's last page in
+VMEM anyway, patches the row in there and writes the 32-row tile back, in
+place (rule 6 of `_int8_kernel`), where serving/kv_append_int8.py read
+that tile again in a launch of its own, once a cache row. Every other
+caller (a one-pass model's step, whose XLA schedule read longer without
+that launch, a verify's r rows a slot, a window row, the drawn blocks'
+call sites, serving/paged_attention_sparse.py) appends first and attends
+a pool that holds the row: `new` None, and the kernel is what it was.
 
 Kernel shape: grid (n_live,), a dynamic bound — ONE grid step per LIVE
 batch row (grid step k serves row order[k]) covering ALL kv heads, as a
@@ -183,6 +198,12 @@ PAGES_PER_BLOCK = 4
 BLOCKS_AHEAD = 2
 # Pages folded into one online-softmax update (`fold_pages`): the block's.
 FOLD_PAGES = 4
+# Rows of an int8 tile: what one DMA descriptor can address in the pool,
+# so what a step's new row costs to write (`NewRow`, kv_append_int8).
+TILE_ROWS = 32
+# Tiles a call's write-backs leave from, in turn: a row's write is waited
+# for when the row this many after it wants the tile.
+WRITES_AHEAD = 2
 
 # The kernel's state in SMEM, carried from one grid step to the next:
 # the buffer and the (place in `order`, block) to ask for next, the
@@ -268,6 +289,33 @@ def _fold_block(q, page, count: int, carry):
     return m_new, l_new, acc * alpha + pv
 
 
+class NewRow(NamedTuple):
+    """What `_int8_kernel` holds of the step's new row where it writes the
+    row itself (`_int8_append_kernel`)."""
+
+    scales: object   # scalar prefetch [B * 2 * KH] f32: new scales, by slot
+    codes: object    # VMEM [1, 2, KH, Hd] int32: row order[k]'s new codes
+    kv_tile: object  # VMEM [WRITES_AHEAD, 2, KH, 32, Hd] int8: a patched tile
+    s_rows: object   # VMEM [WRITES_AHEAD, 2, KH, 1, ps] f32: its scale rows
+    sem: object      # DMA sems [WRITES_AHEAD]: the write-backs'
+
+
+def _int8_append_kernel(lengths_ref, tables_ref, layer_ref, order_ref,
+                        n_live_ref, scales_ref, q_ref, codes_ref, kv_in,
+                        s_in, o_ref, kv_hbm, s_hbm, kv_buf, s_buf, sem, state,
+                        kv_tile, s_rows, wsem, **static):
+    """_int8_kernel for a decode step that has not written its new row yet:
+    the pool ALIASED input to output, the row's codes and scales as
+    operands, and the write done here (`_int8_kernel`, rule 6).
+    `n_live_ref` [2]: the rows to walk, and LiveRows.n_live as it was
+    before the clamp that makes a walk of no rows one of one."""
+    del kv_in, s_in  # the same buffers as the outputs
+    _int8_kernel(lengths_ref, tables_ref, layer_ref, order_ref, n_live_ref,
+                 q_ref, kv_hbm, s_hbm, o_ref, kv_buf, s_buf, sem, state,
+                 new=NewRow(scales_ref, codes_ref, kv_tile, s_rows, wsem),
+                 **static)
+
+
 def _int8_window_kernel(lengths_ref, tables_ref, layer_ref, order_ref,
                         n_live_ref, starts_ref, *refs, **static):
     """_int8_kernel for a WINDOW row: one scalar prefetch more, a row's
@@ -300,6 +348,7 @@ def _int8_kernel(
     tree=None,
     split_kv: bool = False,
     starts_ref=None,
+    new: Optional[NewRow] = None,
 ):
     """One grid step per LIVE BATCH ROW, all kv heads + k and v together:
     step k serves row order[k] through the q and o index maps, and the
@@ -352,7 +401,29 @@ def _int8_kernel(
        the pages that reach into the window, so the walk is the table's
        from its first page, and one compare more masks the tokens of that
        page that slid out already: exact to the token. No other row
-       compiles the compare or the prefetch."""
+       compiles the compare or the prefetch.
+    6. The step's NEW row (`new`; q_rep 1, no tree, no window; kv_hbm and
+       s_hbm are then the pool aliased input to output): the row's token
+       length - 1 lives in its LAST page, which the last block's copies
+       have just brought into VMEM, so that buffer is patched where it
+       lies (the codes into sublane offset % 32 of the tile, the scales
+       into lane offset: kv_append_int8's compare and select) before the
+       fold reads it, and the patched 32-row tile and the scale rows go
+       back to HBM from a tile of their own (`NewRow.kv_tile`), in one
+       write (two code descriptors under `split_kv`). Nothing waits for
+       that write but the row WRITES_AHEAD after this one, which wants
+       the tile, and the last row, which waits for every one still out.
+       A block short of `ppcb` pages is its row's last and writes
+       without a test; a whole block tests whether it is. With nobody
+       live the one row the grid serves gets its tile back as it came.
+       Read on a v5e (PERF.md section 5, PR 46): the write's own DMA
+       time stays, 0.1 us a row at 2 KV heads to 0.4 at 16 (2 x KH tiles
+       of 4 KB and as many scale rows of 512 B a row), so the call grows
+       by four fifths of what the append's kernel took and the pair
+       saves that kernel's read and its launch, 3.5-5 us of 70 at the
+       closed mixes; patching words of four rows in place of the int32
+       round trip, a third write tile, and the codes held whole in VMEM
+       each read SLOWER."""
     k = pl.program_id(0)
     n_live = n_live_ref[0]
     b = order_ref[k]
@@ -445,6 +516,58 @@ def _int8_kernel(
     n = pages_of(b)
     q = q_ref[0].astype(jnp.float32)  # [KH, G, Hd]
 
+    def write_new_row(slot, j):
+        """Rule 6, once the row's last page is page j of buffer `slot`."""
+        pid = tables_ref[b * maxp + n - 1]
+        off = lax.rem(length - 1, ps)
+        tile = pl.multiple_of((off // TILE_ROWS) * TILE_ROWS, TILE_ROWS)
+        rows = pl.ds(tile, TILE_ROWS)
+
+        def write_back(w, act):
+            """`act` on write tile w's way back to the pool, scale rows
+            and all. Built again to wait: a semaphore counts bytes, and
+            every row's write has as many."""
+            pairs = [(new.s_rows.at[w], s_hbm.at[:, layer, :, pid])]
+            if split_kv:
+                pairs += [(new.kv_tile.at[w, h],
+                           kv_hbm.at[h, layer, :, pid, rows]) for h in (0, 1)]
+            else:
+                pairs.append((new.kv_tile.at[w],
+                              kv_hbm.at[:, layer, :, pid, rows]))
+            for pair in pairs:
+                act(pltpu.make_async_copy(*pair, new.sem.at[w]))
+
+        w = lax.rem(k, WRITES_AHEAD)
+
+        @pl.when(k >= WRITES_AHEAD)
+        def _():  # what left from this tile WRITES_AHEAD rows ago has left
+            write_back(w, wait)
+
+        # nobody live (the grid serves an idle row): a place no iota has
+        at = jnp.where(n_live_ref[1] > 0, off, -1)
+        sub = lax.broadcasted_iota(jnp.int32, (TILE_ROWS, Hd), 0) == at - tile
+        lane = lax.broadcasted_iota(jnp.int32, (1, ps), 1) == at
+        for h in (0, 1):
+            codes = new.codes[0, h]  # [KH, Hd] int32
+            for kh in range(KH):
+                old = kv_buf[slot, j, h, kh, rows, :].astype(jnp.int32)
+                patched = jnp.where(sub, codes[kh:kh + 1, :],
+                                    old).astype(jnp.int8)
+                kv_buf[slot, j, h, kh, rows, :] = patched
+                new.kv_tile[w, h, kh] = patched
+                scales = jnp.where(lane, new.scales[(b * 2 + h) * KH + kh],
+                                   s_buf[slot, j, h, kh])
+                s_buf[slot, j, h, kh] = scales
+                new.s_rows[w, h, kh] = scales
+        write_back(w, start)
+
+        @pl.when(k == n_live - 1)
+        def _():  # and before the call ends, every write still out
+            for back in range(WRITES_AHEAD):
+                @pl.when(k >= back)
+                def _():
+                    write_back(lax.rem(k - back, WRITES_AHEAD), wait)
+
     def body(i, carry):
         slot = state[_TAKE_SLOT]
         ask()  # into the buffer the block before this one was taken from
@@ -475,16 +598,25 @@ def _int8_kernel(
                     s_buf[slot, j, 0], s_buf[slot, j, 1],    # [KH, 1, ps]
                     keep)
 
-        def block(count):
+        def block(count, last=False):
             def run(carry):
                 copies(b, i, slot, count, wait)
+                if last:
+                    write_new_row(slot, count - 1)
                 for at in range(0, count, fold):
                     carry = _fold_block(q, lambda j, at=at: page(at + j),
                                         min(fold, count - at), carry)
                 return carry
             return run
 
-        carry = by_live_count(n, i, block, carry)
+        if new is None:
+            carry = by_live_count(n, i, block, carry)
+        else:  # a whole block that is the row's last: one body more
+            live = jnp.minimum(ppcb, n - i * ppcb)
+            carry = lax.switch(
+                jnp.where(n == (i + 1) * ppcb, ppcb, live - 1),
+                [block(c, last=c < ppcb) for c in range(1, ppcb + 1)]
+                + [block(ppcb, last=True)], carry)
         state[_TAKE_SLOT] = after(slot)
         return carry
 
@@ -560,9 +692,18 @@ def _paged_attention_int8(
     split_kv: bool | None = None,
     live: Optional[LiveRows] = None,
     starts: Optional[jax.Array] = None,  # [B] int32: a window row's
-) -> jax.Array:
+    new=None,  # (codes [2, KH, B, Hd] int8, scales [2, KH, B]): the
+               # step's new row a slot, NOT in the pool yet
+):
     """What `paged_attention_int8` (starts None) and
     `paged_attention_int8_window` run.
+
+    `new` (a decode step's one new row a slot, as kv_append_int8 takes
+    it; q_rep 1, no tree, no window): token lengths - 1 of every LIVE row
+    is not in the pool yet and the call writes it there, in place, on its
+    way (`_int8_kernel`, rule 6): -> (out, kv_pages, kv_scales), the pool
+    byte for byte what kv_append_int8 and then this call without `new`
+    leave, and `out` bit for bit. The caller donates the pool.
 
     `live` (live_rows of the step's `active` mask): the kernel walks
     those rows alone, and an idle row's output is zeros, whatever its
@@ -611,10 +752,13 @@ def _paged_attention_int8(
         split_kv = L * KH * P * ps * Hd >= SPLIT_KV_BYTES
     ahead = BLOCKS_AHEAD
     assert starts is None or (q_rep == 1 and tree is None), (q_rep, tree)
+    assert new is None or (starts is None and q_rep == 1 and tree is None), (
+        "one new row a slot, of a plain decode step")
     kernel = functools.partial(
-        _int8_kernel if starts is None else _int8_window_kernel, ppcb=ppcb,
-        fold=fold_pages(KH, G, ppcb), maxp=maxp, page_size=ps, ahead=ahead,
-        q_rep=q_rep, tree=tree, split_kv=split_kv)
+        _int8_append_kernel if new is not None
+        else _int8_kernel if starts is None else _int8_window_kernel,
+        ppcb=ppcb, fold=fold_pages(KH, G, ppcb), maxp=maxp, page_size=ps,
+        ahead=ahead, q_rep=q_rep, tree=tree, split_kv=split_kv)
 
     def qmap(k, Ln, T, LY, order, *_):
         return (order[k], 0, 0, 0)
@@ -624,30 +768,55 @@ def _paged_attention_int8(
     # order[0] alone (an idle one: its page, the sink, is read and the
     # select below discards what comes of it)
     n_walk = jnp.maximum(rows.n_live, 1)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    extra = ()  # the scalar prefetches after n_walk
+    in_specs = [pl.BlockSpec((1, KH, G, Hd), qmap)]
+    out_specs = [pl.BlockSpec((1, KH, G, Hd), qmap)]
+    out_shape = [jax.ShapeDtypeStruct((B, KH, G, Hd), jnp.float32)]
+    scratch = [
+        pltpu.VMEM((ahead + 1, ppcb, 2, KH, ps, Hd), jnp.int8),
+        pltpu.VMEM((ahead + 1, ppcb, 2, KH, 1, ps), kv_scales.dtype),
+        pltpu.SemaphoreType.DMA((ahead + 1,)),
+        pltpu.SMEM((4,), jnp.int32),
+    ]
+    aliases = {}
+    if starts is not None:
+        extra = (starts.astype(jnp.int32),)
+    if new is not None:
+        codes, scales = new
+        assert codes.shape == (2, KH, B, Hd) and scales.shape == (2, KH, B), (
+            codes.shape, scales.shape, kv_pages.shape)
+        # by slot, as kv_append_int8 hands them to its kernel; and the
+        # live rows' count as it is, beside the walk's
+        n_walk = jnp.concatenate([n_walk, rows.n_live])
+        extra = (scales.transpose(2, 0, 1).reshape(-1),)
+        in_specs.append(pl.BlockSpec((1, 2, KH, Hd), qmap))
+        out_specs += [any_spec, any_spec]
+        out_shape += [jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype),
+                      jax.ShapeDtypeStruct(s2.shape, s2.dtype)]
+        scratch += [
+            pltpu.VMEM((WRITES_AHEAD, 2, KH, TILE_ROWS, Hd), jnp.int8),
+            pltpu.VMEM((WRITES_AHEAD, 2, KH, 1, ps), kv_scales.dtype),
+            pltpu.SemaphoreType.DMA((WRITES_AHEAD,)),
+        ]
+        # operands count the scalar prefetches (6), q and the codes
+        aliases = {8: 1, 9: 2}
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 if starts is None else 6,
+        num_scalar_prefetch=5 + len(extra),
         grid=(n_walk[0],),
-        in_specs=[
-            pl.BlockSpec((1, KH, G, Hd), qmap),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, KH, G, Hd), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((ahead + 1, ppcb, 2, KH, ps, Hd), jnp.int8),
-            pltpu.VMEM((ahead + 1, ppcb, 2, KH, 1, ps), kv_scales.dtype),
-            pltpu.SemaphoreType.DMA((ahead + 1,)),
-            pltpu.SMEM((4,), jnp.int32),
-        ],
+        in_specs=in_specs + [any_spec, any_spec],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
     )
     # The blocks are asked for in one chain over the live rows, and each
     # of those takes at least one: a live row of length 0 takes one page,
     # masked but for its first token. Clamp rather than assert.
     lengths = jnp.maximum(lengths.astype(jnp.int32), 1)
-    out = pl.pallas_call(
+    out, *pool = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, Hd), jnp.float32),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         # Sequential grid: what was asked for and taken threads through
         # SMEM from one grid step to the next.
         compiler_params=pltpu.CompilerParams(
@@ -655,9 +824,9 @@ def _paged_attention_int8(
         interpret=interpret,
         name=None if starts is None else "paged_attention_int8_window",
     )(lengths, page_table.reshape(-1).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), rows.order, n_walk,
-      *(() if starts is None else (starts.astype(jnp.int32),)),
-      qk, kv_pages, s2)
+      jnp.asarray(layer, jnp.int32).reshape(1), rows.order, n_walk, *extra,
+      qk, *(() if new is None else (
+          codes.transpose(2, 0, 1, 3).astype(jnp.int32),)), kv_pages, s2)
     if live is not None:
         # a row the grid never served holds whatever the buffer held: a
         # select, so that no stale NaN reaches a router or a sampler
@@ -665,4 +834,7 @@ def _paged_attention_int8(
     if q_rep > 1:
         return out.reshape(B, KH, q_rep, H // KH, Hd).transpose(
             0, 2, 1, 3, 4).reshape(B, q_rep, H, Hd).astype(q.dtype)
-    return out.reshape(B, H, Hd).astype(q.dtype)
+    out = out.reshape(B, H, Hd).astype(q.dtype)
+    if new is None:
+        return out
+    return out, pool[0], pool[1].reshape(kv_scales.shape)

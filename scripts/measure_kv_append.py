@@ -3,7 +3,8 @@
 on the chip, a decode step's worth of calls on a pool of the real size:
 
     chiprun -- python3 scripts/measure_kv_append.py \
-        [--shapes mistral,ouro,tp4] [--live 3,5,16,60,64] [--parent DIR]
+        [--shapes mistral,ouro,tp4] [--live 3,5,16,60,64] [--parent DIR] \
+        [--forms scatter,kernel,attend,fused]
 
 `scatter`: XLA's four scatters a cache row, what every program lowered
 off the chip keeps. `kernel`: one in-place Pallas call a row
@@ -11,7 +12,14 @@ off the chip keeps. `kernel`: one in-place Pallas call a row
 slots alone. `parent` (with `--parent DIR`, a `git archive` of another
 commit, e.g. .scratch/parent): that tree's kernel as it is, called with
 the codes this tree's pool quantizes; one that takes no mask writes every
-slot, an idle one to the sink page. Every form runs at each of `--live`
+slot, an idle one to the sink page. `attend` and `fused` (with
+`--forms`): the step's pair, a row's write AND its attention, as two
+calls in series (the kernel's append, then paged_attention_int8 over the
+slot's one page: what a step ran until PR 46) and as the one call that
+writes the row itself (`QuantPagePool.attend_appending`); their last line
+also says how far their outputs are apart (0.0: bit for bit;
+scripts/measure_paged_attention.py `--append` runs the pair at the cells'
+contexts). Every form runs at each of `--live`
 counts of live slots (capped at the shape's slots), the idle ones as the
 engine sends them: page 0, offset 0, `active` False. The shapes are the
 benchmark cells':
@@ -87,6 +95,8 @@ def main() -> int:
     from benchmark.harness import xplane
     from generativeaiexamples_tpu.serving.kv_cache import (
         QuantPagePool, kernel_append, kernel_live_rows, token_slots)
+    from generativeaiexamples_tpu.serving.paged_attention import (
+        paged_attention_dispatch)
     from scripts.measure_qkv_forms import by_operation
 
     forms = args.forms.split(",")
@@ -136,7 +146,7 @@ def main() -> int:
 
         def step(form):
             def kv_append_step(pool, page_idx, offset, k_new, v_new, active):
-                on = form == "kernel"
+                on = form in ("kernel", "attend", "fused")
                 # the mask becomes the walk's order once, outside the rows
                 slots = token_slots(KH, page_idx, offset, use_pallas=on,
                                     live=kernel_live_rows(pool, active, on))
@@ -146,28 +156,44 @@ def main() -> int:
                         parent_append).parameters:
                     kw["live"] = kernel_live_rows(pool, active, True)
 
+                def attend(k_pages, v_pages, k_scales, layer, new=None):
+                    # the slot's one page, up to the row just written
+                    return paged_attention_dispatch(
+                        q, k_pages, v_pages, page_idx[:, None], offset + 1,
+                        k_scales=k_scales, layer=layer, use_pallas=True,
+                        live=slots.live, new=new)
+
                 def append(pool, row, k, v):
+                    """-> (the pool, what the row's attention gave)"""
+                    if form == "fused":
+                        return pool.attend_appending(row, k, v, attend)[::-1]
+                    if form == "attend":
+                        pool = pool.append(row, slots, k, v)
+                        return pool, attend(*pool.attention_operands(row))
                     if form != "parent":
-                        return pool.append(row, slots, k, v)
+                        return pool.append(row, slots, k, v), 0.0
                     (kq, ks), (vq, vs) = pool._quantize(k), pool._quantize(v)
                     kv, s = parent_append(
                         pool.kv, pool.s, row, page_idx, offset,
                         jnp.stack([kq, vq]), jnp.stack([ks, vs]),
                         interpret=args.rehearse, **kw)
-                    return QuantPagePool(kv, s, PS)
+                    return QuantPagePool(kv, s, PS), 0.0
 
-                def one_pass(p, pool):
+                def one_pass(p, carry):
+                    pool, acc = carry
                     for l in range(blocks):
                         row = p * blocks + l
                         add = jnp.asarray(row, jnp.float32) / R
-                        pool = append(
+                        pool, out = append(
                             pool, row, (k_new + add).astype(jnp.bfloat16),
                             (v_new - add).astype(jnp.bfloat16))
-                    return pool
+                        acc = acc + out
+                    return pool, acc
 
+                carry = (pool, jnp.zeros(q.shape, jnp.float32))
                 if passes == 1:
-                    return one_pass(0, pool)
-                return jax.lax.fori_loop(0, passes, one_pass, pool)
+                    return one_pass(0, carry)
+                return jax.lax.fori_loop(0, passes, one_pass, carry)
             return jax.jit(kv_append_step, donate_argnums=(0,))
 
         @jax.jit
@@ -192,9 +218,10 @@ def main() -> int:
         base[:4] = (0, 31, 32, 127)
         k_new, v_new = (jnp.asarray(rng.standard_normal((KH, B, HD)),
                                     jnp.float32) for _ in range(2))
+        q = jnp.asarray(rng.standard_normal((B, 4 * KH, HD)), jnp.bfloat16)
         spread = rng.permutation(B)  # the live slots, apart as a batch's are
         counts = sorted({min(int(x), B) for x in args.live.split(",")})
-        digests = {}
+        digests, outs = {}, {}
         compiled_forms = {}
         for form, n_live in [(f, n) for n in counts for f in forms]:
             mask = np.zeros((B,), bool)
@@ -219,8 +246,10 @@ def main() -> int:
             text = compiled.as_text()
 
             def run(pool, i):
-                pool = compiled(pool, page_idx, offset(i), k_new, v_new,
-                                active)
+                pool, acc = compiled(pool, page_idx, offset(i), k_new, v_new,
+                                     active)
+                if i == 1 and form in ("attend", "fused"):
+                    outs.setdefault(n_live, {})[form] = np.asarray(acc)[mask]
                 # the TPU interpret mode's callbacks dispatch operations
                 # of their own and deadlock against this thread's next
                 # dispatch: a rehearsal lets each step finish first
@@ -262,11 +291,15 @@ def main() -> int:
             del pool
         for n_live, by_form in digests.items():
             first = next(iter(by_form.values()))
+            pair = list(outs.get(n_live, {}).values())
             say(shape=name, slots_live=n_live, forms=list(by_form),
                 same_bytes=all(
                     x.shape == y.shape and np.array_equal(x, y)
                     for other in by_form.values()
-                    for x, y in zip(first, other)))
+                    for x, y in zip(first, other)),
+                **({"max_abs_output_diff": float(np.max(
+                    np.abs(pair[0] - pair[1]), initial=0.0))}
+                   if len(pair) == 2 else {}))
     return 0
 
 

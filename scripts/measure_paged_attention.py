@@ -4,7 +4,7 @@ on the chip, over pools of the benchmark cells' real shapes:
 
     chiprun -- python3 scripts/measure_paged_attention.py \
         [--shapes mistral,ouro,tp4] [--blocks 4,5,8] [--folds 1,2] \
-        [--parent DIR]
+        [--parent DIR] [--append]
 
     mistral  32 rows,  8 KV heads of 32, 64 slots,  768 pages, tables of 20
     ouro    192 rows, 16 KV heads of 16, 32 slots,  112 pages, tables of 4
@@ -59,6 +59,7 @@ sys.path.insert(0, ROOT)
 
 PS, HD = 128, 128
 KERNEL = "generativeaiexamples_tpu/serving/paged_attention_int8.py"
+APPEND = "generativeaiexamples_tpu/serving/kv_append_int8.py"
 # name: (cache rows, kv heads, query heads, slots, pages, table width)
 SHAPES = {"mistral": (32, 8, 32, 64, 768, 20),
           "ouro": (192, 16, 16, 32, 112, 4),
@@ -86,10 +87,11 @@ def folding(pa8, width):
             fn.clear_cache()
 
 
-def load_kernel(checkout: str):
-    """The int8 kernel's module as another checkout has it."""
+def load_kernel(checkout: str, path: str = KERNEL):
+    """The int8 kernel's module (or the one at `path`) as another
+    checkout has it."""
     spec = importlib.util.spec_from_file_location(
-        "parent_paged_attention_int8", os.path.join(checkout, KERNEL))
+        "parent_" + os.path.basename(path)[:-3], os.path.join(checkout, path))
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
@@ -124,6 +126,44 @@ def length_sets(rng, slots: int, width: int) -> dict:
             "open": (open_mix, [x > 1 for x in open_mix])}
 
 
+def pool_maker(shape):
+    """A jitted maker of an int8 pool of `shape` and its scales, no two
+    neighbours alike (a tile written back wrong shows)."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def fresh_pool():
+        def mix(shape, weights):
+            return sum(w * jax.lax.broadcasted_iota(jnp.int32, shape, a)
+                       for a, w in enumerate(weights))
+        kv = (mix(shape, (131, 7, 29, 13, 3, 1)) % 255 - 127)
+        s = (mix(shape[:-1], (11, 5, 3, 7, 1)) % 97).astype(jnp.float32)
+        return kv.astype(jnp.int8), s * 1e-4 + 0.002
+    return fresh_pool
+
+
+def traced_us(run, program: str, rows: int) -> dict:
+    """Device us a call, whole and by operation, of three traced
+    executions of `run()` (a program named `program` of `rows` calls)."""
+    import jax
+
+    from benchmark.harness import xplane
+    from scripts.measure_qkv_forms import by_operation
+
+    tdir = tempfile.mkdtemp(prefix="paged_attention_trace_")
+    with jax.profiler.trace(tdir):
+        for _ in range(3):
+            res = run()
+        jax.block_until_ready(res)
+    red = by_operation(xplane.find_xplane(tdir), program, {})
+    shutil.rmtree(tdir, ignore_errors=True)
+    calls = max(red["executions"], 1) * rows
+    return dict(device_us_per_call=red["device_ms"] * 1e3 / calls,
+                op_us_per_call={k: v * 1e3 / calls
+                                for k, v in red["ops"].items()})
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--shapes", default="mistral,ouro,tp4")
@@ -134,6 +174,9 @@ def main() -> int:
                          "rule's, at the first of --blocks")
     ap.add_argument("--parent", default=None,
                     help="a checkout whose kernel is measured beside it")
+    ap.add_argument("--append", action="store_true",
+                    help="the step's pair: the new row's write and the "
+                         "attention, in series and fused")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
@@ -144,9 +187,7 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark.harness import xplane
     from generativeaiexamples_tpu.serving import paged_attention_int8 as pa8
-    from scripts.measure_qkv_forms import by_operation
 
     dev = jax.devices()[0]
     if not args.rehearse and dev.platform != "tpu":
@@ -166,6 +207,8 @@ def main() -> int:
         out.flush()
 
     say(device=dev.device_kind, rehearsal=args.rehearse, reps=args.reps)
+    if args.append:
+        return append_pairs(args, shapes, say)
     for name in args.shapes.split(","):
         rows, KH, H, B, P, width = shapes[name]
         forms = dict(parent_form)
@@ -177,16 +220,7 @@ def main() -> int:
             forms[f"change{blocks[0]}f{fold}"] = (pa8, blocks[0], fold)
         shape = (2, rows, KH, P, PS, HD)
 
-        @jax.jit
-        def fresh_pool():
-            def mix(shape, weights):
-                return sum(w * jax.lax.broadcasted_iota(jnp.int32, shape, a)
-                           for a, w in enumerate(weights))
-            kv = (mix(shape, (131, 7, 29, 13, 3, 1)) % 255 - 127)
-            s = (mix(shape[:-1], (11, 5, 3, 7, 1)) % 97).astype(jnp.float32)
-            return kv.astype(jnp.int8), s * 1e-4 + 0.002
-
-        kv, s = jax.block_until_ready(fresh_pool())
+        kv, s = jax.block_until_ready(pool_maker(shape)())
         rng = np.random.default_rng(7)
         q = jnp.asarray(rng.standard_normal((B, H, HD)), jnp.bfloat16)
         table = jnp.asarray(rng.integers(1, P, (B, width)), jnp.int32)
@@ -243,21 +277,142 @@ def main() -> int:
                         np.max(np.abs(got - want), initial=0.0)),
                     finite=bool(np.isfinite(got).all()))
                 if dev.platform == "tpu":
-                    tdir = tempfile.mkdtemp(prefix="paged_attention_trace_")
-                    with jax.profiler.trace(tdir):
-                        for _ in range(3):
-                            res = compiled(q, kv, s, table, lengths, active)
-                        jax.block_until_ready(res)
-                    red = by_operation(xplane.find_xplane(tdir),
-                                       "attend_rows", {})
-                    shutil.rmtree(tdir, ignore_errors=True)
-                    calls = max(red["executions"], 1) * rows
-                    line.update(
-                        device_us_per_call=red["device_ms"] * 1e3 / calls,
-                        op_us_per_call={k: v * 1e3 / calls
-                                        for k, v in red["ops"].items()})
+                    line.update(traced_us(
+                        lambda: compiled(q, kv, s, table, lengths, active),
+                        "attend_rows", rows))
                 say(**line)
         del kv, s
+    return 0
+
+
+def append_pairs(args, shapes, say) -> int:
+    """`--append`: the new row's write and the attention over it, in
+    series and fused, a cache row at a time on a pool the rows carry."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from generativeaiexamples_tpu.serving import kv_append_int8 as ka8
+    from generativeaiexamples_tpu.serving import paged_attention_int8 as pa8
+
+    dev = jax.devices()[0]
+    forms = {}  # name: (the append's module or None: fused, the kernel's)
+    if args.parent:
+        forms["parent_series"] = (load_kernel(args.parent, APPEND),
+                                  load_kernel(args.parent))
+    forms.update(series=(ka8, pa8), fused=(None, pa8))
+    for name in args.shapes.split(","):
+        rows, KH, H, B, P, width = shapes[name]
+        blk = min(int(args.blocks.split(",")[0]), width)
+        fresh_pool = pool_maker((2, rows, KH, P, PS, HD))
+
+        @jax.jit
+        def digest(kv, s, page_idx, offset):
+            """What a step may have touched, and sums over the rest less
+            the sink page (a slice a slot: a gather copies the pool)."""
+            tiles = [jax.lax.dynamic_slice(
+                kv, (0, 0, 0, page_idx[b], offset[b] // 32 * 32, 0),
+                (2, rows, KH, 1, 32, HD)) for b in range(B)]
+            scales = [jax.lax.dynamic_slice(
+                s, (0, 0, 0, page_idx[b], 0), (2, rows, KH, 1, PS))
+                for b in range(B)]
+            return (jnp.concatenate(tiles, 3), jnp.concatenate(scales, 3),
+                    jnp.sum(kv[:, :, :, 1:].astype(jnp.int32)),
+                    jnp.sum(s[:, :, :, 1:]))
+
+        rng = np.random.default_rng(7)
+        q = jnp.asarray(rng.standard_normal((B, H, HD)), jnp.bfloat16)
+        codes = jnp.asarray(rng.integers(-127, 128, (2, KH, B, HD)), jnp.int8)
+        scales = jnp.asarray(rng.random((2, KH, B)) * 0.01 + 0.002,
+                             jnp.float32)
+        sets = length_sets(rng, B, width)
+        # row b's LAST page is page 1 + b, whatever its length; the pages
+        # before it are anybody's but never a last one
+        others = rng.integers(1 + B, P, (B, width))
+        compiled = {}
+        for form, (append_mod, mod) in forms.items():
+            def append_attend_rows(q, kv, s, table, lengths, active, codes,
+                                   scales, append_mod=append_mod, mod=mod):
+                live = pa8.live_rows(active)
+                at = lengths - 1
+                page_idx = table[jnp.arange(B), at // PS]
+                kw = dict(pages_per_compute_block=blk,
+                          interpret=args.rehearse, live=live)
+
+                def row(l, carry):
+                    acc, kv, s = carry
+                    if append_mod is None:
+                        out, kv, s = mod.paged_attention_int8(
+                            q, kv, s, table, lengths, l,
+                            new=(codes, scales), **kw)
+                    else:
+                        kv, s = append_mod.kv_append_int8(
+                            kv, s, l, page_idx, at % PS, codes, scales, live,
+                            interpret=args.rehearse)
+                        out = mod.paged_attention_int8(
+                            q, kv, s, table, lengths, l, **kw)
+                    return acc + out.astype(jnp.float32), kv, s
+                return jax.lax.fori_loop(
+                    0, rows, row, (jnp.zeros((B, H, HD), jnp.float32), kv, s))
+
+            t0 = time.perf_counter()
+            kv, s = fresh_pool()
+            compiled[form] = (jax.jit(
+                append_attend_rows, donate_argnums=(1, 2)).lower(
+                    q, kv, s, jnp.zeros((B, width), jnp.int32),
+                    jnp.ones((B,), jnp.int32), jnp.zeros((B,), bool), codes,
+                    scales).compile(), time.perf_counter() - t0)
+            del kv, s
+        for set_name, (lens, mask) in sets.items():
+            lens, mask = np.asarray(lens), np.asarray(mask)
+            last = np.clip(-(-lens // PS), 1, width) - 1
+            table = others.copy()
+            table[np.arange(B), last] = 1 + np.arange(B)
+            table, lengths = jnp.asarray(table, jnp.int32), jnp.asarray(lens)
+            active = jnp.asarray(mask)
+            page_idx = jnp.asarray(np.where(mask, 1 + np.arange(B), 0))
+            offset = jnp.asarray(np.where(mask, (lens - 1) % PS, 0))
+            left, outs = {}, {}
+            for form, (program, compile_s) in compiled.items():
+                def run(kv, s):
+                    return program(q, kv, s, table, lengths, active, codes,
+                                   scales)
+
+                res, kv, s = run(*jax.block_until_ready(fresh_pool()))
+                outs[form] = np.asarray(res)[mask]
+                got = [np.asarray(x) for x in digest(kv, s, page_idx, offset)]
+                left[form] = [got[0][:, :, :, mask],
+                              got[1][:, :, :, mask]] + got[2:]
+                t0 = time.perf_counter()
+                for _ in range(args.reps):
+                    res, kv, s = run(kv, s)
+                jax.block_until_ready(res)
+                host_us = (time.perf_counter() - t0) * 1e6 / args.reps / rows
+                mem = program.memory_analysis()
+                line = dict(
+                    shape=name, form=form, lengths=set_name, rows=rows,
+                    kv_heads=KH, slots=B, table_width=width,
+                    rows_live=int(mask.sum()), temp_bytes=mem.temp_size_in_bytes,
+                    compile_s=round(compile_s, 1), host_us_per_call=host_us,
+                    finite=bool(np.isfinite(outs[form]).all()))
+                if dev.platform == "tpu":
+                    held = [kv, s]  # the donated pool, from run to run
+
+                    def again():
+                        res, *held[:] = run(*held)
+                        return res
+                    line.update(traced_us(again, "append_attend_rows", rows))
+                    kv, s = held
+                say(**line)
+                del res, kv, s
+            first, want = next(iter(left.values())), next(iter(outs.values()))
+            say(shape=name, lengths=set_name, forms=list(left),
+                same_bytes=all(
+                    x.shape == y.shape and np.array_equal(x, y)
+                    for other in left.values() for x, y in zip(first, other)),
+                max_abs_output_diff=max(
+                    float(np.max(np.abs(o - want), initial=0.0))
+                    for o in outs.values()))
     return 0
 
 

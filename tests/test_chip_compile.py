@@ -393,20 +393,32 @@ def test_decode_projections_stage_no_weight_in_a_short_or_looped_block(
 
 @pytest.mark.parametrize("looped,n_steps", [
     (False, 2), (False, 8), (True, 2)])
-def test_decode_program_appends_with_the_kernel_and_no_scatter(
+def test_decode_program_writes_its_new_row_in_place_and_with_no_scatter(
         chip, looped, n_steps):
-    """The served decode programs (the short block, the long one, a
-    looped model's) write the new K and V through kv_append_int8, once a
-    step and cache row; no XLA scatter is left anywhere in them, and
-    nothing the size of the pool is a temporary."""
+    """The served decode programs write the new K and V in place, with no
+    XLA scatter anywhere, the pool aliased through every call and nothing
+    the size of the pool a temporary. A looped model's program holds ONE
+    Pallas call a step and cache row, the attention's, which writes the
+    row itself (PR 46: no kv_append_int8 call); a one-pass model's blocks,
+    short and long, keep PR 32's two, the append's and the attention's
+    (`engine_model.fuses_append`)."""
     import re
 
     cfg, text, mem, pool_bytes = _compiled_decode(chip, looped, n_steps)
     assert " scatter(" not in text
-    calls = len(re.findall(r"^\s*(?:ROOT )?%?kv_append_int8[\w.]* = ", text,
-                           re.M))
+
+    def calls(name):
+        return len(re.findall(rf"^\s*(?:ROOT )?%?{name}[\w.]* = ", text,
+                              re.M))
+
     # a looped model's passes are a loop around its blocks
-    assert calls == n_steps * cfg.n_layers, calls
+    rows = n_steps * cfg.n_layers
+    fused = looped
+    assert calls("paged_attention_int8") == rows
+    assert calls("kv_append_int8") == (0 if fused else rows)
+    assert ("kv_append_int8" in text) == (not fused)
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        rows if fused else 2 * rows)
     # the step's mask is turned into the kernels' walk ONCE a program
     # (`active` does not change inside a block): one sort, whatever the
     # steps and layers (PR 41)
@@ -470,6 +482,62 @@ def test_kv_append_kernel_compiles_in_place_for_v5e(topo, chip, name, masked):
     text = compiled.as_text()
     assert "kv_append_int8" in text and "tpu_custom_call" in text
     assert " scatter(" not in text
+    mem = compiled.memory_analysis()  # of one device
+    pool_bytes = 2 * rows * kv_heads * pages * PS * (HD + 4)
+    if mesh is not None:
+        pool_bytes //= mesh.size
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 1000, mem
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["", "masked"])
+@pytest.mark.parametrize("name", sorted(APPEND_SHAPES))
+def test_the_attention_call_writes_the_new_row_in_place_for_v5e(
+        topo, chip, name, masked):
+    """PR 46: the attention call with the step's new row as an operand,
+    through the dispatch (shard_map under the mesh), at the three pools'
+    shapes and the cells' table widths: what interpret mode cannot see
+    is that the tile's dynamic sublane slice and its words of four rows
+    lower, that the write tiles fit beside the blocks' buffers in VMEM
+    (Ouro's 541 KB pages, split descriptors), and that the pool is
+    ALIASED through the call, with no temporary."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from generativeaiexamples_tpu.serving.paged_attention import (
+        paged_attention_dispatch)
+
+    rows, kv_heads, slots, pages = APPEND_SHAPES[name]
+    heads, width = {"mistral-7b": (32, 20), "ouro-2.6b": (16, 4),
+                    "mistral-small-tp4": (32, 4)}[name]
+    mesh = None
+    if name.endswith("tp4"):  # the 2x2 topology, kv heads on "tensor"
+        mesh = Mesh(np.array(topo.devices), ("tensor",))
+    specs = {"pool": PartitionSpec(None, None, "tensor"),
+             "new": PartitionSpec(None, "tensor"), None: PartitionSpec()}
+
+    def arr(shape, dtype, kind=None):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=(
+            NamedSharding(mesh, specs[kind]) if mesh is not None else chip))
+
+    def attend(q, kv, s, table, lengths, codes, scales, active):
+        return paged_attention_dispatch(
+            q, kv, None, table, lengths, k_scales=s, layer=1,
+            use_pallas=True, mesh=mesh, new=(codes, scales),
+            live=live_rows(active) if masked else None)
+
+    compiled = jax.jit(attend, donate_argnums=(1, 2)).lower(
+        arr((slots, heads, HD), BF16, "new"),
+        arr((2, rows, kv_heads, pages, PS, HD), I8, "pool"),
+        arr((2, rows, kv_heads, pages, PS), F32, "pool"),
+        arr((slots, width), I32), arr((slots,), I32),
+        arr((2, kv_heads, slots, HD), I8, "new"),
+        arr((2, kv_heads, slots), F32, "new"),
+        arr((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "paged_attention_int8" in text and "tpu_custom_call" in text
+    assert "kv_append_int8" not in text and " scatter(" not in text
+    assert "all-gather" not in text and "all-reduce" not in text
     mem = compiled.memory_analysis()  # of one device
     pool_bytes = 2 * rows * kv_heads * pages * PS * (HD + 4)
     if mesh is not None:

@@ -325,8 +325,9 @@ def _tiny(looped=False):
 def test_decode_multi_step_with_the_kernel_gives_the_scatters_tokens_and_pool(
         looped, monkeypatch):
     """One tiny int8 `decode_multi_step` with kernels on (interpreted),
-    its append the kernel, against the same program made to keep the
-    scatters: the same tokens, and the same pool byte for byte outside
+    its new row written by the append's kernel or, in the looped walk, by
+    the attention call itself (PR 46), against the same program made to
+    keep the scatters: the same tokens, and the same pool byte for byte outside
     the sink page. (Against the program the CPU serves the tokens are
     the same too; its attention is the XLA reference, whose rounding
     moves a later layer's scales by an ulp.) The batch is mostly idle,
@@ -375,7 +376,9 @@ def test_decode_multi_step_with_the_kernel_gives_the_scatters_tokens_and_pool(
                                   pool_0.s[:, :, :, 0])
     # every slot computed, by the scatters and the unmasked attention
     # kernel: the parent's program
+    assert em.fuses_append(cfg, pool_k, True) == looped
     monkeypatch.setattr(kv_cache, "kernel_append", lambda *a, **k: False)
+    monkeypatch.setattr(em, "kernel_append", lambda *a, **k: False)
     _, (block_s, last_s, pool_s) = run(True)
     np.testing.assert_array_equal(np.asarray(block_k)[active],
                                   np.asarray(block_s)[active])
@@ -469,6 +472,7 @@ def test_an_engine_with_idle_slots_streams_the_tokens_the_parent_streamed(
     assert [len(t) for t in got] == [9, 4, 7]
     snap, steps = metrics.snapshot(), metrics.decode_steps
     assert snap["decode_steps_kernel_append"] == steps > 0
+    assert snap["decode_steps_fused_append"] == 0  # a one-pass model
     assert snap["decode_attn_rows_skipped"] == (
         steps * B - metrics.busy_slots_acc) > steps  # over a row a step idle
     monkeypatch.setattr(em, "kernel_live_rows", lambda *a, **k: None)
@@ -550,11 +554,16 @@ def test_a_pool_without_the_two_kernels_gets_no_live_list():
     assert token_slots(1, active, active).live is None
 
 
+@pytest.mark.parametrize("looped", [False, True], ids=["", "looped"])
 @pytest.mark.parametrize("use_pallas", [True, False])
-def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas):
+def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas,
+                                                            looped):
     """`decode_steps_kernel_append` equals `decode_steps` for a plain
-    int8 engine with kernels on and is 0, never absent, with them off,
-    and so are the pages its attention kernel reads and would have walked
+    int8 engine with kernels on, and `decode_steps_fused_append` does for
+    a LOOPED model's (its attention call writes the row, PR 46: no step
+    is left to the other counter, which counts the steps that launch the
+    append); both are 0, never absent, with kernels off, and so are the
+    pages its attention kernel reads and would have walked
     and the idle rows both kernels leave out; they are in snapshot(), in
     /metrics and among the fleet's sums."""
     from generativeaiexamples_tpu.config.schema import EngineConfig
@@ -565,7 +574,7 @@ def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas):
     from generativeaiexamples_tpu.serving.flight import prometheus_text
     from generativeaiexamples_tpu.utils.tokenizer import ByteTokenizer
 
-    cfg = _tiny()
+    cfg = _tiny(looped)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     ecfg = EngineConfig(max_batch_size=2, max_seq_len=256, page_size=PS,
                         kv_dtype="int8", prefill_buckets=(128,),
@@ -582,8 +591,11 @@ def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas):
             eng.stop()
     assert len(served) == 6
     assert snap["decode_steps"] > 0
-    assert snap["decode_steps_kernel_append"] == (
-        snap["decode_steps"] if use_pallas else 0)
+    by_form = {"decode_steps_fused_append": looped,
+               "decode_steps_kernel_append": not looped}
+    for name, engaged in by_form.items():
+        assert snap[name] == (
+            snap["decode_steps"] if use_pallas and engaged else 0), name
     # the same steps attend through paged_attention_int8: of a step's
     # two rows the live one (under a page long) HAS a page and the idle
     # one none, where whole blocks over a table of two would cover both
@@ -594,9 +606,82 @@ def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas):
     assert snap["decode_attn_rows_skipped"] == steps
     # ... and a row of one page is one softmax update, whatever the fold
     assert snap["decode_attn_updates"] == steps
-    for name in ("decode_steps_kernel_append", "decode_attn_pages_live",
+    for name in ("decode_steps_kernel_append", "decode_steps_fused_append",
+                 "decode_attn_pages_live",
                  "decode_attn_pages_walked", "decode_attn_rows_skipped",
                  "decode_attn_updates"):
         assert name in fleet._COUNTER_KEYS
         assert name in prometheus_text(snap)
         assert EngineMetrics().snapshot()[name] == 0
+
+
+# name: (a looped model, the plan)
+FUSING = {"looped": (True, PLANS["plain"]), "plain": (False, PLANS["plain"]),
+          "rider": (False, PLANS["rider"]),
+          "spec_state": (False, PLANS["spec_state"]),
+          "verify": (False, PLANS["verify"])}
+
+
+@pytest.mark.parametrize("name", list(FUSING))
+def test_a_looped_walks_step_hands_its_new_row_to_the_attention_call(
+        name, monkeypatch):
+    """PR 46: with kernels on, a LOOPED model's decode program never calls
+    the pool's append: the attention call takes the step's one new row a
+    slot (`QuantPagePool.attend_appending`), once a step and cache row. A
+    one-pass model's programs of one row a slot (plain, the fused rider's
+    decode half, the spec-state lane) keep the append's kernel in a launch
+    of its own; a verify's r rows a slot (rank 2) keep the scatters.
+    `engine_model.fuses_append` says which, and no drawn block's
+    configuration fuses."""
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.models.window_attn_moe import (
+        WindowAttnMoeConfig)
+    from generativeaiexamples_tpu.serving import engine_model as em
+
+    looped, plan = FUSING[name]
+    cfg, plan = _tiny(looped), em.StepPlan(**plan)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    B = 2
+    pool = _pool(cfg.n_kv_heads, 5, rows=cfg.cache_rows)
+    assert em.fuses_append(cfg, pool, True) == looped
+    assert not em.fuses_append(cfg, pool, False)  # kernels off
+    # a drawn block keeps its own call site, and the separate append
+    assert kernel_append(pool, True) and not em.fuses_append(
+        WindowAttnMoeConfig.tiny(), pool, True)
+    calls = {"append": 0, "attend_appending": 0, "_append_kernel": 0}
+    for method in calls:
+        def counted(self, *a, _method=method,
+                    _fn=getattr(QuantPagePool, method), **k):
+            calls[_method] += 1
+            return _fn(self, *a, **k)
+        monkeypatch.setattr(QuantPagePool, method, counted)
+    kw = dict(pool=pool, last_tokens=jnp.asarray([3, 7], jnp.int32),
+              page_tables=jnp.asarray([[1, 2], [3, 4]], jnp.int32),
+              active=jnp.ones((B,), bool), use_pallas=True)
+    lengths = jnp.asarray([5, 100], jnp.int32)
+    if plan.spec_k or plan.spec_state:
+        kw.update(history=jnp.zeros((B, 256), jnp.int32), dev_lengths=lengths)
+    if not plan.spec_k:
+        kw.update(lengths=lengths, temperature=jnp.zeros((B,), jnp.float32),
+                  top_p=jnp.ones((B,), jnp.float32),
+                  top_k=jnp.zeros((B,), jnp.int32),
+                  rng=jax.random.PRNGKey(1))
+    if plan.rider_width:
+        kw.update(cache=llama.KVCache.zeros(cfg, 1, max_len=32),
+                  chunk_tokens=jnp.zeros((1, 8), jnp.int32),
+                  chunk_valid=jnp.int32(5))
+    jax.clear_caches()  # the program is traced anew, the counters in it
+    with interpreted():
+        jax.block_until_ready(em._plan_step(params, cfg, plan, **kw))
+    jax.clear_caches()
+    # a looped model's passes are a loop around its blocks: traced once
+    rows = plan.decode_k * cfg.n_layers
+    if name == "verify":  # rank 2: the scatters
+        assert calls["attend_appending"] == calls["_append_kernel"] == 0
+        assert calls["append"] > 0
+    elif looped:
+        assert calls == {"append": 0, "attend_appending": rows,
+                         "_append_kernel": 0}
+    else:
+        assert calls == {"append": rows, "attend_appending": 0,
+                         "_append_kernel": rows}

@@ -476,3 +476,179 @@ def test_the_rule_is_a_width_the_block_can_hold():
                             (8, 16), (8, 20), (2, 2)]:
         for ppcb in (1, 2, 4, 5, 8):
             assert 1 <= pa8.fold_pages(kv_heads, group, ppcb) <= ppcb
+
+
+# -- the step's new row, written by the attention call (PR 46) --------------
+# `paged_attention_int8(..., new=(codes, scales))` against the two calls a
+# step ran until then (kv_append_int8, then the kernel): the pools compared
+# BYTE FOR BYTE everywhere, the outputs bit for bit. At the tiles' real
+# size (the write is a 32-row tile of a 128-row page), pools of random
+# bytes, every live row on pages of its own and an idle one on page 0.
+
+APS, AHD, AKH, AH = 128, 128, 2, 4
+# name: (table width, pages a block, lengths, live rows or None: no mask,
+# split descriptors). Eight rows each and few table widths, so that the
+# interpreted programs are traced once a form and not once a case.
+APPENDED = {
+    "offset_0": (4, None, [1, APS + 1, 2 * APS + 1, 3 * APS + 1] * 2, None,
+                 False),
+    "offset_31": (4, None, [32, APS + 32, 2 * APS + 32, 3 * APS + 32] * 2,
+                  None, False),
+    "offset_32": (4, None, [33, APS + 33, 2 * APS + 33, 3 * APS + 33] * 2,
+                  None, False),
+    "offset_last_of_a_page": (4, None, [APS, 2 * APS, 3 * APS, 4 * APS] * 2,
+                              None, False),
+    "length_1": (4, None, [1] * 8, None, False),
+    "more_rows_than_writes_ahead": (
+        4, None, [7, 40, 129, 200, 256, 257, 300, 512], None, False),
+    # the row's last page is the only page of its last block (5 and 9 of
+    # blocks of 4), and a whole block that IS the last (4, 8)
+    "last_page_alone_in_its_block": (
+        9, None, [4 * APS + 1, 9 * APS, 4 * APS, 8 * APS - 3, 5 * APS, 1,
+                  8 * APS + 1, 3 * APS], None, False),
+    "blocks_of_2_and_every_count": (
+        5, 2, [APS, 2 * APS, 2 * APS + 7, 4 * APS, 5 * APS - 1, 1, 3 * APS,
+               4 * APS + 1], None, False),
+    "idle_between_live": (
+        4, None, [13, 1, 300, 1, 1, APS + 1, 22, 4 * APS],
+        [True, False, True, False, False, True, True, True], False),
+    "first_and_last_rows_idle": (
+        4, None, [1, 2 * APS, 77, 1, 1, 3 * APS + 5, 9, 1],
+        [False, True, True, False, False, True, True, False], False),
+    "all_idle": (4, None, [1, 9, 1, 300, 1, 1, 1, 1], [False] * 8, False),
+    "every_row_live_said_with_a_mask": (
+        4, None, [5, APS + 64, 3 * APS, 1, 2, 4 * APS, 33, 2 * APS + 1],
+        [True] * 8, False),
+    "split_descriptors": (
+        9, None, [1, 32, APS, 4 * APS + 1, 8 * APS, 9 * APS, 77, 5 * APS],
+        None, True),
+    "split_descriptors_idle_between_live": (
+        4, None, [13, 1, 300, 1, 4 * APS, 1, 1, APS + 32],
+        [True, False, True, False, True, False, False, True], True),
+}
+
+
+def _step_with_a_new_row(lengths, maxp, mask, kv_heads=AKH, seed=0):
+    """(q, pool kv, s, table, lengths, live, new row's codes, scales, the
+    rows' (page, offset)) of one decode step: the new row is token
+    lengths - 1 of every live slot, and an idle slot's table row is the
+    sink's."""
+    rng = np.random.default_rng(seed)
+    B = len(lengths)
+    pages = B * maxp + 1
+    shape = (2, LAYERS, kv_heads, pages, APS, AHD)
+    kv = jnp.asarray(rng.integers(-127, 128, shape, dtype=np.int8))
+    s = jnp.asarray(rng.random(shape[:-1], dtype=np.float32) * 0.05 + 0.01)
+    table = 1 + np.arange(B * maxp).reshape(B, maxp)
+    if mask is not None:
+        table = np.where(np.asarray(mask)[:, None], table, 0)
+    lengths = np.asarray(lengths, np.int32)
+    page_idx = table[np.arange(B), (lengths - 1) // APS]
+    q = jax.random.normal(jax.random.PRNGKey(seed),
+                          (B, kv_heads * (AH // AKH), AHD), jnp.float32)
+    codes = jnp.asarray(rng.integers(-127, 128, (2, kv_heads, B, AHD),
+                                     dtype=np.int8))
+    scales = jnp.asarray(rng.random((2, kv_heads, B), dtype=np.float32))
+    live = None if mask is None else pa8.live_rows(jnp.asarray(mask))
+    return (q, kv, s, jnp.asarray(table, jnp.int32), jnp.asarray(lengths),
+            live, codes, scales, jnp.asarray(page_idx, jnp.int32),
+            jnp.asarray((lengths - 1) % APS, jnp.int32))
+
+
+@pytest.mark.parametrize("case", list(APPENDED))
+def test_the_call_that_writes_the_new_row_leaves_the_two_calls_bytes(case):
+    from generativeaiexamples_tpu.serving.kv_append_int8 import kv_append_int8
+
+    maxp, block, lengths, mask, split_kv = APPENDED[case]
+    (q, kv, s, table, lens, live, codes, scales, page_idx,
+     offset) = _step_with_a_new_row(lengths, maxp, mask, seed=len(case))
+    kw = dict(pages_per_compute_block=block, split_kv=split_kv,
+              interpret=True, live=live)
+    kv_2, s_2 = kv_append_int8(kv, s, LAYER, page_idx, offset, codes, scales,
+                               live, interpret=True, split_kv=split_kv)
+    want = pa8.paged_attention_int8(q, kv_2, s_2, table, lens, LAYER, **kw)
+    got, kv_1, s_1 = pa8.paged_attention_int8(
+        q, kv, s, table, lens, LAYER, new=(codes, scales), **kw)
+    np.testing.assert_array_equal(np.asarray(kv_1), np.asarray(kv_2))
+    np.testing.assert_array_equal(np.asarray(s_1), np.asarray(s_2))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the sink page and every other layer's rows are what they were
+    for after, before in ((kv_1, kv), (s_1, s)):
+        after, before = np.asarray(after), np.asarray(before)
+        np.testing.assert_array_equal(after[:, :, :, 0], before[:, :, :, 0])
+        np.testing.assert_array_equal(after[:, 1 - LAYER], before[:, 1 - LAYER])
+    served = np.ones(len(lengths), bool) if mask is None else np.asarray(mask)
+    touched = np.argwhere(np.asarray(kv_1) != np.asarray(kv))
+    assert set(touched[:, 3]) <= set(np.asarray(page_idx)[served].tolist())
+    assert served.any() == bool(len(touched))
+    # the new row is IN what the live rows attended: without it they
+    # read otherwise
+    if served.any():
+        stale = pa8.paged_attention_int8(q, kv, s, table, lens, LAYER, **kw)
+        assert not np.array_equal(np.asarray(stale)[served],
+                                  np.asarray(got)[served])
+
+
+def test_without_the_new_row_the_call_is_the_one_it_was():
+    """No `starts`, no `new`: ONE array comes back, the reference's over
+    a pool that holds the row already, and the call aliases nothing (the
+    pool comes back from the other variant alone)."""
+    (q, kv, s, table, lens, _, _, _, _, _) = _step_with_a_new_row(
+        [5, APS + 1, 3 * APS], 4, None)
+    out = pa8.paged_attention_int8(q, kv, s, table, lens, LAYER,
+                                   interpret=True)
+    assert isinstance(out, jax.Array) and out.shape == q.shape
+    want = pa8.paged_attention_int8_reference_fused(
+        q, kv[:, LAYER], s[:, LAYER], table, lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    def traced(**kw):  # not interpreted: the call is one equation
+        return str(jax.make_jaxpr(lambda *a: pa8.paged_attention_int8(
+            *a, LAYER, **kw))(q, kv, s, table, lens))
+
+    assert "input_output_aliases=()" in traced()
+    assert "input_output_aliases=((8, 1), (9, 2))" in traced(
+        new=(jnp.zeros((2, AKH, 3, AHD), jnp.int8), jnp.zeros((2, AKH, 3))))
+    # a verify's and a window row's calls take no new row
+    with pytest.raises(AssertionError, match="one new row a slot"):
+        pa8.paged_attention_int8(
+            jnp.zeros((3, 2, AH, AHD)), kv, s, table, lens, LAYER, q_rep=2,
+            interpret=True, new=(jnp.zeros((2, AKH, 3, AHD), jnp.int8),
+                                 jnp.zeros((2, AKH, 3))))
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["", "masked"])
+def test_the_new_row_over_four_virtual_devices(masked):
+    """Under a tensor-parallel mesh, through the dispatch's shard_map on
+    the kv heads (one a device): the pool and the output of the pool's
+    own append under the same mesh and then the dispatch without the
+    row."""
+    from jax.sharding import Mesh
+
+    from generativeaiexamples_tpu.serving.kv_cache import QuantPagePool
+    from generativeaiexamples_tpu.serving.paged_attention import (
+        paged_attention_dispatch)
+    from test_kv_append_kernel import interpreted
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four (virtual) devices")
+    mesh = Mesh(np.array(jax.devices()[:4]), ("tensor",))
+    mask = [True, False, True, True] if masked else None
+    (q, kv, s, table, lens, live, codes, scales, page_idx,
+     offset) = _step_with_a_new_row([13, 1, 2 * APS, APS + 32], 4, mask,
+                                    kv_heads=4)
+
+    def attend(kv, s, new=None):
+        return paged_attention_dispatch(
+            q, kv, None, table, lens, k_scales=s, layer=LAYER,
+            use_pallas=True, mesh=mesh, live=live, new=new)
+
+    with interpreted():
+        two = QuantPagePool(kv, s, APS)._append_kernel(
+            LAYER, page_idx, offset, mesh, codes, scales, live)
+        want = attend(two.kv, two.s)
+        got, kv_1, s_1 = attend(kv, s, (codes, scales))
+    np.testing.assert_array_equal(np.asarray(kv_1), np.asarray(two.kv))
+    np.testing.assert_array_equal(np.asarray(s_1), np.asarray(two.s))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.array_equal(np.asarray(kv_1), np.asarray(kv))
