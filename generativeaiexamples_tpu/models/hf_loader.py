@@ -292,20 +292,6 @@ def _quantize_numpy_leaf(a: np.ndarray, contract_axis: int = -2):
     return QuantizedTensor(q, np.squeeze(s, axis=contract_axis))
 
 
-def quantize_llama_numpy_tree(tree: dict) -> dict:
-    """bf16/f32 numpy llama tree -> weight-only-int8 tree, on host."""
-    from generativeaiexamples_tpu.ops.quant import LLAMA_QUANT_KEYS
-
-    out = dict(tree)
-    out["layers"] = {
-        k: (_quantize_numpy_leaf(v) if k in LLAMA_QUANT_KEYS else v)
-        for k, v in tree["layers"].items()
-    }
-    if "lm_head" in tree:
-        out["lm_head"] = _quantize_numpy_leaf(tree["lm_head"])
-    return out
-
-
 # ---------------------------------------------------------------------------
 # Layer-streaming llama load
 # ---------------------------------------------------------------------------

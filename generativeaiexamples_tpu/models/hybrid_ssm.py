@@ -103,9 +103,6 @@ class HybridSsmConfig:
     # what serving/ reads of any model configuration
     n_passes = 1
     post_norms = False
-    latent_row = None
-    index_row = None
-    window_rows = None
     expert_offset = 0
 
     def __post_init__(self):

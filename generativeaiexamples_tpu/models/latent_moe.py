@@ -82,9 +82,6 @@ class LatentMoeConfig:
     # what serving/ reads of any model configuration
     n_passes = 1
     post_norms = False
-    recurrent_state = None
-    index_row = None
-    window_rows = None
 
     def __post_init__(self):
         if not 0 < self.experts_held <= self.n_routed_experts \

@@ -135,28 +135,8 @@ class LlamaConfig:
         The cache's leading axis is THIS, not the weights' layer axis."""
         return self.n_layers * self.n_passes
 
-    @property
-    def latent_row(self):
-        """What a cache row holds when it is not K and V per head: None
-        here (serving/kv_cache.py builds the pool from this)."""
-        return None
-
-    @property
-    def recurrent_state(self):
-        """What the model carries per SEQUENCE beside its cache rows
-        (a state-space layer's state): nothing here
-        (serving/kv_cache.py builds a HybridPool where there is)."""
-        return None
-
     # sparse experts whose weights live here: none (serving/engine.py)
     experts_held = 0
-    # an indexer's key cached beside K and V (learned sparse attention):
-    # none (serving/kv_cache.py builds a SparseIndexPool where there is)
-    index_row = None
-    # window layers beside global ones, each group of cache rows under a
-    # page table of its own: none (serving/kv_cache.py builds a WindowPool
-    # where there are)
-    window_rows = None
 
     @property
     def post_norm_init(self) -> float:
@@ -188,11 +168,6 @@ class LlamaConfig:
     def llama3_70b() -> "LlamaConfig":
         return LlamaConfig(dim=8192, n_layers=80, n_heads=64, n_kv_heads=8,
                            mlp_dim=28672)
-
-    @staticmethod
-    def llama3_1_8b() -> "LlamaConfig":
-        return LlamaConfig(max_seq_len=131072,
-                           rope_scaling=RopeScaling(factor=8.0))
 
     @staticmethod
     def llama3_2_1b() -> "LlamaConfig":

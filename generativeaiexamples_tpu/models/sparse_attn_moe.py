@@ -99,9 +99,6 @@ class SparseAttnMoeConfig:
     # what serving/ reads of any model configuration
     n_passes = 1
     post_norms = False
-    latent_row = None
-    recurrent_state = None
-    window_rows = None
     expert_offset = 0
 
     def __post_init__(self):

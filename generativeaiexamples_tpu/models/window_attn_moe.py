@@ -112,9 +112,6 @@ class WindowAttnMoeConfig:
     # what serving/ reads of any model configuration
     n_passes = 1
     post_norms = False
-    latent_row = None
-    recurrent_state = None
-    index_row = None
     expert_offset = 0
 
     def __post_init__(self):

@@ -69,21 +69,19 @@ from generativeaiexamples_tpu.models.llama import LlamaConfig
 from generativeaiexamples_tpu.obs import tracing
 from generativeaiexamples_tpu.serving import engine_model
 from generativeaiexamples_tpu.serving.kv_cache import (
-    PageAllocator, PagePool, SequencePages, WindowPool, WindowSequencePages,
-    WindowTables, engine_window_table_pages, kernel_append,
-    window_pool_pages)
-from generativeaiexamples_tpu.serving.ssm_state_update import kernel_update
+    PageAllocator, SequencePages, WindowSequencePages, WindowTables,
+    kernel_append)
 from generativeaiexamples_tpu.serving import flight as flight_mod
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
     PAGES_PER_BLOCK, fold_pages, page_counts)
-from generativeaiexamples_tpu.serving.paged_attention_sparse import walk_counts
+from generativeaiexamples_tpu.serving.served_models import metric_keys, served
 from generativeaiexamples_tpu.serving.multihost import (
     fetch_addressable as mh_fetch_addressable,
     fetch_replicated as mh_fetch_replicated)
 from generativeaiexamples_tpu.serving.flight import (
     EV_ADMIT, EV_ADMIT_RETRY, EV_DECODE_JOIN, EV_FIRST_TOKEN, EV_KV_DEMOTE,
     EV_KV_PROMOTE, EV_KV_TRANSFER, EV_MOE_LOAD, EV_PREFILL_CHUNK,
-    EV_SPARSE_SELECT, EV_WINDOW_CACHE,
+    EV_WINDOW_CACHE,
     EV_PREFILL_DISPATCH, EV_PROGRAM, EV_QOS_PAUSE, EV_QOS_PICK,
     EV_QOS_RESUME, EV_RETIRE, EV_SUBMIT, PROG_CHUNK, PROG_DECODE,
     PROG_PREFILL, PROGRAM_CLASSES, RETIRE_CODES, ExpHistogram,
@@ -222,7 +220,7 @@ class _InFlight:
 
     __slots__ = ("block", "metas", "K", "releases", "spec_worst",
                  "plain_spec", "t_dispatch", "plan", "prog", "t_ready",
-                 "sparse", "window")
+                 "noted", "window")
 
     def __init__(self, block, metas, K, spec_worst: int = 0,
                  plain_spec: bool = False):
@@ -236,10 +234,9 @@ class _InFlight:
         # off) and the clock reading of the thread its fetch ended on.
         self.prog: Optional[Program] = None
         self.t_ready = 0.0
-        # A model with learned sparse attention: the block's
-        # `sparse_select` event (a, b), from the lengths it was
-        # dispatched with (_note_sparse_select); None for every other.
-        self.sparse = None
+        # The flight event (code, a, b) the entry's `note_decode` made of
+        # the lengths the block was dispatched with; None for most.
+        self.noted = None
         # A model with window layers: the block's `window_cache` event
         # and, a live slot, the position its window pages are released
         # behind when the block lands (_note_window_cache); None for
@@ -312,11 +309,35 @@ class _LongPrefill:
         self.chunk = chunk
 
 
+# EngineMetrics' counters that are an attribute of their key's name:
+# snapshot() emits them and a fleet sums them from this ONE list.
+_COUNTERS = (
+    "decode_steps", "layer_passes", "decode_steps_direct_qkv",
+    "decode_steps_kernel_append", "decode_steps_fused_append",
+    "decode_attn_pages_live", "decode_attn_pages_walked",
+    "decode_attn_updates", "decode_attn_rows_skipped", "prefill_rows_live",
+    "prefill_rows_bucket", "moe_pairs_routed", "moe_pairs_local",
+    "window_pages_released", "decode_attn_window_pages_walked",
+    "prefill_tokens", "fused_steps", "fused_prefill_tokens",
+    "prefill_stall_beats", "fused_sample_dispatches", "prefix_hits",
+    "prefix_miss", "prefix_evictions", "prefix_hit_tokens",
+    "plan_variants_compiled", "spec_fallback_steps", "kv_transfer_pages",
+    "kv_transfer_device_pages", "kv_transfer_chunks", "admission_failures",
+    "qos_preemptions", "stuck_thread_joins", "program_stalls",
+)
+
+
 class EngineMetrics:
     """Serving metrics (BASELINE.md north stars): TTFT, tokens/s, batch
     occupancy. Lock-free reads, single-writer scheduler thread."""
 
     RATE_WINDOW_S = 30.0  # tokens_per_sec sliding window
+
+    # The snapshot's keys that SUM across the replicas of a fleet
+    # (fleet.counter_keys adds the pager's and the served architectures'
+    # own); every other key is a gauge, a rate or a histogram.
+    SUMMED = _COUNTERS + ("tokens_generated", "kv_transfer_ms",
+                          "flight_beats", "flight_events")
 
     def __init__(self):
         # Exponential-bucket latency histograms (serving/flight.py)
@@ -391,40 +412,17 @@ class EngineMetrics:
         self.moe_pairs_routed = 0
         self.moe_pairs_local = 0
         self.experts_held = 0
-        # Recurrent state beside the cache (0 for a model without it):
-        # the bytes a decode slot's state-space rows take and the layers
-        # that have them (gauges), the slots a prefill wrote whole, and
-        # the decode steps whose state update ran as the in-place kernel
-        # (ssm_state_update.kernel_update).
-        self.ssm_state_bytes_per_slot = 0
-        self.ssm_layers = 0
-        self.ssm_slot_writes = 0
-        self.ssm_steps_kernel = 0
-        # Learned sparse attention (0 for a model without an indexer):
-        # the bytes of index key a cached token takes over all layers and
-        # the tokens a query attends to at most (gauges); index keys
-        # scored (the live slots' lengths, summed over steps and layers),
-        # rows attended (min(length, topk) the same way), and slot-steps
-        # whose context was within topk, where selection is the identity;
-        # the pages serving/paged_attention_sparse.py copies and multiplies
-        # for them (each live slot's cdiv(length, page_size)) and the
-        # blocks it walks them in, a softmax update and a loop turn each
-        # (paged_attention_sparse.walk_counts): pages / blocks is the
-        # pages an update covers.
-        self.index_bytes_per_token = 0
-        self.sparse_topk = 0
-        self.sparse_keys_scored = 0
-        self.sparse_rows_attended = 0
-        self.sparse_steps_dense = 0
-        self.sparse_attn_pages_walked = 0
-        self.sparse_attn_blocks_walked = 0
-        # Window and global layers in one model (0 for a model without
-        # window rows): the window in tokens and the bytes a cached token
-        # takes in a window row's pages over all window layers (gauges;
-        # kv_bytes_per_token counts the global rows alone); window pages
-        # given back to their allocator behind a sliding window, pages
-        # the live sequences hold there now (a gauge), and pages the
-        # window rows' attention calls walked (a page a call).
+        # What the served architectures count and describe beside these
+        # (serving/served_models.py: every entry's `counters` and
+        # `gauges`; docs/observability.md says what each one is): 0 and
+        # present for every model that is not theirs.
+        self._arch_keys = sum(metric_keys(), ())
+        for key in self._arch_keys:
+            setattr(self, key, 0)
+        # Window rows (0 without them): the window in tokens and the bytes
+        # a cached token takes in their pages (gauges; kv_bytes_per_token
+        # counts the global rows alone), window pages given back behind a
+        # sliding window, held now (a gauge), and walked by their calls.
         self.window_tokens = 0
         self.window_bytes_per_token = 0
         self.window_pages_released = 0
@@ -488,7 +486,7 @@ class EngineMetrics:
         # engine IMPORTED from a prefill-role replica and the wall ms
         # those imports cost (scatter dispatch + radix insert). Always
         # present — 0, never absent, when fleet.disagg is off — and
-        # summed fleet-wide via fleet._COUNTER_KEYS.
+        # summed fleet-wide (SUMMED above).
         self.kv_transfer_pages = 0
         self.kv_transfer_ms = 0.0
         # Device-path / chunked transfer (PR 17): pages that arrived as
@@ -581,50 +579,16 @@ class EngineMetrics:
             # None until a first token has been recorded, as before.
             "ttft_p50_ms": ttft["p50"], "ttft_p95_ms": ttft["p95"],
             "tokens_generated": self.tokens_out,
-            "decode_steps": self.decode_steps,
-            "layer_passes": self.layer_passes,
-            "decode_steps_direct_qkv": self.decode_steps_direct_qkv,
-            "decode_steps_kernel_append": self.decode_steps_kernel_append,
-            "decode_steps_fused_append": self.decode_steps_fused_append,
-            "decode_attn_pages_live": self.decode_attn_pages_live,
-            "decode_attn_pages_walked": self.decode_attn_pages_walked,
-            "decode_attn_updates": self.decode_attn_updates,
-            "decode_attn_rows_skipped": self.decode_attn_rows_skipped,
-            "prefill_rows_live": self.prefill_rows_live,
-            "prefill_rows_bucket": self.prefill_rows_bucket,
+            **{key: getattr(self, key) for key in _COUNTERS},
             "kv_cache_rows": self.kv_cache_rows,
             "kv_bytes_per_token": self.kv_bytes_per_token,
-            "moe_pairs_routed": self.moe_pairs_routed,
-            "moe_pairs_local": self.moe_pairs_local,
             "experts_held": self.experts_held,
-            "ssm_state_bytes_per_slot": self.ssm_state_bytes_per_slot,
-            "ssm_layers": self.ssm_layers,
-            "ssm_slot_writes": self.ssm_slot_writes,
-            "ssm_steps_kernel": self.ssm_steps_kernel,
-            "index_bytes_per_token": self.index_bytes_per_token,
-            "sparse_topk": self.sparse_topk,
-            "sparse_keys_scored": self.sparse_keys_scored,
-            "sparse_rows_attended": self.sparse_rows_attended,
-            "sparse_steps_dense": self.sparse_steps_dense,
-            "sparse_attn_pages_walked": self.sparse_attn_pages_walked,
-            "sparse_attn_blocks_walked": self.sparse_attn_blocks_walked,
             "window_tokens": self.window_tokens,
             "window_bytes_per_token": self.window_bytes_per_token,
-            "window_pages_released": self.window_pages_released,
             "window_pages_held": self.window_pages_held,
-            "decode_attn_window_pages_walked":
-                self.decode_attn_window_pages_walked,
+            **{key: getattr(self, key) for key in self._arch_keys},
             "mean_batch_occupancy": occ,
             "tokens_per_sec": self.tokens_per_sec(),
-            "prefill_tokens": self.prefill_tokens,
-            "fused_steps": self.fused_steps,
-            "fused_prefill_tokens": self.fused_prefill_tokens,
-            "prefill_stall_beats": self.prefill_stall_beats,
-            "fused_sample_dispatches": self.fused_sample_dispatches,
-            "prefix_hits": self.prefix_hits,
-            "prefix_miss": self.prefix_miss,
-            "prefix_evictions": self.prefix_evictions,
-            "prefix_hit_tokens": self.prefix_hit_tokens,
             "multihost_processes": self.multihost_processes,
             "planner_headroom_bytes": self.planner_headroom_bytes,
             "replay_records_published": self.replay_records_published,
@@ -635,16 +599,7 @@ class EngineMetrics:
             "spec_tokens_per_step": (self.spec_committed
                                      / self.spec_slot_steps
                                      if self.spec_slot_steps else 0.0),
-            "plan_variants_compiled": self.plan_variants_compiled,
-            "spec_fallback_steps": self.spec_fallback_steps,
-            "kv_transfer_pages": self.kv_transfer_pages,
             "kv_transfer_ms": round(self.kv_transfer_ms, 3),
-            "kv_transfer_device_pages": self.kv_transfer_device_pages,
-            "kv_transfer_chunks": self.kv_transfer_chunks,
-            "admission_failures": self.admission_failures,
-            "qos_preemptions": self.qos_preemptions,
-            "stuck_thread_joins": self.stuck_thread_joins,
-            "program_stalls": self.program_stalls,
             # Copied so a scrape never observes the scheduler mutating
             # the gauge mid-iteration (dict reads are GIL-atomic, the
             # copy just freezes the snapshot).
@@ -715,121 +670,22 @@ _ONE_PASS_LANES = (
 
 def _refuse_unwalked_lanes(cfg: LlamaConfig, ecfg: EngineConfig,
                            mesh=None) -> None:
-    if cfg.latent_row is not None:
-        # A latent page pool (kv_cache.LatentPagePool) is written by the
-        # prefill and decode programs only: nothing reads its pages
-        # back, moves or shares them, and no step but those two has the
-        # latent block.
-        on = [(name, what) for name, what in _ONE_PASS_LANES
-              if getattr(ecfg, name)]
-        if mesh is not None:
-            on.append(("mesh", "tensor parallelism over heads: a latent "
-                       "row is one vector for all heads"))
-        if jnp.dtype(ecfg.kv_dtype) == jnp.int8:
-            on.append(("kv_dtype int8", "an int8 latent pool"))
-        if ecfg.multihost:
-            on.append(("multihost", "the multi-host replay"))
-        if on:
-            raise ValueError(
-                f"model caches a latent row of {sum(cfg.latent_row)} values "
-                f"a token and layer (latent attention); not served with "
-                + ", ".join(f"engine.{name} ({what})" for name, what in on)
-                + ": those lanes have no latent form; turn them off")
+    """Refuse, by name, the lanes that are on and have no form for `cfg`'s
+    architecture: `_ONE_PASS_LANES` and its entry's own."""
+    entry = served(cfg)
+    caches = entry.caches(cfg)
+    if caches is None:
         return
-    if cfg.recurrent_state is not None:
-        # The per-slot rows of a kv_cache.HybridPool are written by the
-        # prefill and decode programs only: nothing snapshots them beside
-        # a shared page, moves them with a sequence or rolls them back,
-        # and no step but those two has the state-space block.
-        on = [(name, what) for name, what in _ONE_PASS_LANES
-              if getattr(ecfg, name)]
-        if mesh is not None:
-            on.append(("mesh", "tensor parallelism: state-space heads have "
-                       "no sharded form"))
-        if ecfg.multihost:
-            on.append(("multihost", "the multi-host replay"))
-        if ecfg.qos and ecfg.qos_preempt_prefill:
-            on.append(("qos_preempt_prefill", "pausing and resuming a "
-                       "sequence's prefill"))
-        if on:
-            rs = cfg.recurrent_state
-            raise ValueError(
-                f"model carries recurrent state ({rs.layers} state-space "
-                f"layers, {rs.bytes_per_slot} bytes a sequence) beside its "
-                f"cache; not served with "
-                + ", ".join(f"engine.{name} ({what})" for name, what in on)
-                + ": those lanes re-read, share, move or roll back cache "
-                "and would have to carry the state too; turn them off")
-        return
-    if cfg.index_row is not None:
-        # The index rows of a kv_cache.SparseIndexPool are written by the
-        # prefill and decode programs only: nothing snapshots them beside
-        # a shared page, moves them with a sequence or rolls them back,
-        # and no step but those two has the indexer and the selection.
-        on = [(name, what) for name, what in _ONE_PASS_LANES
-              if getattr(ecfg, name)]
-        if mesh is not None:
-            on.append(("mesh", "tensor parallelism: the indexer's one key "
-                       "head and the selection have no sharded form"))
-        if jnp.dtype(ecfg.kv_dtype) != jnp.int8:
-            on.append((f"kv_dtype {jnp.dtype(ecfg.kv_dtype).name}",
-                       "K and V beside the index rows in another type than "
-                       "int8"))
-        if ecfg.multihost:
-            on.append(("multihost", "the multi-host replay"))
-        if ecfg.qos and ecfg.qos_preempt_prefill:
-            on.append(("qos_preempt_prefill", "pausing and resuming a "
-                       "sequence's prefill"))
-        if on:
-            raise ValueError(
-                f"model caches an index key of {cfg.index_row} values a "
-                f"token and layer beside K and V (learned sparse attention, "
-                f"top {cfg.index_topk}); not served with "
-                + ", ".join(f"engine.{name} ({what})" for name, what in on)
-                + ": those lanes re-read, share, move or roll back cache "
-                "and would have to carry the index rows too; turn them off")
-        return
-    if cfg.window_rows is not None:
-        # A kv_cache.WindowPool has two page tables a sequence, and a
-        # window row's pages are given back while the sequence lives:
-        # every lane below knows one table and pages held to the end.
-        # Nobody has said yet what a prefix hit, a snapshot or a rollback
-        # means for a page that slid out.
-        on = [(name, what) for name, what in _ONE_PASS_LANES
-              if getattr(ecfg, name)]
-        if mesh is not None:
-            on.append(("mesh", "tensor parallelism: the window rows' kernel "
-                       "call has no sharded form"))
-        if jnp.dtype(ecfg.kv_dtype) != jnp.int8:
-            on.append((f"kv_dtype {jnp.dtype(ecfg.kv_dtype).name}",
-                       "window rows in another type than int8"))
-        if ecfg.multihost:
-            on.append(("multihost", "the multi-host replay"))
-        if ecfg.qos and ecfg.qos_preempt_prefill:
-            on.append(("qos_preempt_prefill", "pausing and resuming a "
-                       "sequence's prefill"))
-        if on:
-            wr = cfg.window_rows
-            raise ValueError(
-                f"model has {wr.n_window} window layers ({wr.window} tokens) "
-                f"beside {wr.n_global} global ones, each group of cache "
-                f"rows under a page table of its own; not served with "
-                + ", ".join(f"engine.{name} ({what})" for name, what in on)
-                + ": those lanes re-read, share, move or roll back cache "
-                "through ONE table a sequence whose pages are held to its "
-                "end; turn them off")
-        return
-    if cfg.n_passes == 1:
-        return
+    kv_dtype = jnp.dtype(ecfg.kv_dtype).name
     on = [(name, what) for name, what in _ONE_PASS_LANES
           if getattr(ecfg, name)]
+    on += [(name.format(kv_dtype=kv_dtype), what)
+           for is_on, name, what in entry.lanes if is_on(ecfg, mesh)]
     if on:
         raise ValueError(
-            f"model runs its {cfg.n_layers} blocks n_passes={cfg.n_passes} "
-            f"times a token ({cfg.cache_rows} cache rows); not served with "
+            f"{caches}; not served with "
             + ", ".join(f"engine.{name} ({what})" for name, what in on)
-            + ": those lanes are untested against a looped model; turn "
-            "them off")
+            + f": {entry.why_not}; turn them off")
 
 
 class LLMEngine:
@@ -935,29 +791,22 @@ class LLMEngine:
                                                shd.KV_FUSED_SCALE_SPEC)
             else:
                 kv_sharding = NamedSharding(self.mesh, shd.KV_POOL_SPEC)
-        # A model with window layers: a second pool, allocator and page
-        # table for their rows. A sequence holds at most
-        # `_window_table_pages` of its pages: the window's and those the
-        # decode blocks in flight write ahead of a release.
+        # The architecture's entry: its pool, flags and accounting. Window
+        # layers' rows have a second pool, allocator and page table; a
+        # sequence holds at most `_window_table_pages` of its pages.
+        self.served = entry = served(cfg)
         self.window_allocator = None
         self._window_table_pages = 0
-        if cfg.window_rows is not None:
-            self._window_table_pages = engine_window_table_pages(
-                cfg.window_rows.window, self.ecfg)
-            n_window_pages = window_pool_pages(cfg.window_rows.window,
-                                               self.ecfg)
-            self.pool = WindowPool.zeros(cfg, n_pages, n_window_pages, ps)
+        if entry.second_pool is not None:
+            n_window_pages, self._window_table_pages = entry.second_pool(
+                cfg, self.ecfg)
             self.window_allocator = PageAllocator(n_window_pages,
                                                   name="window-row KV")
-        else:
-            self.pool = PagePool.zeros(cfg, n_pages, ps,
-                                       dtype=jnp.dtype(self.ecfg.kv_dtype),
-                                       sharding=kv_sharding,
-                                       scale_sharding=scale_sharding,
-                                       slots=self.ecfg.max_batch_size)
+        self.pool = entry.new_pool(cfg, self.ecfg, n_pages, kv_sharding,
+                                   scale_sharding)
         self.allocator = PageAllocator(
-            n_pages, name="global-row KV" if cfg.window_rows is not None
-            else "KV")
+            n_pages, name="KV" if self.window_allocator is None
+            else "global-row KV")
         # Cross-request prefix KV reuse (serving/prefix_cache.py):
         # scheduler-thread-owned, like the allocator. The allocator's
         # reclaim hook LRU-evicts cached pages whenever live traffic
@@ -1009,43 +858,10 @@ class LLMEngine:
         self._load_rows = engine_model.expert_load_rows(cfg)
         self.metrics.experts_held = (cfg.experts_held if self._load_rows
                                      else 0)
-        rs = cfg.recurrent_state
-        paged = self.pool
-        if rs is not None or cfg.index_row is not None:
-            paged = self.pool.pages  # K and V alone
-        if cfg.window_rows is not None:
-            paged = self.pool.glob  # the global rows; the window rows below
         self.metrics.kv_bytes_per_token = sum(
-            leaf.nbytes for leaf in jax.tree.leaves(paged)
+            leaf.nbytes for leaf in jax.tree.leaves(entry.kv_pages(self.pool))
         ) // (n_pages * ps)
-        if cfg.index_row is not None:
-            self.metrics.index_bytes_per_token = (
-                self.pool.idx.nbytes // (n_pages * ps))
-            self.metrics.sparse_topk = cfg.index_topk
-            _LOG.info("index rows: %d values a token and layer, %d bytes a "
-                      "cached token; a query attends to %d tokens at most",
-                      cfg.index_row, self.metrics.index_bytes_per_token,
-                      cfg.index_topk)
-        if cfg.window_rows is not None:
-            wr, win = cfg.window_rows, self.pool.win
-            self.metrics.window_tokens = wr.window
-            self.metrics.window_bytes_per_token = sum(
-                leaf.nbytes for leaf in jax.tree.leaves(win)
-            ) // (win.n_pages * ps)
-            _LOG.info("window rows: %d layers see %d tokens, %d pages of %d "
-                      "tokens (a sequence holds %d at most), %d bytes a "
-                      "cached token; the %d global rows take %d bytes a "
-                      "cached token in the pool below",
-                      wr.n_window, wr.window, win.n_pages, ps,
-                      self._window_table_pages,
-                      self.metrics.window_bytes_per_token, wr.n_global,
-                      self.metrics.kv_bytes_per_token)
-        if rs is not None:
-            self.metrics.ssm_layers = rs.layers
-            self.metrics.ssm_state_bytes_per_slot = rs.bytes_per_slot
-            _LOG.info("state pool: %d state-space layers x %d slots, %d "
-                      "bytes a slot", rs.layers, self.ecfg.max_batch_size,
-                      rs.bytes_per_slot)
+        entry.describe(self.metrics, cfg, self.ecfg, self.pool, n_pages)
         _LOG.info("kv pool: %d rows (%d layers x %d passes) x %d pages of "
                   "%d tokens, %s; %d bytes a cached token",
                   cfg.cache_rows, cfg.n_layers, cfg.n_passes, n_pages, ps,
@@ -1810,10 +1626,7 @@ class LLMEngine:
         # scatter into the page pool), so the real ceiling is the page
         # capacity minus one generated token.
         max_prompt = self.max_pages * self.ecfg.page_size - 1
-        if self.cfg.latent_row is not None \
-                or self.cfg.recurrent_state is not None \
-                or self.cfg.index_row is not None \
-                or self.cfg.window_rows is not None:
+        if not self.served.long_prompts:
             # the chunked long-prompt lane (a contiguous scratch cache of
             # K and V per head) has no latent form, carries no recurrent
             # state from chunk to chunk, holds no index rows and writes
@@ -2294,10 +2107,9 @@ class LLMEngine:
                 host = self._fetch_block_host(fl)
             if self._load_rows and not isinstance(host, tuple):
                 host = self._note_expert_load(fl, host, time.perf_counter())
-            if fl.sparse is not None:
-                self.flight.record_event(EV_SPARSE_SELECT,
-                                         time.perf_counter(),
-                                         a=fl.sparse[0], b=fl.sparse[1])
+            if fl.noted is not None:
+                event, a, b = fl.noted
+                self.flight.record_event(event, time.perf_counter(), a=a, b=b)
             if fl.window is not None:
                 self._land_window_cache(fl.window)
             # The landed block proves every program enqueued before it
@@ -2331,32 +2143,6 @@ class LLMEngine:
         return fold_pages(self.cfg.n_kv_heads // tensor,
                           self.cfg.n_heads // self.cfg.n_kv_heads,
                           min(PAGES_PER_BLOCK, table_width))
-
-    def _note_sparse_select(self, lengths, active_mask, K: int):
-        """A decode block of a model with learned sparse attention, from
-        the lengths the host dispatches it with: every live slot scores
-        all its cached index keys in every layer and step (a slot is one
-        token longer each step) and attends to min(length, topk) of
-        them, walking all its pages for it in blocks (walk_counts).
-        Counts them and returns the block's `sparse_select` event
-        (a = keys scored a live slot, step and layer; b = rows attended
-        over keys scored); None for every other model."""
-        if self.cfg.index_row is None:
-            return None
-        live = np.asarray(lengths, np.int64)[np.asarray(active_mask, bool)]
-        ctx = live[None, :] + np.arange(K)[:, None]          # [K, n_live]
-        topk = self.cfg.index_topk
-        scored = int(ctx.sum())
-        attended = int(np.minimum(ctx, topk).sum())
-        L = self.cfg.n_layers
-        self.metrics.sparse_keys_scored += scored * L
-        self.metrics.sparse_rows_attended += attended * L
-        self.metrics.sparse_steps_dense += int((ctx <= topk).sum())
-        pages, blocks = walk_counts(ctx, self.pool.page_size, self.max_pages)
-        self.metrics.sparse_attn_pages_walked += pages * L
-        self.metrics.sparse_attn_blocks_walked += blocks * L
-        return (scored / max(ctx.size, 1),
-                attended / scored if scored else 0.0)
 
     def _note_window_cache(self, lengths, active, win_base, K: int):
         """A decode block of a model with window layers, from the lengths
@@ -3064,9 +2850,7 @@ class LLMEngine:
         all_greedy = bool(all(temps[:n] <= 0.0))
         flags = (True, False, False) if all_greedy else (False, True, True)
         live = bucket  # the rows the program computes of each prompt
-        if self.cfg.latent_row is None and self.cfg.recurrent_state is None \
-                and self.cfg.index_row is None \
-                and self.cfg.window_rows is None:
+        if self.served.live_prefill_rows:
             live = engine_model.prefill_row_counts(bucket, ps, N)[
                 int(engine_model.prefill_live_index(lengths, bucket, ps))]
         self.metrics.prefill_rows_live += N * live
@@ -3084,14 +2868,13 @@ class LLMEngine:
         self._await_program(prog, toks)
         seq_no = float(prog.seq) if prog is not None else 0.0
         metas = []
+        self.served.note_prefill(self.metrics, self.cfg, len(entries))
         for req, slot_idx, seq, ids in entries:
             slot = _Slot(req, seq, StreamDetokenizer(self.tokenizer),
                          span=self._request_span(req, len(ids)))
             self.slots[slot_idx] = slot
             metas.append((slot_idx, slot))
             self.metrics.prefill_tokens += len(ids)
-            if self.cfg.recurrent_state is not None:
-                self.metrics.ssm_slot_writes += 1
             if self.flight.enabled:
                 self.flight.record_event(
                     EV_PREFILL_DISPATCH, time.perf_counter(),
@@ -3820,19 +3603,17 @@ class LLMEngine:
         # the plain and the fused decode programs choose their form so;
         # a speculative engine's programs keep the staged one
         if not (plan.spec_k or plan.spec_state) \
-                and self.cfg.latent_row is None \
-                and self.cfg.recurrent_state is None \
-                and self.cfg.index_row is None \
+                and self.served.direct_qkv \
                 and engine_model.direct_qkv(self.cfg, K):
             self.metrics.decode_steps_direct_qkv += K
         if self._load_rows:  # every live slot's token, in every expert block
             self.metrics.moe_pairs_routed += (
                 len(active) * K * self.cfg.n_moe_layers
                 * self.cfg.n_experts_per_tok)
-        if self.cfg.recurrent_state is not None \
-                and kernel_update(self.pool.state, self.use_pallas):
-            self.metrics.ssm_steps_kernel += K
-        sparse = self._note_sparse_select(lengths, active_mask, K)
+        # the architecture's own counts, and its flight event or None
+        noted = self.served.note_decode(
+            self.metrics, self.cfg, lengths, active_mask, K, self.pool,
+            self.use_pallas, self.max_pages)
         window = self._note_window_cache(lengths, active, win_base, K)
         # every decode program but the verifies writes one row a slot
         if not plan.spec_k and kernel_append(self.pool, self.use_pallas):
@@ -3904,7 +3685,7 @@ class LLMEngine:
             fl.t_dispatch = res.get("t_dispatch") or time.perf_counter()
             fl.plan = plan
             fl.prog = prog
-            fl.sparse = sparse
+            fl.noted = noted
             fl.window = window
             self._await_program(prog, block)
             self._inflight.append(fl)
@@ -3996,13 +3777,10 @@ class LLMEngine:
     # host scalars — cross the wire (the GL703 invariant).
 
     def _state_slots(self, idxs):
-        """A prefill's `state_slots`: the decode slots its rows were
-        admitted to, for a model whose recurrent state a prefill writes
-        (a padding row's index is past the last slot and dropped); None
-        for every other model, whose programs then lower as they did."""
-        if self.cfg.recurrent_state is None:
-            return None
-        return self._put(idxs)
+        """A prefill's `state_slots`, for an architecture whose prefill
+        writes per-slot state: the decode slots its rows were admitted to
+        (a padding row's is past the last slot and dropped); else None."""
+        return self._put(idxs) if self.served.state_slots else None
 
     def _exec_prefill(self, rec: Dict[str, Any]):
         """Execute one `prefill` record: the batched prefill forward +

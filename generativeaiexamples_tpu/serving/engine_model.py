@@ -1,5 +1,11 @@
-"""Paged llama forward: the jitted prefill/decode steps of the engine.
+"""The jitted prefill/decode steps of the engine, and the Llama walk.
 
+`prefill_step`, `prefill_batch_step`, `decode_step` and `decode_multi_step`
+run every architecture: each takes the block from the configuration's
+entry (`served(cfg)`, serving/served_models.py) and samples, chains
+tokens and stacks the expert-load rows itself. Every other program runs
+the Llama walk alone (LLMEngine refuses those lanes by name for another
+entry), whose bodies live here (`llama_prefill`, `_decode_once`):
 models.llama's transformer block (its two halves, project_qkv and
 finish_block, around the attention each step supplies; tests assert
 paged forward == contiguous forward) over the serving PagePool, whose
@@ -18,7 +24,6 @@ Both are shape-stable: prefill compiles once per bucket, decode once per
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import os
 import time
@@ -28,23 +33,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from generativeaiexamples_tpu.models import (
-    hybrid_ssm, latent_moe, sparse_attn_moe, window_attn_moe)
+from generativeaiexamples_tpu.models import llama
 from generativeaiexamples_tpu.models.llama import (
-    LlamaConfig, attn_out, final_norm, finish_block, project_qkv, rms_norm,
+    LlamaConfig, final_norm, finish_block, project_qkv, rms_norm,
     walk_passes)
 from generativeaiexamples_tpu.ops import attention as attn_ops
 from generativeaiexamples_tpu.ops.quant import mm
-from generativeaiexamples_tpu.serving import ssm_state_update as ssm_update
 from generativeaiexamples_tpu.serving.kv_cache import (
-    PagePool, kernel_append, kernel_live_rows, token_slots)
+    PagePool, kernel_append, kernel_live_rows, kv_pool_zeros, kv_token_bytes,
+    token_slots)
 from generativeaiexamples_tpu.serving.paged_attention import (
     paged_attention_dispatch)
-from generativeaiexamples_tpu.serving.paged_attention_sparse import (
-    paged_attention_sparse)
-from generativeaiexamples_tpu.serving.sparse_index_scores import (
-    sparse_index_scores)
-from generativeaiexamples_tpu.serving.sparse_select import sparse_select
+from generativeaiexamples_tpu.serving import served_models
+from generativeaiexamples_tpu.serving.served_models import served
 from generativeaiexamples_tpu.utils.platform import log_kernel_declined
 
 
@@ -138,391 +139,6 @@ def _logits(cfg: LlamaConfig, params, x):
         return mm(x, params["lm_head"]).astype(jnp.float32)
 
 
-# -- latent attention and sparse experts (models/latent_moe.py) ------------
-#
-# A model whose cache row is a latent (cfg.latent_row) runs the SAME four
-# programs (prefill_step, prefill_batch_step, decode_step,
-# decode_multi_step), scheduler and page allocator; what differs is the
-# block, so each program takes the two functions below where a Llama
-# takes its own. The speculative, fused and chunked programs have no
-# latent form: LLMEngine refuses those lanes by name at construction.
-
-
-def _latent_prefill(params, cfg, pool, tokens, lengths, table_rows,
-                    use_pallas):
-    """Prompts [N, S] in their un-absorbed form (keys and values built
-    from the prompt's own latent rows); the rows of every layer go to
-    the slots' pages in one write. -> (last-position logits [N, V], pool)."""
-    N, S = tokens.shape
-    ps = pool.page_size
-    x, rows, _ = latent_moe.walk_prompt(params, cfg, tokens, lengths,
-                                        use_pallas)
-    pages = pool.encode_pages(rows)  # [R, N, S, W]
-    pages = pages.reshape(pages.shape[0], N * (S // ps), ps, -1)
-    pool = pool.write_pages(pages, table_rows.reshape(-1))
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
-    return latent_moe.logits_of(cfg, params, last)[:, 0], pool
-
-
-def _latent_decode_once(params, cfg, pool, tokens, page_tables, lengths,
-                        use_pallas, mask=None):
-    """_decode_once for a latent model, write-then-attend: every block
-    appends the new token's [c_kv ; k_rope] and attends in the absorbed
-    form through the paged kernel (serving/paged_attention_mla.py); the
-    blocks unrolled, each weight an operand of its matmul and the held
-    experts' stacks read where they lie. `mask` [B]: the slots whose
-    token-expert pairs count and are computed (idle slots: none).
-    Returns (logits [B, V], pool, pairs each held expert took in each
-    expert block [Lm, E], the router's choices [Lm, B, k])."""
-    from generativeaiexamples_tpu.serving.paged_attention_mla import (
-        paged_attention_mla_dispatch)
-
-    B = tokens.shape[0]
-    ps = pool.page_size
-    C, _ = cfg.latent_row
-    positions = (lengths - 1)[:, None]
-    slots = token_slots(1, page_tables[jnp.arange(B), (lengths - 1) // ps],
-                        (lengths - 1) % ps)
-    x = params["tok_emb"][tokens][:, None].astype(cfg.residual_dtype)
-
-    def block(x, pool, w, row, experts=None, layer=None):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        q_nope, q_rope, new = latent_moe.project_latent(cfg, h, w, positions)
-        pool = pool.append(row, slots, new[:, 0])
-
-        def attend(q):
-            c, r = pool.attention_operands(row)
-            q = jnp.pad(q, ((0, 0), (0, 0), (0, c.shape[-1] - q.shape[-1])))
-            return paged_attention_mla_dispatch(
-                q, c, r, page_tables, lengths, latent=C,
-                scale=cfg.softmax_scale, use_pallas=use_pallas)
-
-        out = latent_moe.attend_cached(cfg, q_nope[:, 0], q_rope[:, 0], w,
-                                       attend)
-        x = attn_out(cfg, x, out[:, :, None, :], w)
-        x, counts, idx = latent_moe.feed_forward(cfg, x, w, experts, layer,
-                                                 use_pallas, mask)
-        return x, pool, counts, idx
-
-    for l in range(cfg.n_dense_layers):
-        x, pool, _, _ = block(x, pool, latent_moe.take_layer(
-            params["dense"], l), l)
-    counts, choices = [], []
-    _, experts = latent_moe.split_experts(params["layers"])
-    for l in range(cfg.n_moe_layers):
-        w = latent_moe.take_layer(params["layers"], l,
-                                  skip=latent_moe.EXPERT_WEIGHTS)
-        x, pool, n, idx = block(x, pool, w, cfg.n_dense_layers + l, experts, l)
-        counts.append(n)
-        choices.append(idx[:, 0])
-    logits = latent_moe.logits_of(cfg, params, x)[:, 0]
-    return logits, pool, jnp.stack(counts), jnp.stack(choices)
-
-
-# -- state-space layers beside attention (models/hybrid_ssm.py) ------------
-#
-# A model with recurrent state (cfg.recurrent_state) runs the same four
-# programs over a kv_cache.HybridPool: its attention layers' K and V go
-# to the pool's pages as a Llama's do, and every state-space layer's
-# state and convolution tail live in the pool's per-SLOT rows, which a
-# prefill writes whole (so it takes the slots, beside the page tables)
-# and a decode step updates in place.
-
-
-def _hybrid_prefill(params, cfg, pool, tokens, lengths, table_rows,
-                    state_slots, use_pallas):
-    """Prompts [N, S] through every block's prompt form; the attention
-    layers' K and V go to the rows' pages and each state-space layer's
-    state after the row's LAST REAL token (the padding does not advance
-    it) to decode slots `state_slots` [N] (None: row i's to slot i),
-    whole. -> (last-position logits [N, V], pool)."""
-    N, S = tokens.shape
-    if state_slots is None:  # row i of the group is decode slot i
-        state_slots = jnp.arange(N, dtype=jnp.int32)
-    ps = pool.page_size
-    x, kv, states, tails, _ = hybrid_ssm.walk_prompt(params, cfg, tokens,
-                                                     lengths, use_pallas)
-
-    def paged(t):  # [La, N, KH, S, Hd] -> [La, KH, N * npages, ps, Hd]
-        La, _, KH, _, Hd = t.shape
-        t = t.reshape(La, N, KH, S // ps, ps, Hd).transpose(0, 2, 1, 3, 4, 5)
-        return t.reshape(La, KH, N * (S // ps), ps, Hd)
-
-    pages = pool.pages.write_pages(
-        pool.pages.encode_pages(paged(kv[0]), paged(kv[1])),
-        table_rows.reshape(-1))
-    pool = dataclasses.replace(pool, pages=pages).write_slots(
-        state_slots.reshape(-1), states, tails)
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
-    return hybrid_ssm.logits_of(cfg, params, last)[:, 0], pool
-
-
-def _hybrid_decode_once(params, cfg, pool, tokens, page_tables, lengths,
-                        use_pallas, mask=None):
-    """_decode_once for a model with recurrent state, the blocks
-    unrolled: a state-space block reads and rewrites its slots' rows of
-    the pool (the convolution's tail here, the state in place through
-    serving/ssm_state_update.py), an attention block appends K and V and
-    attends through the paged kernel. `mask` [B]: the live slots; an idle
-    slot's state, tail and expert pairs are left alone, and where the
-    int8 pool's kernels are on they walk the live slots only. Returns
-    (logits [B, V], pool, pairs each expert took in each block [L, E],
-    the router's choices [L, B, k])."""
-    B = tokens.shape[0]
-    ps = pool.page_size
-    pages, state, tail = pool.pages, pool.state, pool.tail
-    slots = token_slots(
-        cfg.n_kv_heads, page_tables[jnp.arange(B), (lengths - 1) // ps],
-        (lengths - 1) % ps, use_pallas,
-        live=kernel_live_rows(pages, mask, use_pallas))
-    x = hybrid_ssm.embed(cfg, params, tokens)[:, None]  # [B, 1, D]
-    sliced, experts = hybrid_ssm.split_experts(params["ffn"])
-    counts, choices = [], []
-    for l, (kind, i) in enumerate(hybrid_ssm.layer_plan(cfg)):
-        if kind == hybrid_ssm.MAMBA:
-            w = hybrid_ssm.take_layer(params["ssm"], i)
-            h = rms_norm(x[:, 0], w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-            z, xbc, dt = hybrid_ssm.ssm_project(cfg, h, w)
-            xbc, window = hybrid_ssm.conv_step(cfg, xbc, tail[i], w)
-            if mask is not None:
-                window = jnp.where(mask[None, :, None], window, tail[i])
-            tail = tail.at[i].set(window)
-            xs, Bv, Cv = hybrid_ssm.split_xbc(cfg, xbc)
-            step, log_a = hybrid_ssm.step_and_decay(w, dt)
-            with jax.named_scope("ssm.update"):
-                state, y = ssm_update.ssm_state_update(
-                    state, i, mask, step, log_a, xs, Bv, Cv, use_pallas)
-                y = y + w["D"][:, None] * xs.astype(jnp.float32)
-            x = hybrid_ssm.branch(
-                cfg, x, hybrid_ssm.gate_and_project(cfg, y, z, w)[:, None])
-        else:
-            w = hybrid_ssm.take_layer(params["attn"], i)
-            h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-            q, k, v = hybrid_ssm.project_qkv(cfg, h, w)
-            pages = pages.append(i, slots, k[:, :, 0].transpose(1, 0, 2),
-                                 v[:, :, 0].transpose(1, 0, 2))
-            k_pages, v_pages, k_scales, layer = pages.attention_operands(i)
-            out = paged_attention_dispatch(
-                q[:, :, 0], k_pages, v_pages, page_tables, lengths,
-                scale=cfg.attention_multiplier, k_scales=k_scales,
-                layer=layer, use_pallas=use_pallas, live=slots.live)
-            x = hybrid_ssm.attn_out(cfg, x, out[:, :, None, :], w)
-        x, n, idx = hybrid_ssm.feed_forward(
-            cfg, x, hybrid_ssm.take_layer(sliced, l), experts, l, use_pallas,
-            mask)
-        counts.append(n)
-        choices.append(idx[:, 0])
-    logits = hybrid_ssm.logits_of(cfg, params, x)[:, 0]
-    pool = dataclasses.replace(pool, pages=pages, state=state, tail=tail)
-    return logits, pool, jnp.stack(counts), jnp.stack(choices)
-
-
-# -- learned sparse attention (models/sparse_attn_moe.py) ------------------
-#
-# A model whose tokens cache an index key beside K and V (cfg.index_row)
-# runs the same four programs over a kv_cache.SparseIndexPool. A prompt
-# is walked in tiles (sparse_attn_moe.sparse_attend_prompt) and its K, V
-# and index keys go to its pages in one write; a decode step, in every
-# layer, appends the three, scores ALL of the slot's cached index keys
-# (serving/sparse_index_scores.py), selects, and attends over what was
-# selected (serving/paged_attention_sparse.py).
-
-
-def _sparse_prefill(params, cfg, pool, tokens, lengths, table_rows,
-                    use_pallas):
-    """Prompts [N, S]: every layer's K, V and index keys go to the rows'
-    pages (a padded row's to the sink). -> (last-position logits [N, V],
-    pool)."""
-    N, S = tokens.shape
-    ps = pool.page_size
-    x, (k, v, ki), _ = sparse_attn_moe.walk_prompt(params, cfg, tokens,
-                                                   lengths, use_pallas)
-
-    def paged(t):  # [L, N, KH, S, Hd] -> [L, KH, N * npages, ps, Hd]
-        L, _, KH, _, Hd = t.shape
-        t = t.reshape(L, N, KH, S // ps, ps, Hd).transpose(0, 2, 1, 3, 4, 5)
-        return t.reshape(L, KH, N * (S // ps), ps, Hd)
-
-    ki = ki.reshape(ki.shape[0], N * (S // ps), ps, -1)
-    pool = pool.write_pages(pool.encode_pages(paged(k), paged(v), ki),
-                            table_rows.reshape(-1))
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
-    return sparse_attn_moe.logits_of(cfg, params, last)[:, 0], pool
-
-
-def _sparse_decode_once(params, cfg, pool, tokens, page_tables, lengths,
-                        use_pallas, mask=None):
-    """_decode_once for a model with learned sparse attention, the blocks
-    unrolled: append K, V and the index key, score the slot's cached index
-    keys, select, attend over the selected tokens. `mask` [B]: the live
-    slots; where the kernels are on they walk those alone, so an idle slot
-    costs no score and no read and its expert pairs are left out. Returns
-    (logits [B, V], pool, pairs each expert took in each block [L, E], the
-    router's choices [L, B, k])."""
-    B = tokens.shape[0]
-    ps = pool.page_size
-    positions = (lengths - 1)[:, None]
-    slots = token_slots(
-        cfg.n_kv_heads, page_tables[jnp.arange(B), (lengths - 1) // ps],
-        (lengths - 1) % ps, use_pallas,
-        live=kernel_live_rows(pool, mask, use_pallas))
-    x = sparse_attn_moe.embed(cfg, params, tokens)[:, None]  # [B, 1, D]
-    sliced, experts = sparse_attn_moe.split_experts(params["layers"])
-    counts, choices = [], []
-    for l in range(cfg.n_layers):
-        w = sparse_attn_moe.take_layer(sliced, l)
-        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        q, k, v = sparse_attn_moe.project_qkv(cfg, h, w, positions)
-        qi, ki, wt = sparse_attn_moe.project_index(cfg, h, w, positions)
-        pool = pool.append(l, slots, k[:, :, 0].transpose(1, 0, 2),
-                           v[:, :, 0].transpose(1, 0, 2), ki[:, 0])
-        with jax.named_scope("index.scores"):
-            scores = sparse_index_scores(
-                qi[:, 0], wt[:, 0], pool.idx, l, page_tables, lengths,
-                use_pallas=use_pallas, live=slots.live)
-        with jax.named_scope("index.select"):
-            selected = sparse_select(scores, lengths, cfg.index_topk, ps,
-                                     use_pallas=use_pallas, live=slots.live)
-        with jax.named_scope("attn.sparse"):
-            kv, _, kv_scales, layer = pool.attention_operands(l)
-            out = paged_attention_sparse(
-                q[:, :, 0], kv, kv_scales, page_tables, lengths, selected,
-                layer, use_pallas=use_pallas, live=slots.live)
-        x = sparse_attn_moe.attn_out(cfg, x, out[:, :, None, :], w)
-        x, n, idx = sparse_attn_moe.feed_forward(cfg, x, w, experts, l,
-                                                 use_pallas, mask)
-        counts.append(n)
-        choices.append(idx[:, 0])
-    logits = sparse_attn_moe.logits_of(cfg, params, x)[:, 0]
-    return logits, pool, jnp.stack(counts), jnp.stack(choices)
-
-
-# -- window and full attention in one model (models/window_attn_moe.py) ----
-#
-# A model with window layers beside global ones (cfg.window_rows) runs the
-# same four programs over a kv_cache.WindowPool, and takes a
-# kv_cache.WindowTables where every other model takes one page table: the
-# global layers' rows are written and read through `glob` as a Llama's
-# are; the window layers' rows through `win`, which holds only the pages
-# that reach into the window.
-
-
-def _window_prefill(params, cfg, pool, tokens, lengths, tables, use_pallas):
-    """Prompts [N, S]: every layer's K and V go to its group's pages, a
-    window layer's through `tables.win`, whose entries behind the window
-    point at the sink (as a padded row's do). -> (last-position logits
-    [N, V], pool)."""
-    N, S = tokens.shape
-    ps = pool.page_size
-    x, kv, _ = window_attn_moe.walk_prompt(
-        params, cfg, tokens, lengths, use_pallas,
-        encode=pool.glob.encode_pages)  # [L, N, KH, S, ...] x 4
-
-    def paged(t):  # [R, N, KH, S, ...] -> [R, KH, N * npages, ps, ...]
-        R, _, KH = t.shape[:3]
-        rest = t.shape[4:]
-        t = t.reshape(R, N, KH, S // ps, ps, *rest)
-        order = (0, 2, 1, 3, 4) + tuple(5 + i for i in range(len(rest)))
-        return t.transpose(*order).reshape(R, KH, N * (S // ps), ps, *rest)
-
-    def write(rows_pool, kind, table):
-        layers = np.asarray([l for l, (k, _) in enumerate(
-            window_attn_moe.layer_plan(cfg)) if k == kind])
-        return rows_pool.write_pages(tuple(paged(t[layers]) for t in kv),
-                                     table.reshape(-1))
-
-    pool = dataclasses.replace(
-        pool, glob=write(pool.glob, window_attn_moe.GLOBAL, tables.glob),
-        win=write(pool.win, window_attn_moe.WINDOW, tables.win))
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)  # [N,1,D]
-    return window_attn_moe.logits_of(cfg, params, last)[:, 0], pool
-
-
-def _window_decode_once(params, cfg, pool, tokens, tables, lengths,
-                        use_pallas, mask=None):
-    """_decode_once for a model with window layers, the blocks unrolled in
-    published order: a global layer appends and attends through
-    `tables.glob` as a Llama's does; a window layer through `tables.win`,
-    with the slot's length and its window's first token counted from the
-    table's first page (`tables.base`): the kernel walks the pages the
-    table holds and masks, inside the first, the tokens that slid out.
-    The router reads the ATTENTION's input, so a layer's experts and gates
-    depend on nothing its attention computes. `mask` [B]: the live slots;
-    where the kernels are on they walk those alone. Returns (logits
-    [B, V], pool, pairs each expert took in each block [L, E], the
-    router's choices [L, B, k])."""
-    B = tokens.shape[0]
-    ps = pool.page_size
-    rows = jnp.arange(B)
-    positions = (lengths - 1)[:, None]
-    live = kernel_live_rows(pool, mask, use_pallas)
-    rel = lengths - tables.base  # counted from the window table's first page
-    starts = jnp.maximum(lengths - cfg.window, 0) - tables.base
-    slots = {
-        window_attn_moe.GLOBAL: token_slots(
-            cfg.n_kv_heads, tables.glob[rows, (lengths - 1) // ps],
-            (lengths - 1) % ps, use_pallas, live=live),
-        window_attn_moe.WINDOW: token_slots(
-            cfg.n_kv_heads, tables.win[rows, (rel - 1) // ps],
-            (rel - 1) % ps, use_pallas, live=live)}
-    groups = {window_attn_moe.GLOBAL: pool.glob,
-              window_attn_moe.WINDOW: pool.win}
-    x = window_attn_moe.embed(cfg, params, tokens)[:, None]  # [B, 1, D]
-    sliced, experts = window_attn_moe.split_experts(params["layers"])
-    counts, choices = [], []
-    for l, (kind, row) in enumerate(window_attn_moe.layer_plan(cfg)):
-        w = window_attn_moe.take_layer(sliced, l)
-        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
-        idx, gates = window_attn_moe.route(cfg, h[:, 0], w["router"])
-        q, k, v = window_attn_moe.project_qkv(cfg, h, w, positions,
-                                              cfg.rope_layout[l])
-        pages = groups[kind].append(row, slots[kind],
-                                    k[:, :, 0].transpose(1, 0, 2),
-                                    v[:, :, 0].transpose(1, 0, 2))
-        groups[kind] = pages
-        kv, _, kv_scales, layer = pages.attention_operands(row)
-        if kind == window_attn_moe.WINDOW:
-            with jax.named_scope("attn.window"):
-                out = paged_attention_dispatch(
-                    q[:, :, 0], kv, None, tables.win, rel,
-                    k_scales=kv_scales, layer=layer, use_pallas=use_pallas,
-                    live=live, starts=starts)
-        else:
-            with jax.named_scope("attn.global"):
-                out = paged_attention_dispatch(
-                    q[:, :, 0], kv, None, tables.glob, lengths,
-                    k_scales=kv_scales, layer=layer, use_pallas=use_pallas,
-                    live=live)
-        x = window_attn_moe.attn_out(cfg, x, out[:, :, None, :], w)
-        x, n = window_attn_moe.feed_forward(cfg, x, w, experts, l, idx,
-                                            gates, use_pallas, mask)
-        counts.append(n)
-        choices.append(idx)
-    logits = window_attn_moe.logits_of(cfg, params, x)[:, 0]
-    pool = dataclasses.replace(pool, glob=groups[window_attn_moe.GLOBAL],
-                               win=groups[window_attn_moe.WINDOW])
-    return logits, pool, jnp.stack(counts), jnp.stack(choices)
-
-
-def _expert_decode_once(cfg):
-    """The decode body of a model the Llama walk does not run, or None:
-    `(params, cfg, pool, tokens, page_tables, lengths, use_pallas, mask)
-    -> (logits, pool, expert pair counts, choices)`."""
-    if cfg.latent_row is not None:
-        return _latent_decode_once
-    if cfg.recurrent_state is not None:
-        return _hybrid_decode_once
-    if cfg.index_row is not None:
-        return _sparse_decode_once
-    if cfg.window_rows is not None:
-        return _window_decode_once
-    return None
-
-
 def expert_load_rows(cfg) -> int:
     """Rows a decode block carries below its B token rows: one per
     (expert block, held expert), column 1 + i the pairs it took in step
@@ -603,22 +219,11 @@ def prefill_step(
     written once afterwards, all rows of all passes in the one scatter
     — never re-stacked through scan outputs (that would copy the whole
     pool per call)."""
-    if cfg.latent_row is not None:
-        logits, pool = _latent_prefill(params, cfg, pool, tokens,
-                                       length[None], table_row, use_pallas)
-        return logits[0], pool
-    if cfg.recurrent_state is not None:
-        logits, pool = _hybrid_prefill(params, cfg, pool, tokens,
-                                       length[None], table_row, state_slot,
-                                       use_pallas)
-        return logits[0], pool
-    if cfg.index_row is not None:
-        logits, pool = _sparse_prefill(params, cfg, pool, tokens,
-                                       length[None], table_row, use_pallas)
-        return logits[0], pool
-    if cfg.window_rows is not None:
-        logits, pool = _window_prefill(params, cfg, pool, tokens,
-                                       length[None], table_row, use_pallas)
+    entry = served(cfg)
+    if entry.prefill is not llama_prefill:  # the batch form's body, at N = 1
+        logits, pool = entry.prefill(
+            params, cfg, pool, tokens, length[None], table_row, use_pallas,
+            mesh=mesh, state_slots=state_slot)
         return logits[0], pool
     _, S = tokens.shape
     ps = pool.page_size
@@ -685,26 +290,20 @@ def prefill_batch_step(
 
     all_greedy, any_top_k, any_top_p = sampling_flags
     sp = SamplingParams(temperature, top_p, top_k)
-    if cfg.latent_row is not None:
-        logits, pool = _latent_prefill(params, cfg, pool, tokens, lengths,
-                                       table_rows, use_pallas)
-        return sample(logits, sp, key, all_greedy=all_greedy,
-                      any_top_k=any_top_k, any_top_p=any_top_p), pool
-    if cfg.recurrent_state is not None:
-        logits, pool = _hybrid_prefill(params, cfg, pool, tokens, lengths,
-                                       table_rows, state_slots, use_pallas)
-        return sample(logits, sp, key, all_greedy=all_greedy,
-                      any_top_k=any_top_k, any_top_p=any_top_p), pool
-    if cfg.index_row is not None:
-        logits, pool = _sparse_prefill(params, cfg, pool, tokens, lengths,
-                                       table_rows, use_pallas)
-        return sample(logits, sp, key, all_greedy=all_greedy,
-                      any_top_k=any_top_k, any_top_p=any_top_p), pool
-    if cfg.window_rows is not None:
-        logits, pool = _window_prefill(params, cfg, pool, tokens, lengths,
-                                       table_rows, use_pallas)
-        return sample(logits, sp, key, all_greedy=all_greedy,
-                      any_top_k=any_top_k, any_top_p=any_top_p), pool
+    logits, pool = served(cfg).prefill(
+        params, cfg, pool, tokens, lengths, table_rows, use_pallas,
+        mesh=mesh, state_slots=state_slots)  # [N, V]
+    toks = sample(logits, sp, key, all_greedy=all_greedy,
+                  any_top_k=any_top_k, any_top_p=any_top_p)
+    return _replicate_tokens(mesh, toks), pool
+
+
+def llama_prefill(params, cfg: LlamaConfig, pool, tokens, lengths, table_rows,
+                  use_pallas, *, mesh=None, state_slots=None):
+    """The Llama entry's prompt body: prompts
+    [N, S] block by block, the pages written once, a lone prompt's live
+    rows only (prefill_row_counts). -> (last-position logits [N, V],
+    pool)."""
     N, S = tokens.shape
     ps = pool.page_size
     KH, Hd = cfg.n_kv_heads, cfg.head_dim
@@ -759,10 +358,7 @@ def prefill_batch_step(
             prefill_live_index(lengths, S, ps),
             [functools.partial(first_rows, S_k) for S_k in counts],
             pool, tokens, table_rows)
-    logits = _logits(cfg, params, last)[:, 0]  # [N, V]
-    toks = sample(logits, sp, key, all_greedy=all_greedy,
-                  any_top_k=any_top_k, any_top_p=any_top_p)
-    return _replicate_tokens(mesh, toks), pool
+    return _logits(cfg, params, last)[:, 0], pool
 
 
 @functools.partial(jax.jit, donate_argnames=("last_tokens",))
@@ -827,7 +423,7 @@ def fuses_append(cfg: LlamaConfig, pool, use_pallas) -> bool:
     6, PR 46). From the step program's static arguments and the pool and
     nothing else; the engine counts `decode_steps_fused_append` by this
     function."""
-    return (cfg.n_passes > 1 and _expert_decode_once(cfg) is None
+    return (cfg.n_passes > 1 and served(cfg).direct_qkv
             and kernel_append(pool, use_pallas))
 
 
@@ -921,12 +517,8 @@ def decode_step(
     mesh=None,
 ) -> Tuple[jax.Array, PagePool]:
     """One decode step for the whole slot batch -> (logits [B, V], pool)."""
-    once = _expert_decode_once(cfg)
-    if once is not None:
-        return once(params, cfg, pool, tokens, page_tables, lengths,
-                    use_pallas)[:2]
-    return _decode_once(params, cfg, pool, tokens, page_tables, lengths,
-                        use_pallas, mesh, direct_qkv(cfg, 1))
+    return served(cfg).decode_once(params, cfg, pool, tokens, page_tables,
+                                   lengths, use_pallas, mesh=mesh)[:2]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "n_steps", "use_pallas",
@@ -963,19 +555,14 @@ def decode_multi_step(
     all_greedy, any_top_k, any_top_p = sampling_flags
     tokens = last_tokens
     out_tokens = [tokens]
-    once = _expert_decode_once(cfg)
-    direct = once is None and direct_qkv(cfg, n_steps)
+    once = served(cfg).decode_once
     loads = []  # a model with experts: the pairs each took, step by step
     for i in range(n_steps):
-        if once is not None:
-            logits, pool, load, _ = once(
-                params, cfg, pool, tokens, page_tables, lengths, use_pallas,
-                mask=active)
+        logits, pool, load, _ = once(
+            params, cfg, pool, tokens, page_tables, lengths, use_pallas,
+            active, mesh=mesh, n_steps=n_steps)
+        if load is not None:
             loads.append(load.reshape(-1))
-        else:
-            logits, pool = _decode_once(
-                params, cfg, pool, tokens, page_tables, lengths, use_pallas,
-                mesh, direct, active)
         rng, key = jax.random.split(rng)
         nxt = sample(logits, sp, key, all_greedy=all_greedy,
                      any_top_k=any_top_k, any_top_p=any_top_p)
@@ -1932,3 +1519,37 @@ def _plan_step(params, cfg: LlamaConfig, plan: StepPlan, *,
         temperature, top_p, top_k, rng, plan.decode_k, use_pallas,
         sampling_flags=sampling_flags, mesh=mesh)
     return {"block": block, "last_tokens": last_tokens, "pool": pool}
+
+
+# -- the Llama entry (serving/served_models.py) ---------------------------
+# A looped decoder is the same config class with `n_passes` > 1: the lanes
+# of engine._ONE_PASS_LANES index the page pool by layer and have no test
+# against a looped model's reference (benchmark/architectures/ouro.py).
+
+
+def _llama_decode_once(params, cfg, pool, tokens, page_tables, lengths,
+                       use_pallas, mask=None, *, mesh=None, n_steps=1):
+    """_decode_once as an entry's decode body: the q, k and v projections
+    in the form a program of `n_steps` steps takes; no experts to count."""
+    logits, pool = _decode_once(params, cfg, pool, tokens, page_tables,
+                                lengths, use_pallas, mesh,
+                                direct_qkv(cfg, n_steps), mask)
+    return logits, pool, None, None
+
+
+served_models.register(LlamaConfig, served_models.ServedModel(
+    name="llama", prefill=llama_prefill, decode_once=_llama_decode_once,
+    zeros=lambda cfg, n_pages, page_size, dtype, sharding, scale_sharding,
+    slots: kv_pool_zeros(cfg, n_pages, page_size, dtype, sharding,
+                         scale_sharding),
+    init_params=lambda cfg, quantize: llama.init_params(
+        cfg, jax.random.PRNGKey(0)),
+    param_specs=llama.param_specs,
+    token_bytes=lambda cfg, ecfg, axis_sizes: {
+        "K and V": kv_token_bytes(cfg, cfg.cache_rows, ecfg.kv_dtype,
+                                  int(axis_sizes.get("tensor", 1)))},
+    caches=lambda cfg: None if cfg.n_passes == 1 else (
+        f"model runs its {cfg.n_layers} blocks n_passes={cfg.n_passes} "
+        f"times a token ({cfg.cache_rows} cache rows)"),
+    why_not="those lanes are untested against a looped model",
+    long_prompts=True, live_prefill_rows=True, direct_qkv=True))

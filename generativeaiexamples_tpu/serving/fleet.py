@@ -73,50 +73,16 @@ from generativeaiexamples_tpu.serving.router import PrefixLocalityRouter
 
 _LOG = logging.getLogger(__name__)
 
-_COUNTER_KEYS = (
-    "tokens_generated", "decode_steps", "layer_passes",
-    "decode_steps_direct_qkv", "decode_steps_kernel_append",
-    "decode_steps_fused_append",
-    "decode_attn_pages_live", "decode_attn_pages_walked",
-    "decode_attn_updates",
-    "decode_attn_rows_skipped",
-    "prefill_rows_live", "prefill_rows_bucket",
-    "moe_pairs_routed", "moe_pairs_local",
-    "ssm_slot_writes", "ssm_steps_kernel",
-    "sparse_keys_scored", "sparse_rows_attended", "sparse_steps_dense",
-    "window_pages_released", "decode_attn_window_pages_walked",
-    "sparse_attn_pages_walked", "sparse_attn_blocks_walked",
-    "prefill_tokens", "fused_steps",
-    "fused_prefill_tokens", "prefill_stall_beats",
-    "fused_sample_dispatches", "prefix_hits",
-    "prefix_miss", "prefix_evictions", "prefix_hit_tokens",
-    "plan_variants_compiled", "spec_fallback_steps",
-    "admission_failures", "qos_preemptions",
-    # Disagg KV transfer counters (serving/disagg.py): pages a decode
-    # replica imported from a prefill-role replica, and the wall ms
-    # those imports cost — summed fleet-wide, zeros when disagg is off.
-    # device_pages arrived as jax.Arrays over the ICI fast path
-    # (zero host serialization); chunks counts import control ops
-    # (each window of a chunked/pipelined transfer is one).
-    "kv_transfer_pages", "kv_transfer_ms",
-    "kv_transfer_device_pages", "kv_transfer_chunks",
-    # KV-pager counters and tier gauges (serving/kv_pager.py) sum
-    # across replicas: fleet-wide parked-session pages per tier.
-    "kv_demotions", "kv_promotions", "kv_promote_tokens",
-    "kv_host_pages", "kv_spill_pages", "kv_host_bytes", "kv_spill_bytes",
-    "kv_spill_writes", "kv_spill_compactions", "kv_forced_drops",
-    "kv_pager_errors",
-    # Flight-recorder counters (serving/flight.py) sum across
-    # replicas; the per-lane rings themselves are served by
-    # /debug/timeline (one Perfetto lane per local replica).
-    "flight_beats", "flight_events",
-    # Programs whose device time passed flight.STALL_FACTOR times the
-    # running median of their class and shape (one WARNING line each).
-    "program_stalls",
-    # stop()-path joins that timed out (engine.py stop); the fleet
-    # adds its own control-thread stuck joins on top of this sum.
-    "stuck_thread_joins",
-)
+def counter_keys():
+    """The keys of an engine's /metrics that sum across replicas: its own
+    (EngineMetrics.SUMMED), the pager's (counters and tier gauges: parked
+    pages fleet-wide) and every served architecture's `counters`."""
+    from generativeaiexamples_tpu.serving.engine import EngineMetrics
+    from generativeaiexamples_tpu.serving.kv_pager import KV_PAGER_KEYS
+    from generativeaiexamples_tpu.serving.served_models import metric_keys
+
+    return EngineMetrics.SUMMED + KV_PAGER_KEYS + metric_keys()[0]
+
 
 # Fleet control-plane counters (FleetOps below): always present in
 # /metrics — 0, never absent — whether served by a fleet or a single
@@ -805,12 +771,13 @@ class FleetMetrics:
         else:
             snaps = [r.metrics_snapshot() for r in reps]
         per_replica = {r.rid: s for r, s in zip(reps, snaps)}
-        out: Dict[str, Any] = {k: 0 for k in _COUNTER_KEYS}
+        summed = counter_keys()
+        out: Dict[str, Any] = {k: 0 for k in summed}
         occ_num = occ_den = 0.0
         tps = 0.0
         spec_num = spec_den = 0.0
         for snap in per_replica.values():
-            for k in _COUNTER_KEYS:
+            for k in summed:
                 out[k] += snap.get(k) or 0
             steps = snap.get("decode_steps") or 0
             occ_num += (snap.get("mean_batch_occupancy") or 0.0) * steps

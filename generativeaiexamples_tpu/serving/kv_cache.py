@@ -3,42 +3,25 @@
 The TPU-native replacement for what TRT-LLM's paged KV manager does
 inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
 
-- Device: one page pool per model, built from what the model says a row
-  caches. A latent-attention model (cfg.latent_row) caches one vector
-  per token, shared by all heads: LatentPagePool, [R, P, page_size, W].
-  Every other model caches K and V per head: k/v arrays
-  [R, KH, P, page_size, Hd],
-  R = cfg.cache_rows: one row per layer, times the passes of a looped
-  model (a row per (pass, block); written "L" below where a model has
-  one pass) (kv-heads outermost after the row axis: per-layer slices are the
-  [KH, P, ps, Hd] layout the JetStream-style multi-page Pallas kernel
-  wants, and the TP sharding axis is a leading dim). Page 0 is a
-  reserved garbage sink — padding positions in bucketed prefills and
-  unused page-table slots point at it, so scatter/gather never needs
-  dynamic shapes. Nothing reads it: XLA's scatters leave an idle decode
-  slot's row there, and the int8 kernels, which walk the live slots
-  alone, neither write nor read it in a decode step.
-- A model with recurrent state (cfg.recurrent_state: state-space layers
-  beside its attention layers) gets a HybridPool: the page pool of its
-  attention layers' rows (`pages`, any of the pools above) and, beside
-  it, a pool of per-SLOT state that is neither paged nor per token: for
-  every state-space layer and decode slot a float32 state and the last
-  inputs of the layer's convolution. A prefill writes a slot's state
-  whole; a decode step updates it in place
-  (serving/ssm_state_update.py).
-- A model with learned sparse attention (cfg.index_row: an indexer whose
-  key every token caches beside its K and V) gets a SparseIndexPool: the
-  int8 pool of its K and V (`pages`) and a third kind of row under the
-  SAME page table, the index keys, written by the same append and the
-  same page write and freed with the same pages
-  (serving/sparse_index_scores.py reads them).
-- A model with window layers beside global ones (cfg.window_rows) gets a
-  WindowPool: TWO int8 pools, each with its own PageAllocator and its own
-  page table a sequence. The global layers' rows grow with the sequence
-  as every pool above; the window layers' rows hold only the pages that
-  reach into the window, and a sequence's WindowSequencePages gives the
-  page that slid out back to the window allocator when the decode block
-  that moved the window past it has landed.
+- Device: one page pool per model, built by the model's architecture
+  entry (serving/served_models.py: `PagePool.zeros` asks `served(cfg)`
+  and knows no architecture itself). A Llama caches K and V per head:
+  k/v arrays [R, KH, P, page_size, Hd], R = cfg.cache_rows: one row per
+  layer, times the passes of a looped model (a row per (pass, block);
+  written "L" below where a model has one pass) (kv-heads outermost
+  after the row axis: per-layer slices are the [KH, P, ps, Hd] layout
+  the JetStream-style multi-page Pallas kernel wants, and the TP
+  sharding axis is a leading dim). Page 0 is a reserved garbage sink —
+  padding positions in bucketed prefills and unused page-table slots
+  point at it, so scatter/gather never needs dynamic shapes. Nothing
+  reads it: XLA's scatters leave an idle decode slot's row there, and
+  the int8 kernels, which walk the live slots alone, neither write nor
+  read it in a decode step.
+- The other architectures' pool classes live here too, each described
+  where it is defined: LatentPagePool (serving/served_latent.py builds
+  and walks it), HybridPool (served_hybrid.py), SparseIndexPool
+  (served_sparse.py), WindowPool with WindowTables and
+  WindowSequencePages (served_window.py).
 - Host: PageAllocator hands out page ids (plain Python free list — the
   scheduler thread owns it; no device sync needed to allocate).
 - Page tables are [B, max_pages] int32 arrays shipped to the device each
@@ -50,7 +33,7 @@ inside NIM (invisible to the reference repo; SURVEY.md §2.3). Design:
   walk (QuantPagePool.attend_appending, serving/paged_attention_int8.py;
   engine_model.fuses_append decides), the append's
   (serving/kv_append_int8.py) in a one-pass model's blocks and in the
-  drawn blocks, whose call sites append first; and through XLA's
+  other entries' blocks, whose call sites append first; and through XLA's
   scatters everywhere else: off the chip, a bf16 pool, a verify's r rows
   a slot. All three write the same bytes outside the sink page
   (QuantPagePool.append; tests/test_kv_append_kernel.py,
@@ -129,7 +112,7 @@ def kernel_live_rows(pool, active, use_pallas: Optional[bool] = None):
     mask: the XLA forms compute every slot, as they did. Taken once a
     step, outside the layer walk, by the decode bodies that
     `decode_multi_step` hands its `active` (engine_model._decode_once,
-    _hybrid_decode_once)."""
+    served_hybrid.decode_once)."""
     if active is None or not kernel_append(pool, use_pallas):
         return None
     from generativeaiexamples_tpu.serving import paged_attention_int8
@@ -263,55 +246,22 @@ class PagePool:
     @staticmethod
     def zeros(cfg: LlamaConfig, n_pages: int, page_size: int = 64,
               dtype=None, sharding=None, scale_sharding=None, slots=None):
-        """With `sharding`, each buffer is allocated ALREADY sharded
-        (jit with out_shardings) — a TP-serving pool sized to fill the
-        whole mesh must never materialize on one device first.
-        `dtype="int8"` returns the fused QuantPagePool. A model with
-        recurrent state gets a HybridPool with room for `slots` decode
-        slots beside the pages."""
-        dtype = jnp.dtype(dtype or cfg.dtype)
-        if cfg.window_rows is not None:  # the model says what rows it has
-            raise ValueError(
-                "a model with window layers has two pools of pages: "
-                "WindowPool.zeros(cfg, n_pages, n_window_pages, page_size)")
-        if cfg.index_row is not None:  # the model says what a token caches
-            if dtype != jnp.int8:
-                raise ValueError(
-                    f"engine.kv_dtype {dtype.name}: the pool of a model "
-                    "with learned sparse attention "
-                    "(kv_cache.SparseIndexPool) holds K and V in int8 only")
-            return SparseIndexPool.zeros(cfg, n_pages, page_size)
-        if cfg.recurrent_state is not None:  # the model says what it carries
-            if slots is None:
-                raise ValueError("a model with recurrent state keeps it per "
-                                 "decode slot: PagePool.zeros needs `slots`")
-            return HybridPool.zeros(cfg, n_pages, page_size, dtype, slots)
-        if cfg.latent_row is not None:  # the model says what a row caches
-            if dtype == jnp.int8:
-                raise ValueError(
-                    "engine.kv_dtype int8: a latent page pool "
-                    "(kv_cache.LatentPagePool) has no int8 form yet")
-            return LatentPagePool.zeros(cfg, n_pages, page_size, dtype,
-                                        sharding)
-        if dtype == jnp.int8:
-            return QuantPagePool.zeros(cfg, n_pages, page_size,
-                                       sharding=sharding,
-                                       scale_sharding=scale_sharding)
-        shape = (cfg.cache_rows, cfg.n_kv_heads, n_pages, page_size,
-                 cfg.head_dim)
-        k = _alloc(shape, dtype, sharding)
-        v = _alloc(shape, dtype, sharding)
-        return PagePool(k, v, page_size)
+        """The pool of `cfg`'s architecture, as its entry builds it
+        (serving/served_models.py; the entry's errors are this call's).
+        With `sharding`, each buffer is allocated ALREADY sharded (jit
+        with out_shardings): a TP-serving pool sized to fill the whole
+        mesh must never materialize on one device first."""
+        from generativeaiexamples_tpu.serving.served_models import served
+
+        return served(cfg).zeros(cfg, n_pages, page_size,
+                                 jnp.dtype(dtype or cfg.dtype), sharding,
+                                 scale_sharding, slots)
 
     @staticmethod
     def for_budget(cfg: LlamaConfig, hbm_bytes: int, page_size: int = 64,
                    dtype=None):
         dtype = jnp.dtype(dtype or cfg.dtype)
-        itemsize = dtype.itemsize
-        per_tok = cfg.n_kv_heads * cfg.head_dim * itemsize
-        if dtype == jnp.int8:
-            per_tok += cfg.n_kv_heads * 4  # narrow f32 scales
-        per_page = cfg.cache_rows * page_size * per_tok * 2
+        per_page = page_size * kv_token_bytes(cfg, cfg.cache_rows, dtype)
         n_pages = max(2, hbm_bytes // per_page)
         return PagePool.zeros(cfg, int(n_pages), page_size, dtype)
 
@@ -321,6 +271,29 @@ def _alloc(shape, dtype, sharding):
         return jax.jit(lambda: jnp.zeros(shape, dtype),
                        out_shardings=sharding)()
     return jnp.zeros(shape, dtype)
+
+
+def kv_pool_zeros(cfg, n_pages: int, page_size: int, dtype, sharding=None,
+                  scale_sharding=None):
+    """A pool of K and V per head for `cfg.cache_rows` rows: the fused
+    QuantPagePool for int8, a PagePool in every other type."""
+    if dtype == jnp.int8:
+        return QuantPagePool.zeros(cfg, n_pages, page_size,
+                                   sharding=sharding,
+                                   scale_sharding=scale_sharding)
+    shape = (cfg.cache_rows, cfg.n_kv_heads, n_pages, page_size,
+             cfg.head_dim)
+    return PagePool(_alloc(shape, dtype, sharding),
+                    _alloc(shape, dtype, sharding), page_size)
+
+
+def kv_token_bytes(cfg, rows: int, kv_dtype, tensor: int = 1) -> int:
+    """Bytes a device holds of ONE cached token in `rows` rows of such a
+    pool: int8 codes and a float32 scale a (head, token), or the values."""
+    kh = -(-cfg.n_kv_heads // tensor)
+    if jnp.dtype(kv_dtype) == jnp.int8:
+        return rows * kh * (2 * cfg.head_dim + 2 * 4)
+    return rows * kh * 2 * cfg.head_dim * jnp.dtype(kv_dtype).itemsize
 
 
 @dataclasses.dataclass
@@ -686,15 +659,8 @@ class HybridPool:
     def zeros(cfg, n_pages: int, page_size: int, dtype,
               slots: int) -> "HybridPool":
         rs = cfg.recurrent_state
-        if dtype == jnp.int8:
-            pages = QuantPagePool.zeros(cfg, n_pages, page_size)
-        else:
-            shape = (cfg.cache_rows, cfg.n_kv_heads, n_pages, page_size,
-                     cfg.head_dim)
-            pages = PagePool(_alloc(shape, dtype, None),
-                             _alloc(shape, dtype, None), page_size)
         return HybridPool(
-            pages,
+            kv_pool_zeros(cfg, n_pages, page_size, dtype),
             _alloc((rs.layers, slots, rs.heads, rs.head_dim, rs.state),
                    jnp.float32, None),
             _alloc((rs.layers, rs.tail, slots, rs.conv_width), cfg.dtype,

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from generativeaiexamples_tpu.config.schema import EngineConfig
 from generativeaiexamples_tpu.models.llama import LlamaConfig
+from generativeaiexamples_tpu.serving.served_models import served
 
 GiB = float(1 << 30)
 
@@ -162,69 +163,30 @@ def _shard_numel(shape, spec, axis_sizes: Dict[str, int]) -> int:
     return n
 
 
-def _one_chip(lcfg) -> bool:
-    """A model whose parameters have no sharded layout."""
-    return lcfg.latent_row is not None or lcfg.recurrent_state is not None \
-        or lcfg.index_row is not None or lcfg.window_rows is not None
-
-
-def state_pool_bytes_per_device(lcfg, ecfg: EngineConfig) -> int:
-    """Bytes of the per-slot recurrent state beside the pages
-    (kv_cache.HybridPool.state and .tail): a fixed cost of
-    max_batch_size slots, not paged; 0 for a model without it."""
-    rs = lcfg.recurrent_state
-    return 0 if rs is None else ecfg.max_batch_size * rs.bytes_per_slot
-
-
-def window_pool_bytes_per_device(lcfg, ecfg: EngineConfig) -> int:
-    """Bytes of the window layers' pool beside the global layers' pages
-    (kv_cache.WindowPool.win): a fixed cost of max_batch_size slots'
-    window tables, sized by the engine's own function; 0 for a model
-    without window layers."""
-    if lcfg.window_rows is None:
-        return 0
-    from generativeaiexamples_tpu.serving.kv_cache import window_pool_pages
-
-    return (window_pool_pages(lcfg.window_rows.window, ecfg) * ecfg.page_size
-            * pool_token_bytes(lcfg, ecfg, {})["window rows"])
-
-
 def weight_bytes_per_device(lcfg: LlamaConfig, axis_sizes: Dict[str, int],
                             quantize: bool = False) -> int:
     """Exact per-device bytes of the (possibly int8) sharded param tree.
 
-    Shapes come from `jax.eval_shape` of the real initializer; specs from
-    `llama.param_specs`; int8 leaves count q (int8, full spec) + s
+    Shapes come from `jax.eval_shape` of the entry's initializer; specs
+    from its `param_specs`; int8 leaves count q (int8, full spec) + s
     (float32, spec minus the contracted axis) exactly as
     `serving.sharding._quantized_leaf_spec` places them.
     """
-    from generativeaiexamples_tpu.models import llama
     from generativeaiexamples_tpu.ops.quant import LLAMA_QUANT_KEYS
 
-    if _one_chip(lcfg):
-        # models/latent_moe.py, models/hybrid_ssm.py,
-        # models/sparse_attn_moe.py, models/window_attn_moe.py: whole on
-        # one chip (a share of the experts is the configuration's, not a
-        # mesh axis's)
+    entry = served(lcfg)
+    if entry.param_specs is None:
+        # whole on one chip (a share of the experts is the
+        # configuration's, not a mesh axis's)
         if any(int(n) > 1 for n in axis_sizes.values()):
             raise MemoryPlanError(
-                "a model with latent attention, recurrent state, an "
-                f"indexer or window layers has no tensor-parallel layout: "
+                f"a model with {entry.name} has no tensor-parallel layout: "
                 f"mesh axes {axis_sizes}")
-        from generativeaiexamples_tpu.models import (
-            hybrid_ssm, latent_moe, sparse_attn_moe, window_attn_moe)
-
-        model = (latent_moe if lcfg.latent_row is not None
-                 else hybrid_ssm if lcfg.recurrent_state is not None
-                 else sparse_attn_moe if lcfg.index_row is not None
-                 else window_attn_moe)
-        shapes = jax.eval_shape(lambda: model.init_params_on_device(
-            lcfg, quantize=quantize))
+        shapes = jax.eval_shape(lambda: entry.init_params(lcfg, quantize))
         return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
                    for leaf in jax.tree.leaves(shapes))
-    shapes = jax.eval_shape(lambda: llama.init_params(
-        lcfg, jax.random.PRNGKey(0)))
-    specs = llama.param_specs(lcfg)
+    shapes = jax.eval_shape(lambda: entry.init_params(lcfg, False))
+    specs = entry.param_specs(lcfg)
     wsize = jnp.dtype(lcfg.dtype).itemsize
 
     def leaf(shape_sd, spec, quantized: bool) -> int:
@@ -249,55 +211,13 @@ def weight_bytes_per_device(lcfg: LlamaConfig, axis_sizes: Dict[str, int],
     return total
 
 
-def pool_token_bytes(lcfg: LlamaConfig, ecfg: EngineConfig,
-                     axis_sizes: Dict[str, int]) -> Dict[str, int]:
-    """Exact per-device bytes ONE cached token takes in each pool of
-    pages, by the pool's name, over all of that pool's rows.
-
-    bf16 PagePool: k/v each [L, KH, P, ps, Hd], kv-heads on tensor
-    (sharding.KV_POOL_SPEC). Fused int8: codes [2, L, KH, P, ps, Hd]
-    int8 + scales [2, L, KH, P, ps] f32, kv-heads on tensor
-    (KV_FUSED_SPEC / KV_FUSED_SCALE_SPEC).
-    """
-    if lcfg.latent_row is not None:
-        # kv_cache.LatentPagePool: ONE vector a token and row for all
-        # heads, [c_kv ; k_rope] in whole 128-lane tiles; not
-        # n_kv_heads * head_dim, and no second array for V
-        from generativeaiexamples_tpu.serving.kv_cache import latent_lanes
-
-        return {"latent rows": lcfg.cache_rows * latent_lanes(lcfg.latent_row)
-                * jnp.dtype(ecfg.kv_dtype).itemsize}
-    tp = int(axis_sizes.get("tensor", 1))
-    kh = math.ceil(lcfg.n_kv_heads / tp)
-    int8 = jnp.dtype(ecfg.kv_dtype) == jnp.int8 \
-        or lcfg.index_row is not None or lcfg.window_rows is not None
-
-    def kv(rows):  # K and V of `rows` rows (a row per (pass, block))
-        if int8:  # codes and a float32 scale a (head, token), each twice
-            return rows * kh * (2 * lcfg.head_dim + 2 * 4)
-        return rows * kh * 2 * lcfg.head_dim \
-            * jnp.dtype(ecfg.kv_dtype).itemsize
-
-    if lcfg.window_rows is not None:
-        # kv_cache.WindowPool: two int8 pools, each with pages of its own
-        return {"global rows": kv(lcfg.window_rows.n_global),
-                "window rows": kv(lcfg.window_rows.n_window)}
-    pools = {"K and V": kv(lcfg.cache_rows)}
-    if lcfg.index_row is not None:
-        # kv_cache.SparseIndexPool: a bf16 index key a token and row
-        pools["index keys"] = lcfg.cache_rows * lcfg.index_row * 2
-    return pools
-
-
 def pool_page_bytes_per_device(lcfg: LlamaConfig, ecfg: EngineConfig,
                                axis_sizes: Dict[str, int]) -> int:
     """Exact per-device bytes of ONE page of the pool that grows with a
-    sequence (`pool_token_bytes` over the pools under its page table; a
-    window layer's rows have pages of their own and a fixed number of
-    them: `window_pool_bytes_per_device`)."""
-    pools = pool_token_bytes(lcfg, ecfg, axis_sizes)
-    pools.pop("window rows", None)
-    return ecfg.page_size * sum(pools.values())
+    sequence: what a cached token takes in each pool under its page
+    table, as the architecture's entry counts it (`token_bytes`)."""
+    return ecfg.page_size * sum(
+        served(lcfg).token_bytes(lcfg, ecfg, axis_sizes).values())
 
 
 def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
@@ -308,7 +228,7 @@ def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
     # KVCache [L, 1, KH, max_seq_len, Hd] x (k, v) on device
     # (engine._max_long_prefills = 1); counted unsharded — GSPMD may
     # shard it, so this over-counts, never under.
-    if _one_chip(lcfg):
+    if not served(lcfg).long_prompts:
         long_pf = 0  # no long-prompt scratch: the engine refuses the lane
     else:
         long_pf = (2 * lcfg.cache_rows * lcfg.n_kv_heads
@@ -397,19 +317,9 @@ def plan_engine_memory(
         lcfg, sizes, quantize=quantize), True,
         "int8 + f32 scales" if quantize else str(lcfg.dtype)),
     ) + _scratch_lines(lcfg, ecfg, sizes)
-    if lcfg.recurrent_state is not None:
-        lines += (PlanLine(
-            "state_pool", state_pool_bytes_per_device(lcfg, ecfg), False,
-            f"{ecfg.max_batch_size} slots x "
-            f"{lcfg.recurrent_state.bytes_per_slot} B, not paged"),)
-
-    if lcfg.window_rows is not None:
-        per = pool_token_bytes(lcfg, ecfg, sizes)
-        lines += (PlanLine(
-            "window_pool", window_pool_bytes_per_device(lcfg, ecfg), False,
-            f"{ecfg.max_batch_size} slots' window tables, "
-            f"{per['window rows']} B a cached token (the paged pool below: "
-            f"{per['global rows']} B)"),)
+    # the architecture's pools of fixed size beside the pages
+    lines += tuple(PlanLine(name, n_bytes, False, note) for name, n_bytes, note
+                   in served(lcfg).fixed_pools(lcfg, ecfg))
 
     page = pool_page_bytes_per_device(lcfg, ecfg, sizes)
     fixed = sum(l.bytes_per_device for l in lines)
@@ -467,7 +377,7 @@ def smallest_fitting_mesh(lcfg: LlamaConfig, ecfg: EngineConfig,
     `sharding.validate_tp` would accept — in increasing order and
     returns the first geometry that holds at least one max-length
     sequence, or None."""
-    if _one_chip(lcfg):
+    if served(lcfg).param_specs is None:
         return None  # whole on one chip: weight_bytes_per_device
     g = math.gcd(math.gcd(lcfg.n_heads, lcfg.n_kv_heads),
                  math.gcd(lcfg.mlp_dim, lcfg.vocab_size))

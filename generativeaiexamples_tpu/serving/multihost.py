@@ -96,10 +96,6 @@ class MultihostDivergenceError(MultihostError):
     collective would deadlock the slice."""
 
 
-def is_active() -> bool:
-    return jax.process_count() > 1
-
-
 def coordination_client():
     """The jax.distributed coordination-service client (KV store +
     barriers). Raises if jax.distributed was never initialized."""

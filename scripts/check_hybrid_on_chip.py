@@ -10,7 +10,7 @@ through `prefill_batch_step` (the cell's group of 4, one padding row)
 into decode slots that an earlier sequence has just used and left dirty,
 then NEW tokens through `decode_multi_step` (greedy, blocks of 8) and
 both pools with every other slot idle; the same positions replayed
-through `_hybrid_decode_once` (the body of `decode_step` and
+through `served_hybrid.decode_once` (the body of `decode_step` and
 `decode_multi_step`, which returns logits and the router's choices) and
 compared with the plain reference's ONE forward pass of each whole
 sequence (`benchmark/architectures/granitemoehybrid.py`: the recurrence
@@ -92,6 +92,7 @@ def main() -> int:
     from benchmark import architectures
     from benchmark.harness import system
     from generativeaiexamples_tpu.serving import engine_model as em
+    from generativeaiexamples_tpu.serving import served_hybrid
     from generativeaiexamples_tpu.serving import ssm_state_update as upd
     from generativeaiexamples_tpu.serving.kv_cache import PagePool
     from generativeaiexamples_tpu.utils.platform import setup_compile_cache
@@ -183,10 +184,10 @@ def main() -> int:
         return served, pool
 
     def replay_step(patch=None):
-        """`_hybrid_decode_once` jitted (with `patch` on while traced):
+        """`served_hybrid.decode_once` jitted (with `patch` on while traced):
         -> (logits, pool, choices [L, B, k])."""
         def step(p, pool, t, tb, ln, live):
-            logits, pool, _, choices = em._hybrid_decode_once(
+            logits, pool, _, choices = served_hybrid.decode_once(
                 p, mcfg, pool, t, tb, ln, None, mask=live)
             return logits, pool, choices
         jitted = jax.jit(step, donate_argnums=(1,))

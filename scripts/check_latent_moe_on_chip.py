@@ -7,7 +7,7 @@ widths, on the chip, through the step programs the benchmark times:
 logits: a seeded prompt through `prefill_batch_step` (the cell's group of
 4, one real row) and `prefill_step`, then NEW tokens through
 `decode_multi_step` (greedy, blocks of 8) and the latent pool; the same
-positions replayed through `_latent_decode_once` (the body of
+positions replayed through `served_latent.decode_once` (the body of
 `decode_step` and `decode_multi_step`, which returns logits and the
 router's choices) and compared with the plain reference's ONE forward
 pass of the whole sequence (`benchmark/architectures/axk1.py`, computed
@@ -81,6 +81,7 @@ def main() -> int:
     from benchmark.harness import system
     from generativeaiexamples_tpu.models import latent_moe
     from generativeaiexamples_tpu.serving import engine_model as em
+    from generativeaiexamples_tpu.serving import served_latent
     from generativeaiexamples_tpu.serving.kv_cache import PagePool
     from generativeaiexamples_tpu.utils.platform import setup_compile_cache
 
@@ -117,10 +118,10 @@ def main() -> int:
         else (16, 2 * K)
 
     def replay_step(patch=None):
-        """`_latent_decode_once` jitted (with `patch` on while traced):
+        """`served_latent.decode_once` jitted (with `patch` on while traced):
         -> (logits, pool, choices [Lm, B, k])."""
         def step(p, pool, t, tb, ln):
-            logits, pool, _, choices = em._latent_decode_once(
+            logits, pool, _, choices = served_latent.decode_once(
                 p, mcfg, pool, t, tb, ln, None)
             return logits, pool, choices
         jitted = jax.jit(step, donate_argnums=(1,))

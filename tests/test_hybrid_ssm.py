@@ -412,9 +412,11 @@ def test_a_llamas_engine_reports_the_state_counters_as_zero():
     snap = eng.metrics.snapshot()
     assert (snap["ssm_state_bytes_per_slot"], snap["ssm_layers"],
             snap["ssm_slot_writes"], snap["ssm_steps_kernel"]) == (0, 0, 0, 0)
-    assert cfg.recurrent_state is None and cfg.experts_held == 0
+    from generativeaiexamples_tpu.serving.served_models import served
+    assert served(cfg).prefill is em.llama_prefill
+    assert cfg.experts_held == 0
     from generativeaiexamples_tpu.serving import fleet
-    assert {"ssm_slot_writes", "ssm_steps_kernel"} <= set(fleet._COUNTER_KEYS)
+    assert {"ssm_slot_writes", "ssm_steps_kernel"} <= set(fleet.counter_keys())
 
 
 @pytest.mark.parametrize("lane,over", [
@@ -458,11 +460,13 @@ def test_memory_plan_counts_the_state_pool(params):
     assert isinstance(pool.pages, QuantPagePool)
     page = memory_plan.pool_page_bytes_per_device(CFG, ecfg, {})
     assert page == sum(x.nbytes for x in jax.tree.leaves(pool.pages)) // 5
-    state = memory_plan.state_pool_bytes_per_device(CFG, ecfg)
+    from generativeaiexamples_tpu.serving.served_models import served
+    ((name, state, _),) = served(CFG).fixed_pools(CFG, ecfg)
+    assert name == "state_pool"
     assert state == pool.state.nbytes + pool.tail.nbytes \
         == 4 * CFG.recurrent_state.bytes_per_slot
-    assert memory_plan.state_pool_bytes_per_device(
-        llama.LlamaConfig.tiny(), ecfg) == 0
+    tiny = llama.LlamaConfig.tiny()
+    assert served(tiny).fixed_pools(tiny, ecfg) == ()
     weights = memory_plan.weight_bytes_per_device(CFG, {}, quantize=True)
     assert weights == sum(x.nbytes for x in jax.tree.leaves(params))
     with pytest.raises(memory_plan.MemoryPlanError, match="tensor"):

@@ -610,7 +610,7 @@ def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas,
                  "decode_attn_pages_live",
                  "decode_attn_pages_walked", "decode_attn_rows_skipped",
                  "decode_attn_updates"):
-        assert name in fleet._COUNTER_KEYS
+        assert name in fleet.counter_keys()
         assert name in prometheus_text(snap)
         assert EngineMetrics().snapshot()[name] == 0
 

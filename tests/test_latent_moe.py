@@ -370,7 +370,7 @@ def test_a_llamas_engine_reports_the_expert_counters_as_zero():
     assert (snap["moe_pairs_routed"], snap["moe_pairs_local"],
             snap["experts_held"]) == (0, 0, 0)
     from generativeaiexamples_tpu.serving import fleet
-    assert {"moe_pairs_routed", "moe_pairs_local"} <= set(fleet._COUNTER_KEYS)
+    assert {"moe_pairs_routed", "moe_pairs_local"} <= set(fleet.counter_keys())
 
 
 @pytest.mark.parametrize("lane,over", [

@@ -299,6 +299,6 @@ def test_engine_counts_the_rows_its_prefills_compute(buckets, prompts, live,
     assert snap["prefill_rows_live"] == live
     assert snap["prefill_rows_bucket"] == bucket_rows
     for name in ("prefill_rows_live", "prefill_rows_bucket"):
-        assert name in fleet._COUNTER_KEYS
+        assert name in fleet.counter_keys()
         assert name in prometheus_text(snap)
         assert EngineMetrics().snapshot()[name] == 0

@@ -604,7 +604,7 @@ class TestOnOffIdle:
             assert snap[key]["count"] == 0 and snap[key]["buckets"] == {}
             assert key in flight.HIST_KEYS
         else:
-            assert snap[key] == 0 and key in fleet._COUNTER_KEYS
+            assert snap[key] == 0 and key in fleet.counter_keys()
         text = flight.prometheus_text(snap)
         name = "gaie_" + (key[5:] if key.startswith("hist_") else key)
         assert name in text

@@ -89,18 +89,20 @@ def test_a_layers_kind_comes_from_the_two_layouts():
     published = wm.WindowAttnMoeConfig()
     assert published.window_layout == (0, 1, 1, 1) * 13
     assert tuple(published.window_rows) == (4096, 13, 39)
-    assert (CFG.n_passes, CFG.latent_row, CFG.recurrent_state,
-            CFG.index_row, CFG.experts_held, CFG.cache_rows) == (
-        1, None, None, None, 8, 4)
+    assert (CFG.n_passes, CFG.experts_held, CFG.cache_rows) == (1, 8, 4)
     with pytest.raises(ValueError, match="window_layout"):
         wm.WindowAttnMoeConfig.tiny(window_layout=(0, 1))
-    # every other model says it has no window rows
+    # no other model has the attribute; each is served by its own entry
     from generativeaiexamples_tpu.models import (
         hybrid_ssm, latent_moe, sparse_attn_moe)
-    assert llama.LlamaConfig.tiny().window_rows is None
-    assert hybrid_ssm.HybridSsmConfig.tiny().window_rows is None
-    assert latent_moe.LatentMoeConfig.window_rows is None
-    assert sparse_attn_moe.SparseAttnMoeConfig.tiny().window_rows is None
+    from generativeaiexamples_tpu.serving import served_window
+    from generativeaiexamples_tpu.serving.served_models import served
+    assert served(CFG).decode_once is served_window.decode_once
+    for other in (llama.LlamaConfig.tiny(), hybrid_ssm.HybridSsmConfig.tiny(),
+                  latent_moe.LatentMoeConfig.tiny(),
+                  sparse_attn_moe.SparseAttnMoeConfig.tiny()):
+        assert not hasattr(other, "window_rows")
+        assert served(other).decode_once is not served_window.decode_once
 
 
 # -- the program's forward against the plain reference ----------------------
@@ -592,7 +594,7 @@ def test_a_llamas_engine_reports_the_window_counters_as_zero():
     assert eng.window_allocator is None
     from generativeaiexamples_tpu.serving import fleet
     assert {"window_pages_released", "decode_attn_window_pages_walked"} \
-        <= set(fleet._COUNTER_KEYS)
+        <= set(fleet.counter_keys())
 
 
 @pytest.mark.parametrize("lane,over", [
@@ -637,26 +639,30 @@ def test_memory_plan_counts_both_pools(params):
                                decode_steps_per_dispatch=2)
     n_window = window_pool_pages(W, ecfg)
     pool = WindowPool.zeros(CFG, 5, n_window, PS)
-    per = memory_plan.pool_token_bytes(CFG, ecfg, {})
-    assert per == {"global rows": 1 * 2 * (2 * 16 + 8),
-                   "window rows": 3 * 2 * (2 * 16 + 8)}
+    # the pages under the sequence's table are the global rows'; the
+    # window rows' pool is a fixed number of pages beside them
+    from generativeaiexamples_tpu.serving.served_models import served
+    per = served(CFG).token_bytes(CFG, ecfg, {})
+    assert per == {"global rows": 1 * 2 * (2 * 16 + 8)}
+    window_row_bytes = 3 * 2 * (2 * 16 + 8)
     page = memory_plan.pool_page_bytes_per_device(CFG, ecfg, {})
     assert page == sum(x.nbytes for x in jax.tree.leaves(pool.glob)) // 5
-    assert memory_plan.window_pool_bytes_per_device(CFG, ecfg) \
-        == sum(x.nbytes for x in jax.tree.leaves(pool.win))
+    ((name, fixed, _),) = served(CFG).fixed_pools(CFG, ecfg)
+    assert (name, fixed) == (
+        "window_pool", sum(x.nbytes for x in jax.tree.leaves(pool.win)))
     weights = memory_plan.weight_bytes_per_device(CFG, {}, quantize=True)
     assert weights == sum(x.nbytes for x in jax.tree.leaves(params))
     plan = memory_plan.plan_engine_memory(
         CFG, ecfg, axis_sizes={}, hbm_bytes_per_device=2**30)
     line = next(l for l in plan.lines if l.name == "window_pool")
-    assert line.bytes_per_device == n_window * PS * per["window rows"]
+    assert line.bytes_per_device == n_window * PS * window_row_bytes
     assert "240 B a cached token" in line.note and "80 B" in line.note
     with pytest.raises(memory_plan.MemoryPlanError, match="tensor"):
         memory_plan.weight_bytes_per_device(CFG, {"tensor": 2}, quantize=True)
     # every other model's pools by their names, a page their sum
     tiny = llama.LlamaConfig.tiny()
-    assert list(memory_plan.pool_token_bytes(tiny, ecfg, {})) == ["K and V"]
-    assert memory_plan.window_pool_bytes_per_device(tiny, ecfg) == 0
+    assert list(served(tiny).token_bytes(tiny, ecfg, {})) == ["K and V"]
+    assert served(tiny).fixed_pools(tiny, ecfg) == ()
 
 
 def test_hf_loader_refuses_a_smallthinker_snapshot(tmp_path):
