@@ -187,6 +187,14 @@ def llama_config_from_hf(path: str) -> llama_lib.LlamaConfig:
     `_require_llama_names`.)"""
     with open(os.path.join(path, "config.json")) as fh:
         c = json.load(fh)
+    if "linear_attn_config" in c:
+        raise ValueError(
+            f"{path}: model_type {c.get('model_type')!r} has "
+            "linear-attention layers (linear_attn_config) beside latent "
+            "attention: it is no LlamaConfig; its configuration is "
+            "models/linear_attn_moe.py's LinearAttnMoeConfig, and this "
+            "loader holds no tensor-name map for it (the convolutions, "
+            "A_log, dt_bias, the low-rank gates, the expert stacks)")
     if "kv_lora_rank" in c:
         raise ValueError(
             f"{path}: model_type {c.get('model_type')!r} has latent "

@@ -274,13 +274,14 @@ def take_layer(tree: Params, l, skip=()) -> Params:
     return {k: (v if k in skip else at(v)) for k, v in tree.items()}
 
 
-def layer_plan(cfg: HybridSsmConfig):
-    """[(mixer, index in its own stack)] in layer order."""
-    seen = {MAMBA: 0, ATTENTION: 0}
+def layer_plan(cfg):
+    """[(mixer, index in its own stack)] in layer order, for any
+    configuration with `layer_types`."""
+    seen: Dict[str, int] = {}
     plan = []
     for kind in cfg.layer_types:
-        plan.append((kind, seen[kind]))
-        seen[kind] += 1
+        plan.append((kind, seen.get(kind, 0)))
+        seen[kind] = seen.get(kind, 0) + 1
     return plan
 
 

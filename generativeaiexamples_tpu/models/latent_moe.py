@@ -6,16 +6,19 @@ What differs from models/llama.py's block, and nothing else:
 - ATTENTION caches one vector per token, shared by all heads:
   `[c_kv ; k_rope]` (cfg.latent_row = (kv_lora_rank, qk_rope_head_dim)),
   where the Llama block caches K and V per head. Queries go through a
-  low-rank bottleneck with a norm of its own; only the `qk_rope_head_dim`
-  last dimensions of a query or key head are rotated, with YaRN
-  frequencies (llama.YarnScaling). A prefill builds keys and values from
+  low-rank bottleneck with a norm of its own (`q_lora_rank` None: one
+  `w_q`, no bottleneck); only the `qk_rope_head_dim` last dimensions of a
+  query or key head are rotated, with YaRN frequencies
+  (llama.YarnScaling), or none is (`rotary` False: a model that takes
+  its positions from other layers). A prefill builds keys and values from
   the latent (`attend_prompt`); a decode step never does: it absorbs the
   key up-projection into the query and the value up-projection into the
   output (`attend_cached`), which is the same function.
 - FEED-FORWARD: the first `n_dense_layers` blocks are llama's SwiGLU;
   every later one routes each token over `n_routed_experts` experts
-  (sigmoid scores, the `n_experts_per_tok` largest, weights normalised
-  over the selected and scaled), adds the shared expert, and computes the
+  (sigmoid scores, the `n_experts_per_tok` largest, by score plus a
+  block's `router_bias` where it has one, weights normalised over the
+  selected and scaled), adds the shared expert, and computes the
   routed part for the `experts_held` experts from `expert_offset` on that
   live HERE: expert parallelism's share of the layer. What the experts
   elsewhere would add is left out; on one chip the layer runs with no
@@ -23,7 +26,7 @@ What differs from models/llama.py's block, and nothing else:
 
 Parameters: `tok_emb`, `ln_f`, `lm_head`; `dense` (the leading blocks,
 stacked) and `layers` (the expert blocks, stacked), each with the
-attention's leaves (`w_qa q_norm w_qb w_kva kv_norm w_kvb wo`), `ln1`,
+attention's leaves (`w_qa q_norm w_qb`, or `w_q`; `w_kva kv_norm w_kvb wo`), `ln1`,
 `ln2` and llama's `w_gate w_up w_down` (the dense feed-forward, or the
 shared expert); an expert block adds `router` [L, D, n_routed_experts]
 and the held experts' `we_gate_up` [L, E, D, 2 * Me] (gate ; up) and
@@ -57,7 +60,7 @@ class LatentMoeConfig:
     n_layers: int = 61           # dense + expert blocks
     n_dense_layers: int = 1      # HF first_k_dense_replace
     n_heads: int = 64
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536  # None: queries projected directly
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -74,6 +77,7 @@ class LatentMoeConfig:
     expert_offset: int = 0
     rope_theta: float = 10000.0
     rope_scaling: Optional[YarnScaling] = None
+    rotary: bool = True  # False: the row's and a query's last R as they are
     rms_eps: float = 1e-6
     max_seq_len: int = 131072
     tie_embeddings: bool = False
@@ -144,19 +148,30 @@ class LatentMoeConfig:
 ROUTED_INIT_GAIN = 0.25
 
 
-def _block_shapes(cfg: LatentMoeConfig, L: int, mlp: int):
+def attention_shapes(cfg, L: int):
+    """(int8-able weights, norms of one) of `L` stacked attention mixers."""
     D, H = cfg.dim, cfg.n_heads
     C, R = cfg.latent_row
     Dn, Dv = cfg.qk_nope_head_dim, cfg.v_head_dim
-    return {
-        "w_qa": (L, D, cfg.q_lora_rank),
-        "w_qb": (L, cfg.q_lora_rank, H * (Dn + R)),
-        "w_kva": (L, D, C + R),
-        "w_kvb": (L, C, H * (Dn + Dv)),
-        "wo": (L, H * Dv, D),
-        "w_gate": (L, D, mlp), "w_up": (L, D, mlp), "w_down": (L, mlp, D),
-    }, {"ln1": (L, D), "ln2": (L, D), "q_norm": (L, cfg.q_lora_rank),
-        "kv_norm": (L, C)}
+    weights = {"w_qa": (L, D, cfg.q_lora_rank),
+               "w_qb": (L, cfg.q_lora_rank, H * (Dn + R)),
+               "w_kva": (L, D, C + R), "w_kvb": (L, C, H * (Dn + Dv)),
+               "wo": (L, H * Dv, D)}
+    norms = {"ln1": (L, D), "q_norm": (L, cfg.q_lora_rank),
+             "kv_norm": (L, C)}
+    if cfg.q_lora_rank is None:
+        del weights["w_qa"], weights["w_qb"], norms["q_norm"]
+        weights["w_q"] = (L, D, H * (Dn + R))
+    return weights, norms
+
+
+def _block_shapes(cfg: LatentMoeConfig, L: int, mlp: int):
+    D = cfg.dim
+    weights, norms = attention_shapes(cfg, L)
+    weights.update({"w_gate": (L, D, mlp), "w_up": (L, D, mlp),
+                    "w_down": (L, mlp, D)})
+    norms["ln2"] = (L, D)
+    return weights, norms
 
 
 def init_params_on_device(cfg: LatentMoeConfig, seed: int = 0, *,
@@ -235,15 +250,24 @@ def project_latent(cfg: LatentMoeConfig, h, w, positions):
     H, Dn = cfg.n_heads, cfg.qk_nope_head_dim
     C, R = cfg.latent_row
     with jax.named_scope("attn.q_latent"):
-        cq = rms_norm(mm(h, w["w_qa"]), w["q_norm"], cfg.rms_eps)
-        q = mm(cq.astype(cfg.dtype), w["w_qb"]).reshape(B, S, H, Dn + R)
-        q_rope = rope(q[..., Dn:].transpose(0, 2, 1, 3), positions,
-                      cfg.rope_theta, cfg.rope_scaling).transpose(0, 2, 1, 3)
+        if cfg.q_lora_rank is None:
+            q = mm(h, w["w_q"]).reshape(B, S, H, Dn + R)
+        else:
+            cq = rms_norm(mm(h, w["w_qa"]), w["q_norm"], cfg.rms_eps)
+            q = mm(cq.astype(cfg.dtype), w["w_qb"]).reshape(B, S, H, Dn + R)
+        q_rope = q[..., Dn:]
+        if cfg.rotary:
+            q_rope = rope(q_rope.transpose(0, 2, 1, 3), positions,
+                          cfg.rope_theta,
+                          cfg.rope_scaling).transpose(0, 2, 1, 3)
     with jax.named_scope("attn.kv_latent"):
         ckv = mm(h, w["w_kva"])
         c = rms_norm(ckv[..., :C], w["kv_norm"], cfg.rms_eps)
-        k_rope = rope(ckv[..., None, :, C:], positions, cfg.rope_theta,
-                      cfg.rope_scaling)[:, 0]  # ONE head, shared by all
+        if cfg.rotary:
+            k_rope = rope(ckv[..., None, :, C:], positions, cfg.rope_theta,
+                          cfg.rope_scaling)[:, 0]  # ONE head, shared by all
+        else:
+            k_rope = ckv[..., C:]
         row = jnp.concatenate([c, k_rope], axis=-1).astype(cfg.dtype)
     return q[..., :Dn], q_rope, row
 
@@ -302,15 +326,21 @@ def attend_prompt(cfg: LatentMoeConfig, q_nope, q_rope, row, w, lengths,
 
 # -- the expert layer ------------------------------------------------------
 
-def route(cfg: LatentMoeConfig, h, router):
+def route(cfg: LatentMoeConfig, h, router, bias=None):
     """Scores over ALL experts for tokens h [T, D]: sigmoid of a product
     accumulated in float32, the n_experts_per_tok largest, their weights
-    normalised over the selected and scaled. (`topk_method` "none": no
-    group limit, no correction bias.) Returns (experts [T, k] int32,
+    normalised over the selected and scaled. No group limit. `bias`
+    [experts] float32, a correction the SELECTION alone reads: the
+    largest of score + bias are chosen and weighed by their scores (None,
+    `topk_method` "none": by the scores). Returns (experts [T, k] int32,
     weights [T, k] float32)."""
     s = jax.nn.sigmoid(jnp.dot(h, router,
                                preferred_element_type=jnp.float32))
-    top, idx = jax.lax.top_k(s, cfg.n_experts_per_tok)
+    if bias is None:
+        top, idx = jax.lax.top_k(s, cfg.n_experts_per_tok)
+    else:
+        _, idx = jax.lax.top_k(s + bias, cfg.n_experts_per_tok)
+        top = jnp.take_along_axis(s, idx, axis=-1)
     if cfg.norm_topk_prob:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), top * cfg.routed_scaling_factor
@@ -327,7 +357,7 @@ def moe_branch(cfg: LatentMoeConfig, h, w, experts, layer, use_pallas=None,
     pairs each held expert took [E], the router's choice [T, k])."""
     E, Me = cfg.experts_held, cfg.moe_mlp_dim
     with jax.named_scope("moe.router"):
-        idx, weights = route(cfg, h, w["router"])
+        idx, weights = route(cfg, h, w["router"], w.get("router_bias"))
     with jax.named_scope("moe.dispatch"):
         local = idx - cfg.expert_offset
         here = (local >= 0) & (local < E)

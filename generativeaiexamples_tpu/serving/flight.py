@@ -117,6 +117,12 @@ EV_SPARSE_SELECT = 22
 # and call) the window rows' kernel calls walked in the block, those
 # calls, and the softmax updates the pages were folded into.
 EV_WINDOW_CACHE = 23
+# Recurrent state beside a latent row: one a landed decode block of a
+# model with both (scheduler thread), from the lengths the host
+# dispatched the block with. a = the live slots' mean context over the
+# block's steps; b = a live sequence's latent-row bytes over its state +
+# tail + latent-row bytes (the share of a sequence that GROWS).
+EV_STATE_CACHE = 24
 
 # Program classes (EV_PROGRAM.code).
 PROG_DECODE = 0    # a decode block (n = steps K)
@@ -144,7 +150,7 @@ EVENT_NAMES = {
     EV_CHAOS: "chaos", EV_KV_TRANSFER: "kv_transfer",
     EV_MOE_LOAD: "moe_load", EV_PROGRAM: "program",
     EV_DECODE_JOIN: "decode_join", EV_SPARSE_SELECT: "sparse_select",
-    EV_WINDOW_CACHE: "window_cache",
+    EV_WINDOW_CACHE: "window_cache", EV_STATE_CACHE: "state_cache",
 }
 
 # Retire reason codes (EV_RETIRE.code); anything unknown maps to -1.

@@ -516,6 +516,13 @@ def latent_lanes(latent_row) -> int:
     return -(-sum(latent_row) // LANES) * LANES
 
 
+def latent_token_bytes(cfg, kv_dtype) -> int:
+    """Bytes a device holds of ONE cached token in the `cfg.cache_rows`
+    rows of a LatentPagePool."""
+    return (cfg.cache_rows * latent_lanes(cfg.latent_row)
+            * jnp.dtype(kv_dtype).itemsize)
+
+
 @dataclasses.dataclass
 class LatentPagePool:
     """The pool of a latent-attention model (cfg.latent_row = (C, R)):
@@ -596,23 +603,25 @@ class HybridPool:
     two kinds of state in one donated tree.
 
     `pages` is the page pool of the ATTENTION layers' rows (cfg.cache_rows
-    of them; a PagePool or a QuantPagePool, with every method those have);
-    the page allocator, the page tables and the attention kernels see
-    only it. `state` [Ls, slots, H, P, N] float32 and `tail` [Ls, T,
+    of them; a PagePool or a QuantPagePool, with every method those have,
+    or a LatentPagePool where those layers cache a latent row); the page
+    allocator, the page tables and the attention kernels see only it.
+    `state` [Ls, slots, H, P, N] float32 and `tail` [Ls, T,
     slots, W] (the convolution's last T inputs, oldest first, in the
     model's type; the slots before the channels so that T = 3 is not
     padded to a tile) belong to DECODE SLOTS, not to pages: slot b's rows
     are whatever sequence occupies slot b. Nothing allocates them: a
     prefill writes a slot's rows whole (`write_slots`), so a reused slot
     never sees its predecessor's, and a decode step updates them in place
-    (`state` through serving/ssm_state_update.py).
+    (`state` through serving/ssm_state_update.py, or
+    serving/kda_state_update.py for a delta-rule layer's).
 
     The lanes that re-read, share, move, snapshot or roll back cache
     (prefix reuse, the pager, the disaggregated transfer, speculation,
     the long-prompt scratch cache) would have to carry this state too and
     do not: LLMEngine refuses them by name for such a model."""
 
-    pages: "PagePool | QuantPagePool"
+    pages: "PagePool | QuantPagePool | LatentPagePool"
     state: jax.Array
     tail: jax.Array
 
@@ -657,10 +666,13 @@ class HybridPool:
 
     @staticmethod
     def zeros(cfg, n_pages: int, page_size: int, dtype,
-              slots: int) -> "HybridPool":
+              slots: int, pages=None) -> "HybridPool":
+        """`pages`: the attention layers' pool where it is not K and V a
+        head (a LatentPagePool)."""
         rs = cfg.recurrent_state
         return HybridPool(
-            kv_pool_zeros(cfg, n_pages, page_size, dtype),
+            pages if pages is not None
+            else kv_pool_zeros(cfg, n_pages, page_size, dtype),
             _alloc((rs.layers, slots, rs.heads, rs.head_dim, rs.state),
                    jnp.float32, None),
             _alloc((rs.layers, rs.tail, slots, rs.conv_width), cfg.dtype,

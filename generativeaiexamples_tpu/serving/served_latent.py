@@ -13,7 +13,7 @@ from generativeaiexamples_tpu.models import latent_moe
 from generativeaiexamples_tpu.models.llama import attn_out, rms_norm
 from generativeaiexamples_tpu.serving import served_models as sm
 from generativeaiexamples_tpu.serving.kv_cache import (
-    LatentPagePool, latent_lanes, token_slots)
+    LatentPagePool, latent_token_bytes, token_slots)
 
 
 def prefill(params, cfg, pool, tokens, lengths, table_rows, use_pallas, *,
@@ -105,8 +105,7 @@ sm.register(latent_moe.LatentMoeConfig, sm.ServedModel(
     init_params=lambda cfg, quantize: latent_moe.init_params_on_device(
         cfg, quantize=quantize),
     token_bytes=lambda cfg, ecfg, axis_sizes: {
-        "latent rows": cfg.cache_rows * latent_lanes(cfg.latent_row)
-        * jnp.dtype(ecfg.kv_dtype).itemsize},
+        "latent rows": latent_token_bytes(cfg, ecfg.kv_dtype)},
     caches=lambda cfg: (
         f"model caches a latent row of {sum(cfg.latent_row)} values "
         f"a token and layer (latent attention)"),

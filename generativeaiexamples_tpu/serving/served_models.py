@@ -23,7 +23,8 @@ from generativeaiexamples_tpu.serving.kv_cache import PagePool
 _ENTRY_MODULES = tuple(
     f"generativeaiexamples_tpu.serving.{name}" for name in (
         "engine_model",  # the Llama entry, beside the walk that is its bodies
-        "served_latent", "served_hybrid", "served_sparse", "served_window"))
+        "served_latent", "served_hybrid", "served_sparse", "served_window",
+        "served_linear"))
 
 
 # A lane an architecture has no form for, beyond engine._ONE_PASS_LANES:

@@ -72,6 +72,19 @@ def _update_kernel(layer_ref, order_ref, n_ref, a_ref, xdt_ref, b_ref, c_ref,
         y_ref[...] = jnp.zeros_like(y_ref)
 
 
+def live_slots(active):
+    """A step's `active` mask [B] as the in-place state kernels walk it:
+    (the live slots' indices first, their count [1])."""
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+    return order, jnp.sum(active, dtype=jnp.int32).reshape(1)
+
+
+def walked_slot(i, order, n):
+    """Grid step i's slot: past the last live one, stay on it (Pallas
+    neither fetches nor writes its block again)."""
+    return order[jnp.minimum(i, jnp.maximum(n[0] - 1, 0))]
+
+
 def ssm_state_update_pallas(state, layer, order, n_live, a, xdt, Bv, Cv, *,
                             interpret: bool = False):
     """The kernel form. state [L, slots, H, P, N] float32; order [B] the
@@ -80,17 +93,14 @@ def ssm_state_update_pallas(state, layer, order, n_live, a, xdt, Bv, Cv, *,
     _, B, H, P, N = state.shape
     hp = HEADS_PER_PASS if H % HEADS_PER_PASS == 0 else 1
 
-    def slot(i, l, o, n):  # past the last live slot: stay on it
-        return o[jnp.minimum(i, jnp.maximum(n[0] - 1, 0))]
-
     def per_slot(*block):
         return pl.BlockSpec((None,) + block,
-                            lambda i, l, o, n: (slot(i, l, o, n),)
+                            lambda i, l, o, n: (walked_slot(i, o, n),)
                             + (0,) * len(block))
 
     state_spec = pl.BlockSpec(
         (None, None, H, P, N),
-        lambda i, l, o, n: (l[0], slot(i, l, o, n), 0, 0, 0))
+        lambda i, l, o, n: (l[0], walked_slot(i, o, n), 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B,),
@@ -162,8 +172,7 @@ def ssm_state_update(state, layer, active, step, log_a, x, Bv, Cv,
         return ssm_state_update_reference(state, layer, active, a, xdt, Bv,
                                           Cv)
     N = state.shape[-1]
-    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
-    n_live = jnp.sum(active, dtype=jnp.int32).reshape(1)
+    order, n_live = live_slots(active)
     state, y = ssm_state_update_pallas(
         state, layer, order, n_live,
         jnp.broadcast_to(a[..., None], a.shape + (N,)), xdt, Bv, Cv)

@@ -25,6 +25,8 @@ from generativeaiexamples_tpu.ops.attention import flash_attention
 from generativeaiexamples_tpu.ops.encoder_attention import encoder_attention
 from generativeaiexamples_tpu.serving.paged_attention import paged_attention
 from generativeaiexamples_tpu.ops.quant import QuantizedTensor
+from generativeaiexamples_tpu.serving.kda_state_update import (
+    kda_state_update_pallas)
 from generativeaiexamples_tpu.serving.paged_attention_int8 import (
     live_rows, paged_attention_int8, paged_attention_int8_window)
 from generativeaiexamples_tpu.serving.paged_attention_mla import (
@@ -261,6 +263,42 @@ KERNELS = {
         lambda *a: _grouped(64, *a),
         [((24576 + 64 * 64, 768), BF16), ((12, 64, 768, 2560), I8),
          ((12, 64, 2560), F32), ((448,), I32), ((1,), I32)]),
+    # Kimi-Linear-48B-A3B's share of an 8-chip group
+    # (benchmark/configs/kimi-linear-48b-a3b-int8-ep8.json): 80 slots; the
+    # in-place delta-rule update of one KDA layer over the per-slot pool,
+    # [32, 128, 128] float32 a slot (three row-to-column relayouts a head,
+    # which interpret mode never lowers); the absorbed paged kernel at 32
+    # heads over tables of 48 pages and seven rows; a latent prompt of
+    # 1,536 at 32 heads; the grouped matmul's fifth shape, 32 held experts
+    # of 1,024 at a width of 2,304: a decode step's 640 pairs' worth of
+    # tiles (an eighth of them held) and a prompt's 12,288
+    "kda_state_update_80_slots": (
+        lambda state, o, n, a, k, q, v, b: kda_state_update_pallas(
+            state, 4, o, n, a, k, q, v, b),
+        [((20, 80, 32, 128, 128), F32), ((80,), I32), ((1,), I32)]
+        + [((80, 32, 128), F32)] * 5),
+    "paged_decode_latent_32_heads_tables_of_48": (
+        lambda q, pool, t, ln: paged_attention_mla(
+            q, pool, 3, t, ln, latent=512, scale=0.07),
+        [((80, 32, 640), BF16), ((7, 3880, PS, 640), BF16),
+         ((80, 48), I32), ((80,), I32)]),
+    "flash_prefill_latent_32_heads_1536": (
+        lambda q, k, v, ln: flash_attention(q, k, v, causal=True,
+                                            lengths=ln, scale=0.07),
+        [((1, 32, 1536, 256), BF16)] * 2 + [((1, 32, 1536, 128), BF16),
+                                            ((1,), I32)]),
+    "grouped_expert_matmul_decode_32_of_1024": (
+        lambda *a: _grouped(32, *a),
+        [((640 + 32 * 32, 2304), BF16), ((26, 32, 2304, 2048), I8),
+         ((26, 32, 2048), F32), ((52,), I32), ((1,), I32)]),
+    "grouped_expert_matmul_down_32_of_1024": (
+        lambda *a: _grouped(32, *a),
+        [((640 + 32 * 32, 1024), BF16), ((26, 32, 1024, 2304), I8),
+         ((26, 32, 2304), F32), ((52,), I32), ((1,), I32)]),
+    "grouped_expert_matmul_prefill_32_of_1024": (
+        lambda *a: _grouped(128, *a),
+        [((12288 + 32 * 128, 2304), BF16), ((26, 32, 2304, 2048), I8),
+         ((26, 32, 2048), F32), ((128,), I32), ((1,), I32)]),
 }
 
 
@@ -723,13 +761,21 @@ PARENT_LATENT = {"decode_multi_step_k8": "cb1fb18d90994585",
 
 
 def _latent_lowered(chip):
+    programs, mcfg = _step_programs_lowered(chip, "ax-k1-int8-ep16")
+    assert mcfg.latent_row is not None
+    return programs
+
+
+def _step_programs_lowered(chip, name):
+    """(a configuration's decode block, decode step, lone prefill of 128
+    and group of 4 x 384, lowered for the chip with kernels on at its
+    served sizes; its model configuration)."""
     import json
 
     from benchmark import architectures
     from benchmark.harness import system
     from generativeaiexamples_tpu.serving import engine_model as em
 
-    name = "ax-k1-int8-ep16"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
                            name + ".json")) as fh:
@@ -737,7 +783,6 @@ def _latent_lowered(chip):
     ecfg = system.engine_config(config)
     mcfg, params, pool, _ = architectures.load(config).compile_shapes(
         config, ecfg, [next(iter(chip.device_set))])
-    assert mcfg.latent_row is not None
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -754,7 +799,7 @@ def _latent_lowered(chip):
         "decode_step": em.decode_step.lower(params, mcfg, pool, *state, True),
         "prefill_1x128": _prefill_lowered(chip, name, 1, 128)[0],
         "prefill_4x384": _prefill_lowered(chip, name, 4, 384)[0],
-    }
+    }, mcfg
 
 
 def test_a_latent_models_programs_lower_to_the_text_the_parent_did(chip):
@@ -765,6 +810,86 @@ def test_a_latent_models_programs_lower_to_the_text_the_parent_did(chip):
         v.as_text()).encode()).hexdigest()[:16]
         for k, v in _latent_lowered(chip).items()}
     assert got == PARENT_LATENT, json.dumps(got)
+
+
+# -- a latent pool under the state pool leaves granite's programs alone (PR 48)
+# `kv_cache.HybridPool` may hold a LatentPagePool since PR 48, the state
+# kernels share their walk over the live slots
+# (`ssm_state_update.live_slots`, `walked_slot`), and `latent_moe` took
+# three options. `granite4h-small.decode-closed96` runs the first two: its
+# decode and prefill programs, lowered for the chip with kernels on at the
+# configuration's served sizes, are the text they were on PR 48's PARENT
+# (a75da57), taken there by `_step_programs_lowered`.
+PARENT_HYBRID = {"decode_multi_step_k8": "06f0ddb75bf23d60",
+                 "decode_step": "6066480c1075772b",
+                 "prefill_1x128": "c659e638f18dc785",
+                 "prefill_4x384": "1e8212f55cfde0fc"}
+
+
+def test_a_hybrid_models_programs_lower_to_the_text_the_parent_did(chip):
+    import hashlib
+    import json
+
+    programs, mcfg = _step_programs_lowered(chip, "granite-4.0-h-small-int8")
+    assert mcfg.recurrent_state.layers == 9
+    got = {k: hashlib.sha256(_without_kernel_payload(
+        v.as_text()).encode()).hexdigest()[:16] for k, v in programs.items()}
+    assert got == PARENT_HYBRID, json.dumps(got)
+
+
+# -- linear attention beside latent attention: the configuration's own
+# shapes (PR 48). The decode program of `kimi-linear-48b-a3b-int8-ep8`,
+# lowered for the chip from its architecture entry's `compile_shapes` with
+# kernels on: a KDA layer updates its slots' state through a kernel of its
+# own name (`trace_kernel` matches by substring: `ssm_state_update` must
+# not find it), a latent layer attends through A.X-K1's, and every expert
+# layer runs the grouped matmul twice.
+def test_the_linear_decode_program_runs_its_kernels_under_their_names(chip):
+    import json
+
+    from benchmark import architectures
+    from benchmark.harness import system
+    from generativeaiexamples_tpu.serving import engine_model as em
+    from generativeaiexamples_tpu.serving.kv_cache import (
+        HybridPool, LatentPagePool)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-linear-48b-a3b-int8-ep8.json")) as fh:
+        config = json.load(fh)
+    ecfg = system.engine_config(config)
+    mcfg, params, pool, mesh = architectures.load(config).compile_shapes(
+        config, ecfg, [next(iter(chip.device_set))])
+    assert mesh is None and mcfg.latent_row == (512, 64)
+    assert isinstance(pool, HybridPool)
+    assert isinstance(pool.pages, LatentPagePool)
+    assert pool.state.shape == (20, 80, 32, 128, 128)
+    assert pool.state.dtype == F32
+    assert pool.tail.shape == (20, 3, 80, 12288) and pool.tail.dtype == BF16
+    assert pool.pages.c.shape == (7, 3880, 128, 640)
+    # weights 7.3 GB, state and tails 3.47 GB, latent rows 4.45 GB
+    total = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves((params, pool)))
+    assert 15.1e9 < total < 15.4e9, total
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, maxp = ecfg.max_batch_size, ecfg.max_seq_len // ecfg.page_size
+    assert (slots, maxp) == (80, 48)
+    text = em.decode_multi_step.lower(
+        params, mcfg, pool, arr((slots,), I32), arr((slots, maxp), I32),
+        arr((slots,), I32), arr((slots,), jnp.bool_), arr((slots,), F32),
+        arr((slots,), F32), arr((slots,), I32), arr((2,), jnp.uint32), 1,
+        True, sampling_flags=(True, False, False)).as_text()
+    for kernel, calls in (("kda_state_update", 20),
+                          ("paged_attention_mla", 7),
+                          ("moe_grouped_matmul_int8", 52)):
+        assert text.count(f'kernel_name = "{kernel}"') == calls, kernel
+    assert "ssm_state_update" not in text and "kv_append_int8" not in text
+    # the step's mask is turned into the state kernel's walk ONCE a step
+    # (`kda_state_update.live_slots`), not once a layer
+    assert text.count("stablehlo.sort") == 1
 
 
 # -- learned sparse attention: the configuration's own shapes (PR 42) -------
