@@ -237,6 +237,14 @@ class EngineConfig:
     # at max_batch_size=256 would otherwise spike ~2x the steady-state
     # footprint); 0 = uncapped (group = max_batch_size).
     max_prefill_group: int = 64
+    # The CEILING of a decode block, in steps: what a block runs while
+    # nobody can be waiting for it (every slot live, nothing queued).
+    # While an arrival can be waiting the scheduler shortens the block
+    # to a warm K that fits a time budget, by the step time it observes
+    # (serving/decode_block.py: BLOCK_BUDGET_MS, choose_k), because an
+    # arrival waits about two blocks and a block is steps x step time.
+    # Warm-up compiles K = 1, 2 and this value rounded down to a power
+    # of two; the window pool is sized with this value.
     decode_steps_per_dispatch: int = 8
     # Decode dispatch pipeline depth: blocks enqueued ahead of the host
     # fetch so device compute overlaps result readback.

@@ -21,9 +21,15 @@ concurrency discipline; this one is explicit):
          blocks in flight over ALL active slots (fixed batch shape,
          inactive slots masked to the page-0 sink, sampling on device,
          tokens chained device-side); block only on fetching the OLDEST
-         in-flight block; emit/retire from it. A slot awaiting its
-         first token gets a K=1 block so TTFT never rides a full
-         K-step block.
+         in-flight block; emit/retire from it. A block runs AT MOST
+         decode_steps_per_dispatch steps: the field is the ceiling, and
+         serving/decode_block.py::choose_k, the one place a block's
+         length is chosen, shortens it to a warm K that fits a time
+         budget (BLOCK_BUDGET_MS of device time, by the step time the
+         landed blocks read) while an arrival can be waiting for it (an
+         empty slot, a queued request, a prefill whose slot has not
+         decoded yet), since a freed slot's next occupant waits out
+         about two blocks before its slot decodes.
 
   Latency design (r4; the r3 study's measured failure modes shaped
   it): the blocking fetch itself runs on a reader thread that is
@@ -67,7 +73,7 @@ import numpy as np
 from generativeaiexamples_tpu.config.schema import EngineConfig
 from generativeaiexamples_tpu.models.llama import LlamaConfig
 from generativeaiexamples_tpu.obs import tracing
-from generativeaiexamples_tpu.serving import engine_model
+from generativeaiexamples_tpu.serving import decode_block, engine_model
 from generativeaiexamples_tpu.serving.kv_cache import (
     PageAllocator, SequencePages, WindowSequencePages, WindowTables,
     kernel_append)
@@ -312,7 +318,8 @@ class _LongPrefill:
 # EngineMetrics' counters that are an attribute of their key's name:
 # snapshot() emits them and a fleet sums them from this ONE list.
 _COUNTERS = (
-    "decode_steps", "layer_passes", "decode_steps_direct_qkv",
+    "decode_steps", "decode_blocks_short_for_arrival", "layer_passes",
+    "decode_steps_direct_qkv",
     "decode_steps_kernel_append", "decode_steps_fused_append",
     "decode_attn_pages_live", "decode_attn_pages_walked",
     "decode_attn_updates", "decode_attn_rows_skipped", "prefill_rows_live",
@@ -350,6 +357,10 @@ class EngineMetrics:
                       for k in flight_mod.HIST_KEYS}
         self.tokens_out = 0
         self.decode_steps = 0
+        # Decode blocks whose K the time budget lowered because an
+        # arrival could be waiting for them (decode_block.choose_k): how
+        # often the rule engaged, before the page and token bounds.
+        self.decode_blocks_short_for_arrival = 0
         # Block executions dispatched by decode steps: a step runs
         # every block once a pass (cfg.cache_rows of them), so
         # layer_passes / decode_steps is the depth a token pays for.
@@ -899,6 +910,11 @@ class LLMEngine:
         # while the recorder is on; drained by the scheduler thread
         # into `program` events (_drain_programs).
         self.programs = ProgramLedger()
+        # One decode step's device time by the ledger's rows of the last
+        # landed blocks: what decode_block.choose_k holds a block to its
+        # time budget with. None (the rule off) until a block has landed
+        # and for as long as the recorder, and so the ledger, is off.
+        self._step_time = decode_block.StepTime()
         # Scheduler-thread beat bookkeeping for the recorder: previous
         # beat's host-ready stamp (drives the beat-gap histogram and
         # host-gap attribution) and pager pages moved since the last
@@ -1141,8 +1157,8 @@ class LLMEngine:
             k_live = max(1, self.ecfg.decode_steps_per_dispatch)
             while k_live & (k_live - 1):
                 k_live &= k_live - 1
-            # 2 is the low-occupancy block size (see _dispatch_decode).
-            ks = sorted({1, 2, k_live})
+            # the short block of low occupancy and of the time budget
+            ks = sorted({1, decode_block.SHORT_K, k_live})
         # The dispatcher will never pick a K outside this set while it
         # is non-empty — a cold decode variant compiling mid-traffic
         # freezes every live stream for 20-40 s. K=1 is forced in so a
@@ -2116,6 +2132,10 @@ class LLMEngine:
             # complete: their rows resolve now, the block's own with
             # them, before the beat row that reads it.
             self._drain_programs(fl.prog.seq if fl.prog is not None else -1)
+            if fl.prog is not None and fl.prog.t_start:
+                # the ledger's interval of this block: what a step
+                # costs whoever waits out the next one (decode_block)
+                self._step_time.note(fl.prog.ran_ms, fl.K)
             with _phase("sched.emit"):
                 self._process_block_host(fl, host)
         except Exception:
@@ -3430,7 +3450,6 @@ class LLMEngine:
         # Linear/plain engines: r_nodes == r, byte-identical sizing.
         r = self._spec_r if spec_mode else 1
         r_nodes = self._spec_tree_nodes if spec_mode else 1
-        K = max(1, self.ecfg.decode_steps_per_dispatch)
         lengths = np.ones((B,), np.int32)
         tables = np.zeros((B, self.max_pages), np.int32)
         win_tables = win_base = None
@@ -3462,18 +3481,24 @@ class LLMEngine:
             live.append(i)
         if not live:
             return False
-        if len(live) * 4 <= B:
-            # Low-occupancy (arrival-heavy) regime: short blocks keep
-            # the device queue shallow, so a new arrival's prefill is
-            # never stuck behind ~K full weight reads of mostly-empty
-            # decode work. At high occupancy the K=8 blocks that
-            # maximize throughput return.
-            K = min(K, 2)
-        if self._long_prefills and self.ecfg.prefill_decode_k_cap > 0:
-            # Chunked-prefill priority lane: short decode blocks keep
-            # the device queue shallow so prefill chunks interleave at
-            # a fine grain.
-            K = min(K, self.ecfg.prefill_decode_k_cap)
+        # The block's length is ONE function's choice (decode_block):
+        # the configured K is its ceiling, and while an arrival can be
+        # waiting for this block (an empty slot, a queued request, a
+        # slot whose prefill is enqueued and rides its first block
+        # here) the block is held to a time budget by the step time
+        # the landed blocks read. Asked twice: what the budget took
+        # away is `decode_blocks_short_for_arrival`.
+        before_step_time = (
+            self.ecfg.decode_steps_per_dispatch, self._warm_ks, len(live), B,
+            bool(self.waiting) or any(
+                s is None or s.prefilling or s.awaiting_first
+                for s in self.slots),
+            self.ecfg.prefill_decode_k_cap if self._long_prefills else 0)
+        K, unhurried = (
+            decode_block.choose_k(*before_step_time, step_ms,
+                                  decode_block.BLOCK_BUDGET_MS)
+            for step_ms in (self._step_time.ms, None))
+        short_for_arrival = K < unhurried
         # Two caps with different semantics: page capacity is HARD
         # (steps past it write out of bounds) — round DOWN; the token
         # budget is SOFT (steps past the last requested token are
@@ -3599,6 +3624,7 @@ class LLMEngine:
         if plan.rider_width:
             self._rider_bookkeeping(lp, n_part)
         self.metrics.decode_steps += K
+        self.metrics.decode_blocks_short_for_arrival += short_for_arrival
         self.metrics.layer_passes += K * self.cfg.cache_rows
         # the plain and the fused decode programs choose their form so;
         # a speculative engine's programs keep the staged one
@@ -4116,18 +4142,10 @@ class LLMEngine:
                 "pager_in": self._exec_pager_in}
 
     def _pick_k(self, bound: int) -> int:
-        """Largest dispatchable K <= bound: power-of-two, and (when a
-        warmup ran) restricted to the precompiled variants. K=1 always
-        exists as a shape (it is forced into every warmup ks set), so
+        """Largest dispatchable K <= bound (decode_block.round_to_warm):
         the invariant "no cold K mid-traffic" holds even when the bound
-        is below every warmed variant."""
-        k = max(1, bound)
-        while k & (k - 1):
-            k &= k - 1
-        if self._warm_ks and k not in self._warm_ks:
-            # Non-empty: warmup() forces 1 into the set, and k >= 1.
-            k = max(w for w in self._warm_ks if w <= k)
-        return k
+        is below every warmed variant, K=1 being in every warm set."""
+        return decode_block.round_to_warm(bound, self._warm_ks)
 
     def _advance_capacity(self, slot: "_Slot", used: int):
         """(table_cap, avail): tokens this slot can still store against
