@@ -241,8 +241,10 @@ class EngineConfig:
     # nobody can be waiting for it (every slot live, nothing queued).
     # While an arrival can be waiting the scheduler shortens the block
     # to a warm K that fits a time budget, by the step time it observes
-    # (serving/decode_block.py: BLOCK_BUDGET_MS, choose_k), because an
-    # arrival waits about two blocks and a block is steps x step time.
+    # (serving/decode_block.py: BLOCK_BUDGET_MS = 60 ms, choose_k),
+    # because an arrival waits about two blocks and a block is steps x
+    # step time: 60 is what an arrival can afford, so only a model whose
+    # step is 7.5 ms or less keeps eight steps while a slot is empty.
     # Warm-up compiles K = 1, 2 and this value rounded down to a power
     # of two; the window pool is sized with this value.
     decode_steps_per_dispatch: int = 8

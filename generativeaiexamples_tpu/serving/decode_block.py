@@ -5,11 +5,13 @@ step time it is made with.
 A block is the unit an arrival waits in: a freed slot's next occupant
 stands behind the rest of the running block, the block queued behind it
 (`pipeline_depth` 2), its own prefill program, and then joins the next
-one. `EngineConfig.decode_steps_per_dispatch` is a number of STEPS, and
-a step is 11 ms in one model and 29 ms in another, so the same eight
-steps cost an arrival 94 ms here and 230 ms there. The block is
-therefore held to a time budget WHILE somebody can be waiting for it,
-and is the configured length whenever nobody can.
+one: about two blocks. `EngineConfig.decode_steps_per_dispatch` is a
+number of STEPS, and a step is 11 ms in one model and 29 ms in another,
+so the same eight steps cost an arrival 94 ms here and 230 ms there.
+The block is therefore held to a time budget WHILE somebody can be
+waiting for it: what an ARRIVAL can afford (BLOCK_BUDGET_MS), not what
+eight steps of some model happen to cost. Whenever nobody can be
+waiting a block is the configured length.
 
 Nothing here touches the device, a clock or the engine: tests call the
 function with plain numbers.
@@ -21,10 +23,17 @@ import statistics
 from collections import deque
 from typing import Collection, Deque, Optional
 
-# What one block may cost an arrival, in milliseconds of device time:
-# the block a 13 ms step makes of eight steps (103 ms) fits, the 200-245
-# ms blocks of the 25-29 ms steps do not.
-BLOCK_BUDGET_MS = 125.0
+# What one block may cost an arrival, in milliseconds of device time.
+# An arrival waits about two of them (the rest of the running block and
+# the one queued behind it), so 60 holds the queue's share of a freed
+# slot's empty time near 100-120 ms at ANY step time: a slot that stands
+# empty longer costs more tokens than short blocks do (a two-step
+# block's step reads within about 1 % of an eight-step one's, on one
+# chip and on four: PERF.md section 6, PR 51). With the warm set
+# {1, 2, ceiling} every step over 60 / ceiling ms (7.5 at eight steps)
+# gets the short block; a faster model's eight steps fit and stay, which
+# is why this is a budget and not "always SHORT_K".
+BLOCK_BUDGET_MS = 60.0
 # The block of the low-occupancy regime, which warm-up compiles beside
 # K = 1 and the ceiling: the shortest block a time budget asks for.
 SHORT_K = 2

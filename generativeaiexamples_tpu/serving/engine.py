@@ -24,12 +24,12 @@ concurrency discipline; this one is explicit):
          in-flight block; emit/retire from it. A block runs AT MOST
          decode_steps_per_dispatch steps: the field is the ceiling, and
          serving/decode_block.py::choose_k, the one place a block's
-         length is chosen, shortens it to a warm K that fits a time
-         budget (BLOCK_BUDGET_MS of device time, by the step time the
-         landed blocks read) while an arrival can be waiting for it (an
-         empty slot, a queued request, a prefill whose slot has not
-         decoded yet), since a freed slot's next occupant waits out
-         about two blocks before its slot decodes.
+         length is chosen, shortens it to a warm K that fits what an
+         arrival can afford (BLOCK_BUDGET_MS = 60 ms of device time, by
+         the step time the landed blocks read, whatever a step costs)
+         while one can be waiting for it (an empty slot, a queued
+         request, a prefill whose slot has not decoded yet): a freed
+         slot's next occupant waits out about two blocks.
 
   Latency design (r4; the r3 study's measured failure modes shaped
   it): the blocking fetch itself runs on a reader thread that is

@@ -33,25 +33,39 @@ def _k(configured=8, warm=WARM, live=64, slots=64, arrival_waiting=False,
 
 @pytest.mark.parametrize("case, kwargs, want", [
     # every slot live and nobody queued: K is the configured one at ANY
-    # step time (Kimi-Linear's 222 ms blocks stay)
+    # step time (Kimi-Linear's 222 ms blocks stay, and so do the window's
+    # blocks of Keye's 13.1 ms and SmallThinker's 14.7 ms steps)
     ("full batch, fast step", dict(FULL, step_ms=12.9), 8),
     ("full batch, slow step", dict(FULL, step_ms=27.7), 8),
     ("full batch, absurd step", dict(FULL, step_ms=500.0), 8),
+    ("full batch, 7.6 ms", dict(FULL, step_ms=7.6), 8),
+    ("full batch, 11.75 ms", dict(FULL, step_ms=11.75), 8),
+    ("full batch, 13.1 ms", dict(FULL, step_ms=13.1), 8),
+    ("full batch, 14.7 ms", dict(FULL, step_ms=14.7), 8),
     # an empty slot and eight steps over the budget: the largest warm K
-    # that fits (4 steps of 28.8 ms fit, 4 is not warm: 2)
+    # that fits, never under the short block (2 steps of 28.8 ms fit)
     ("empty slot, 28.8 ms", dict(EMPTY_SLOT, step_ms=28.8), 2),
     ("empty slot, 24.8 ms", dict(EMPTY_SLOT, step_ms=24.8), 2),
-    ("empty slot, 4 is warm", dict(EMPTY_SLOT, step_ms=28.8,
-                                   warm={1, 2, 4, 8}), 4),
-    ("empty slot, no warm-up ran", dict(EMPTY_SLOT, step_ms=28.8,
-                                        warm=()), 4),
+    ("empty slot, 28.8 ms, 4 is warm", dict(EMPTY_SLOT, step_ms=28.8,
+                                            warm={1, 2, 4, 8}), 2),
+    ("empty slot, 28.8 ms, no warm-up ran", dict(EMPTY_SLOT, step_ms=28.8,
+                                                 warm=()), 2),
     # never below the short block on the budget's account, however slow
     ("empty slot, one step over", dict(EMPTY_SLOT, step_ms=200.0), 2),
-    # under the budget: unchanged (103 ms and 94 ms blocks)
-    ("empty slot, 12.9 ms", dict(EMPTY_SLOT, step_ms=12.9), 8),
-    ("empty slot, 11.75 ms", dict(EMPTY_SLOT, step_ms=11.75), 8),
-    ("empty slot, at the budget", dict(EMPTY_SLOT,
-                                       step_ms=BLOCK_BUDGET_MS / 8), 8),
+    # the 12-ms-step models: 4 steps fit, 4 is not warm, so 2
+    ("empty slot, 12.9 ms", dict(EMPTY_SLOT, step_ms=12.9), 2),
+    ("empty slot, 11.75 ms", dict(EMPTY_SLOT, step_ms=11.75), 2),
+    ("empty slot, 14.7 ms (a ramp)", dict(EMPTY_SLOT, step_ms=14.7), 2),
+    ("empty slot, 12.9 ms, 4 is warm", dict(EMPTY_SLOT, step_ms=12.9,
+                                            warm={1, 2, 4, 8}), 4),
+    ("empty slot, 12.9 ms, no warm-up ran", dict(EMPTY_SLOT, step_ms=12.9,
+                                                 warm=()), 4),
+    # a model whose eight steps fit keeps them: the budget is what keeps
+    # a FAST model's blocks long
+    ("empty slot, at the budget (7.5 ms)", dict(
+        EMPTY_SLOT, step_ms=BLOCK_BUDGET_MS / 8), 8),
+    ("empty slot, 7.6 ms", dict(EMPTY_SLOT, step_ms=7.6), 2),
+    ("empty slot, 3 ms", dict(EMPTY_SLOT, step_ms=3.0), 8),
     # no step time yet: unchanged
     ("empty slot, nothing landed", dict(EMPTY_SLOT, step_ms=None), 8),
     ("empty slot, budget infinite", dict(EMPTY_SLOT, step_ms=28.8,
@@ -79,7 +93,8 @@ def test_choose_k(case, kwargs, want):
     assert got & (got - 1) == 0, case             # a power of two
 
 
-@pytest.mark.parametrize("step_ms", [None, 0.5, 11.75, 15.7, 28.8, 70.0])
+@pytest.mark.parametrize("step_ms", [None, 0.5, 7.5, 7.6, 11.75, 15.7, 28.8,
+                                     70.0])
 @pytest.mark.parametrize("arrival", [False, True])
 @pytest.mark.parametrize("live", [1, 16, 17, 64])
 @pytest.mark.parametrize("cap", [0, 1, 2, 4])
@@ -100,6 +115,20 @@ def test_choose_k_is_warm_never_longer_and_only_an_arrival_shortens(
     for pages_allow in (1, 2, 3, 5, 8):
         bounded = round_to_warm(min(got, pages_allow), WARM)
         assert bounded in WARM and bounded <= min(got, pages_allow)
+
+
+@pytest.mark.parametrize("step_ms", [24.2, 26.0, 27.7, 30.2])
+@pytest.mark.parametrize("warm, at_125", [(WARM, 2), ({1, 2, 4, 8}, 4)])
+def test_the_long_step_cells_keep_the_k_a_budget_of_125_gave_them(
+        step_ms, warm, at_125):
+    """Ouro's, A.X-K1's, granite's and Kimi-Linear's steps: with the warm
+    set that warm-up compiles, two steps while a slot is empty under
+    either budget, and the ceiling while none is. (Were K = 4 warm, 125
+    gave them 4 and 60 gives 2: ROADMAP S2 (ii).)"""
+    assert _k(**EMPTY_SLOT, warm=warm, step_ms=step_ms) == 2
+    assert _k(**EMPTY_SLOT, warm=warm, step_ms=step_ms,
+              budget_ms=125.0) == at_125
+    assert _k(**FULL, warm=warm, step_ms=step_ms) == 8
 
 
 def test_step_time_is_the_median_of_the_last_landed_blocks():
