@@ -224,6 +224,15 @@ def llama_config_from_hf(path: str) -> llama_lib.LlamaConfig:
             "LlamaConfig; its configuration is models/window_attn_moe.py's "
             "WindowAttnMoeConfig, and this loader holds no tensor-name map "
             "for it (the router, the expert stacks)")
+    if c.get("model_type") == "afmoe":
+        raise ValueError(
+            f"{path}: model_type 'afmoe' has gated attention over window "
+            "and global layers (layer_types), a norm on both sides of each "
+            "branch and sparse experts behind dense layers: it is no "
+            "LlamaConfig; its configuration is models/gated_window_moe.py's "
+            "GatedWindowMoeConfig, and this loader holds no tensor-name map "
+            "for it (the gate's projection, q_norm, k_norm, the four norms, "
+            "the router and its bias, the expert stacks)")
     family = {}
     if c.get("model_type") in _LOOPED_TYPES:
         family = dict(n_passes=int(c["total_ut_steps"]), post_norms=True)

@@ -347,13 +347,14 @@ def route(cfg: LatentMoeConfig, h, router, bias=None):
 
 
 def moe_branch(cfg: LatentMoeConfig, h, w, experts, layer, use_pallas=None,
-               mask=None):
+               mask=None, tile_rows=None):
     """The feed-forward of an expert block for the normed stream
     h [T, D]: the shared expert plus THIS chip's part of the routed sum.
     `w`: the block's router and shared expert; `experts`: the stacked
     held experts (EXPERT_WEIGHTS, [L, E, ...]) with `layer` the block's
     index in the stack, a Python int or a traced scalar; `mask` [T]
-    leaves tokens out (a decode step's idle slots). Returns (y [T, D],
+    leaves tokens out (a decode step's idle slots); `tile_rows`: the
+    dispatch plan's (None: ops/moe.py's for the pairs). Returns (y [T, D],
     pairs each held expert took [E], the router's choice [T, k])."""
     E, Me = cfg.experts_held, cfg.moe_mlp_dim
     with jax.named_scope("moe.router"):
@@ -363,7 +364,7 @@ def moe_branch(cfg: LatentMoeConfig, h, w, experts, layer, use_pallas=None,
         here = (local >= 0) & (local < E)
         if mask is not None:
             here &= mask[:, None]
-        plan = moe.dispatch_plan(jnp.where(here, local, E), E)
+        plan = moe.dispatch_plan(jnp.where(here, local, E), E, tile_rows)
         x = h[plan.rows]
     with jax.named_scope("moe.experts"):
         gu = moe.grouped_matmul_int8(x, experts["we_gate_up"], layer, plan,

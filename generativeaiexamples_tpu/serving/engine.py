@@ -324,7 +324,9 @@ _COUNTERS = (
     "decode_attn_pages_live", "decode_attn_pages_walked",
     "decode_attn_updates", "decode_attn_rows_skipped", "prefill_rows_live",
     "prefill_rows_bucket", "moe_pairs_routed", "moe_pairs_local",
+    "moe_experts_hit", "moe_expert_steps",
     "window_pages_released", "decode_attn_window_pages_walked",
+    "decode_attn_global_pages_walked",
     "prefill_tokens", "fused_steps", "fused_prefill_tokens",
     "prefill_stall_beats", "fused_sample_dispatches", "prefix_hits",
     "prefix_miss", "prefix_evictions", "prefix_hit_tokens",
@@ -419,9 +421,13 @@ class EngineMetrics:
         self.kv_bytes_per_token = 0
         # Sparse experts (0 for a model without them): token-expert
         # pairs the router chose in decode steps, those that fell on
-        # experts held here and were computed, and the experts held.
+        # experts held here and were computed, and the experts held;
+        # the (expert layer, held expert, step) triples of landed decode
+        # blocks, and those of them in which the expert took a pair.
         self.moe_pairs_routed = 0
         self.moe_pairs_local = 0
+        self.moe_experts_hit = 0
+        self.moe_expert_steps = 0
         self.experts_held = 0
         # What the served architectures count and describe beside these
         # (serving/served_models.py: every entry's `counters` and
@@ -433,12 +439,14 @@ class EngineMetrics:
         # Window rows (0 without them): the window in tokens and the bytes
         # a cached token takes in their pages (gauges; kv_bytes_per_token
         # counts the global rows alone), window pages given back behind a
-        # sliding window, held now (a gauge), and walked by their calls.
+        # sliding window, held now (a gauge), and walked by their calls;
+        # the pages the GLOBAL rows' calls of such a model walked.
         self.window_tokens = 0
         self.window_bytes_per_token = 0
         self.window_pages_released = 0
         self.window_pages_held = 0
         self.decode_attn_window_pages_walked = 0
+        self.decode_attn_global_pages_walked = 0
         self.busy_slots_acc = 0
         # Speculative decoding: committed tokens vs slot-steps, for the
         # acceptance-rate gauge (1.0 = no drafts accepted, k+1 = all).
@@ -2176,9 +2184,11 @@ class LLMEngine:
         one kind of row would have seen; b = pages x rows the live slots
         hold in both pools over what one table for every row would hold;
         aux = the window pages walked, the calls that walked them, a call
-        being one window layer's of one step, and the softmax updates they
-        were folded into) and, a live slot, its sequence and the position
-        no later step reads behind; None for every other model."""
+        being one window layer's of one step, the softmax updates they
+        were folded into, and the pages the global rows' calls walked, a
+        live slot's whole context a call, with those calls) and, a live
+        slot, its sequence and the position no later step reads behind;
+        None for every other model."""
         if self.window_allocator is None:
             return None
         wr = self.cfg.window_rows
@@ -2191,13 +2201,16 @@ class LLMEngine:
                                         fold=self._attn_fold(maxw))
         walked, updates = pages * wr.n_window, updates * wr.n_window
         self.metrics.decode_attn_window_pages_walked += walked
+        glob = page_counts(ctx, ps, self.max_pages)[0] * wr.n_global
+        self.metrics.decode_attn_global_pages_walked += glob
         seqs = [self.slots[i].seq for i in active]
         held = sum(wr.n_global * len(q.pages)
                    + wr.n_window * len(q.window_pages) for q in seqs)
         one = (wr.n_global + wr.n_window) * sum(len(q.pages) for q in seqs)
         event = (float(seen.sum()) / float(self.cfg.n_layers * ctx.sum()),
                  held / one, f"window_pages={walked} calls={K * wr.n_window} "
-                 f"updates={updates}")
+                 f"updates={updates} global_pages={glob} "
+                 f"global_calls={K * wr.n_global}")
         # the block's last step has length `live + K - 1`; the next
         # block's first is one longer, and its window starts there
         return event, [(q, int(n) + K - wr.window)
@@ -2217,14 +2230,20 @@ class LLMEngine:
         """A landed decode block of a model with experts carries, below
         its token rows, the pairs each held expert of each expert block
         took in each step (engine_model.expert_load_rows): count them,
-        write the block's `moe_load` event, hand back the token rows."""
+        write the block's `moe_load` event (aux: the (expert layer, held
+        expert, step) triples in which the expert took a pair, of all the
+        block's), hand back the token rows."""
         load = host[-self._load_rows:, 1:]        # [Lm * E, K]
         pairs = int(load.sum())
+        hit = int(np.count_nonzero(load))
         self.metrics.moe_pairs_local += pairs
+        self.metrics.moe_experts_hit += hit
+        self.metrics.moe_expert_steps += load.size
         busiest = float(load.sum(axis=1).max())   # one expert of one block
         self.flight.record_event(
             EV_MOE_LOAD, t_ready, a=pairs / (fl.K * self.cfg.n_moe_layers),
-            b=busiest * self._load_rows / pairs if pairs else 0.0)
+            b=busiest * self._load_rows / pairs if pairs else 0.0,
+            aux=f"hit={hit} of={load.size}")
         return host[:-self._load_rows]
 
     # graftlint: hot-path

@@ -88,7 +88,9 @@ EV_KV_TRANSFER = 18
 # Sparse experts: one a landed decode block of a model that has them
 # (scheduler thread). a = token-expert pairs computed on the experts
 # held here, per step and expert layer; b = the pairs of the busiest
-# held expert of any one layer over the mean (1.0 = even load).
+# held expert of any one layer over the mean (1.0 = even load); aux =
+# "hit=<n> of=<m>", the (expert layer, held expert, step) triples of the
+# block in which the expert took a pair, and all of them.
 EV_MOE_LOAD = 19
 # One a program the device finished (`ProgramLedger`, below), written
 # by the scheduler thread when the completion is known. ts = t_ready;
@@ -113,9 +115,11 @@ EV_SPARSE_SELECT = 22
 # attention calls see over layers x context of its live slots (what one
 # kind of row would have seen); b = pages x rows the live slots hold in
 # both pools over what one table for every row would hold; aux =
-# "window_pages=<n> calls=<m> updates=<u>", the pages (a page a live slot
-# and call) the window rows' kernel calls walked in the block, those
-# calls, and the softmax updates the pages were folded into.
+# "window_pages=<n> calls=<m> updates=<u> global_pages=<n>
+# global_calls=<m>", the pages (a page a live slot and call) the window
+# rows' kernel calls walked in the block, those calls, the softmax
+# updates the pages were folded into, and the pages the GLOBAL rows'
+# calls walked (a live slot's whole context a call) with those calls.
 EV_WINDOW_CACHE = 23
 # Recurrent state beside a latent row: one a landed decode block of a
 # model with both (scheduler thread), from the lengths the host

@@ -24,7 +24,7 @@ _ENTRY_MODULES = tuple(
     f"generativeaiexamples_tpu.serving.{name}" for name in (
         "engine_model",  # the Llama entry, beside the walk that is its bodies
         "served_latent", "served_hybrid", "served_sparse", "served_window",
-        "served_linear"))
+        "served_linear", "served_gated_window"))
 
 
 # A lane an architecture has no form for, beyond engine._ONE_PASS_LANES:

@@ -992,3 +992,108 @@ def test_the_window_decode_program_runs_its_kernels_under_their_names(chip):
         assert text.count(f'kernel_name = "{kernel}"') == bodies, kernel
     assert text.count("call @paged_attention_int8_window") == 9
     assert text.count("call @paged_attention_int8(") == 3
+
+
+# -- gated attention over window and global rows beside a share of the
+# experts: the configuration's own shapes (PR 52). The step programs of
+# `trinity-large-preview-int8-ep8`, COMPILED for the chip from its
+# architecture entry's `compile_shapes` with kernels on: both pools, the
+# 20,480-row prefill program (its token-wise parts in five chunks of rows,
+# the prompt's flash attention under the window and without it, the
+# grouped matmul's sixth shape in tiles of 64 rows) and the decode blocks
+# of 1, 2 and 8 steps (tables of 224 and of 34 pages, a score tile of
+# 8 x 6); the compiler's own count of a program's arguments and
+# temporaries stays under what a v5e offers.
+V5E_BYTES = 16.9e9
+
+
+@pytest.fixture(scope="module")
+def gated_window(chip):
+    import json
+
+    from benchmark import architectures
+    from benchmark.harness import system
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-large-preview-int8-ep8.json")) as fh:
+        config = json.load(fh)
+    ecfg = system.engine_config(config)
+    return (ecfg,) + architectures.load(config).compile_shapes(
+        config, ecfg, [next(iter(chip.device_set))])
+
+
+def _program_bytes(lowered):
+    m = lowered.compile().memory_analysis()
+    return (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_the_gated_window_pools_are_the_configurations(gated_window):
+    import math
+
+    from generativeaiexamples_tpu.serving.paged_attention_int8 import (
+        SPLIT_KV_BYTES)
+    ecfg, mcfg, params, pool, mesh = gated_window
+    assert mesh is None and tuple(mcfg.window_rows) == (4096, 2, 7)
+    assert pool.glob.kv.shape == (2, 2, 8, 7232, 128, 128)
+    assert pool.win.kv.shape == (2, 7, 8, 1123, 128, 128)
+    # each half of each group stays under the one-descriptor limit
+    assert math.prod(pool.glob.kv.shape[1:]) < SPLIT_KV_BYTES
+    assert math.prod(pool.win.kv.shape[1:]) < SPLIT_KV_BYTES
+    held = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree.leaves((params, pool)))
+    # weights and pools fill 85 % of the chip: 14.4 GB of 16.9
+    assert 0.80 * V5E_BYTES < held < 0.86 * V5E_BYTES
+
+
+@pytest.mark.parametrize("n_steps", [1, 2, 8])
+def test_the_gated_window_decode_block_compiles_for_v5e(chip, gated_window,
+                                                        n_steps):
+    from generativeaiexamples_tpu.serving import engine_model as em
+    from generativeaiexamples_tpu.serving.kv_cache import WindowTables
+
+    ecfg, mcfg, params, pool, _ = gated_window
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, maxp = ecfg.max_batch_size, ecfg.max_seq_len // ecfg.page_size
+    assert (slots, maxp) == (32, 224)
+    tables = WindowTables(arr((slots, maxp), I32), arr((slots, 34), I32),
+                          arr((slots,), I32))
+    lowered = em.decode_multi_step.lower(
+        params, mcfg, pool, arr((slots,), I32), tables,
+        arr((slots,), I32), arr((slots,), jnp.bool_), arr((slots,), F32),
+        arr((slots,), F32), arr((slots,), I32), arr((2,), jnp.uint32),
+        n_steps, True, sampling_flags=(True, False, False))
+    if n_steps == 1:
+        text = lowered.as_text()
+        for kernel, bodies in (("paged_attention_int8_window", 1),
+                               ("_int8_kernel", 1), ("kv_append_int8", 2),
+                               ("moe_grouped_matmul_int8", 16)):
+            assert text.count(f'kernel_name = "{kernel}"') == bodies, kernel
+        assert text.count("call @paged_attention_int8_window") == 7
+        assert text.count("call @paged_attention_int8(") == 2
+    assert _program_bytes(lowered) < V5E_BYTES
+
+
+def test_the_gated_window_longest_prefill_compiles_for_v5e(chip,
+                                                           gated_window):
+    from generativeaiexamples_tpu.serving import engine_model as em
+    from generativeaiexamples_tpu.serving.kv_cache import WindowTables
+
+    ecfg, mcfg, params, pool, _ = gated_window
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    bucket = max(ecfg.prefill_buckets)
+    assert bucket == 20480
+    tables = WindowTables(arr((1, bucket // 128), I32),
+                          arr((1, bucket // 128), I32))
+    lowered = em.prefill_batch_step.lower(
+        params, mcfg, pool, arr((1, bucket), I32), arr((1,), I32), tables,
+        arr((1,), F32), arr((1,), F32), arr((1,), I32),
+        arr((2,), jnp.uint32), True, sampling_flags=(True, False, False))
+    assert _program_bytes(lowered) < V5E_BYTES

@@ -213,7 +213,7 @@ def _greedy(params, ids, n):
 
 
 def test_the_entry_is_one_line_and_the_engine_names_no_architecture():
-    assert _ENTRY_MODULES[-1].endswith(".served_linear")
+    assert sum(m.endswith(".served_linear") for m in _ENTRY_MODULES) == 1
     entry = served(CFG)
     assert entry.prefill is served_linear.prefill and entry.state_slots
     assert not entry.long_prompts and not entry.direct_qkv
