@@ -322,7 +322,8 @@ _COUNTERS = (
     "decode_steps_direct_qkv",
     "decode_steps_kernel_append", "decode_steps_fused_append",
     "decode_attn_pages_live", "decode_attn_pages_walked",
-    "decode_attn_updates", "decode_attn_rows_skipped", "prefill_rows_live",
+    "decode_attn_updates", "decode_attn_grid_steps",
+    "decode_attn_rows_skipped", "prefill_rows_live",
     "prefill_rows_bucket", "moe_pairs_routed", "moe_pairs_local",
     "moe_experts_hit", "moe_expert_steps",
     "window_pages_released", "decode_attn_window_pages_walked",
@@ -396,6 +397,12 @@ class EngineMetrics:
         self.decode_attn_pages_live = 0
         self.decode_attn_pages_walked = 0
         self.decode_attn_updates = 0
+        # ... and the grid steps of a step's call: one a live row (one
+        # with nobody live). busy_slots_acc over it is the rows a grid
+        # step serves: 1.0 while every row takes a step of its own
+        # (PERF.md section 6, PR 53: the step itself is 0.03 us of a
+        # row's 0.33).
+        self.decode_attn_grid_steps = 0
         # Over the same steps again: the idle rows that both int8 pool
         # kernels left out, (B - live slots) a step of a program that
         # hands them its `active` mask (decode_multi_step): how often
@@ -2197,8 +2204,8 @@ class LLMEngine:
         ctx = live[None, :] + np.arange(K)[:, None]          # [K, n_live]
         seen = wr.n_global * ctx + wr.n_window * np.minimum(ctx, wr.window)
         maxw = self._window_table_pages
-        pages, _, updates = page_counts(ctx - win_base[active], ps, maxw,
-                                        fold=self._attn_fold(maxw))
+        pages, _, updates, _ = page_counts(ctx - win_base[active], ps, maxw,
+                                           fold=self._attn_fold(maxw))
         walked, updates = pages * wr.n_window, updates * wr.n_window
         self.metrics.decode_attn_window_pages_walked += walked
         glob = page_counts(ctx, ps, self.max_pages)[0] * wr.n_global
@@ -3672,7 +3679,7 @@ class LLMEngine:
             # decode_multi_step's kernels walk the live rows alone (the
             # fused and the spec-state lanes hand them no mask)
             masked = engine_model.masks_pool_kernels(plan)
-            live_pages, walked, updates = page_counts(
+            live_pages, walked, updates, grid_steps = page_counts(
                 lengths + np.arange(K)[:, None] * active_mask,
                 self.pool.page_size, self.max_pages,
                 mask=active_mask if masked else None,
@@ -3680,6 +3687,7 @@ class LLMEngine:
             self.metrics.decode_attn_pages_live += live_pages
             self.metrics.decode_attn_pages_walked += walked
             self.metrics.decode_attn_updates += updates
+            self.metrics.decode_attn_grid_steps += grid_steps
             if masked:
                 self.metrics.decode_attn_rows_skipped += (
                     B - len(active)) * K

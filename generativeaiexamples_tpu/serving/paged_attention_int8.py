@@ -44,24 +44,28 @@ fori_loop over blocks of PAGES_PER_BLOCK pages. Each page's
 k AND v move HBM->VMEM as a SINGLE DMA descriptor strided across the
 (KH, 2) axes, and both scale rows as one more — 2 descriptors per page
 instead of the 4 an unfused pool needs and the 8 a per-head grid pays.
-The copies of the next BLOCKS_AHEAD blocks, this row's and then the next
-LIVE rows', are in flight while the current one computes (cross-grid-step
-buffering). A block's live pages are folded into the online softmax in
+The copies of the next `blocks_ahead` blocks (about 3 MB: 2 blocks of 541
+KB pages, 3 of 270 KB ones, 4 of smaller ones), this row's and then the
+next LIVE rows', are in flight while the current one computes
+(cross-grid-step buffering), each asked for once the block in its buffer
+was multiplied. A block's live pages are folded into the online softmax in
 ONE update (`_fold_block`, which serving/paged_attention_sparse.py runs
 too): all their score tiles first, one maximum, one `alpha`, one sum, one
 rescaled accumulator, where a page at a time made a chain of those a
-page, each waiting for the one before. Alone on a v5e, parent (an update
-a page) -> this kernel, us a call (PERF.md section 5, PR 45;
-scripts/measure_paged_attention.py and scripts/check_window_on_chip.py
---phases kernels, each with --parent, read them again):
+page, each waiting for the one before. Alone on a v5e, us a call: an
+update a page -> an update a block (PERF.md section 5, PR 45) -> a block
+asked for after the one in its buffer, `blocks_ahead` of them in flight
+(PR 53); scripts/measure_paged_attention.py and
+scripts/check_window_on_chip.py --phases kernels, each with --parent,
+read them again:
 
-  pool (score tile KH x G; page)      closed mix     every row full   3 long rows
-  Mistral-7B   (8 x 4; 270 KB)        60.5 -> 55.7   459.5 -> 459.2   17.3 -> 16.9
-  Ouro         (16 x 1; 541 KB)       53.4 -> 53.2    94.0 ->  93.8   11.1 -> 11.0
-  a chip of TP4 (2 x 4; 68 KB)        46.9 -> 35.4    75.0 ->  45.2    4.1 ->  2.7
+  pool (score tile KH x G; page)  closed mix            every row full            3 long rows
+  Mistral-7B   (8 x 4; 270 KB)    60.5 -> 55.7 -> 52.3  459.5 -> 459.2 -> 459.2  17.3 -> 16.9 -> 16.9
+  Ouro         (16 x 1; 541 KB)   53.4 -> 53.2 -> 53.2   94.0 ->  93.8 ->  93.8  11.1 -> 11.0 -> 11.0
+  a chip of TP4 (2 x 4; 68 KB)    46.9 -> 35.4 -> 31.2   75.0 ->  45.2 ->  45.0   4.1 ->  2.7 ->  2.7
   SmallThinker (4 x 7; 135 KB), 64 rows at 5k | 9k | 16k:
-    window rows, tables of 34   578.4 | 578.0 | 578.9 -> 406.4 | 406.8 | 408.0
-    global rows, tables of 128  735.8 | 1315.4 | 2327.7 -> 506.3 | 897.2 | 1581.4
+    window rows, tables of 34   578.4 | 578.0 | 578.9 -> 406.4 | 406.8 | 408.0 -> 386.2 | 387.1 | 388.1
+    global rows, tables of 128  735.8 | 1315.4 | 2327.7 -> 506.3 | 897.2 | 1581.4 -> 505.1 | 892.7 | 1575.9
 
 The smaller the page, the less its copy hid the chain: 0.274-0.291 us a
 page became 0.181-0.191 us a page and 0.375 us a row at SmallThinker's
@@ -69,7 +73,12 @@ shape (84-86 % of the HBM's rate by a page's bytes), a quarter to two
 fifths of a call went at TP4's, a twelfth of Mistral-7B's closed mix, and
 nothing where rows of whole blocks of 270 KB pages were the bytes'
 already. Folding 2 or 3 pages an update reads between the two at every
-shape, so `fold_pages` is the block's width for every tile.
+shape, so `fold_pages` is the block's width for every tile. What a
+closed-mix call still takes beside its bytes at 2 KV heads (31.2 us for
+11.7 us of pages) is the rows' own chains of dot, maximum, exp and dot
+and the scalar work of their descriptors, not their grid steps
+(`_int8_kernel`, rule 3; PERF.md section 6, PR 53, says what was tried
+against it).
 
 A live row has n = clip(cdiv(length + q_rep - 1, ps), 1, maxp) pages, and
 an idle one (a decode slot nobody occupies: `active` False) none: it is
@@ -188,14 +197,18 @@ def _tree_keep(pos, length, jrow, r, tree):
 SPLIT_KV_BYTES = 2 ** 32
 
 # Pages a block copies together and multiplies in one unrolled body, and
-# blocks whose copies are in flight while one is multiplied (VMEM holds
-# one buffer more). Read on a v5e at the cells' shapes (PERF.md section
-# 5, PR 34): 4 and 5 pages read alike and 8 a tenth slower on rows of 20
-# pages (a block waits for all its copies), and 4 makes the fewest
-# bodies; a second block in flight takes a fifth off Mistral-7B's mix
-# and an eighth off Ouro's, a third nothing more.
+# the fewest blocks whose copies are in flight while one is multiplied
+# (`blocks_ahead` gives a shape's; VMEM holds one buffer more). Read on a
+# v5e at the cells' shapes (PERF.md section 5, PR 34): 4 and 5 pages read
+# alike and 8 a tenth slower on rows of 20 pages (a block waits for all
+# its copies), and 4 makes the fewest bodies; a second block in flight
+# takes a fifth off Mistral-7B's mix and an eighth off Ouro's.
 PAGES_PER_BLOCK = 4
 BLOCKS_AHEAD = 2
+# The bytes `blocks_ahead` keeps on their way, and the most blocks it
+# gives (PERF.md section 5, PR 53).
+BYTES_IN_FLIGHT = 3 << 20
+MAX_BLOCKS_AHEAD = 4
 # Pages folded into one online-softmax update (`fold_pages`): the block's.
 FOLD_PAGES = 4
 # Rows of an int8 tile: what one DMA descriptor can address in the pool,
@@ -249,6 +262,29 @@ def fold_pages(kv_heads: int, group: int, ppcb: int) -> int:
     is a constant and the tile decides nothing yet. A tile that reads otherwise gets its
     width here, and nowhere else: no caller sets it."""
     return min(FOLD_PAGES, ppcb)
+
+
+def blocks_ahead(kv_heads: int, page_size: int, head_dim: int, ppcb: int,
+                 scale_bytes: int = 4) -> int:
+    """The blocks whose copies `_int8_kernel` keeps in flight while one is
+    multiplied, from the bytes of a block (`ppcb` pages of 2 x kv_heads x
+    page_size x head_dim codes and their scale rows): as many as put
+    BYTES_IN_FLIGHT on their way, never fewer than BLOCKS_AHEAD nor more
+    than MAX_BLOCKS_AHEAD. By a page's bytes at four pages a block: 68 KB
+    (2 KV heads, a chip of TP4) and 135 KB (4, SmallThinker) 4 blocks, 270
+    KB (8, Mistral-7B, granite, Trinity) 3, 541 KB (16, Ouro) 2; the
+    buffers, one more, are 1.4, 2.7, 4.3 and 6.5 MB of VMEM. Read on a v5e
+    (PERF.md section 5, PR 53) once a block was asked for AFTER the one in
+    its buffer was multiplied (`_int8_kernel`, rule 3): a third block takes
+    2.4 us off Mistral-7B's closed mix of 54.7 and a fourth nothing more;
+    four for two take 19 us off SmallThinker's window rows' 405; Ouro's
+    calls read the same at 2, 3 and 4 (a block is 2.2 MB: two are 5.3 us
+    of the HBM's time), and so do a chip of TP4's, whose rows of 150 KB
+    wait for their arithmetic, not for their copies. Like `fold_pages`,
+    the kernel's own rule: no caller sets it."""
+    block = ppcb * 2 * kv_heads * page_size * (head_dim + scale_bytes)
+    return min(max(-(-BYTES_IN_FLIGHT // block), BLOCKS_AHEAD),
+               MAX_BLOCKS_AHEAD)
 
 
 def _fold_block(q, page, count: int, carry):
@@ -390,7 +426,18 @@ def _int8_kernel(
        a block is multiplied, the copies of the `ahead` blocks after it
        (this row's, then the next live rows') are in flight in the other
        buffers; what was asked for and taken persists in SMEM across
-       grid steps.
+       grid steps. A block is asked for AFTER the one in its buffer was
+       multiplied, not at the head of the block before (PR 53): the
+       descriptors' scalar work (a table entry and two descriptors a
+       page, a switch a block) then runs while the row's last results
+       drain, where at the head it stood between the row's wait
+       and its first dot: 4.2 of the 35.4 us of a closed-mix call at 2
+       KV heads (64 rows, 141 pages, 11.7 us of bytes). The rest of what
+       a live row costs beside its pages there is its chain of dot,
+       maximum, exp and dot and this scalar work, NOT the grid step: a
+       walk that is a loop of the kernel's own, q and o whole in VMEM,
+       read 33.5 us where the grid read 35.4 (PERF.md section 6, PR 53).
+       `ahead` is `blocks_ahead` of a block's bytes.
     4. Only the live rows, at no scalar test more for one of them (25 ns
        each, PR 34): the chain of copies ends at n_live where it ended
        at B, and so does the grid, so there is no dead step to guard: no
@@ -509,7 +556,9 @@ def _int8_kernel(
     def _first():
         for field in range(4):  # the first block goes into buffer 0
             state[field] = 0
-        for _ in range(ahead):
+
+        @pl.loop(0, ahead + 1)  # a loop: each ask is ppcb bodies of copies
+        def _(_):
             ask()
 
     length = lengths_ref[b]
@@ -570,7 +619,6 @@ def _int8_kernel(
 
     def body(i, carry):
         slot = state[_TAKE_SLOT]
-        ask()  # into the buffer the block before this one was taken from
 
         def page(j):
             """A live page of the block and its mask, all kv heads
@@ -618,6 +666,7 @@ def _int8_kernel(
                 [block(c, last=c < ppcb) for c in range(1, ppcb + 1)]
                 + [block(ppcb, last=True)], carry)
         state[_TAKE_SLOT] = after(slot)
+        ask()  # into the buffer this block was taken from (rule 3)
         return carry
 
     init = (jnp.full((KH, G, 1), NEG_INF, jnp.float32),
@@ -630,25 +679,32 @@ def _int8_kernel(
 
 def page_counts(lengths, page_size: int, max_pages: int,
                 block: int | None = None, mask=None,
-                fold: int | None = None) -> tuple[int, int, int]:
-    """On the host, for a batch's `lengths` (numpy, any shape) and its
-    `mask` of live rows (broadcast against them; None: every row): the
+                fold: int | None = None) -> tuple[int, int, int, int]:
+    """On the host, for a batch's `lengths` (numpy, any shape: a call a
+    row of its last axis) and its `mask` of live rows (broadcast against
+    them; None: every row): the
     pages the kernel copies and multiplies (each live row's n, an idle
     row's none), what whole blocks over EVERY row would cover, which
     is what it walked before it stopped at n and at the live rows (an
-    idle row walked a block), and the softmax updates it makes for the
+    idle row walked a block), the softmax updates it makes for the
     live rows' pages at `fold` pages an update (`fold_pages` of the
-    caller's tile; None: a whole block), each block's live pages apart.
-    The engine's `decode_attn_pages_live` / `decode_attn_pages_walked` /
-    `decode_attn_updates`."""
+    caller's tile; None: a whole block), each block's live pages apart,
+    and the grid steps of those calls: one a LIVE row (rule 4 of
+    `_int8_kernel`), and one for a call with nobody live. The engine's
+    `decode_attn_pages_live` / `decode_attn_pages_walked` /
+    `decode_attn_updates` / `decode_attn_grid_steps`."""
     block = min(block or PAGES_PER_BLOCK, max_pages)
     fold = min(fold or block, block)
     n = np.clip(-(-np.asarray(lengths, np.int64) // page_size), 1, max_pages)
     walked = np.minimum(-(-n // block) * block, max_pages)
+    live = np.ones(n.shape, bool)
     if mask is not None:
-        n = n * np.asarray(mask, bool)
+        live = live & np.asarray(mask, bool)
+        n = n * live
     updates = n // block * -(-block // fold) + -(-(n % block) // fold)
-    return int(n.sum()), int(walked.sum()), int(updates.sum())
+    grid_steps = np.maximum(live.sum(axis=-1), 1)
+    return (int(n.sum()), int(walked.sum()), int(updates.sum()),
+            int(grid_steps.sum()))
 
 
 _STATIC = ("scale", "pages_per_compute_block", "q_rep", "tree", "interpret",
@@ -750,7 +806,7 @@ def _paged_attention_int8(
 
     if split_kv is None:  # from the pool's shape alone
         split_kv = L * KH * P * ps * Hd >= SPLIT_KV_BYTES
-    ahead = BLOCKS_AHEAD
+    ahead = blocks_ahead(KH, ps, Hd, ppcb, kv_scales.dtype.itemsize)
     assert starts is None or (q_rep == 1 and tree is None), (q_rep, tree)
     assert new is None or (starts is None and q_rep == 1 and tree is None), (
         "one new row a slot, of a plain decode step")

@@ -4,7 +4,7 @@
 
     chiprun -- timeout 3000 python3 scripts/check_window_on_chip.py \
         [--phases buckets,kernels,compare,step] [--seeds 1] \
-        [--buckets 4608,8192,8704] [--parent DIR] [--folds 1,2]
+        [--buckets 4608,8192,8704] [--parent DIR] [--folds 1,2] [--aheads 2]
 
 Four phases, one JSON line each result (also chiprun_out/window/check.jsonl):
 
@@ -73,6 +73,9 @@ def main() -> int:
     ap.add_argument("--folds", default="",
                     help="pages a softmax update `kernels` tries besides "
                          "the kernel's own rule")
+    ap.add_argument("--aheads", default="",
+                    help="blocks in flight `kernels` tries besides the "
+                         "kernel's own rule")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     if args.rehearse:
@@ -207,11 +210,14 @@ def main() -> int:
                         jnp.bfloat16)
         live = pa8.every_row(B)
         page_bytes = entry.kv_bytes_per_token_layer(config) * ps
-        forms = {"change": (pa8, None)}       # name: (module, fold)
+        forms = {"change": (pa8, None, None)}  # name: (module, fold, ahead)
         if args.parent:
-            forms = {"parent": (load_kernel(args.parent), None), **forms}
+            forms = {"parent": (load_kernel(args.parent), None, None),
+                     **forms}
         for fold in sorted({int(x) for x in args.folds.split(",") if x}):
-            forms[f"change_f{fold}"] = (pa8, fold)
+            forms[f"change_f{fold}"] = (pa8, fold, None)
+        for ahead in sorted({int(x) for x in args.aheads.split(",") if x}):
+            forms[f"change_a{ahead}"] = (pa8, None, ahead)
 
         def calls_of(mod):
             """The step's two attention calls through `mod`'s kernel, a
@@ -242,7 +248,7 @@ def main() -> int:
                     ("global", global_calls, wr.n_global))
 
         G = mcfg.n_heads // mcfg.n_kv_heads
-        programs = {form: calls_of(mod) for form, (mod, _) in forms.items()}
+        programs = {form: calls_of(mod) for form, (mod, *_) in forms.items()}
         for ctx in contexts:
             # contexts spread a page either side, so that starts fall
             # anywhere inside a page
@@ -252,13 +258,14 @@ def main() -> int:
             ln = jnp.asarray(lengths)
             rows = {"window": (lengths - np.asarray(tables.base), maxw),
                     "global": (lengths, maxp)}
-            for form, (mod, fold) in forms.items():
+            for form, (mod, fold, ahead) in forms.items():
                 for name, fn, calls in programs[form]:
-                    with folding(pa8, fold):  # a program's first call traces
+                    # a program's first call traces
+                    with folding(pa8, fold, ahead):
                         sec = timed(fn, state["pool"], q, tables, ln,
                                     reps=20) / calls
                     row_lengths, width = rows[name]
-                    pages, _, updates = pa8.page_counts(
+                    pages, _, updates, _ = pa8.page_counts(
                         row_lengths, ps, width, fold=pages_an_update(
                             mod, fold, mcfg.n_kv_heads, G,
                             min(pa8.PAGES_PER_BLOCK, width)))

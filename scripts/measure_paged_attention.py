@@ -3,35 +3,46 @@
 on the chip, over pools of the benchmark cells' real shapes:
 
     chiprun -- python3 scripts/measure_paged_attention.py \
-        [--shapes mistral,ouro,tp4] [--blocks 4,5,8] [--folds 1,2] \
-        [--parent DIR] [--append]
+        [--shapes mistral,ouro,tp4,granite] [--blocks 4,5,8] \
+        [--folds 1,2] [--aheads 2,4] [--parent DIR[,DIR2]] [--append]
 
     mistral  32 rows,  8 KV heads of 32, 64 slots,  768 pages, tables of 20
     ouro    192 rows, 16 KV heads of 16, 32 slots,  112 pages, tables of 4
             (a half of the pool is over SPLIT_KV_BYTES: split descriptors)
     tp4      40 rows,  2 KV heads of 8,  64 slots, 3072 pages, tables of 4
             (one chip's share of Mistral-Small-24B under TP=4, no mesh)
+    granite   1 row,   8 KV heads of 32, 96 slots, 1056 pages, tables of 11
+            (granite-4.0-h-small's one attention layer of a period; `half`
+            is its cell's mean context: the call whose roofline share that
+            cell reads at 100 %)
 
 For every shape it compiles one program a form that calls the kernel once
-a cache row, as a decode step does, and runs it over six sets of lengths
+a cache row, as a decode step does, and runs it over seven sets of lengths
 and live rows: `one` (every row idle, as the engine sends an idle slot:
 length 1, `active` False; what an idle slot costs), `mix` (the closed
 cells' contexts: a prompt of 32-128 plus a uniform share of an answer of
 192-320, mean about 208, every row live), `mix60` (the same with 60 of 64
 rows live, or 30 of 32: a closed cell's occupancy), `full` (every row at
 the table's width: what the guards cost where nothing is dead), `chain`
-(three rows of 1,700 tokens, the rest idle: `rag.chain-open`) and `open`
+(three rows of 1,700 tokens, the rest idle: `rag.chain-open`), `open`
 (three rows of 250 tokens, two pages each, apart among idle ones: the
 open mix, `mistral7b.chat-open`; the append's side of both mixes is
-scripts/measure_kv_append.py `--live 3,60,64`). The
+scripts/measure_kv_append.py `--live 3,60,64`) and `half` (every row at
+half the table's width: rows of a block and a bit). The
 forms: the kernel of this tree at each of `--blocks` pages a block, given
 the step's mask (`change4`: the served one), at the first of those also
 with each of `--folds` pages a softmax update in place of what the
 kernel's own rule gives the shape (`change4f1` is a chain a page; the
-probe patches `fold_pages`, which no caller can set), and with
+probe patches `fold_pages`, which no caller can set), likewise with each of
+`--aheads` blocks' copies in flight in place of `blocks_ahead`'s
+(`change4a2`: the two every shape had until PR 53), and with
 `--parent DIR` (a checkout of another commit,
-e.g. `git archive` into .scratch/parent) that tree's kernel as it is; a
-parent that takes no mask computes every row, an idle one at length 1.
+e.g. `git archive` into .scratch/parent; several with commas, `parent`,
+`parent1`, ...: any directory that holds a kernel file under this tree's
+path will do, which is how a kernel not in the tree is read beside this
+one) that tree's kernel as it is; a parent that takes no mask computes
+every row, an idle one at length 1 (the entry points' signatures have not
+changed since the mask, PR 41).
 Times are the device's: `--reps` executions by the host's clock around
 `block_until_ready`, and three traced ones summed by operation. It also
 reads how far each form's output is from the first form's, over the live
@@ -63,26 +74,31 @@ APPEND = "generativeaiexamples_tpu/serving/kv_append_int8.py"
 # name: (cache rows, kv heads, query heads, slots, pages, table width)
 SHAPES = {"mistral": (32, 8, 32, 64, 768, 20),
           "ouro": (192, 16, 16, 32, 112, 4),
-          "tp4": (40, 2, 8, 64, 3072, 4)}
+          "tp4": (40, 2, 8, 64, 3072, 4),
+          "granite": (1, 8, 32, 96, 1056, 11)}
 TINY = {"mistral": (2, 2, 4, 4, 9, 5), "ouro": (3, 2, 2, 4, 7, 4),
-        "tp4": (2, 1, 2, 4, 9, 4)}
+        "tp4": (2, 1, 2, 4, 9, 4), "granite": (1, 2, 4, 6, 9, 7)}
 
 
 @contextlib.contextmanager
-def folding(pa8, width):
+def folding(pa8, width, ahead=None):
     """The kernel module's programs traced inside fold `width` pages into a
-    softmax update whatever `fold_pages` gives their shape (None: as it
-    is); a probe's way in, since the rule is no caller's to set."""
-    rule = pa8.fold_pages
+    softmax update whatever `fold_pages` gives their shape, and keep
+    `ahead` blocks' copies in flight whatever `blocks_ahead` gives it
+    (None: as it is); a probe's way in, since neither rule is a caller's
+    to set."""
+    rules = pa8.fold_pages, pa8.blocks_ahead
     programs = (pa8.paged_attention_int8, pa8.paged_attention_int8_window)
     if width is not None:
         pa8.fold_pages = lambda kv_heads, group, ppcb: min(width, ppcb)
+    if ahead is not None:
+        pa8.blocks_ahead = lambda *shape: ahead
     for fn in programs:
         fn.clear_cache()
     try:
         yield
     finally:
-        pa8.fold_pages = rule
+        pa8.fold_pages, pa8.blocks_ahead = rules
         for fn in programs:
             fn.clear_cache()
 
@@ -105,6 +121,14 @@ def pages_an_update(mod, fold, kv_heads: int, group: int, ppcb: int) -> int:
                                                             ppcb)
 
 
+def blocks_in_flight(mod, ahead, kv_heads: int, ppcb: int) -> int:
+    """What a form keeps in flight: the probe's `ahead`, else the rule of
+    the form's own module; a checkout from before the rule had a
+    constant."""
+    return ahead or getattr(mod, "blocks_ahead", lambda *_: mod.BLOCKS_AHEAD)(
+        kv_heads, PS, HD, ppcb)
+
+
 def length_sets(rng, slots: int, width: int) -> dict:
     """name: (lengths, live rows). An idle row is what the engine sends:
     length 1, `active` False."""
@@ -123,7 +147,8 @@ def length_sets(rng, slots: int, width: int) -> dict:
             "mix60": ([x if a else 1 for x, a in zip(mix, live60)], live60),
             "full": ([top] * slots, every),
             "chain": (chain, [x > 1 for x in chain]),
-            "open": (open_mix, [x > 1 for x in open_mix])}
+            "open": (open_mix, [x > 1 for x in open_mix]),
+            "half": ([top // 2] * slots, every)}
 
 
 def pool_maker(shape):
@@ -172,8 +197,12 @@ def main() -> int:
     ap.add_argument("--folds", default="",
                     help="pages a softmax update to try in place of the "
                          "rule's, at the first of --blocks")
+    ap.add_argument("--aheads", default="",
+                    help="blocks in flight to try in place of the rule's, "
+                         "at the first of --blocks")
     ap.add_argument("--parent", default=None,
-                    help="a checkout whose kernel is measured beside it")
+                    help="a checkout whose kernel is measured beside it "
+                         "(several, with commas: parent, parent1, ...)")
     ap.add_argument("--append", action="store_true",
                     help="the step's pair: the new row's write and the "
                          "attention, in series and fused")
@@ -193,8 +222,10 @@ def main() -> int:
     if not args.rehearse and dev.platform != "tpu":
         raise SystemExit("measure_paged_attention: no TPU; refusing")
     parent_form = {}
-    if args.parent:
-        parent_form["parent"] = (load_kernel(args.parent), None, None)
+    checkouts = [x for x in (args.parent or "").split(",") if x]
+    for at, checkout in enumerate(checkouts):
+        parent_form["parent" + str(at or "")] = (
+            load_kernel(checkout), None, None, None)
     shapes = TINY if args.rehearse else SHAPES
     out_dir = os.path.join(ROOT, "chiprun_out", "paged_attention")
     os.makedirs(out_dir, exist_ok=True)
@@ -214,10 +245,13 @@ def main() -> int:
         forms = dict(parent_form)
         blocks = sorted({min(int(x), width) for x in args.blocks.split(",")})
         for blk in blocks:
-            forms[f"change{blk}"] = (pa8, blk, None)
+            forms[f"change{blk}"] = (pa8, blk, None, None)
         for fold in sorted({min(int(x), blocks[0])
                             for x in args.folds.split(",") if x}):
-            forms[f"change{blocks[0]}f{fold}"] = (pa8, blocks[0], fold)
+            forms[f"change{blocks[0]}f{fold}"] = (pa8, blocks[0], fold, None)
+        for ahead in sorted({int(x) for x in args.aheads.split(",") if x}):
+            forms[f"change{blocks[0]}a{ahead}"] = (pa8, blocks[0], None,
+                                                   ahead)
         shape = (2, rows, KH, P, PS, HD)
 
         kv, s = jax.block_until_ready(pool_maker(shape)())
@@ -226,7 +260,7 @@ def main() -> int:
         table = jnp.asarray(rng.integers(1, P, (B, width)), jnp.int32)
         sets = length_sets(rng, B, width)
         first = {}
-        for form, (mod, blk, fold) in forms.items():
+        for form, (mod, blk, fold, ahead) in forms.items():
             fn = mod.paged_attention_int8
             takes_mask = hasattr(mod, "live_rows")
 
@@ -245,7 +279,7 @@ def main() -> int:
                     0, rows, row, jnp.zeros((B, H, HD), jnp.float32))
 
             t0 = time.perf_counter()
-            with folding(pa8, fold):
+            with folding(pa8, fold, ahead):
                 compiled = jax.jit(attend_rows).lower(
                     q, kv, s, table, jnp.zeros((B,), jnp.int32),
                     jnp.zeros((B,), bool)).compile()
@@ -262,7 +296,7 @@ def main() -> int:
                     res = compiled(q, kv, s, table, lengths, active)
                 jax.block_until_ready(res)
                 host_us = (time.perf_counter() - t0) * 1e6 / args.reps / rows
-                live, walked, updates = pa8.page_counts(
+                live, walked, updates, _ = pa8.page_counts(
                     np.asarray(lens), PS, width, blk,
                     mask=np.asarray(mask) if takes_mask else None,
                     fold=folded)
@@ -272,6 +306,8 @@ def main() -> int:
                     rows_live=int(np.sum(mask)) if takes_mask else B,
                     pages_live=live, pages_in_whole_blocks=walked,
                     pages_an_update=folded, softmax_updates=updates,
+                    blocks_ahead=blocks_in_flight(mod, ahead, KH,
+                                                  blk or width),
                     compile_s=round(compile_s, 1), host_us_per_call=host_us,
                     max_abs_diff_to_first_form=float(
                         np.max(np.abs(got - want), initial=0.0)),
@@ -298,8 +334,11 @@ def append_pairs(args, shapes, say) -> int:
     dev = jax.devices()[0]
     forms = {}  # name: (the append's module or None: fused, the kernel's)
     if args.parent:
-        forms["parent_series"] = (load_kernel(args.parent, APPEND),
-                                  load_kernel(args.parent))
+        checkout = args.parent.split(",")[0]
+        parent = load_kernel(checkout)
+        forms["parent_series"] = (load_kernel(checkout, APPEND), parent)
+        if hasattr(parent, "NewRow"):  # it writes the row itself (PR 46)
+            forms["parent_fused"] = (None, parent)
     forms.update(series=(ka8, pa8), fused=(None, pa8))
     for name in args.shapes.split(","):
         rows, KH, H, B, P, width = shapes[name]
@@ -403,6 +442,7 @@ def append_pairs(args, shapes, say) -> int:
                         return res
                     line.update(traced_us(again, "append_attend_rows", rows))
                     kv, s = held
+                    del held[:]  # or the next form's pool finds no room
                 say(**line)
                 del res, kv, s
             first, want = next(iter(left.values())), next(iter(outs.values()))

@@ -81,6 +81,24 @@ MASKED = {
     "one_live_row": (4, None, lambda r: [1, 1, 3 * PS, 1],
                      [False, False, True, False]),
 }
+# The walk over the live rows, a grid step each (PR 53: a block is asked
+# for into the buffer the block just multiplied was taken from, and
+# `blocks_ahead` + 1 of them are on their way from a call's first grid
+# step: five at these tiny pages): twelve rows of 1 to 8 pages (one or two
+# blocks of 4) of which none, one, two, five, eleven and all are live,
+# never a prefix of the batch. The look-ahead is deeper than what one and
+# two live rows have, and the longer walks go round the five buffers.
+# name: the live rows
+_WALK_PAGES = (3, 8, 1, 5, 2, 8, 4, 6, 1, 7, 5, 2)
+_WALKS = {"walk_of_0_of_12": (), "walk_of_1_of_12": (7,),
+          "walk_of_2_of_12": (3, 9), "walk_of_5_of_12": (1, 4, 5, 9, 11),
+          "walk_of_11_of_12": tuple(b for b in range(12) if b != 6),
+          "walk_of_12_of_12": tuple(range(12))}
+WALKS = {name: (8, None,
+                lambda r: [n * PS - r + 1 if n > 1 else 1
+                           for n in _WALK_PAGES],
+                [b in rows for b in range(12)])
+         for name, rows in _WALKS.items()}
 
 
 def _pool(pages, seed):
@@ -148,7 +166,7 @@ def an_idle_row_is_never_asked_for_and_reads_zeros(case, form, split_kv):
     but with its table row on the poison page: nothing of it may be
     copied, and a row the grid never served must not show stale VMEM."""
     q_rep, tree = FORMS[form]
-    maxp, block, lengths_of, mask = MASKED[case]
+    maxp, block, lengths_of, mask = (MASKED.get(case) or WALKS[case])
     lengths = np.asarray(lengths_of(q_rep), np.int32)
     mask = np.asarray(mask)
     B = len(lengths)
@@ -217,6 +235,13 @@ WINDOWED = {
     "blocks_first_block_wholly_behind": (
         [9 * PS, 10 * PS - 1, 11 * PS, 12 * PS],
         [4 * PS, 4 * PS + 3, 5 * PS - 1, 8 * PS + 1], None),
+    # five live rows of three blocks each among seven idle ones (PR 53):
+    # fifteen blocks through the walk's five buffers
+    "blocks_walked_for_5_of_12_rows": (
+        [1, 9 * PS, 1, 1, 10 * PS - 3, 12 * PS, 1, 1, 1, 11 * PS + 1, 1,
+         9 * PS + 1],
+        [0, 3, 0, 0, PS, 4 * PS + 3, 0, 0, 0, 2 * PS - 1, 0, 8 * PS],
+        [b in (1, 4, 5, 9, 11) for b in range(12)]),
 }
 
 
@@ -277,6 +302,14 @@ APPENDED = {
     "split_descriptors_idle_between_live": (
         4, None, [13, 1, 300, 1, 4 * APS, 1, 1, APS + 32],
         [True, False, True, False, True, False, False, True], True),
+    # five live rows of two and three blocks among idle ones (PR 53):
+    # thirteen blocks through the five buffers pages of 68 KB get, each
+    # asked for after the block before it in its buffer was patched and
+    # multiplied, and five writes through WRITES_AHEAD tiles
+    "idle_between_live_several_blocks": (
+        9, None, [1, 9 * APS, 5 * APS + 1, 1, 8 * APS + 33, 1, 9 * APS - 1,
+                  6 * APS], [False, True, True, False, True, False, True,
+                             True], False),
 }
 
 

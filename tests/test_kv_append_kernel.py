@@ -606,10 +606,12 @@ def test_engine_counts_the_steps_whose_append_is_the_kernel(use_pallas,
     assert snap["decode_attn_rows_skipped"] == steps
     # ... and a row of one page is one softmax update, whatever the fold
     assert snap["decode_attn_updates"] == steps
+    # ... and a step's call is a grid step a live row: one of the two
+    assert snap["decode_attn_grid_steps"] == steps
     for name in ("decode_steps_kernel_append", "decode_steps_fused_append",
                  "decode_attn_pages_live",
                  "decode_attn_pages_walked", "decode_attn_rows_skipped",
-                 "decode_attn_updates"):
+                 "decode_attn_updates", "decode_attn_grid_steps"):
         assert name in fleet.counter_keys()
         assert name in prometheus_text(snap)
         assert EngineMetrics().snapshot()[name] == 0

@@ -11,7 +11,7 @@ forms.
 import pytest
 
 from paged_int8_cases import (
-    LENGTHS, MASKED,
+    LENGTHS, MASKED, WALKS,
     a_page_past_a_rows_last_is_neither_copied_nor_multiplied,
     an_idle_row_is_never_asked_for_and_reads_zeros)
 
@@ -30,3 +30,12 @@ def test_a_page_past_a_rows_last_is_neither_copied_nor_multiplied(
 @pytest.mark.parametrize("case", list(MASKED))
 def test_an_idle_row_is_never_asked_for_and_reads_zeros(case, form, split_kv):
     an_idle_row_is_never_asked_for_and_reads_zeros(case, form, split_kv)
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_the_walk_serves_the_live_rows_alone(case):
+    """None, one, two, five, eleven and all of twelve rows live, apart
+    among idle ones (PR 53: a block is asked for AFTER the one in its
+    buffer was multiplied; a look-ahead deeper than the few live rows'
+    blocks and shorter than the many's)."""
+    an_idle_row_is_never_asked_for_and_reads_zeros(case, "q_rep1", False)

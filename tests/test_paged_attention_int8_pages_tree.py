@@ -32,6 +32,13 @@ def test_an_idle_row_is_never_asked_for_and_reads_zeros(case, form, split_kv):
     an_idle_row_is_never_asked_for_and_reads_zeros(case, form, split_kv)
 
 
+@pytest.mark.parametrize("case", ["walk_of_5_of_12"])
+def test_the_walk_serves_the_live_rows_alone(case):
+    """tests/test_paged_attention_int8_pages.py's, in this file's form at
+    one count: five live rows' eight blocks through five buffers."""
+    an_idle_row_is_never_asked_for_and_reads_zeros(case, "tree", False)
+
+
 # a decode batch of 64 slots as the cells send it: name -> live slots
 LIVE_OF_64 = {"3_of_64": 3, "60_of_64": 60, "1_of_64": 1, "all_64": 64}
 
@@ -89,28 +96,41 @@ def test_live_rows_lists_the_live_rows_first_and_in_order():
 
 
 def test_page_counts_of_a_known_batch():
-    """What the engine's three counters add a step: the rows' pages, what
-    whole blocks over the same rows cover, and the softmax updates the
-    kernel folds the rows' pages into."""
+    """What the engine's four counters add a step: the rows' pages, what
+    whole blocks over the same rows cover, the softmax updates the
+    kernel folds the rows' pages into, and the calls' grid steps: one a
+    live row, and one for a call (a row of the lengths' last axis) with
+    nobody live."""
     lengths = np.array([1, 128, 129, 0, 640, 2560, 4000], np.int32)
-    live, walked, updates = pa8.page_counts(lengths, page_size=128,
-                                            max_pages=20, block=5)
+    live, walked, updates, grid_steps = pa8.page_counts(
+        lengths, page_size=128, max_pages=20, block=5)
     assert (live, walked) == (1 + 1 + 2 + 1 + 5 + 20 + 20,
                               5 + 5 + 5 + 5 + 5 + 20 + 20)
     assert updates == 1 + 1 + 1 + 1 + 1 + 4 + 4  # a block an update
+    assert grid_steps == 7  # no mask: every row is live
     # a block that does not divide the table's width stops at the width
-    assert pa8.page_counts(lengths[-1:], 128, 20, block=8) == (20, 20, 3)
-    assert pa8.page_counts(lengths[:0], 128, 20, block=8) == (0, 0, 0)
+    assert pa8.page_counts(lengths[-1:], 128, 20, block=8) == (20, 20, 3, 1)
+    assert pa8.page_counts(lengths[:0], 128, 20, block=8) == (0, 0, 0, 1)
     # with the step's mask an idle row has no page to copy; what whole
     # blocks over every row covered is the walk it is compared with
     mask = np.array([True, False, True, False, True, False, True])
     assert pa8.page_counts(lengths, 128, 20, block=5, mask=mask) == (
-        1 + 2 + 5 + 20, walked, 1 + 1 + 1 + 4)
+        1 + 2 + 5 + 20, walked, 1 + 1 + 1 + 4, 4)
+    # ... and with nobody live the call still makes one grid step
+    assert pa8.page_counts(lengths, 128, 20, block=5,
+                           mask=np.zeros(7, bool)) == (0, walked, 0, 1)
     # a block of K steps: [K, B] lengths against the [B] mask
     assert pa8.page_counts(np.stack([lengths, lengths + 1]), 128, 20,
                            block=5, mask=mask) == (
         (1 + 2 + 5 + 20) + (1 + 2 + 6 + 20), 2 * walked + 5,
-        (1 + 1 + 1 + 4) + (1 + 1 + 2 + 4))
+        (1 + 1 + 1 + 4) + (1 + 1 + 2 + 4), 4 + 4)
+    # eight steps of three cache rows each: twenty-four calls of 7 rows,
+    # of 4 live ones, and of none
+    steps = np.ones((8, 3, 7), np.int32)
+    assert pa8.page_counts(steps, 128, 20)[3] == 24 * 7
+    assert pa8.page_counts(steps, 128, 20, mask=mask)[3] == 24 * 4
+    assert pa8.page_counts(steps, 128, 20, mask=~mask | mask)[3] == 24 * 7
+    assert pa8.page_counts(steps, 128, 20, mask=mask & ~mask)[3] == 24
 
 
 # rows of 1, 4, 6, 7, 11 and 20 pages; name: (pages a block, pages an

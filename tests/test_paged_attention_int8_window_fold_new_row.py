@@ -152,7 +152,7 @@ def test_the_hosts_update_count_is_the_folds_the_kernel_runs(
                 q, kv, s, table, jnp.asarray(lengths), LAYER, interpret=True,
                 live=pa8.live_rows(jnp.asarray(mask))))
             jax.effects_barrier()
-            pages, _, updates = pa8.page_counts(
+            pages, _, updates, _ = pa8.page_counts(
                 lengths, PS, maxp, mask=np.asarray(mask), fold=width)
             assert (sum(ran), len(ran)) == (pages, updates)
             assert max(ran) <= width
@@ -165,6 +165,35 @@ def test_the_rule_is_a_width_the_block_can_hold():
                             (8, 16), (8, 20), (2, 2)]:
         for ppcb in (1, 2, 4, 5, 8):
             assert 1 <= pa8.fold_pages(kv_heads, group, ppcb) <= ppcb
+
+
+# a page's bytes at the cells' shapes (KV heads of 128 x 128 int8 codes and
+# float32 scales, four pages a block) -> the blocks in flight
+DEPTHS = {"68_KB_a_chip_of_tp4": (2, 128, 128, 4, 4),
+          "135_KB_smallthinker": (4, 128, 128, 4, 4),
+          "270_KB_mistral_7b": (8, 128, 128, 4, 3),
+          "541_KB_ouro": (16, 128, 128, 4, 2),
+          "34_KB_one_kv_head": (1, 128, 128, 4, pa8.MAX_BLOCKS_AHEAD),
+          "a_table_of_one_page": (8, 128, 128, 1, pa8.MAX_BLOCKS_AHEAD),
+          "the_tests_tiny_pages": (2, 8, 16, 4, pa8.MAX_BLOCKS_AHEAD),
+          "1_MB_32_kv_heads": (32, 128, 128, 4, pa8.BLOCKS_AHEAD)}
+
+
+@pytest.mark.parametrize("case", list(DEPTHS))
+def test_the_look_ahead_keeps_three_megabytes_in_flight(case):
+    """`blocks_ahead`, a pure function of a block's bytes: what its
+    docstring states for the cells' four page sizes, and its two bounds."""
+    kv_heads, ps, hd, ppcb, want = DEPTHS[case]
+    got = pa8.blocks_ahead(kv_heads, ps, hd, ppcb)
+    assert got == want
+    block = ppcb * 2 * kv_heads * ps * (hd + 4)
+    assert pa8.BLOCKS_AHEAD <= got <= pa8.MAX_BLOCKS_AHEAD
+    # the buffers (one more): never over what is in flight and two blocks,
+    # or the three blocks a large page always had
+    assert (got + 1) * block <= max(pa8.BYTES_IN_FLIGHT + 2 * block,
+                                    (pa8.BLOCKS_AHEAD + 1) * block)
+    if pa8.BLOCKS_AHEAD < got < pa8.MAX_BLOCKS_AHEAD:
+        assert (got - 1) * block < pa8.BYTES_IN_FLIGHT <= got * block
 
 
 @pytest.mark.parametrize("case", list(APPENDED))
