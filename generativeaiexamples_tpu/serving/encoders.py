@@ -46,7 +46,8 @@ _OWN_LEDGER_ROWS = 256
 def _enqueue_forward(engine, phase: str, rows: int, tokens: int, S: int,
                      forward: Callable[[], Any]):
     """One forward through the ledger's stamp (serving/flight.py): a
-    sequence number and t_enqueue just before the dispatch call. The
+    sequence number and t_enqueue just before the dispatch call, and
+    t_dispatched where the call returned (the event's `call=`). The
     encoder's threads never write a flight ring; the rows are the
     hand-off the engine's scheduler drains (`engine.programs`, which an
     OpenAIServer points at the LLM engine's ledger)."""
@@ -56,6 +57,7 @@ def _enqueue_forward(engine, phase: str, rows: int, tokens: int, S: int,
     try:
         with jax.profiler.TraceAnnotation(phase, seq=prog.seq):
             out = forward()
+            ledger.dispatched(prog)
     except BaseException:
         ledger.cancel(prog)
         raise

@@ -64,7 +64,7 @@ import queue
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Iterator, List, Optional, Sequence
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,7 +85,8 @@ from generativeaiexamples_tpu.serving.multihost import (
     fetch_addressable as mh_fetch_addressable,
     fetch_replicated as mh_fetch_replicated)
 from generativeaiexamples_tpu.serving.flight import (
-    EV_ADMIT, EV_ADMIT_RETRY, EV_DECODE_JOIN, EV_FIRST_TOKEN, EV_KV_DEMOTE,
+    EV_ADMIT, EV_ADMIT_RETRY, EV_DECODE_JOIN, EV_FIRST_TOKEN, EV_HOST_PAUSE,
+    EV_KV_DEMOTE,
     EV_KV_PROMOTE, EV_KV_TRANSFER, EV_MOE_LOAD, EV_PREFILL_CHUNK,
     EV_WINDOW_CACHE,
     EV_PREFILL_DISPATCH, EV_PROGRAM, EV_QOS_PAUSE, EV_QOS_PICK,
@@ -334,6 +335,8 @@ _COUNTERS = (
     "plan_variants_compiled", "spec_fallback_steps", "kv_transfer_pages",
     "kv_transfer_device_pages", "kv_transfer_chunks", "admission_failures",
     "qos_preemptions", "stuck_thread_joins", "program_stalls",
+    "program_stalls_host", "host_gc_collections", "host_gc_pauses",
+    "host_gc_pause_ms", "host_late_wakes",
 )
 
 
@@ -421,6 +424,20 @@ class EngineMetrics:
         # passed flight.STALL_FACTOR times the running median of their
         # class and shape; each also left one WARNING line.
         self.program_stalls = 0
+        # ... and those of them of which the host's known pauses
+        # (collections, dispatch calls, late wake-ups) cover half or
+        # more of what the program ran over its class's median: the
+        # host stood still, not the device.
+        self.program_stalls_host = 0
+        # The interpreter's collections while this engine ran
+        # (flight.HostPauses: every one, and the ms inside them), the
+        # `host_pause` rows written of them (1 ms or more, or
+        # generation 2), and the scheduler's timed waits that came back
+        # flight.LATE_WAKE_MS or more late.
+        self.host_gc_collections = 0
+        self.host_gc_pauses = 0
+        self.host_gc_pause_ms = 0.0
+        self.host_late_wakes = 0
         # KV pool geometry (set once at engine build): rows of the pool
         # (layers x passes) and the bytes one cached token takes over
         # all rows, scales included.
@@ -925,6 +942,12 @@ class LLMEngine:
         # while the recorder is on; drained by the scheduler thread
         # into `program` events (_drain_programs).
         self.programs = ProgramLedger()
+        # The host's pauses: this engine's cursor into the process's
+        # collections (flight.HOST_PAUSES; None until start()) and the
+        # last pauses it wrote, (start, end, cause), which a stalled
+        # program's interval is held against (_note_stall).
+        self._pause_cursor: Optional[Tuple[int, int, float]] = None
+        self._recent_pauses: deque = deque(maxlen=256)
         # One decode step's device time by the ledger's rows of the last
         # landed blocks: what decode_block.choose_k holds a block to its
         # time budget with. None (the rule off) until a block has landed
@@ -1563,6 +1586,7 @@ class LLMEngine:
 
     def start(self) -> "LLMEngine":
         self._running = True
+        self._pause_cursor = flight_mod.HOST_PAUSES.acquire(self.flight)
         self._reader = threading.Thread(target=self._reader_loop,
                                         daemon=True, name="llm-engine-read")
         self._reader.start()
@@ -1592,6 +1616,7 @@ class LLMEngine:
                 _LOG.warning("multihost: stop record publish failed",
                              exc_info=True)
         self._running = False
+        flight_mod.HOST_PAUSES.release(self.flight)
         self._wake.set()
         self._pace_wake.set()
         self._await_q.put(None)
@@ -2110,7 +2135,7 @@ class LLMEngine:
                 # (e.g. every active request finished at its first
                 # token): poll rather than sleep the full timeout.
                 with _phase("sched.idle"):
-                    self._wake.wait(timeout=0.002)
+                    self._timed_wait(self._wake, 0.002, "idle")
                 self._wake.clear()
                 continue
             if not did_work:
@@ -2121,7 +2146,7 @@ class LLMEngine:
                 # exists to expose.
                 self._last_beat_ready = 0.0
                 with _phase("sched.idle"):
-                    self._wake.wait(timeout=0.02)
+                    self._timed_wait(self._wake, 0.02, "idle")
                 self._wake.clear()
 
     # graftlint: hot-path
@@ -2384,7 +2409,7 @@ class LLMEngine:
             return self._fetch_inline(fl)  # tests may drive _loop inline
         self._fetch_done.clear()
         self._fetch_req.put(fl.block)
-        while not self._fetch_done.wait(timeout=0.005):
+        while not self._timed_wait(self._fetch_done, 0.005, "fetch"):
             if not self._running or not self._reader.is_alive():
                 # stop() raced the handoff. If the reader exited without
                 # consuming the block, reclaim it and fetch inline;
@@ -2413,6 +2438,87 @@ class LLMEngine:
         if "err" in box:
             raise box["err"]
         return box["host"]
+
+    # graftlint: hot-path
+    def _timed_wait(self, event: threading.Event, timeout: float,
+                    where: str) -> bool:
+        """`event.wait(timeout)` of the scheduler's own polls. Where it
+        comes back UNSET and flight.LATE_WAKE_MS or more past its
+        timeout, the thread was held off (another thread kept the
+        interpreter's lock, or the OS the core): one `host_pause` row
+        of cause `late_wake`, its length the lateness. Two clock reads
+        a poll; with the recorder off, none."""
+        if not self.flight.enabled:
+            return event.wait(timeout)
+        t0 = time.perf_counter()
+        if event.wait(timeout):
+            return True
+        t1 = time.perf_counter()
+        if (t1 - t0 - timeout) * 1e3 >= flight_mod.LATE_WAKE_MS:
+            self.metrics.host_late_wakes += 1
+            self._record_pause(flight_mod.PAUSE_LATE_WAKE, t0 + timeout, t1,
+                               0, "where=" + where)
+        return False
+
+    def _record_pause(self, cause: int, t0: float, t1: float, gen: int,
+                      aux: str) -> None:
+        """One `host_pause` event (scheduler thread), its histogram
+        sample, and the row a later stall is held against."""
+        ms = (t1 - t0) * 1e3
+        self.metrics.hists["host_pause_ms"].observe(ms)
+        self._recent_pauses.append(
+            (t0, t1, flight_mod.PAUSE_CAUSES[cause]))
+        self.flight.record_event(EV_HOST_PAUSE, t1, code=cause, a=ms,
+                                 b=float(gen), aux=aux)
+
+    # graftlint: hot-path
+    def _drain_pauses(self) -> None:
+        """The process's collections since this engine last looked
+        (flight.HOST_PAUSES, by this engine's cursor): the two sums,
+        and a `host_pause` event a listed one."""
+        cursor = self._pause_cursor
+        if cursor is None or not self.flight.enabled:
+            return
+        rows, after = flight_mod.HOST_PAUSES.read(cursor)
+        if after is cursor:     # nothing was collected meanwhile
+            return
+        self._pause_cursor = after
+        m = self.metrics
+        m.host_gc_collections += after[1] - cursor[1]
+        m.host_gc_pause_ms += after[2] - cursor[2]
+        for t0, t1, gen, collected, thread in rows:
+            m.host_gc_pauses += 1
+            self._record_pause(
+                flight_mod.PAUSE_GC, t0, t1, gen,
+                f"gen={gen} collected={collected} "
+                f"thread={thread.replace(' ', '_')}")
+
+    def _note_stall(self, prog: Program) -> None:
+        """A stalled program names its cause: how much of its start ->
+        ready the union of the host's known pauses covers (collections
+        and late wake-ups this engine wrote, and the dispatch call of
+        every program enqueued meanwhile), counted as the HOST's where
+        that is half or more of what it ran over its class's median,
+        and ONE line logged."""
+        pauses = list(self._recent_pauses)
+        pauses += [(t0, t1, flight_mod.CAUSE_DISPATCH_CALL)
+                   for t0, t1 in self.programs.calls()]
+        prog.host_ms, by = flight_mod.host_cover(
+            prog.t_start, prog.t_ready, pauses)
+        self.metrics.program_stalls += 1
+        if prog.host_ms >= 0.5 * (prog.ran_ms - prog.median_ms):
+            self.metrics.program_stalls_host += 1
+        _LOG.warning(
+            "device program stalled: class=%s shape=%s seq=%d "
+            "waited a=%.1f ms, ran b=%.1f ms (over %g x the "
+            "running median of its class and shape); host=%.1f ms of "
+            "%.1f ms (gc %.1f ms, dispatch_call %.1f ms, late_wake "
+            "%.1f ms)",
+            PROGRAM_CLASSES[prog.cls], prog.shape, prog.seq,
+            prog.waited_ms, prog.ran_ms, flight_mod.STALL_FACTOR,
+            prog.host_ms, prog.ran_ms, by.get("gc", 0.0),
+            by.get(flight_mod.CAUSE_DISPATCH_CALL, 0.0),
+            by.get("late_wake", 0.0))
 
     def _fetch_inline(self, fl: _InFlight) -> np.ndarray:
         host = _to_host(fl.block)
@@ -2478,6 +2584,8 @@ class LLMEngine:
             with (_phase(phase) if prog is None
                   else _phase(phase, seq=prog.seq)):
                 yield prog
+                if prog is not None:  # the dispatch call returned
+                    self.programs.dispatched(prog)
         except BaseException:
             if prog is not None:
                 self.programs.cancel(prog)
@@ -2498,21 +2606,18 @@ class LLMEngine:
         """Write the `program` event of every ledger row whose
         completion is now known (scheduler thread: the ring's single
         writer; an encoder's rows arrive here the same way), feed the
-        prefill histograms, count and log a stall."""
+        prefill histograms, count and log a stall. The host's pauses
+        go first: a stall is held against them."""
+        self._drain_pauses()
         for prog in self.programs.drain(proved):
+            self.metrics.hists["dispatch_call_ms"].observe(prog.call_ms)
             if prog.cls == PROG_PREFILL:
                 self.metrics.hists["device_queue_ms"].observe(
                     prog.queued_ms)
                 self.metrics.hists["program_ms_prefill"].observe(
                     prog.ran_ms)
             if prog.stalled:
-                self.metrics.program_stalls += 1
-                _LOG.warning(
-                    "device program stalled: class=%s shape=%s seq=%d "
-                    "waited a=%.1f ms, ran b=%.1f ms (over %g x the "
-                    "running median of its class and shape)",
-                    PROGRAM_CLASSES[prog.cls], prog.shape, prog.seq,
-                    prog.waited_ms, prog.ran_ms, flight_mod.STALL_FACTOR)
+                self._note_stall(prog)
             self.flight.record_event(
                 EV_PROGRAM, prog.t_ready, code=prog.cls, slot=prog.rows,
                 a=prog.waited_ms, b=prog.ran_ms, aux=prog.aux())

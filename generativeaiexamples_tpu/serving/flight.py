@@ -18,7 +18,14 @@ scripts/smoke_flight.py and reported as a bench extra). On top of it:
   a sequence number and three instants on one clock: enqueue, ready
   (stamped by the thread on which the wait for its result ends) and
   start = max(enqueue, the previous program's ready): one device
-  queue runs in order. The scheduler drains it into `program` events.
+  queue runs in order; a fourth, where its dispatch call returned.
+  The scheduler drains it into `program` events.
+- `HostPauses` — what the HOST was doing while a program "ran long":
+  the interpreter's collections (one process-wide `gc.callbacks` hook),
+  beside the dispatch calls' own lengths (the ledger's) and the
+  scheduler's late wake-ups (the engine's), all on the ledger's clock.
+  The scheduler drains them into `host_pause` events, and a stalled
+  program says how much of its interval they cover (`host_cover`).
 - `chrome_trace()` — the recorder rings rendered as Chrome trace-event
   JSON (Perfetto loads it directly): one process lane per replica, one
   slice per beat and per prefill / encoder program on the device lane
@@ -45,12 +52,15 @@ skipped, never mis-read. `ExpHistogram` is single-writer the same way
 from __future__ import annotations
 
 import bisect
+import gc
+import heapq
 import math
 import statistics
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
+from typing import (
+    Any, Callable, Deque, Dict, Iterable, List, Optional, Tuple)
 
 import numpy as np
 
@@ -127,6 +137,12 @@ EV_WINDOW_CACHE = 23
 # block's steps; b = a live sequence's latent-row bytes over its state +
 # tail + latent-row bytes (the share of a sequence that GROWS).
 EV_STATE_CACHE = 24
+# The host stood still (scheduler thread, from `HostPauses` and its own
+# timed waits). ts = the pause's end; a = its length in ms; code = cause
+# (PAUSE_CAUSES: 0 a collection of the interpreter's, 1 a timed wait of
+# the scheduler's that came back late); b = the generation (gc) or 0;
+# aux = "gen=<g> collected=<n> thread=<name>" or "where=<fetch|idle>".
+EV_HOST_PAUSE = 25
 
 # Program classes (EV_PROGRAM.code).
 PROG_DECODE = 0    # a decode block (n = steps K)
@@ -142,6 +158,21 @@ STALL_FACTOR = 8.0
 STALL_MEDIAN_WINDOW = 32   # the median's last samples, a class and shape
 STALL_MIN_SAMPLES = 4      # fewer than these say nothing yet
 
+# Host pauses (EV_HOST_PAUSE.code). A dispatch call is the third cause a
+# stall can name; it has no event of its own, it is `call=` on its
+# program's.
+PAUSE_GC = 0
+PAUSE_LATE_WAKE = 1
+PAUSE_CAUSES = ("gc", "late_wake")
+CAUSE_DISPATCH_CALL = "dispatch_call"
+# A collection shorter than this is summed and not listed, unless it is
+# of generation 2.
+PAUSE_MIN_MS = 1.0
+# A timed wait of the scheduler's that returns unset this long past its
+# timeout was held off: another thread kept the interpreter's lock, or
+# the OS the core.
+LATE_WAKE_MS = 20.0
+
 EVENT_NAMES = {
     EV_SUBMIT: "submit", EV_QOS_PICK: "qos_pick", EV_ADMIT: "admit",
     EV_PREFILL_DISPATCH: "prefill_dispatch",
@@ -155,6 +186,7 @@ EVENT_NAMES = {
     EV_MOE_LOAD: "moe_load", EV_PROGRAM: "program",
     EV_DECODE_JOIN: "decode_join", EV_SPARSE_SELECT: "sparse_select",
     EV_WINDOW_CACHE: "window_cache", EV_STATE_CACHE: "state_cache",
+    EV_HOST_PAUSE: "host_pause",
 }
 
 # Retire reason codes (EV_RETIRE.code); anything unknown maps to -1.
@@ -223,6 +255,9 @@ HIST_KEYS = (
     # From the program ledger, a prefill group's t_start - t_enqueue
     # (what the blocks in flight cost a new arrival) and its device time.
     "hist_device_queue_ms", "hist_program_ms_prefill",
+    # The host's pauses (collections and late wake-ups, the `host_pause`
+    # event's `a`) and every program's dispatch call (its `call=`).
+    "hist_host_pause_ms", "hist_dispatch_call_ms",
 )
 
 
@@ -498,11 +533,14 @@ class FlightRecorder:
 
 class Program:
     """One program enqueued on the device. `seq` and `t_enqueue` are
-    written by `ProgramLedger.enqueue`, `t_ready` by whichever thread
-    the wait for its result ends on, the rest by `drain`."""
+    written by `ProgramLedger.enqueue`, `t_dispatched` by the same
+    thread where its dispatch call returned, `t_ready` by whichever
+    thread the wait for its result ends on, the rest by `drain` (and
+    `host_ms`, of a stalled program, by the engine that drained it)."""
 
-    __slots__ = ("seq", "cls", "rows", "n", "shape", "t_enqueue", "t_ready",
-                 "t_start", "t_prev_ready", "cancelled", "stalled")
+    __slots__ = ("seq", "cls", "rows", "n", "shape", "t_enqueue",
+                 "t_dispatched", "t_ready", "t_start", "t_prev_ready",
+                 "cancelled", "stalled", "median_ms", "host_ms")
 
     def __init__(self, seq: int, cls: int, rows: int, n: int, shape: str,
                  t_enqueue: float):
@@ -512,11 +550,14 @@ class Program:
         self.n = n
         self.shape = shape
         self.t_enqueue = t_enqueue
+        self.t_dispatched = 0.0
         self.t_ready = 0.0
         self.t_start = 0.0
         self.t_prev_ready = 0.0
         self.cancelled = False
         self.stalled = False
+        self.median_ms = 0.0  # of its class and shape, when it stalled
+        self.host_ms = 0.0    # of a stall: what the host's pauses cover
 
     @property
     def waited_ms(self) -> float:
@@ -533,12 +574,23 @@ class Program:
         """enqueue -> start: the wait behind the programs before it."""
         return (self.t_start - self.t_enqueue) * 1e3
 
+    @property
+    def call_ms(self) -> float:
+        """enqueue -> its dispatch call returned: what the call itself
+        held the calling thread (0 where nobody stamped the return)."""
+        return max(0.0, (self.t_dispatched - self.t_enqueue) * 1e3)
+
     def aux(self) -> str:
-        return f"seq={self.seq} n={self.n} shape={self.shape}"
+        aux = (f"seq={self.seq} n={self.n} shape={self.shape} "
+               f"call={self.call_ms:.3f}")
+        if self.stalled:
+            aux += f" stalled=1 host={self.host_ms:.1f}"
+        return aux
 
 
 def parse_program_aux(aux: str) -> Dict[str, str]:
-    """`seq=12 n=8 shape=K8` -> {"seq": "12", "n": "8", "shape": "K8"}."""
+    """`seq=12 n=8 shape=K8 call=0.412` -> {"seq": "12", "n": "8",
+    "shape": "K8", "call": "0.412"}."""
     return dict(kv.split("=", 1) for kv in aux.split() if "=" in kv)
 
 
@@ -569,6 +621,10 @@ class ProgramLedger:
         self._capacity = max(2, int(capacity))
         self._lock = threading.Lock()
         self._open: Deque[Program] = deque()
+        # The last dispatch calls, (t_enqueue, t_dispatched): what a
+        # stall's interval is held against (`calls`), whether or not
+        # their programs are complete yet.
+        self._calls: Deque[Tuple[float, float]] = deque(maxlen=256)
         self._n = 0
         self._prev_ready = 0.0
         self.dropped = 0
@@ -588,6 +644,16 @@ class ProgramLedger:
                 self._open.popleft()
                 self.dropped += 1
         return prog
+
+    # graftlint: hot-path
+    def dispatched(self, prog: Program) -> None:
+        """The program's dispatch call returned, on the calling thread."""
+        prog.t_dispatched = self._clock()
+        self._calls.append((prog.t_enqueue, prog.t_dispatched))
+
+    def calls(self) -> Tuple[Tuple[float, float], ...]:
+        """The last dispatch calls as (start, end), any thread's."""
+        return tuple(self._calls)
 
     # graftlint: hot-path
     def ready(self, prog: Program, t: Optional[float] = None) -> None:
@@ -646,10 +712,170 @@ class ProgramLedger:
             recent = self._recent[(prog.cls, prog.shape)] = deque(
                 maxlen=STALL_MEDIAN_WINDOW)
         ran = prog.ran_ms
-        if len(recent) >= STALL_MIN_SAMPLES \
-                and ran > STALL_FACTOR * statistics.median(recent):
-            prog.stalled = True
+        if len(recent) >= STALL_MIN_SAMPLES:
+            median = statistics.median(recent)
+            if ran > STALL_FACTOR * median:
+                prog.stalled = True
+                prog.median_ms = median
         recent.append(ran)
+
+
+# ---------------------------------------------------------------------------
+# The host's pauses
+# ---------------------------------------------------------------------------
+
+
+def host_cover(t0: float, t1: float,
+               pauses: Iterable[Tuple[float, float, str]]
+               ) -> Tuple[float, Dict[str, float]]:
+    """How much of [t0, t1] the host's known pauses cover, in ms: the
+    length of their UNION (a collection inside a late wake-up counts
+    once), and each cause's own union beside it. `pauses` are (start,
+    end, cause) on the interval's clock."""
+    by_cause: Dict[str, List[Tuple[float, float]]] = {}
+    for start, end, cause in pauses:
+        lo, hi = max(start, t0), min(end, t1)
+        if hi > lo:
+            by_cause.setdefault(cause, []).append((lo, hi))
+
+    def union_ms(intervals: List[Tuple[float, float]]) -> float:
+        total, cursor = 0.0, t0
+        for lo, hi in sorted(intervals):
+            lo = max(lo, cursor)
+            if hi > lo:
+                total += hi - lo
+                cursor = hi
+        return total * 1e3
+
+    return (union_ms([iv for ivs in by_cause.values() for iv in ivs]),
+            {cause: union_ms(ivs) for cause, ivs in by_cause.items()})
+
+
+class HostPauses:
+    """The interpreter's collections, process-wide, on the ledger's
+    clock: ONE `gc.callbacks` hook, installed by the first engine that
+    starts (`acquire`) and taken out when the last one stops
+    (`release`). Every collection adds to two sums (collections seen,
+    ms inside them); one of `PAUSE_MIN_MS` or more, or of generation 2,
+    also leaves a row (start, end, generation, collected, the thread's
+    name) in a bounded hand-off of the ledger's kind: the collecting
+    thread appends, the oldest row is overwritten, and each engine's
+    scheduler reads with a cursor of its own (`read`), so two engines in
+    one process both see a pause that held both.
+
+    The hook takes no lock (a thread that held it could be the one that
+    collects), formats nothing and never raises; collections do not
+    nest, so it is single-writer by the collector's own rule. While no
+    acquired recorder is on it takes no stamp. Around each collection
+    it enters and leaves a `TraceAnnotation("host.gc", gen=...)`: in a
+    profile the collector then stands on the host's plane under the
+    program's own name (a flag test while no profile runs)."""
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter,
+                 capacity: int = 1024):
+        self._clock = clock
+        self._capacity = max(2, int(capacity))
+        self._ring: List[Optional[tuple]] = [None] * self._capacity
+        self._n = 0             # rows ever written
+        self.collections = 0    # collections seen while a recorder was on
+        self.pause_ms = 0.0     # ... and the ms inside them
+        self.errors = 0         # times the hook swallowed an error of its own
+        self._t0 = 0.0
+        self._annotation = None       # jax's TraceAnnotation, from acquire
+        self._open_annotation = None
+        # The running engines' recorders, replaced whole (never mutated)
+        # so the hook iterates a list nobody changes under it.
+        self._recorders: List[Any] = []
+        self._lock = threading.Lock()  # acquire / release; never the hook
+
+    # -- the engines' side --------------------------------------------------
+
+    def acquire(self, recorder: Any) -> Tuple[int, int, float]:
+        """An engine starts: the hook goes in with the first. Returns
+        the engine's cursor: it reads what happens from now on."""
+        with self._lock:
+            if self._annotation is None:
+                import jax  # not at import: the chain server imports this
+
+                self._annotation = jax.profiler.TraceAnnotation
+            if not any(r is recorder for r in self._recorders):
+                self._recorders = self._recorders + [recorder]
+            if self._on_gc not in gc.callbacks:
+                # FIRST in the list: the callbacks of others run inside
+                # the pause they lengthen. JAX's own (`jax/_src/lib`:
+                # `collect_garbage()`, the runtime's `PythonRefManager::
+                # CollectGarbage`, on both phases) is then inside ours
+                # on `start`, with the backlog it frees; what a later
+                # callback does on `stop` comes after our second stamp.
+                gc.callbacks.insert(0, self._on_gc)
+            return self._n, self.collections, self.pause_ms
+
+    def release(self, recorder: Any) -> None:
+        """An engine stops: the hook comes out with the last."""
+        with self._lock:
+            self._recorders = [r for r in self._recorders
+                               if r is not recorder]
+            if not self._recorders and self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
+
+    # graftlint: hot-path
+    def read(self, cursor: Tuple[int, int, float]
+             ) -> Tuple[List[tuple], Tuple[int, int, float]]:
+        """The rows written since `cursor` that the ring still holds,
+        oldest first, and the cursor to read on from; the cursor's
+        second and third fields are the two sums then."""
+        head = self._n
+        if head == cursor[0] and self.collections == cursor[1]:
+            return [], cursor
+        rows = []
+        for i in range(max(cursor[0], head - self._capacity), head):
+            row = self._ring[i % self._capacity]
+            if row is not None and row[0] == i:   # not lapped meanwhile
+                rows.append(row[1:])
+        return rows, (head, self.collections, self.pause_ms)
+
+    # -- the hook (whichever thread collects) -------------------------------
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        try:
+            if phase == "start":
+                self._t0 = 0.0
+                for rec in self._recorders:
+                    if rec.enabled:
+                        break
+                else:
+                    return
+                if self._annotation is not None:
+                    ann = self._annotation("host.gc",
+                                           gen=info["generation"])
+                    ann.__enter__()
+                    self._open_annotation = ann
+                self._t0 = self._clock()
+                return
+            t0, self._t0 = self._t0, 0.0
+            if not t0:
+                return
+            t1 = self._clock()
+            ann, self._open_annotation = self._open_annotation, None
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            self.collections += 1
+            self.pause_ms += (t1 - t0) * 1e3
+            gen = info["generation"]
+            if (t1 - t0) * 1e3 >= PAUSE_MIN_MS or gen >= 2:
+                n = self._n
+                self._ring[n % self._capacity] = (
+                    n, t0, t1, gen, info["collected"],
+                    threading.current_thread().name)
+                self._n = n + 1
+        except Exception:
+            # Raised from here it would be printed as unraisable at every
+            # collection, from inside the collector: counted, nothing more.
+            self.errors += 1
+
+
+# The process's one: every engine acquires and reads this.
+HOST_PAUSES = HostPauses()
 
 
 # ---------------------------------------------------------------------------
@@ -760,6 +986,54 @@ def _program_events(pid: int, events: List[Dict[str, Any]],
     return out
 
 
+def _host_pause_events(pid: int, events: List[Dict[str, Any]],
+                       base: float) -> List[Dict[str, Any]]:
+    """The host's pauses as slices on the scheduler lane: a `host_pause`
+    event's (named by its cause) and every dispatch call of
+    `PAUSE_MIN_MS` or more (`dispatch_call`: its program's enqueue ->
+    the call returned). Pauses of different threads can overlap by a
+    part; a slice that would is cut to start where the one before it
+    ends, so the lane nests and still shows their union."""
+    spans: List[Tuple[float, float, str, Dict[str, Any]]] = []
+    for ev in events:
+        if ev["kind"] == EV_HOST_PAUSE:
+            cause = PAUSE_CAUSES[ev["code"]] \
+                if 0 <= ev["code"] < len(PAUSE_CAUSES) else str(ev["code"])
+            spans.append((ev["ts"] - ev["a"] / 1e3, ev["ts"], cause,
+                          {"ms": round(ev["a"], 3), "aux": ev["aux"]}))
+        elif ev["kind"] == EV_PROGRAM:
+            aux = parse_program_aux(ev["aux"])
+            call_ms = float(aux.get("call", 0.0))
+            if call_ms >= PAUSE_MIN_MS:
+                t_enqueue = ev["ts"] - ev["a"] / 1e3
+                spans.append((t_enqueue, t_enqueue + call_ms / 1e3,
+                              CAUSE_DISPATCH_CALL,
+                              {"ms": round(call_ms, 3),
+                               "seq": int(aux.get("seq", -1))}))
+    out: List[Dict[str, Any]] = []
+    open_ends: List[float] = []   # ends of the slices this one is inside
+    heap = [(start, -end, i) for i, (start, end, _, _) in enumerate(spans)]
+    heapq.heapify(heap)
+    while heap:
+        start, neg_end, i = heapq.heappop(heap)
+        end = -neg_end
+        while open_ends and open_ends[-1] <= start:
+            open_ends.pop()
+        if open_ends and end > open_ends[-1]:
+            # starts inside the slice before it and outlasts it: its
+            # turn comes again where that slice ends
+            heapq.heappush(heap, (open_ends[-1], neg_end, i))
+            continue
+        open_ends.append(end)
+        ts_us = round((start - base) * 1e6, 1)
+        end_us = round((end - base) * 1e6, 1)
+        out.append({"name": spans[i][2], "cat": "host-pause", "ph": "X",
+                    "pid": pid, "tid": TID_SCHED, "ts": ts_us,
+                    "dur": max(0.0, round(end_us - ts_us, 1)),
+                    "args": spans[i][3]})
+    return out
+
+
 def _request_events(pid: int, events: List[Dict[str, Any]],
                     base: float) -> List[Dict[str, Any]]:
     from generativeaiexamples_tpu.serving.qos import TIERS
@@ -867,7 +1141,9 @@ def chrome_trace(recorders: Dict[str, FlightRecorder]) -> Dict[str, Any]:
     # earliest timestamp (a first-entry base would go negative).
     stamps = [float(b["t_dispatch"]) for bs, _ in snaps.values()
               for b in bs]
-    stamps += [_program_start(ev) if ev["kind"] == EV_PROGRAM else ev["ts"]
+    stamps += [_program_start(ev) if ev["kind"] == EV_PROGRAM
+               else ev["ts"] - ev["a"] / 1e3 if ev["kind"] == EV_HOST_PAUSE
+               else ev["ts"]
                for _, evs in snaps.values() for ev in evs]
     base = min(stamps) if stamps else 0.0
     for pid, name in enumerate(sorted(snaps)):
@@ -880,6 +1156,7 @@ def chrome_trace(recorders: Dict[str, FlightRecorder]) -> Dict[str, Any]:
                            "tid": tid, "args": {"name": tname}})
         events.extend(_beat_events(pid, beats, base))
         events.extend(_program_events(pid, evs, base))
+        events.extend(_host_pause_events(pid, evs, base))
         events.extend(_request_events(pid, evs, base))
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 

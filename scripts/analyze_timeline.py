@@ -15,7 +15,15 @@ dumped by bench/smoke under build/). Attribution per replica lane:
   splits the total into decode / prefill / chunk / encoder. What the
   slices cannot show: time the device spent on a program of ANOTHER
   process, and a completion stamped up to a thread switch late.
-- gaps between busy intervals are charged to the FIRST known cause
+- the part of a gap that a HOST PAUSE covers is charged to it first,
+  by the pause's own length and not the whole gap's (the `host-pause`
+  slices of the scheduler lane, `flight.py::_host_pause_events`): **gc**
+  (a collection of the interpreter's), **late_wake** (a timed wait of
+  the scheduler's that came back late: another thread kept the
+  interpreter's lock, or the OS the core), **dispatch_call** (a
+  program's dispatch call of 1 ms or more), in that order where they
+  overlap.
+- what is left of a gap is charged to the FIRST known cause
   whose marker falls inside the gap (priority order): **qos_pause**
   (a latency-tier TTFT phase paused lower-tier prefills),
   **pager_gather** (KV pager promote — the host-side tier read),
@@ -57,9 +65,15 @@ CAUSE_PRIORITY = (
     ("kv_demote", "kv_demote"),
 )
 
-CATEGORIES = ("device_busy", "cold_plan", "qos_pause", "pager_gather",
-              "disagg", "admission_retry", "prefill_chunk", "kv_demote",
-              "host_gap", "idle")
+# The host's pauses (the names of the `host-pause` slices), in priority
+# order: the part of a gap one covers is charged to it before anything
+# else, and where two overlap the first takes the overlap.
+HOST_PAUSES = ("gc", "late_wake", "dispatch_call")
+
+CATEGORIES = ("device_busy",) + HOST_PAUSES + (
+    "cold_plan", "qos_pause", "pager_gather",
+    "disagg", "admission_retry", "prefill_chunk", "kv_demote",
+    "host_gap", "idle")
 
 
 def _merge_intervals(iv: List[Tuple[float, float]]
@@ -71,6 +85,24 @@ def _merge_intervals(iv: List[Tuple[float, float]]
         else:
             out.append((lo, hi))
     return out
+
+
+def _subtract(pieces: List[Tuple[float, float]], lo: float, hi: float
+              ) -> Tuple[List[Tuple[float, float]], float]:
+    """`pieces` without [lo, hi], and the length taken out."""
+    out: List[Tuple[float, float]] = []
+    taken = 0.0
+    for a, b in pieces:
+        cut_lo, cut_hi = max(a, lo), min(b, hi)
+        if cut_hi <= cut_lo:
+            out.append((a, b))
+            continue
+        taken += cut_hi - cut_lo
+        if a < cut_lo:
+            out.append((a, cut_lo))
+        if cut_hi < b:
+            out.append((cut_hi, b))
+    return out, taken
 
 
 def busy_by_class(beats: List[Dict[str, Any]],
@@ -94,7 +126,8 @@ def attribute_lane(beats: List[Dict[str, Any]],
                    instants: List[Dict[str, Any]],
                    span: Tuple[float, float],
                    host_gap_us: float,
-                   programs: Tuple[Dict[str, Any], ...] = ()
+                   programs: Tuple[Dict[str, Any], ...] = (),
+                   pauses: Tuple[Dict[str, Any], ...] = ()
                    ) -> Dict[str, float]:
     """Category -> microseconds over one lane's [t0, t1] span."""
     out = {c: 0.0 for c in CATEGORIES}
@@ -126,6 +159,15 @@ def attribute_lane(beats: List[Dict[str, Any]],
         gaps.append((cursor, t1))
     inst_sorted = sorted(instants, key=lambda e: e["ts"])
     for lo, hi in gaps:
+        # the host's pauses first, each by what it covers of the gap
+        left = [(lo, hi)]
+        for cause in HOST_PAUSES:
+            for p in pauses:
+                if p["name"] == cause:
+                    left, taken = _subtract(
+                        left, p["ts"], p["ts"] + p.get("dur", 0.0))
+                    out[cause] += taken
+        rest = sum(b - a for a, b in left)
         inside = [e["name"] for e in inst_sorted if lo <= e["ts"] <= hi]
         cat = None
         for name, category in CAUSE_PRIORITY:
@@ -135,8 +177,8 @@ def attribute_lane(beats: List[Dict[str, Any]],
         if cat is None and any(abs(edge - hi) < 1.0 for edge in cold_edges):
             cat = "cold_plan"
         if cat is None:
-            cat = "host_gap" if (hi - lo) <= host_gap_us else "idle"
-        out[cat] += hi - lo
+            cat = "host_gap" if rest <= host_gap_us else "idle"
+        out[cat] += rest
     return out
 
 
@@ -154,7 +196,8 @@ def analyze(trace: Dict[str, Any], host_gap_ms: float = 50.0,
         if lane is not None and pid != lane:
             continue
         d = by_pid.setdefault(pid, {"beats": [], "programs": [],
-                                    "instants": [], "all_ts": []})
+                                    "instants": [], "pauses": [],
+                                    "all_ts": []})
         ts = float(ev.get("ts", 0.0))
         end = ts + float(ev.get("dur", 0.0) or 0.0)
         d["all_ts"] += [ts, end]
@@ -164,6 +207,8 @@ def analyze(trace: Dict[str, Any], host_gap_ms: float = 50.0,
             d["programs"].append(ev)
         elif ev.get("cat") == "gap-cause" and ev.get("ph") == "i":
             d["instants"].append(ev)
+        elif ev.get("cat") == "host-pause" and ev.get("ph") == "X":
+            d["pauses"].append(ev)
     lanes: Dict[str, Any] = {}
     total = {c: 0.0 for c in CATEGORIES}
     by_class: Dict[str, float] = {}
@@ -173,7 +218,8 @@ def analyze(trace: Dict[str, Any], host_gap_ms: float = 50.0,
             continue
         span = (min(d["all_ts"]), max(d["all_ts"]))
         cats = attribute_lane(d["beats"], d["instants"], span,
-                              host_gap_ms * 1e3, tuple(d["programs"]))
+                              host_gap_ms * 1e3, tuple(d["programs"]),
+                              tuple(d["pauses"]))
         classes = busy_by_class(d["beats"], d["programs"], span)
         lane_wall = span[1] - span[0]
         lanes[str(pid)] = {

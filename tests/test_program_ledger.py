@@ -258,7 +258,8 @@ class TestLedgerOnAFakeClock:
         led = ProgramLedger(clock=FakeClock())
         p = led.enqueue(PROG_CHUNK, rows=1, n=300, shape="W512/S4096")
         assert parse_program_aux(p.aux()) == {
-            "seq": "0", "n": "300", "shape": "W512/S4096"}
+            "seq": "0", "n": "300", "shape": "W512/S4096",
+            "call": "0.000"}        # (PR 54: nobody stamped the return)
 
 
 # ---------------------------------------------------------------------------
@@ -393,8 +394,9 @@ class TestEngineEvents:
         rows = program_rows(eng)
         first = rows[0][0]                  # pl-0 and pl-1 in one group
         assert first["code"] == PROG_PREFILL and first["slot"] == 2
-        assert parse_program_aux(first["aux"]) == {
-            "seq": "0", "n": "7", "shape": "2x16"}
+        aux = parse_program_aux(first["aux"])
+        assert float(aux.pop("call")) > 0.0     # (PR 54: its call's ms)
+        assert aux == {"seq": "0", "n": "7", "shape": "2x16"}
         block = next(e for e, _, _ in rows.values()
                      if e["code"] == PROG_DECODE)
         aux = parse_program_aux(block["aux"])
