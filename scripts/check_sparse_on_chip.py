@@ -4,7 +4,8 @@
 
     chiprun -- timeout 3000 python3 scripts/check_sparse_on_chip.py \
         [--phases hazard,kernels,compare,step] [--seeds 1] \
-        [--attn-widths 4,8,16] [--parent DIR]
+        [--kernels index,select,attention] [--attn-widths 4,8,16] \
+        [--parent DIR[,DIR2]]
 
 Four phases, one JSON line each result (also chiprun_out/sparse/check.jsonl):
 
@@ -15,9 +16,16 @@ Four phases, one JSON line each result (also chiprun_out/sparse/check.jsonl):
            prompt: does the device stop (PERF.md section 7, OPEN since PR
            35), what does a program take.
   kernels  the three decode kernels alone at 16 slots and contexts of 6k,
-           10k and 16k, twelve calls a program as a step makes them: the
-           index scores; the selection as the kernel, as the XLA bitwise
-           partial sort and as `jax.lax.top_k`; the selected attention as
+           10k and 16k, twelve calls a program as a step makes them
+           (`--kernels index` reads one of the three alone): the
+           index scores, pages a call, us a call and us a page, with
+           `--parent` each of those trees' kernel beside this one's (and
+           whether its scores and the selection on them are this one's,
+           bit for bit) and two forms the probe alone builds, the walk's
+           copies without the dot and the dot without the copies, which
+           say whose a page's time is; the selection as the kernel, as the
+           XLA bitwise partial sort and as `jax.lax.top_k`; the selected
+           attention as
            the kernel that walks the slot's pages whole under the mask and
            as a GATHER of the 2,048 selected rows in XLA. The walk's line
            says the width, tail and look-ahead it ran with and us a page
@@ -26,8 +34,10 @@ Four phases, one JSON line each result (also chiprun_out/sparse/check.jsonl):
            `paged_attention_sparse._walk`; left out, the tail is the
            width, or 4 past a width of 8, and the look-ahead the
            module's), and `--parent DIR` (a `git archive` of another
-           commit, e.g. `.scratch/parent`) that commit's kernel beside
-           them.
+           commit, e.g. `.scratch/parent`; several with commas, which is
+           also how a kernel file kept outside the tree is read: `DIR/
+           generativeaiexamples_tpu/serving/sparse_index_scores.py`)
+           that commit's kernels beside them.
   compare  ISSUE 42's three-part comparison at the published widths on what
            the step programs produce (a 4,096-token prompt through
            `prefill_step` in the 6,144 bucket, then eight decode steps
@@ -70,9 +80,16 @@ def main() -> int:
     ap.add_argument("--attn-widths", default="",
                     help="widths (or width/tail/ahead) to time the selected "
                          "attention's walk at, beside the module's own")
+    ap.add_argument("--kernels", default="index,select,attention",
+                    help="which of the three the kernels phase reads")
+    ap.add_argument("--contexts", default="",
+                    help="the kernels phase's contexts in place of 6144,"
+                         "10240,16384; `mix`: a length a slot, drawn once "
+                         "between the first and the last of them")
     ap.add_argument("--parent", default=None,
-                    help="a checkout of another commit: its "
-                         "paged_attention_sparse timed beside this one's")
+                    help="checkouts of other commits, with commas: their "
+                         "sparse_index_scores and paged_attention_sparse "
+                         "timed beside this one's")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     if args.rehearse:
@@ -93,6 +110,7 @@ def main() -> int:
     from generativeaiexamples_tpu.serving import paged_attention_sparse as pas
     from generativeaiexamples_tpu.serving.paged_attention_sparse import (
         paged_attention_sparse)
+    from generativeaiexamples_tpu.serving import sparse_index_scores as sis
     from generativeaiexamples_tpu.serving.sparse_index_scores import (
         sparse_index_scores)
     from generativeaiexamples_tpu.serving.sparse_select import sparse_select
@@ -187,6 +205,72 @@ def main() -> int:
                                        use_pallas=use_pallas, live=live)
 
         scores_fn = twelve(scores_of)
+        which = set(args.kernels.split(","))
+
+        parents = {}  # tree -> the kernel modules it holds, by file name
+        for tree in filter(None, (args.parent or "").split(",")):
+            for name in ("sparse_index_scores", "paged_attention_sparse"):
+                path = os.path.join(tree, "generativeaiexamples_tpu",
+                                    "serving", name + ".py")
+                if os.path.exists(path):
+                    spec = importlib.util.spec_from_file_location(
+                        f"parent{len(parents)}_{name}", path)
+                    module = importlib.util.module_from_spec(spec)
+                    spec.loader.exec_module(module)
+                    parents.setdefault(tree, {})[name] = module
+
+        def index_program(module):
+            """Twelve calls of `module`'s index kernel (off the chip it is
+            interpreted, at the rehearsal's size)."""
+            return twelve(
+                lambda pools, l, ln: module.sparse_index_scores_pallas(
+                    qi, wt, pools[2], l, table, ln, live,
+                    interpret=args.rehearse))
+
+        def built(**patch):
+            """This tree's index kernel traced ONCE with `patch` over its
+            module's names: a form the probe alone builds (the lengths are
+            an argument, so one trace serves every context)."""
+            kept = {name: getattr(sis, name) for name in patch}
+            sis.sparse_index_scores_pallas.clear_cache()
+            for name, value in patch.items():
+                setattr(sis, name, value)
+            try:
+                fn = index_program(sis)
+                jax.block_until_ready(fn(pools, jnp.full((B,), contexts[0],
+                                                         jnp.int32)))
+            finally:
+                for name, value in kept.items():
+                    setattr(sis, name, value)
+                sis.sparse_index_scores_pallas.clear_cache()
+            return fn
+
+        class NoCopies:
+            """`pltpu` for a kernel whose copies neither start nor wait:
+            it multiplies whatever its buffers hold."""
+            def __init__(self, real):
+                self.real = real
+
+            def __getattr__(self, name):
+                return getattr(self.real, name)
+
+            def make_async_copy(self, *refs):
+                class Nothing:
+                    start = wait = staticmethod(lambda: None)
+                return Nothing
+
+        index_forms = {}
+        if "index" in which:
+            index_forms = {
+                "copies_alone_us": built(
+                    _tile_scores=lambda q, w, pages: jnp.zeros(
+                        (pages.shape[0], pages.shape[-1]), jnp.float32)),
+                "dot_alone_us": built(pltpu=NoCopies(sis.pltpu))}
+        index_parents = [(tree, index_program(held["sparse_index_scores"]),
+                          held["sparse_index_scores"])
+                         for tree, held in parents.items()
+                         if "sparse_index_scores" in held
+                         and "index" in which]
 
         def xla_threshold(sc, ln):
             valid = jnp.arange(sc.shape[1])[None, :] < ln[:, None]
@@ -223,20 +307,16 @@ def main() -> int:
             ahead = rest[1] if len(rest) > 1 else pas.BLOCKS_AHEAD
             walk = pas._walk(maxp, (width, tail, ahead))
             walks.append((walk, twelve(walk_with(walk))))
-        parent_walk = None
-        if args.parent:
-            spec = importlib.util.spec_from_file_location(
-                "parent_paged_attention_sparse", os.path.join(
-                    args.parent, "generativeaiexamples_tpu", "serving",
-                    "paged_attention_sparse.py"))
-            parent = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(parent)
 
-            @twelve
-            def parent_walk(pools, l, sel, ln):
-                return parent.paged_attention_sparse_pallas(
+        def walk_of(module):
+            return twelve(
+                lambda pools, l, sel, ln: module.paged_attention_sparse_pallas(
                     q, pools[0], pools[1], table, ln, sel, l, live,
-                    interpret=args.rehearse)
+                    interpret=args.rehearse))
+
+        parent_walks = [(tree, walk_of(held["paged_attention_sparse"]))
+                        for tree, held in parents.items()
+                        if "paged_attention_sparse" in held]
 
         def gather_attention(pools, l, sel_idx, ln):
             """The other form: the selected rows gathered, then dense
@@ -255,30 +335,69 @@ def main() -> int:
             p = jax.nn.softmax(jnp.where(keep, sc, -1e30), axis=-1)
             return jnp.einsum("bkgs,kbsd->bkgd", p, v).reshape(B, H, Hd)
 
-        for context in contexts:
-            ln = jnp.full((B,), context, jnp.int32)
+        def selection(sc, ln):
+            return np.asarray(sparse_select(sc, ln, topk, ps,
+                                            use_pallas=use_pallas, live=live))
+
+        asked = [c if c == "mix" else int(c)
+                 for c in filter(None, args.contexts.split(","))] or contexts
+        for context in asked:
+            if context == "mix":  # as a cell's slots are: no two alike
+                ln = jnp.asarray(np.random.default_rng(7).integers(
+                    contexts[0], contexts[-1] + 1, B), jnp.int32)
+            else:
+                ln = jnp.full((B,), context, jnp.int32)
             sc = jax.jit(scores_of, static_argnums=1)(pools, 0, ln)
+            pages = int(np.sum(-(-np.asarray(ln) // ps)))
             line = dict(phase="kernels", slots=B, context=context,
-                        calls_a_program=L)
-            line["index_scores_us"] = timed(scores_fn, pools, ln) / L * 1e6
-            forms = {
-                "select_kernel_us": lambda _, l, sc, ln: sparse_select(
-                    sc + l * 0.0, ln, topk, ps, use_pallas=use_pallas,
-                    live=live),
-                "select_xla_bitwise_us": lambda _, l, sc, ln: xla_threshold(
-                    sc + l * 0.0, ln),
-                "select_top_k_us": lambda _, l, sc, ln: top_k_threshold(
-                    sc + l * 0.0, ln)}
-            masks = {}
-            for name, fn in forms.items():
-                line[name] = timed(twelve(fn), (), sc, ln) / L * 1e6
-                masks[name] = np.asarray(jax.jit(fn, static_argnums=(0, 1))(
-                    (), 0, sc, ln))
-            line["selections_agree"] = bool(all(
-                np.array_equal(m, masks["select_kernel_us"])
-                for m in masks.values()))
-            sel = jnp.asarray(masks["select_kernel_us"])
-            pages = B * -(-context // ps)
+                        calls_a_program=L, pages_a_call=pages)
+            if "index" in which:
+                us = timed(scores_fn, pools, ln) / L * 1e6
+                block, ahead = sis._walk(Di, ps, idx.dtype.itemsize)
+                line["index_scores_us"] = us
+                line["index_scores"] = dict(
+                    block=block, ahead=ahead, us_per_page=us / pages,
+                    **{name: timed(fn, pools, ln) / L * 1e6
+                       for name, fn in index_forms.items()})
+                # the kernel itself on both sides (off the chip `sc` is
+                # the XLA form's)
+                ours = np.asarray(sis.sparse_index_scores_pallas(
+                    qi, wt, pools[2], 0, table, ln, live,
+                    interpret=args.rehearse))
+                line["index_scores_parents"] = []
+                for tree, fn, module in index_parents:
+                    us = timed(fn, pools, ln) / L * 1e6
+                    theirs = module.sparse_index_scores_pallas(
+                        qi, wt, pools[2], 0, table, ln, live,
+                        interpret=args.rehearse)
+                    line["index_scores_parents"].append(dict(
+                        tree=tree, us_per_call=us, us_per_page=us / pages,
+                        same_scores=bool(np.array_equal(
+                            np.asarray(theirs), ours)),
+                        same_selection=bool(np.array_equal(
+                            selection(theirs, ln),
+                            selection(jnp.asarray(ours), ln)))))
+            if "select" in which:
+                forms = {
+                    "select_kernel_us": lambda _, l, sc, ln: sparse_select(
+                        sc + l * 0.0, ln, topk, ps, use_pallas=use_pallas,
+                        live=live),
+                    "select_xla_bitwise_us": lambda _, l, sc, ln:
+                        xla_threshold(sc + l * 0.0, ln),
+                    "select_top_k_us": lambda _, l, sc, ln: top_k_threshold(
+                        sc + l * 0.0, ln)}
+                masks = {}
+                for name, fn in forms.items():
+                    line[name] = timed(twelve(fn), (), sc, ln) / L * 1e6
+                    masks[name] = np.asarray(jax.jit(
+                        fn, static_argnums=(0, 1))((), 0, sc, ln))
+                line["selections_agree"] = bool(all(
+                    np.array_equal(m, masks["select_kernel_us"])
+                    for m in masks.values()))
+            if "attention" not in which:
+                say(**line)
+                continue
+            sel = jnp.asarray(selection(sc, ln))
             line["attention_walk_us"] = timed(
                 twelve(walk_attention), pools, sel, ln) / L * 1e6
             width, tail, ahead = pas._walk(maxp)
@@ -294,10 +413,10 @@ def main() -> int:
                     blocks=pas.walk_counts(np.asarray(ln), ps, maxp,
                                            walk=walk)[1],
                     us_per_call=us, us_per_page=us / pages))
-            if parent_walk is not None:
-                us = timed(parent_walk, pools, sel, ln) / L * 1e6
-                line["attention_walk_parent"] = dict(
-                    us_per_call=us, us_per_page=us / pages)
+            for tree, fn in parent_walks:
+                us = timed(fn, pools, sel, ln) / L * 1e6
+                line.setdefault("attention_walk_parents", []).append(dict(
+                    tree=tree, us_per_call=us, us_per_page=us / pages))
             line["attention_dense_int8_us"] = timed(twelve(
                 lambda pools, l, ln: paged_attention_int8(
                     q, pools[0], pools[1], table, ln, l, live=live,
@@ -311,10 +430,11 @@ def main() -> int:
             g = np.asarray(jax.jit(gather_attention, static_argnums=1)(
                 pools, 0, sel_idx, ln), np.float32)
             line["walk_against_gather_max_abs"] = float(np.abs(a - g).max())
-            line["bytes_walk"] = B * context * entry.kv_bytes_per_token_layer(
-                config)
-            line["bytes_gather"] = B * min(context, topk) \
+            line["bytes_walk"] = int(np.sum(np.asarray(ln))) \
                 * entry.kv_bytes_per_token_layer(config)
+            line["bytes_gather"] = int(np.sum(np.minimum(
+                np.asarray(ln), topk))) * entry.kv_bytes_per_token_layer(
+                    config)
             say(**line)
         del kv, s, idx, pools
 

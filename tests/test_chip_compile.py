@@ -938,6 +938,49 @@ def test_the_sparse_decode_program_runs_its_kernels_under_their_names(chip):
     assert "paged_attention" not in "sparse_index_scores sparse_select"
 
 
+# -- the index scores' walk is one architecture's (PR 56) -------------------
+# `serving/sparse_index_scores.py` changed its walk (a chain of copies over
+# the live slots, whole blocks, `_walk`'s depth) and `served_sparse.
+# decode_once` is its one caller: the sparse decode program above still
+# holds ONE body of it called twelve times, the kernel compiles for the
+# chip with its new scratch shapes (`sparse_index_scores_masked_tables_of_
+# 152`, above), and a Llama's decode block, lowered for the chip with
+# kernels on at Mistral-7B's served sizes, is the text it was on PR 56's
+# PARENT (c937e79), taken there by these lines.
+PARENT_MISTRAL_DECODE_BLOCK = "9ac438bd35f80a0e"
+
+
+def test_a_llamas_decode_block_lowers_to_the_text_the_parent_did(chip):
+    import hashlib
+    import json
+
+    from benchmark import architectures
+    from benchmark.harness import system
+    from generativeaiexamples_tpu.serving import engine_model as em
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mistral-7b-v0.3-int8.json")) as fh:
+        config = json.load(fh)
+    ecfg = system.engine_config(config)
+    mcfg, params, pool, _ = architectures.load(config).compile_shapes(
+        config, ecfg, [next(iter(chip.device_set))])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, maxp = ecfg.max_batch_size, ecfg.max_seq_len // ecfg.page_size
+    text = em.decode_multi_step.lower(
+        params, mcfg, pool, arr((slots,), I32), arr((slots, maxp), I32),
+        arr((slots,), I32), arr((slots,), jnp.bool_), arr((slots,), F32),
+        arr((slots,), F32), arr((slots,), I32), arr((2,), jnp.uint32),
+        ecfg.decode_steps_per_dispatch, True,
+        sampling_flags=(True, False, False)).as_text()
+    assert 'kernel_name = "sparse_index_scores"' not in text
+    assert hashlib.sha256(_without_kernel_payload(
+        text).encode()).hexdigest()[:16] == PARENT_MISTRAL_DECODE_BLOCK
+
+
 # -- window and full attention in one model: the configuration's own shapes
 # (PR 44). The decode program of `smallthinker-21b-a3b-int8`, lowered for
 # the chip from its architecture entry's `compile_shapes` with kernels on:

@@ -34,6 +34,7 @@ from generativeaiexamples_tpu.serving.paged_attention_int8 import (
 from generativeaiexamples_tpu.serving import paged_attention_sparse as pas
 from generativeaiexamples_tpu.serving.paged_attention_sparse import (
     paged_attention_sparse, paged_attention_sparse_pallas)
+from generativeaiexamples_tpu.serving import sparse_index_scores as sis
 from generativeaiexamples_tpu.serving.sparse_index_scores import (
     sparse_index_scores, sparse_index_scores_pallas)
 from generativeaiexamples_tpu.serving.sparse_select import (
@@ -108,6 +109,15 @@ CASES = {
     "an-idle-slot": ((1, 37, 96, 64, 9), (True, True, True, False, True)),
     "one-live": ((5, 5, 80, 5, 5), (False, False, True, False, False)),
     "nobody": ((1, 1, 1, 1, 1), (False,) * 5),
+    # the kernels' chains of copies cross from one live slot to the next
+    # (PR 56: the index scores' too): a short slot behind a long one and a
+    # long one behind a short one, the first live slot not slot 0, idle
+    # slots first, between and last
+    "short-behind-long": ((96, 3, 96, 1, 90), None),
+    "the-first-live-slot-is-not-slot-0":
+        ((96, 17, 96, 64, 9), (False, True, True, True, True)),
+    "idle-first-between-and-last":
+        ((40, 96, 8, 96, 33), (False, True, False, True, False)),
 }
 
 
@@ -149,6 +159,102 @@ def test_the_three_kernels_are_their_xla_forms(case):
                                           interpret=True)
     np.testing.assert_allclose(got_o, want_o, rtol=1e-5, atol=1e-6)
     assert not np.asarray(want_o)[~live].any()
+
+
+# -- the index scores' walk: whole blocks, one chain over the live slots ----
+# (PR 56) A slot's pages are copied in WHOLE blocks of `_walk`'s width, with
+# no test a page, `ahead` blocks in flight across the live slots: a table
+# entry past a slot's last page is page 0, the sink, as the engine's tables
+# have it, and here the sink holds NaN: nothing copied from it may reach a
+# finite score, and every row of the output past a slot's last block stays
+# minus infinity. One shape for every scene (a kernel's trace is the test's
+# cost): tables wider than everything the rule keeps in flight.
+
+INDEX_BLOCK, INDEX_AHEAD = sis._walk(8, PS, 2)
+INDEX_MAXP = (INDEX_AHEAD + 2) * INDEX_BLOCK + 3
+INDEX_SLOTS = 6
+
+
+def _index_pages(name):
+    return {"one": 1, "block-1": INDEX_BLOCK - 1, "block": INDEX_BLOCK,
+            "block+1": INDEX_BLOCK + 1, "in-flight+1":
+                (INDEX_AHEAD + 1) * INDEX_BLOCK + 1,
+            "maxp": INDEX_MAXP}[name]
+
+
+def _index_scene(name):
+    """-> (lengths, mask) of INDEX_SLOTS slots."""
+    if name in ("one", "block-1", "block", "block+1", "in-flight+1", "maxp"):
+        # a row of that many pages: its last one whole, a token into it, a
+        # token short of whole; two short rows and a long one beside them
+        n = _index_pages(name)
+        return (n * PS, 3, max((n - 1) * PS + 1, 1), n * PS - 1,
+                INDEX_MAXP * PS - 5, 9), None
+    long, short = INDEX_MAXP * PS, 5
+    return {
+        "short-behind-long": ((long, short, long - 9, 1, long, short), None),
+        "long-behind-short": ((short, long, 1, long - 9, short, long), None),
+        "the-first-live-slot-is-not-slot-0":
+            ((long, 70, long, 9, 200, 64),
+             (False, False, True, True, True, True)),
+        "idle-first-between-and-last":
+            ((long, long - 1, 9, long, 33, long),
+             (False, True, False, False, True, False)),
+        "one-live": ((5, 5, long - 3, 5, 5, 5),
+                     (False, False, True, False, False, False)),
+        "nobody": ((long, 1, 9, 1, 1, long), (False,) * INDEX_SLOTS),
+    }[name]
+
+
+INDEX_SCENES = ["one", "block-1", "block", "block+1", "in-flight+1", "maxp",
+                "short-behind-long", "long-behind-short",
+                "the-first-live-slot-is-not-slot-0",
+                "idle-first-between-and-last", "one-live", "nobody"]
+
+
+@pytest.mark.parametrize("scene", INDEX_SCENES)
+def test_the_index_walk_copies_whole_blocks_and_scores_only_what_a_slot_has(
+        scene):
+    lengths, mask = _index_scene(scene)
+    rng = np.random.default_rng(len(scene))
+    B, L, Hi, Di = INDEX_SLOTS, 2, 4, 8
+    P = B * INDEX_MAXP + 1
+    n = -(-np.asarray(lengths) // PS)
+    table = rng.permutation(np.arange(1, P))[:B * INDEX_MAXP].reshape(
+        B, INDEX_MAXP)
+    table[np.arange(INDEX_MAXP)[None] >= n[:, None]] = 0  # padding -> page 0
+    idx = jnp.asarray(rng.normal(size=(L, P, Di, PS)), jnp.bfloat16)
+    idx = idx.at[:, 0].set(jnp.nan)                       # the sink's page
+    q = jnp.asarray(rng.normal(size=(B, Hi, Di)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(B, Hi)), jnp.float32)
+    live = None if mask is None else live_rows(jnp.asarray(mask))
+    args = (q, w, idx, 1, jnp.asarray(table, jnp.int32),
+            jnp.asarray(lengths, jnp.int32))
+    want = np.asarray(sparse_index_scores(*args, use_pallas=False, live=live))
+    got = np.asarray(sparse_index_scores_pallas(*args, live, interpret=True))
+    assert not np.isnan(got).any()
+    alive = np.ones(B, bool) if mask is None else np.asarray(mask)
+    # finite below a live slot's length, minus infinity at and past it,
+    # past its last block and everywhere in a slot nobody walked
+    at = np.arange(INDEX_MAXP * PS)[None]
+    assert np.array_equal(np.isfinite(got),
+                          (at < np.asarray(lengths)[:, None]) & alive[:, None])
+    assert np.all(got[~np.isfinite(got)] == -np.inf)
+    np.testing.assert_allclose(np.where(np.isfinite(want), got, 0),
+                               np.where(np.isfinite(want), want, 0),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_the_index_walks_rule_at_the_cells_page():
+    """`_walk` from a page's shape alone: whole tiles of 8 output rows a
+    block, and buffers (what is in flight and the block being multiplied)
+    that fit VMEM beside the slot's output block."""
+    block, ahead = sis._walk(64, 128, 2)       # Keye's index keys: 16 KB
+    assert block % 8 == 0 and ahead >= 2
+    assert (ahead + 1) * block * 64 * 128 * 2 <= 4 << 20
+    # a wider key gets its own depth by the same rule, never under two
+    wide = sis._walk(256, 128, 2)
+    assert wide[0] % 8 == 0 and 2 <= wide[1] <= ahead
 
 
 # -- the selected attention's walk: a block is the unit of its softmax -------
