@@ -195,6 +195,15 @@ def llama_config_from_hf(path: str) -> llama_lib.LlamaConfig:
             "models/linear_attn_moe.py's LinearAttnMoeConfig, and this "
             "loader holds no tensor-name map for it (the convolutions, "
             "A_log, dt_bias, the low-rank gates, the expert stacks)")
+    if int(c.get("hc_mult", 1)) > 1:
+        raise ValueError(
+            f"{path}: model_type {c.get('model_type')!r} has "
+            f"{c['hc_mult']} residual streams (hc_mult) mixed around every "
+            "branch: it is no LlamaConfig, whose block knows one stream and "
+            "one add; the mixing is models/hyper_connections.py's, under "
+            "models/latent_moe.py's LatentMoeConfig, and this loader holds "
+            "no tensor-name map for it (each branch's phi, biases and "
+            "three gains)")
     if "kv_lora_rank" in c:
         raise ValueError(
             f"{path}: model_type {c.get('model_type')!r} has latent "

@@ -23,6 +23,13 @@ What differs from models/llama.py's block, and nothing else:
   live HERE: expert parallelism's share of the layer. What the experts
   elsewhere would add is left out; on one chip the layer runs with no
   exchange (`moe_branch`).
+- THE RESIDUAL is not written here: a branch (the attention up to
+  `heads_out`, `feed_forward`) takes its input and returns its OUTPUT,
+  and models/hyper_connections.py says how the stream goes in and comes
+  out (`residual.open` before a branch, `residual.close` after it). With
+  `hc_mult` 1 that is `x` and `x + y`; with `hc_mult` n > 1 the stream
+  is n wide and a block holds the mixing's leaves (`hc_attn_*`,
+  `hc_ffn_*`: that module's docstring).
 
 Parameters: `tok_emb`, `ln_f`, `lm_head`; `dense` (the leading blocks,
 stacked) and `layers` (the expert blocks, stacked), each with the
@@ -30,7 +37,8 @@ attention's leaves (`w_qa q_norm w_qb`, or `w_q`; `w_kva kv_norm w_kvb wo`), `ln
 `ln2` and llama's `w_gate w_up w_down` (the dense feed-forward, or the
 shared expert); an expert block adds `router` [L, D, n_routed_experts]
 and the held experts' `we_gate_up` [L, E, D, 2 * Me] (gate ; up) and
-`we_down` [L, E, Me, D].
+`we_down` [L, E, Me, D], and `router_bias` [L, n_routed_experts] float32
+where cfg.router_bias says the selection reads one.
 """
 
 from __future__ import annotations
@@ -42,8 +50,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from generativeaiexamples_tpu.models import hyper_connections as residual
 from generativeaiexamples_tpu.models.llama import (
-    YarnScaling, attn_out, rms_norm, rope, swiglu)
+    YarnScaling, rms_norm, rope, swiglu)
 from generativeaiexamples_tpu.ops import attention as attn_ops
 from generativeaiexamples_tpu.ops import moe
 from generativeaiexamples_tpu.ops.quant import QuantizedTensor, mm
@@ -82,6 +91,14 @@ class LatentMoeConfig:
     max_seq_len: int = 131072
     tie_embeddings: bool = False
     dtype: Any = jnp.bfloat16
+    # the block's correction bias, which the selection alone reads
+    # (`topk_method` "noaux_tc"): a float32 `router_bias` leaf a block
+    router_bias: bool = False
+    # the residual path (models/hyper_connections.py): 1 is x + y
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     # what serving/ reads of any model configuration
     n_passes = 1
@@ -96,6 +113,8 @@ class LatentMoeConfig:
                 f"{self.n_routed_experts} experts")
         if self.n_shared_experts != 1:
             raise ValueError("one shared expert is what is written")
+        if self.hc_mult < 1:
+            raise ValueError(f"hc_mult {self.hc_mult}: a stream or several")
 
     @property
     def cache_rows(self) -> int:
@@ -146,6 +165,10 @@ class LatentMoeConfig:
 # at a quarter it is the size of bf16's own noise. A checkpoint's own
 # weights replace these; the mathematics is the same whatever the gain.
 ROUTED_INIT_GAIN = 0.25
+# The seeded correction bias (cfg.router_bias), as linear_attn_moe draws
+# it: large enough to change the selection in about one token in ten,
+# the weights never.
+ROUTER_BIAS_STD = 0.02
 
 
 def attention_shapes(cfg, L: int):
@@ -175,12 +198,18 @@ def _block_shapes(cfg: LatentMoeConfig, L: int, mlp: int):
 
 
 def init_params_on_device(cfg: LatentMoeConfig, seed: int = 0, *,
-                          quantize: bool = False) -> Params:
+                          quantize: bool = False,
+                          depth_gain: bool = False) -> Params:
     """Seeded random parameters drawn leaf by leaf on the device, each
     in the type it is served in (llama.init_params_on_device's recipe:
     uniform int8 codes with the per-column scale that gives the leaf its
     standard deviation, fan_in ** -0.5; norms of one; the router and
-    the embedding in cfg.dtype)."""
+    the embedding in cfg.dtype). `depth_gain`: the convention
+    linear_attn_moe.py draws by, for a deep stack: the embedding at a
+    standard deviation of 1 and every branch's last projection at
+    (2 * n_layers) ** -0.5 of its fan-in scale, so that no block's input
+    is another block's output alone and a rounding does not double a
+    layer (40 layers: PERF.md, PR 57)."""
     root = jax.random.key(seed)
     leaf_ids = itertools.count(1)
 
@@ -206,9 +235,13 @@ def init_params_on_device(cfg: LatentMoeConfig, seed: int = 0, *,
                      jnp.float32)
         return QuantizedTensor(q, s)
 
+    out_gain = (2 * cfg.n_layers) ** -0.5 if depth_gain else 1.0
+    gains = {"wo": out_gain, "w_down": out_gain}
+
     def block(L, mlp):
         weights, norms = _block_shapes(cfg, L, mlp)
-        out = {k: weight(*shape) for k, shape in weights.items()}
+        out = {k: weight(*shape, gain=gains.get(k, 1.0))
+               for k, shape in weights.items()}
         out.update({k: jnp.ones(shape, cfg.dtype)
                     for k, shape in norms.items()})
         return out
@@ -218,15 +251,23 @@ def init_params_on_device(cfg: LatentMoeConfig, seed: int = 0, *,
     layers = block(Lm, Me)
     layers["router"] = normal(Lm, D, cfg.n_routed_experts, scale=D ** -0.5)
     layers["we_gate_up"] = weight(Lm, E, D, 2 * Me)
-    layers["we_down"] = weight(Lm, E, Me, D, gain=ROUTED_INIT_GAIN)
+    layers["we_down"] = weight(Lm, E, Me, D,
+                               gain=ROUTED_INIT_GAIN * out_gain)
     params: Params = {
-        "tok_emb": normal(V, D, scale=0.02),
+        "tok_emb": normal(V, D, scale=1.0 if depth_gain else 0.02),
         "ln_f": jnp.ones((D,), cfg.dtype),
         "dense": block(cfg.n_dense_layers, cfg.mlp_dim),
         "layers": layers,
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = weight(D, V)
+    # leaves of later PRs are drawn LAST: a seed's older leaves stay put
+    if cfg.router_bias:
+        layers["router_bias"] = draw(lambda k: jax.random.normal(
+            k, (Lm, cfg.n_routed_experts), jnp.float32) * ROUTER_BIAS_STD)
+    if cfg.hc_mult > 1:
+        for stack, L in (("dense", cfg.n_dense_layers), ("layers", Lm)):
+            params[stack].update(residual.init_leaves(cfg, L, normal))
     return params
 
 
@@ -324,6 +365,14 @@ def attend_prompt(cfg: LatentMoeConfig, q_nope, q_rope, row, w, lengths,
                               scale=cfg.softmax_scale, use_pallas=use_pallas)
 
 
+def heads_out(out, w):
+    """The attention branch's end: heads `out` [B, H, S, Dv] through the
+    output projection. -> the branch's output [B, S, D]."""
+    B, _, S, _ = out.shape
+    with jax.named_scope("attn.out"):
+        return mm(out.transpose(0, 2, 1, 3).reshape(B, S, -1), w["wo"])
+
+
 # -- the expert layer ------------------------------------------------------
 
 def route(cfg: LatentMoeConfig, h, router, bias=None):
@@ -384,18 +433,19 @@ def moe_branch(cfg: LatentMoeConfig, h, w, experts, layer, use_pallas=None,
     return y, plan.counts, idx
 
 
-def feed_forward(cfg: LatentMoeConfig, x, w, experts=None, layer=None,
+def feed_forward(cfg: LatentMoeConfig, u, w, experts=None, layer=None,
                  use_pallas=None, mask=None):
-    """The block from its attention's residual add on: norm, then the
-    dense SwiGLU (experts None) or the expert layer, added to x [B, S,
-    D]. Returns (x, pair counts [E] or None, router's choice or None)."""
-    B, S, D = x.shape
-    h = rms_norm(x, w["ln2"], cfg.rms_eps).astype(cfg.dtype)
+    """The block's second branch for its input u [B, S, D]: norm, then
+    the dense SwiGLU (experts None) or the expert layer. Returns (the
+    branch's OUTPUT y [B, S, D], which `residual.close` puts into the
+    stream; pair counts [E] or None; the router's choice or None)."""
+    B, S, D = u.shape
+    h = rms_norm(u, w["ln2"], cfg.rms_eps).astype(cfg.dtype)
     if experts is None:
-        return x + swiglu(h, w), None, None
+        return swiglu(h, w), None, None
     y, counts, idx = moe_branch(cfg, h.reshape(B * S, D), w, experts, layer,
                                 use_pallas, mask)
-    return x + y.reshape(B, S, D), counts, idx.reshape(B, S, -1)
+    return y.reshape(B, S, D), counts, idx.reshape(B, S, -1)
 
 
 def split_experts(layers: Params):
@@ -406,7 +456,9 @@ def split_experts(layers: Params):
 
 
 def logits_of(cfg: LatentMoeConfig, params: Params, x):
-    x = rms_norm(x, params["ln_f"], cfg.rms_eps)
+    """The stream x [.., D] (hc_mult streams: [.., n, D], summed) through
+    ln_f and the head."""
+    x = rms_norm(residual.leave(cfg, x), params["ln_f"], cfg.rms_eps)
     with jax.named_scope("lm_head"):
         if cfg.tie_embeddings:
             return (x @ params["tok_emb"].T.astype(x.dtype)
@@ -414,38 +466,48 @@ def logits_of(cfg: LatentMoeConfig, params: Params, x):
         return mm(x, params["lm_head"]).astype(jnp.float32)
 
 
+def embed(cfg: LatentMoeConfig, params: Params, tokens):
+    """Token ids [..] -> the stream they enter as ([.., D], or hc_mult
+    copies [.., n, D])."""
+    return residual.enter(
+        cfg, params["tok_emb"][tokens].astype(cfg.residual_dtype))
+
+
 def walk_prompt(params: Params, cfg: LatentMoeConfig, tokens, lengths=None,
-                use_pallas=None):
+                use_pallas=None, mix=None):
     """Token ids [B, S] through every block in its prompt form, one
     causal pass with no cache: the leading dense blocks unrolled, the
     expert blocks one scanned body (their held experts' weights stay
-    where they lie). Returns (the stream [B, S, D], the latent rows a
-    cache would keep [cache_rows, B, S, C + R], the router's choices
-    [n_moe_layers, B, S, k])."""
+    where they lie). `mix`: who mixes hc_mult > 1 streams (None:
+    models/hyper_connections.py's jax.numpy form). Returns (the stream
+    [B, S, D] or [B, S, n, D], the latent rows a cache would keep
+    [cache_rows, B, S, C + R], the router's choices [n_moe_layers, B, S,
+    k])."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     if lengths is None:
         lengths = jnp.full((B,), S, jnp.int32)
-    x = params["tok_emb"][tokens].astype(cfg.residual_dtype)
+    x = embed(cfg, params, tokens)
 
-    def attention(x, w):
-        h = rms_norm(x, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
+    def block(x, w, experts=None, l=None):
+        u, carry = residual.open(cfg, x, w, "attn", mix)
+        h = rms_norm(u, w["ln1"], cfg.rms_eps).astype(cfg.dtype)
         q_nope, q_rope, row = project_latent(cfg, h, w, positions)
         out = attend_prompt(cfg, q_nope, q_rope, row, w, lengths, use_pallas)
-        return attn_out(cfg, x, out, w), row
+        x = residual.close(cfg, x, heads_out(out, w), carry)
+        u, carry = residual.open(cfg, x, w, "ffn", mix)
+        y, _, idx = feed_forward(cfg, u, w, experts, l, use_pallas)
+        return residual.close(cfg, x, y, carry), row, idx
 
     rows = []
     for l in range(cfg.n_dense_layers):
-        w = take_layer(params["dense"], l)
-        x, row = attention(x, w)
-        x, _, _ = feed_forward(cfg, x, w)
+        x, row, _ = block(x, take_layer(params["dense"], l))
         rows.append(row[None])
     sliced, experts = split_experts(params["layers"])
 
     def body(carry, w):
         x, l = carry
-        x, row = attention(x, w)
-        x, _, idx = feed_forward(cfg, x, w, experts, l, use_pallas)
+        x, row, idx = block(x, w, experts, l)
         return (x, l + 1), (row, idx)
 
     (x, _), (moe_rows, choices) = jax.lax.scan(

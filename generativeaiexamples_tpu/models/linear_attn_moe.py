@@ -49,6 +49,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from generativeaiexamples_tpu.models import hyper_connections as residual
 from generativeaiexamples_tpu.models import latent_moe
 from generativeaiexamples_tpu.models.hybrid_ssm import (
     RecurrentState, layer_plan)
@@ -73,7 +74,7 @@ _F32 = jnp.float32
 DECAY_A = (1.0, 4.0)
 DECAY_STEP = (0.001, 0.025)
 DECAY_GATE_GAIN = 0.5
-ROUTER_BIAS_STD = 0.02
+ROUTER_BIAS_STD = latent_moe.ROUTER_BIAS_STD  # 0.02: one draw for both
 # ... and of how a rounding travels through the depth. At the usual 0.02
 # the embedding is a fortieth of the first branch's output, every early
 # block's input is then its predecessors' output alone, and random blocks
@@ -554,8 +555,9 @@ def walk_prompt(params: Params, cfg: LinearAttnMoeConfig, tokens,
                                 lengths, use_pallas)
             rows.append(row)
         w, experts, e = ffn_weights(cfg, params, l)
-        x, _, idx = latent_moe.feed_forward(cfg, x, w, experts, e,
+        y, _, idx = latent_moe.feed_forward(cfg, x, w, experts, e,
                                             use_pallas)
+        x = residual.close(cfg, x, y, None)  # one stream: x + y
         if idx is not None:
             choices.append(idx)
     return (x, jnp.stack(rows), jnp.stack(states), jnp.stack(tails),

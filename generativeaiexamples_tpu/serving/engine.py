@@ -3019,7 +3019,8 @@ class LLMEngine:
         self._await_program(prog, toks)
         seq_no = float(prog.seq) if prog is not None else 0.0
         metas = []
-        self.served.note_prefill(self.metrics, self.cfg, len(entries))
+        self.served.note_prefill(self.metrics, self.cfg, len(entries),
+                                 int(lengths[:n].sum()))
         for req, slot_idx, seq, ids in entries:
             slot = _Slot(req, seq, StreamDetokenizer(self.tokenizer),
                          span=self._request_span(req, len(ids)))

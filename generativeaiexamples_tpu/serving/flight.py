@@ -143,6 +143,12 @@ EV_STATE_CACHE = 24
 # the scheduler's that came back late); b = the generation (gc) or 0;
 # aux = "gen=<g> collected=<n> thread=<name>" or "where=<fetch|idle>".
 EV_HOST_PAUSE = 25
+# Several residual streams mixed around every branch
+# (models/hyper_connections.py): one a landed decode block of a model
+# with hc_mult > 1 (scheduler thread), from the mask the host dispatched
+# the block with. a = branches mixed a step of the block (live slots x 2
+# x layers); b = the stream's bytes a token (hc_mult x dim x itemsize).
+EV_RESIDUAL_MIX = 26
 
 # Program classes (EV_PROGRAM.code).
 PROG_DECODE = 0    # a decode block (n = steps K)
@@ -186,7 +192,7 @@ EVENT_NAMES = {
     EV_MOE_LOAD: "moe_load", EV_PROGRAM: "program",
     EV_DECODE_JOIN: "decode_join", EV_SPARSE_SELECT: "sparse_select",
     EV_WINDOW_CACHE: "window_cache", EV_STATE_CACHE: "state_cache",
-    EV_HOST_PAUSE: "host_pause",
+    EV_HOST_PAUSE: "host_pause", EV_RESIDUAL_MIX: "residual_mix",
 }
 
 # Retire reason codes (EV_RETIRE.code); anything unknown maps to -1.

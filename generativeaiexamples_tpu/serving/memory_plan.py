@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.config.schema import EngineConfig
+from generativeaiexamples_tpu.models import hyper_connections
 from generativeaiexamples_tpu.models.llama import LlamaConfig
 from generativeaiexamples_tpu.serving.served_models import served
 
@@ -247,13 +248,17 @@ def _scratch_lines(lcfg: LlamaConfig, ecfg: EngineConfig,
     mlp = math.ceil(getattr(lcfg, "mlp_dim", 0) / tp) \
         or 2 * getattr(lcfg, "d_inner", 0) \
         or 2 * lcfg.moe_mlp_dim * lcfg.n_experts_per_tok
-    acts = tokens * (4 * lcfg.dim + 2 * mlp) * wsize
+    # (hc_mult residual streams: every hidden-width copy is so many wide)
+    streams = hyper_connections.streams(lcfg)
+    acts = tokens * (4 * streams * lcfg.dim + 2 * mlp) * wsize
     logits = n_seq * math.ceil(lcfg.vocab_size / tp) * 4
     return (
         PlanLine("long_prefill_scratch", long_pf, False,
                  "1 full-length KVCache, counted unsharded"),
         PlanLine("activation_transients", acts + logits, False,
-                 f"{n_seq} seq x {bucket}-token bucket"),
+                 f"{n_seq} seq x {bucket}-token bucket"
+                 + (f", {streams} residual streams wide" if streams > 1
+                    else "")),
     )
 
 

@@ -149,7 +149,7 @@ def _note_decode(metrics, cfg, lengths, active_mask, K, pool, use_pallas,
         metrics.ssm_steps_kernel += K
 
 
-def _note_prefill(metrics, cfg, n):
+def _note_prefill(metrics, cfg, n, tokens):
     metrics.ssm_slot_writes += n
 
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from generativeaiexamples_tpu.models import hyper_connections as residual
 from generativeaiexamples_tpu.models import latent_moe
 from generativeaiexamples_tpu.models import linear_attn_moe as lam
 from generativeaiexamples_tpu.models.llama import attn_out, rms_norm
@@ -107,8 +108,9 @@ def decode_once(params, cfg, pool, tokens, page_tables, lengths, use_pallas,
                                            w, attend)
             x = attn_out(cfg, x, out[:, :, None, :], w)
         w, experts, e = lam.ffn_weights(cfg, params, l)
-        x, n, idx = latent_moe.feed_forward(cfg, x, w, experts, e,
+        y, n, idx = latent_moe.feed_forward(cfg, x, w, experts, e,
                                             use_pallas, mask)
+        x = residual.close(cfg, x, y, None)  # one stream: x + y
         if n is not None:
             counts.append(n)
             choices.append(idx[:, 0])
@@ -177,7 +179,7 @@ def _note_decode(metrics, cfg, lengths, active_mask, K, pool, use_pallas,
             rows / (rows + cfg.recurrent_state.bytes_per_slot))
 
 
-def _note_prefill(metrics, cfg, n):
+def _note_prefill(metrics, cfg, n, tokens):
     metrics.ssm_slot_writes += n
 
 

@@ -99,7 +99,8 @@ class ServedModel:
     # cfg, ecfg, pool, n_pages) at engine build, with one log line;
     # (metrics, cfg, lengths [B], active_mask [B], K, pool, use_pallas,
     # max_pages) a dispatched decode block -> the flight event (code, a, b)
-    # to record when it lands, or None; (metrics, cfg, n) a prefill.
+    # to record when it lands, or None; (metrics, cfg, n prompts, their
+    # real tokens) a prefill.
     counters: Tuple[str, ...] = ()
     gauges: Tuple[str, ...] = ()
     describe: Callable = lambda *args: None

@@ -90,6 +90,16 @@ def _masked(split_kv=None):
         q, kv, s, t, ln, 1, split_kv=split_kv, live=live_rows(m))
 
 
+def _HC_PRE(tokens):
+    return [((tokens, 4 * 3584), BF16), ((38, 24, 4 * 3584), BF16),
+            ((38, 24), F32), ((38, 3), F32)]
+
+
+def _HC_POST(tokens):
+    return [((tokens, 4 * 3584), BF16), ((tokens, 3584), BF16),
+            ((tokens, 128), F32)]
+
+
 KERNELS = {
     "paged_decode_int8": (
         lambda q, kv, s, t, ln: paged_attention_int8(q, kv, s, t, ln, 1),
@@ -299,7 +309,35 @@ KERNELS = {
         lambda *a: _grouped(128, *a),
         [((12288 + 32 * 128, 2304), BF16), ((26, 32, 2304, 2048), I8),
          ((26, 32, 2048), F32), ((128,), I32), ((1,), I32)]),
+    # Xing4.0-29B-A4B's widths
+    # (benchmark/configs/xing4.0-29b-a4b-int8-ep4.json): the mixing of
+    # four streams of 3,584 around a branch, a decode step's 128 tokens
+    # (blocks of 32) and a prefill group's 4 x 256 (blocks of 128), the
+    # 38 expert blocks' leaves read whole by the block's index
+    "hc_pre_decode_128": (
+        lambda *a: _hc_pre(*a), _HC_PRE(128)),
+    "hc_pre_prefill_1024": (
+        lambda *a: _hc_pre(*a), _HC_PRE(1024)),
+    "hc_post_decode_128": (
+        lambda *a: _hc_post(*a), _HC_POST(128)),
+    "hc_post_prefill_1024": (
+        lambda *a: _hc_post(*a), _HC_POST(1024)),
 }
+
+
+def _hc_cfg():
+    from generativeaiexamples_tpu.models.latent_moe import LatentMoeConfig
+    return LatentMoeConfig(dim=3584, hc_mult=4)
+
+
+def _hc_pre(x, phi, b, alpha):
+    from generativeaiexamples_tpu.serving import hc_mix
+    return hc_mix.hc_pre_pallas(_hc_cfg(), x, phi, b, alpha, 5)
+
+
+def _hc_post(x, y, coef):
+    from generativeaiexamples_tpu.serving import hc_mix
+    return hc_mix.hc_post_pallas(_hc_cfg(), x, y, coef)
 
 
 def _grouped(tm, x, q, s, tile_group, n_tiles):
@@ -835,6 +873,33 @@ def test_a_hybrid_models_programs_lower_to_the_text_the_parent_did(chip):
     got = {k: hashlib.sha256(_without_kernel_payload(
         v.as_text()).encode()).hexdigest()[:16] for k, v in programs.items()}
     assert got == PARENT_HYBRID, json.dumps(got)
+
+
+# -- the residual seam leaves the one-stream programs alone (PR 57) ----------
+# `latent_moe`'s branches return their OUTPUT since PR 57 and
+# `hyper_connections.open` / `close` put it into the stream; with one
+# stream (`hc_mult` 1) that is `x` and `x + y`. A.X-K1's four programs are
+# held above (PARENT_LATENT: unchanged since PR 41's parent). Kimi-Linear's
+# walk takes its expert layers from the same functions: its decode and
+# prefill programs, lowered for the chip with kernels on at the
+# configuration's served sizes, are the text they were on PR 57's PARENT
+# (ecb6f1b), taken there by `_step_programs_lowered`.
+PARENT_LINEAR = {"decode_multi_step_k8": "b12e2ed0705a6323",
+                 "decode_step": "f84ec239f473fda5",
+                 "prefill_1x128": "53b3b1e7a18d36f4",
+                 "prefill_4x384": "76063bae9767719e"}
+
+
+def test_a_linear_models_programs_lower_to_the_text_the_parent_did(chip):
+    import hashlib
+    import json
+
+    programs, mcfg = _step_programs_lowered(
+        chip, "kimi-linear-48b-a3b-int8-ep8")
+    assert mcfg.recurrent_state.layers == 20
+    got = {k: hashlib.sha256(_without_kernel_payload(
+        v.as_text()).encode()).hexdigest()[:16] for k, v in programs.items()}
+    assert got == PARENT_LINEAR, json.dumps(got)
 
 
 # -- linear attention beside latent attention: the configuration's own

@@ -708,7 +708,7 @@ class TestOperatorsKeys:
     def test_event_names_and_kinds(self):
         assert EV_HOST_PAUSE == 25
         assert flight.EVENT_NAMES[EV_HOST_PAUSE] == "host_pause"
-        assert len(flight.EVENT_NAMES) == 25
+        assert len(flight.EVENT_NAMES) == 26  # 25 kinds at PR 54; `residual_mix` (26) since PR 57
         assert flight.PAUSE_CAUSES == ("gc", "late_wake")
         assert (flight.PAUSE_MIN_MS, flight.LATE_WAKE_MS) == (1.0, 20.0)
 

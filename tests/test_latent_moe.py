@@ -279,12 +279,21 @@ def test_a_reference_of_another_share_disagrees(params):
 
 # -- the share test ----------------------------------------------------------
 
-def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("streams", [1, 4])
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(streams):
     """Four chips hold 4 of the 16 experts each. The parts of an expert
     layer's feed-forward that the four shares compute, with what every
     chip computes alike (the shared expert) counted once, add up to the
-    layer computed whole (all 16 experts held: the uncut reference)."""
-    whole_cfg = dataclasses.replace(CFG, experts_held=16, expert_offset=0)
+    layer computed whole (all 16 experts held: the uncut reference). With
+    four residual streams (`hc_mult` 4: every chip mixes its own
+    sequences' streams alike) the same holds of the STREAM after the
+    branch, which is linear in the branch's output: the four chips'
+    routed parts of it plus the shared expert's, counted once, are the
+    uncut layer's."""
+    from generativeaiexamples_tpu.models import hyper_connections as residual
+
+    base = dataclasses.replace(CFG, hc_mult=streams)
+    whole_cfg = dataclasses.replace(base, experts_held=16, expert_offset=0)
     whole = lm.init_params_on_device(whole_cfg, 11, quantize=True)
     w = lm.take_layer(whole["layers"], 0, skip=lm.EXPERT_WEIGHTS)
     h = jax.random.normal(jax.random.PRNGKey(4), (19, CFG.dim), jnp.float32)
@@ -293,16 +302,33 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     assert int(counts.sum()) == 19 * 4
     shared = llama.swiglu(h, w)
     total = shared
+    parts = []
     for share in range(4):
-        cfg = dataclasses.replace(CFG, experts_held=4,
+        cfg = dataclasses.replace(base, experts_held=4,
                                   expert_offset=4 * share)
         mine = {k: QuantizedTensor(v.q[:, 4 * share:4 * share + 4],
                                    v.s[:, 4 * share:4 * share + 4])
                 for k, v in experts.items()}
         y, n, _ = lm.moe_branch(cfg, h, w, mine, 0, False)
         assert int(n.sum()) <= 19 * 4
+        parts.append(y - shared)
         total = total + (y - shared)
     np.testing.assert_allclose(total, y_whole, rtol=1e-4, atol=1e-5)
+    # the stream after the branch: what each chip hands its next layer
+    shape = (19,) + ((streams, CFG.dim) if streams > 1 else (CFG.dim,))
+    x = jax.random.normal(jax.random.PRNGKey(5), shape, jnp.float32)
+    _, carry = residual.open(whole_cfg, x, w, "ffn")
+    assert (carry is None) == (streams == 1)
+
+    def after(y):
+        return residual.close(whole_cfg, x, y, carry)
+
+    alike = after(shared)  # counted once
+    stream = alike + sum(after(shared + part) - alike for part in parts)
+    np.testing.assert_allclose(stream, after(y_whole), rtol=1e-4, atol=1e-4)
+    if streams > 1:  # and the mixing is not the plain add
+        assert float(jnp.abs(after(y_whole)[:, 0] - (x[:, 0] + y_whole))
+                     .max()) > 1e-2
     # and the whole layer is the plain reference's layer
     file16 = config_file(n_routed_experts=16, expert_offset=0)
     wl = jax.tree.map(lambda a: a[0], whole["layers"])
